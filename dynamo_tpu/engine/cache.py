@@ -23,6 +23,7 @@ the uint8 payload dtype IS the packed-int4 marker everywhere downstream
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -129,28 +130,47 @@ def register_device_tier(pool, spec: KVCacheSpec, *, name: str = "device") -> No
     get_mem_ledger().register_tier(name, _occupancy)
 
 
-def allocate_cache(spec: KVCacheSpec, mesh: Mesh | None = None):
-    """Allocate zeroed K and V caches (sharded if a mesh is given).
-
-    Returns plain arrays, or ``{"q", "s"}`` pytrees when ``spec.quantized``
-    (payload and scales sharded with per-leaf out_shardings)."""
+def _zeros(spec: KVCacheSpec):
+    """One zeroed cache (K or V): a plain array, or the ``{"q", "s"}``
+    pytree when ``spec.quantized``."""
     if spec.quantized:
-        def qzeros():
-            return {"q": jnp.zeros(spec.payload_shape, spec.payload_dtype),
-                    "s": jnp.zeros(spec.scale_shape, jnp.float32)}
-        if mesh is not None:
-            sh = {"q": NamedSharding(mesh, kv_cache_spec()),
-                  "s": NamedSharding(mesh, kv_scale_spec())}
-            qzeros = jax.jit(qzeros, out_shardings=sh)
-        return qzeros(), qzeros()
-    if mesh is not None:
-        sharding = NamedSharding(mesh, kv_cache_spec())
-        zeros = jax.jit(
-            lambda: jnp.zeros(spec.shape, jnp.dtype(spec.dtype)), out_shardings=sharding
-        )
-        return zeros(), zeros()
-    z = jnp.zeros(spec.shape, jnp.dtype(spec.dtype))
-    return z, jnp.zeros_like(z)
+        return {"q": jnp.zeros(spec.payload_shape, spec.payload_dtype),
+                "s": jnp.zeros(spec.scale_shape, jnp.float32)}
+    return jnp.zeros(spec.shape, jnp.dtype(spec.dtype))
+
+
+def cache_sharding(spec: KVCacheSpec, mesh: Mesh | None):
+    """Sharding of one cache on ``mesh``, shaped like the cache pytree
+    (payload and scales each on their own spec); None off-mesh."""
+    if mesh is None:
+        return None
+    payload = NamedSharding(mesh, kv_cache_spec())
+    if spec.quantized:
+        return {"q": payload, "s": NamedSharding(mesh, kv_scale_spec())}
+    return payload
+
+
+def allocate_cache(spec: KVCacheSpec, mesh: Mesh | None = None):
+    """Allocate zeroed K and V caches (sharded if a mesh is given: each
+    device then zeroes its own shard and never sees the whole)."""
+    if mesh is None:
+        return _zeros(spec), _zeros(spec)
+    zeros = jax.jit(partial(_zeros, spec),
+                    out_shardings=cache_sharding(spec, mesh))
+    return zeros(), zeros()
+
+
+def abstract_cache(spec: KVCacheSpec, mesh: Mesh | None = None):
+    """Shape, dtype and sharding of one cache with nothing allocated — what
+    the pool-sizing probe lowers the step program against
+    (engine.ModelRunner._probe_step_memory)."""
+    shapes = jax.eval_shape(partial(_zeros, spec))
+    sharding = cache_sharding(spec, mesh)
+    if sharding is None:
+        return shapes
+    return jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, sharding)
 
 
 def cache_payload(cache) -> jax.Array:

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import dataclasses
+import math
 import queue as thread_queue
 import threading
 import time
@@ -33,9 +35,12 @@ import numpy as np
 from jax import lax
 
 from dynamo_tpu import chaos
+from dynamo_tpu.engine import device
 from dynamo_tpu.engine.cache import (
     KVCacheSpec,
+    abstract_cache,
     allocate_cache,
+    cache_sharding,
     register_device_tier,
 )
 from dynamo_tpu.engine.prefix_pool import PrefixPool
@@ -206,7 +211,6 @@ class ModelRunner:
 
         self._repl = (NamedSharding(mesh, PartitionSpec())
                       if mesh is not None else None)
-        key = jax.random.key(rng_seed)
         if params is not None:
             self.params = params
         else:
@@ -237,16 +241,33 @@ class ModelRunner:
                         "%s has no *.safetensors weights: engine will serve "
                         "RANDOM weights (--allow-random-weights)",
                         engine_cfg.model)
-                self.params = llama.init_params(cfg, key)
+                if mesh is None:
+                    self.params = llama.init_params(
+                        cfg, jax.random.key(rng_seed))
+                else:
+                    # On a mesh, one jitted program with the parameter
+                    # shardings as its out_shardings: every device builds
+                    # its own shard and nothing else. Built eagerly, each
+                    # leaf appears whole — in float32 first — on the
+                    # default device, which for a model meant to be spread
+                    # over a mesh is more than that device holds. The seed
+                    # is closed over, so on a multi-host mesh every process
+                    # runs the same input-free program.
+                    from dynamo_tpu.parallel.mesh import param_shardings
+
+                    self.params = jax.jit(
+                        lambda: llama.init_params(
+                            cfg, jax.random.key(rng_seed)),
+                        out_shardings=param_shardings(
+                            llama.param_logical_axes(cfg), mesh))()
         if mesh is not None:
             # Explicitly place params per their logical-axis rules: on one
             # host this pins the TP/EP layout (instead of leaving GSPMD to
             # re-shard uncommitted arrays per bucket); on multi-host it is
             # mandatory — every process must contribute its shard of the
-            # global param arrays. Random init is seed-deterministic, so all
-            # processes hold identical host values to shard from. Leaves the
-            # loader already placed pass through untouched (global_put
-            # returns correctly-sharded arrays as-is).
+            # global param arrays. Leaves already placed (the loader's, the
+            # sharded init's) pass through untouched: global_put returns
+            # correctly-sharded arrays as-is.
             from dynamo_tpu.parallel.mesh import shard_params
 
             self.params = shard_params(
@@ -259,10 +280,11 @@ class ModelRunner:
             from dynamo_tpu.models.quant import quantize_params_int8
 
             self.params = quantize_params_int8(self.params, cfg)
-        num_blocks = engine_cfg.num_blocks or self._auto_num_blocks()
-        self.spec = KVCacheSpec.for_model(cfg, num_blocks, engine_cfg.block_size,
-                                          kv_dtype=engine_cfg.kv_dtype)
-        self.cache_k, self.cache_v = allocate_cache(self.spec, mesh)
+        from dynamo_tpu.ops.paged_attention import select_attn_impl
+
+        self.attn_impl = select_attn_impl(engine_cfg.attn_impl)
+        self.max_nblk = -(-engine_cfg.max_model_len // engine_cfg.block_size)
+        self._check_kernel_fits()
         maxb = engine_cfg.max_batch_size
         # Row maxb is the trash row: padding/non-sampling rows write their
         # sampling-state updates there so real slots are never clobbered by
@@ -275,22 +297,20 @@ class ModelRunner:
         # the pipelined (host/device-overlapped) step loop. Row maxb = trash.
         self.slot_toks = self._place(jnp.zeros((maxb + 1,), jnp.int32))
         self._step_fns: dict[tuple[int, int, int], Callable] = {}
-        self.max_nblk = -(-engine_cfg.max_model_len // engine_cfg.block_size)
         # Compile ledger (obs/compile_ledger.py): every cache miss below is
         # a trace+compile that blocks the engine-core thread; the ledger
         # times it, attributes the victim request, and feeds warmup
         # coverage. Disabled (warmup_mode=off) the gate is one bool read.
         self._ledger = get_compile_ledger()
-        from dynamo_tpu.ops.paged_attention import select_attn_impl
-
-        self.attn_impl = select_attn_impl(engine_cfg.attn_impl)
-        if (self.attn_impl in ("pallas", "pallas_interpret") and mesh is not None
-                and mesh.shape.get("model", 1) > 1
-                and cfg.num_kv_heads % mesh.shape["model"] != 0):
-            log.warning(
-                "num_kv_heads=%d does not divide tp=%d: pallas attention will "
-                "fall back to the dense gather path", cfg.num_kv_heads,
-                mesh.shape["model"])
+        # The pool comes last: everything else that lives on the device is
+        # resident by now, so what memory_stats() calls free really is.
+        self.spec = KVCacheSpec.for_model(
+            cfg, engine_cfg.num_blocks or 1, engine_cfg.block_size,
+            kv_dtype=engine_cfg.kv_dtype)
+        if not engine_cfg.num_blocks:
+            self.spec = dataclasses.replace(
+                self.spec, num_blocks=self._auto_num_blocks())
+        self.cache_k, self.cache_v = allocate_cache(self.spec, mesh)
         # Context-parallel ring prefill gate (ops/ring_attention.py promoted
         # to a serving mode): None = ring off (sp=1 mesh, or the knob set to
         # -1); otherwise the minimum prompt tokens before a fresh
@@ -332,24 +352,192 @@ class ModelRunner:
 
         return global_put(x, self._repl)
 
+    def _check_kernel_fits(self) -> None:
+        """Refuse at construction what the kernel cannot serve, instead of
+        at the first request that reaches it. On a TPU: a mesh the kernel
+        does not divide (the model code would otherwise swap in the dense
+        gather path, models/llama.py forward), and scalar-prefetch operands
+        — the block table, and a quantized pool's scale sidecars — that
+        exceed SMEM."""
+        if self.attn_impl != "pallas" or jax.default_backend() != "tpu":
+            return
+        from dynamo_tpu.ops.paged_attention import (
+            SMEM_USABLE_BYTES,
+            scalar_prefetch_bytes,
+        )
+
+        cfg, ec = self.cfg, self.engine_cfg
+        shape = self.mesh.shape if self.mesh is not None else {}
+        tp, dp = shape.get("model", 1), shape.get("data", 1)
+        rows = sorted({s.b for s in enumerate_buckets(ec)})
+        if tp > 1 and cfg.num_kv_heads % tp:
+            raise ValueError(
+                f"num_kv_heads={cfg.num_kv_heads} does not divide tp={tp}: "
+                "the paged-attention kernel cannot be sharded over this "
+                "mesh, and the dense gather path is not an acceptable "
+                "substitute on a TPU")
+        if tp > 1 and any(b % dp for b in rows):
+            raise ValueError(
+                f"batch buckets {[b for b in rows if b % dp]} do not divide "
+                f"dp={dp}: those steps would leave the paged-attention "
+                "kernel for the dense gather path")
+        quant = ec.kv_dtype in ("int8", "int4")
+        if quant and not ec.num_blocks:
+            raise ValueError(
+                f"kv_dtype={ec.kv_dtype} needs an explicit --num-blocks on a "
+                "TPU: its scale sidecars ride SMEM, which bounds the pool to "
+                "~1,000 blocks, far below what device memory would suggest")
+        table = scalar_prefetch_bytes(batch=rows[-1], nblk=self.max_nblk)
+        need = scalar_prefetch_bytes(
+            batch=rows[-1], nblk=self.max_nblk,
+            num_blocks=ec.num_blocks if quant else 0,
+            kv_heads=cfg.num_kv_heads // tp)
+        if need > SMEM_USABLE_BYTES:
+            raise ValueError(
+                f"the paged-attention kernel's scalar-prefetch operands need "
+                f"{need} B of SMEM and {SMEM_USABLE_BYTES} B are usable: the "
+                f"[{rows[-1]}, {self.max_nblk}] block table takes {table} B"
+                + (f" and the kv_dtype={ec.kv_dtype} scale sidecars of "
+                   f"{ec.num_blocks} blocks take {need - table} B (512 B a "
+                   "block for K and for V); pass a smaller --num-blocks or "
+                   "use kv_dtype=bfloat16" if quant else
+                   "; lower max_model_len or max_batch_size"))
+
+    #: Pool when auto-sizing on the CPU backend, which reports no memory
+    #: statistics: enough for the tests and local work it is there for.
+    _CPU_POOL_BLOCKS = 512
+    #: Share of a device's memory the sizing leaves alone: compiled programs
+    #: live there too (3–17 MB of code a bucket, hundreds of buckets in the
+    #: lattice), and the allocator needs slack for fragmentation.
+    _POOL_HEADROOM = 0.10
+
     def _auto_num_blocks(self) -> int:
-        """Size the device KV pool from free memory (TPU) or a small default."""
+        """Size the device KV pool from what the devices have free, so that
+        the widest step the server can reach still fits beside it."""
         ec = self.engine_cfg
-        try:
-            stats = jax.devices()[0].memory_stats() or {}
-            limit = stats.get("bytes_limit", 0)
-            in_use = stats.get("bytes_in_use", 0)
-            budget = int((limit - in_use) * 0.85)
-        except Exception:
-            budget = 0
-        spec = KVCacheSpec.for_model(self.cfg, 1, ec.block_size,
-                                     kv_dtype=ec.kv_dtype)
-        if budget > 0:
-            n = max(budget // spec.bytes_per_block(), 16)
-        else:
-            n = 512
         cap = (ec.max_model_len // ec.block_size) * ec.max_batch_size + 1
-        return int(min(n, cap))
+        if jax.default_backend() == "cpu":
+            return min(self._CPU_POOL_BLOCKS, cap)
+        devices = (self.mesh.local_devices if self.mesh is not None
+                   else jax.local_devices()[:1])
+        stats = [d.memory_stats() for d in devices]
+        if not all(st and "bytes_limit" in st for st in stats):
+            raise RuntimeError(
+                f"{devices[0].device_kind} reports no memory statistics; "
+                "pass --num-blocks explicitly")
+        free = min(st["bytes_limit"] - st["bytes_in_use"] for st in stats)
+        headroom = int(self._POOL_HEADROOM
+                       * min(st["bytes_limit"] for st in stats))
+        log.info("kv pool sizing: %.2f GB free on each device, %.2f GB kept "
+                 "back", free / 1e9, headroom / 1e9)
+        return min(self._fit_pool(free - headroom), cap)
+
+    def _fit_pool(self, budget: int) -> int:
+        """The largest pool with which the widest reachable step takes no
+        more than ``budget`` bytes of a device beyond what is resident.
+
+        A block costs more than its own bytes: the cache rides the layer
+        scan as xs→ys, so a step holds a second whole copy of K and V as a
+        temporary, next to activations that grow with the bucket. Neither
+        is assumed here. The widest bucket is lowered against an abstract
+        cache at two pool sizes and compiled; XLA's own buffer assignment
+        gives the bytes per block (the block plus its copies) and the bytes
+        that do not depend on the pool (activations), per device. A later
+        change to how the cache passes through the step changes the pool
+        with it."""
+        ec = self.engine_cfg
+        sig = self._widest_bucket()
+        t0 = time.perf_counter()
+        # Probe near where the answer lies: over its whole range the curve
+        # is not a line (small pools are assigned differently), close to
+        # the answer it is. With one copy a block the budget would hold
+        # ``bound`` blocks and the answer lies below that; a program that
+        # does not fit the device does not compile, so start from half the
+        # bound and a quarter, and halve again if even that is refused.
+        n1 = max(budget // (2 * self._block_bytes_per_device()) // 2,
+                 2 * self.max_nblk)
+        while True:
+            n0 = n1 // 2
+            try:
+                (peak0, extra0), (peak1, extra1) = (
+                    self._probe_step_memory(sig, n) for n in (n0, n1))
+                break
+            except jax.errors.JaxRuntimeError:
+                if n0 <= self.max_nblk:
+                    raise
+                log.info("kv pool sizing: a pool of %d blocks does not "
+                         "compile beside the widest step; halving", n1)
+                n1 = n0
+        per_block = (peak1 - peak0) / (n1 - n0)
+        copies = (extra1 - extra0) / (n1 - n0)
+        fixed = extra0 - copies * n0
+        n = int((budget - fixed) // per_block)
+        log.info(
+            "kv pool sizing: widest bucket B=%d T=%d NBLK=%d needs %.2f GB "
+            "beside the pool and %.0f B a block on each device (%.0f B the "
+            "block, %.0f B its copies inside the step) -> %d blocks in "
+            "%.2f GB, probed at %d and %d blocks in %.1fs",
+            sig.b, sig.t, sig.nblk, fixed / 1e9, per_block,
+            per_block - copies, copies, n, budget / 1e9, n0, n1,
+            time.perf_counter() - t0)
+        if n <= self.max_nblk:
+            raise RuntimeError(
+                f"no room for a KV pool that holds one {ec.max_model_len}-"
+                f"token sequence ({self.max_nblk + 1} blocks) in "
+                f"{budget / 1e9:.2f} GB: the widest step (B={sig.b} "
+                f"T={sig.t}) needs {fixed / 1e9:.2f} GB beside the pool and "
+                f"{per_block:.0f} B a block; lower max_batch_size, "
+                "prefill_chunk or max_model_len")
+        return n
+
+    def _widest_bucket(self) -> BucketSig:
+        """The reachable bucket with the most padded tokens (then the
+        widest block table): the step whose activations are largest."""
+        return max(enumerate_buckets(self.engine_cfg),
+                   key=lambda s: (s.b * s.t, s.nblk))
+
+    def _block_bytes_per_device(self) -> int:
+        """One block's bytes on each device (K and V), by the cache's own
+        sharding."""
+        one = abstract_cache(
+            dataclasses.replace(self.spec, num_blocks=1), self.mesh)
+        return 2 * sum(
+            math.prod(leaf.shape if self.mesh is None
+                      else leaf.sharding.shard_shape(leaf.shape))
+            * leaf.dtype.itemsize for leaf in jax.tree.leaves(one))
+
+    def _probe_step_memory(self, sig: BucketSig,
+                           num_blocks: int) -> tuple[int, int]:
+        """Per-device (peak bytes, bytes beyond the arguments) of the step
+        program for ``sig`` over a pool of ``num_blocks``, from XLA's buffer
+        assignment. Nothing is allocated: the cache is abstract. The greedy
+        variant stands for both (measured ahead-of-time: the sampling
+        variant's temporaries are no larger, and it compiles 5x slower)."""
+        cache = abstract_cache(
+            dataclasses.replace(self.spec, num_blocks=num_blocks), self.mesh)
+        fn = self._build_step_fn(sig.b, sig.t, sig.nblk, fast_greedy=True)
+        mem = fn.lower(
+            self.params, cache, cache, self.counts, self.keys, self.slot_toks,
+            *self._padding_inputs(sig.b, sig.t, sig.nblk),
+        ).compile().memory_analysis()
+        extra = (mem.temp_size_in_bytes + mem.output_size_in_bytes
+                 - mem.alias_size_in_bytes)
+        return mem.argument_size_in_bytes + extra, extra
+
+    def _padding_inputs(self, b: int, t: int, nblk: int) -> tuple:
+        """The per-step inputs of a (b, t, nblk) step, all padding: q_len=0
+        rows compute nothing meaningful and do_sample=False routes
+        sampling-state writes to the trash row."""
+        place = self._place
+        zi = np.zeros((b,), np.int32)
+        zf = np.zeros((b,), np.float32)
+        ones = np.ones((b,), np.float32)
+        zb = np.zeros((b,), bool)
+        return (place(np.zeros((b, t), np.int32)), place(zi), place(zi),
+                place(np.zeros((b, nblk), np.int32)), place(zi),
+                place(zf), place(zi), place(ones),
+                place(zf), place(zf), place(ones),
+                place(zb), place(zb))
 
     # ------------------------------------------------------------------
     def _build_step_fn(self, b: int, t: int, nblk: int, sp_prefill: bool = False,
@@ -424,25 +612,15 @@ class ModelRunner:
         the next dispatch feeds them straight back without resharding."""
         if self.mesh is None:
             return {}
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from dynamo_tpu.parallel.mesh import kv_cache_spec, kv_scale_spec
-
-        repl = NamedSharding(self.mesh, P())
-        cache = NamedSharding(self.mesh, kv_cache_spec())
-        if self.spec.quantized:
-            # Quantized caches are {"q","s"} pytrees; shard each leaf.
-            cache = {"q": cache, "s": NamedSharding(self.mesh, kv_scale_spec())}
+        repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
         return {"out_shardings": (cache, cache, repl, repl, repl, repl, repl)}
 
     def _build_window_fn(self, b: int, nblk: int, w: int,
                          fast_greedy: bool = False):
         """Fused decode window: ``w`` single-token steps in ONE compiled
         dispatch, `lax.scan`-sequenced on device with each step's sampled
-        token feeding the next — zero host round trips inside the window.
-        This is the TPU answer to per-token dispatch latency (the reference's
-        engines decode step-by-step because their scheduler lives next to
-        the GPU; ours may sit a network tunnel away from the chip). Stop
+        token feeding the next — zero host round trips inside the window,
+        one dispatch amortised over ``w`` tokens. Stop
         conditions lag ≤ w-1 tokens; finalize discards overrun, so emitted
         streams are bit-identical to w=1 (tests/test_engine.py windowed
         equivalence tests)."""
@@ -761,15 +939,7 @@ class ModelRunner:
 
         kw = {}
         if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from dynamo_tpu.parallel.mesh import kv_cache_spec, kv_scale_spec
-
-            repl = NamedSharding(self.mesh, P())
-            cache = NamedSharding(self.mesh, kv_cache_spec())
-            if self.spec.quantized:
-                cache = {"q": cache,
-                         "s": NamedSharding(self.mesh, kv_scale_spec())}
+            repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
             kw["out_shardings"] = (cache, cache, repl, repl)
         return jax.jit(verify, donate_argnums=(1, 2), **kw)
 
@@ -966,19 +1136,10 @@ class ModelRunner:
                 return True
             fn = self.step_fn(b, t, nblk, False, window, sig.greedy,
                               False, False)
-            zi = np.zeros((b,), np.int32)
-            zf = np.zeros((b,), np.float32)
-            ones = np.ones((b,), np.float32)
-            zb = np.zeros((b,), bool)
             (self.cache_k, self.cache_v, self.counts, self.keys,
              self.slot_toks, toks, _lps) = fn(
                 self.params, self.cache_k, self.cache_v, self.counts,
-                self.keys, self.slot_toks,
-                place(np.zeros((b, t), np.int32)), place(zi), place(zi),
-                place(np.zeros((b, nblk), np.int32)), place(zi),
-                place(zf), place(zi), place(ones),
-                place(zf), place(zf), place(ones),
-                place(zb), place(zb))
+                self.keys, self.slot_toks, *self._padding_inputs(b, t, nblk))
             np.asarray(toks)
         self._ledger.record(sig, time.perf_counter() - t0, source="warmup")
         return False
@@ -994,6 +1155,10 @@ class EngineCore:
         params=None,
         event_sink: Callable[[KvCacheEvent], None] | None = None,
     ):
+        # Before the first jit on every path that builds an engine.
+        device.require_backend(jax.default_backend(),
+                               jax.config.jax_platforms)
+        compile_cache_dir = device.configure_compile_cache()
         if engine_cfg.sp > 1 and engine_cfg.ring_prefill_threshold >= 0 and (
             engine_cfg.prefill_chunk < engine_cfg.max_model_len
             or engine_cfg.max_tokens_per_step < engine_cfg.max_model_len
@@ -1004,12 +1169,11 @@ class EngineCore:
             # scheduler's per-step token budget — would push later chunks
             # (start != 0) onto the dense path and waste the sp axis. Copy
             # the config rather than mutating the caller's.
-            import dataclasses as _dc
             log.info(
                 "sp=%d: raising prefill_chunk %d and max_tokens_per_step %d -> "
                 "max_model_len %d", engine_cfg.sp, engine_cfg.prefill_chunk,
                 engine_cfg.max_tokens_per_step, engine_cfg.max_model_len)
-            engine_cfg = _dc.replace(
+            engine_cfg = dataclasses.replace(
                 engine_cfg,
                 prefill_chunk=max(engine_cfg.prefill_chunk, engine_cfg.max_model_len),
                 max_tokens_per_step=max(engine_cfg.max_tokens_per_step,
@@ -1079,7 +1243,6 @@ class EngineCore:
         from dynamo_tpu.obs import costmodel as cm
         self._hw = cm.hw_spec_for(jax.devices()[0].device_kind)
         if engine_cfg.prefill_chunk <= 0:
-            import dataclasses as _dc
             ladder_cap = min(engine_cfg.max_model_len,
                              engine_cfg.max_tokens_per_step)
             self.chunk_by_qos = {
@@ -1098,7 +1261,7 @@ class EngineCore:
             resolved = max(self.chunk_by_qos.values())
             log.info("auto prefill chunk (itl_slo=%.1fms): %s -> cap %d",
                      engine_cfg.itl_slo_ms, self.chunk_by_qos, resolved)
-            engine_cfg = _dc.replace(engine_cfg, prefill_chunk=resolved)
+            engine_cfg = dataclasses.replace(engine_cfg, prefill_chunk=resolved)
             self.engine_cfg = engine_cfg
         else:
             self.chunk_by_qos = {qos: engine_cfg.prefill_chunk
@@ -1115,6 +1278,18 @@ class EngineCore:
                                         ep=engine_cfg.ep))
         self.runner = ModelRunner(self.model_cfg, engine_cfg, mesh=mesh, params=params,
                                   rng_seed=engine_cfg.seed)
+        # What is running, said once here and again in stats(): the smoke
+        # and the benchmark read it instead of touching JAX themselves.
+        spec = self.runner.spec
+        self.device_info = device.describe(
+            self.runner.mesh, attn_impl=self.runner.attn_impl,
+            pool_blocks=spec.num_blocks,
+            pool_bytes=spec.num_blocks * spec.bytes_per_block(),
+            compile_cache_dir=compile_cache_dir)
+        shown = dict(self.device_info, mesh=",".join(
+            f"{a}={n}" for a, n in self.device_info["mesh"].items()) or "none")
+        log.info("engine on %s",
+                 " ".join(f"{k}={v}" for k, v in shown.items()))
         if engine_cfg.warmup_mode != "off":
             # Publish the reachable lattice so coverage is meaningful even
             # before (or without) a full warmup — a lazy engine's coverage
@@ -2691,6 +2866,10 @@ class AsyncJaxEngine:
         # locally, so their engine state machines replay identically.
         self._op_sink = op_sink
         self._channel_down = False
+        # Set when the device or the compiler refused a step: the loop has
+        # stopped, generate() answers with this error, and the launcher
+        # (launch/run.py) exits non-zero on it.
+        self.fatal: Exception | None = None
         self._loop: asyncio.AbstractEventLoop | None = None
         self._inbox: thread_queue.Queue = thread_queue.Queue()
         self._streams: dict[str, asyncio.Queue] = {}
@@ -2887,8 +3066,19 @@ class AsyncJaxEngine:
                         self._emit_op({"op": "fail_all", "error": str(exc)})
                     except OpChannelDown:
                         pass
+                if isinstance(exc, jax.errors.JaxRuntimeError):
+                    # Out of device memory, a kernel that does not lower: the
+                    # next request to reach this bucket fails the same way.
+                    # Stop, rather than answer every request with an error
+                    # from a process that looks healthy and exits 0. Set
+                    # before the streams are told, so that generate() either
+                    # is among them or sees it.
+                    log.error("device error is fatal: engine stopped serving")
+                    self.fatal = exc
                 for rid in list(self._streams):
                     self._post(rid, LLMEngineOutput(finish_reason=FinishReason.ERROR, error=str(exc)))
+                if self.fatal is not None:
+                    break
                 continue
 
     @staticmethod
@@ -2931,6 +3121,10 @@ class AsyncJaxEngine:
         self.start()
         q: asyncio.Queue = asyncio.Queue()
         self._streams[req.request_id] = q
+        if self.fatal is not None:
+            q.put_nowait(LLMEngineOutput(
+                finish_reason=FinishReason.ERROR,
+                error=f"engine stopped: {self.fatal}"))
         self._inbox.put(("add", req))
         self._wake.set()
         out: LLMEngineOutput | None = None
@@ -2972,6 +3166,7 @@ class AsyncJaxEngine:
 
     def stats(self) -> dict:
         out = self.core.metrics.snapshot(self.core.sched, self.core.pool)
+        out["device"] = self.core.device_info
         if self.core.kvbm is not None:
             out["kvbm"] = self.core.kvbm.snapshot()
             if self.core.kvbm.ckpt_tier is not None:

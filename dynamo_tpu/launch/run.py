@@ -17,6 +17,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import signal
 import sys
 
 from dynamo_tpu.engine.engine import AsyncJaxEngine, EngineCore
@@ -214,11 +215,19 @@ async def run_http(ns: argparse.Namespace) -> None:
         install_compile_metrics(svc.metrics)
     await svc.start(ns.host, ns.port)
     log.info("serving %s on http://%s:%d/v1", ns.model, ns.host, svc.port)
+    # SIGTERM/SIGINT end the server with exit code 0; an engine that a
+    # device error stopped (AsyncJaxEngine.fatal) ends it non-zero.
+    stop = asyncio.Event()
+    for sig in (signal.SIGTERM, signal.SIGINT):
+        asyncio.get_running_loop().add_signal_handler(sig, stop.set)
     try:
-        await asyncio.Event().wait()
+        while engine.fatal is None and not stop.is_set():
+            await asyncio.sleep(0.2)
     finally:
         await svc.stop()
         await engine.shutdown()
+    if engine.fatal is not None:
+        raise SystemExit(f"engine stopped on a device error: {engine.fatal}")
 
 
 async def run_text(ns: argparse.Namespace) -> None:

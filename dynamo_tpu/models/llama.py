@@ -31,7 +31,6 @@ from jax import lax
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.obs.profiler import phase as _perf_phase
-from dynamo_tpu.parallel.mesh import shard_map_compat
 from dynamo_tpu.utils.logging import get_logger
 
 log = get_logger("models.llama")
@@ -388,8 +387,15 @@ def forward(
         # path, partitioned by GSPMD. Trace-time decision — tracing happens
         # once per (batch, chunk) bucket, so this logs once per bucket that
         # actually serves the slow path rather than silently degrading.
+        # CPU meshes only: on a TPU the engine refuses such a mesh at
+        # construction (ModelRunner), and this raise is its backstop.
         reason = (f"num_kv_heads={cfg.num_kv_heads} mod tp={tp}"
                   if cfg.num_kv_heads % tp != 0 else f"batch={b} mod dp={dp}")
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                f"paged-attention kernel cannot serve bucket (b={b}, t={t}): "
+                f"{reason} does not divide, and the dense gather path is "
+                "not an acceptable substitute on a TPU")
         log.warning(
             "paged-attention kernel disabled for bucket (b=%d, t=%d): %s does "
             "not divide; serving the dense gather path", b, t, reason)
@@ -622,7 +628,7 @@ def forward_pp(
         # Only the last stage accumulated into `out`; the psum replicates it.
         return lax.psum(out, "pipe"), ck_loc, cv_loc
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         pp_fn, mesh=mesh,
         in_specs=(P("pipe"), P("pipe"), P("pipe"), P(), P(), P(), P(), P(), P()),
         out_specs=(P(), P("pipe"), P("pipe")),
@@ -705,7 +711,7 @@ def _forward_pp_sequential(params, cfg, positions, kv_lens, slot, block_tables,
             h = lax.psum(jnp.where(keep, h_out, jnp.zeros_like(h_out)), "pipe")
         return h, ck_local, cv_local
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         pp_fn, mesh=mesh,
         in_specs=(P("pipe"), P("pipe"), P("pipe"), P()),
         out_specs=(P(), P("pipe"), P("pipe")),

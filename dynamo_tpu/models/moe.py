@@ -31,7 +31,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.parallel.mesh import shard_map_compat
 
 Params = dict
 
@@ -141,7 +140,7 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
     if shared is not None:
         args.extend(shared)
         in_specs.extend([P(None, "model"), P(None, "model"), P("model", None)])
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=tuple(in_specs), out_specs=batch_spec,
         check_vma=False,

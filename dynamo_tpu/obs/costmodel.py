@@ -91,10 +91,10 @@ class HardwareSpec:
 
 
 # Keyed by a lowercase substring of jax's ``device_kind``; first match wins
-# (dict order), so the more specific "tpu v5p" precedes "tpu v5". The HBM
-# numbers intentionally match bench.py's historical roofline table. The CPU
-# entry is a deliberately rough stand-in for the fallback bench — a few
-# AVX cores and one DDR channel-ish.
+# (dict order), so the more specific "tpu v5p" precedes "tpu v5" (a v5e
+# reports "TPU v5 lite"). TPU entries are the published per-chip peaks
+# (Google Cloud TPU documentation). The CPU entry is a deliberately rough
+# stand-in for CPU test runs — a few AVX cores and one DDR channel-ish.
 HW_SPECS: dict[str, HardwareSpec] = {
     "tpu v6": HardwareSpec("tpu-v6e", 918e12, 1638e9),
     "tpu v5p": HardwareSpec("tpu-v5p", 459e12, 2765e9),
@@ -105,13 +105,18 @@ HW_SPECS: dict[str, HardwareSpec] = {
 
 
 def hw_spec_for(device_kind: str) -> HardwareSpec:
-    """Resolve a jax ``device_kind`` string (e.g. "TPU v5 lite") to a spec;
-    unknown kinds fall back to the conservative CPU entry."""
-    kind = (device_kind or "cpu").lower()
+    """Resolve a jax ``device_kind`` string (e.g. "TPU v5 lite") to a spec.
+    A device that is not in the table is an error, not a default: the spec
+    feeds live decisions (chunk sizing, split-K, the ring threshold) and a
+    utilization computed against another device's peaks is a wrong number."""
+    kind = device_kind.lower()
     for key, spec in HW_SPECS.items():
         if key in kind:
             return spec
-    return HW_SPECS["cpu"]
+    raise ValueError(
+        f"no hardware spec for device_kind {device_kind!r} (known: "
+        f"{', '.join(HW_SPECS)}); add its published peaks to "
+        "obs/costmodel.py HW_SPECS")
 
 
 @dataclass(frozen=True)

@@ -275,8 +275,6 @@ class StepPerfProfiler:
 
 
 def _detect_device_kind() -> str:
-    try:
-        import jax
-        return getattr(jax.devices()[0], "device_kind", "cpu")
-    except Exception:  # pragma: no cover - no runtime available
-        return "cpu"
+    import jax
+
+    return jax.devices()[0].device_kind

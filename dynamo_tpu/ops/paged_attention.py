@@ -10,8 +10,9 @@ a running online softmax. No gathered context tensor ever exists.
 
 Works for both prefill chunks (T>1 query tokens) and decode (T=1) with the
 same causal position masking as the dense path. Numerical equivalence is
-tested in tests/test_ops.py (interpret mode); bench.py exercises TPU
-lowering on hardware and reports which attention impl actually ran.
+tested in tests/test_ops.py (interpret mode), which also compiles the kernel
+ahead-of-time for the v5e with the installed libtpu; chip_smoke.py runs it on
+the chip through the server.
 
 Design notes (reference has no TPU analog; its one kernel is a CUDA block
 copy, lib/llm/src/kernels/block_copy.cu — paged attention itself lives
@@ -37,10 +38,9 @@ inside vLLM/TRT-LLM, which we replace):
   the DMA pipeline chases the page table, the kernel body never sees HBM.
 - K/V blocks load ALL kv heads at once — block shape ``(1, BS, KH, Dp)``
   equals the array's trailing dims, which always satisfies Mosaic's tiling
-  constraint (the round-1 kernel's per-head block ``(1, BS, 1, D)`` had a
-  second-to-minor dim of 1 against KH=8 and failed to lower). The kv-head
-  loop is a static Python loop inside the kernel: KH small 2D matmuls on
-  the MXU per block.
+  constraint (a per-head block ``(1, BS, 1, D)`` has a second-to-minor dim
+  of 1 against KH=8 and does not lower). The kv-head loop is a static
+  Python loop inside the kernel: KH small 2D matmuls on the MXU per block.
 - q rows are pre-laid-out ``[B, KH, T*REP, D]`` (rep = query heads per kv
   head) outside the kernel so each head's queries are one contiguous 2D
   slab — one MXU matmul covers all query heads of the kv head.
@@ -62,13 +62,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.parallel.mesh import shard_map_compat
-
 NEG_INF = -1e30
 _SCRATCH_CAP_BYTES = 4 * 2**20  # online-softmax VMEM scratch budget
-
-# jax renamed TPUCompilerParams → CompilerParams; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 # Mosaic min-tile sublane count by dtype itemsize (lane is always 128):
 # f32 → (8, 128), bf16 → (16, 128), int8/uint8/fp8 → (32, 128).
@@ -83,44 +78,26 @@ def _sublane(dtype) -> int:
     return _MIN_SUBLANE.get(jnp.dtype(dtype).itemsize, 8)
 
 
-def mosaic_block_shape_ok(block_shape: tuple[int, ...],
-                          array_shape: tuple[int, ...], dtype) -> bool:
-    """Mosaic's 2D tiling rule for a BlockSpec: each of the last two block
-    dims must either equal the array's dim (whole-axis block) or be a
-    multiple of the dtype's min tile (sublane × 128). The round-1 bench
-    failure was exactly this: a per-head block ``(1, 16, 1, 128)`` against
-    a ``[NB, BS, KH, D]`` cache put 1 in the second-to-minor position where
-    KH was 8 — neither equal nor divisible — and the kernel refused to
-    lower on TPU (BENCH_r01.json). Packed-int4 caches keep the whole-axis
-    property (their trailing dim is D/2 on both block and array), so they
-    pass the same rule."""
-    if len(block_shape) < 2 or len(array_shape) < 2:
-        return True
-    sub, lane = block_shape[-2], block_shape[-1]
-    asub, alane = array_shape[-2], array_shape[-1]
-    sub_ok = sub == asub or sub % _sublane(dtype) == 0
-    lane_ok = lane == alane or lane % 128 == 0
-    return sub_ok and lane_ok
+#: Scalar memory the kernel's prefetched operands may take on a TPU v5e:
+#: the 1 MiB the compiler reports ("Used 1.00M of 1.00M smem") less 16 KiB
+#: for what it keeps for itself (spill slots, ~3 KiB seen). Checked against
+#: the compiler itself by tests/test_ops.py's ahead-of-time cases.
+SMEM_USABLE_BYTES = (1 << 20) - (16 << 10)
 
 
-def _validate_block_specs(specs: list[tuple[str, tuple[int, ...],
-                                            tuple[int, ...], "jnp.dtype"]]) -> None:
-    """Static trace-time guard: fail with a readable error instead of a
-    deep Mosaic lowering failure on hardware. ``specs`` is a list of
-    (name, block_shape, array_shape, dtype). Covers the q/kv/out blocks AND
-    the split-K partial-state outputs (acc/m/l, float32) plus packed-int4
-    payload blocks."""
-    bad = [
-        f"{name}: block {blk} vs array {arr} ({jnp.dtype(dt).name}: "
-        f"min tile {_sublane(dt)}x128)"
-        for name, blk, arr, dt in specs
-        if not mosaic_block_shape_ok(blk, arr, dt)
-    ]
-    if bad:
-        raise ValueError(
-            "paged-attention BlockSpec violates the TPU tiling rule (last "
-            "two block dims must equal the array dims or be multiples of "
-            "the dtype's min tile): " + "; ".join(bad))
+def scalar_prefetch_bytes(*, batch: int, nblk: int, num_blocks: int = 0,
+                          kv_heads: int = 0) -> int:
+    """SMEM bytes of the kernel's scalar-prefetch operands. Each row of a
+    2-D operand pads to whole 128-lane words (512 B): the ``[B, NBLK]``
+    block table, three ``[B]`` vectors and, for a quantized cache
+    (``num_blocks`` and ``kv_heads`` given), the two ``[NB, KH]`` float32
+    scale sidecars — which is what bounds a quantized pool to ~1,000
+    blocks, and the block table to ~4k blocks a row at 64 rows."""
+    def row(n: int) -> int:
+        return -(-n // 128) * 512
+
+    return (batch * row(nblk) + 3 * row(batch)
+            + 2 * num_blocks * row(kv_heads))
 
 
 # ---------------------------------------------------------------------------
@@ -405,17 +382,7 @@ def paged_attention_kernel(
     if quant:
         scalars = scalars + (k_scale, v_scale)
 
-    check_specs = [
-        ("q", (1, kh, rchunk, d), qs.shape, qs.dtype),
-        ("k_cache", (1, bs, kh, dp), k_cache.shape, k_cache.dtype),
-        ("v_cache", (1, bs, kh, dp), v_cache.shape, v_cache.dtype),
-    ]
     if split:
-        check_specs += [
-            ("out_acc", (1, 1, kh, rchunk, d), (b, ns, kh, r, d), jnp.float32),
-            ("out_m", (1, 1, kh, rchunk, 128), (b, ns, kh, r, 128), jnp.float32),
-            ("out_l", (1, 1, kh, rchunk, 128), (b, ns, kh, r, 128), jnp.float32),
-        ]
         out_shape = (
             jax.ShapeDtypeStruct((b, ns, kh, r, d), jnp.float32),
             jax.ShapeDtypeStruct((b, ns, kh, r, 128), jnp.float32),
@@ -427,10 +394,8 @@ def paged_attention_kernel(
             pl.BlockSpec((1, 1, kh, rchunk, 128), omap_split),
         )
     else:
-        check_specs.append(("out", (1, kh, rchunk, d), (b, kh, r, d), q.dtype))
         out_shape = jax.ShapeDtypeStruct((b, kh, r, d), q.dtype)
         out_specs = pl.BlockSpec((1, kh, rchunk, d), qmap)
-    _validate_block_specs(check_specs)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
@@ -452,7 +417,7 @@ def paged_attention_kernel(
                           quant=quant, int4=int4, split=split),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -489,7 +454,7 @@ def paged_attention_sharded(
         # their matching head axis — each shard dequantizes its own heads.
         # Packed-int4 payloads shard identically (packing is along D).
         cache_spec = {"q": P(None, None, "model", None), "s": P(None, "model")}
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(paged_attention_kernel, num_splits=num_splits,
                           interpret=interpret),
         mesh=mesh,
@@ -512,8 +477,15 @@ def select_attn_impl(requested: str = "auto") -> str:
     """Resolve the attention implementation name.
 
     "auto" → "pallas" on TPU, "dense" elsewhere. TP-sharded meshes use the
-    shard_map-wrapped kernel (paged_attention_sharded).
+    shard_map-wrapped kernel (paged_attention_sharded). The interpreter is
+    for CPU tests: on a TPU it would run the kernel's Python body on the
+    host and call the result a kernel run, so it is refused there.
     """
-    if requested != "auto":
-        return requested
-    return "pallas" if jax.default_backend() == "tpu" else "dense"
+    on_tpu = jax.default_backend() == "tpu"
+    if requested == "auto":
+        return "pallas" if on_tpu else "dense"
+    if requested == "pallas_interpret" and on_tpu:
+        raise ValueError(
+            "attn_impl='pallas_interpret' is the CPU test path; on a TPU "
+            "backend use 'pallas' (or 'auto')")
+    return requested

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.parallel.mesh import shard_map_compat
-
 NEG_INF = -1e30
 
 
@@ -67,10 +65,7 @@ def ring_attention(
     """Causal ring attention over ``axis_name``. Call inside shard_map/pjit
     with q/k/v sharded on the sequence dimension. Returns [B, T_local, H, D].
     """
-    # lax.axis_size is missing on older jax; psum of the literal 1 constant-
-    # folds to the static axis size on every version.
-    n = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-         else lax.psum(1, axis_name))
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     kh = k.shape[2]
@@ -124,7 +119,7 @@ def ring_attention_prefill(
     spec = P("data", "seq", "model", None)
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec, P("data")),
         out_specs=spec, check_vma=False,
     )
@@ -144,7 +139,7 @@ def ring_attention_sharded(mesh: Mesh, *, axis_name: str = "seq") -> Callable:
     spec = P(None, axis_name, None, None)
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec, P(None)),
         out_specs=spec, check_vma=False,
     )

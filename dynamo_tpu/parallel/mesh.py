@@ -85,19 +85,6 @@ def kv_cache_spec() -> P:
     return P("pipe", None, None, "model", None)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: older releases only ship
-    ``jax.experimental.shard_map.shard_map`` and spell the replication-check
-    knob ``check_rep`` instead of ``check_vma``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def kv_scale_spec() -> P:
     """Per-(layer, block, kv_head) dequant scales [layers, blocks, kv_heads]
     for the int8 KV cache — sharded exactly like the payload's corresponding
@@ -136,6 +123,19 @@ def global_put(x, sharding: NamedSharding):
     return jax.make_array_from_callback(arr.shape, sharding, lambda idx: arr[idx])
 
 
+def _is_axes(x) -> bool:
+    """A leaf of a logical-axes tree: a tuple of axis names / None."""
+    return isinstance(x, tuple) and all(
+        isinstance(a, (str, type(None))) for a in x)
+
+
+def param_shardings(logical_axes, mesh: Mesh):
+    """The NamedSharding of every parameter leaf, as a tree shaped like the
+    params: ``out_shardings`` for an init that builds each shard in place."""
+    return jax.tree.map(lambda axes: param_sharding_rules(mesh, axes),
+                        logical_axes, is_leaf=_is_axes)
+
+
 def shard_params(params, logical_axes, mesh: Mesh):
     """Place a params pytree on the mesh per its logical-axis annotations.
 
@@ -143,13 +143,10 @@ def shard_params(params, logical_axes, mesh: Mesh):
     names (models.llama.param_logical_axes). GSPMD then propagates these
     shardings through the jitted step and inserts the TP/EP collectives.
     """
-    import jax
-
     def place(leaf, axes):
         return global_put(leaf, param_sharding_rules(mesh, axes))
 
-    return jax.tree.map(place, params, logical_axes, is_leaf=lambda x: isinstance(x, tuple) and all(
-        isinstance(a, (str, type(None))) for a in x))
+    return jax.tree.map(place, params, logical_axes, is_leaf=_is_axes)
 
 
 def single_device_mesh() -> Mesh:

@@ -176,8 +176,9 @@ class EngineConfig:
     attn_num_splits: int = 0
     # Fused decode window: run up to this many decode steps inside ONE
     # compiled dispatch (lax.scan on device, sampled tokens feeding back
-    # without touching the host). Amortizes the per-dispatch host round
-    # trip — the dominant decode cost when the host is far from the chip.
+    # without touching the host), amortizing the per-dispatch host cost
+    # over the window. Whether that wins on a host next to its chip is
+    # unmeasured (ROADMAP.md D1); > 1 also turns the unified step off.
     # Stop conditions lag by at most window-1 tokens; overrun is discarded
     # at finalize, so emitted streams are bit-identical to window=1.
     decode_window: int = 1

@@ -528,3 +528,50 @@ def test_auto_prefill_chunk_engine_init():
     assert all(c & (c - 1) == 0 for c in core.chunk_by_qos.values())
     out, fin = run_to_completion(core, [make_req(rid="auto")])
     assert len(out["auto"]) == 8 and "auto" in fin
+
+
+async def test_step_failure_carries_on_but_device_error_stops(monkeypatch):
+    """The step loop's catch-all fails the in-flight requests and keeps
+    serving — unless the device or the compiler refused the step
+    (JaxRuntimeError: out of memory, a kernel that does not lower), which
+    the next request would meet again: then the engine stops, says so in
+    ``fatal``, and answers every later request with the error."""
+    import jax
+
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+
+    engine = AsyncJaxEngine(EngineCore(tiny_config()))
+    errors = iter([RuntimeError("transient"),
+                   jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: hbm")])
+
+    def refuse():
+        raise next(errors)
+
+    monkeypatch.setattr(engine.core, "step_begin", refuse)
+
+    async def outcome(rid):
+        outs = [o async for o in engine.generate(make_req(rid=rid))]
+        return outs[-1].finish_reason, outs[-1].error
+
+    assert await outcome("a") == (FinishReason.ERROR, "transient")
+    assert engine.fatal is None and engine._thread.is_alive()
+    reason, error = await outcome("b")
+    assert reason is FinishReason.ERROR and "RESOURCE_EXHAUSTED" in error
+    assert isinstance(engine.fatal, jax.errors.JaxRuntimeError)
+    reason, error = await outcome("c")
+    assert reason is FinishReason.ERROR and "engine stopped" in error
+    await engine.shutdown()
+    assert not engine._thread.is_alive()
+
+
+def test_fit_pool_measures_the_step():
+    """Auto pool sizing (a TPU path: the CPU backend reports no memory)
+    takes the step's memory from XLA's buffer assignment, not from an
+    assumption: at the pool it returns, the widest step's temporaries plus
+    the pool itself fill the budget and do not exceed it."""
+    runner = EngineCore(tiny_config(max_model_len=64)).runner
+    budget = 32 << 20
+    n = runner._fit_pool(budget)
+    _, beyond_args = runner._probe_step_memory(runner._widest_bucket(), n)
+    need = beyond_args + n * runner._block_bytes_per_device()
+    assert 0.95 * budget < need <= budget

@@ -16,10 +16,9 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine.cache import KVCacheSpec, allocate_cache
-from dynamo_tpu.engine.engine import EngineCore, ModelRunner
+from dynamo_tpu.engine.engine import EngineCore
 from dynamo_tpu.models.config import resolve_model_config
 from dynamo_tpu.tokens import compute_block_hashes_for_tokens
-from dynamo_tpu.utils.config import EngineConfig
 
 from tests.test_engine import make_req, run_to_completion, tiny_config
 
@@ -37,30 +36,6 @@ def test_bytes_per_block_near_halves_for_8b():
     assert ratio <= 0.55, f"int8 block is {ratio:.3f}x bf16, want <= 0.55"
     assert int8.quantized and not bf16.quantized
     assert int8.scale_shape == (cfg.num_layers, 1, cfg.num_kv_heads)
-
-
-def test_auto_num_blocks_reflects_halved_blocks(monkeypatch):
-    """With a fixed memory budget, int8 auto-sizing must fit ~2x the
-    blocks (1/ratio more, modulo flooring)."""
-
-    class FakeDev:
-        def memory_stats(self):
-            return {"bytes_limit": 1 << 30, "bytes_in_use": 0}
-
-    monkeypatch.setattr(jax, "devices", lambda: [FakeDev()])
-    cfg = resolve_model_config("llama-3-8b-lite")
-
-    def auto(kv_dtype):
-        r = ModelRunner.__new__(ModelRunner)
-        r.cfg = cfg
-        r.engine_cfg = EngineConfig(
-            model="llama-3-8b-lite", block_size=16,
-            max_model_len=1 << 20, max_batch_size=1 << 10,  # cap far away
-            kv_dtype=kv_dtype)
-        return r._auto_num_blocks()
-
-    n_bf16, n_int8 = auto("bfloat16"), auto("int8")
-    assert n_int8 >= int(1.9 * n_bf16), (n_bf16, n_int8)
 
 
 # -- scatter/gather round-trip (model write/read path) -----------------------
@@ -295,30 +270,6 @@ def test_bytes_per_block_int4_near_quarters():
     assert int4.payload_dtype == jnp.uint8
     assert int4.payload_head_dim == cfg.head_dim // 2
     assert int4.scale_shape == (cfg.num_layers, 1, cfg.num_kv_heads)
-
-
-def test_auto_num_blocks_int4_fits_4x(monkeypatch):
-    """Equal HBM budget fits ~4x the blocks vs bf16 (modulo the per-block
-    scale overhead and flooring)."""
-
-    class FakeDev:
-        def memory_stats(self):
-            return {"bytes_limit": 1 << 30, "bytes_in_use": 0}
-
-    monkeypatch.setattr(jax, "devices", lambda: [FakeDev()])
-    cfg = resolve_model_config("llama-3-8b-lite")
-
-    def auto(kv_dtype):
-        r = ModelRunner.__new__(ModelRunner)
-        r.cfg = cfg
-        r.engine_cfg = EngineConfig(
-            model="llama-3-8b-lite", block_size=16,
-            max_model_len=1 << 20, max_batch_size=1 << 10,
-            kv_dtype=kv_dtype)
-        return r._auto_num_blocks()
-
-    n_bf16, n_int4 = auto("bfloat16"), auto("int4")
-    assert n_int4 >= int(3.8 * n_bf16), (n_bf16, n_int4)
 
 
 def test_int4_odd_head_dim_rejected():

@@ -160,35 +160,64 @@ def test_engine_pallas_interpret_matches_dense():
     assert run("dense") == run("pallas_interpret")
 
 
-# -- Mosaic tiling guard (the BENCH_r01 lowering failure) --------------------
+# -- Ahead-of-time compile for the v5e (no chip: the installed libtpu) ---------
 
-def test_mosaic_tiling_rejects_seed_era_per_head_block():
-    """The round-1 bench died lowering a per-head KV block spec
-    ``(1, 16, 1, 128)`` against the [NB, BS, KH, D] cache: 1 in the
-    second-to-minor position (KH=8) is neither the whole axis nor a
-    multiple of the min tile. The static guard must reject exactly that
-    shape and accept the whole-axis spec the kernel now uses."""
-    from dynamo_tpu.ops.paged_attention import mosaic_block_shape_ok
+@pytest.fixture(scope="module")
+def v5e_device():
+    from jax.experimental import topologies
 
-    cache = (128, 16, 8, 128)  # bench-like: bs=16, kh=8, d=128
-    assert not mosaic_block_shape_ok((1, 16, 1, 128), cache, jnp.bfloat16)
-    assert mosaic_block_shape_ok((1, 16, 8, 128), cache, jnp.bfloat16)
-    # multiples of the min tile are fine even when not the whole axis
-    assert mosaic_block_shape_ok((1, 16, 16, 128), (128, 16, 32, 128),
-                                 jnp.bfloat16)
-    # f32 min tile is 8x128: sublane 8 divides, lane must be 128-multiple
-    assert mosaic_block_shape_ok((8, 128), (64, 128), jnp.float32)
-    assert not mosaic_block_shape_ok((8, 64), (64, 128), jnp.float32)
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
 
 
-def test_validate_block_specs_readable_error():
-    from dynamo_tpu.ops.paged_attention import _validate_block_specs
+@pytest.mark.parametrize("b,t,nblk,nb,kv,ns,compiles", [
+    pytest.param(32, 1, 512, 18000, "bfloat16", 1, True, id="bf16-decode"),
+    # The mixed step's shape: T = prefill_chunk, decode rows one token of it.
+    pytest.param(8, 512, 512, 18000, "bfloat16", 1, True, id="bf16-chunk512"),
+    pytest.param(1, 1, 512, 18000, "bfloat16", 8, True, id="bf16-split-k"),
+    pytest.param(32, 1, 16, 449, "int8", 1, True, id="int8-small-pool"),
+    # The scale sidecars ride scalar prefetch into SMEM (1 MiB), 512 B a
+    # block for K and for V: the compiler refuses the pool, and so must
+    # the engine's own arithmetic, at construction.
+    pytest.param(32, 1, 16, 36000, "int8", 1, False, id="int8-pool-refused"),
+])
+def test_kernel_compiles_for_v5e(v5e_device, b, t, nblk, nb, kv, ns, compiles):
+    """Mosaic itself, at the llama-3-8b geometry (32 Q / 8 KV heads x 128,
+    block 16), judges the kernel's block shapes and memory — and the
+    engine's SMEM arithmetic (ModelRunner._check_kernel_fits) has to agree
+    with it on which pools fit."""
+    from jax.sharding import SingleDeviceSharding
 
-    with pytest.raises(ValueError, match="tiling rule"):
-        _validate_block_specs([
-            ("k_cache", (1, 16, 1, 128), (128, 16, 8, 128), jnp.bfloat16)])
-    _validate_block_specs([
-        ("k_cache", (1, 16, 8, 128), (128, 16, 8, 128), jnp.bfloat16)])
+    from dynamo_tpu.ops.paged_attention import (
+        SMEM_USABLE_BYTES,
+        scalar_prefetch_bytes,
+    )
+
+    kh, h, d, bs = 8, 32, 128, 16
+    sh = SingleDeviceSharding(v5e_device)
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cache = abstract((nb, bs, kh, d), jnp.bfloat16)
+    if kv == "int8":
+        cache = {"q": abstract((nb, bs, kh, d), jnp.int8),
+                 "s": abstract((nb, kh), jnp.float32)}
+    lowered = jax.jit(
+        lambda q, k, v, bt, qs, kl: paged_attention_kernel(
+            q, k, v, bt, qs, kl, num_splits=ns)
+    ).lower(abstract((b, t, h, d), jnp.bfloat16), cache, cache,
+            abstract((b, nblk), jnp.int32), abstract((b,), jnp.int32),
+            abstract((b,), jnp.int32))
+    fits = scalar_prefetch_bytes(
+        batch=b, nblk=nblk, num_blocks=nb if kv == "int8" else 0,
+        kv_heads=kh) <= SMEM_USABLE_BYTES
+    assert fits == compiles
+    if compiles:
+        lowered.compile()
+    else:
+        with pytest.raises(Exception, match="smem"):
+            lowered.compile()
 
 
 def test_paged_attention_kernel_parity_at_bench_shapes():
@@ -459,26 +488,3 @@ def test_int4_cache_split_k_matches_sequential():
                                    num_splits=2, interpret=True)
     np.testing.assert_allclose(np.asarray(split), np.asarray(seq),
                                atol=2e-6, rtol=2e-6)
-
-
-def test_validate_block_specs_int4_and_split_state():
-    """The static guard understands packed-int4 payload blocks (uint8,
-    trailing dim D/2, whole-axis on both minor dims) and the split-K f32
-    partial-state outputs; a per-head packed block still fails readably."""
-    from dynamo_tpu.ops.paged_attention import (
-        _validate_block_specs,
-        mosaic_block_shape_ok,
-    )
-
-    # int4 payload: whole-axis KH and D/2 pass; per-head slice fails.
-    assert mosaic_block_shape_ok((1, 16, 8, 64), (128, 16, 8, 64), jnp.uint8)
-    assert not mosaic_block_shape_ok((1, 16, 1, 64), (128, 16, 8, 64),
-                                     jnp.uint8)
-    _validate_block_specs([
-        ("k_cache_int4", (1, 16, 8, 64), (128, 16, 8, 64), jnp.uint8),
-        ("acc_split", (1, 1, 8, 4, 128), (2, 4, 8, 4, 128), jnp.float32),
-        ("m_split", (1, 1, 8, 4, 128), (2, 4, 8, 4, 128), jnp.float32),
-    ])
-    with pytest.raises(ValueError, match="k_cache_int4.*uint8"):
-        _validate_block_specs([
-            ("k_cache_int4", (1, 16, 1, 64), (128, 16, 8, 64), jnp.uint8)])

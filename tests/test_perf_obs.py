@@ -209,8 +209,12 @@ def test_hw_spec_lookup():
     assert cm.hw_spec_for("TPU v5 lite").name == "tpu-v5e"
     assert cm.hw_spec_for("TPU v5p chip").name == "tpu-v5p"
     assert cm.hw_spec_for("TPU v6e").name == "tpu-v6e"
-    assert cm.hw_spec_for("Grace CPU").name == "cpu"
-    assert cm.hw_spec_for("").name == "cpu"  # unknown -> conservative
+    assert cm.hw_spec_for("cpu").name == "cpu"  # a named entry, for tests
+    # A device that is not in the table is an error, not a default: its
+    # peaks feed live decisions and every utilization.
+    for unknown in ("", "TPU v9", "NVIDIA H100"):
+        with pytest.raises(ValueError, match="no hardware spec"):
+            cm.hw_spec_for(unknown)
 
 
 def test_mixed_step_cost_hand_computed_all_kv_dtypes():
