@@ -1,0 +1,92 @@
+"""From the run's records and counter snapshots to metrics.
+
+End-to-end metrics are computed here, over all requests due in the window
+and all the window's time; per-layer metrics are read by the small readers
+under ``chipbench/layers/``, one file each, found by the metric's name.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+from dataclasses import dataclass, field
+
+from .loadgen import Record
+from .manifest import BENCH
+from .stats import percentile, token_gaps
+
+
+@dataclass
+class Context:
+    """What a per-layer reader may read."""
+
+    window: tuple[float, float]            # perf_counter clock
+    window_wall: tuple[float, float]       # time.time clock (ledger events)
+    chips: int
+    records: list[Record]                  # every request of the run
+    counters: tuple[dict, dict]            # engine.stats() at the edges
+    kv_usage: list[float] = field(default_factory=list)   # sampled in window
+    in_flight: list[int] = field(default_factory=list)    # running + waiting
+    compile_events: list[dict] = field(default_factory=list)
+    memory_peak_bytes: int = 0
+    trace: dict | None = None    # harness.trace.reduce(), if it found ops
+
+    @property
+    def seconds(self) -> float:
+        return self.window[1] - self.window[0]
+
+    def delta(self, *path: str) -> float:
+        a, b = self.counters
+        for k in path:
+            a, b = a[k], b[k]
+        return b - a
+
+    @property
+    def due_in_window(self) -> list[Record]:
+        lo, hi = self.window
+        return [r for r in self.records if lo <= r.due < hi]
+
+
+def end_to_end(ctx: Context, setup_s: float) -> dict[str, float]:
+    """Every end-to-end metric the benchmark knows; the caller reports the
+    ones its cell lists."""
+    lo, hi = ctx.window
+    due = ctx.due_in_window
+    ttft = [(r.first_token - r.due) * 1e3 for r in due
+            if r.first_token is not None]
+    gaps = [g * 1e3 for r in due for g in token_gaps(r.deltas)]
+    tokens = sum(k for r in ctx.records for t, k in r.deltas if lo <= t < hi)
+    out = {"setup_s": setup_s,
+           "tokens_per_s": tokens / ctx.seconds / ctx.chips}
+    if ttft:
+        out["ttft_mean_ms"] = sum(ttft) / len(ttft)
+        out["ttft_p50_ms"] = percentile(ttft, 50)
+        out["ttft_p90_ms"] = percentile(ttft, 90)
+    if gaps:
+        out["itl_p95_ms"] = percentile(gaps, 95)
+    return out
+
+
+def failed(ctx: Context) -> int:
+    return sum(1 for r in ctx.due_in_window
+               if r.finish is None or r.error or r.finish == "error")
+
+
+def load_reader(name: str):
+    path = BENCH / "layers" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_layer_" + "".join(c if c.isalnum() else "_" for c in name),
+        path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def per_layer(ctx: Context, names: list[str]) -> dict[str, dict]:
+    """``{name: {"value", "unit"}}`` for the readers that found something."""
+    out = {}
+    for name in names:
+        mod = load_reader(name)
+        value = mod.read(ctx)
+        if value is not None:
+            out[name] = {"value": float(value), "unit": mod.unit}
+    return out

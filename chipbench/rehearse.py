@@ -1,0 +1,189 @@
+"""Rehearse the benchmark on the CPU: no chip time, and no number under a
+device metric's name.
+
+    python3 chipbench/rehearse.py
+
+Checks the schedule arithmetic (the same multiset in every seed, due times),
+the percentile and gap arithmetic on a synthetic stream, the interval
+arithmetic of the trace reduction and its reading of a small recorded
+``.xplane.pb``, the manifest against every file it names, the warm-up set
+against the lengths a cell can reach, and then runs the whole harness, probe
+against the reference included, on a tiny configuration kept under
+``chipbench/rehearsal/``.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import io
+import json
+import os
+import sys
+from pathlib import Path
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE))
+sys.path.insert(0, str(HERE.parent))
+
+import run as bench_run  # noqa: E402
+from harness import manifest, measure, stats, sut, trace, traffic  # noqa: E402
+
+
+def check_schedule() -> None:
+    tr = manifest.load_cell("mistral-7b.chat").traffic
+    a = traffic.schedule(tr, 32768, 51.0, 1)
+    b = traffic.schedule(tr, 32768, 51.0, 2**31 + 12345)
+    assert len(a) == len(b) == round(tr["rate_per_s"] * 51)
+    # Another seed: the same lengths at the same instants, other contents.
+    assert [(len(r.prompt), r.max_tokens, r.due_s) for r in a] == \
+        [(len(r.prompt), r.max_tokens, r.due_s) for r in b]
+    assert [r.prompt for r in a] != [r.prompt for r in b]
+    assert traffic.schedule(tr, 32768, 51.0, 1) == a     # same seed, same run
+    # Another order (the held-out check): the same lengths and gaps, moved.
+    c = traffic.schedule(tr, 32768, 51.0, 1, order=traffic.ORDER + 1)
+    assert [len(r.prompt) for r in c] != [len(r.prompt) for r in a]
+    assert sorted(len(r.prompt) for r in c) == sorted(len(r.prompt) for r in a)
+    assert sorted(r.max_tokens for r in c) == sorted(r.max_tokens for r in a)
+    # Two cells of one traffic mix share its file and differ in rate alone.
+    nemo = manifest.load_cell("mistral-nemo-12b.chat").traffic
+    assert {k: v for k, v in nemo.items() if not k.startswith("rate")} == \
+        {k: v for k, v in tr.items() if not k.startswith("rate")}
+    assert [r.due_s for r in a] == sorted(r.due_s for r in a)
+    assert 0 <= a[0].due_s and a[-1].due_s < 51.0
+    p, n = tr["prompt_tokens"], len(a)
+    assert all(p["min"] <= len(r.prompt) <= p["max"] for r in a)
+    assert all(0 <= t < 32768 for r in a for t in r.prompt)
+    # The lengths are the stated lognormal's quantiles, whatever the order.
+    assert sorted(len(r.prompt) for r in a) == traffic.lognormal_quantiles(
+        n, p["median"], p["sigma"], p["min"], p["max"])
+    # The ramp is another phase: its own order and contents.
+    ramp = traffic.schedule(tr, 32768, 10.0, 1, phase=1)
+    assert len(ramp) == round(tr["rate_per_s"] * 10)
+    gaps = traffic.exponential_gaps(n, 51.0)
+    assert abs(sum(gaps) - 51.0) < 1e-9 and gaps == sorted(gaps)
+
+
+def check_stats() -> None:
+    assert stats.percentile([1, 2, 3, 4, 5], 50) == 3
+    assert abs(stats.percentile(list(range(101)), 95) - 95) < 1e-9
+    assert stats.percentile([7.0], 99) == 7.0
+    # first delta 1 token, then 1 token 0.1 later, then 3 tokens 0.3 later
+    g = stats.token_gaps([(1.0, 1), (1.1, 1), (1.4, 3)])
+    assert [round(x, 6) for x in g] == [0.1, 0.3, 0.0, 0.0]
+    assert stats.token_gaps([(1.0, 2)]) == [0.0]
+
+
+def check_trace() -> None:
+    assert trace.union([(0, 2), (1, 3), (5, 6)]) == [(0, 3), (5, 6)]
+    own = trace.self_times([("while", 0, 10), ("dot", 1, 4), ("dot", 5, 7),
+                            ("copy", 12, 13)])
+    assert own == {"dot": 5, "while": 5, "copy": 1}
+    assert trace.gaps([(0, 3), (5, 6)], 0, 10) == [(3, 5), (6, 10)]
+    host = [("loop: run", 0, 10), ("loop: plan", 3.2, 4.9)]
+    assert trace.attribute((3, 5), host) == "loop: plan"
+    assert trace.attribute((6, 10), host) == "loop: run"
+    # The recorded trace is of the CPU backend (five jitted steps under
+    # "bench.step" annotations): it has no device plane, so name the CPU
+    # client's line as if it were one.
+    rec = HERE / "rehearsal" / "cpu-5-steps.xplane.pb"
+    import jax
+
+    line = next(ln.name for pl in jax.profiler.ProfileData.from_file(
+        str(rec)).planes for ln in pl.lines if "PjRtCpuClient" in ln.name)
+    red = trace.reduce(rec, device_plane=r"^/host:CPU$", op_line=line,
+                       host_plane=r"^/host:CPU$")
+    assert 0 < red["busy_s"] < red["window_s"]
+    assert any(n.startswith("dot_general") for n, _ in red["device_ops"])
+    assert "busy_s" not in trace.reduce(rec)    # no TPU plane: nothing to read
+    # A trace from the chip (mistral-7b.chat, overloaded, PR 24; cut to the
+    # events that begin in its first 0.3 s, so a ``while`` that outlasts the
+    # cut has lost its children): read with the defaults.
+    chip = trace.reduce(HERE / "rehearsal" / "v5e-chat-0.3s.xplane.pb")
+    assert chip["devices"] == 1 and 0 < chip["busy_s"] <= chip["window_s"]
+    longer = trace.reduce(HERE / "rehearsal" / "v5e-chat-0.3s.xplane.pb", 6.0)
+    assert longer["window_s"] == 6.0 and longer["busy_s"] == chip["busy_s"]
+    assert longer["idle_gaps"][0][1] == 6.0 - chip["window_s"]
+    assert chip["planes"]["/device:TPU:0"]["XLA Ops"] > 1000
+    assert any(n.startswith("attention bf16[") for n, _ in chip["device_ops"])
+    assert len(chip["device_ops"]) == 10 and chip["idle_gaps"]
+    assert trace.short_name(
+        "%fusion.150 = bf16[32,512,14336]{2,1,0:T(8,128)(2,1)} fusion(bf16"
+    ) == "fusion bf16[32,512,14336]"
+
+
+def check_manifest() -> None:
+    faults = manifest.check()
+    assert not faults, faults
+    bench = manifest.load_benchmark()
+    for m in bench["per_layer"]:
+        mod = measure.load_reader(m["name"])
+        for k in ("name", "unit", "layer", "moves", "source"):
+            assert getattr(mod, k) == m[k], (m["name"], k)
+    for w in bench["workloads"]:
+        cell = manifest.load_cell(w["name"], bench)
+        assert "setup_s" in cell.end_to_end and len(cell.end_to_end) >= 2
+        ec = sut.engine_config(cell.config_dir, cell.about)
+        sigs = sut.reachable_buckets(cell.traffic, ec)
+        greedy = cell.traffic["sampling"]["temperature"] <= 0
+        assert sigs and all(s.greedy == greedy for s in sigs)
+        print(f"rehearse: {w['name']}: {len(sigs)} step programs to warm "
+              f"(rows {sorted({s.b for s in sigs})}, "
+              f"chunks {sorted({s.t for s in sigs})}, "
+              f"block tables {sorted({s.nblk for s in sigs})})")
+
+
+def check_harness() -> None:
+    """The whole command on the tiny configuration, traced."""
+    bench = {
+        "configs": [{"name": "tiny", "file": "chipbench/rehearsal/tiny/config.json",
+                     "source": "none", "reduced": []}],
+        "workloads": [{"name": "tiny.rehearsal", "config": "tiny",
+                       "traffic": "rehearsal", "chips": 1}],
+        "end_to_end": manifest.load_benchmark()["end_to_end"],
+        "per_layer": [dict(m, **{"workloads": ["tiny.rehearsal"]})
+                      for m in manifest.load_benchmark()["per_layer"]],
+    }
+    for m in bench["end_to_end"]:
+        m.pop("workloads", None)
+    for trace_flag in ("0", "1"):
+        buf = io.StringIO()
+        with contextlib.redirect_stdout(buf):
+            rc = bench_run.main(
+                ["--workload", "tiny.rehearsal", "--seed", str(2**31 + 7),
+                 "--seconds", "3", "--trace", trace_flag],
+                allow_cpu=True, bench=bench,
+                data_dir=HERE / "rehearsal")
+        last = json.loads(buf.getvalue().strip().splitlines()[-1])
+        assert rc == 0
+        assert set(last) - {"breakdown"} == {
+            "correct", "attempted", "failed", "metrics", "device"}, last
+        assert last["correct"] is True, buf.getvalue()[-3000:]
+        assert last["failed"] == 0 and last["attempted"] == 12, last
+        assert last["device"]["platform"] == "cpu"
+        names = set(last["metrics"])
+        if trace_flag == "0":
+            assert names == {m["name"] for m in bench["end_to_end"]}, names
+        else:
+            # No device plane on the CPU: the trace readers return nothing
+            # and the harness leaves their metrics out.
+            assert "device.idle_pct" not in names and "busy_s" not in last["device"]
+            assert {"sched.rows_per_step", "engine.step_period_ms",
+                    "engine.compiles_in_window",
+                    "stream.ttft_p90_ms"} <= names, names
+        print(f"rehearse: harness --trace {trace_flag}: control flow ok "
+              f"({last['attempted']} requests, probe agrees with the reference)")
+
+
+def main() -> int:
+    for check in (check_schedule, check_stats, check_trace, check_manifest,
+                  check_harness):
+        check()
+        print(f"rehearse: {check.__name__} ok")
+    print("rehearse: all ok (CPU: no time, rate or utilization here means "
+          "anything)")
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
