@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
 import dataclasses
 import math
 import queue as thread_queue
@@ -40,6 +41,7 @@ from dynamo_tpu.engine.cache import (
     KVCacheSpec,
     abstract_cache,
     allocate_cache,
+    cache_payload,
     cache_sharding,
     register_device_tier,
 )
@@ -66,7 +68,12 @@ from dynamo_tpu.obs.compile_ledger import (
     enumerate_buckets,
     get_compile_ledger,
 )
-from dynamo_tpu.obs.profiler import StepPerfProfiler, phase as _perf_phase
+from dynamo_tpu.obs.profiler import (
+    LoopClock,
+    StepPerfProfiler,
+    loop_phase,
+    phase as _perf_phase,
+)
 from dynamo_tpu.obs.mem_ledger import get_mem_ledger, live_ids_of
 from dynamo_tpu.obs.sched_ledger import HolStall, get_sched_ledger, step_geometry
 from dynamo_tpu.obs.tracer import get_tracer, trace_context_of
@@ -91,6 +98,18 @@ def _pow2_bucket(n: int, lo: int, hi: int) -> int:
     while b < n and b < hi:
         b *= 2
     return b
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """Give the function about to be jitted a name that says what it is and
+    which bucket: the device trace's ``XLA Modules`` line then reads
+    ``jit_<name>(...)`` and decode, mixed and verify steps can be told
+    apart. (The persistent compile cache keys on it too.)"""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+_NO_PHASE = contextlib.nullcontext()
 
 
 @jax.jit
@@ -149,11 +168,29 @@ class EngineMetrics:
     # exported as dynamo_engine_kv_cache_bytes / dynamo_engine_kv_quant_enabled.
     kv_cache_bytes: int = 0
     kv_quant_enabled: bool = False
+    # Per-device shape of one of K or V, [L, NB, BS, KH, D] with KH divided
+    # by tp (set once): what a reader of a device trace looks for to find
+    # the operations that move the cache.
+    kv_cache_shape: tuple[int, ...] = ()
+    # Time to first token in parts, summed over the sequences whose first
+    # token has been posted (each once, a re-prefilled one too): arrival at
+    # generate() -> add_request (the inbox), arrival -> the first plan that
+    # carried a chunk of it (so the inbox wait is inside), that plan ->
+    # first token posted.
+    ttft_count: int = 0
+    ttft_inbox_s: float = 0.0
+    ttft_queue_s: float = 0.0
+    ttft_prefill_s: float = 0.0
 
     def snapshot(self, sched: Scheduler, pool: PrefixPool) -> dict:
         return {
             "kv_cache_bytes": self.kv_cache_bytes,
             "kv_quant_enabled": self.kv_quant_enabled,
+            "kv_cache_shape": list(self.kv_cache_shape),
+            "ttft_count": self.ttft_count,
+            "ttft_inbox_s": self.ttft_inbox_s,
+            "ttft_queue_s": self.ttft_queue_s,
+            "ttft_prefill_s": self.ttft_prefill_s,
             "num_waiting": sched.num_waiting,
             "num_running": sched.num_running,
             "kv_usage": pool.usage,
@@ -302,6 +339,13 @@ class ModelRunner:
         # times it, attributes the victim request, and feeds warmup
         # coverage. Disabled (warmup_mode=off) the gate is one bool read.
         self._ledger = get_compile_ledger()
+        # Loop-phase seconds (obs/profiler.py): the runner adds
+        # engine.compile around a step program built inside serving;
+        # EngineCore shares this clock for the rest of the loop.
+        self.loop_clock = LoopClock()
+        # (kind, b, t, nblk) of the last dispatched step program: what the
+        # engine.dispatch span says it enqueued.
+        self.last_bucket: tuple[str, int, int, int] = ("", 0, 0, 0)
         # The pool comes last: everything else that lives on the device is
         # resident by now, so what memory_stats() calls free really is.
         self.spec = KVCacheSpec.for_model(
@@ -602,7 +646,13 @@ class ModelRunner:
             slot_toks = slot_toks.at[write_slots].set(toks)
             return ck, cv, counts, keys, slot_toks, toks, lps
 
-        return jax.jit(step, donate_argnums=(1, 2, 3, 4, 5),
+        name = (f"step_decode_b{b}_n{nblk}" if t == 1
+                else f"step_mixed_b{b}_t{t}_n{nblk}")
+        for flag, suffix in ((sp_prefill, "_sp"), (not fast_greedy, "_sampled"),
+                             (mm, "_mm"), (masked, "_masked")):
+            if flag:
+                name += suffix
+        return jax.jit(_named(step, name), donate_argnums=(1, 2, 3, 4, 5),
                        **self._jit_shardings())
 
     def _jit_shardings(self) -> dict:
@@ -669,7 +719,9 @@ class ModelRunner:
                 jnp.arange(w, dtype=jnp.int32))
             return ck, cv, counts, keys, slot_toks, toks_w.T, lps_w.T  # [B, W]
 
-        return jax.jit(step, donate_argnums=(1, 2, 3, 4, 5),
+        name = (f"step_window_b{b}_n{nblk}_w{w}"
+                + ("" if fast_greedy else "_sampled"))
+        return jax.jit(_named(step, name), donate_argnums=(1, 2, 3, 4, 5),
                        **self._jit_shardings())
 
     def step_fn(self, b: int, t: int, nblk: int, sp_prefill: bool = False,
@@ -863,9 +915,13 @@ class ModelRunner:
                 if m is not None:
                     logit_mask[i, ~m] = -1e30
         led = self._ledger
-        cold = led.enabled and (
-            (b, t, nblk, sp_prefill, window, fast_greedy, mm, masked)
-            not in self._step_fns)
+        kind = ("window" if window > 1
+                else "decode" if t == 1
+                else "mixed" if mixed else "prefill")
+        self.last_bucket = (kind, b, t, nblk)
+        miss = ((b, t, nblk, sp_prefill, window, fast_greedy, mm, masked)
+                not in self._step_fns)
+        cold = led.enabled and miss
         fn = self.step_fn(b, t, nblk, sp_prefill, window, fast_greedy, mm,
                           masked)
         place = self._place
@@ -878,22 +934,20 @@ class ModelRunner:
             # async), so timing the call measures the engine-thread stall.
             led.mark_inflight(True)
             t_compile = time.perf_counter()
-        (self.cache_k, self.cache_v, self.counts, self.keys, self.slot_toks,
-         toks, lps) = fn(
-            self.params, self.cache_k, self.cache_v, self.counts, self.keys,
-            self.slot_toks,
-            place(tokens), place(q_start), place(q_len),
-            place(bt), place(slots), place(temp),
-            place(top_k), place(top_p), place(fp),
-            place(pp), place(rp), place(do_sample),
-            place(from_slot), *extra,
-        )
+        with self._compile_phase(miss, kind, b, t, nblk):
+            (self.cache_k, self.cache_v, self.counts, self.keys,
+             self.slot_toks, toks, lps) = fn(
+                self.params, self.cache_k, self.cache_v, self.counts,
+                self.keys, self.slot_toks,
+                place(tokens), place(q_start), place(q_len),
+                place(bt), place(slots), place(temp),
+                place(top_k), place(top_p), place(fp),
+                place(pp), place(rp), place(do_sample),
+                place(from_slot), *extra,
+            )
         if cold:
             dt = time.perf_counter() - t_compile
             led.mark_inflight(False)
-            kind = ("window" if window > 1
-                    else "decode" if t == 1
-                    else "mixed" if mixed else "prefill")
             led.record(
                 BucketSig(kind, b, t, nblk, fast_greedy,
                           ec.kv_dtype or "bfloat16"),
@@ -901,6 +955,16 @@ class ModelRunner:
                 trace_ctx=next((s.trace_ctx for s, _, _ in rows
                                 if s.trace_ctx is not None), None))
         return toks, lps
+
+    def _compile_phase(self, miss: bool, kind: str, b: int, t: int,
+                       nblk: int):
+        """``engine.compile`` around the first call of a step program the
+        serving path had to build (jit compiles inside that call); nothing
+        around a call that hits."""
+        if not miss:
+            return _NO_PHASE
+        return loop_phase(self.loop_clock, "engine.compile", kind=kind,
+                          b=b, t=t, nblk=nblk)
 
     def run(
         self,
@@ -941,7 +1005,8 @@ class ModelRunner:
         if self.mesh is not None:
             repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
             kw["out_shardings"] = (cache, cache, repl, repl)
-        return jax.jit(verify, donate_argnums=(1, 2), **kw)
+        return jax.jit(_named(verify, f"step_verify_b{b}_t{t}_n{nblk}"),
+                       donate_argnums=(1, 2), **kw)
 
     def dispatch_verify(self, rows: list[tuple[Seq, int, int]],
                         chunks: list[list[int]]) -> tuple[jax.Array, jax.Array]:
@@ -974,9 +1039,11 @@ class ModelRunner:
             bt[i, : len(ids)] = ids
 
         key = ("verify", b, t, nblk)
+        self.last_bucket = key
         led = self._ledger
-        cold = led.enabled and key not in self._step_fns
-        if key not in self._step_fns:
+        miss = key not in self._step_fns
+        cold = led.enabled and miss
+        if miss:
             log.info("compiling verify fn B=%d T=%d NBLK=%d", b, t, nblk)
             self._step_fns[key] = self._build_verify_fn(b, t, nblk)
         fn = self._step_fns[key]
@@ -984,9 +1051,10 @@ class ModelRunner:
         if cold:
             led.mark_inflight(True)
             t_compile = time.perf_counter()
-        self.cache_k, self.cache_v, toks, lps = fn(
-            self.params, self.cache_k, self.cache_v,
-            place(tokens), place(q_start), place(q_len), place(bt))
+        with self._compile_phase(miss, "verify", b, t, nblk):
+            self.cache_k, self.cache_v, toks, lps = fn(
+                self.params, self.cache_k, self.cache_v,
+                place(tokens), place(q_start), place(q_len), place(bt))
         if cold:
             dt = time.perf_counter() - t_compile
             led.mark_inflight(False)
@@ -1027,7 +1095,7 @@ class ModelRunner:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             kw["out_shardings"] = NamedSharding(self.mesh, P())
-        return jax.jit(embed, **kw)
+        return jax.jit(_named(embed, f"embed_b{b}_t{t}"), **kw)
 
     def embed(self, token_lists: list[list[int]]) -> np.ndarray:
         """Embed a batch of token sequences → [N, H] float32 (last-token
@@ -1044,8 +1112,9 @@ class ModelRunner:
         b = _bucket(len(token_lists), (1, 2, 4, 8, 16, 32, 64))
         key = ("embed", b, t, 0, 0)
         led = self._ledger
-        cold = led.enabled and key not in self._step_fns
-        if key not in self._step_fns:
+        miss = key not in self._step_fns
+        cold = led.enabled and miss
+        if miss:
             log.info("compiling embed fn B=%d T=%d", b, t)
             self._step_fns[key] = self._build_embed_fn(b, t)
         fn = self._step_fns[key]
@@ -1057,7 +1126,9 @@ class ModelRunner:
         if cold:
             led.mark_inflight(True)
             t_compile = time.perf_counter()
-        hidden = np.asarray(fn(self.params, self._place(tokens), self._place(q_len)))
+        with self._compile_phase(miss, "embed", b, t, 0):
+            hidden = np.asarray(fn(self.params, self._place(tokens),
+                                   self._place(q_len)))
         if cold:
             led.mark_inflight(False)
             led.record(
@@ -1321,11 +1392,22 @@ class EngineCore:
         if engine_cfg.session_ttl > 0 and engine_cfg.enable_prefix_caching:
             self.sessions = SessionStore(self.pool,
                                          ttl=engine_cfg.session_ttl)
+        payload = cache_payload(self.runner.cache_k)
         self.metrics = EngineMetrics(
             kv_cache_bytes=(self.runner.spec.bytes_per_block()
                             * self.runner.spec.num_blocks),
             kv_quant_enabled=self.runner.spec.quantized,
+            kv_cache_shape=tuple(
+                payload.sharding.shard_shape(payload.shape)),
         )
+        # The engine thread's loop phases (obs/profiler.py loop_phase):
+        # always-on seconds per phase, and profiler spans at the same
+        # boundaries. The runner times its serve-path compiles into it.
+        self.loop_clock = self.runner.loop_clock
+        # (t_arrival, t_added, t_first_plan) of the sequences whose first
+        # token the last finalize emitted; whoever hands the outputs on
+        # closes them (first_tokens_posted).
+        self._first_tokens: list[tuple[float, float, float]] = []
         # Hardware counters: analytic FLOPs/bytes + MFU/BW-util per step
         # (obs/profiler.py). DYN_PERF_PROFILE=0 turns the whole thing into
         # a no-op dict lookup per step.
@@ -1481,10 +1563,18 @@ class EngineCore:
 
     # ------------------------------------------------------------------
     def add_request(self, req: PreprocessedRequest,
-                    now: float | None = None) -> LLMEngineOutput | None:
+                    now: float | None = None,
+                    arrival: tuple[float, float] | None = None,
+                    ) -> LLMEngineOutput | None:
         """Queue a request; returns an immediate error output if rejected.
         `now` pins the deadline-expiry clock (multi-host replay passes the
-        leader's timestamp so all ranks make the same admit decision)."""
+        leader's timestamp so all ranks make the same admit decision).
+        ``arrival`` is when the request reached ``generate()``, as
+        ``(perf_counter, time.time)`` of one instant; without it the
+        request arrives now. It starts the request's time to first token
+        and its ``engine.queue`` span, and no decision reads it."""
+        t_added = time.perf_counter()
+        t_arrival, wall_arrival = arrival or (t_added, time.time())
         if not req.token_ids:
             return LLMEngineOutput(
                 finish_reason=FinishReason.ERROR, error="empty prompt (no token_ids)"
@@ -1496,7 +1586,8 @@ class EngineCore:
             # prefill compute is ever dispatched for it.
             self.metrics.deadline_cancelled += 1
             return LLMEngineOutput(finish_reason=FinishReason.CANCELLED)
-        seq = Seq(req=req, block_size=self.engine_cfg.block_size)
+        seq = Seq(req=req, block_size=self.engine_cfg.block_size,
+                  t_arrival=t_arrival, t_added=t_added)
         if req.sampling_options.guided_json is not None:
             from dynamo_tpu.engine.guided import TokenMasker
 
@@ -1541,10 +1632,12 @@ class EngineCore:
         self._seqs[req.request_id] = seq
         seq.trace_ctx = trace_context_of(getattr(req, "annotations", None))
         if seq.trace_ctx is not None:
-            # Admission wait starts now; step_begin ends it when the first
-            # prefill chunk is planned (engine.queue → engine.prefill).
+            # Admission wait started when the request reached generate()
+            # (its wait in the inbox is part of it); step_begin ends it when
+            # the first prefill chunk is planned (engine.queue →
+            # engine.prefill).
             seq.trace_span = get_tracer().start_span(
-                "engine.queue", ctx=seq.trace_ctx,
+                "engine.queue", ctx=seq.trace_ctx, start=wall_arrival,
                 request_id=req.request_id, model=req.model,
                 prompt_tokens=seq.prompt_len, priority=seq.qos_priority)
         if self.sessions is not None and seq.session_id is not None:
@@ -1722,6 +1815,24 @@ class EngineCore:
         to build step N+1, and a finished/stopped stream costs at most one
         speculative row, discarded at finalize.
         """
+        with loop_phase(self.loop_clock, "engine.plan"):
+            plan = self._plan_step()
+        if plan is None:
+            return None
+        with loop_phase(self.loop_clock, "engine.dispatch") as span:
+            pending = self._dispatch_plan(plan)
+            kind, b, t, nblk = self.runner.last_bucket
+            span.set(kind=kind, b=b, t=t, nblk=nblk,
+                     rows=sum(len(x[1]) for x in pending.batches))
+        if self.sched_led.enabled:
+            with loop_phase(self.loop_clock, "engine.plan"):
+                pending.sched = self._sched_context(plan)
+        return pending
+
+    def _plan_step(self) -> "StepPlan | None":
+        """The scheduling half of :meth:`step_begin`: session sweep, plan,
+        write-back of evicted blocks, plan-time accounting. None when there
+        is nothing to dispatch."""
         if self.sessions is not None:
             self._session_sweep()
         plan = self.sched.plan()
@@ -1756,6 +1867,9 @@ class EngineCore:
             if seq.ckpt_counted:
                 continue
             seq.ckpt_counted = True
+            # The same once-per-sequence instant ends the queue part of its
+            # time to first token (a re-prefill does not move it).
+            seq.t_first_plan = time.perf_counter()
             ann = getattr(seq.req, "annotations", None) or {}
             if int(ann.get(CKPT_GENERATED_KEY) or 0) > 0:
                 self.metrics.stream_ckpt_resumes += 1
@@ -1764,7 +1878,11 @@ class EngineCore:
                 sm.resume_recomputed_tokens.inc(max(
                     seq.prefill_target()
                     - seq.prefix_hit_blocks * seq.block_size, 0))
+        return plan
 
+    def _dispatch_plan(self, plan: StepPlan) -> "PendingStep":
+        """The device half of :meth:`step_begin`: slot init, batch
+        building, input prep and the jitted call(s)."""
         for seq in [w.seq for w in plan.prefill] + plan.decode:
             if not seq.slot_initialized and seq.slot >= 0:
                 self._init_slot(seq)
@@ -1862,49 +1980,52 @@ class EngineCore:
                 if sample_rows[i]:
                     seq.inflight_samples += 1
             pending.batches.append((kind, rows, sample_rows, toks, lps))
-        if self.sched_led.enabled:
-            used = (len(plan.decode) * plan.decode_window
-                    + sum(w.length for w in plan.prefill))
-            hol = None
-            if plan.prefill and plan.decode:
-                # Every decode-ready stream in this step waits out the
-                # prefill work before its token materializes; the culprit
-                # is the request contributing the largest chunk. Under the
-                # unified step the stall is NOT a whole separate launch —
-                # only the chunk's marginal share of the mixed step's wall
-                # (priced by the cost model) is charged to the victims.
-                culprit = max(plan.prefill, key=lambda w: w.length)
-                stall_share = None
-                if self._unified:
-                    from dynamo_tpu.obs import costmodel as cm
-                    kw = dict(
-                        decode_rows=len(plan.decode),
-                        decode_kv_len=max(s.num_computed
-                                          for s in plan.decode),
-                        chunk_kv_len=max(w.start + w.length
-                                         for w in plan.prefill),
-                        block_size=self.engine_cfg.block_size,
-                        kv_dtype=self.engine_cfg.kv_dtype or "bfloat16",
-                        quantization=self.engine_cfg.quantization or "none")
-                    mixed_s = cm.mixed_step_seconds(
-                        self.model_cfg, self._hw,
-                        chunk=sum(w.length for w in plan.prefill), **kw)
-                    pure_s = cm.mixed_step_seconds(
-                        self.model_cfg, self._hw, chunk=0, **kw)
-                    if mixed_s > 0:
-                        stall_share = max(mixed_s - pure_s, 0.0) / mixed_s
-                hol = HolStall(
-                    culprit=culprit.seq.request_id,
-                    culprit_tokens=sum(w.length for w in plan.prefill),
-                    victims=[(s.trace_ctx, s.request_id, s.qos_priority)
-                             for s in plan.decode],
-                    stall_share=stall_share)
-            pending.sched = {
-                "decode_window": plan.decode_window,
-                "budget_util": used / max(self.sched.max_tokens_per_step, 1),
-                "hol": hol,
-            }
         return pending
+
+    def _sched_context(self, plan: StepPlan) -> dict:
+        """Scheduling-ledger context of a dispatched plan (token-budget
+        utilization, HOL victims), consumed by ``_record_step``."""
+        used = (len(plan.decode) * plan.decode_window
+                + sum(w.length for w in plan.prefill))
+        hol = None
+        if plan.prefill and plan.decode:
+            # Every decode-ready stream in this step waits out the
+            # prefill work before its token materializes; the culprit
+            # is the request contributing the largest chunk. Under the
+            # unified step the stall is NOT a whole separate launch —
+            # only the chunk's marginal share of the mixed step's wall
+            # (priced by the cost model) is charged to the victims.
+            culprit = max(plan.prefill, key=lambda w: w.length)
+            stall_share = None
+            if self._unified:
+                from dynamo_tpu.obs import costmodel as cm
+                kw = dict(
+                    decode_rows=len(plan.decode),
+                    decode_kv_len=max(s.num_computed
+                                      for s in plan.decode),
+                    chunk_kv_len=max(w.start + w.length
+                                     for w in plan.prefill),
+                    block_size=self.engine_cfg.block_size,
+                    kv_dtype=self.engine_cfg.kv_dtype or "bfloat16",
+                    quantization=self.engine_cfg.quantization or "none")
+                mixed_s = cm.mixed_step_seconds(
+                    self.model_cfg, self._hw,
+                    chunk=sum(w.length for w in plan.prefill), **kw)
+                pure_s = cm.mixed_step_seconds(
+                    self.model_cfg, self._hw, chunk=0, **kw)
+                if mixed_s > 0:
+                    stall_share = max(mixed_s - pure_s, 0.0) / mixed_s
+            hol = HolStall(
+                culprit=culprit.seq.request_id,
+                culprit_tokens=sum(w.length for w in plan.prefill),
+                victims=[(s.trace_ctx, s.request_id, s.qos_priority)
+                         for s in plan.decode],
+                stall_share=stall_share)
+        return {
+            "decode_window": plan.decode_window,
+            "budget_util": used / max(self.sched.max_tokens_per_step, 1),
+            "hol": hol,
+        }
 
     def _trace_plan(self, plan: StepPlan) -> None:
         """Advance per-seq phase spans from the step plan. Spans are
@@ -2014,6 +2135,7 @@ class EngineCore:
                 total=self.pool.num_blocks - 1)
             self.mem_led.observe_free(self.pool.num_free, now=time.time())
             self.mem_led.maybe_audit(time.time())
+        self.loop_clock.publish()
 
     def _plan_verify(self, decode_seqs: list
                      ) -> tuple[list, list[list[int]], list]:
@@ -2067,6 +2189,11 @@ class EngineCore:
         Returns the number of tokens emitted."""
         emitted: list[int] = []
         reason = None
+        if seq.t_first_plan and candidates:
+            # Its first first-token: cleared here, so it counts once.
+            self._first_tokens.append(
+                (seq.t_arrival, seq.t_added, seq.t_first_plan))
+            seq.t_first_plan = 0.0
         for token in candidates:
             seq.tokens.append(token)
             seq.block_seq.append(token)
@@ -2115,54 +2242,83 @@ class EngineCore:
         effects: append tokens, commit full blocks (hash chain), evaluate
         stop conditions, assemble per-request outputs."""
         t0 = time.perf_counter()
+        clock = self.loop_clock
         outputs: dict[str, LLMEngineOutput] = {}
         for kind, rows, sample_rows, toks_dev, lps_dev in pending.batches:
-            if kind == "verify":
-                self._finalize_verify(rows, sample_rows, toks_dev, lps_dev,
-                                      outputs)
-                continue
-            n = len(rows)
-            # Normalize to [n, W]: single-step dispatches return [B], fused
-            # decode windows [B, W] — one finalize path serves both.
-            toks = np.asarray(toks_dev)[:n].reshape(n, -1)
-            lps = np.asarray(lps_dev)[:n].reshape(n, -1)
-            width = toks.shape[1]
-            for i, (seq, start, length) in enumerate(rows):
-                if seq.phase is Phase.FINISHED:
-                    # Finished (stop/abort) while this step was in flight:
-                    # its speculative row is discarded.
-                    continue
-                # A mixed batch's leading rows are decode rows (the split
-                # was captured at plan time); everything after them, and
-                # every row of a plain prefill batch, counts as prefill.
-                decode_row = (kind == "decode"
-                              or (kind == "mixed"
-                                  and i < pending.mixed_dec_rows))
-                if not decode_row:
-                    self.metrics.num_prefill_tokens += length
-                if sample_rows[i]:
-                    seq.inflight_samples -= 1
-                if not sample_rows[i]:
-                    # Intermediate prefill chunk: no token emitted. (A seq
-                    # preempted while in flight is WAITING with num_computed
-                    # reset to 0 — commit is then a no-op.)
-                    self.sched.commit_computed_blocks(seq)
-                    continue
-                # Append window tokens until a stop fires; the rest of the
-                # window is discarded (its KV lives in blocks this seq owns,
-                # freed at finish).
-                self._emit_and_finish(
-                    seq, [int(x) for x in toks[i]], lps[i], outputs,
-                    count_decode=decode_row)
-        self._record_step(t0, pending)
+            with loop_phase(clock, "engine.finalize.wait"):
+                # The host blocks here until the device has run the step.
+                toks = np.asarray(toks_dev)
+                lps = np.asarray(lps_dev)
+            with loop_phase(clock, "engine.finalize.host"):
+                self._finalize_batch(kind, rows, sample_rows, toks, lps,
+                                     pending.mixed_dec_rows, outputs)
+        with loop_phase(clock, "engine.record"):
+            self._record_step(t0, pending)
         if self.kvbm is not None and not self.sched.has_work():
             # Engine going idle: this finalize's commits would otherwise sit
             # in the publish-on-commit queue until the next step_begin —
             # which may be a long time away on a drained worker.
-            self.kvbm.drain_publish()
+            with loop_phase(clock, "engine.finalize.host"):
+                self.kvbm.drain_publish()
         return outputs
 
-    def _finalize_verify(self, rows, chunks, toks_dev, lps_dev,
+    def first_tokens_posted(self) -> None:
+        """The outputs of the last finalize have been handed on: close the
+        time to first token of the sequences whose first token was among
+        them (``EngineMetrics.ttft_*``). A sequence counts once, at its
+        first first-token, however often it is preempted and re-prefilled."""
+        if not self._first_tokens:
+            return
+        now = time.perf_counter()
+        m = self.metrics
+        for t_arrival, t_added, t_first_plan in self._first_tokens:
+            m.ttft_count += 1
+            m.ttft_inbox_s += t_added - t_arrival
+            m.ttft_queue_s += t_first_plan - t_arrival
+            m.ttft_prefill_s += now - t_first_plan
+        self._first_tokens.clear()
+
+    def _finalize_batch(self, kind: str, rows, sample_rows, toks, lps,
+                        mixed_dec_rows: int,
+                        outputs: dict[str, LLMEngineOutput]) -> None:
+        """Apply one batch's materialized tokens (host arrays, padded to the
+        bucket)."""
+        if kind == "verify":
+            self._finalize_verify(rows, sample_rows, toks, lps, outputs)
+            return
+        n = len(rows)
+        # Normalize to [n, W]: single-step dispatches return [B], fused
+        # decode windows [B, W] — one finalize path serves both.
+        toks = toks[:n].reshape(n, -1)
+        lps = lps[:n].reshape(n, -1)
+        for i, (seq, start, length) in enumerate(rows):
+            if seq.phase is Phase.FINISHED:
+                # Finished (stop/abort) while this step was in flight:
+                # its speculative row is discarded.
+                continue
+            # A mixed batch's leading rows are decode rows (the split
+            # was captured at plan time); everything after them, and
+            # every row of a plain prefill batch, counts as prefill.
+            decode_row = (kind == "decode"
+                          or (kind == "mixed" and i < mixed_dec_rows))
+            if not decode_row:
+                self.metrics.num_prefill_tokens += length
+            if sample_rows[i]:
+                seq.inflight_samples -= 1
+            if not sample_rows[i]:
+                # Intermediate prefill chunk: no token emitted. (A seq
+                # preempted while in flight is WAITING with num_computed
+                # reset to 0 — commit is then a no-op.)
+                self.sched.commit_computed_blocks(seq)
+                continue
+            # Append window tokens until a stop fires; the rest of the
+            # window is discarded (its KV lives in blocks this seq owns,
+            # freed at finish).
+            self._emit_and_finish(
+                seq, [int(x) for x in toks[i]], lps[i], outputs,
+                count_decode=decode_row)
+
+    def _finalize_verify(self, rows, chunks, toks, lps,
                          outputs: dict[str, LLMEngineOutput]) -> None:
         """Accept/rollback a speculative verify step (engine/spec.py).
 
@@ -2172,9 +2328,6 @@ class EngineCore:
         unreachable — later true tokens overwrite those positions)."""
         from dynamo_tpu.engine.spec import accept
 
-        n = len(rows)
-        toks = np.asarray(toks_dev)[:n]
-        lps = np.asarray(lps_dev)[:n]
         for i, (seq, start, length) in enumerate(rows):
             seq.verify_inflight = False
             if seq.phase is Phase.FINISHED:
@@ -2355,6 +2508,7 @@ class EngineCore:
         pending = self.step_begin()
         if pending is not None:
             outs.update(self.step_finalize(pending))
+            self.first_tokens_posted()
         return outs
 
     # -- disagg / KV-transfer primitives (engine-core thread only) ---------
@@ -2433,7 +2587,6 @@ class EngineCore:
 
     def my_box(self) -> tuple[int, int, int, int]:
         """This rank's (layer, head) extents of the global cache."""
-        from dynamo_tpu.engine.cache import cache_payload
         from dynamo_tpu.kvbm.distributed import local_box
 
         starts, stops = local_box(cache_payload(self.runner.cache_k))
@@ -2935,86 +3088,16 @@ class AsyncJaxEngine:
         # and dispatches step N+1 BEFORE blocking on step N's tokens, so host
         # work (scheduling, numpy prep, output assembly, SSE handoff) runs
         # while the device computes — the overlap reference-class engines get
-        # from async scheduling (see EngineCore.step_begin).
+        # from async scheduling (see EngineCore.step_begin). The loop's
+        # phases are cut by ``loop_phase`` (obs/profiler.py LOOP_PHASES):
+        # idle wait, inbox and post here; plan, dispatch, finalize and
+        # record inside step_begin / step_finalize.
         pending: PendingStep | None = None
+        clock = self.core.loop_clock
         while not self._stop:
-            moved = False
-            while True:
-                try:
-                    kind, payload = self._inbox.get_nowait()
-                except thread_queue.Empty:
-                    break
-                moved = True
-                if kind == "add":
-                    # The admit timestamp rides the op so follower ranks
-                    # evaluate deadline expiry at the leader's instant.
-                    t_add = time.time()
-                    try:
-                        self._emit_op({"op": "add", "req": payload.to_dict(),
-                                       "now": t_add})
-                    except OpChannelDown as exc:
-                        self._post(payload.request_id, LLMEngineOutput(
-                            finish_reason=FinishReason.ERROR, error=str(exc)))
-                        break
-                    err = self.core.add_request(payload, now=t_add)
-                    if err is not None:
-                        self._post(payload.request_id, err)
-                elif kind == "abort":
-                    try:
-                        self._emit_op({"op": "abort", "rid": payload})
-                    except OpChannelDown:
-                        break  # _stop is set; streams fail below
-                    self.core.abort(payload)
-                    self._post(payload, LLMEngineOutput(finish_reason=FinishReason.CANCELLED))
-                elif kind == "exec_op":
-                    # Named core op (CORE_OPS): broadcast first so followers
-                    # replay it at the same point in the stream, then run
-                    # locally. This is how disagg KV staging/import composes
-                    # with multi-host engines.
-                    name, args, fut, fut_loop = payload
-                    try:
-                        self._emit_op({"op": "exec", "name": name, "args": args})
-                    except OpChannelDown as exc:
-                        fut_loop.call_soon_threadsafe(self._resolve, fut, None, exc)
-                        break
-                    try:
-                        result, exc = self.core.run_op(name, args), None
-                    except Exception as e:
-                        result, exc = None, e
-                    try:
-                        fut_loop.call_soon_threadsafe(self._resolve, fut, result, exc)
-                    except RuntimeError:
-                        log.warning("exec_op result dropped: caller loop closed")
-                elif kind == "exec" and self._op_sink is not None:
-                    # Closure-based core access can't ride the op stream —
-                    # running it would desync the followers' SPMD programs.
-                    # Refuse loudly; use run_op (named ops) instead.
-                    fn, fut, fut_loop = payload
-                    exc = RuntimeError(
-                        "run_in_core is not supported on a multi-host leader; "
-                        "use run_op with a registered named op")
-                    try:
-                        fut_loop.call_soon_threadsafe(self._resolve, fut, None, exc)
-                    except RuntimeError:
-                        pass
-                elif kind == "exec":
-                    # Arbitrary core access (KV export/import/pin for disagg)
-                    # marshaled onto this thread — the only thread allowed to
-                    # touch device state. The future resolves on the loop it
-                    # was created on (the caller's), which may differ from
-                    # self._loop — cross-loop set_result is not thread-safe.
-                    fn, fut, fut_loop = payload
-                    try:
-                        result, exc = fn(self.core), None
-                    except Exception as e:
-                        result, exc = None, e
-                    try:
-                        fut_loop.call_soon_threadsafe(self._resolve, fut, result, exc)
-                    except RuntimeError:
-                        # Caller's loop closed before we resolved (e.g. a
-                        # cancelled asyncio.run): the future's owner is gone;
-                        # dropping the result must not kill this thread.
-                        log.warning("exec result dropped: caller loop closed")
+            if not self._inbox.empty():
+                with loop_phase(clock, "engine.inbox"):
+                    self._drain_inbox()
             if self._channel_down:
                 # Op channel died mid-drain: fail everything in flight
                 # (checked before the idle-continue so an idle engine still
@@ -3026,32 +3109,38 @@ class AsyncJaxEngine:
                         error="multi-host op channel down"))
                 break
             if not self.core.has_work() and pending is None:
-                if not moved:
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                # One span for the whole wait, however many timeouts it
+                # takes, so that a gap of the device trace is covered by
+                # one host span. Only the inbox (or shutdown) can give this
+                # thread work, so that is all the wait looks at.
+                with loop_phase(clock, "engine.idle_wait"):
+                    while self._inbox.empty() and not self._stop:
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
                 continue
             try:
                 # Chaos: inside the try so an error-kind injection exercises
                 # the engine-fatal path (fail_all + drain), and a delay is a
                 # straggling device step.
                 chaos.inject("engine.step")
-                if self.core.has_work() or pending is not None:
-                    t_step = time.time()
-                    if self.core.has_expired_waiting(t_step):
-                        # Broadcast-then-apply, like every state-changing op:
-                        # followers reap the same seqs at the same instant.
-                        self._emit_op({"op": "reap", "now": t_step})
-                        for rid, out in self.core.reap_expired(t_step).items():
-                            self._post(rid, out)
-                    self._emit_op({"op": "step", "now": t_step})
-                    self.core.set_step_time(t_step)
+                t_step = time.time()
+                if self.core.has_expired_waiting(t_step):
+                    # Broadcast-then-apply, like every state-changing op:
+                    # followers reap the same seqs at the same instant.
+                    self._emit_op({"op": "reap", "now": t_step})
+                    for rid, out in self.core.reap_expired(t_step).items():
+                        self._post(rid, out)
+                self._emit_op({"op": "step", "now": t_step})
+                self.core.set_step_time(t_step)
                 nxt = self.core.step_begin() if self.core.has_work() else None
-                if pending is not None:
-                    outputs = self.core.step_finalize(pending)
+                outputs = (self.core.step_finalize(pending)
+                           if pending is not None else {})
+                pending = nxt
+                with loop_phase(clock, "engine.post"):
                     for rid, out in outputs.items():
                         self._post(rid, out)
-                pending = nxt
-                self._stage_stream_waves()
+                    self.core.first_tokens_posted()
+                    self._stage_stream_waves()
             except Exception as exc:
                 # Engine-fatal: fail + drain all in-flight state so the loop
                 # doesn't spin hot retrying the same failing step.
@@ -3080,6 +3169,87 @@ class AsyncJaxEngine:
                 if self.fatal is not None:
                     break
                 continue
+
+    def _drain_inbox(self) -> None:
+        """Apply everything that has arrived: requests (admission, prefix
+        match), aborts, core ops. Stops early when the op channel dies."""
+        while True:
+            try:
+                kind, payload = self._inbox.get_nowait()
+            except thread_queue.Empty:
+                break
+            if kind == "add":
+                req, arrival = payload
+                # The admit timestamp rides the op so follower ranks
+                # evaluate deadline expiry at the leader's instant. The
+                # arrival stamps do not: they are this process's clocks.
+                t_add = time.time()
+                try:
+                    self._emit_op({"op": "add", "req": req.to_dict(),
+                                   "now": t_add})
+                except OpChannelDown as exc:
+                    self._post(req.request_id, LLMEngineOutput(
+                        finish_reason=FinishReason.ERROR, error=str(exc)))
+                    break
+                err = self.core.add_request(req, now=t_add, arrival=arrival)
+                if err is not None:
+                    self._post(req.request_id, err)
+            elif kind == "abort":
+                try:
+                    self._emit_op({"op": "abort", "rid": payload})
+                except OpChannelDown:
+                    break  # _stop is set; streams fail below
+                self.core.abort(payload)
+                self._post(payload, LLMEngineOutput(finish_reason=FinishReason.CANCELLED))
+            elif kind == "exec_op":
+                # Named core op (CORE_OPS): broadcast first so followers
+                # replay it at the same point in the stream, then run
+                # locally. This is how disagg KV staging/import composes
+                # with multi-host engines.
+                name, args, fut, fut_loop = payload
+                try:
+                    self._emit_op({"op": "exec", "name": name, "args": args})
+                except OpChannelDown as exc:
+                    fut_loop.call_soon_threadsafe(self._resolve, fut, None, exc)
+                    break
+                try:
+                    result, exc = self.core.run_op(name, args), None
+                except Exception as e:
+                    result, exc = None, e
+                try:
+                    fut_loop.call_soon_threadsafe(self._resolve, fut, result, exc)
+                except RuntimeError:
+                    log.warning("exec_op result dropped: caller loop closed")
+            elif kind == "exec" and self._op_sink is not None:
+                # Closure-based core access can't ride the op stream —
+                # running it would desync the followers' SPMD programs.
+                # Refuse loudly; use run_op (named ops) instead.
+                fn, fut, fut_loop = payload
+                exc = RuntimeError(
+                    "run_in_core is not supported on a multi-host leader; "
+                    "use run_op with a registered named op")
+                try:
+                    fut_loop.call_soon_threadsafe(self._resolve, fut, None, exc)
+                except RuntimeError:
+                    pass
+            elif kind == "exec":
+                # Arbitrary core access (KV export/import/pin for disagg)
+                # marshaled onto this thread — the only thread allowed to
+                # touch device state. The future resolves on the loop it
+                # was created on (the caller's), which may differ from
+                # self._loop — cross-loop set_result is not thread-safe.
+                fn, fut, fut_loop = payload
+                try:
+                    result, exc = fn(self.core), None
+                except Exception as e:
+                    result, exc = None, e
+                try:
+                    fut_loop.call_soon_threadsafe(self._resolve, fut, result, exc)
+                except RuntimeError:
+                    # Caller's loop closed before we resolved (e.g. a
+                    # cancelled asyncio.run): the future's owner is gone;
+                    # dropping the result must not kill this thread.
+                    log.warning("exec result dropped: caller loop closed")
 
     @staticmethod
     def _resolve(fut: asyncio.Future, result, exc: Exception | None) -> None:
@@ -3125,7 +3295,10 @@ class AsyncJaxEngine:
             q.put_nowait(LLMEngineOutput(
                 finish_reason=FinishReason.ERROR,
                 error=f"engine stopped: {self.fatal}"))
-        self._inbox.put(("add", req))
+        # The arrival stamps (one instant on both clocks) ride beside the
+        # request, not in it: a field of the request would go down the op
+        # stream to follower ranks.
+        self._inbox.put(("add", (req, (time.perf_counter(), time.time()))))
         self._wake.set()
         out: LLMEngineOutput | None = None
         try:
@@ -3167,6 +3340,8 @@ class AsyncJaxEngine:
     def stats(self) -> dict:
         out = self.core.metrics.snapshot(self.core.sched, self.core.pool)
         out["device"] = self.core.device_info
+        # Seconds per phase of the engine thread's loop (LOOP_PHASES).
+        out["loop"] = self.core.loop_clock.snapshot()
         if self.core.kvbm is not None:
             out["kvbm"] = self.core.kvbm.snapshot()
             if self.core.kvbm.ckpt_tier is not None:

@@ -113,6 +113,14 @@ class Seq:
     trace_ctx: object | None = None
     trace_span: object | None = None
     trace_tokens: int = 0
+    # Time to first token, in parts (``perf_counter``; EngineMetrics.ttft_*):
+    # when the request reached generate(), when add_request took it from the
+    # inbox, and when the first plan carried a chunk of it. The engine
+    # stamps t_first_plan once and clears it when the first token has been
+    # posted, so a preempted and re-prefilled sequence counts once.
+    t_arrival: float = 0.0
+    t_added: float = 0.0
+    t_first_plan: float = 0.0
 
     def __post_init__(self) -> None:
         self.tokens = list(self.req.token_ids)

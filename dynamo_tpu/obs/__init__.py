@@ -28,7 +28,6 @@ from dynamo_tpu.obs.costmodel import (
 from dynamo_tpu.obs.profiler import (
     PerfMetrics,
     StepPerfProfiler,
-    capture_phases,
     get_perf_metrics,
     install_perf_metrics,
     phase,
@@ -59,7 +58,6 @@ __all__ = [
     "StepPerfProfiler",
     "StepProfiler",
     "Tracer",
-    "capture_phases",
     "get_perf_metrics",
     "get_tracer",
     "hw_spec_for",

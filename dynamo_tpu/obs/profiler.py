@@ -1,15 +1,20 @@
 """Step performance profiler: hardware counters for every engine step.
 
-Three pieces:
+Four pieces:
 
-* ``phase(name)`` — the lightweight hook models/llama.py and
-  engine/engine.py wrap their phases in (scatter, gather, attention,
-  logits, sampling). Outside a capture it is exactly ``jax.named_scope``:
-  zero runtime ops (the scope only annotates the traced HLO, so XLA
-  profiles group by phase), and since the model runs under ``jax.jit`` the
-  context manager itself executes only at trace time. Inside
-  ``capture_phases()`` (an eager/``jax.disable_jit`` profiling run) it
-  additionally accumulates wall time per phase.
+* ``phase(name)`` — the hook models/llama.py and engine/engine.py wrap
+  their device phases in (scatter, gather, attention, logits, sampling):
+  plain ``jax.named_scope``. Zero runtime ops (the scope only annotates the
+  traced HLO, so XLA profiles group by phase), and since the model runs
+  under ``jax.jit`` the context manager itself executes only at trace time.
+
+* ``loop_phase(clock, name)`` / ``LoopClock`` — the boundaries of the
+  engine thread's own loop (idle wait, inbox, plan, dispatch, finalize,
+  record, post, compile), written two ways from one context manager: a
+  ``jax.profiler.TraceAnnotation`` (recorded only while a profiler session
+  is open; it lands on the engine thread's line of the trace, on the device
+  trace's clock) and always-on seconds per phase (``stats()["loop"]``,
+  ``dynamo_engine_loop_seconds_total{phase}``).
 
 * ``StepPerfProfiler`` — folds the analytic cost model (obs/costmodel.py)
   over each dispatched step's batches and, with the measured step wall,
@@ -29,7 +34,6 @@ Three pieces:
 from __future__ import annotations
 
 import os
-import threading
 import time
 from typing import Any
 
@@ -58,58 +62,83 @@ def perf_enabled(default: bool = True) -> bool:
 # Phase hooks
 # ---------------------------------------------------------------------------
 
-_capture = threading.local()
+def phase(name: str):
+    """Wrap one device phase: ``jax.named_scope`` (annotation only, zero ops
+    in the compiled program)."""
+    import jax
+
+    return jax.named_scope(name)
 
 
-class _TimedPhase:
-    """Capture-mode phase: named_scope + wall accumulation. Wall times are
-    trustworthy in eager/disable_jit profiling runs (each phase's dispatch
-    is ~synchronous on CPU); under jit they fire at trace time and the
-    capture dict records trace cost, which is why captures are explicit."""
+# The engine thread's loop, cut at one set of boundaries
+# (engine/engine.py: AsyncJaxEngine._run, EngineCore.step_begin,
+# step_finalize). They do not nest, except engine.compile inside
+# engine.dispatch: a step's host self time is the sum of the non-wait
+# phases less engine.compile.
+LOOP_PHASES = (
+    "engine.idle_wait",       # nothing to do: waiting on the wake event
+    "engine.inbox",           # add_request (prefix match), aborts, exec ops
+    "engine.plan",            # session sweep, sched.plan(), accounting
+    "engine.dispatch",        # input prep, H2D, enqueue of the step program
+    "engine.finalize.wait",   # host blocked on the device's tokens
+    "engine.finalize.host",   # token append, hash commit, stop checks
+    "engine.record",          # the always-on ledgers (_record_step)
+    "engine.post",            # hand-off to the asyncio loop, stream waves
+    "engine.compile",         # a step program built inside serving
+)
 
-    __slots__ = ("name", "_scope", "_t0")
 
-    def __init__(self, name: str):
-        self.name = name
+class LoopClock:
+    """Seconds the engine thread spent in each loop phase since the engine
+    was built. The engine thread is the only writer; readers copy
+    (``snapshot``). Every key exists from the start, so a copy never meets
+    a dict that is growing."""
+
+    def __init__(self) -> None:
+        self.seconds: dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._published: dict[str, float] = dict(self.seconds)
+
+    def snapshot(self) -> dict[str, float]:
+        return dict(self.seconds)
+
+    def publish(self) -> None:
+        """Feed ``dynamo_engine_loop_seconds_total{phase}`` with what has
+        accrued since the last call (once a step, from ``_record_step``)."""
+        counter = get_perf_metrics().loop_seconds
+        for name, total in self.seconds.items():
+            delta = total - self._published[name]
+            if delta > 0.0:
+                counter.inc(delta, phase=name)
+                self._published[name] = total
+
+
+class loop_phase:
+    """One phase of the engine loop: a ``TraceAnnotation`` named ``name``
+    (a no-op unless a profiler session is open) and ``clock.seconds[name]``
+    plus the elapsed ``perf_counter`` seconds. Observational only: no
+    decision reads either. ``set(**attrs)`` adds attributes known only
+    inside the phase (the bucket a dispatch picked)."""
+
+    __slots__ = ("_clock", "_name", "_ann", "_t0")
+
+    def __init__(self, clock: LoopClock, name: str, **attrs: Any):
+        import jax
+
+        self._clock, self._name = clock, name
+        self._ann = jax.profiler.TraceAnnotation(name, **attrs)
+
+    def set(self, **attrs: Any) -> None:
+        self._ann.set_metadata(**attrs)
 
     def __enter__(self):
-        import jax
-        self._scope = jax.named_scope(self.name)
-        self._scope.__enter__()
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
-        self._scope.__exit__(*exc)
-        sink = getattr(_capture, "sink", None)
-        if sink is not None:
-            sink[self.name] = sink.get(self.name, 0.0) + dt
-        return False
-
-
-def phase(name: str):
-    """Wrap one engine phase. No capture active → plain ``jax.named_scope``
-    (annotation only, zero ops in the compiled program)."""
-    if getattr(_capture, "sink", None) is None:
-        import jax
-        return jax.named_scope(name)
-    return _TimedPhase(name)
-
-
-class capture_phases:
-    """Context manager enabling wall-time capture for ``phase()`` hooks on
-    this thread; yields the {phase: seconds} dict. Use with
-    ``jax.disable_jit()`` (or eager calls) for real per-phase walls."""
-
-    def __enter__(self) -> dict[str, float]:
-        self._prev = getattr(_capture, "sink", None)
-        sink: dict[str, float] = {}
-        _capture.sink = sink
-        return sink
-
-    def __exit__(self, *exc):
-        _capture.sink = self._prev
+        self._ann.__exit__(*exc)
+        self._clock.seconds[self._name] += dt
         return False
 
 
@@ -151,8 +180,15 @@ class PerfMetrics:
             "Cumulative analytic HBM bytes moved by engine steps")
         self.step_seconds = registry.histogram(
             "engine_perf_step_seconds",
-            "Engine step wall time (dispatch to materialize)",
+            "Time step_finalize took for one engine step: mostly the host's "
+            "wait for the device to finish it, plus token append, commits "
+            "and stop checks",
             buckets=_STEP_SECONDS_BUCKETS)
+        self.loop_seconds = registry.counter(
+            "engine_loop_seconds_total",
+            "Seconds the engine thread spent in each phase of its loop "
+            "(phase = engine.idle_wait|inbox|plan|dispatch|finalize.wait|"
+            "finalize.host|record|post|compile; compile nests in dispatch)")
 
 
 _metrics: PerfMetrics | None = None

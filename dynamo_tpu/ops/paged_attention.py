@@ -422,6 +422,9 @@ def paged_attention_kernel(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        # A name of its own in the device trace (the custom call would
+        # otherwise take it from whatever scope encloses it).
+        name="paged_attention_splitk" if split else "paged_attention",
     )(*scalars, qs, k_cache, v_cache)
     if split:
         out = _combine_splits(*out, out_dtype=q.dtype)

@@ -350,6 +350,9 @@ def follower_loop(core_factory: Callable[[dict], Any], sock: socket.socket) -> N
                 nxt = core.step_begin() if core.has_work() else None
                 if pending is not None:
                     core.step_finalize(pending)
+                    # A follower posts to nobody; close the first-token
+                    # stamps so they do not pile up.
+                    core.first_tokens_posted()
                 pending = nxt
             except Exception as exc:
                 log.exception("follower step failed; wiping in-flight state")

@@ -1,0 +1,256 @@
+"""The benchmark's per-layer readers (``chipbench/layers/``) and the trace
+events they read (``chipbench/harness/xevents.py``): the manifest against its
+files, the counter readers on hand-made contexts, the trace readers on a
+small trace recorded on the chip. No number here is a measurement."""
+
+from __future__ import annotations
+
+import json
+import sys
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "chipbench"))
+
+from harness import manifest, measure, xevents  # noqa: E402
+
+REHEARSAL = ROOT / "chipbench" / "rehearsal"
+# mistral-nemo-12b.chat on the v5e with this PR's program (PR 25, my chip run
+# 1): 0.25 s of the traced slice (six decode steps, one arriving prompt and
+# its mixed step), cut to the device's three lines and the host's spans.
+CHIP_TRACE = REHEARSAL / "v5e-nemo-chat-named-0.25s.xplane.pb"
+# mistral-7b.chat on PR 24's program: no named step programs, no engine spans.
+OLD_TRACE = REHEARSAL / "v5e-chat-0.3s.xplane.pb"
+CPU_TRACE = REHEARSAL / "cpu-5-steps.xplane.pb"
+
+BENCH = manifest.load_benchmark()
+NEW_COUNTER = ("engine.inbox_wait_mean_ms", "sched.queue_wait_mean_ms",
+               "engine.prefill_mean_ms", "engine.host_ms_per_step",
+               "engine.record_ms_per_step", "engine.idle_wait_pct",
+               "engine.device_wait_pct")
+NEW_TRACE = ("engine.decode_step_dev_ms", "engine.mixed_step_dev_ms",
+             "device.cache_copy_pct", "device.attention_pct",
+             "mesh.collective_exposed_pct")
+POOL = [10, 2837, 16, 8, 128]            # the recorded cell's pool
+
+
+def test_manifest_names_files_that_exist():
+    assert manifest.check() == []
+    names = {m["name"] for m in BENCH["per_layer"]}
+    assert set(NEW_COUNTER + NEW_TRACE) - names == {
+        "mesh.collective_exposed_pct"}      # no four-chip cell to read it in
+    assert all(w["chips"] == 1 for w in BENCH["workloads"])
+
+
+def test_the_four_chip_cells_files_are_ready_for_their_entries():
+    """``mistral-nemo-12b.tp4.chat`` was measured in PR 25 and not entered
+    in ``BENCHMARK.json`` (PERF.md section 7 says which edits of the harness
+    it waits for). Its files are data: with the entries PERF.md gives, the
+    manifest finds them and has no fault."""
+    about = ROOT / "chipbench/configs/mistral-nemo-12b-tp4/about.json"
+    bench = dict(
+        BENCH,
+        configs=BENCH["configs"] + [{
+            "name": "mistral-nemo-12b-tp4",
+            "source": json.loads(about.read_text())["source"],
+            "file": "chipbench/configs/mistral-nemo-12b-tp4/config.json",
+            "reduced": []}],
+        workloads=BENCH["workloads"] + [{
+            "name": "mistral-nemo-12b.tp4.chat",
+            "config": "mistral-nemo-12b-tp4", "traffic": "chat", "chips": 4}],
+        per_layer=BENCH["per_layer"] + [{
+            "name": "mesh.collective_exposed_pct", "moves": "itl_p95_ms",
+            "workloads": ["mistral-nemo-12b.tp4.chat"]}])
+    assert manifest.check(bench) == []
+    cell = manifest.load_cell("mistral-nemo-12b.tp4.chat", bench)
+    assert cell.about["engine"]["tp"] == 4 and cell.about["reduced"] == {}
+    assert cell.model["num_hidden_layers"] == 40 and cell.chips == 4
+    assert cell.traffic["rate_per_s"] == 0.8
+    assert "mesh.collective_exposed_pct" in cell.per_layer
+    assert "mesh.collective_exposed_pct" not in \
+        manifest.load_cell("mistral-7b.chat", bench).per_layer
+    sweep = json.loads((ROOT / "chipbench/sweeps/"
+                        "mistral-nemo-12b.tp4.chat.json").read_text())
+    assert sweep["knee_per_s_all_chips"] == 1.0
+    assert cell.traffic["rate_per_s"] == 0.8 * sweep["knee_per_s_all_chips"]
+    mod = measure.load_reader("mesh.collective_exposed_pct")
+    assert (mod.unit, mod.moves, mod.source) == \
+        ("%", "itl_p95_ms", "device_trace")
+
+
+@pytest.mark.parametrize("entry", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_reader_file_agrees_with_its_entry(entry):
+    mod = measure.load_reader(entry["name"])
+    for key in ("name", "unit", "layer", "moves", "source"):
+        assert getattr(mod, key) == entry[key], key
+    assert callable(mod.read) and mod.__doc__
+
+
+def _loop(**over) -> dict:
+    keys = ("engine.idle_wait", "engine.inbox", "engine.plan",
+            "engine.dispatch", "engine.finalize.wait", "engine.finalize.host",
+            "engine.record", "engine.post", "engine.compile")
+    return {k: float(over.get(k, 0.0)) for k in keys}
+
+
+def _ctx(c0: dict, c1: dict, seconds: float = 50.0, chips: int = 1,
+         trace: dict | None = None) -> measure.Context:
+    return measure.Context(window=(100.0, 100.0 + seconds),
+                           window_wall=(1e9, 1e9 + seconds), chips=chips,
+                           records=[], counters=(c0, c1), trace=trace)
+
+
+def _counters() -> tuple[dict, dict]:
+    c0 = {"num_steps": 100, "ttft_count": 10, "ttft_inbox_s": 1.0,
+          "ttft_queue_s": 2.0, "ttft_prefill_s": 5.0, "loop": _loop()}
+    c1 = {"num_steps": 1100, "ttft_count": 50, "ttft_inbox_s": 1.8,
+          "ttft_queue_s": 3.0, "ttft_prefill_s": 15.0,
+          "kv_cache_shape": POOL,
+          "loop": _loop(**{"engine.idle_wait": 5.0, "engine.inbox": 0.1,
+                           "engine.plan": 0.5, "engine.dispatch": 2.4,
+                           "engine.compile": 0.4,
+                           "engine.finalize.wait": 40.0,
+                           "engine.finalize.host": 0.6,
+                           "engine.record": 0.3, "engine.post": 0.5})}
+    return c0, c1
+
+
+@pytest.mark.parametrize("name, expect", [
+    ("engine.inbox_wait_mean_ms", 20.0),       # 0.8 s over 40 sequences
+    ("sched.queue_wait_mean_ms", 25.0),
+    ("engine.prefill_mean_ms", 250.0),
+    # (0.1 + 0.5 + 2.4 - 0.4 + 0.6 + 0.3 + 0.5) s over 1000 steps
+    ("engine.host_ms_per_step", 4.0),
+    ("engine.record_ms_per_step", 0.3),
+    ("engine.idle_wait_pct", 10.0),            # 5 s of 50
+    ("engine.device_wait_pct", 80.0),
+])
+def test_counter_reader_on_a_hand_made_context(name, expect):
+    value = measure.load_reader(name).read(_ctx(*_counters()))
+    assert value == pytest.approx(expect)
+
+
+@pytest.mark.parametrize("name", NEW_COUNTER + NEW_TRACE)
+def test_reader_finds_nothing_in_a_program_without_the_counters(name,
+                                                                 monkeypatch):
+    """The parent commit has no loop clock, no TTFT parts, no cache shape
+    and no named programs: every new reader returns None, none raises."""
+    monkeypatch.setattr(xevents, "newest_xplane", lambda *a, **k: OLD_TRACE)
+    old = ({"num_steps": 1}, {"num_steps": 9})
+    trace = {"busy_s": 0.2, "window_s": 0.3}
+    assert measure.load_reader(name).read(_ctx(*old, trace=trace)) is None
+    assert measure.load_reader(name).read(_ctx(*old, chips=4, trace=trace)) \
+        in (None, 0.0)        # one chip's trace holds no collective
+
+
+def test_ttft_parts_with_no_first_token_in_the_window():
+    c0, c1 = _counters()
+    c1["ttft_count"] = c0["ttft_count"]
+    assert measure.load_reader("sched.queue_wait_mean_ms").read(
+        _ctx(c0, c1)) is None
+
+
+def test_hlo_text_helpers():
+    hlo = ("%constant_dynamic-update-slice_fusion.4 = bf16[16,2183,16,8,128]"
+           "{4,3,2,1,0:T(8,128)(2,1)} fusion(bf16[16,2183,16,8,128] %p)")
+    assert xevents.result_shape(hlo) == (16, 2183, 16, 8, 128)
+    assert xevents.instruction(hlo) == \
+        "%constant_dynamic-update-slice_fusion.4"
+    start = ("%copy-start = (bf16[34928,8,128]{2,1,0}, bf16[34928,8,128]"
+             "{2,1,0:S(1)}, u32[]{:S(2)}) copy-start(bf16[34928,8,128] %x)")
+    assert xevents.result_shape(start) == (34928, 8, 128)
+    assert xevents.result_shape("%tuple.1 = () tuple()") is None
+    assert xevents.result_shape("%c = s32[] constant(0)") == ()
+    for name in ("%all-reduce.7", "all-gather", "%all-reduce-start.2",
+                 "%collective-permute-done", "%reduce-scatter.11",
+                 "%all-to-all.3"):
+        assert xevents.COLLECTIVE.match(name), name
+    for name in ("%fusion.3", "%all-reduce_fusion.1", "%paged_attention.1"):
+        assert not xevents.COLLECTIVE.match(name), name
+
+
+def test_subtract_intervals():
+    assert xevents.subtract([(0, 10)], []) == 10
+    assert xevents.subtract([(0, 10)], [(2, 4), (6, 7)]) == 7
+    assert xevents.subtract([(0, 4), (8, 12)], [(3, 9)]) == 6
+    assert xevents.subtract([(5, 6)], [(0, 10)]) == 0
+
+
+def test_collective_exposed_on_hand_made_events():
+    """A synchronous all-reduce alone on the core is all exposed; an
+    asynchronous one is exposed only where nothing runs beside it."""
+    ar = "%all-reduce.1 = bf16[8,5120]{1,0} all-reduce(bf16[8,5120] %x)"
+    st = "%all-gather-start.2 = (f32[8], f32[32]) all-gather-start(f32[8] %y)"
+    dn = "%all-gather-done.5 = f32[32] all-gather-done(%all-gather-start.2)"
+    ops = [("%while.1 = (s32[]) while(%t)", 0, 100),
+           ("%fusion.1 = bf16[8] fusion(%a)", 0, 10),
+           (ar, 10, 30),                       # 20 exposed
+           ("%fusion.2 = bf16[8] fusion(%b)", 30, 40),
+           (st, 40, 41),
+           ("%fusion.3 = bf16[8] fusion(%c)", 41, 50),
+           (dn, 50, 60)]                       # 40..60 less 40..50 = 10
+    ev = xevents.Events(ops=[ops], async_ops=[[]])
+    assert xevents.collective_exposed_ns(ev) == 30
+    assert xevents.collective_exposed_ns(xevents.Events()) is None
+    # The same asynchronous pair seen on the Async XLA Ops line alone: the
+    # instant in which the core issued it (40..41) now holds nothing else.
+    ev = xevents.Events(ops=[[o for o in ops if o[0] not in (st, dn)]],
+                        async_ops=[[(st, 40, 60)]])
+    assert xevents.collective_exposed_ns(ev) == 31
+    reader = measure.load_reader("mesh.collective_exposed_pct")
+    assert reader.read(_ctx({}, {}, chips=4, trace=None)) is None
+
+
+def test_recorded_chip_trace_has_names_and_engine_spans():
+    ev = xevents.load(CHIP_TRACE)
+    assert len(ev.ops) == 1 and len(ev.ops[0]) > 500
+    programs = {name.split("(")[0] for name, _, _ in ev.modules}
+    assert {"jit_step_decode_b8_n64", "jit_step_mixed_b8_t256_n64"} <= programs
+    assert any(xevents.instruction(hlo).startswith("%paged_attention")
+               for hlo, _, _ in ev.ops[0])
+    spans = {name for name, *_ in ev.host}
+    assert {"engine.plan", "engine.dispatch", "engine.finalize.wait",
+            "engine.finalize.host", "engine.record", "engine.post"} <= spans
+    dispatch = next(a for n, _, _, a in ev.host if n == "engine.dispatch")
+    assert {"kind", "b", "t", "nblk", "rows"} <= set(dispatch)
+    assert 0 < ev.busy_ns() <= max(e for _, _, e in ev.ops[0])
+
+
+def test_trace_readers_on_the_recorded_chip_trace(monkeypatch):
+    monkeypatch.setattr(xevents, "newest_xplane", lambda *a, **k: CHIP_TRACE)
+    ctx = _ctx(*_counters(), trace={"busy_s": 0.2, "window_s": 0.25})
+    read = lambda name: measure.load_reader(name).read(ctx)
+    assert 5.0 < read("engine.decode_step_dev_ms") < 100.0
+    copy, attn = read("device.cache_copy_pct"), read("device.attention_pct")
+    assert 20.0 < copy < 80.0 and 0.0 < attn < 40.0 and copy + attn < 100.0
+    assert read("engine.mixed_step_dev_ms") > \
+        2 * read("engine.decode_step_dev_ms")
+    assert read("mesh.collective_exposed_pct") is None       # one chip
+    # Another pool size: nothing in this trace has that shape.
+    ctx.counters[1]["kv_cache_shape"] = [10, 999, 16, 8, 128]
+    assert read("device.cache_copy_pct") == 0.0
+
+
+def test_cpu_trace_has_no_device_plane(monkeypatch):
+    monkeypatch.setattr(xevents, "newest_xplane", lambda *a, **k: CPU_TRACE)
+    ev = xevents.current()
+    assert ev.ops == [] and ev.modules == []
+    ctx = _ctx(*_counters(), trace=None)
+    for name in NEW_TRACE:
+        assert measure.load_reader(name).read(ctx) is None, name
+
+
+def test_newest_xplane_takes_only_this_runs_trace(tmp_path):
+    assert xevents.newest_xplane(tmp_path) is None
+    old = tmp_path / "a" / "old.xplane.pb"
+    new = tmp_path / "b" / "plugins" / "new.xplane.pb"
+    for p in (old, new):
+        p.parent.mkdir(parents=True)
+        p.write_bytes(b"")
+    import os
+    os.utime(old, (1000.0, 1000.0))
+    assert xevents.newest_xplane(tmp_path, since=2000.0) == new
+    assert xevents.newest_xplane(tmp_path, since=0.0) == new
+    assert xevents.process_start() > 0

@@ -36,6 +36,11 @@ NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 PROVIDER_METRICS = {
     "engine": (
         "kv_cache_bytes", "kv_quant_enabled",
+        # A list (the per-device cache shape): in stats(), not a gauge.
+        "kv_cache_shape",
+        # Time to first token in parts, summed over sequences (engine.py
+        # EngineMetrics): means are deltas over deltas of ttft_count.
+        "ttft_count", "ttft_inbox_s", "ttft_queue_s", "ttft_prefill_s",
         "num_waiting", "num_running", "kv_usage", "kv_total_blocks",
         "num_steps", "prefill_tokens", "decode_tokens",
         "requests_finished", "preemptions", "prefix_hit_rate",
@@ -66,6 +71,8 @@ PERF_METRICS = (
     "engine_perf_model_flops_total",
     "engine_perf_hbm_bytes_total",
     "engine_perf_step_seconds",
+    # Seconds per phase of the engine thread's loop (LoopClock.publish).
+    "engine_loop_seconds_total",
 )
 
 # Label sets of the perf family's labelled series — the dashboard-facing
@@ -75,6 +82,7 @@ PERF_METRICS = (
 # a label silently breaks every PromQL ``by (label)`` aggregation).
 PERF_METRIC_LABELS = {
     "engine_perf_tokens_per_second": ("kind", "kv_dtype"),
+    "engine_loop_seconds_total": ("phase",),
 }
 
 # The fleet-wide prefix cache family (kvbm/metrics.py PrefixCacheMetrics):
