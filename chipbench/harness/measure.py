@@ -3,6 +3,15 @@
 End-to-end metrics are computed here, over all requests due in the window
 and all the window's time; per-layer metrics are read by the small readers
 under ``chipbench/layers/``, one file each, found by the metric's name.
+
+``tokens_per_s`` is the output tokens of the requests due in the window
+over the time they took: from the window's start to the last of their
+deltas or the window's end, whichever is later, per chip. That is the
+convention of the field's serving benchmarks (vLLM ``benchmark_serving.py``,
+genai-perf: output tokens over the time to the last completion); the floor
+at the window's end keeps it at or under the offered load. The count it
+replaced (PR 27), every delta of any request that arrived inside the
+window's edges, stays beside it unlisted as ``tokens_in_window_per_s``.
 """
 
 from __future__ import annotations
@@ -48,15 +57,23 @@ class Context:
 
 def end_to_end(ctx: Context, setup_s: float) -> dict[str, float]:
     """Every end-to-end metric the benchmark knows; the caller reports the
-    ones its cell lists."""
+    ones its cell lists (``ttft_p50_ms``, ``ttft_p90_ms`` and
+    ``tokens_in_window_per_s`` are listed by none: they go to the log)."""
     lo, hi = ctx.window
     due = ctx.due_in_window
     ttft = [(r.first_token - r.due) * 1e3 for r in due
             if r.first_token is not None]
     gaps = [g * 1e3 for r in due for g in token_gaps(r.deltas)]
-    tokens = sum(k for r in ctx.records for t, k in r.deltas if lo <= t < hi)
+    # A record holds the deltas that arrived before the run's cut (window's
+    # end + drain_s, where run_schedule cancels), so a request cut there
+    # counts what it delivered, over the stretch to its last delta.
+    own = [(t, k) for r in due for t, k in r.deltas]
+    took = max([hi] + [t for t, _ in own]) - lo
+    in_window = sum(k for r in ctx.records for t, k in r.deltas
+                    if lo <= t < hi)
     out = {"setup_s": setup_s,
-           "tokens_per_s": tokens / ctx.seconds / ctx.chips}
+           "tokens_per_s": sum(k for _, k in own) / took / ctx.chips,
+           "tokens_in_window_per_s": in_window / ctx.seconds / ctx.chips}
     if ttft:
         out["ttft_mean_ms"] = sum(ttft) / len(ttft)
         out["ttft_p50_ms"] = percentile(ttft, 50)

@@ -5,12 +5,14 @@
 Builds the engine once and offers the cell's traffic in turn at each rate
 (in the cell's own order), then at the cell's own rate in each other order
 (ramp, window, drain between passes; other token ids in every pass). A rate
-kept up when the output tokens that arrived in its window are at least 0.97
-of those offered, no more than 2 requests are waiting at its end, and none
-failed: one criterion. With some tens of requests a window it swings with
-what is in flight at the window's edges (a pass of 12 requests read 0.79 at
-a third of the knee), so a knee is the highest rate that kept up and is
-known only to the bracket up to the lowest rate above it that did not; the
+kept up when the output tokens that arrived in its window, over all chips
+(``tokens_in_window_per_s``, the count the recorded knees were found with;
+not the cell's ``tokens_per_s``), are at least 0.97 of those offered, no
+more than 2 requests are waiting at its end, and none failed: one
+criterion. With some tens of requests a window it swings with what is in
+flight at the window's edges (a pass of 12 requests read 0.79 at a third of
+the knee), so a knee is the highest rate that kept up and is known only to
+the bracket up to the lowest rate above it that did not; the
 cell's ``rate_from`` records the bracket. The table goes to
 ``chipbench/out/sweeps/<name>.json`` (copy it to ``chipbench/sweeps/``); the
 cell's ``rate_per_s`` is 0.8 x the knee, written into its workload file as
@@ -37,6 +39,13 @@ KEPT_UP_SHARE = 0.97
 KEPT_UP_WAITING = 2
 
 
+def kept_up_share(e2e: dict, offered: float, chips: int) -> float:
+    """The output tokens that arrived in the window, over all chips, as a
+    share of those the window offered: the count the recorded knees were
+    found with (``tokens_in_window_per_s`` is a chip's, ``offered`` is not)."""
+    return e2e["tokens_in_window_per_s"] * chips / offered
+
+
 async def _sweep(cell, sut, passes, seconds, seed, log) -> list[dict]:
     rows = []
     for i, (rate, order) in enumerate(passes):
@@ -51,7 +60,8 @@ async def _sweep(cell, sut, passes, seconds, seed, log) -> list[dict]:
                "requests": len(ctx.due_in_window),
                "offered_tokens_per_s": offered,
                "tokens_per_s": e2e["tokens_per_s"],
-               "share": e2e["tokens_per_s"] / offered,
+               "tokens_in_window_per_s": e2e["tokens_in_window_per_s"],
+               "share": kept_up_share(e2e, offered, ctx.chips),
                "waiting_at_end": waiting,
                "running_at_end": ctx.counters[1]["num_running"],
                "in_flight_max": max(ctx.in_flight, default=0),
