@@ -172,6 +172,15 @@ class EngineMetrics:
     # by tp (set once): what a reader of a device trace looks for to find
     # the operations that move the cache.
     kv_cache_shape: tuple[int, ...] = ()
+    # The pool as sized (set once): its blocks, one block's bytes on each
+    # device (K and V), and the bytes a step holds for each block of the
+    # pool BEYOND the pool itself, from XLA's buffer assignment of the
+    # widest step (ModelRunner._fit_pool; None where the pool was given and
+    # nothing was probed). ~0 says the step updates the cache in place; a
+    # block's worth or more says it copies the pool.
+    kv_pool_blocks: int = 0
+    kv_block_bytes: int = 0
+    kv_step_copy_bytes_per_block: float | None = None
     # Time to first token in parts, summed over the sequences whose first
     # token has been posted (each once, a re-prefilled one too): arrival at
     # generate() -> add_request (the inbox), arrival -> the first plan that
@@ -187,6 +196,9 @@ class EngineMetrics:
             "kv_cache_bytes": self.kv_cache_bytes,
             "kv_quant_enabled": self.kv_quant_enabled,
             "kv_cache_shape": list(self.kv_cache_shape),
+            "kv_pool_blocks": self.kv_pool_blocks,
+            "kv_block_bytes": self.kv_block_bytes,
+            "kv_step_copy_bytes_per_block": self.kv_step_copy_bytes_per_block,
             "ttft_count": self.ttft_count,
             "ttft_inbox_s": self.ttft_inbox_s,
             "ttft_queue_s": self.ttft_queue_s,
@@ -351,6 +363,9 @@ class ModelRunner:
         self.spec = KVCacheSpec.for_model(
             cfg, engine_cfg.num_blocks or 1, engine_cfg.block_size,
             kv_dtype=engine_cfg.kv_dtype)
+        # What _fit_pool measured (stats()["kv_step_copy_bytes_per_block"]);
+        # None while nothing was probed: a given pool, the CPU backend.
+        self.step_copy_bytes_per_block: float | None = None
         if not engine_cfg.num_blocks:
             self.spec = dataclasses.replace(
                 self.spec, num_blocks=self._auto_num_blocks())
@@ -480,15 +495,18 @@ class ModelRunner:
         """The largest pool with which the widest reachable step takes no
         more than ``budget`` bytes of a device beyond what is resident.
 
-        A block costs more than its own bytes: the cache rides the layer
-        scan as xs→ys, so a step holds a second whole copy of K and V as a
-        temporary, next to activations that grow with the bucket. Neither
-        is assumed here. The widest bucket is lowered against an abstract
-        cache at two pool sizes and compiled; XLA's own buffer assignment
-        gives the bytes per block (the block plus its copies) and the bytes
-        that do not depend on the pool (activations), per device. A later
-        change to how the cache passes through the step changes the pool
-        with it."""
+        What a block costs inside a step is measured, not assumed. The
+        widest bucket is lowered against an abstract cache at two pool
+        sizes and compiled; XLA's own buffer assignment gives the bytes per
+        block (the block plus whatever copies of it the step holds) and the
+        bytes that do not depend on the pool (activations, which grow with
+        the bucket), per device. The cache is donated and carried whole
+        through the layer loop, written and read in place (models/llama.py
+        ``_run_layers``), so the copies are 0 B a block and a block costs
+        its own bytes (a step that holds a second K and V pays 2.5x that:
+        PERF.md section 6). The copies measured here go to ``stats()`` as
+        ``kv_step_copy_bytes_per_block``: a change to how the cache passes
+        through the step shows there, and changes the pool with it."""
         ec = self.engine_cfg
         sig = self._widest_bucket()
         t0 = time.perf_counter()
@@ -516,6 +534,7 @@ class ModelRunner:
         copies = (extra1 - extra0) / (n1 - n0)
         fixed = extra0 - copies * n0
         n = int((budget - fixed) // per_block)
+        self.step_copy_bytes_per_block = copies
         log.info(
             "kv pool sizing: widest bucket B=%d T=%d NBLK=%d needs %.2f GB "
             "beside the pool and %.0f B a block on each device (%.0f B the "
@@ -1399,6 +1418,9 @@ class EngineCore:
             kv_quant_enabled=self.runner.spec.quantized,
             kv_cache_shape=tuple(
                 payload.sharding.shard_shape(payload.shape)),
+            kv_pool_blocks=self.runner.spec.num_blocks,
+            kv_block_bytes=self.runner._block_bytes_per_device(),
+            kv_step_copy_bytes_per_block=self.runner.step_copy_bytes_per_block,
         )
         # The engine thread's loop phases (obs/profiler.py loop_phase):
         # always-on seconds per phase, and profiler spans at the same

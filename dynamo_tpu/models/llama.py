@@ -8,8 +8,9 @@ vLLM/SGLang/TRT-LLM (SURVEY.md §7: first-party JAX engine). Design points:
   KV cache addressed by per-request block tables. Static shapes per
   (batch-bucket, T-bucket) so XLA compiles once per bucket.
 - **Layers are scanned** (``lax.scan`` over stacked layer params) so 80-layer
-  models trace/compile in constant time, with the per-layer KV cache slices
-  threaded through the scan.
+  models trace/compile in constant time. The whole KV cache is the scan's
+  carry, written and read in place at (layer, block): no layer of it is
+  ever cut out (``_run_layers``).
 - **Paged attention via gather** in the portable path: context KV is gathered
   from cache blocks by block table then attended densely with position
   masking (XLA fuses this well); a Pallas kernel (ops/) replaces it on TPU.
@@ -160,26 +161,39 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 _KV_SCALE_EPS = 1e-8
 
 
-def _scatter_kv(cache, new: jax.Array, slot_idx: jax.Array):
-    """Write new KV [B,T,KH,D] into paged cache [NB,BS,KH,D] at flat slots.
+def _as_layers(cache):
+    """A single layer's cache [NB,...] seen as a one-layer stack [1,NB,...]
+    (a free reshape), so the helpers below have one form: the whole cache
+    and a layer index."""
+    return jax.tree.map(lambda a: a[None], cache)
+
+
+def _scatter_kv(cache, new: jax.Array, slot_idx: jax.Array, layer=None):
+    """Write new KV [B,T,KH,D] into layer ``layer`` of the paged cache
+    [L,NB,BS,KH,D] at flat slots, and return the whole cache: one scatter
+    on the buffer itself, which XLA does in place where the buffer is
+    donated and loop-carried. No layer of it is cut out and put back.
+    ``layer=None`` takes a single layer [NB,BS,KH,D] and returns one.
 
     slot_idx: [B,T] flat slot index (block*block_size + offset); padding
     tokens point at the trash block (block 0).
 
-    Quantized caches ({"q": int8 [NB,BS,KH,D], "s": f32 [NB,KH]}) quantize
-    at scatter time, symmetric per-block-per-head (engine/cache.py).
+    Quantized caches ({"q": int8 [L,NB,BS,KH,D], "s": f32 [L,NB,KH]})
+    quantize at scatter time, symmetric per-block-per-head (engine/cache.py).
     """
+    if layer is None:
+        out = _scatter_kv(_as_layers(cache), new, slot_idx, 0)
+        return jax.tree.map(lambda a: a[0], out)
     if isinstance(cache, dict):
-        return _scatter_kv_quant(cache, new, slot_idx)
-    nb, bs, kh, d = cache.shape
-    flat = cache.reshape(nb * bs, kh, d)
+        return _scatter_kv_quant(cache, new, slot_idx, layer)
+    _, _, bs, kh, d = cache.shape
     idx = slot_idx.reshape(-1)
     vals = new.reshape(-1, kh, d)
-    flat = flat.at[idx].set(vals, mode="drop")
-    return flat.reshape(nb, bs, kh, d)
+    return cache.at[layer, idx // bs, idx % bs].set(vals, mode="drop")
 
 
-def _scatter_kv_quant(cache: dict, new: jax.Array, slot_idx: jax.Array) -> dict:
+def _scatter_kv_quant(cache: dict, new: jax.Array, slot_idx: jax.Array,
+                      layer) -> dict:
     """Int8/int4 scatter: abs-max over the block update sets/merges the
     block's per-head scale, existing rows of touched blocks are rescaled to
     the new scale, then the new rows are quantized and written.
@@ -194,13 +208,18 @@ def _scatter_kv_quant(cache: dict, new: jax.Array, slot_idx: jax.Array) -> dict:
     ±7 and pack two nibbles per byte along head_dim; the scale lifecycle
     (reset / max-merge / requant of committed rows) is identical — requant
     unpacks, rescales, and repacks the touched blocks.
+
+    Like the plain scatter it works on the whole cache: the touched blocks
+    are read and written at ``(layer, blk)``, and only the layer's scales
+    ([NB,KH], small) are taken out and put back.
     """
     from dynamo_tpu.ops.paged_attention import pack_int4, unpack_int4
 
-    q, s = cache["q"], cache["s"]
+    q, s_all = cache["q"], cache["s"]
+    s = s_all[layer]                                             # [NB,KH]
     int4 = q.dtype == jnp.uint8
     qmax = 7.0 if int4 else 127.0
-    nb, bs, kh, _dp = q.shape
+    _, nb, bs, kh, _dp = q.shape
     d = new.shape[-1]
     idx = slot_idx.reshape(-1)                                   # [N]
     vals = new.reshape(-1, kh, d).astype(jnp.float32)            # [N,KH,D]
@@ -219,39 +238,41 @@ def _scatter_kv_quant(cache: dict, new: jax.Array, slot_idx: jax.Array) -> dict:
     # token row (duplicates write identical values) keeps shapes static; cost
     # is bounded by (tokens-in-update × block_size), not by NB.
     ratio = jnp.where(s_new > 0, s / jnp.maximum(s_new, _KV_SCALE_EPS), 0.0)
-    old = q[blk]                                                 # [N,BS,KH,Dp]
+    old = q[layer, blk]                                          # [N,BS,KH,Dp]
     old = (unpack_int4(old) if int4 else old).astype(jnp.float32)  # [N,BS,KH,D]
     requant = jnp.clip(jnp.round(old * ratio[blk][:, None, :, None]),
                        -qmax, qmax).astype(jnp.int32)
     requant = pack_int4(requant) if int4 else requant.astype(jnp.int8)
-    q = q.at[blk].set(requant, mode="drop")
+    q = q.at[layer, blk].set(requant, mode="drop")
 
     # Quantize and write the new rows (overwrites the rescaled slots).
     s_rows = jnp.maximum(s_new[blk], _KV_SCALE_EPS)              # [N,KH]
     q_rows = jnp.clip(jnp.round(vals / s_rows[:, :, None]), -qmax, qmax)
     q_rows = (pack_int4(q_rows.astype(jnp.int32)) if int4
               else q_rows.astype(jnp.int8))
-    flat = q.reshape(nb * bs, kh, -1)
-    flat = flat.at[idx].set(q_rows, mode="drop")
-    return {"q": flat.reshape(q.shape), "s": s_new}
+    q = q.at[layer, idx // bs, off].set(q_rows, mode="drop")
+    return {"q": q, "s": s_all.at[layer].set(s_new)}
 
 
-def _gather_kv(cache, block_tables: jax.Array) -> jax.Array:
-    """Gather context KV: cache [NB,BS,KH,D], block_tables [B,NBLK] →
-    [B, NBLK*BS, KH, D] laid out in position order. Quantized caches are
-    dequantized on gather (dense fallback path); packed-int4 payloads
-    (uint8) unpack their nibbles first."""
+def _gather_kv(cache, block_tables: jax.Array, layer=None) -> jax.Array:
+    """Gather context KV of layer ``layer``: cache [L,NB,BS,KH,D],
+    block_tables [B,NBLK] → [B, NBLK*BS, KH, D] laid out in position order,
+    one gather at ``(layer, block)`` of the whole cache. ``layer=None``
+    takes a single layer [NB,BS,KH,D]. Quantized caches are dequantized on
+    gather (dense fallback path); packed-int4 payloads (uint8) unpack their
+    nibbles first."""
+    if layer is None:
+        return _gather_kv(_as_layers(cache), block_tables, 0)
     if isinstance(cache, dict):
         from dynamo_tpu.ops.paged_attention import unpack_int4
 
-        g = cache["q"][block_tables]                      # [B,NBLK,BS,KH,Dp]
+        g = cache["q"][layer, block_tables]               # [B,NBLK,BS,KH,Dp]
         if g.dtype == jnp.uint8:
             g = unpack_int4(g)
         g = g.astype(jnp.float32)
-        g = g * cache["s"][block_tables][:, :, None, :, None]
-        b, nblk, bs, kh, d = g.shape
-        return g.reshape(b, nblk * bs, kh, d)
-    g = cache[block_tables]  # [B, NBLK, BS, KH, D]
+        g = g * cache["s"][layer, block_tables][:, :, None, :, None]
+    else:
+        g = cache[layer, block_tables]                    # [B,NBLK,BS,KH,D]
     b, nblk, bs, kh, d = g.shape
     return g.reshape(b, nblk * bs, kh, d)
 
@@ -341,6 +362,101 @@ def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
 # Forward
 # ---------------------------------------------------------------------------
 
+def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
+           positions, slot, block_tables, q_start, kv_lens,
+           attn_impl: str = "dense", attn_num_splits: int = 0,
+           moe_impl: str = "dense", mesh=None, use_ring: bool = False):
+    """One transformer layer over the WHOLE cache ([L,NB,BS,KH,D], or the
+    stage-local part of it under pp): writes this step's K/V at
+    ``(layer, slot)``, attends over layer ``layer``, returns
+    (hidden, cache_k, cache_v). The one layer body of ``forward`` and of
+    both pp schedules. Nothing here materialises a layer of the cache: the
+    scatter, the kernel's DMAs and the dense gather all address the carried
+    buffer by layer index."""
+    b, t = positions.shape
+    tp = mesh.shape.get("model", 1) if mesh is not None else 1
+    x = rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
+    q = mm(x, lp["wq"]).reshape(b, t, cfg.num_heads, cfg.head_dim)
+    k = mm(x, lp["wk"]).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+    v = mm(x, lp["wv"]).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+    q = rope(q, positions, cfg.rope_theta)
+    k = rope(k, positions, cfg.rope_theta)
+    # Phase hooks (obs/profiler.py): jax.named_scope annotations for
+    # XLA profiles, plus wall capture in eager profiling runs. Under
+    # jit they execute at trace time only — zero ops in the program.
+    with _perf_phase("scatter"):
+        cache_k = _scatter_kv(cache_k, k, slot, layer)
+        cache_v = _scatter_kv(cache_v, v, slot, layer)
+    if use_ring:
+        from dynamo_tpu.ops.ring_attention import ring_attention_prefill
+
+        with _perf_phase("attention"):
+            attn = ring_attention_prefill(mesh, q, k, v, kv_lens)
+    elif attn_impl in ("pallas", "pallas_interpret"):
+        from dynamo_tpu.ops.paged_attention import (
+            paged_attention_kernel,
+            paged_attention_sharded,
+        )
+
+        interp = attn_impl == "pallas_interpret"
+        with _perf_phase("attention"):
+            if tp > 1:
+                # TP: shard_map the kernel over the head axis; GSPMD's
+                # psum in the wo projection completes the TP contraction.
+                attn = paged_attention_sharded(
+                    mesh, q, cache_k, cache_v, block_tables, q_start,
+                    kv_lens, layer=layer, num_splits=attn_num_splits,
+                    interpret=interp,
+                )
+            else:
+                attn = paged_attention_kernel(
+                    q, cache_k, cache_v, block_tables, q_start, kv_lens,
+                    layer=layer, num_splits=attn_num_splits, interpret=interp,
+                )
+    else:
+        with _perf_phase("gather"):
+            ctx_k = _gather_kv(cache_k, block_tables, layer)
+            ctx_v = _gather_kv(cache_v, block_tables, layer)
+        with _perf_phase("attention"):
+            attn = paged_attention(q, ctx_k, ctx_v, positions, kv_lens)
+    hid = hid + mm(attn.reshape(b, t, cfg.q_size), lp["wo"])
+    x = rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
+    if cfg.is_moe:
+        if moe_impl == "ep":
+            # Dropless ragged dispatch (serving default for ep>1): exact
+            # under any routing skew — see models/moe.py.
+            from dynamo_tpu.models.moe import moe_mlp_dropless
+
+            mlp_out = moe_mlp_dropless(x, lp, cfg, mesh=mesh)
+        elif moe_impl == "ep_capacity":
+            from dynamo_tpu.models.moe import moe_mlp_ep
+
+            mlp_out = moe_mlp_ep(x, lp, cfg)
+        else:
+            mlp_out = moe_mlp(x, lp, cfg)
+    else:
+        mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+    return hid + mlp_out, cache_k, cache_v
+
+
+def _run_layers(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, **kw):
+    """Scan :func:`_layer` over the stacked layer params. The cache enters
+    the loop once, whole, as carried state beside the hidden state; only
+    the params and the layer index ride xs. (The cache must not ride xs→ys:
+    XLA then cuts each layer out, stacks it back and keeps a second K and V
+    as a temporary, which cost over half of a decode step on the v5e —
+    PERF.md section 6.)"""
+    n = jax.tree.leaves(layers)[0].shape[0]
+
+    def layer_fn(carry, xs):
+        lp, layer = xs
+        return _layer(cfg, lp, layer, *carry, **kw), None
+
+    carry, _ = lax.scan(layer_fn, (h, cache_k, cache_v),
+                        (layers, jnp.arange(n, dtype=jnp.int32)))
+    return carry
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
@@ -424,74 +540,11 @@ def forward(
         # bookkeeping — see preprocessor digest-salted placeholders).
         h = jnp.where(embed_mask[..., None], embed_override.astype(h.dtype), h)
 
-    def layer_fn(carry, xs):
-        hid = carry
-        lp, ck, cv = xs
-        x = rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
-        q = mm(x, lp["wq"]).reshape(b, t, cfg.num_heads, cfg.head_dim)
-        k = mm(x, lp["wk"]).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-        v = mm(x, lp["wv"]).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        # Phase hooks (obs/profiler.py): jax.named_scope annotations for
-        # XLA profiles, plus wall capture in eager profiling runs. Under
-        # jit they execute at trace time only — zero ops in the program.
-        with _perf_phase("scatter"):
-            ck = _scatter_kv(ck, k, slot)
-            cv = _scatter_kv(cv, v, slot)
-        if use_ring:
-            from dynamo_tpu.ops.ring_attention import ring_attention_prefill
-
-            with _perf_phase("attention"):
-                attn = ring_attention_prefill(mesh, q, k, v, kv_lens)
-        elif attn_impl in ("pallas", "pallas_interpret"):
-            from dynamo_tpu.ops.paged_attention import (
-                paged_attention_kernel,
-                paged_attention_sharded,
-            )
-
-            interp = attn_impl == "pallas_interpret"
-            with _perf_phase("attention"):
-                if tp > 1:
-                    # TP: shard_map the kernel over the head axis; GSPMD's
-                    # psum in the wo projection completes the TP contraction.
-                    attn = paged_attention_sharded(
-                        mesh, q, ck, cv, block_tables, q_start, kv_lens,
-                        num_splits=attn_num_splits, interpret=interp,
-                    )
-                else:
-                    attn = paged_attention_kernel(
-                        q, ck, cv, block_tables, q_start, kv_lens,
-                        num_splits=attn_num_splits, interpret=interp,
-                    )
-        else:
-            with _perf_phase("gather"):
-                ctx_k = _gather_kv(ck, block_tables)
-                ctx_v = _gather_kv(cv, block_tables)
-            with _perf_phase("attention"):
-                attn = paged_attention(q, ctx_k, ctx_v, positions, kv_lens)
-        attn = mm(attn.reshape(b, t, cfg.q_size), lp["wo"])
-        hid = hid + attn
-        x = rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
-        if cfg.is_moe:
-            if moe_impl == "ep":
-                # Dropless ragged dispatch (serving default for ep>1): exact
-                # under any routing skew — see models/moe.py.
-                from dynamo_tpu.models.moe import moe_mlp_dropless
-
-                mlp_out = moe_mlp_dropless(x, lp, cfg, mesh=mesh)
-            elif moe_impl == "ep_capacity":
-                from dynamo_tpu.models.moe import moe_mlp_ep
-
-                mlp_out = moe_mlp_ep(x, lp, cfg)
-            else:
-                mlp_out = moe_mlp(x, lp, cfg)
-        else:
-            mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-        hid = hid + mlp_out
-        return hid, (ck, cv)
-
-    h, (cache_k, cache_v) = lax.scan(layer_fn, h, (params["layers"], cache_k, cache_v))
+    h, cache_k, cache_v = _run_layers(
+        cfg, params["layers"], h, cache_k, cache_v, positions=positions,
+        slot=slot, block_tables=block_tables, q_start=q_start,
+        kv_lens=kv_lens, attn_impl=attn_impl, attn_num_splits=attn_num_splits,
+        moe_impl=moe_impl, mesh=mesh, use_ring=use_ring)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
 
     if return_all_hidden:
@@ -614,9 +667,10 @@ def forward_pp(
             # and the output contribution is masked.
             slot_t = jnp.where(live, slot_mb[mbc], 0)
             h_in = jnp.where(s == 0, h0_mb[mbc], h_cur)
-            h_out, ck, cv = _pp_stage_block(
-                cfg, lp_stack, ck, cv, h_in, pos_mb[mbc], slot_t, bt_mb[mbc],
-                kl_mb[mbc], attn_impl=attn_impl, q_start=qs_mb[mbc],
+            h_out, ck, cv = _run_layers(
+                cfg, lp_stack, h_in, ck, cv, positions=pos_mb[mbc],
+                slot=slot_t, block_tables=bt_mb[mbc], q_start=qs_mb[mbc],
+                kv_lens=kl_mb[mbc], attn_impl=attn_impl,
                 attn_num_splits=attn_num_splits)
             out = out.at[mbc].add(jnp.where((s == pp - 1) & live, h_out, 0))
             h_nxt = lax.ppermute(
@@ -643,49 +697,6 @@ def forward_pp(
     return last_h, cache_k, cache_v
 
 
-def _pp_stage_block(cfg, lp_stack, ck_loc, cv_loc, h, pos, slot, bt, kv_lens,
-                    attn_impl="dense", q_start=None, attn_num_splits=0):
-    """One pipeline stage's layer block — the shared layer math of BOTH pp
-    schedules (microbatched and sequential fallback): same per-layer flow
-    as forward's layer_fn, attention over the stage's local cache slice.
-    ``q_start`` is only needed by the Pallas kernel path."""
-    b_, t_ = pos.shape
-
-    def layer_fn(carry, xs):
-        hid = carry
-        lp, ck, cv = xs
-        x = rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
-        q = mm(x, lp["wq"]).reshape(b_, t_, cfg.num_heads, cfg.head_dim)
-        k = mm(x, lp["wk"]).reshape(b_, t_, cfg.num_kv_heads, cfg.head_dim)
-        v = mm(x, lp["wv"]).reshape(b_, t_, cfg.num_kv_heads, cfg.head_dim)
-        q = rope(q, pos, cfg.rope_theta)
-        k = rope(k, pos, cfg.rope_theta)
-        ck = _scatter_kv(ck, k, slot)
-        cv = _scatter_kv(cv, v, slot)
-        if attn_impl in ("pallas", "pallas_interpret"):
-            from dynamo_tpu.ops.paged_attention import paged_attention_kernel
-
-            attn = paged_attention_kernel(
-                q, ck, cv, bt, q_start, kv_lens,
-                num_splits=attn_num_splits,
-                interpret=(attn_impl == "pallas_interpret"))
-        else:
-            ctx_k = _gather_kv(ck, bt)
-            ctx_v = _gather_kv(cv, bt)
-            attn = paged_attention(q, ctx_k, ctx_v, pos, kv_lens)
-        hid = hid + mm(attn.reshape(b_, t_, cfg.q_size), lp["wo"])
-        x = rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
-        if cfg.is_moe:
-            mlp_out = moe_mlp(x, lp, cfg)
-        else:
-            mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-        hid = hid + mlp_out
-        return hid, (ck, cv)
-
-    h, (ck_loc, cv_loc) = lax.scan(layer_fn, h, (lp_stack, ck_loc, cv_loc))
-    return h, ck_loc, cv_loc
-
-
 def _forward_pp_sequential(params, cfg, positions, kv_lens, slot, block_tables,
                            cache_k, cache_v, mesh, h0, q_len, pp):
     """Fallback pipeline for shapes too small to microbatch (e.g. a lone
@@ -699,9 +710,10 @@ def _forward_pp_sequential(params, cfg, positions, kv_lens, slot, block_tables,
     def pp_fn(lp_stack, ck_local, cv_local, h):
         s = lax.axis_index("pipe")
         for i in range(pp):
-            h_out, ck_new, cv_new = _pp_stage_block(
-                cfg, lp_stack, ck_local, cv_local, h, positions, slot,
-                block_tables, kv_lens)
+            h_out, ck_new, cv_new = _run_layers(
+                cfg, lp_stack, h, ck_local, cv_local, positions=positions,
+                slot=slot, block_tables=block_tables, q_start=None,
+                kv_lens=kv_lens)
             keep = s == i
             # tree_map: quantized caches are {"q","s"} pytrees.
             ck_local = jax.tree.map(lambda a, b: jnp.where(keep, a, b),

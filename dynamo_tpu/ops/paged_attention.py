@@ -36,6 +36,12 @@ inside vLLM/TRT-LLM, which we replace):
 - block tables + positions are scalar-prefetched (PrefetchScalarGridSpec)
   so the K/V BlockSpec index maps can address HBM blocks by table lookup —
   the DMA pipeline chases the page table, the kernel body never sees HBM.
+- the kernel takes the WHOLE cache ``[L, NB, BS, KH, Dp]`` and a layer
+  index (one more scalar-prefetch operand): the K/V index map is
+  ``(layer, table[b, j], 0, 0, 0)``, the same blocks from another base, so
+  the model's layer loop never cuts a layer out of the cache for it
+  (models/llama.py ``_layer``). A quantized pool's scales stay a per-layer
+  ``[NB, KH]`` operand: SMEM must not grow with L.
 - K/V blocks load ALL kv heads at once — block shape ``(1, BS, KH, Dp)``
   equals the array's trailing dims, which always satisfies Mosaic's tiling
   constraint (a per-head block ``(1, BS, 1, D)`` has a second-to-minor dim
@@ -89,14 +95,15 @@ def scalar_prefetch_bytes(*, batch: int, nblk: int, num_blocks: int = 0,
                           kv_heads: int = 0) -> int:
     """SMEM bytes of the kernel's scalar-prefetch operands. Each row of a
     2-D operand pads to whole 128-lane words (512 B): the ``[B, NBLK]``
-    block table, three ``[B]`` vectors and, for a quantized cache
-    (``num_blocks`` and ``kv_heads`` given), the two ``[NB, KH]`` float32
-    scale sidecars — which is what bounds a quantized pool to ~1,000
-    blocks, and the block table to ~4k blocks a row at 64 rows."""
+    block table, three ``[B]`` vectors, the ``[1]`` layer index and, for a
+    quantized cache (``num_blocks`` and ``kv_heads`` given), the two
+    ``[NB, KH]`` float32 scale sidecars of ONE layer — which is what bounds
+    a quantized pool to ~1,000 blocks, and the block table to ~4k blocks a
+    row at 64 rows."""
     def row(n: int) -> int:
         return -(-n // 128) * 512
 
-    return (batch * row(nblk) + 3 * row(batch)
+    return (batch * row(nblk) + 3 * row(batch) + row(1)
             + 2 * num_blocks * row(kv_heads))
 
 
@@ -180,16 +187,16 @@ def _kernel(*refs, bs: int, kh: int, rep: int, spb: int, quant: bool,
         # dequant needs no extra DMA: the int8/int4 block is widened
         # in-register and the per-(block, head) scale folds into the MXU
         # results.
-        (bt_ref, qs_ref, kl_ref, ub_ref, ks_ref, vs_ref, *refs) = refs
+        (bt_ref, qs_ref, kl_ref, ub_ref, ly_ref, ks_ref, vs_ref, *refs) = refs
     else:
-        (bt_ref, qs_ref, kl_ref, ub_ref, *refs) = refs
+        (bt_ref, qs_ref, kl_ref, ub_ref, ly_ref, *refs) = refs
         ks_ref = vs_ref = None
     if split:
         (q_ref, k_ref, v_ref, o_ref, mo_ref, lo_ref,
          acc_ref, m_ref, l_ref) = refs
     else:
         (q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref) = refs
-    del ub_ref  # consumed by the index maps (DMA clamp), not the body
+    del ub_ref, ly_ref  # consumed by the index maps (DMA clamp, layer), not the body
     b = pl.program_id(0)
     qi = pl.program_id(1)
     si = pl.program_id(2)
@@ -288,20 +295,38 @@ def _combine_splits(o_p: jax.Array, m_p: jax.Array, l_p: jax.Array,
     return (acc / l_tot).astype(out_dtype)
 
 
+def _layer_stack(k_cache, v_cache, layer):
+    """(k_cache, v_cache, int32 layer) with a leading layer axis: a single
+    layer's cache (``layer`` None) is a one-layer stack, a free reshape."""
+    if layer is None:
+        k_cache, v_cache = jax.tree.map(lambda a: a[None], (k_cache, v_cache))
+        layer = 0
+    return k_cache, v_cache, jnp.asarray(layer, jnp.int32)
+
+
 def paged_attention_kernel(
     q: jax.Array,             # [B, T, H, D]
-    k_cache,                  # [NB, BS, KH, D] — or {"q": int8 [NB,BS,KH,D]
-                              #   | uint8 packed int4 [NB,BS,KH,D/2],
-                              #   "s": f32 [NB, KH]}
+    k_cache,                  # [L, NB, BS, KH, D] — or {"q": int8
+                              #   [L,NB,BS,KH,D] | uint8 packed int4
+                              #   [L,NB,BS,KH,D/2], "s": f32 [L, NB, KH]}
     v_cache,
     block_tables: jax.Array,  # [B, NBLK] int32
     q_start: jax.Array,       # [B] int32 first query position
     kv_lens: jax.Array,       # [B] int32 valid context length
     *,
+    layer=None,               # int32 scalar (may be traced): which layer of
+                              #   the cache; None = the cache IS one layer,
+                              #   [NB, BS, KH, D]
     num_splits: int = 0,      # 0 = auto (cost model), 1 = sequential, N = forced
     interpret: bool = False,
 ) -> jax.Array:
-    """Flash paged attention over a block-table cache. Returns [B, T, H, D].
+    """Flash paged attention over layer ``layer`` of a block-table cache.
+    Returns [B, T, H, D].
+
+    The cache is only read, and only the blocks the tables name: the layer
+    index rides the scalar-prefetch channel into the K/V index maps, so a
+    caller that carries the whole cache through a loop hands it over as it
+    is, without cutting the layer out.
 
     Quantized caches (``{"q", "s"}`` — engine/cache.py) DMA int8 blocks
     (half the HBM bytes of bf16) or packed-int4 blocks (a quarter — uint8
@@ -314,15 +339,18 @@ def paged_attention_kernel(
     index maps so ragged batches skip DMA + compute past each row's real
     context.
     """
+    k_cache, v_cache, layer = _layer_stack(k_cache, v_cache, layer)
     quant = isinstance(k_cache, dict)
     int4 = False
     if quant:
-        k_scale = k_cache["s"].astype(jnp.float32)   # [NB, KH]
-        v_scale = v_cache["s"].astype(jnp.float32)
+        # The layer's scales, [NB, KH]: small, and what SMEM holds must not
+        # grow with L.
+        k_scale = k_cache["s"][layer].astype(jnp.float32)
+        v_scale = v_cache["s"][layer].astype(jnp.float32)
         k_cache, v_cache = k_cache["q"], v_cache["q"]
         int4 = k_cache.dtype == jnp.uint8            # packed marker dtype
     b, t, h, d = q.shape
-    nb, bs, kh, dp = k_cache.shape
+    _, nb, bs, kh, dp = k_cache.shape
     if int4 and dp * 2 != d:
         raise ValueError(
             f"packed int4 cache trailing dim {dp} != head_dim/2 ({d}//2)")
@@ -364,21 +392,21 @@ def paged_attention_kernel(
                            0, nblk)
 
     # Index maps see all scalar-prefetch refs after the grid indices
-    # (bt, q_start, kv_lens, used_blocks[, k_scale, v_scale]).
+    # (bt, q_start, kv_lens, used_blocks, layer[, k_scale, v_scale]).
     def qmap(bi, qi, si, jj, *_prefetch):
         return (bi, 0, qi, 0)
 
     def kvmap(bi, qi, si, jj, *prefetch):
-        bt, ub = prefetch[0], prefetch[3]
+        bt, ub, ly = prefetch[0], prefetch[3], prefetch[4]
         g = si * spb + jj
         clamped = jnp.minimum(g, jnp.maximum(ub[bi] - 1, 0))
-        return (bt[bi, clamped], 0, 0, 0)
+        return (ly[0], bt[bi, clamped], 0, 0, 0)
 
     def omap_split(bi, qi, si, jj, *_prefetch):
         return (bi, si, 0, qi, 0)
 
     scalars = (block_tables.astype(jnp.int32), q_start.astype(jnp.int32),
-               kv_lens.astype(jnp.int32), used_blocks)
+               kv_lens.astype(jnp.int32), used_blocks, layer.reshape(1))
     if quant:
         scalars = scalars + (k_scale, v_scale)
 
@@ -402,8 +430,9 @@ def paged_attention_kernel(
         grid=(b, nq, ns, spb),
         in_specs=[
             pl.BlockSpec((1, kh, rchunk, d), qmap),
-            pl.BlockSpec((1, bs, kh, dp), kvmap),
-            pl.BlockSpec((1, bs, kh, dp), kvmap),
+            # The layer axis is squeezed: the body sees (1, BS, KH, Dp).
+            pl.BlockSpec((None, 1, bs, kh, dp), kvmap),
+            pl.BlockSpec((None, 1, bs, kh, dp), kvmap),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -435,12 +464,13 @@ def paged_attention_kernel(
 def paged_attention_sharded(
     mesh,
     q: jax.Array,             # [B, T, H, D] — H sharded on "model"
-    k_cache,                  # [NB, BS, KH, D] (KH on "model") or {"q","s"}
+    k_cache,                  # [L, NB, BS, KH, D] (KH on "model") or {"q","s"}
     v_cache,
     block_tables: jax.Array,  # [B, NBLK]
     q_start: jax.Array,       # [B]
     kv_lens: jax.Array,       # [B]
     *,
+    layer=None,               # as paged_attention_kernel
     num_splits: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
@@ -451,15 +481,21 @@ def paged_attention_sharded(
 
     Batch rides the "data" axis (size-1 no-op on pure-TP meshes).
     """
-    cache_spec = P(None, None, "model", None)
+    k_cache, v_cache, layer = _layer_stack(k_cache, v_cache, layer)
+    cache_spec = P(None, None, None, "model", None)
     if isinstance(k_cache, dict):
         # Quantized cache pytree: payload sharded on kv_heads, scales on
         # their matching head axis — each shard dequantizes its own heads.
         # Packed-int4 payloads shard identically (packing is along D).
-        cache_spec = {"q": P(None, None, "model", None), "s": P(None, "model")}
+        cache_spec = {"q": cache_spec, "s": P(None, None, "model")}
+
+    def local(q, k_cache, v_cache, block_tables, q_start, kv_lens, layer):
+        return paged_attention_kernel(
+            q, k_cache, v_cache, block_tables, q_start, kv_lens, layer=layer,
+            num_splits=num_splits, interpret=interpret)
+
     fn = jax.shard_map(
-        functools.partial(paged_attention_kernel, num_splits=num_splits,
-                          interpret=interpret),
+        local,
         mesh=mesh,
         in_specs=(
             P("data", None, "model", None),
@@ -468,12 +504,13 @@ def paged_attention_sharded(
             P("data", None),
             P("data"),
             P("data"),
+            P(),
         ),
         out_specs=P("data", None, "model", None),
         check_vma=False,
     )
     return fn(q, k_cache, v_cache, block_tables.astype(jnp.int32),
-              q_start.astype(jnp.int32), kv_lens.astype(jnp.int32))
+              q_start.astype(jnp.int32), kv_lens.astype(jnp.int32), layer)
 
 
 def select_attn_impl(requested: str = "auto") -> str:

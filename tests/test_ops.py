@@ -160,6 +160,81 @@ def test_engine_pallas_interpret_matches_dense():
     assert run("dense") == run("pallas_interpret")
 
 
+# -- The whole cache and a layer index ------------------------------------------
+
+def _whole_cache(rng, kv, nl, nb, bs, kh, d):
+    """A random [L, NB, BS, KH, D] cache in storage format ``kv``."""
+    if kv == "bfloat16":
+        return jnp.asarray(rng.standard_normal((nl, nb, bs, kh, d)),
+                           jnp.bfloat16)
+    scales = jnp.asarray(rng.uniform(0.005, 0.02, (nl, nb, kh)), jnp.float32)
+    if kv == "int8":
+        payload = jnp.asarray(rng.integers(-127, 128, (nl, nb, bs, kh, d)),
+                              jnp.int8)
+    else:  # packed int4: any byte is two valid nibbles
+        payload = jnp.asarray(rng.integers(0, 256, (nl, nb, bs, kh, d // 2)),
+                              jnp.uint8)
+    return {"q": payload, "s": scales}
+
+
+def _spoil_other_layers(cache, layer):
+    """The same cache with every OTHER layer overwritten by garbage (NaN,
+    extreme payloads, infinite scales)."""
+    def spoil(a):
+        if a.dtype == jnp.float32:                      # scales
+            bad = jnp.inf
+        elif jnp.issubdtype(a.dtype, jnp.floating):
+            bad = jnp.nan
+        else:
+            bad = jnp.iinfo(a.dtype).max
+        keep = (jnp.arange(a.shape[0]) == layer).reshape(
+            (-1,) + (1,) * (a.ndim - 1))
+        return jnp.where(keep, a, jnp.full_like(a, bad))
+    return jax.tree.map(spoil, cache)
+
+
+@pytest.mark.parametrize("t", [1, 8], ids=["decode", "chunk"])
+@pytest.mark.parametrize("ns", [1, 2], ids=["nosplit", "split2"])
+@pytest.mark.parametrize("kv", ["bfloat16", "int8", "int4"])
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+def test_kernel_addresses_one_layer_of_the_whole_cache(layer, kv, ns, t):
+    """The kernel on the whole [L, NB, ...] cache with layer index ``l`` is
+    the kernel on that layer's slice, bit for bit, and the dense reference
+    within tolerance; what the other layers hold does not matter."""
+    from dynamo_tpu.models.llama import _gather_kv
+
+    nl, b, h, kh, d, nb, bs, nblk = 3, 3, 4, 2, 64, 16, 16, 4
+    rng = np.random.default_rng(100 * layer + 10 * ns + t)
+    kc = _whole_cache(rng, kv, nl, nb, bs, kh, d)
+    vc = _whole_cache(rng, kv, nl, nb, bs, kh, d)
+    q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.bfloat16)
+    ids = rng.permutation(nb - 1)[: b * nblk].reshape(b, nblk) + 1
+    bt = jnp.asarray(ids, jnp.int32)
+    q_start = jnp.asarray([0, 21, nblk * bs - t], jnp.int32)    # ragged
+    kv_lens = q_start + t
+
+    def kernel(k, v, **kw):
+        return np.asarray(paged_attention_kernel(
+            q, k, v, bt, q_start, kv_lens, num_splits=ns, interpret=True,
+            **kw).astype(jnp.float32))
+
+    # The layer index traced, as inside the model's scan.
+    whole = np.asarray(jax.jit(
+        lambda k, v, l: paged_attention_kernel(
+            q, k, v, bt, q_start, kv_lens, layer=l, num_splits=ns,
+            interpret=True))(kc, vc, jnp.int32(layer)).astype(jnp.float32))
+    one = jax.tree.map(lambda a: a[layer], (kc, vc))
+    np.testing.assert_array_equal(whole, kernel(*one))
+    np.testing.assert_array_equal(
+        whole, kernel(_spoil_other_layers(kc, layer),
+                      _spoil_other_layers(vc, layer), layer=layer))
+    ref = paged_attention(
+        q.astype(jnp.float32), _gather_kv(kc, bt, layer).astype(jnp.float32),
+        _gather_kv(vc, bt, layer).astype(jnp.float32),
+        q_start[:, None] + jnp.arange(t)[None, :], kv_lens)
+    np.testing.assert_allclose(whole, np.asarray(ref), atol=2e-2, rtol=2e-2)
+
+
 # -- Ahead-of-time compile for the v5e (no chip: the installed libtpu) ---------
 
 @pytest.fixture(scope="module")
@@ -199,16 +274,20 @@ def test_kernel_compiles_for_v5e(v5e_device, b, t, nblk, nb, kv, ns, compiles):
     def abstract(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-    cache = abstract((nb, bs, kh, d), jnp.bfloat16)
+    # The whole cache and a traced layer index, as the model's layer loop
+    # hands them over. What SMEM holds must not grow with the layers: the
+    # quantized pool's scales go in one layer at a time.
+    nl = 4
+    cache = abstract((nl, nb, bs, kh, d), jnp.bfloat16)
     if kv == "int8":
-        cache = {"q": abstract((nb, bs, kh, d), jnp.int8),
-                 "s": abstract((nb, kh), jnp.float32)}
+        cache = {"q": abstract((nl, nb, bs, kh, d), jnp.int8),
+                 "s": abstract((nl, nb, kh), jnp.float32)}
     lowered = jax.jit(
-        lambda q, k, v, bt, qs, kl: paged_attention_kernel(
-            q, k, v, bt, qs, kl, num_splits=ns)
+        lambda q, k, v, bt, qs, kl, layer: paged_attention_kernel(
+            q, k, v, bt, qs, kl, layer=layer, num_splits=ns)
     ).lower(abstract((b, t, h, d), jnp.bfloat16), cache, cache,
             abstract((b, nblk), jnp.int32), abstract((b,), jnp.int32),
-            abstract((b,), jnp.int32))
+            abstract((b,), jnp.int32), abstract((), jnp.int32))
     fits = scalar_prefetch_bytes(
         batch=b, nblk=nblk, num_blocks=nb if kv == "int8" else 0,
         kv_heads=kh) <= SMEM_USABLE_BYTES
@@ -218,6 +297,30 @@ def test_kernel_compiles_for_v5e(v5e_device, b, t, nblk, nb, kv, ns, compiles):
     else:
         with pytest.raises(Exception, match="smem"):
             lowered.compile()
+
+
+def test_step_holds_no_copy_of_the_pool_on_v5e(v5e_device):
+    """The benchmark's 16-layer Mistral-7B cut, a decode step compiled for
+    the described v5e at two pool sizes (what ModelRunner._fit_pool does on
+    the chip): the bytes beyond the arguments do not grow with the bf16
+    pool. Memory only: no time is read here."""
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root / "chipbench"))
+    import aot_check
+
+    config_dir = root / "chipbench" / "configs" / "mistral-7b-v0.3-l16"
+    parts = aot_check.build_abstract_runner(config_dir, {})
+    n0, n1 = 1024, 2048
+    r0, r1 = (aot_check.compile_bucket(*parts, 8, 1, 64, True, n)
+              for n in (n0, n1))
+    assert r0["kernel"] and r1["kernel"]
+    block = 2 * 16 * 16 * 8 * 128 * 2               # K and V, 16 layers, bf16
+    copies = (r1["beyond_arguments_bytes"] - r0["beyond_arguments_bytes"]) \
+        / (n1 - n0)
+    assert copies < 0.05 * block, (r0, r1)
 
 
 def test_paged_attention_kernel_parity_at_bench_shapes():

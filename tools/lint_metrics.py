@@ -38,6 +38,10 @@ PROVIDER_METRICS = {
         "kv_cache_bytes", "kv_quant_enabled",
         # A list (the per-device cache shape): in stats(), not a gauge.
         "kv_cache_shape",
+        # The pool as sized (ModelRunner._fit_pool): blocks, a block's bytes
+        # on a device, and the bytes a step holds for each block beyond the
+        # pool (None, so no gauge, where nothing was probed).
+        "kv_pool_blocks", "kv_block_bytes", "kv_step_copy_bytes_per_block",
         # Time to first token in parts, summed over sequences (engine.py
         # EngineMetrics): means are deltas over deltas of ttft_count.
         "ttft_count", "ttft_inbox_s", "ttft_queue_s", "ttft_prefill_s",
