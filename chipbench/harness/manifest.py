@@ -7,12 +7,15 @@ what is the cell's own (its rate and where that came from; optionally
 ``max_rows``, or any key of the mix it has to override) is
 ``chipbench/workloads/<cell name>.json``. The configuration is the directory
 of its ``file`` (``config.json`` in the keys ``ModelConfig.from_hf_config``
-reads, ``about.json`` beside it). Nothing here knows any cell,
-configuration, mix or metric by name.
+reads, ``about.json`` beside it; optionally ``reference.py``, the
+configuration's own plain reference, and a ``probe`` block in
+``about.json``, its own tolerances: ``harness/probe.py`` finds both).
+Nothing here knows any cell, configuration, mix or metric by name.
 """
 
 from __future__ import annotations
 
+import ast
 import json
 from dataclasses import dataclass
 from pathlib import Path
@@ -43,6 +46,20 @@ def _in_cell(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def cell_metrics(bench: dict, cell: str) -> tuple[list, list]:
+    """The names of the end-to-end and of the per-layer metrics that the
+    cell reports. A per-layer metric is read in the cells its ``workloads``
+    lists; one without the key, in every cell that reports the end-to-end
+    metric it ``moves`` (a cell that does not judge that metric has nothing
+    for it to move: a quantity read there too has an entry of its own that
+    moves what the cell does judge, as ``stream.ttft_p50_ms.chat``)."""
+    e2e = [m["name"] for m in bench["end_to_end"] if _in_cell(m, cell)]
+    layer = [m["name"] for m in bench["per_layer"]
+             if (cell in m["workloads"] if "workloads" in m
+                 else m["moves"] in e2e)]
+    return e2e, layer
+
+
 def _json(path: Path) -> dict:
     return json.loads(path.read_text())
 
@@ -58,6 +75,7 @@ def load_cell(name: str, bench: dict | None = None,
     cfg = next(c for c in bench["configs"] if c["name"] == entry["config"])
     config_dir = (ROOT / cfg["file"]).parent
     data = data_dir or BENCH
+    end_to_end, per_layer = cell_metrics(bench, name)
     return Cell(
         name=name, chips=entry["chips"], config_name=cfg["name"],
         config_dir=config_dir,
@@ -65,10 +83,66 @@ def load_cell(name: str, bench: dict | None = None,
         about=_json(config_dir / "about.json"),
         traffic={**_json(data / "traffic" / f"{entry['traffic']}.json"),
                  **_json(data / "workloads" / f"{name}.json")},
-        end_to_end=[m["name"] for m in bench["end_to_end"]
-                    if _in_cell(m, name)],
-        per_layer=[m["name"] for m in bench["per_layer"]
-                   if _in_cell(m, name)])
+        end_to_end=end_to_end, per_layer=per_layer)
+
+
+# A ``probe`` block's tolerances, each beside the reading it is held to.
+PROBE_PAIRS = (("logprob_tol", "worst_logprob_diff"),
+               ("argmax_tol", "worst_argmax_gap"))
+
+
+def _number(x) -> bool:
+    return isinstance(x, (int, float)) and not isinstance(x, bool)
+
+
+def probe_faults(config_dir: Path, about: dict) -> list[str]:
+    """What is wrong with a configuration's own comparison: its ``probe``
+    block (two tolerances, each above what the configuration gave as stated
+    and at least one below what it gave one precision down) and its
+    ``reference.py``. Reads the reference's source and never imports it:
+    this runs without JAX."""
+    faults: list[str] = []
+    block = about.get("probe")
+    if block is not None:
+        if not block.get("why"):
+            faults.append("probe block: no why")
+        readings = block.get("readings") or {}
+        sides = {k: readings.get(k) for k in ("as_stated", "one_precision_down")}
+        faults += [f"probe block: no readings.{k}"
+                   for k, r in sides.items() if not isinstance(r, dict)]
+        faults += [f"probe block: {tol} is not a number"
+                   for tol, _ in PROBE_PAIRS if not _number(block.get(tol))]
+        faults += [f"probe block: readings.{k}.{read} is not a number"
+                   for k, r in sides.items() if isinstance(r, dict)
+                   for _, read in PROBE_PAIRS if not _number(r.get(read))]
+        if not faults:
+            stated, down = sides.values()
+            faults += [f"probe block: {tol} {block[tol]} is not above the "
+                       f"as_stated reading {stated[read]}"
+                       for tol, read in PROBE_PAIRS
+                       if not block[tol] > stated[read]]
+            if not any(block[tol] < down[read] for tol, read in PROBE_PAIRS):
+                faults.append("probe block: neither tolerance is below its "
+                              "one_precision_down reading: lower precision "
+                              "would pass")
+    ref = Path(config_dir) / "reference.py"
+    if ref.is_file():
+        tree = ast.parse(ref.read_text(), str(ref))
+        if not any(isinstance(n, ast.FunctionDef) and n.name == "logits_at"
+                   for n in tree.body):
+            faults.append("reference.py defines no logits_at")
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [node.module or ""]
+            else:
+                continue
+            if any(n.split(".")[0] == "dynamo_tpu" for n in names):
+                faults.append("reference.py imports dynamo_tpu (line "
+                              f"{node.lineno}): the reference shares no "
+                              "code with the program")
+    return faults
 
 
 def check(bench: dict | None = None) -> list[str]:
@@ -91,6 +165,8 @@ def check(bench: dict | None = None) -> list[str]:
             faults.append(f"config {cfg['name']}: source differs from about.json")
         if sorted(a["reduced"]) != sorted(cfg["reduced"]):
             faults.append(f"config {cfg['name']}: reduced differs from about.json")
+        faults += [f"config {cfg['name']}: {fault}"
+                   for fault in probe_faults(f.parent, a)]
     for w in bench["workloads"]:
         if not (BENCH / "workloads" / f"{w['name']}.json").is_file():
             faults.append(f"workload {w['name']}: no workloads/{w['name']}.json")
@@ -98,6 +174,16 @@ def check(bench: dict | None = None) -> list[str]:
             faults.append(f"workload {w['name']}: no traffic/{w['traffic']}.json")
         if w["config"] not in {c["name"] for c in bench["configs"]}:
             faults.append(f"workload {w['name']}: unknown config {w['config']}")
+        judged, layer = cell_metrics(bench, w["name"])
+        if "setup_s" not in judged or len(judged) < 2:
+            faults.append(f"workload {w['name']}: reports {judged}: not "
+                          "setup_s and one more end-to-end metric")
+        if not layer:
+            faults.append(f"workload {w['name']}: no per-layer metric")
+        faults += [f"per-layer metric {m['name']}: moves {m['moves']}, "
+                   f"which workload {w['name']} does not report"
+                   for m in bench["per_layer"]
+                   if m["name"] in layer and m["moves"] not in judged]
     for m in bench["per_layer"]:
         if not (BENCH / "layers" / f"{m['name']}.py").is_file():
             faults.append(f"per-layer metric {m['name']}: no layers/{m['name']}.py")

@@ -37,6 +37,7 @@ class Context:
     in_flight: list[int] = field(default_factory=list)    # running + waiting
     compile_events: list[dict] = field(default_factory=list)
     memory_peak_bytes: int = 0
+    beat_late_s: list[float] = field(default_factory=list)  # loop heartbeat
     trace: dict | None = None    # harness.trace.reduce(), if it found ops
 
     @property
@@ -83,19 +84,40 @@ def end_to_end(ctx: Context, setup_s: float) -> dict[str, float]:
     return out
 
 
+STALL_S = 0.25   # over the ~0.1 s pause most windows have once (PERF.md)
+
+
+def stalls(ctx: Context) -> dict[str, float]:
+    """What the heartbeat on the generator's loop saw in the window: its
+    worst lateness, and the stretches in which it ran over ``STALL_S`` late
+    (one stall makes every beat due inside it late: one stretch). Logged
+    and printed beside the result, never a metric: a run the machine froze
+    in is far off in every latency (PERF.md, Findings, PR 27 and 29)."""
+    late = ctx.beat_late_s
+    starts = [b for a, b in zip([0.0] + late, late)
+              if b > STALL_S >= a]
+    return {"stall_max_ms": max(late, default=0.0) * 1e3,
+            "stalls": len(starts), "stalled_ms": sum(starts) * 1e3}
+
+
 def failed(ctx: Context) -> int:
     return sum(1 for r in ctx.due_in_window
                if r.finish is None or r.error or r.finish == "error")
 
 
-def load_reader(name: str):
-    path = BENCH / "layers" / f"{name}.py"
+def load_module(path, label: str):
+    """The module in the file at ``path`` (a reader, a configuration's
+    reference: files found by a name in the data, not packages)."""
     spec = importlib.util.spec_from_file_location(
-        "chipbench_layer_" + "".join(c if c.isalnum() else "_" for c in name),
+        "chipbench_" + "".join(c if c.isalnum() else "_" for c in label),
         path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(name: str):
+    return load_module(BENCH / "layers" / f"{name}.py", "layer_" + name)
 
 
 def per_layer(ctx: Context, names: list[str]) -> dict[str, dict]:

@@ -10,6 +10,8 @@ import time
 from . import loadgen, manifest, measure, traffic
 from . import trace as xtrace
 
+BEAT_S = 0.05    # the heartbeat on the generator's loop, window only
+
 
 async def offer(cell, sut, seed: int, seconds: float, log, *,
                 rate: float | None = None, order: int = traffic.ORDER,
@@ -48,8 +50,16 @@ async def offer(cell, sut, seed: int, seconds: float, log, *,
         kv.append(st["kv_usage"])
         running.append(st["num_running"] + st["num_waiting"])
 
+    def beat(when):
+        return lambda: beats.append(time.perf_counter() - when)
+
     marks = [(w0, edge("start")), (w1, edge("end"))]
     marks += [(w0 + 0.5 * i, sample) for i in range(int(seconds * 2) + 1)]
+    # How late a callback that only notes the time runs: a machine that
+    # stops every thread for a second shows here and nowhere else.
+    beats: list[float] = []
+    marks += [(w0 + BEAT_S * i, beat(w0 + BEAT_S * i))
+              for i in range(1, int(seconds / BEAT_S))]
     trace_dir, tstate = None, {}
     if trace:
         trace_dir = manifest.OUT / cell.name / f"trace-seed{seed}"
@@ -87,7 +97,7 @@ async def offer(cell, sut, seed: int, seconds: float, log, *,
     ctx = measure.Context(
         window=(p0, p1), window_wall=(wall0, wall1), chips=cell.chips,
         records=recs, counters=(c0, c1), kv_usage=kv, in_flight=running,
-        compile_events=events, memory_peak_bytes=peak)
+        compile_events=events, memory_peak_bytes=peak, beat_late_s=beats)
     if trace_dir is not None:
         xp = xtrace.find_xplane(trace_dir)
         traced_s = tstate.get("t1", 0.0) - tstate.get("t0", 0.0)
@@ -105,6 +115,6 @@ async def offer(cell, sut, seed: int, seconds: float, log, *,
                        for k, v in by_phase.items()},
         window_edges_late_s=[p0 - w0, p1 - w1],
         waiting_at_end=c1["num_waiting"], running_at_end=c1["num_running"],
-        in_flight_max=max(running, default=0),
+        in_flight_max=max(running, default=0), host=measure.stalls(ctx),
         compile_events_serve=sum(1 for e in events if e["source"] == "serve"))
     return ctx

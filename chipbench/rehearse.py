@@ -8,8 +8,10 @@ the percentile and gap arithmetic on a synthetic stream, the interval
 arithmetic of the trace reduction and its reading of a small recorded
 ``.xplane.pb``, the manifest against every file it names, the warm-up set
 against the lengths a cell can reach, and then runs the whole harness, probe
-against the reference included, on a tiny configuration kept under
-``chipbench/rehearsal/``.
+against the reference included, on the tiny configurations kept under
+``chipbench/rehearsal/``: ``tiny`` (dense: the default reference and
+tolerances) and ``tiny-moe`` (routed experts: its own ``reference.py``), and
+on copies of them in which ``correct`` has to come out false.
 """
 
 from __future__ import annotations
@@ -18,7 +20,9 @@ import contextlib
 import io
 import json
 import os
+import shutil
 import sys
+import tempfile
 from pathlib import Path
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -27,7 +31,7 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parent))
 
 import run as bench_run  # noqa: E402
-from harness import manifest, measure, stats, sut, trace, traffic  # noqa: E402
+from harness import manifest, measure, probe, stats, sut, trace, traffic  # noqa: E402
 
 
 def check_schedule() -> None:
@@ -133,37 +137,62 @@ def check_manifest() -> None:
               f"block tables {sorted({s.nblk for s in sigs})})")
 
 
-def check_harness() -> None:
-    """The whole command on the tiny configuration, traced."""
-    bench = {
-        "configs": [{"name": "tiny", "file": "chipbench/rehearsal/tiny/config.json",
+def _rehearsal_bench(config: str, config_dir: Path) -> dict:
+    """``BENCHMARK.json`` with one rehearsal cell, ``<config>.rehearsal``, on
+    the configuration in ``config_dir``: one ``configs`` entry and one
+    ``workloads`` entry are all a configuration of another architecture
+    adds."""
+    cell = f"{config}.rehearsal"
+    real = manifest.load_benchmark()
+    return {
+        "configs": [{"name": config, "file": str(config_dir / "config.json"),
                      "source": "none", "reduced": []}],
-        "workloads": [{"name": "tiny.rehearsal", "config": "tiny",
+        "workloads": [{"name": cell, "config": config,
                        "traffic": "rehearsal", "chips": 1}],
-        "end_to_end": manifest.load_benchmark()["end_to_end"],
-        "per_layer": [dict(m, **{"workloads": ["tiny.rehearsal"]})
-                      for m in manifest.load_benchmark()["per_layer"]],
+        "end_to_end": [{k: v for k, v in m.items() if k != "workloads"}
+                       for m in real["end_to_end"]],
+        "per_layer": [dict(m, workloads=[cell]) for m in real["per_layer"]],
     }
-    for m in bench["end_to_end"]:
-        m.pop("workloads", None)
+
+
+def _run_rehearsal(config: str, config_dir: Path, trace_flag: str = "0"):
+    """The whole command on one rehearsal configuration: its result line
+    and its ``probe`` log line."""
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf), contextlib.redirect_stderr(buf):
+        rc = bench_run.main(
+            ["--workload", f"{config}.rehearsal", "--seed", str(2**31 + 7),
+             "--seconds", "3", "--trace", trace_flag],
+            allow_cpu=True, bench=_rehearsal_bench(config, config_dir),
+            data_dir=HERE / "rehearsal")
+    lines = [ln for ln in buf.getvalue().splitlines() if ln.startswith("{")]
+    last = json.loads(lines[-1])
+    probe_line = next(d for d in map(json.loads, reversed(lines))
+                      if d.get("event") == "probe")
+    assert rc == 0
+    assert set(last) - {"breakdown", "host"} == {
+        "correct", "attempted", "failed", "metrics", "device"}, last
+    assert last["failed"] == 0 and last["attempted"] == 12, last
+    assert last["device"]["platform"] == "cpu"
+    return last, probe_line, buf.getvalue()
+
+
+def check_harness() -> None:
+    """The whole command on the tiny configurations: ``tiny`` (the default
+    reference and tolerances, untraced and traced) and ``tiny-moe`` (its own
+    ``reference.py``); then the three ways ``correct`` has to come out
+    false: an altered token, the wrong reference, a tolerance too tight."""
+    rehearsal = HERE / "rehearsal"
+    e2e = {m["name"] for m in manifest.load_benchmark()["end_to_end"]}
     for trace_flag in ("0", "1"):
-        buf = io.StringIO()
-        with contextlib.redirect_stdout(buf):
-            rc = bench_run.main(
-                ["--workload", "tiny.rehearsal", "--seed", str(2**31 + 7),
-                 "--seconds", "3", "--trace", trace_flag],
-                allow_cpu=True, bench=bench,
-                data_dir=HERE / "rehearsal")
-        last = json.loads(buf.getvalue().strip().splitlines()[-1])
-        assert rc == 0
-        assert set(last) - {"breakdown"} == {
-            "correct", "attempted", "failed", "metrics", "device"}, last
-        assert last["correct"] is True, buf.getvalue()[-3000:]
-        assert last["failed"] == 0 and last["attempted"] == 12, last
-        assert last["device"]["platform"] == "cpu"
+        last, pr, out = _run_rehearsal("tiny", rehearsal / "tiny", trace_flag)
+        assert last["correct"] is True, out[-3000:]
+        assert pr["reference"] == "chipbench/harness/reference.py", pr
+        assert (pr["logprob_tol"], pr["argmax_tol"]) == (0.1, 0.05), pr
+        worst_tiny = pr["worst_logprob_diff"]
         names = set(last["metrics"])
         if trace_flag == "0":
-            assert names == {m["name"] for m in bench["end_to_end"]}, names
+            assert names == e2e, names
         else:
             # No device plane on the CPU: the trace readers return nothing
             # and the harness leaves their metrics out.
@@ -173,6 +202,58 @@ def check_harness() -> None:
                     "stream.ttft_p90_ms"} <= names, names
         print(f"rehearse: harness --trace {trace_flag}: control flow ok "
               f"({last['attempted']} requests, probe agrees with the reference)")
+    # Another architecture is a directory: config.json, about.json and its
+    # own reference.py; no line under harness/ knows it.
+    last, pr, out = _run_rehearsal("tiny-moe", rehearsal / "tiny-moe")
+    assert last["correct"] is True, out[-3000:]
+    assert pr["reference"] == "chipbench/rehearsal/tiny-moe/reference.py", pr
+    assert (pr["logprob_tol"], pr["argmax_tol"]) == (0.1, 0.05), pr
+    print("rehearse: tiny-moe agrees with its own reference.py "
+          f"(worst {pr['worst_logprob_diff']:.4f} / {pr['worst_argmax_gap']:.4f})")
+    # A token altered where it is produced (the last of each probe
+    # request's, after the engine has returned it): the comparison sees it.
+    served = probe.run_schedule
+
+    async def altered(*args, **kwargs):
+        recs = await served(*args, **kwargs)
+        for r in recs:
+            r.tokens[-1] = (r.tokens[-1] + 1) % 512
+        return recs
+
+    probe.run_schedule = altered
+    try:
+        last, pr, out = _run_rehearsal("tiny", rehearsal / "tiny")
+    finally:
+        probe.run_schedule = served
+    assert last["correct"] is False and len(pr["faults"]) == 4, out[-3000:]
+    print("rehearse: one altered token in each probe request is not correct "
+          f"({pr['faults'][0]})")
+    with tempfile.TemporaryDirectory() as tmp:
+        # The same configuration without its reference.py: the default,
+        # dense reference cannot read a routed layer's parameters. The hook
+        # decides, not the tolerance.
+        bare = Path(tmp) / "tiny-moe-bare"
+        bare.mkdir()
+        for name in ("config.json", "about.json"):
+            shutil.copy(rehearsal / "tiny-moe" / name, bare / name)
+        last, pr, out = _run_rehearsal("tiny-moe", bare)
+        assert pr["reference"] == "chipbench/harness/reference.py", pr
+        assert last["correct"] is False and pr["faults"], out[-3000:]
+        print("rehearse: tiny-moe against harness/reference.py is not "
+              f"correct ({pr['faults'][0][:120]})")
+        # A probe block decides the tolerances: one under the CPU's own
+        # worst difference turns correct false.
+        tight = Path(tmp) / "tiny-tight"
+        tight.mkdir()
+        shutil.copy(rehearsal / "tiny" / "config.json", tight / "config.json")
+        about = json.loads((rehearsal / "tiny" / "about.json").read_text())
+        about["probe"] = {"logprob_tol": worst_tiny / 2, "argmax_tol": 0.05}
+        (tight / "about.json").write_text(json.dumps(about))
+        last, pr, out = _run_rehearsal("tiny", tight)
+        assert (pr["logprob_tol"], pr["argmax_tol"]) == (worst_tiny / 2, 0.05), pr
+        assert last["correct"] is False and pr["faults"], out[-3000:]
+        print(f"rehearse: a probe block's logprob_tol {worst_tiny / 2:.4f}, "
+              f"under tiny's worst difference {worst_tiny:.4f}, is not correct")
 
 
 def main() -> int:

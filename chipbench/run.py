@@ -63,7 +63,7 @@ def require_device(chips: int, allow_cpu: bool) -> dict:
 async def _serve(cell, sut, args, log) -> tuple[measure.Context, dict]:
     ctx = await serve.offer(cell, sut, args.seed, float(args.seconds), log,
                             order=args.order, trace=bool(args.trace))
-    pr = await probe.run_probe(sut, cell.model)
+    pr = await probe.run_probe(sut, cell)
     pr["faults"] = probe.check_counts(
         ctx.records, cell.model["vocab_size"]) + pr["faults"]
     log("probe", **pr)
@@ -109,12 +109,19 @@ def main(argv=None, allow_cpu: bool = False, bench: dict | None = None,
     result = {"correct": not pr["faults"],
               "attempted": len(ctx.due_in_window),
               "failed": measure.failed(ctx), "metrics": metrics,
-              "device": device}
+              "device": device, "host": measure.stalls(ctx)}
     if args.trace and ctx.trace:
         device["busy_s"] = ctx.trace["busy_s"]
         device["window_s"] = ctx.trace["window_s"]
         result["breakdown"] = {"device_ops": ctx.trace["device_ops"],
                                "idle_gaps": ctx.trace["idle_gaps"]}
+    # Each number compared beside its limit, last on standard error too.
+    print(f"probe against {pr['reference']}: worst_logprob_diff "
+          f"{pr['worst_logprob_diff']} (limit {pr['logprob_tol']}), "
+          f"worst_argmax_gap {pr['worst_argmax_gap']} (limit "
+          f"{pr['argmax_tol']}), {len(pr['faults'])} faults"
+          + "".join(f"\n  {f}" for f in pr["faults"][:8]),
+          file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 
