@@ -9,7 +9,7 @@ what is the cell's own (its rate and where that came from; optionally
 of its ``file`` (``config.json`` in the keys ``ModelConfig.from_hf_config``
 reads, ``about.json`` beside it; optionally ``reference.py``, the
 configuration's own plain reference, and a ``probe`` block in
-``about.json``, its own tolerances: ``harness/probe.py`` finds both).
+``about.json``, its own limits: ``harness/probe.py`` finds both).
 Nothing here knows any cell, configuration, mix or metric by name.
 """
 
@@ -86,23 +86,41 @@ def load_cell(name: str, bench: dict | None = None,
         end_to_end=end_to_end, per_layer=per_layer)
 
 
-# A ``probe`` block's tolerances, each beside the reading it is held to.
+# A ``probe`` block's limits, each beside the reading it is held to.
 PROBE_PAIRS = (("logprob_tol", "worst_logprob_diff"),
                ("argmax_tol", "worst_argmax_gap"))
+RMS_PAIR = ("rms_tol", "rms_logprob_diff")     # the block may leave it out
+TIE_KEYS = ("margin", "max_tied_share")        # a routed configuration's
 
 
 def _number(x) -> bool:
     return isinstance(x, (int, float)) and not isinstance(x, bool)
 
 
+def _functions(ref: Path) -> tuple[ast.Module, set[str]]:
+    tree = ast.parse(ref.read_text(), str(ref))
+    return tree, {n.name for n in tree.body if isinstance(n, ast.FunctionDef)}
+
+
 def probe_faults(config_dir: Path, about: dict) -> list[str]:
     """What is wrong with a configuration's own comparison: its ``probe``
-    block (two tolerances, each above what the configuration gave as stated
-    and at least one below what it gave one precision down) and its
+    block (each limit above what the configuration gave as stated and at
+    least one below what it gave one precision down; ``margin`` and
+    ``max_tied_share`` there exactly when the reference names tied
+    positions, the share that is tied as stated under its cap) and its
     ``reference.py``. Reads the reference's source and never imports it:
     this runs without JAX."""
     faults: list[str] = []
+    ref = Path(config_dir) / "reference.py"
+    tree, defined = _functions(ref) if ref.is_file() else (None, set())
     block = about.get("probe")
+    tie = block is not None and any(k in block for k in TIE_KEYS)
+    if tie != ("routing_margin_at" in defined):
+        faults.append(
+            "probe block: margin and max_tied_share without a "
+            "routing_margin_at in reference.py" if tie else
+            "reference.py defines routing_margin_at and the probe block "
+            "gives no margin and max_tied_share")
     if block is not None:
         if not block.get("why"):
             faults.append("probe block: no why")
@@ -110,26 +128,46 @@ def probe_faults(config_dir: Path, about: dict) -> list[str]:
         sides = {k: readings.get(k) for k in ("as_stated", "one_precision_down")}
         faults += [f"probe block: no readings.{k}"
                    for k, r in sides.items() if not isinstance(r, dict)]
+        from .probe import RMS_TOL     # here: probe imports this module
+
+        # rms_tol is held to its readings where the block gives it, and the
+        # default is where the block gives the readings alone
+        limits = {"rms_tol": RMS_TOL, **block}
+        pairs = PROBE_PAIRS + ((RMS_PAIR,) if "rms_tol" in block or all(
+            isinstance(r, dict) and RMS_PAIR[1] in r
+            for r in sides.values()) else ())
         faults += [f"probe block: {tol} is not a number"
-                   for tol, _ in PROBE_PAIRS if not _number(block.get(tol))]
+                   for tol in [t for t, _ in pairs] + (list(TIE_KEYS) if tie else [])
+                   if not _number(limits.get(tol))]
         faults += [f"probe block: readings.{k}.{read} is not a number"
                    for k, r in sides.items() if isinstance(r, dict)
-                   for _, read in PROBE_PAIRS if not _number(r.get(read))]
+                   for _, read in pairs if not _number(r.get(read))]
+        stated = sides["as_stated"]
+        if tie and isinstance(stated, dict) and not _number(stated.get("tied_share")):
+            faults.append("probe block: no readings.as_stated.tied_share")
         if not faults:
-            stated, down = sides.values()
-            faults += [f"probe block: {tol} {block[tol]} is not above the "
+            down = sides["one_precision_down"]
+            faults += [f"probe block: {tol} {limits[tol]} is not above the "
                        f"as_stated reading {stated[read]}"
-                       for tol, read in PROBE_PAIRS
-                       if not block[tol] > stated[read]]
-            if not any(block[tol] < down[read] for tol, read in PROBE_PAIRS):
-                faults.append("probe block: neither tolerance is below its "
+                       for tol, read in pairs
+                       if not limits[tol] > stated[read]]
+            if not any(limits[tol] < down[read] for tol, read in pairs):
+                faults.append("probe block: no tolerance is below its "
                               "one_precision_down reading: lower precision "
                               "would pass")
-    ref = Path(config_dir) / "reference.py"
-    if ref.is_file():
-        tree = ast.parse(ref.read_text(), str(ref))
-        if not any(isinstance(n, ast.FunctionDef) and n.name == "logits_at"
-                   for n in tree.body):
+            if tie:
+                faults += [f"probe block: readings.{k}.tied_share "
+                           f"{r['tied_share']} is over max_tied_share "
+                           f"{block['max_tied_share']}"
+                           for k, r in sides.items()
+                           if _number(r.get("tied_share"))
+                           and r["tied_share"] > block["max_tied_share"]]
+                if not 0 < block["max_tied_share"] < 1 or not block["margin"] > 0:
+                    faults.append("probe block: margin has to be above 0 and "
+                                  "max_tied_share inside (0, 1): a probe "
+                                  "that ties nothing or everything")
+    if tree is not None:
+        if "logits_at" not in defined:
             faults.append("reference.py defines no logits_at")
         for node in ast.walk(tree):
             if isinstance(node, ast.Import):

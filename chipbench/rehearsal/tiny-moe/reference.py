@@ -21,6 +21,16 @@ Departures: weights are the engine's bf16 leaves widened to float32; every
 expert is computed for every token and the unchosen ones get weight 0, which
 is the same sum.
 
+``routing_margin_at`` says which probed positions are tied: where a held
+expert's router score lies so close to the boundary of being chosen that
+the engine's bf16 rounding can choose otherwise than this float32 pass, and
+the position's logits then differ by tenths with no fault in the program
+(``harness/probe.py`` leaves such a position out; ``about.json``'s ``probe``
+block says how close is tied). Here every expert is held; a configuration
+that is one chip's share of a deployment holds the first
+``n_routed_experts`` of a router ``n_routed_experts_published`` wide
+(``chipbench/README.md``), and ``_margin`` looks at those alone.
+
 Shares no code with ``dynamo_tpu``; it reads only the parameter tree's
 layout: stacked ``[L, ...]`` leaves under ``layers``, the experts' matrices
 ``[L, E, ...]``, ``router`` ``[L, H, E]``, ``shared_*`` for the shared expert.
@@ -33,6 +43,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# A context position counts toward a probed position's margin where some
+# head attends to it by this much: a flip there moves that position's hidden
+# state by about a chosen expert's weight (0.3 of a unit-scale output), and a
+# tenth of that is what the tolerances allow.
+ATTENDED = 0.1
 
 
 def _rms_norm(x, w, eps):
@@ -53,7 +70,21 @@ def _swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
-def _experts(x, lp, top_k, shared):
+def _margin(scores, top_k, held):
+    """[T]: how far the nearest held expert (the first ``held`` of the
+    router's ``E``) is from changing sides. For a held, chosen expert: its
+    score less the best unchosen score; for a held, unchosen one: the
+    weakest chosen score less its own; the least of those. In the units the
+    choice is made in (here router logits: softmax keeps their order)."""
+    top = jax.lax.top_k(scores, top_k + 1)[0]
+    weakest_chosen, best_unchosen = top[:, top_k - 1, None], top[:, top_k, None]
+    distance = jnp.where(scores >= weakest_chosen, scores - best_unchosen,
+                         weakest_chosen - scores)
+    is_held = jnp.arange(scores.shape[-1]) < held
+    return jnp.min(jnp.where(is_held, distance, jnp.inf), axis=-1)
+
+
+def _experts(x, lp, top_k, shared, held):
     f32 = lambda a: a.astype(jnp.float32)
     scores = x @ f32(lp["router"])                       # [T, E]
     n_experts = scores.shape[-1]
@@ -67,13 +98,13 @@ def _experts(x, lp, top_k, shared):
     if shared:
         out = out + _swiglu(x, f32(lp["shared_gate"]), f32(lp["shared_up"]),
                             f32(lp["shared_down"]))
-    return out
+    return out, _margin(scores, top_k, held)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "n_kv", "head_dim", "theta",
-                                   "eps", "top_k", "shared"))
+                                   "eps", "top_k", "shared", "held"))
 def _layer(h, lp, n_valid, *, n_heads, n_kv, head_dim, theta, eps, top_k,
-           shared):
+           shared, held):
     with jax.default_matmul_precision("highest"):
         t = h.shape[0]
         f32 = lambda a: a.astype(jnp.float32)
@@ -88,10 +119,12 @@ def _layer(h, lp, n_valid, *, n_heads, n_kv, head_dim, theta, eps, top_k,
         s = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(head_dim))
         mask = (pos[None, :] <= pos[:, None]) & (pos[None, :] < n_valid)
         s = jnp.where(mask[None], s, -jnp.inf)
-        a = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
+        w = jax.nn.softmax(s, axis=-1)
+        a = jnp.einsum("hqk,khd->qhd", w, v)
         h = h + a.reshape(t, n_heads * head_dim) @ f32(lp["wo"])
-        return h + _experts(_rms_norm(h, lp["mlp_norm"], eps), lp, top_k,
-                            shared)
+        out, margin = _experts(_rms_norm(h, lp["mlp_norm"], eps), lp, top_k,
+                               shared, held)
+        return h + out, margin, jnp.max(w, axis=0)
 
 
 @jax.jit
@@ -100,25 +133,49 @@ def _head(hid, final_norm, w_out, eps):
         return _rms_norm(hid, final_norm, eps) @ w_out.astype(jnp.float32)
 
 
+def _forward(params, model: dict, tokens: list[int], positions: list[int],
+             pad_to: int):
+    """(logits [len(positions), vocab], routing margin [len(positions)]: the
+    least over the layers)."""
+    n = len(tokens)
+    ids = np.zeros((max(pad_to, n),), np.int32)
+    ids[:n] = tokens
+    at = jnp.asarray(positions)
+    h = params["embed"][jnp.asarray(ids)].astype(jnp.float32)
+    margin = jnp.full((len(positions),), jnp.inf)
+    below = jnp.full((len(ids),), jnp.inf)    # each position's, layers so far
+    for i in range(model["num_hidden_layers"]):
+        lp = {k: v[i] for k, v in params["layers"].items()}
+        h, m, attended = _layer(h, lp, jnp.int32(n),
+                      n_heads=model["num_attention_heads"],
+                      n_kv=model["num_key_value_heads"],
+                      head_dim=model["head_dim"],
+                      theta=float(model["rope_theta"]),
+                      eps=float(model["rms_norm_eps"]),
+                      top_k=model["num_experts_per_tok"],
+                      shared=bool(model.get("n_shared_experts")),
+                      held=model["n_routed_experts"])
+        # An expert flipped at an earlier position in a layer below reaches
+        # this one through attention, by the weight it is attended with.
+        reach = jnp.where(attended[at] >= ATTENDED, below[None, :], jnp.inf)
+        margin = jnp.minimum(margin, jnp.minimum(m[at], reach.min(axis=-1)))
+        below = jnp.minimum(below, m)
+    logits = _head(h[at], params["final_norm"], params["lm_head"],
+                   jnp.float32(model["rms_norm_eps"]))
+    return np.asarray(logits), np.asarray(margin)
+
+
 def logits_at(params, model: dict, tokens: list[int], positions: list[int],
               pad_to: int = 0) -> np.ndarray:
     """Float32 logits [len(positions), vocab] after the tokens at
     ``positions`` of the sequence ``tokens``; ``pad_to`` pads the sequence
     (masked) so that several lengths share one compiled program."""
-    n = len(tokens)
-    ids = np.zeros((max(pad_to, n),), np.int32)
-    ids[:n] = tokens
-    h = params["embed"][jnp.asarray(ids)].astype(jnp.float32)
-    for i in range(model["num_hidden_layers"]):
-        lp = {k: v[i] for k, v in params["layers"].items()}
-        h = _layer(h, lp, jnp.int32(n),
-                   n_heads=model["num_attention_heads"],
-                   n_kv=model["num_key_value_heads"],
-                   head_dim=model["head_dim"],
-                   theta=float(model["rope_theta"]),
-                   eps=float(model["rms_norm_eps"]),
-                   top_k=model["num_experts_per_tok"],
-                   shared=bool(model.get("n_shared_experts")))
-    return np.asarray(_head(h[jnp.asarray(positions)], params["final_norm"],
-                            params["lm_head"],
-                            jnp.float32(model["rms_norm_eps"])))
+    return _forward(params, model, tokens, positions, pad_to)[0]
+
+
+def routing_margin_at(params, model: dict, tokens: list[int],
+                      positions: list[int], pad_to: int = 0) -> np.ndarray:
+    """Float32 [len(positions)]: each position's routing margin, the least
+    of ``_margin`` over the routed layers, from the parameters and the
+    tokens alone."""
+    return _forward(params, model, tokens, positions, pad_to)[1]

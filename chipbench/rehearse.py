@@ -10,8 +10,10 @@ arithmetic of the trace reduction and its reading of a small recorded
 against the lengths a cell can reach, and then runs the whole harness, probe
 against the reference included, on the tiny configurations kept under
 ``chipbench/rehearsal/``: ``tiny`` (dense: the default reference and
-tolerances) and ``tiny-moe`` (routed experts: its own ``reference.py``), and
-on copies of them in which ``correct`` has to come out false.
+limits) and ``tiny-moe`` (routed experts: its own ``reference.py``, which
+names the positions where bf16 flips an expert, at a weight seed where it
+does), and on copies of them in which ``correct`` has to come out false.
+``tests/test_controls.py`` holds the controls over many weight seeds.
 """
 
 from __future__ import annotations
@@ -179,16 +181,20 @@ def _run_rehearsal(config: str, config_dir: Path, trace_flag: str = "0"):
 
 def check_harness() -> None:
     """The whole command on the tiny configurations: ``tiny`` (the default
-    reference and tolerances, untraced and traced) and ``tiny-moe`` (its own
-    ``reference.py``); then the three ways ``correct`` has to come out
-    false: an altered token, the wrong reference, a tolerance too tight."""
+    reference and limits, untraced and traced) and ``tiny-moe`` (its own
+    ``reference.py`` and ``probe`` block: an expert flips at a probed
+    position, which is tied); then the ways ``correct`` has to come out
+    false: an altered token, the wrong reference, a tolerance too tight, a
+    margin function that calls every position tied."""
     rehearsal = HERE / "rehearsal"
     e2e = {m["name"] for m in manifest.load_benchmark()["end_to_end"]}
     for trace_flag in ("0", "1"):
         last, pr, out = _run_rehearsal("tiny", rehearsal / "tiny", trace_flag)
         assert last["correct"] is True, out[-3000:]
         assert pr["reference"] == "chipbench/harness/reference.py", pr
-        assert (pr["logprob_tol"], pr["argmax_tol"]) == (0.1, 0.05), pr
+        assert (pr["logprob_tol"], pr["argmax_tol"], pr["rms_tol"]) == \
+            (0.1, 0.05, 0.033), pr
+        assert (pr["compared"], pr["tied"], pr["margin"]) == (64, 0, None), pr
         worst_tiny = pr["worst_logprob_diff"]
         names = set(last["metrics"])
         if trace_flag == "0":
@@ -207,9 +213,19 @@ def check_harness() -> None:
     last, pr, out = _run_rehearsal("tiny-moe", rehearsal / "tiny-moe")
     assert last["correct"] is True, out[-3000:]
     assert pr["reference"] == "chipbench/rehearsal/tiny-moe/reference.py", pr
-    assert (pr["logprob_tol"], pr["argmax_tol"]) == (0.1, 0.05), pr
-    print("rehearse: tiny-moe agrees with its own reference.py "
-          f"(worst {pr['worst_logprob_diff']:.4f} / {pr['worst_argmax_gap']:.4f})")
+    block = json.loads((rehearsal / "tiny-moe" / "about.json").read_text())["probe"]
+    assert all(pr[k] == block[k] for k in (
+        "logprob_tol", "argmax_tol", "rms_tol", "margin", "max_tied_share")), pr
+    # Its weight seed is one where bf16 flips an expert at a probed
+    # position: over a tolerance, tied, left out.
+    assert pr["tied_over_tolerance"] >= 1 and pr["compared"] + pr["tied"] == 64, pr
+    assert pr["worst_tied_logprob_diff"] > pr["logprob_tol"] > pr["worst_logprob_diff"]
+    print("rehearse: tiny-moe agrees with its own reference.py at the "
+          f"{pr['compared']} positions compared (worst "
+          f"{pr['worst_logprob_diff']:.4f} / {pr['worst_argmax_gap']:.4f}, rms "
+          f"{pr['rms_logprob_diff']:.4f}); {pr['tied']} tied, "
+          f"{pr['tied_over_tolerance']} of them off by up to "
+          f"{pr['worst_tied_logprob_diff']:.4f}")
     # A token altered where it is produced (the last of each probe
     # request's, after the engine has returned it): the comparison sees it.
     served = probe.run_schedule
@@ -225,7 +241,9 @@ def check_harness() -> None:
         last, pr, out = _run_rehearsal("tiny", rehearsal / "tiny")
     finally:
         probe.run_schedule = served
-    assert last["correct"] is False and len(pr["faults"]) == 4, out[-3000:]
+    # each request's altered position, and the steady statistic with them
+    assert last["correct"] is False and \
+        sum("token" in f for f in pr["faults"]) == 4, out[-3000:]
     print("rehearse: one altered token in each probe request is not correct "
           f"({pr['faults'][0]})")
     with tempfile.TemporaryDirectory() as tmp:
@@ -238,9 +256,22 @@ def check_harness() -> None:
             shutil.copy(rehearsal / "tiny-moe" / name, bare / name)
         last, pr, out = _run_rehearsal("tiny-moe", bare)
         assert pr["reference"] == "chipbench/harness/reference.py", pr
-        assert last["correct"] is False and pr["faults"], out[-3000:]
+        assert last["correct"] is False, out[-3000:]
+        failed = next(f for f in pr["faults"] if "failed" in f)
         print("rehearse: tiny-moe against harness/reference.py is not "
-              f"correct ({pr['faults'][0][:120]})")
+              f"correct ({failed[:120]})")
+        # A margin function that calls every position tied leaves nothing
+        # to compare: a fault, not a pass.
+        all_tied = Path(tmp) / "tiny-moe-all-tied"
+        shutil.copytree(rehearsal / "tiny-moe", all_tied)
+        with open(all_tied / "reference.py", "a") as fh:
+            fh.write("\n\ndef routing_margin_at(params, model, tokens, "
+                     "positions, pad_to=0):\n"
+                     "    return np.zeros(len(positions), np.float32)\n")
+        last, pr, out = _run_rehearsal("tiny-moe", all_tied)
+        assert last["correct"] is False and pr["tied"] == 64, out[-3000:]
+        print("rehearse: a margin function that ties every position is not "
+              f"correct ({pr['faults'][-1]})")
         # A probe block decides the tolerances: one under the CPU's own
         # worst difference turns correct false.
         tight = Path(tmp) / "tiny-tight"

@@ -116,10 +116,15 @@ def main(argv=None, allow_cpu: bool = False, bench: dict | None = None,
         result["breakdown"] = {"device_ops": ctx.trace["device_ops"],
                                "idle_gaps": ctx.trace["idle_gaps"]}
     # Each number compared beside its limit, last on standard error too.
-    print(f"probe against {pr['reference']}: worst_logprob_diff "
+    tie = (f", tied {pr['tied']} (routing margin under {pr['margin']}; at "
+           f"most {pr['max_tied_share']} of {pr['positions']})"
+           if pr["margin"] is not None else ", tied 0 (no routing margin)")
+    print(f"probe against {pr['reference']}: compared {pr['compared']} of "
+          f"{pr['positions']} positions{tie}: worst_logprob_diff "
           f"{pr['worst_logprob_diff']} (limit {pr['logprob_tol']}), "
           f"worst_argmax_gap {pr['worst_argmax_gap']} (limit "
-          f"{pr['argmax_tol']}), {len(pr['faults'])} faults"
+          f"{pr['argmax_tol']}), rms_logprob_diff {pr['rms_logprob_diff']} "
+          f"(limit {pr['rms_tol']}), {len(pr['faults'])} faults"
           + "".join(f"\n  {f}" for f in pr["faults"][:8]),
           file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)

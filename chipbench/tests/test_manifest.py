@@ -1,6 +1,7 @@
 """Which metrics a cell reports (``manifest.cell_metrics``), the faults
-``manifest.check`` finds in them, and the heartbeat's reading of a stalled
-machine (``measure.stalls``). CPU, no engine.
+``manifest.check`` finds in them and in a routed configuration's comparison,
+and the heartbeat's reading of a stalled machine (``measure.stalls``). CPU,
+no engine.
 
     python3 -m pytest chipbench/tests -q -p no:cacheprovider
 """
@@ -8,6 +9,7 @@ machine (``measure.stalls``). CPU, no engine.
 from __future__ import annotations
 
 import copy
+import json
 import sys
 from pathlib import Path
 
@@ -91,6 +93,82 @@ def test_check_refuses_a_cell_with_nothing_judged_or_nothing_read():
             m["workloads"] = [CELLS[0]]
 
     assert f"workload {CELLS[1]}: no per-layer metric" in _faults(nothing_read)
+
+
+ROUTED = {
+    "logprob_tol": 0.1, "argmax_tol": 0.05, "rms_tol": 0.03, "margin": 0.06,
+    "max_tied_share": 0.5, "why": "8 routed experts, 2 a token",
+    "readings": {
+        "as_stated": {"worst_logprob_diff": 0.08, "worst_argmax_gap": 0.04,
+                      "rms_logprob_diff": 0.02, "tied_share": 0.4},
+        "one_precision_down": {"worst_logprob_diff": 0.06,
+                               "worst_argmax_gap": 0.0,
+                               "rms_logprob_diff": 0.05, "tied_share": 0.4}}}
+TWO_FUNCTIONS = ("def logits_at(params, model, tokens, positions, pad_to=0):\n"
+                 "    return None\n\n\ndef routing_margin_at(params, model, "
+                 "tokens, positions, pad_to=0):\n    return None\n")
+
+
+def _with_config(tmp_path, block, reference) -> list[str]:
+    """``manifest.check`` on the tree's manifest plus one configuration in
+    ``tmp_path``: its faults alone."""
+    about = {"source": "none", "reduced": {}, "seed": 0, "engine": {}}
+    if block is not None:
+        about["probe"] = block
+    (tmp_path / "about.json").write_text(json.dumps(about))
+    (tmp_path / "config.json").write_text("{}")
+    (tmp_path / "reference.py").write_text(reference)
+    bench = dict(REAL, configs=REAL["configs"] + [{
+        "name": "tmp", "source": "none", "reduced": [],
+        "file": str(tmp_path / "config.json")}])
+    faults = manifest.check(bench)
+    assert all(f.startswith("config tmp: ") for f in faults), faults
+    return faults
+
+
+def _block(**edits) -> dict:
+    block = copy.deepcopy(ROUTED)
+    for key, value in edits.items():
+        where, _, leaf = key.rpartition("__")
+        d = block
+        for part in filter(None, where.split("__")):
+            d = d[part]
+        if value is None:
+            del d[leaf]
+        else:
+            d[leaf] = value
+    return block
+
+
+def test_check_takes_a_routed_configurations_comparison(tmp_path):
+    assert _with_config(tmp_path, ROUTED, TWO_FUNCTIONS) == []
+
+
+@pytest.mark.parametrize("block, reference, said", [
+    (ROUTED, TWO_FUNCTIONS.split("\n\n\n")[0] + "\n",
+     "margin and max_tied_share without a routing_margin_at"),
+    (_block(margin=None, max_tied_share=None), TWO_FUNCTIONS,
+     "defines routing_margin_at and the probe block gives no margin"),
+    (None, TWO_FUNCTIONS,
+     "defines routing_margin_at and the probe block gives no margin"),
+    (_block(margin="0.06"), TWO_FUNCTIONS, "margin is not a number"),
+    (_block(max_tied_share=None), TWO_FUNCTIONS,
+     "max_tied_share is not a number"),
+    (_block(readings__as_stated__tied_share=None), TWO_FUNCTIONS,
+     "no readings.as_stated.tied_share"),
+    (_block(readings__as_stated__tied_share=0.6), TWO_FUNCTIONS,
+     "readings.as_stated.tied_share 0.6 is over max_tied_share 0.5"),
+    (_block(rms_tol=0.02), TWO_FUNCTIONS,
+     "rms_tol 0.02 is not above the as_stated reading 0.02"),
+    (_block(rms_tol=0.06), TWO_FUNCTIONS, "no tolerance is below"),
+], ids=["margin-without-function", "function-without-margin",
+        "function-without-block", "margin-not-a-number",
+        "share-not-a-number", "no-tied-share-reading", "tied-share-over-cap",
+        "rms-tol-not-above-stated", "nothing-fails-one-precision-down"])
+def test_check_names_each_fault_of_a_routed_comparison(tmp_path, block,
+                                                       reference, said):
+    faults = _with_config(tmp_path, block, reference)
+    assert any(said in f for f in faults), faults
 
 
 def _ctx(late: list[float]) -> measure.Context:
