@@ -39,7 +39,17 @@ def require_backend(platform: str, requested: str | None) -> None:
 def configure_compile_cache() -> str | None:
     """Place JAX's persistent compilation cache; returns the directory.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already read it and
-    nothing is set here; otherwise the cache goes to ``DEFAULT_CACHE_DIR``.
+    the directory is left alone; otherwise the cache goes to
+    ``DEFAULT_CACHE_DIR``.
+
+    Every compiled program is kept, whatever its compile time or size. JAX
+    by itself writes an entry only for a compile of a second or more, and a
+    small step program (a ``T=16`` mixed step, a narrow decode step)
+    compiles in less: left out of the cache, it is compiled again by every
+    restart, which costs more than loading it and is what a warm start is
+    there to avoid (PERF.md section 6, PR 32: twelve to sixteen such
+    programs a cell, 5-11 s of set-up). A setting in code, the same for
+    every caller.
 
     On the CPU backend the cache is switched off and None returned. There
     a program compiles in a second or two, so a restart gains little, and
@@ -52,6 +62,8 @@ def configure_compile_cache() -> str | None:
         return None
     if not os.environ.get(CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return jax.config.jax_compilation_cache_dir
 
 

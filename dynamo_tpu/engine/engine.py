@@ -18,6 +18,7 @@ import asyncio
 import collections
 import contextlib
 import dataclasses
+import itertools
 import math
 import queue as thread_queue
 import threading
@@ -67,6 +68,8 @@ from dynamo_tpu.obs.compile_ledger import (
     BucketSig,
     enumerate_buckets,
     get_compile_ledger,
+    pack_rows,
+    token_bucket,
 )
 from dynamo_tpu.obs.profiler import (
     LoopClock,
@@ -98,6 +101,18 @@ def _pow2_bucket(n: int, lo: int, hi: int) -> int:
     while b < n and b < hi:
         b *= 2
     return b
+
+
+def _step_tokens(b: int, t: int, sp_prefill: bool) -> int:
+    """The [N, H] a (b, t) step program runs its dense layers over: its
+    token bucket, or the whole rectangle where ring prefill shards it
+    over "seq"."""
+    return b * t if sp_prefill else token_bucket("mixed", b, t)
+
+
+def _runs(sizes: list[int]) -> list[tuple[int, int]]:
+    """(first index, size) of consecutive runs of the given sizes."""
+    return list(zip(itertools.accumulate(sizes, initial=0), sizes))
 
 
 def _named(fn: Callable, name: str) -> Callable:
@@ -234,7 +249,9 @@ class PendingStep:
     # Unified steps: the leading decode-row count of the "mixed" batch
     # (rows [0:n] are decode/guided, the rest prefill chunks) — captured
     # at plan time because prefill_target() moves as finalize appends
-    # tokens, so a finalize-time re-derivation would misclassify.
+    # tokens, so a finalize-time re-derivation would misclassify. A step
+    # whose chunks overflow one token bucket is several mixed batches
+    # (compile_ledger.pack_rows): the decode rows lead the first of them.
     mixed_dec_rows: int = 0
 
 
@@ -355,9 +372,9 @@ class ModelRunner:
         # engine.compile around a step program built inside serving;
         # EngineCore shares this clock for the rest of the loop.
         self.loop_clock = LoopClock()
-        # (kind, b, t, nblk) of the last dispatched step program: what the
+        # (kind, b, t, nblk, n) of the last dispatched step program: what the
         # engine.dispatch span says it enqueued.
-        self.last_bucket: tuple[str, int, int, int] = ("", 0, 0, 0)
+        self.last_bucket: tuple[str, int, int, int, int] = ("", 0, 0, 0, 0)
         # The pool comes last: everything else that lives on the device is
         # resident by now, so what memory_stats() calls free really is.
         self.spec = KVCacheSpec.for_model(
@@ -554,10 +571,11 @@ class ModelRunner:
         return n
 
     def _widest_bucket(self) -> BucketSig:
-        """The reachable bucket with the most padded tokens (then the
-        widest block table): the step whose activations are largest."""
+        """The reachable bucket with the most tokens through the dense
+        layers (then the largest rectangle in attention, then the widest
+        block table): the step whose activations are largest."""
         return max(enumerate_buckets(self.engine_cfg),
-                   key=lambda s: (s.b * s.t, s.nblk))
+                   key=lambda s: (s.n, s.b * s.t, s.nblk))
 
     def _block_bytes_per_device(self) -> int:
         """One block's bytes on each device (K and V), by the cache's own
@@ -614,6 +632,9 @@ class ModelRunner:
         mesh = self.mesh
         pp_micro = self.engine_cfg.pp_microbatches
         attn_splits = self.engine_cfg.attn_num_splits
+        # dispatch() sends no batch with more live tokens than this
+        # (EngineCore cuts steps by pack_rows).
+        n_tok = _step_tokens(b, t, sp_prefill)
 
         def step(params, ck, cv, counts, keys, slot_toks, tokens, q_start, q_len,
                  bt, slots, temp, top_k, top_p, fp, pp, rp, do_sample, from_slot,
@@ -634,7 +655,8 @@ class ModelRunner:
                                            embed_override=emb_override,
                                            embed_mask=emb_mask,
                                            pp_microbatches=pp_micro,
-                                           attn_num_splits=attn_splits)
+                                           attn_num_splits=attn_splits,
+                                           num_tokens=n_tok)
             logits = llama.logits_from_hidden(params, cfg, hidden).astype(jnp.float32)
             if masked:
                 # Structured output (engine/guided.py): the grammar's
@@ -666,7 +688,7 @@ class ModelRunner:
             return ck, cv, counts, keys, slot_toks, toks, lps
 
         name = (f"step_decode_b{b}_n{nblk}" if t == 1
-                else f"step_mixed_b{b}_t{t}_n{nblk}")
+                else f"step_mixed_b{b}_t{t}_k{n_tok}_n{nblk}")
         for flag, suffix in ((sp_prefill, "_sp"), (not fast_greedy, "_sampled"),
                              (mm, "_mm"), (masked, "_masked")):
             if flag:
@@ -785,6 +807,42 @@ class ModelRunner:
                 k = _advance_key_data(k, jnp.int32(advance)).astype(jnp.uint32)
             self.keys = self.keys.at[slot].set(k)
 
+    def bucket_of(self, rows: list[tuple[Seq, int, int]], window: int = 1,
+                  mixed: bool = False) -> tuple[str, int, int, int, int]:
+        """(kind, b, t, nblk, window) of the step program that serves
+        ``rows``: dispatch()'s geometry, which ``sig_for_rows``
+        (obs/compile_ledger.py) mirrors device-free."""
+        ec = self.engine_cfg
+        n = len(rows)
+        t_max = max(length for _, _, length in rows)
+        if t_max == 1:
+            # Degenerate mixed batches (every live row is one token) ARE
+            # the decode program — classify them as such so the ledger
+            # matches the program actually minted.
+            b, t = _bucket(n, ec.decode_bucket), 1
+        elif mixed:
+            window = 1
+            b, t = _bucket(n, ec.decode_bucket), _pow2_bucket(t_max, 16, ec.prefill_chunk)
+        else:
+            window = 1  # windows are a decode-dispatch concept
+            b, t = _bucket(n, (1, 2, 4, 8)), _pow2_bucket(t_max, 16, ec.prefill_chunk)
+        # Block-table width from the batch's max KV coverage — NOT the max
+        # allocated table length: every query/context position this step
+        # touches is < start + length (+ window-1 for fused decode windows),
+        # so blocks past that are pure waste (the Pallas kernel still burns
+        # one HBM DMA per table entry per step, and the dense path gathers
+        # them). Pow2-bucketed to bound the number of compiled programs.
+        bsz = ec.block_size
+        nblk_need = max(
+            min(len(s.block_ids),
+                -(-(start + length + window - 1) // bsz))
+            for s, start, length in rows)
+        nblk = min(_pow2_bucket(max(nblk_need, 1), 4, self.max_nblk), self.max_nblk)
+        kind = ("window" if window > 1
+                else "decode" if t == 1
+                else "mixed" if mixed else "prefill")
+        return kind, b, t, nblk, window
+
     def dispatch(
         self,
         rows: list[tuple[Seq, int, int]],  # (seq, start, length) per row
@@ -804,33 +862,15 @@ class ModelRunner:
         the batch buckets over the DECODE row ladder while t takes the
         prefill chunk ladder — same ragged step program, different bucket
         geometry (legacy prefill's (1,2,4,8) row ladder can't hold a full
-        decode batch)."""
+        decode batch).
+
+        The program runs its dense layers over a token bucket N that
+        follows from the (b, t) picked here (``token_bucket``); the rows
+        hold no more live tokens than that, which the caller sees to by
+        cutting a step with ``pack_rows``."""
         ec = self.engine_cfg
-        n = len(rows)
         t_max = max(length for _, _, length in rows)
-        if t_max == 1:
-            # Degenerate mixed batches (every live row is one token) ARE
-            # the decode program — classify them as such so the ledger
-            # matches the program actually minted.
-            b, t, mixed = _bucket(n, ec.decode_bucket), 1, False
-        elif mixed:
-            window = 1
-            b, t = _bucket(n, ec.decode_bucket), _pow2_bucket(t_max, 16, ec.prefill_chunk)
-        else:
-            window = 1  # windows are a decode-dispatch concept
-            b, t = _bucket(n, (1, 2, 4, 8)), _pow2_bucket(t_max, 16, ec.prefill_chunk)
-        # Block-table width from the batch's max KV coverage — NOT the max
-        # allocated table length: every query/context position this step
-        # touches is < start + length (+ window-1 for fused decode windows),
-        # so blocks past that are pure waste (the Pallas kernel still burns
-        # one HBM DMA per table entry per step, and the dense path gathers
-        # them). Pow2-bucketed to bound the number of compiled programs.
-        bsz = ec.block_size
-        nblk_need = max(
-            min(len(s.block_ids),
-                -(-(start + length + window - 1) // bsz))
-            for s, start, length in rows)
-        nblk = min(_pow2_bucket(max(nblk_need, 1), 4, self.max_nblk), self.max_nblk)
+        kind, b, t, nblk, window = self.bucket_of(rows, window, mixed)
         # Sequence-parallel prefill: a batch of fresh full-prompt chunks
         # (every row starts at 0) on a seq>1 mesh rides ring attention —
         # but only past the ring-vs-chunked threshold (explicit knob or
@@ -934,10 +974,12 @@ class ModelRunner:
                 if m is not None:
                     logit_mask[i, ~m] = -1e30
         led = self._ledger
-        kind = ("window" if window > 1
-                else "decode" if t == 1
-                else "mixed" if mixed else "prefill")
-        self.last_bucket = (kind, b, t, nblk)
+        n_tok = _step_tokens(b, t, sp_prefill)
+        if int(q_len.sum()) > n_tok:
+            raise ValueError(
+                f"{int(q_len.sum())} live tokens in a {kind} batch whose "
+                f"bucket (b={b}, t={t}) holds {n_tok}: cut it with pack_rows")
+        self.last_bucket = (kind, b, t, nblk, n_tok)
         miss = ((b, t, nblk, sp_prefill, window, fast_greedy, mm, masked)
                 not in self._step_fns)
         cold = led.enabled and miss
@@ -1058,7 +1100,7 @@ class ModelRunner:
             bt[i, : len(ids)] = ids
 
         key = ("verify", b, t, nblk)
-        self.last_bucket = key
+        self.last_bucket = (*key, b * t)
         led = self._ledger
         miss = key not in self._step_fns
         cold = led.enabled and miss
@@ -1843,8 +1885,8 @@ class EngineCore:
             return None
         with loop_phase(self.loop_clock, "engine.dispatch") as span:
             pending = self._dispatch_plan(plan)
-            kind, b, t, nblk = self.runner.last_bucket
-            span.set(kind=kind, b=b, t=t, nblk=nblk,
+            kind, b, t, nblk, n_tok = self.runner.last_bucket
+            span.set(kind=kind, b=b, t=t, nblk=nblk, n=n_tok,
                      rows=sum(len(x[1]) for x in pending.batches))
         if self.sched_led.enabled:
             with loop_phase(self.loop_clock, "engine.plan"):
@@ -1911,11 +1953,12 @@ class EngineCore:
                 seq.slot_initialized = True
 
         # Unified mode: decode rows and the step's prefill-chunk rows pack
-        # into ONE ragged "mixed" program (per-row live-token counts ride
-        # the scalar-prefetch path, so padding costs DMA-elided grid steps,
-        # not FLOPs). Legacy mode (--no-unified-step, or decode_window>1)
-        # runs them as two bucketed programs, decode first — see the
-        # scheduler module docstring.
+        # into ONE ragged "mixed" program, whose dense layers run over the
+        # rows' live tokens and whose attention runs over the [B, T] rows.
+        # Legacy mode (--no-unified-step, or decode_window>1) runs them as
+        # two bucketed programs, decode first — see the scheduler module
+        # docstring. Either way a batch whose chunks hold more tokens than
+        # its program's token bucket goes out as several programs, below.
         pending = PendingStep()
         batches: list[tuple[str, list, list[bool], int, list | None]] = []
         decode_seqs = plan.decode
@@ -1989,6 +2032,13 @@ class EngineCore:
                 batches.append(("prefill", pf_rows, pf_sample_rows, 1,
                                 pf_masks))
 
+        ec = self.engine_cfg
+        batches = [
+            (kind, rows[lo:lo + k], sample_rows[lo:lo + k], window,
+             b_masks and b_masks[lo:lo + k])
+            for kind, rows, sample_rows, window, b_masks in batches
+            for lo, k in _runs(pack_rows([r[2] for r in rows], ec,
+                                         kind == "mixed"))]
         for kind, rows, sample_rows, window, b_masks in batches:
             toks, lps = self.runner.dispatch(rows, sample_rows, window=window,
                                              masks=b_masks,
@@ -2115,15 +2165,10 @@ class EngineCore:
 
     def _record_step(self, t0: float, pending: "PendingStep") -> None:
         """Always-on step profile: one ring append per engine step."""
-        n_pf = n_dec = 0
-        for kind, rows, *_ in pending.batches:
-            if kind == "prefill":
-                n_pf += len(rows)
-            elif kind == "mixed":
-                n_dec += pending.mixed_dec_rows
-                n_pf += len(rows) - pending.mixed_dec_rows
-            else:
-                n_dec += len(rows)
+        n_dec = sum(len(rows) for kind, rows, *_ in pending.batches
+                    if kind not in ("prefill", "mixed")
+                    ) + pending.mixed_dec_rows
+        n_pf = sum(len(rows) for _, rows, *_ in pending.batches) - n_dec
         pc = self.sched.preemption_count
         wall = time.perf_counter() - t0
         get_tracer().recorder.steps.record(
@@ -2266,6 +2311,7 @@ class EngineCore:
         t0 = time.perf_counter()
         clock = self.loop_clock
         outputs: dict[str, LLMEngineOutput] = {}
+        dec_left = pending.mixed_dec_rows
         for kind, rows, sample_rows, toks_dev, lps_dev in pending.batches:
             with loop_phase(clock, "engine.finalize.wait"):
                 # The host blocks here until the device has run the step.
@@ -2273,7 +2319,9 @@ class EngineCore:
                 lps = np.asarray(lps_dev)
             with loop_phase(clock, "engine.finalize.host"):
                 self._finalize_batch(kind, rows, sample_rows, toks, lps,
-                                     pending.mixed_dec_rows, outputs)
+                                     dec_left, outputs)
+            if kind == "mixed":
+                dec_left = max(dec_left - len(rows), 0)
         with loop_phase(clock, "engine.record"):
             self._record_step(t0, pending)
         if self.kvbm is not None and not self.sched.has_work():

@@ -12,11 +12,14 @@ One step = decode rows AND at most a token-budgeted set of prefill chunks
 prefill can stall ITL by at most one chunk's compute, not the whole prompt
 (the reference's engines mix within token-budgeted steps the same way,
 lib/llm/src/mocker/scheduler.rs:117-178). By default the engine dispatches
-the whole plan as ONE ragged mixed-phase XLA launch: the step program is
-already per-row ragged (per-row q_start/q_len ride the scalar-prefetch
-path, so a decode row padded to the chunk ladder T costs DMA-elided grid
-steps, not T× FLOPs), which removes the second launch's dispatch gap and
-lets XLA overlap decode attention with prefill matmuls.
+the whole plan as ONE ragged mixed-phase XLA launch: the step program
+runs its dense layers over the rows' live tokens, packed end to end, so a
+decode row beside a chunk costs its one token there, not T (in the
+attention kernel, which runs over the [B, T] rows, it still costs padded
+grid steps). That removes the second launch's dispatch gap and lets XLA
+overlap decode attention with prefill matmuls. A plan whose chunks hold
+more tokens than one program's bucket goes out as several launches
+(obs/compile_ledger.py pack_rows).
 ``--no-unified-step`` restores the legacy two-launch path; fused decode
 windows (decode_window > 1) are decode-only scans and also keep it.
 Static-shape buckets keep XLA compile counts bounded either way.

@@ -567,7 +567,7 @@ class MockEngine:
                         wall_s=wall, kinds=(kind,), prefill_rows=1,
                         decode_rows=len(decodes),
                         live_tokens=new_tokens + len(decodes),
-                        sched_tokens=sig.b * sig.t,
+                        sched_tokens=sig.n, rect_tokens=sig.b * sig.t,
                         queue_depths=self._queue_depths(),
                         hol=HolStall(
                             culprit=seq.req.request_id,
@@ -618,7 +618,8 @@ class MockEngine:
                                        len(seq.block_ids), self._lattice_cfg)
                     self._sled.record_step(
                         wall_s=wall, kinds=("prefill",), prefill_rows=1,
-                        live_tokens=new_tokens, sched_tokens=sig.b * sig.t,
+                        live_tokens=new_tokens, sched_tokens=sig.n,
+                        rect_tokens=sig.b * sig.t,
                         queue_depths=self._queue_depths(),
                         hol=HolStall(
                             culprit=seq.req.request_id,
@@ -649,7 +650,8 @@ class MockEngine:
                     self._sled.record_step(
                         wall_s=wall, kinds=("decode",),
                         decode_rows=len(decodes),
-                        live_tokens=len(decodes), sched_tokens=sig.b,
+                        live_tokens=len(decodes), sched_tokens=sig.n,
+                        rect_tokens=sig.b,
                         queue_depths=self._queue_depths())
                 for seq in decodes:
                     # grow blocks as generated tokens fill them

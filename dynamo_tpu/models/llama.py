@@ -3,10 +3,17 @@
 This is the engine's model math — the part the reference delegates to
 vLLM/SGLang/TRT-LLM (SURVEY.md §7: first-party JAX engine). Design points:
 
-- **One forward for prefill and decode.** A step processes ``T`` query
-  tokens per sequence (T=chunk for prefill, T=1 for decode) against a paged
-  KV cache addressed by per-request block tables. Static shapes per
+- **One forward for prefill and decode.** A step processes up to ``T``
+  query tokens per sequence (T=chunk for prefill, T=1 for decode) against a
+  paged KV cache addressed by per-request block tables. Static shapes per
   (batch-bucket, T-bucket) so XLA compiles once per bucket.
+- **Token-major outside attention.** Embedding, norms, projections, rope,
+  the KV scatter and the MLP run over ``[N, H]``: the step's live tokens,
+  rows packed end to end, in a bucket N that the caller picks (``B*T`` is
+  the plain rectangle, and what a decode step is). Only attention sees
+  rows: ``q`` is laid out ``[B, T, heads, D]`` for the kernel and its
+  output packed back (``TokenLayout``). A ragged mixed step so pays its
+  matmuls for the tokens it has, not for ``B x T`` positions.
 - **Layers are scanned** (``lax.scan`` over stacked layer params) so 80-layer
   models trace/compile in constant time. The whole KV cache is the scan's
   carry, written and read in place at (layer, block): no layer of it is
@@ -24,7 +31,7 @@ and MLP intermediate on the "model" mesh axis, experts on "expert".
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -144,13 +151,14 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary embedding, half-rotate (HF llama) convention.
 
-    x: [B, T, H, D]; positions: [B, T].
+    x: [..., H, D]; positions: [...] (token-major [N] in the step, [B, T]
+    for a rectangle).
     """
     d = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B,T,D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [...,D/2]
+    cos = jnp.cos(angles)[..., None, :]
+    sin = jnp.sin(angles)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -169,14 +177,15 @@ def _as_layers(cache):
 
 
 def _scatter_kv(cache, new: jax.Array, slot_idx: jax.Array, layer=None):
-    """Write new KV [B,T,KH,D] into layer ``layer`` of the paged cache
-    [L,NB,BS,KH,D] at flat slots, and return the whole cache: one scatter
-    on the buffer itself, which XLA does in place where the buffer is
-    donated and loop-carried. No layer of it is cut out and put back.
-    ``layer=None`` takes a single layer [NB,BS,KH,D] and returns one.
+    """Write new KV [N,KH,D] (or a rectangle [B,T,KH,D]) into layer
+    ``layer`` of the paged cache [L,NB,BS,KH,D] at flat slots, and return
+    the whole cache: one scatter on the buffer itself, which XLA does in
+    place where the buffer is donated and loop-carried. No layer of it is
+    cut out and put back. ``layer=None`` takes a single layer
+    [NB,BS,KH,D] and returns one.
 
-    slot_idx: [B,T] flat slot index (block*block_size + offset); padding
-    tokens point at the trash block (block 0).
+    slot_idx: [N] (or [B,T]) flat slot index (block*block_size + offset);
+    padding tokens point at the trash block (block 0).
 
     Quantized caches ({"q": int8 [L,NB,BS,KH,D], "s": f32 [L,NB,KH]})
     quantize at scatter time, symmetric per-block-per-head (engine/cache.py).
@@ -336,9 +345,9 @@ def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     top-k router weights). Exact for any E; the EP-sharded ragged-dispatch
     version lives in models/moe.py and is numerically equivalent.
 
-    x: [B, T, H]
+    x: [..., H] (token-major [N, H] in the step)
     """
-    b, t, h = x.shape
+    h = x.shape[-1]
     xt = x.reshape(-1, h)                                     # [N, H]
     logits = (xt.astype(jnp.float32)) @ lp["router"].astype(jnp.float32)  # [N, E]
     k = cfg.num_experts_per_tok
@@ -355,15 +364,66 @@ def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     out = jnp.einsum("neh,ne->nh", per_expert.astype(jnp.float32), gate_mask).astype(x.dtype)
     if cfg.num_shared_experts:
         out = out + swiglu(xt, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
-    return out.reshape(b, t, h)
+    return out.reshape(x.shape)
 
 
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
+class TokenLayout(NamedTuple):
+    """How a step's rows ``[B, T]`` map onto its tokens ``[N]``.
+
+    Tokens are the rows' live query tokens packed end to end (row 0's
+    ``q_len[0]`` tokens, then row 1's, ...), padded to the bucket N. The
+    dense layers run over tokens; attention runs over rows. ``tok_row`` /
+    ``tok_off`` name each token's rectangle position and ``row_tok`` each
+    rectangle position's token; padding on either side points at some live
+    neighbour, whose value is computed twice and read by nobody.
+
+    With ``N == B*T`` the packing is the rectangle itself, row-major: the
+    three index arrays are None and both moves are reshapes. That is a
+    decode step (T=1) and every caller that keeps a rectangle."""
+
+    b: int
+    t: int
+    tok_row: jax.Array | None = None   # [N] row of each token
+    tok_off: jax.Array | None = None   # [N] offset of each token in its row
+    row_tok: jax.Array | None = None   # [B, T] token at each row position
+
+    def to_rows(self, x: jax.Array) -> jax.Array:
+        """[N, ...] -> [B, T, ...]."""
+        if self.row_tok is None:
+            return x.reshape(self.b, self.t, *x.shape[1:])
+        return x[self.row_tok]
+
+    def to_tokens(self, x: jax.Array) -> jax.Array:
+        """[B, T, ...] -> [N, ...]."""
+        if self.tok_row is None:
+            return x.reshape(self.b * self.t, *x.shape[2:])
+        return x[self.tok_row, self.tok_off]
+
+
+def token_layout(q_len: jax.Array, b: int, t: int, n: int) -> tuple[
+        TokenLayout, jax.Array]:
+    """The layout of a step with ``q_len [B]`` live tokens a row in a
+    bucket of ``n`` tokens, and which of the n are live ``[N]``. The caller
+    sees to it that ``sum(q_len) <= n``: tokens past n would be dropped."""
+    if n == b * t:
+        valid = jnp.arange(t)[None, :] < q_len[:, None]
+        return TokenLayout(b, t), valid.reshape(-1)
+    ends = jnp.cumsum(q_len)
+    starts = ends - q_len
+    i = jnp.arange(n, dtype=q_len.dtype)
+    tok_row = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), b - 1)
+    tok_off = jnp.clip(i - starts[tok_row], 0, t - 1)
+    row_tok = jnp.minimum(
+        starts[:, None] + jnp.arange(t, dtype=q_len.dtype)[None, :], n - 1)
+    return TokenLayout(b, t, tok_row, tok_off, row_tok), i < ends[-1]
+
+
 def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
-           positions, slot, block_tables, q_start, kv_lens,
+           lay: TokenLayout, positions, slot, block_tables, q_start, kv_lens,
            attn_impl: str = "dense", attn_num_splits: int = 0,
            moe_impl: str = "dense", mesh=None, use_ring: bool = False):
     """One transformer layer over the WHOLE cache ([L,NB,BS,KH,D], or the
@@ -372,13 +432,20 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     (hidden, cache_k, cache_v). The one layer body of ``forward`` and of
     both pp schedules. Nothing here materialises a layer of the cache: the
     scatter, the kernel's DMAs and the dense gather all address the carried
-    buffer by layer index."""
-    b, t = positions.shape
+    buffer by layer index.
+
+    Token-major: ``hid [N, H]``, ``positions`` and ``slot [N]``. Norms,
+    the Q/K/V/O projections, rope, the scatter and the MLP run over the N
+    tokens; ``q`` alone is laid out as rows ``[B, T, heads, D]`` (``lay``)
+    for attention, and the attention output packed back to ``[N, q_size]``.
+    Ring attention takes K and V as rows too, and only a rectangle
+    (``N == B*T``), where those moves are reshapes."""
+    n = hid.shape[0]
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
     x = rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
-    q = mm(x, lp["wq"]).reshape(b, t, cfg.num_heads, cfg.head_dim)
-    k = mm(x, lp["wk"]).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-    v = mm(x, lp["wv"]).reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+    q = mm(x, lp["wq"]).reshape(n, cfg.num_heads, cfg.head_dim)
+    k = mm(x, lp["wk"]).reshape(n, cfg.num_kv_heads, cfg.head_dim)
+    v = mm(x, lp["wv"]).reshape(n, cfg.num_kv_heads, cfg.head_dim)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     # Phase hooks (obs/profiler.py): jax.named_scope annotations for
@@ -387,11 +454,13 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     with _perf_phase("scatter"):
         cache_k = _scatter_kv(cache_k, k, slot, layer)
         cache_v = _scatter_kv(cache_v, v, slot, layer)
+    q = lay.to_rows(q)                                       # [B,T,heads,D]
     if use_ring:
         from dynamo_tpu.ops.ring_attention import ring_attention_prefill
 
         with _perf_phase("attention"):
-            attn = ring_attention_prefill(mesh, q, k, v, kv_lens)
+            attn = ring_attention_prefill(
+                mesh, q, lay.to_rows(k), lay.to_rows(v), kv_lens)
     elif attn_impl in ("pallas", "pallas_interpret"):
         from dynamo_tpu.ops.paged_attention import (
             paged_attention_kernel,
@@ -418,8 +487,11 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
             ctx_k = _gather_kv(cache_k, block_tables, layer)
             ctx_v = _gather_kv(cache_v, block_tables, layer)
         with _perf_phase("attention"):
-            attn = paged_attention(q, ctx_k, ctx_v, positions, kv_lens)
-    hid = hid + mm(attn.reshape(b, t, cfg.q_size), lp["wo"])
+            attn = paged_attention(
+                q, ctx_k, ctx_v,
+                q_start[:, None] + jnp.arange(lay.t)[None, :], kv_lens)
+    attn = lay.to_tokens(attn).reshape(n, cfg.q_size)
+    hid = hid + mm(attn, lp["wo"])
     x = rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
     if cfg.is_moe:
         if moe_impl == "ep":
@@ -457,6 +529,18 @@ def _run_layers(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, **kw):
     return carry
 
 
+def _positions_and_slots(lay: TokenLayout, valid, q_start, block_tables,
+                         bs: int):
+    """Position and flat cache slot of each token [N]: read off the rows'
+    [B, T] rectangles of both (integers, small). Padding → trash block 0."""
+    pos_rows = q_start[:, None] + jnp.arange(lay.t)[None, :]       # [B, T]
+    blk = jnp.take_along_axis(
+        block_tables,
+        jnp.clip(pos_rows // bs, 0, block_tables.shape[1] - 1), axis=1)
+    slot = jnp.where(valid, lay.to_tokens(blk * bs + pos_rows % bs), 0)
+    return lay.to_tokens(pos_rows), slot
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
@@ -475,15 +559,24 @@ def forward(
     embed_mask: jax.Array | None = None,      # [B, T] True → use override
     pp_microbatches: int = 0,                 # pp>1: schedule depth (0 = auto)
     attn_num_splits: int = 0,                 # split-K: 0 auto, 1 off, N forced
+    num_tokens: int | None = None,            # token bucket N (None = B*T)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One engine step. Returns (last_hidden [B,H], cache_k, cache_v) —
     or (hidden [B,T,H], ...) with ``return_all_hidden`` (the speculative
     verify step needs logits at every chunk position).
 
-    Query token t of sequence b sits at position q_start[b]+t; its KV is
+    Query token j of sequence b sits at position q_start[b]+j; its KV is
     written into the cache slot named by the block table; attention sees all
     cache positions ≤ its own. Works unchanged for prefill chunks (T>1) and
     decode (T=1).
+
+    The inputs are rows; the work is over tokens. The rows' live tokens are
+    packed into ``[N, H]`` (``num_tokens``, static; the caller picks a bucket
+    that holds ``sum(q_len)``) and stay so through every layer, as rows only
+    inside attention (``TokenLayout``). None, or B*T, is the rectangle:
+    every position a token, what T=1 is anyway. Ring prefill and pp keep the
+    rectangle (attention over "seq" shards it; the pp schedules cut
+    microbatches from it).
     """
     b, t = token_ids.shape
     bs = _cache_block_size(cache_k)
@@ -523,36 +616,41 @@ def forward(
         sp_prefill and sp > 1 and t > 1 and t % sp == 0
         and cfg.num_kv_heads % tp == 0 and b % dp == 0
     )
-    positions = q_start[:, None] + jnp.arange(t)[None, :]          # [B, T]
-    valid = jnp.arange(t)[None, :] < q_len[:, None]                # [B, T]
+    n = b * t if num_tokens is None or use_ring else num_tokens
+    lay, valid = token_layout(q_len, b, t, n)
+    positions, slot = _positions_and_slots(
+        lay, valid, q_start, block_tables, bs)                     # [N]
     kv_lens = q_start + q_len                                      # [B]
 
-    # Flat cache slot per query token; padding → trash block 0.
-    blk = jnp.take_along_axis(
-        block_tables, jnp.clip(positions // bs, 0, block_tables.shape[1] - 1), axis=1
-    )                                                              # [B, T]
-    slot = jnp.where(valid, blk * bs + positions % bs, 0)
-
-    h = embed_lookup(params["embed"], token_ids, _dtype(cfg))      # [B, T, H]
+    h = embed_lookup(params["embed"], lay.to_tokens(token_ids),
+                     _dtype(cfg))                                  # [N, H]
     if embed_override is not None:
         # Multimodal positions carry encoder outputs instead of token
         # embeddings (their placeholder ids exist only for position/hash
         # bookkeeping — see preprocessor digest-salted placeholders).
-        h = jnp.where(embed_mask[..., None], embed_override.astype(h.dtype), h)
+        h = jnp.where(lay.to_tokens(embed_mask)[:, None],
+                      lay.to_tokens(embed_override).astype(h.dtype), h)
 
     h, cache_k, cache_v = _run_layers(
-        cfg, params["layers"], h, cache_k, cache_v, positions=positions,
-        slot=slot, block_tables=block_tables, q_start=q_start,
-        kv_lens=kv_lens, attn_impl=attn_impl, attn_num_splits=attn_num_splits,
-        moe_impl=moe_impl, mesh=mesh, use_ring=use_ring)
+        cfg, params["layers"], h, cache_k, cache_v, lay=lay,
+        positions=positions, slot=slot, block_tables=block_tables,
+        q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
+        attn_num_splits=attn_num_splits, moe_impl=moe_impl, mesh=mesh,
+        use_ring=use_ring)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
 
     if return_all_hidden:
-        return h, cache_k, cache_v                                 # [B, T, H]
-    # Hidden state at each sequence's last valid query token.
-    last_idx = jnp.clip(q_len - 1, 0, t - 1)                       # [B]
-    last_h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]  # [B, H]
-    return last_h, cache_k, cache_v
+        return lay.to_rows(h), cache_k, cache_v                    # [B, T, H]
+    return _last_hidden(h, lay, q_len), cache_k, cache_v
+
+
+def _last_hidden(h: jax.Array, lay: TokenLayout, q_len: jax.Array) -> jax.Array:
+    """Hidden state [B, H] at each row's last live token, out of [N, H]."""
+    last_off = jnp.clip(q_len - 1, 0, lay.t - 1)                   # [B]
+    if lay.row_tok is None:
+        return jnp.take_along_axis(
+            lay.to_rows(h), last_off[:, None, None], axis=1)[:, 0]
+    return h[jnp.take_along_axis(lay.row_tok, last_off[:, None], axis=1)[:, 0]]
 
 
 def forward_pp(
@@ -603,13 +701,12 @@ def forward_pp(
     nblk = block_tables.shape[1]
     from jax.sharding import PartitionSpec as P
 
-    positions = q_start[:, None] + jnp.arange(t)[None, :]
-    valid = jnp.arange(t)[None, :] < q_len[:, None]
-    blk = jnp.take_along_axis(
-        block_tables, jnp.clip(positions // bs, 0, block_tables.shape[1] - 1), axis=1
-    )
-    slot = jnp.where(valid, blk * bs + positions % bs, 0)
-    h0 = embed_lookup(params["embed"], token_ids, _dtype(cfg))
+    # The schedules cut microbatches from the [B, T] rectangle, so pp keeps
+    # it: each microbatch is a rectangle [B', T'] whose B'*T' positions are
+    # its tokens (TokenLayout's reshape case).
+    lay, valid = token_layout(q_len, b, t, b * t)
+    positions, slot = _positions_and_slots(lay, valid, q_start, block_tables, bs)
+    h0 = embed_lookup(params["embed"], token_ids.reshape(-1), _dtype(cfg))
 
     # Microbatch count: the largest divisor of the split axis ≤ the target
     # (default 2*pp — enough for ~2/3+ steady-state efficiency without
@@ -628,16 +725,16 @@ def forward_pp(
                 "pp>1 bucket (b=%d, t=%d) too small to microbatch: serving "
                 "the sequential dense-attention pipeline", b, t)
         return _forward_pp_sequential(
-            params, cfg, positions, q_start + q_len, slot, block_tables,
-            cache_k, cache_v, mesh, h0, q_len, pp)
+            params, cfg, lay, positions, q_start, q_start + q_len, slot,
+            block_tables, cache_k, cache_v, mesh, h0, q_len, pp)
 
-    # Per-microbatch statics, uniformly [M, B', T', ...].
+    # Per-microbatch statics, uniformly [M, B'*T', ...] (token-major).
     if split_t:
         tm = t // m
         bm = b
-        h0_mb = h0.reshape(b, m, tm, -1).swapaxes(0, 1)
-        pos_mb = positions.reshape(b, m, tm).swapaxes(0, 1)
-        slot_mb = slot.reshape(b, m, tm).swapaxes(0, 1)
+        h0_mb = h0.reshape(b, m, tm, -1).swapaxes(0, 1).reshape(m, b * tm, -1)
+        pos_mb = positions.reshape(b, m, tm).swapaxes(0, 1).reshape(m, -1)
+        slot_mb = slot.reshape(b, m, tm).swapaxes(0, 1).reshape(m, -1)
         bt_mb = jnp.broadcast_to(block_tables[None], (m, b, nblk))
         qs_mb = q_start[None, :] + (jnp.arange(m) * tm)[:, None]
         # visible context after sub-chunk c = everything ≤ its last valid
@@ -647,12 +744,14 @@ def forward_pp(
     else:
         tm = t
         bm = b // m
-        h0_mb = h0.reshape(m, bm, t, -1)
-        pos_mb = positions.reshape(m, bm, t)
-        slot_mb = slot.reshape(m, bm, t)
+        h0_mb = h0.reshape(m, bm * t, -1)
+        pos_mb = positions.reshape(m, bm * t)
+        slot_mb = slot.reshape(m, bm * t)
         bt_mb = block_tables.reshape(m, bm, nblk)
         qs_mb = q_start.reshape(m, bm)
         kl_mb = (q_start + q_len).reshape(m, bm)
+
+    lay_mb = TokenLayout(bm, tm)
 
     def pp_fn(lp_stack, ck_loc, cv_loc, h0_mb, pos_mb, slot_mb, bt_mb, qs_mb, kl_mb):
         s = lax.axis_index("pipe")
@@ -668,7 +767,7 @@ def forward_pp(
             slot_t = jnp.where(live, slot_mb[mbc], 0)
             h_in = jnp.where(s == 0, h0_mb[mbc], h_cur)
             h_out, ck, cv = _run_layers(
-                cfg, lp_stack, h_in, ck, cv, positions=pos_mb[mbc],
+                cfg, lp_stack, h_in, ck, cv, lay=lay_mb, positions=pos_mb[mbc],
                 slot=slot_t, block_tables=bt_mb[mbc], q_start=qs_mb[mbc],
                 kv_lens=kl_mb[mbc], attn_impl=attn_impl,
                 attn_num_splits=attn_num_splits)
@@ -690,30 +789,28 @@ def forward_pp(
     )
     out, cache_k, cache_v = fn(params["layers"], cache_k, cache_v,
                                h0_mb, pos_mb, slot_mb, bt_mb, qs_mb, kl_mb)
-    h = out.swapaxes(0, 1).reshape(b, t, -1) if split_t else out.reshape(b, t, -1)
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    last_idx = jnp.clip(q_len - 1, 0, t - 1)
-    last_h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    return last_h, cache_k, cache_v
+    if split_t:
+        out = out.reshape(m, b, tm, -1).swapaxes(0, 1)
+    h = rms_norm(out.reshape(b * t, -1), params["final_norm"], cfg.rms_norm_eps)
+    return _last_hidden(h, lay, q_len), cache_k, cache_v
 
 
-def _forward_pp_sequential(params, cfg, positions, kv_lens, slot, block_tables,
-                           cache_k, cache_v, mesh, h0, q_len, pp):
+def _forward_pp_sequential(params, cfg, lay, positions, q_start, kv_lens, slot,
+                           block_tables, cache_k, cache_v, mesh, h0, q_len, pp):
     """Fallback pipeline for shapes too small to microbatch (e.g. a lone
     decode row): pp select-and-broadcast rounds — every stage computes the
     full batch each round, round i keeps stage i's result. Efficiency 1/pp;
     correctness identical. Dense attention only (the warning at the call
     site covers the kernel case)."""
-    b, t = positions.shape
     from jax.sharding import PartitionSpec as P
 
     def pp_fn(lp_stack, ck_local, cv_local, h):
         s = lax.axis_index("pipe")
         for i in range(pp):
             h_out, ck_new, cv_new = _run_layers(
-                cfg, lp_stack, h, ck_local, cv_local, positions=positions,
-                slot=slot, block_tables=block_tables, q_start=None,
-                kv_lens=kv_lens)
+                cfg, lp_stack, h, ck_local, cv_local, lay=lay,
+                positions=positions, slot=slot, block_tables=block_tables,
+                q_start=q_start, kv_lens=kv_lens)
             keep = s == i
             # tree_map: quantized caches are {"q","s"} pytrees.
             ck_local = jax.tree.map(lambda a, b: jnp.where(keep, a, b),
@@ -731,9 +828,7 @@ def _forward_pp_sequential(params, cfg, positions, kv_lens, slot, block_tables,
     )
     h, cache_k, cache_v = fn(params["layers"], cache_k, cache_v, h0)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    last_idx = jnp.clip(q_len - 1, 0, t - 1)
-    last_h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
-    return last_h, cache_k, cache_v
+    return _last_hidden(h, lay, q_len), cache_k, cache_v
 
 
 def logits_from_hidden(params: Params, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:

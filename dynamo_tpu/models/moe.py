@@ -81,9 +81,10 @@ def _dropless_rows(xt, topi, weights, w_gate, w_up, w_down, e_lo, e_local):
 
 def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
                      mesh=None) -> jax.Array:
-    """Dropless MoE FFN. x: [B, T, H] → [B, T, H]; exact vs the dense
-    reference under ANY routing skew (tests/test_moe.py pressure tests)."""
-    b, t, h = x.shape
+    """Dropless MoE FFN. x: [..., H] → [..., H] (token-major [N, H] in the
+    step); exact vs the dense reference under ANY routing skew
+    (tests/test_moe.py pressure tests)."""
+    b, h = x.shape[0], x.shape[-1]
     e = cfg.num_experts
     ep = mesh.shape.get("expert", 1) if mesh is not None else 1
 
@@ -125,8 +126,9 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
         y = lax.psum(y, ("expert", "model"))
         return y.astype(x3.dtype).reshape(x3.shape)
 
-    # Batch rides the "data" axis when it divides; odd buckets (e.g. the
-    # B=1 prefill bucket on a dp>1 mesh) fall back to replicated batch.
+    # The leading axis (tokens, or a rectangle's batch) rides the "data"
+    # axis when it divides; odd buckets (e.g. the B=1 prefill bucket on a
+    # dp>1 mesh) fall back to replicated.
     batch_spec = P("data") if b % mesh.shape.get("data", 1) == 0 else P()
     args = [x, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"]]
     # Weight specs mirror PARAM_RULES (parallel/mesh.py): experts on
@@ -157,17 +159,17 @@ def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
 
 def moe_mlp_ep(x: jax.Array, lp: Params, cfg: ModelConfig,
                capacity_factor: float = 2.0) -> jax.Array:
-    """Capacity-based EP MoE FFN. x: [B, T, H] → [B, T, H].
+    """Capacity-based EP MoE FFN. x: [..., H] → [..., H].
 
     The dispatch/combine tensors route each token's top-k expert choices to
     per-expert buffers of C slots; choice order is priority order (a token's
     1st choice wins slots over another token's 2nd choice at equal index by
     flattened position).
     """
-    b, t, h = x.shape
-    n = b * t
+    h = x.shape[-1]
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    xt = x.reshape(n, h)
+    xt = x.reshape(-1, h)
+    n = xt.shape[0]
     topi, weights = _router_topk(xt, lp, cfg)                            # [N, k]
 
     cap = expert_capacity(n, e, k, capacity_factor)
@@ -205,4 +207,4 @@ def moe_mlp_ep(x: jax.Array, lp: Params, cfg: ModelConfig,
         from dynamo_tpu.models.llama import swiglu
 
         y = y + swiglu(xt, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
-    return y.reshape(b, t, h)
+    return y.reshape(x.shape)

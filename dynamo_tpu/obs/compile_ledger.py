@@ -6,7 +6,8 @@ compile blocks the engine-core thread for its full trace+compile wall. This
 module makes those stalls observable and schedulable:
 
 * ``CompileLedger`` — process-global record of every compile event keyed by
-  bucket signature ``(kind, b, t, nblk, greedy, kv_dtype)``: wall seconds,
+  bucket signature ``(kind, b, t, nblk, greedy, kv_dtype)``, whose token
+  bucket ``n`` follows from ``(kind, b, t)``: wall seconds,
   trigger timestamp, the victim request's trace id, and the live
   compile-cache inventory. Serve-path events additionally emit
   ``engine.compile`` spans into the Tracer/FlightRecorder so
@@ -67,6 +68,45 @@ def _pow2_bucket(n: int, lo: int, hi: int) -> int:
     return b
 
 
+def token_bucket(kind: str, b: int, t: int) -> int:
+    """N: the tokens a ``(b, t)`` step program runs its dense layers over
+    (models/llama.py ``forward(num_tokens=N)``); attention alone runs over
+    the ``b x t`` rectangle. One N for each ``(kind, b, t)``, so the
+    lattice has no dimension for it.
+
+    A step with prefill work ("mixed", "prefill") holds one chunk of up to
+    t tokens beside up to b - 1 one-token rows, so ``t + b`` tokens hold
+    it; the rectangle where that is smaller (one row). A step whose chunks
+    hold more is cut into several programs (``pack_rows``), never padded
+    to the rectangle. Every other kind is the rectangle: a decode row is
+    its one token (``b``), a verify row fills its chunk."""
+    if kind in ("mixed", "prefill"):
+        return min(b * t, t + b)
+    return b * t
+
+
+def pack_rows(lengths: list[int], ec, mixed: bool) -> list[int]:
+    """Cut a batch's rows, in dispatch order, into runs that each fit the
+    token bucket of the program their own ``(b, t)`` buckets name; returns
+    the rows in each run. ``lengths`` is each row's live tokens. A run
+    grows while its tokens fit: several short chunks share a program, two
+    full chunks do not. One row always fits, and so do one chunk and the
+    one-token rows before it (``t + b > t_max + n - 1``): a decode batch,
+    or a mixed step with one chunk, is one run as it always was."""
+    ladder = ec.decode_bucket if mixed else (1, 2, 4, 8)
+    runs: list[int] = []
+    n = t_max = total = 0
+    for length in lengths:
+        n, t_max, total = n + 1, max(t_max, length), total + length
+        if n > 1 and t_max > 1 and total > token_bucket(
+                "mixed", _bucket(n, ladder),
+                _pow2_bucket(t_max, 16, ec.prefill_chunk)):
+            runs.append(n - 1)
+            n, t_max, total = 1, length, length
+    runs.append(n)
+    return runs
+
+
 @dataclass(frozen=True)
 class BucketSig:
     """One compiled program's bucket signature. ``kind`` is one of
@@ -74,7 +114,8 @@ class BucketSig:
     argmax-only fast path variant (always True for verify/embed). "mixed"
     is the unified ragged step (decode rows + a prefill chunk in one
     launch): b buckets over the DECODE ladder, t over the prefill chunk
-    ladder — the program itself is the same ragged step fn either way."""
+    ladder — the program itself is the same ragged step fn either way.
+    ``n`` is the program's token bucket, read off ``(kind, b, t)``."""
 
     kind: str
     b: int
@@ -83,9 +124,13 @@ class BucketSig:
     greedy: bool
     kv_dtype: str
 
+    @property
+    def n(self) -> int:
+        return token_bucket(self.kind, self.b, self.t)
+
     def to_dict(self) -> dict:
         return {"kind": self.kind, "b": self.b, "t": self.t,
-                "nblk": self.nblk, "greedy": self.greedy,
+                "nblk": self.nblk, "n": self.n, "greedy": self.greedy,
                 "kv_dtype": self.kv_dtype}
 
 
@@ -436,7 +481,9 @@ def enumerate_buckets(ec) -> list[BucketSig]:
 def sig_for_rows(kind: str, n_rows: int, t_max: int, nblk_need: int,
                  ec, greedy: bool = True) -> BucketSig:
     """Bucket signature for a dispatched batch — the device-free mirror of
-    dispatch()'s geometry math, used by the mocker and tests."""
+    dispatch()'s geometry math, used by the mocker and tests. The batch is
+    one run of ``pack_rows``; the signature's ``n`` is the ``[N, H]`` its
+    dense layers compute, ``b x t`` the rows its attention sees."""
     kv = ec.kv_dtype if getattr(ec, "kv_dtype", None) else "bfloat16"
     max_nblk = -(-ec.max_model_len // ec.block_size)
     nblk = min(_pow2_bucket(max(nblk_need, 1), 4, max_nblk), max_nblk)

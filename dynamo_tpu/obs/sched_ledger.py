@@ -44,7 +44,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
-from dynamo_tpu.obs.compile_ledger import _bucket, _pow2_bucket
+from dynamo_tpu.obs.compile_ledger import _bucket, _pow2_bucket, token_bucket
 from dynamo_tpu.utils.metrics import MetricsRegistry
 
 SCHED_ENV = "DYN_SCHED_LEDGER"
@@ -203,7 +203,8 @@ class SchedStepRecord:
     decode_rows: int = 0
     decode_window: int = 1
     live_tokens: int = 0            # tokens the plan actually needed
-    sched_tokens: int = 0           # tokens the padded buckets computed
+    sched_tokens: int = 0           # tokens the dense layers computed (N)
+    rect_tokens: int = 0            # positions attention ran over (b x t)
     live_flops: float = 0.0
     sched_flops: float = 0.0
     live_bytes: float = 0.0
@@ -229,6 +230,7 @@ class SchedStepRecord:
             "decode_window": self.decode_window,
             "live_tokens": self.live_tokens,
             "sched_tokens": self.sched_tokens,
+            "rect_tokens": self.rect_tokens,
             "goodput": round(self.goodput, 4),
             "budget_util": round(self.budget_util, 4),
         }
@@ -269,6 +271,7 @@ class SchedLedger:
         self.steps_total = 0
         self.live_tokens_total = 0
         self.sched_tokens_total = 0
+        self.rect_tokens_total = 0
         self.padding_flops_total = 0.0
         self.padding_bytes_total = 0.0
         self.hol_stall_seconds_total = 0.0
@@ -298,6 +301,7 @@ class SchedLedger:
             self.steps_total = 0
             self.live_tokens_total = 0
             self.sched_tokens_total = 0
+            self.rect_tokens_total = 0
             self.padding_flops_total = 0.0
             self.padding_bytes_total = 0.0
             self.hol_stall_seconds_total = 0.0
@@ -355,6 +359,7 @@ class SchedLedger:
         decode_window: int = 1,
         live_tokens: int = 0,
         sched_tokens: int = 0,
+        rect_tokens: int = 0,
         live_flops: float = 0.0,
         sched_flops: float = 0.0,
         live_bytes: float = 0.0,
@@ -384,6 +389,7 @@ class SchedLedger:
             prefill_rows=prefill_rows, decode_rows=decode_rows,
             decode_window=decode_window,
             live_tokens=live_tokens, sched_tokens=sched_tokens,
+            rect_tokens=rect_tokens,
             live_flops=live_flops, sched_flops=sched_flops,
             live_bytes=live_bytes, sched_bytes=sched_bytes,
             goodput=goodput, budget_util=budget_util,
@@ -425,6 +431,7 @@ class SchedLedger:
             self.steps_total += 1
             self.live_tokens_total += live_tokens
             self.sched_tokens_total += sched_tokens
+            self.rect_tokens_total += rect_tokens
             self.padding_flops_total += pad_f
             self.padding_bytes_total += pad_b
             if rec.hol_victims:
@@ -472,6 +479,7 @@ class SchedLedger:
                                        if recent else 0.0),
                 "live_tokens_total": self.live_tokens_total,
                 "sched_tokens_total": self.sched_tokens_total,
+                "rect_tokens_total": self.rect_tokens_total,
                 "padding_flops_total": self.padding_flops_total,
                 "padding_hbm_bytes_total": self.padding_bytes_total,
                 "admission_blocked": dict(self.blocked_totals),
@@ -559,12 +567,16 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
 
     Unified "mixed" batches (decode rows + prefill chunks in one launch)
     price as: live = per-row exact tokens/contexts, scheduled = the mixed
-    program's b (DECODE row ladder) × t (prefill chunk ladder) envelope.
-    ``mixed_dec_rows`` is the plan-time decode-row count of the step's
-    mixed batch (leading rows), splitting prefill_rows/decode_rows.
+    program's token bucket N through the dense layers (``token_bucket`` of
+    its b over the DECODE row ladder and t over the prefill chunk ladder)
+    and its b × t rows through attention. ``mixed_dec_rows`` is the
+    plan-time decode-row count of the step's mixed batches (the leading
+    rows of the first), splitting prefill_rows/decode_rows.
 
     Returns {kinds, prefill_rows, decode_rows, live_tokens, sched_tokens,
-    live_flops, sched_flops, live_bytes, sched_bytes}.
+    rect_tokens, live_flops, sched_flops, live_bytes, sched_bytes}:
+    ``sched_tokens`` is what the dense layers computed, ``rect_tokens`` the
+    positions of the attention rectangles.
     """
     from dynamo_tpu.obs import costmodel as cm
 
@@ -576,7 +588,8 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
     live = {"tokens": 0, "logit_rows": 0, "attn_q_ctx": 0.0, "kv_blocks": 0.0}
     sched = {"tokens": 0, "logit_rows": 0, "attn_q_ctx": 0.0, "kv_blocks": 0.0}
     kinds: list[str] = []
-    pf_rows = dec_rows = 0
+    pf_rows = dec_rows = rect = 0
+    dec_left = mixed_dec_rows
     for kind, rows, _sample_rows, toks, _lps in batches:
         if not rows:
             continue
@@ -614,7 +627,8 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
             # construction; the split is captured at plan time because
             # prefill_target() moves as finalize appends tokens.
             kinds.append("mixed" if t_max > 1 else "decode")
-            d = min(mixed_dec_rows, n)
+            d = min(dec_left, n)
+            dec_left -= d
             dec_rows += d
             pf_rows += n - d
         elif kind == "verify":
@@ -642,6 +656,7 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
             # scheduled: b padded rows x window positions at the bucketed
             # block-table width
             sched["tokens"] += b * window
+            rect += b * window
             sched["logit_rows"] += b * window
             sched["attn_q_ctx"] += b * window * nblk * bs
             sched["kv_blocks"] += b * window * nblk
@@ -652,7 +667,8 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
                 nb = -(-(start + length) // bs)
                 live["attn_q_ctx"] += length * nb * bs
                 live["kv_blocks"] += nb
-            sched["tokens"] += b * t
+            sched["tokens"] += token_bucket(kind, b, t)
+            rect += b * t
             sched["logit_rows"] += b
             sched["attn_q_ctx"] += b * t * nblk * bs
             sched["kv_blocks"] += b * nblk
@@ -672,6 +688,7 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
         "decode_rows": dec_rows,
         "live_tokens": live["tokens"],
         "sched_tokens": sched["tokens"],
+        "rect_tokens": rect,
         "live_flops": lc.flops if lc else 0.0,
         "sched_flops": sc.flops if sc else 0.0,
         "live_bytes": lc.hbm_bytes if lc else 0.0,

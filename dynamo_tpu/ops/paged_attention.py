@@ -33,6 +33,13 @@ inside vLLM/TRT-LLM, which we replace):
   real block, so every grid step past it re-requests the same HBM block and
   Pallas elides the DMA (revisited block ⇒ no copy), while pl.when skips the
   matmuls. Batch cost is proportional to total context, not B × max_blocks.
+  The same holds along a row's query chunks: the counts are per (row,
+  query chunk), so a chunk past the row's live tokens walks nothing (a
+  one-token row in a T=512 rectangle pays one chunk of eight), and a live
+  chunk stops at the last block its own last position can see (the causal
+  mask hides the rest). Both leave every live position's result as it
+  was, bit for bit: a block whose every score is masked changes no running
+  state. With one query chunk a row (decode) the counts are the row's own.
 - block tables + positions are scalar-prefetched (PrefetchScalarGridSpec)
   so the K/V BlockSpec index maps can address HBM blocks by table lookup —
   the DMA pipeline chases the page table, the kernel body never sees HBM.
@@ -95,7 +102,9 @@ def scalar_prefetch_bytes(*, batch: int, nblk: int, num_blocks: int = 0,
                           kv_heads: int = 0) -> int:
     """SMEM bytes of the kernel's scalar-prefetch operands. Each row of a
     2-D operand pads to whole 128-lane words (512 B): the ``[B, NBLK]``
-    block table, three ``[B]`` vectors, the ``[1]`` layer index and, for a
+    block table, three ``[B]`` vectors (one of them ``[B x query chunks]``
+    for a prefill rectangle: 2 KB at most, inside the slack of
+    ``SMEM_USABLE_BYTES``), the ``[1]`` layer index and, for a
     quantized cache (``num_blocks`` and ``kv_heads`` given), the two
     ``[NB, KH]`` float32 scale sidecars of ONE layer — which is what bounds
     a quantized pool to ~1,000 blocks, and the block table to ~4k blocks a
@@ -180,8 +189,8 @@ def resolve_num_splits(num_splits: int, *, nblk: int, batch: int,
     return max(1, min(num_splits, nblk))
 
 
-def _kernel(*refs, bs: int, kh: int, rep: int, spb: int, quant: bool,
-            int4: bool, split: bool):
+def _kernel(*refs, bs: int, kh: int, rep: int, spb: int, nq: int,
+            quant: bool, int4: bool, split: bool):
     if quant:
         # Scales ride the scalar-prefetch channel with the block table, so
         # dequant needs no extra DMA: the int8/int4 block is widened
@@ -196,7 +205,7 @@ def _kernel(*refs, bs: int, kh: int, rep: int, spb: int, quant: bool,
          acc_ref, m_ref, l_ref) = refs
     else:
         (q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref) = refs
-    del ub_ref, ly_ref  # consumed by the index maps (DMA clamp, layer), not the body
+    del ly_ref  # consumed by the index maps (layer), not the body
     b = pl.program_id(0)
     qi = pl.program_id(1)
     si = pl.program_id(2)
@@ -211,7 +220,10 @@ def _kernel(*refs, bs: int, kh: int, rep: int, spb: int, quant: bool,
 
     kv_len = kl_ref[b]
 
-    @pl.when(g * bs < kv_len)
+    # Blocks this query chunk of the row can see (the same count clamps the
+    # K/V DMAs in the index map): none past the row's context, none past
+    # the chunk's own last position, none at all for a chunk of padding.
+    @pl.when(g < ub_ref[b if nq == 1 else b * nq + qi])
     def _compute():
         r = q_ref.shape[2]  # rows in this q chunk (row = token*rep + q-head)
         # Causal/visibility mask is head-independent: [R, BS].
@@ -385,11 +397,21 @@ def paged_attention_kernel(
     spb = -(-nblk // ns)  # context blocks walked per split
     split = ns > 1
 
-    # Ragged early-exit: rows see DMAs only up to their last used block —
-    # past it the clamped index map re-requests the same block and Pallas
-    # elides the copy (compute is already pl.when-gated on kv_len).
-    used_blocks = jnp.clip((kv_lens.astype(jnp.int32) + bs - 1) // bs,
-                           0, nblk)
+    # Ragged early-exit: each query chunk of a row sees DMAs only up to
+    # the last block it can see — past it the clamped index map re-requests
+    # the same block and Pallas elides the copy (compute is pl.when-gated
+    # on the same count). Chunk c holds rows c*rchunk.. of the row's slab,
+    # row r being query token r // rep: it sees context up to its own last
+    # position and within kv_len, and nothing if it starts at or past
+    # kv_len (a row's live query tokens end there: the chunk is padding).
+    # [B * NQ], row-major; with NQ == 1 that is the row's used blocks.
+    qs32, kl32 = q_start.astype(jnp.int32), kv_lens.astype(jnp.int32)
+    chunk = jnp.arange(nq, dtype=jnp.int32)
+    first = qs32[:, None] + (chunk * rchunk) // rep
+    last = qs32[:, None] + ((chunk + 1) * rchunk - 1) // rep
+    seen = jnp.where(first < kl32[:, None],
+                     jnp.minimum(kl32[:, None], last + 1), 0)
+    used_blocks = jnp.clip((seen + bs - 1) // bs, 0, nblk).reshape(-1)
 
     # Index maps see all scalar-prefetch refs after the grid indices
     # (bt, q_start, kv_lens, used_blocks, layer[, k_scale, v_scale]).
@@ -399,14 +421,15 @@ def paged_attention_kernel(
     def kvmap(bi, qi, si, jj, *prefetch):
         bt, ub, ly = prefetch[0], prefetch[3], prefetch[4]
         g = si * spb + jj
-        clamped = jnp.minimum(g, jnp.maximum(ub[bi] - 1, 0))
+        used = ub[bi if nq == 1 else bi * nq + qi]
+        clamped = jnp.minimum(g, jnp.maximum(used - 1, 0))
         return (ly[0], bt[bi, clamped], 0, 0, 0)
 
     def omap_split(bi, qi, si, jj, *_prefetch):
         return (bi, si, 0, qi, 0)
 
-    scalars = (block_tables.astype(jnp.int32), q_start.astype(jnp.int32),
-               kv_lens.astype(jnp.int32), used_blocks, layer.reshape(1))
+    scalars = (block_tables.astype(jnp.int32), qs32, kl32, used_blocks,
+               layer.reshape(1))
     if quant:
         scalars = scalars + (k_scale, v_scale)
 
@@ -442,7 +465,7 @@ def paged_attention_kernel(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, kh=kh, rep=rep, spb=spb,
+        functools.partial(_kernel, bs=bs, kh=kh, rep=rep, spb=spb, nq=nq,
                           quant=quant, int4=int4, split=split),
         grid_spec=grid_spec,
         out_shape=out_shape,

@@ -102,10 +102,14 @@ class EngineConfig:
     decode_bucket: tuple[int, ...] = (8, 16, 32, 64)
     # Unified ragged mixed-phase steps: pack the step's decode rows (one
     # live token each) and prefill-chunk rows (up to prefill_chunk live
-    # tokens) into ONE ragged XLA program per iteration — per-row live
-    # token counts ride the scalar-prefetch path, so padding costs
-    # DMA-elided grid steps, not FLOPs. False = legacy two-launch path
-    # (decode program, then prefill program) for bisection.
+    # tokens) into ONE ragged XLA program per iteration. Its dense layers
+    # run over the rows' live tokens, packed into a bucket of t + b
+    # (models/llama.py forward, compile_ledger.token_bucket); only the
+    # attention kernel runs over the [b, t] rows, where a padded position
+    # still costs its grid step (on the [b, t] rectangle all through, the
+    # matmuls paid for 85 % padding: PERF.md section 6, PR 32). False =
+    # legacy two-launch path (decode program, then prefill program) for
+    # bisection.
     unified_step: bool = True
     # Decode inter-token-latency SLO budget (milliseconds) that
     # costmodel.auto_prefill_chunk sizes chunks against when

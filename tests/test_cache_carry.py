@@ -30,18 +30,19 @@ def _reference_forward(params, cfg, token_ids, q_start, q_len, block_tables,
     ``cache[l]`` out, writes the step's K/V into that slice, attends over
     the slice and stacks the slices back."""
     b, t = token_ids.shape
+    bs, nblk = llama._cache_block_size(cache_k), block_tables.shape[1]
     positions = q_start[:, None] + jnp.arange(t)[None, :]
     valid = jnp.arange(t)[None, :] < q_len[:, None]
     kv_lens = q_start + q_len
     blk = jnp.take_along_axis(
-        block_tables, jnp.clip(positions // BS, 0, NBLK - 1), axis=1)
-    slot = jnp.where(valid, blk * BS + positions % BS, 0)
+        block_tables, jnp.clip(positions // bs, 0, nblk - 1), axis=1)
+    slot = jnp.where(valid, blk * bs + positions % bs, 0)
 
     def scatter(layer_cache, new):
         if isinstance(layer_cache, dict):
             # The quantized write of ONE layer (its own tests: test_kv_quant).
             return llama._scatter_kv(layer_cache, new, slot)
-        nb, bs, kh, d = layer_cache.shape
+        nb, _, kh, d = layer_cache.shape
         flat = layer_cache.reshape(nb * bs, kh, d)
         flat = flat.at[slot.reshape(-1)].set(new.reshape(-1, kh, d))
         return flat.reshape(nb, bs, kh, d)

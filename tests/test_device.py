@@ -25,12 +25,20 @@ def test_cpu_backend_must_be_asked_for(platform, requested, refused):
         device.require_backend(platform, requested)
 
 
+# Every program is kept, whatever its compile time or size (PERF.md, PR 32:
+# small step programs compile in under JAX's one-second floor, and a
+# restart that finds them missing compiles them again).
+KEEP_ALL = [("jax_persistent_cache_min_compile_time_secs", 0),
+            ("jax_persistent_cache_min_entry_size_bytes", -1)]
+
+
 @pytest.mark.parametrize("backend,env_dir,updates", [
-    # Set from outside: JAX has read it, nothing is set in code.
-    ("tpu", "/somewhere/else", []),
+    # Set from outside: JAX has read it, the directory is not set in code.
+    ("tpu", "/somewhere/else", KEEP_ALL),
     # Unset: the fixed <checkout>/.jax_cache.
     ("tpu", None, [("jax_compilation_cache_dir",
-                    str(Path(__file__).resolve().parents[1] / ".jax_cache"))]),
+                    str(Path(__file__).resolve().parents[1] / ".jax_cache")),
+                   *KEEP_ALL]),
     # The CPU backend: off, wherever the variable points.
     ("cpu", "/somewhere/else", [("jax_enable_compilation_cache", False)]),
     ("cpu", None, [("jax_enable_compilation_cache", False)]),
@@ -46,6 +54,15 @@ def test_compile_cache_placement(monkeypatch, backend, env_dir, updates):
         monkeypatch.setenv(device.CACHE_ENV, env_dir)
     device.configure_compile_cache()
     assert seen == updates
+
+
+def test_persistence_floors_are_real_jax_options():
+    """The two names are options this JAX has, and 0 / -1 mean "no floor"
+    there: a typo would be a silent no-op under the mock above."""
+    assert jax.config.jax_persistent_cache_min_compile_time_secs >= 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes >= -1
+    for name, _ in KEEP_ALL:
+        assert hasattr(jax.config, name)
 
 
 @pytest.mark.parametrize("kv_dtype,num_blocks,tp,refusal", [

@@ -406,7 +406,11 @@ def test_fast_greedy_path_matches_general():
     general, _ = run_to_completion(gen_core, [
         *(make_req(prompt=p, max_tokens=7, rid=f"g{i}")
           for i, p in enumerate(prompts)),
-        make_req(prompt=[7, 8, 9, 11], max_tokens=7, rid="sampled",
+        # Two tokens: 9 + 9 + 2 fill the token bucket of the (b=4, t=16)
+        # prefill program, so the three prompts share one batch; one
+        # token more and the sampled prompt would go out in a program of
+        # its own (compile_ledger.pack_rows).
+        make_req(prompt=[7, 8], max_tokens=7, rid="sampled",
                  temperature=0.8, seed=3),
     ])
     assert not gen_core.runner.used_fast_greedy(), \

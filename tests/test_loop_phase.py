@@ -186,7 +186,7 @@ def runner():
 
 @pytest.mark.parametrize("t, greedy, expect", [
     (1, True, "jit_step_decode_b4_n4"),
-    (16, True, "jit_step_mixed_b4_t16_n4"),
+    (16, True, "jit_step_mixed_b4_t16_k20_n4"),
     (1, False, "jit_step_decode_b4_n4_sampled"),
 ])
 def test_step_program_name_carries_the_bucket(runner, t, greedy, expect):

@@ -109,6 +109,46 @@ def test_paged_attention_kernel_qchunked_matches_dense(monkeypatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("num_splits", [1, 2], ids=["sequential", "split_k"])
+def test_paged_attention_kernel_leaves_padded_query_chunks(monkeypatch,
+                                                           num_splits):
+    """Rows of a mixed step in one [B, T] rectangle: a full chunk, a chunk
+    cut short, a one-token row deep in its context, a padding row. Every
+    live position equals the dense path; a query chunk that holds padding
+    only is not walked at all, and so reads 0 where it used to hold the
+    attention of a position nobody asked for."""
+    import dynamo_tpu.ops.paged_attention as pa
+
+    rng = np.random.default_rng(6)
+    b, t, h, kh, d, bs, nblk = 4, 32, 4, 2, 128, 16, 4
+    q, k_cache, v_cache, block_tables, _, _ = _make_case(
+        rng, b, t, h, kh, d, nb=32, bs=bs, nblk=nblk)
+    q_start = jnp.asarray([0, 16, 41, 0], jnp.int32)
+    q_len = jnp.asarray([32, 11, 1, 0], jnp.int32)
+    ref = np.asarray(_dense_ref(q, k_cache, v_cache, block_tables, q_start,
+                                q_len))
+    monkeypatch.setattr(pa, "_SCRATCH_CAP_BYTES", 48 * 1024, raising=False)
+    grids = []
+    real_call = pa.pl.pallas_call
+    monkeypatch.setattr(
+        pa.pl, "pallas_call",
+        lambda kernel, *a, grid_spec=None, **kw: (
+            grids.append(grid_spec.grid),
+            real_call(kernel, *a, grid_spec=grid_spec, **kw))[1])
+    out = np.asarray(pa.paged_attention_kernel(
+        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
+        num_splits=num_splits, interpret=True))
+    nq = grids[0][1]
+    assert nq == 4 and grids[0][2] == num_splits, grids
+    tq = t // nq
+    for row, n in enumerate(np.asarray(q_len)):
+        np.testing.assert_allclose(out[row, :n], ref[row, :n],
+                                   atol=2e-5, rtol=2e-5)
+        dead_from = -(-int(n) // tq) * tq      # first wholly padded chunk
+        assert not out[row, dead_from:].any(), row
+        assert n == 0 or np.abs(ref[row, dead_from:]).max(initial=1.0) > 0
+
+
 def test_paged_attention_sharded_tp_matches_dense():
     """shard_map'd kernel over a tp=2 mesh (heads split) matches the dense
     path — the TP serving configuration of the kernel."""

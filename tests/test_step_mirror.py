@@ -1,0 +1,195 @@
+"""What is warmed is what is reached.
+
+The benchmark warms the step programs that ``chipbench/harness/sut.py
+reachable_buckets`` enumerates from the program's device-free mirror
+(``obs/compile_ledger.py sig_for_rows``). Held here, for each cell of
+``BENCHMARK.json``: a replay of the cell's own trace through the real
+scheduler, cut into programs as ``EngineCore`` cuts it (``pack_rows``) and
+bucketed by ``ModelRunner.bucket_of`` (dispatch's own geometry), mints no
+``(kind, b, t, nblk, N)`` outside that set; the set is no larger than it
+was; no program is sent more live tokens than its bucket N; and the
+scheduling ledger counts N a program. Nothing here runs a model: no number
+is a measurement.
+"""
+
+from __future__ import annotations
+
+import sys
+import types
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "chipbench"))
+
+from harness import manifest, sut, traffic  # noqa: E402
+
+from dynamo_tpu.engine.engine import ModelRunner  # noqa: E402
+from dynamo_tpu.engine.prefix_pool import PrefixPool  # noqa: E402
+from dynamo_tpu.engine.scheduler import Scheduler, Seq  # noqa: E402
+from dynamo_tpu.models.config import resolve_model_config  # noqa: E402
+from dynamo_tpu.obs.compile_ledger import (  # noqa: E402
+    pack_rows,
+    sig_for_rows,
+    token_bucket,
+)
+from dynamo_tpu.obs.sched_ledger import step_geometry  # noqa: E402
+from dynamo_tpu.protocols.common import (  # noqa: E402
+    FinishReason,
+    PreprocessedRequest,
+    SamplingOptions,
+    StopConditions,
+)
+
+# Warmed programs a cell, as the accepted benchmark had them (PERF.md).
+WARMED = {"mistral-7b.chat": 64, "mistral-7b.longprompt": 48,
+          "mistral-nemo-12b.chat": 64}
+# Seconds a step takes in the replay: (a decode step, each chunk token on
+# top). Two clocks, because which prompts coincide in a step depends on how
+# long the steps before took: about the parent's step and about this one.
+CLOCKS = {"fast": (0.015, 0.00015), "slow": (0.015, 0.0004)}
+POOL_BLOCKS = 6000
+
+
+def _replay(cell, ec, order: int, clock: tuple[float, float]):
+    """Every program the cell's trace dispatches: (signature, live tokens),
+    and every step's batches as ``PendingStep`` holds them."""
+    tr = cell.traffic
+    ramp_s, rate = float(tr["ramp_s"]), float(tr["rate_per_s"])
+    vocab = cell.model["vocab_size"]
+    reqs = traffic.schedule(tr, vocab, ramp_s, 1, rate, 1, order)
+    reqs = [(r.due_s, r) for r in reqs] + [
+        (ramp_s + r.due_s, r)
+        for r in traffic.schedule(tr, vocab, 51.0, 1, rate, 0, order)]
+    sched = Scheduler(PrefixPool(POOL_BLOCKS, ec.block_size),
+                      ec.max_batch_size, ec.prefill_chunk, ec.max_model_len,
+                      ec.max_tokens_per_step)
+    runner = types.SimpleNamespace(
+        engine_cfg=ec, max_nblk=-(-ec.max_model_len // ec.block_size))
+    now, nxt, programs, steps = 0.0, 0, [], []
+    while nxt < len(reqs) or sched.has_work():
+        while nxt < len(reqs) and reqs[nxt][0] <= now:
+            r = reqs[nxt][1]
+            sched.add(Seq(req=PreprocessedRequest(
+                token_ids=list(r.prompt),
+                stop_conditions=StopConditions(max_tokens=r.max_tokens,
+                                               ignore_eos=True),
+                sampling_options=SamplingOptions(temperature=0.0)),
+                block_size=ec.block_size))
+            nxt += 1
+        plan = sched.plan()
+        if plan.empty:
+            now = reqs[nxt][0]
+            continue
+        # EngineCore._dispatch_plan, unified: decode rows, then the chunks.
+        rows = ([(s, s.num_computed, 1) for s in plan.decode]
+                + [(w.seq, w.start, w.length) for w in plan.prefill])
+        mixed = bool(plan.prefill)
+        batches, lo = [], 0
+        for k in pack_rows([r[2] for r in rows], ec, mixed):
+            run, lo = rows[lo:lo + k], lo + k
+            kind, b, t, nblk, _ = ModelRunner.bucket_of(runner, run, 1, mixed)
+            programs.append(((kind, b, t, nblk, token_bucket(kind, b, t)),
+                             sum(r[2] for r in run)))
+            batches.append(("mixed" if mixed else "decode", run,
+                            [True] * k, None, None))
+        steps.append((batches, len(plan.decode)))
+        for seq, start, length in rows:
+            samples = start + length >= seq.prefill_target()
+            seq.num_computed = start + length
+            if samples:
+                seq.tokens.append(1)
+                if (seq.num_output_tokens
+                        >= seq.req.stop_conditions.max_tokens):
+                    sched.finish(seq, FinishReason.LENGTH)
+        now += clock[0] + clock[1] * sum(w.length for w in plan.prefill)
+    return programs, steps
+
+
+@pytest.fixture(scope="module")
+def cells():
+    bench = manifest.load_benchmark()
+    out = {}
+    for name in WARMED:
+        cell = manifest.load_cell(name, bench)
+        ec = sut.engine_config(cell.config_dir, cell.about)
+        warmed = {(s.kind, s.b, s.t, s.nblk, s.n)
+                  for s in sut.reachable_buckets(cell.traffic, ec)}
+        out[name] = (cell, ec, warmed)
+    return out
+
+
+def test_benchmark_has_the_cells_held_here():
+    names = {w["name"] for w in manifest.load_benchmark()["workloads"]}
+    assert names == set(WARMED)
+
+
+@pytest.mark.parametrize("name", sorted(WARMED))
+def test_a_cell_warms_no_more_programs_than_before(cells, name):
+    cell, ec, warmed = cells[name]
+    assert len(warmed) == len(sut.reachable_buckets(cell.traffic, ec))
+    assert len(warmed) <= WARMED[name]
+    # N follows from (kind, b, t): the lattice has no dimension for it.
+    assert len({s[:4] for s in warmed}) == len(warmed)
+
+
+@pytest.mark.parametrize("clock", sorted(CLOCKS))
+@pytest.mark.parametrize("order", [1, 2, 3, 4])
+@pytest.mark.parametrize("name", sorted(WARMED))
+def test_replayed_trace_reaches_only_warmed_programs(cells, name, order,
+                                                     clock):
+    cell, ec, warmed = cells[name]
+    programs, steps = _replay(cell, ec, order, CLOCKS[clock])
+    assert len(programs) > 500
+    cold = sorted({sig for sig, _ in programs} - warmed)
+    assert not cold, cold
+    # No program is sent more tokens than its dense layers hold, and the
+    # mirror names the same program from the same rows.
+    assert all(live <= sig[4] for sig, live in programs)
+    # Long prompts overlap: steps with two full chunks, which overflow one
+    # token bucket and go out as two programs (7-17 a run; chat has 0-4).
+    if cell.traffic["prompt_tokens"]["median"] > ec.prefill_chunk:
+        assert sum(len(batches) > 1 for batches, _ in steps) >= 5
+    model_cfg = resolve_model_config(str(cell.config_dir))
+    for batches, dec_rows in steps[::7]:
+        g = step_geometry(model_cfg, ec, batches, mixed_dec_rows=dec_rows)
+        runs = [_bucket_of_batch(ec, batch) for batch in batches]
+        assert g["sched_tokens"] == sum(s.n for s in runs)
+        assert g["rect_tokens"] == sum(s.b * s.t for s in runs)
+        assert g["live_tokens"] == sum(
+            length for _, rows, *_ in batches for _, _, length in rows)
+        assert g["decode_rows"] == dec_rows
+
+
+def _bucket_of_batch(ec, batch):
+    kind, rows, *_ = batch
+    need = max(-(-(start + length) // ec.block_size)
+               for _, start, length in rows)
+    return sig_for_rows(kind, len(rows), max(r[2] for r in rows), need, ec)
+
+
+@pytest.mark.parametrize("lengths, mixed, want", [
+    ([1, 1, 1], True, [3]),                      # a decode batch is one run
+    ([1, 1, 512], True, [3]),                    # one chunk and its decoders
+    ([1, 512, 512], True, [2, 1]),               # two full chunks: two programs
+    ([1, 300, 100, 90], True, [4]),              # short chunks share one
+    ([1] * 7 + [512, 30], True, [8, 1]),         # the ninth row would not fit
+    ([512] * 3, False, [1, 1, 1]),               # the legacy prefill batch too
+    ([9, 9, 2], False, [3]),
+    ([9, 9, 3], False, [2, 1]),
+])
+def test_pack_rows_cuts_a_step_at_the_token_bucket(lengths, mixed, want):
+    from dynamo_tpu.utils.config import EngineConfig
+
+    ec = EngineConfig(model="tiny-llama")
+    assert pack_rows(lengths, ec, mixed) == want
+
+
+@pytest.mark.parametrize("kind, b, t, want", [
+    ("decode", 8, 1, 8), ("mixed", 8, 1, 8), ("mixed", 8, 512, 520),
+    ("mixed", 16, 16, 32), ("mixed", 64, 512, 576), ("prefill", 1, 512, 512),
+    ("prefill", 4, 16, 20), ("verify", 8, 4, 32), ("window", 16, 1, 16),
+])
+def test_token_bucket_follows_from_the_signature(kind, b, t, want):
+    assert token_bucket(kind, b, t) == want
