@@ -61,8 +61,6 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "(greedy-exact; 0 = off)")
     p.add_argument("--spec-k", type=int, default=4,
                    help="max proposed tokens per verify step")
-    p.add_argument("--decode-window", type=int, default=1,
-                   help="decode steps fused per device dispatch")
     p.add_argument("--prefill-chunk", type=int, default=512,
                    help="prefill chunk tokens per step; 0 = SLO-driven auto "
                         "sizing (largest per-QoS chunk keeping predicted "
@@ -70,9 +68,6 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--itl-slo-ms", type=float, default=50.0,
                    help="decode ITL SLO budget for --prefill-chunk 0 auto "
                         "sizing (interactive 1x, standard 2x, batch 4x)")
-    p.add_argument("--no-unified-step", action="store_true",
-                   help="dispatch decode and prefill chunks as the legacy "
-                        "two XLA launches instead of one ragged mixed step")
     p.add_argument("--quantization", choices=["none", "int8"], default="none",
                    help="weight-only quantization (int8: per-channel scales, "
                         "bf16 compute; halves decode HBM traffic)")
@@ -385,10 +380,8 @@ async def amain(ns: argparse.Namespace) -> None:
             dp=ns.dp,
             ep=ns.ep,
             sp=ns.sp,
-            decode_window=ns.decode_window,
             prefill_chunk=ns.prefill_chunk,
             itl_slo_ms=ns.itl_slo_ms,
-            unified_step=not ns.no_unified_step,
             quantization=ns.quantization,
             kv_dtype=ns.kv_dtype,
             spec_ngram=ns.spec_ngram,

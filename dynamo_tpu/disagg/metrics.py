@@ -4,7 +4,7 @@ The consumer-side overlap ratio is the tentpole's headline number: the
 fraction of a streamed transfer's pull window that ran while the remote
 prefill was still computing (1.0 = the transfer fully hid behind prefill,
 0.0 = today's serialized handoff). Stage/pull byte counters and the
-per-wave size histogram feed the wave-sizing guidance in docs/PERF.md.
+per-wave size histogram are what wave sizing is judged by.
 
 Registrations are idempotent (MetricsRegistry keys by name), so the
 module-level singleton can be re-bound into a runtime's registry via

@@ -3,8 +3,8 @@ programs are cached, and what it reports about both.
 
 Everything that builds an engine goes through ``EngineCore.__init__``, which
 calls :func:`require_backend` and :func:`configure_compile_cache` before its
-first ``jit`` — so the launcher, the worker, ``bench.py`` and the tests all
-get the same two rules.
+first ``jit`` — so the launcher, the worker, the benchmark and the tests
+all get the same two rules.
 """
 
 from __future__ import annotations

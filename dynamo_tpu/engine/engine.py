@@ -69,6 +69,7 @@ from dynamo_tpu.obs.compile_ledger import (
     enumerate_buckets,
     get_compile_ledger,
     pack_rows,
+    sig_for_rows,
     token_bucket,
 )
 from dynamo_tpu.obs.profiler import (
@@ -87,20 +88,6 @@ from dynamo_tpu.utils.config import EngineConfig
 from dynamo_tpu.utils.logging import get_logger
 
 log = get_logger("engine")
-
-
-def _bucket(n: int, buckets: tuple[int, ...]) -> int:
-    for b in buckets:
-        if n <= b:
-            return b
-    return n
-
-
-def _pow2_bucket(n: int, lo: int, hi: int) -> int:
-    b = lo
-    while b < n and b < hi:
-        b *= 2
-    return b
 
 
 def _step_tokens(b: int, t: int, sp_prefill: bool) -> int:
@@ -239,20 +226,22 @@ class EngineMetrics:
 @dataclass
 class PendingStep:
     """A dispatched-but-unmaterialized engine step: per batch,
-    (kind, rows, sample_rows, device tokens, device logprobs)."""
+    (signature of the program dispatched, rows, sample_rows, device
+    tokens, device logprobs). For a "verify" batch the third entry is
+    the rows' proposal chunks."""
 
-    batches: list[tuple[str, list, list[bool], Any, Any]] = field(default_factory=list)
-    # Scheduling-ledger context captured at plan time (decode_window,
-    # token-budget utilization, HOL victim list) — consumed by
-    # _record_step at finalize. None when DYN_SCHED_LEDGER=0.
+    batches: list[tuple[BucketSig, list, list, Any, Any]] = field(default_factory=list)
+    # Scheduling-ledger context captured at plan time (token-budget
+    # utilization, HOL victim list) — consumed by _record_step at
+    # finalize. None when DYN_SCHED_LEDGER=0.
     sched: Any = None
-    # Unified steps: the leading decode-row count of the "mixed" batch
-    # (rows [0:n] are decode/guided, the rest prefill chunks) — captured
-    # at plan time because prefill_target() moves as finalize appends
-    # tokens, so a finalize-time re-derivation would misclassify. A step
-    # whose chunks overflow one token bucket is several mixed batches
+    # How many of the step batches' rows, counted from the first, are
+    # decode/guided rows; the rest are prefill chunks. Captured at plan
+    # time because prefill_target() moves as finalize appends tokens, so
+    # a finalize-time re-derivation would misclassify. A step whose chunks
+    # overflow one token bucket is several batches
     # (compile_ledger.pack_rows): the decode rows lead the first of them.
-    mixed_dec_rows: int = 0
+    dec_rows: int = 0
 
 
 class ModelRunner:
@@ -372,9 +361,6 @@ class ModelRunner:
         # engine.compile around a step program built inside serving;
         # EngineCore shares this clock for the rest of the loop.
         self.loop_clock = LoopClock()
-        # (kind, b, t, nblk, n) of the last dispatched step program: what the
-        # engine.dispatch span says it enqueued.
-        self.last_bucket: tuple[str, int, int, int, int] = ("", 0, 0, 0, 0)
         # The pool comes last: everything else that lives on the device is
         # resident by now, so what memory_stats() calls free really is.
         self.spec = KVCacheSpec.for_model(
@@ -706,87 +692,24 @@ class ModelRunner:
         repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
         return {"out_shardings": (cache, cache, repl, repl, repl, repl, repl)}
 
-    def _build_window_fn(self, b: int, nblk: int, w: int,
-                         fast_greedy: bool = False):
-        """Fused decode window: ``w`` single-token steps in ONE compiled
-        dispatch, `lax.scan`-sequenced on device with each step's sampled
-        token feeding the next — zero host round trips inside the window,
-        one dispatch amortised over ``w`` tokens. Stop
-        conditions lag ≤ w-1 tokens; finalize discards overrun, so emitted
-        streams are bit-identical to w=1 (tests/test_engine.py windowed
-        equivalence tests)."""
-        cfg = self.cfg
-        trash_row = self.engine_cfg.max_batch_size
-        attn_impl = self.attn_impl
-        moe_impl = "ep" if self.engine_cfg.ep > 1 else "dense"
-        mesh = self.mesh
-        pp_micro = self.engine_cfg.pp_microbatches
-        attn_splits = self.engine_cfg.attn_num_splits
-
-        def step(params, ck, cv, counts, keys, slot_toks, tokens, q_start, q_len,
-                 bt, slots, temp, top_k, top_p, fp, pp, rp, do_sample, from_slot):
-            first = jnp.where(from_slot, slot_toks[slots], tokens[:, 0])
-            write_slots = jnp.where(do_sample, slots, trash_row)
-
-            def body(carry, j):
-                ck, cv, counts, keys, slot_toks, cur = carry
-                hidden, ck, cv = llama.forward(
-                    params, cfg, cur[:, None], q_start + j, q_len, bt, ck, cv,
-                    attn_impl=attn_impl, moe_impl=moe_impl, mesh=mesh,
-                    pp_microbatches=pp_micro, attn_num_splits=attn_splits)
-                logits = llama.logits_from_hidden(params, cfg, hidden).astype(jnp.float32)
-                with _perf_phase("sampling"):
-                    if fast_greedy:
-                        # See _build_step_fn: bit-identical for all-greedy
-                        # penalty-free batches, minus the sampling machinery.
-                        toks, lps = _greedy_sample(logits)
-                    else:
-                        st = SamplingState(
-                            temperature=temp, top_k=top_k, top_p=top_p,
-                            frequency_penalty=fp, presence_penalty=pp,
-                            repetition_penalty=rp, keys=keys[slots],
-                            token_counts=counts[slots],
-                        )
-                        toks, lps, new_keys = sample(logits, st)
-                        new_counts = record_tokens(st.token_counts, toks,
-                                                   do_sample)
-                        counts = counts.at[write_slots].set(new_counts)
-                        keys = keys.at[write_slots].set(new_keys)
-                slot_toks = slot_toks.at[write_slots].set(toks)
-                return (ck, cv, counts, keys, slot_toks, toks), (toks, lps)
-
-            (ck, cv, counts, keys, slot_toks, _), (toks_w, lps_w) = lax.scan(
-                body, (ck, cv, counts, keys, slot_toks, first),
-                jnp.arange(w, dtype=jnp.int32))
-            return ck, cv, counts, keys, slot_toks, toks_w.T, lps_w.T  # [B, W]
-
-        name = (f"step_window_b{b}_n{nblk}_w{w}"
-                + ("" if fast_greedy else "_sampled"))
-        return jax.jit(_named(step, name), donate_argnums=(1, 2, 3, 4, 5),
-                       **self._jit_shardings())
-
     def step_fn(self, b: int, t: int, nblk: int, sp_prefill: bool = False,
-                window: int = 1, fast_greedy: bool = False, mm: bool = False,
+                fast_greedy: bool = False, mm: bool = False,
                 masked: bool = False):
-        key = (b, t, nblk, sp_prefill, window, fast_greedy, mm, masked)
+        key = (b, t, nblk, sp_prefill, fast_greedy, mm, masked)
         if key not in self._step_fns:
-            log.info("compiling step fn B=%d T=%d NBLK=%d sp_prefill=%s W=%d "
+            log.info("compiling step fn B=%d T=%d NBLK=%d sp_prefill=%s "
                      "greedy=%s mm=%s masked=%s", b, t, nblk, sp_prefill,
-                     window, fast_greedy, mm, masked)
-            if window > 1:
-                self._step_fns[key] = self._build_window_fn(
-                    b, nblk, window, fast_greedy)
-            else:
-                self._step_fns[key] = self._build_step_fn(
-                    b, t, nblk, sp_prefill, fast_greedy, mm, masked)
+                     fast_greedy, mm, masked)
+            self._step_fns[key] = self._build_step_fn(
+                b, t, nblk, sp_prefill, fast_greedy, mm, masked)
         return self._step_fns[key]
 
     def used_fast_greedy(self) -> bool:
         """Whether any compiled step so far took the argmax-only greedy
         variant — THE accessor for the compile-cache key layout (step keys
-        are (b, t, nblk, sp, window, fast_greedy, mm); 'verify'/'embed'
+        are (b, t, nblk, sp, fast_greedy, mm, masked); 'verify'/'embed'
         entries are string-prefixed and excluded)."""
-        return any(not isinstance(k[0], str) and k[5]
+        return any(not isinstance(k[0], str) and k[4]
                    for k in self._step_fns)
 
     def reset_slot(self, slot: int, seed: int | None, *, advance: int = 0,
@@ -794,8 +717,8 @@ class ModelRunner:
         """Initialize a seq's persistent sampling state. ``advance`` replays
         that many sampler draws on the fresh key (sample()'s split chain is
         a pure function of (seed, draws), so a checkpoint-resumed stream's
-        n+1'th draw is bit-identical to the unkilled run's at
-        decode_window=1); ``resume_tokens`` rebuilds the penalty counts
+        n+1'th draw is bit-identical to the unkilled run's);
+        ``resume_tokens`` rebuilds the penalty counts
         from the already-generated ledger riding the resume prompt."""
         self.counts = self.counts.at[slot].set(0)
         if resume_tokens:
@@ -807,70 +730,45 @@ class ModelRunner:
                 k = _advance_key_data(k, jnp.int32(advance)).astype(jnp.uint32)
             self.keys = self.keys.at[slot].set(k)
 
-    def bucket_of(self, rows: list[tuple[Seq, int, int]], window: int = 1,
-                  mixed: bool = False) -> tuple[str, int, int, int, int]:
-        """(kind, b, t, nblk, window) of the step program that serves
-        ``rows``: dispatch()'s geometry, which ``sig_for_rows``
-        (obs/compile_ledger.py) mirrors device-free."""
-        ec = self.engine_cfg
-        n = len(rows)
-        t_max = max(length for _, _, length in rows)
-        if t_max == 1:
-            # Degenerate mixed batches (every live row is one token) ARE
-            # the decode program — classify them as such so the ledger
-            # matches the program actually minted.
-            b, t = _bucket(n, ec.decode_bucket), 1
-        elif mixed:
-            window = 1
-            b, t = _bucket(n, ec.decode_bucket), _pow2_bucket(t_max, 16, ec.prefill_chunk)
-        else:
-            window = 1  # windows are a decode-dispatch concept
-            b, t = _bucket(n, (1, 2, 4, 8)), _pow2_bucket(t_max, 16, ec.prefill_chunk)
+    def bucket_of(self, rows: list[tuple[Seq, int, int]],
+                  verify: bool = False) -> BucketSig:
+        """The program that serves ``rows``, from the one place that knows
+        (``sig_for_rows``, obs/compile_ledger.py)."""
         # Block-table width from the batch's max KV coverage — NOT the max
         # allocated table length: every query/context position this step
-        # touches is < start + length (+ window-1 for fused decode windows),
-        # so blocks past that are pure waste (the Pallas kernel still burns
-        # one HBM DMA per table entry per step, and the dense path gathers
-        # them). Pow2-bucketed to bound the number of compiled programs.
-        bsz = ec.block_size
+        # touches is < start + length, so blocks past that are pure waste
+        # (the Pallas kernel still burns one HBM DMA per table entry per
+        # step, and the dense path gathers them).
+        bsz = self.engine_cfg.block_size
         nblk_need = max(
-            min(len(s.block_ids),
-                -(-(start + length + window - 1) // bsz))
-            for s, start, length in rows)
-        nblk = min(_pow2_bucket(max(nblk_need, 1), 4, self.max_nblk), self.max_nblk)
-        kind = ("window" if window > 1
-                else "decode" if t == 1
-                else "mixed" if mixed else "prefill")
-        return kind, b, t, nblk, window
+            min(len(seq.block_ids), -(-(start + length) // bsz))
+            for seq, start, length in rows)
+        return sig_for_rows(
+            "verify" if verify else "mixed", len(rows),
+            max(length for _, _, length in rows), nblk_need, self.engine_cfg)
 
     def dispatch(
         self,
         rows: list[tuple[Seq, int, int]],  # (seq, start, length) per row
         sample_rows: list[bool],
-        window: int = 1,
         masks: list | None = None,  # per-row bool[V] allow-masks (guided)
-        mixed: bool = False,
-    ) -> tuple[jax.Array, jax.Array]:
+    ) -> tuple[BucketSig, jax.Array, jax.Array]:
         """Enqueue one bucketed step on the device WITHOUT blocking; returns
-        device arrays (tokens [B] or [B, window], logprobs likewise) still
-        being computed. The caller overlaps host work (scheduling, output
-        assembly for earlier steps) with the device, then materializes via
-        ``np.asarray``. ``window > 1`` (decode rows only) fuses that many
-        steps into the dispatch — the caller must have grown each seq's
-        block table to cover ``window`` more tokens. ``mixed`` marks a
-        unified ragged step (decode rows packed with prefill-chunk rows):
-        the batch buckets over the DECODE row ladder while t takes the
-        prefill chunk ladder — same ragged step program, different bucket
-        geometry (legacy prefill's (1,2,4,8) row ladder can't hold a full
-        decode batch).
+        the signature of the program it ran and device arrays (tokens [B],
+        logprobs likewise) still being computed. The caller overlaps host
+        work (scheduling, output assembly for earlier steps) with the
+        device, then materializes via ``np.asarray``. A batch whose longest
+        row is one token is the decode program; anything else is the ragged
+        mixed program (decode rows packed with prefill-chunk rows): rows
+        bucket over the decode ladder, t over the prefill chunk ladder.
 
         The program runs its dense layers over a token bucket N that
         follows from the (b, t) picked here (``token_bucket``); the rows
         hold no more live tokens than that, which the caller sees to by
         cutting a step with ``pack_rows``."""
-        ec = self.engine_cfg
         t_max = max(length for _, _, length in rows)
-        kind, b, t, nblk, window = self.bucket_of(rows, window, mixed)
+        sig = self.bucket_of(rows)
+        kind, b, t, nblk = sig.kind, sig.b, sig.t, sig.nblk
         # Sequence-parallel prefill: a batch of fresh full-prompt chunks
         # (every row starts at 0) on a seq>1 mesh rides ring attention —
         # but only past the ring-vs-chunked threshold (explicit knob or
@@ -947,7 +845,7 @@ class ModelRunner:
         # length-1 prefill tail (chunk budget, prefix-cache hit leaving one
         # token) can land inside a span, and serving the placeholder
         # embedding there would poison the digest-keyed prefix cache.
-        # Decode/window rows start at/after the prompt end, so they never
+        # Decode rows start at/after the prompt end, so they never
         # intersect and mm stays False for them naturally.
         emb_override = None
         for i, (seq, start, length) in enumerate(rows):
@@ -979,12 +877,12 @@ class ModelRunner:
             raise ValueError(
                 f"{int(q_len.sum())} live tokens in a {kind} batch whose "
                 f"bucket (b={b}, t={t}) holds {n_tok}: cut it with pack_rows")
-        self.last_bucket = (kind, b, t, nblk, n_tok)
-        miss = ((b, t, nblk, sp_prefill, window, fast_greedy, mm, masked)
+        if not fast_greedy:
+            sig = dataclasses.replace(sig, greedy=False)
+        miss = ((b, t, nblk, sp_prefill, fast_greedy, mm, masked)
                 not in self._step_fns)
         cold = led.enabled and miss
-        fn = self.step_fn(b, t, nblk, sp_prefill, window, fast_greedy, mm,
-                          masked)
+        fn = self.step_fn(b, t, nblk, sp_prefill, fast_greedy, mm, masked)
         place = self._place
         extra = ((place(emb_override), place(emb_mask)) if mm else ())
         if masked:
@@ -1010,12 +908,10 @@ class ModelRunner:
             dt = time.perf_counter() - t_compile
             led.mark_inflight(False)
             led.record(
-                BucketSig(kind, b, t, nblk, fast_greedy,
-                          ec.kv_dtype or "bfloat16"),
-                dt,
+                sig, dt,
                 trace_ctx=next((s.trace_ctx for s, _, _ in rows
                                 if s.trace_ctx is not None), None))
-        return toks, lps
+        return sig, toks, lps
 
     def _compile_phase(self, miss: bool, kind: str, b: int, t: int,
                        nblk: int):
@@ -1026,16 +922,6 @@ class ModelRunner:
             return _NO_PHASE
         return loop_phase(self.loop_clock, "engine.compile", kind=kind,
                           b=b, t=t, nblk=nblk)
-
-    def run(
-        self,
-        rows: list[tuple[Seq, int, int]],
-        sample_rows: list[bool],
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """Dispatch one step and block for host results (tokens, logprobs)."""
-        toks, lps = self.dispatch(rows, sample_rows)
-        n = len(rows)
-        return np.asarray(toks)[:n], np.asarray(lps)[:n]
 
     # -- speculative verify --------------------------------------------
     def _build_verify_fn(self, b: int, t: int, nblk: int):
@@ -1070,23 +956,13 @@ class ModelRunner:
                        donate_argnums=(1, 2), **kw)
 
     def dispatch_verify(self, rows: list[tuple[Seq, int, int]],
-                        chunks: list[list[int]]) -> tuple[jax.Array, jax.Array]:
+                        chunks: list[list[int]]
+                        ) -> tuple[BucketSig, jax.Array, jax.Array]:
         """Enqueue one verify step; chunk tokens are EXPLICIT (the proposals
-        are not in seq.tokens yet). Returns ([B, t] argmax tokens, lps)."""
-        ec = self.engine_cfg
-        n = len(rows)
-        t_max = max(len(c) for c in chunks)
-        b = _bucket(n, ec.decode_bucket)
-        # clamp: _pow2_bucket's hi stops further doubling but doesn't cap
-        # the result — a 5-token chunk must not mint (and pay for) T=8
-        t = min(_pow2_bucket(t_max, 2, ec.spec_k + 1), ec.spec_k + 1)
-        # Same coverage-based table-width bucketing as dispatch(): the
-        # verify chunk reads nothing past start + len(chunk).
-        bsz = ec.block_size
-        nblk_need = max(
-            min(len(seq.block_ids), -(-(start + len(c)) // bsz))
-            for (seq, start, _), c in zip(rows, chunks))
-        nblk = min(_pow2_bucket(max(nblk_need, 1), 4, self.max_nblk), self.max_nblk)
+        are not in seq.tokens yet) and each row's length is its chunk's.
+        Returns the signature and ([B, t] argmax tokens, lps)."""
+        sig = self.bucket_of(rows, verify=True)
+        b, t, nblk = sig.b, sig.t, sig.nblk
 
         tokens = np.zeros((b, t), np.int32)
         q_start = np.zeros((b,), np.int32)
@@ -1100,7 +976,6 @@ class ModelRunner:
             bt[i, : len(ids)] = ids
 
         key = ("verify", b, t, nblk)
-        self.last_bucket = (*key, b * t)
         led = self._ledger
         miss = key not in self._step_fns
         cold = led.enabled and miss
@@ -1120,12 +995,10 @@ class ModelRunner:
             dt = time.perf_counter() - t_compile
             led.mark_inflight(False)
             led.record(
-                BucketSig("verify", b, t, nblk, True,
-                          ec.kv_dtype or "bfloat16"),
-                dt,
+                sig, dt,
                 trace_ctx=next((s.trace_ctx for s, _, _ in rows
                                 if s.trace_ctx is not None), None))
-        return toks, lps
+        return sig, toks, lps
 
     # -- embeddings ----------------------------------------------------
     def _build_embed_fn(self, b: int, t: int):
@@ -1167,10 +1040,9 @@ class ModelRunner:
             raise ValueError(
                 f"embedding input of {t_max} tokens exceeds max_model_len="
                 f"{self.engine_cfg.max_model_len}")
-        t = _pow2_bucket(t_max, 16, self.engine_cfg.max_model_len)
-        # Bounded bucket ladder: client batch sizes must not mint unbounded
-        # compile-cache entries (each compile blocks the engine-core thread).
-        b = _bucket(len(token_lists), (1, 2, 4, 8, 16, 32, 64))
+        sig = sig_for_rows("embed", len(token_lists), t_max, 0,
+                           self.engine_cfg)
+        b, t = sig.b, sig.t
         key = ("embed", b, t, 0, 0)
         led = self._ledger
         miss = key not in self._step_fns
@@ -1192,10 +1064,7 @@ class ModelRunner:
                                    self._place(q_len)))
         if cold:
             led.mark_inflight(False)
-            led.record(
-                BucketSig("embed", b, t, 0, True,
-                          self.engine_cfg.kv_dtype or "bfloat16"),
-                time.perf_counter() - t_compile)
+            led.record(sig, time.perf_counter() - t_compile)
         out[:] = hidden[: len(token_lists)]
         return out
 
@@ -1261,13 +1130,10 @@ class ModelRunner:
                 place(np.zeros((b, nblk), np.int32)))
             np.asarray(toks)
         else:
-            window = (self.engine_cfg.decode_window
-                      if sig.kind == "window" else 1)
-            key = (b, t, nblk, False, window, sig.greedy, False, False)
+            key = (b, t, nblk, False, sig.greedy, False, False)
             if key in self._step_fns:
                 return True
-            fn = self.step_fn(b, t, nblk, False, window, sig.greedy,
-                              False, False)
+            fn = self.step_fn(b, t, nblk, False, sig.greedy, False, False)
             (self.cache_k, self.cache_v, self.counts, self.keys,
              self.slot_toks, toks, _lps) = fn(
                 self.params, self.cache_k, self.cache_v, self.counts,
@@ -1312,14 +1178,9 @@ class EngineCore:
                                         engine_cfg.max_model_len),
             )
         self.engine_cfg = engine_cfg
-        if engine_cfg.spec_ngram > 0:
-            if engine_cfg.decode_window > 1:
-                raise ValueError(
-                    "spec_ngram and decode_window>1 are mutually exclusive "
-                    "(both amortize dispatches over future tokens; pick one)")
-            if engine_cfg.pp > 1:
-                raise ValueError("spec_ngram requires pp=1 (forward_pp has "
-                                 "no all-positions output)")
+        if engine_cfg.spec_ngram > 0 and engine_cfg.pp > 1:
+            raise ValueError("spec_ngram requires pp=1 (forward_pp has "
+                             "no all-positions output)")
         if engine_cfg.pp > 1 and (engine_cfg.tp > 1 or engine_cfg.ep > 1
                                   or engine_cfg.sp > 1):
             raise ValueError(
@@ -1399,11 +1260,6 @@ class EngineCore:
             self.chunk_by_qos = {qos: engine_cfg.prefill_chunk
                                  for qos in cm.QOS_ITL_SLO_SCALE}
         self.sched_led.set_prefill_chunks(self.chunk_by_qos)
-        # Unified ragged mixed-phase steps: one launch per iteration when
-        # prefill work rides along. Fused decode windows keep the legacy
-        # path (a window is a decode-only scan).
-        self._unified = (engine_cfg.unified_step
-                         and engine_cfg.decode_window == 1)
         if mesh is None and any(v != 1 for v in engine_cfg.mesh_shape().values()):
             mesh = make_mesh(MeshConfig(dp=engine_cfg.dp, pp=engine_cfg.pp,
                                         sp=engine_cfg.sp, tp=engine_cfg.tp,
@@ -1439,7 +1295,6 @@ class EngineCore:
             prefill_chunk=engine_cfg.prefill_chunk,
             max_model_len=engine_cfg.max_model_len,
             max_tokens_per_step=engine_cfg.max_tokens_per_step,
-            decode_window=engine_cfg.decode_window,
             spec_lookahead=(engine_cfg.spec_k if engine_cfg.spec_ngram > 0
                             else 0),
             chunk_by_qos=self.chunk_by_qos,
@@ -1781,8 +1636,8 @@ class EngineCore:
         and penalty counts when the request carries stream_ckpt.* resume
         annotations. Every stream gets a concrete seed (explicit or
         request-derived), so the key after n draws is a pure function of
-        the request — the invariant that makes sampled resume bit-identical
-        at decode_window=1."""
+        the request — the invariant that makes sampled resume
+        bit-identical."""
         so = seq.req.sampling_options
         seed = so.seed if so.seed is not None else _derived_seed(
             seq.request_id)
@@ -1885,9 +1740,13 @@ class EngineCore:
             return None
         with loop_phase(self.loop_clock, "engine.dispatch") as span:
             pending = self._dispatch_plan(plan)
-            kind, b, t, nblk, n_tok = self.runner.last_bucket
-            span.set(kind=kind, b=b, t=t, nblk=nblk, n=n_tok,
-                     rows=sum(len(x[1]) for x in pending.batches))
+            # The last program enqueued (n: its signature's token bucket;
+            # a ring-prefill step runs the whole b x t instead).
+            if pending.batches:
+                sig = pending.batches[-1][0]
+                span.set(kind=sig.kind, b=sig.b, t=sig.t, nblk=sig.nblk,
+                         n=sig.n,
+                         rows=sum(len(x[1]) for x in pending.batches))
         if self.sched_led.enabled:
             with loop_phase(self.loop_clock, "engine.plan"):
                 pending.sched = self._sched_context(plan)
@@ -1952,15 +1811,14 @@ class EngineCore:
                 self._init_slot(seq)
                 seq.slot_initialized = True
 
-        # Unified mode: decode rows and the step's prefill-chunk rows pack
-        # into ONE ragged "mixed" program, whose dense layers run over the
-        # rows' live tokens and whose attention runs over the [B, T] rows.
-        # Legacy mode (--no-unified-step, or decode_window>1) runs them as
-        # two bucketed programs, decode first — see the scheduler module
-        # docstring. Either way a batch whose chunks hold more tokens than
-        # its program's token bucket goes out as several programs, below.
+        # A plan with chunks packs its decode rows and the chunks into ONE
+        # ragged "mixed" program, whose dense layers run over the rows'
+        # live tokens and whose attention runs over the [B, T] rows — see
+        # the scheduler module docstring. A batch whose chunks hold more
+        # tokens than its program's token bucket goes out as several
+        # programs, below.
         pending = PendingStep()
-        batches: list[tuple[str, list, list[bool], int, list | None]] = []
+        batches: list[tuple[list, list[bool], list | None]] = []
         decode_seqs = plan.decode
         guided_rows: list = []
         if any(s.guided is not None for s in decode_seqs):
@@ -1977,13 +1835,14 @@ class EngineCore:
         if self.engine_cfg.spec_ngram > 0 and decode_seqs:
             verify_rows, verify_chunks, decode_seqs = self._plan_verify(decode_seqs)
             if verify_rows:
-                toks, lps = self.runner.dispatch_verify(verify_rows, verify_chunks)
+                sig, toks, lps = self.runner.dispatch_verify(
+                    verify_rows, verify_chunks)
                 for seq, start, length in verify_rows:
                     seq.num_computed = start + length
                     seq.inflight_samples += 1
                     seq.verify_inflight = True
                 pending.batches.append(
-                    ("verify", verify_rows, verify_chunks, toks, lps))
+                    (sig, verify_rows, verify_chunks, toks, lps))
         pf_rows, pf_sample_rows, pf_masks = [], [], None
         if plan.prefill:
             pf_rows = [(w.seq, w.start, w.length) for w in plan.prefill]
@@ -2003,90 +1862,76 @@ class EngineCore:
                     if (w.seq.guided is not None and pf_sample_rows[i])
                     else None
                     for i, w in enumerate(plan.prefill)]
-        if self._unified and pf_rows:
+        pending.dec_rows = len(decode_seqs) + len(guided_rows)
+        if pf_rows:
             # One ragged launch: decode rows, guided decode rows (their
             # masks join per-row), then the prefill chunks. dispatch()
-            # classifies a degenerate all-length-1 batch back to "decode".
+            # gives a degenerate all-length-1 batch the decode program.
             rows = ([(s, s.num_computed, 1) for s in decode_seqs]
                     + guided_rows + pf_rows)
-            sample_rows = ([True] * (len(decode_seqs) + len(guided_rows))
-                           + pf_sample_rows)
-            pending.mixed_dec_rows = len(decode_seqs) + len(guided_rows)
+            sample_rows = [True] * pending.dec_rows + pf_sample_rows
             masks = None
             if guided_rows or pf_masks is not None:
                 masks = ([None] * len(decode_seqs)
                          + [s.guided.mask() for s, _, _ in guided_rows]
                          + (pf_masks if pf_masks is not None
                             else [None] * len(pf_rows)))
-            batches.append(("mixed", rows, sample_rows, 1, masks))
+            batches.append((rows, sample_rows, masks))
         else:
             if decode_seqs:
                 rows = [(s, s.num_computed, 1) for s in decode_seqs]
-                batches.append(("decode", rows, [True] * len(rows),
-                                plan.decode_window, None))
+                batches.append((rows, [True] * len(rows), None))
             if guided_rows:
-                batches.append(("decode", guided_rows,
-                                [True] * len(guided_rows), 1,
+                batches.append((guided_rows, [True] * len(guided_rows),
                                 [s.guided.mask() for s, _, _ in guided_rows]))
-            if pf_rows:
-                batches.append(("prefill", pf_rows, pf_sample_rows, 1,
-                                pf_masks))
 
         ec = self.engine_cfg
-        batches = [
-            (kind, rows[lo:lo + k], sample_rows[lo:lo + k], window,
-             b_masks and b_masks[lo:lo + k])
-            for kind, rows, sample_rows, window, b_masks in batches
-            for lo, k in _runs(pack_rows([r[2] for r in rows], ec,
-                                         kind == "mixed"))]
-        for kind, rows, sample_rows, window, b_masks in batches:
-            toks, lps = self.runner.dispatch(rows, sample_rows, window=window,
-                                             masks=b_masks,
-                                             mixed=(kind == "mixed"))
-            # Value-independent bookkeeping, done at dispatch so the next
-            # plan() sees advanced positions. Token metrics count at
-            # finalize, so discarded speculative rows don't inflate them.
-            advance = window if kind == "decode" else None
-            for i, (seq, start, length) in enumerate(rows):
-                seq.num_computed = start + (advance or length)
-                if sample_rows[i]:
-                    seq.inflight_samples += 1
-            pending.batches.append((kind, rows, sample_rows, toks, lps))
+        for rows, sample_rows, b_masks in batches:
+            for lo, k in _runs(pack_rows([r[2] for r in rows], ec)):
+                run, samples = rows[lo:lo + k], sample_rows[lo:lo + k]
+                sig, toks, lps = self.runner.dispatch(
+                    run, samples, masks=b_masks and b_masks[lo:lo + k])
+                # Value-independent bookkeeping, done at dispatch so the
+                # next plan() sees advanced positions. Token metrics count
+                # at finalize, so discarded speculative rows don't inflate
+                # them.
+                for (seq, start, length), sampled in zip(run, samples):
+                    seq.num_computed = start + length
+                    if sampled:
+                        seq.inflight_samples += 1
+                pending.batches.append((sig, run, samples, toks, lps))
         return pending
 
     def _sched_context(self, plan: StepPlan) -> dict:
         """Scheduling-ledger context of a dispatched plan (token-budget
         utilization, HOL victims), consumed by ``_record_step``."""
-        used = (len(plan.decode) * plan.decode_window
-                + sum(w.length for w in plan.prefill))
+        used = len(plan.decode) + sum(w.length for w in plan.prefill)
         hol = None
         if plan.prefill and plan.decode:
             # Every decode-ready stream in this step waits out the
             # prefill work before its token materializes; the culprit
-            # is the request contributing the largest chunk. Under the
-            # unified step the stall is NOT a whole separate launch —
-            # only the chunk's marginal share of the mixed step's wall
-            # (priced by the cost model) is charged to the victims.
+            # is the request contributing the largest chunk. The stall is
+            # not a whole launch: only the chunk's marginal share of the
+            # mixed step's wall (priced by the cost model) is charged to
+            # the victims.
+            from dynamo_tpu.obs import costmodel as cm
+
             culprit = max(plan.prefill, key=lambda w: w.length)
             stall_share = None
-            if self._unified:
-                from dynamo_tpu.obs import costmodel as cm
-                kw = dict(
-                    decode_rows=len(plan.decode),
-                    decode_kv_len=max(s.num_computed
-                                      for s in plan.decode),
-                    chunk_kv_len=max(w.start + w.length
-                                     for w in plan.prefill),
-                    block_size=self.engine_cfg.block_size,
-                    kv_dtype=self.engine_cfg.kv_dtype or "bfloat16",
-                    quantization=self.engine_cfg.quantization or "none")
-                mixed_s = cm.mixed_step_seconds(
-                    self.model_cfg, self._hw,
-                    chunk=sum(w.length for w in plan.prefill), **kw)
-                pure_s = cm.mixed_step_seconds(
-                    self.model_cfg, self._hw, chunk=0, **kw)
-                if mixed_s > 0:
-                    stall_share = max(mixed_s - pure_s, 0.0) / mixed_s
+            kw = dict(
+                decode_rows=len(plan.decode),
+                decode_kv_len=max(s.num_computed for s in plan.decode),
+                chunk_kv_len=max(w.start + w.length for w in plan.prefill),
+                block_size=self.engine_cfg.block_size,
+                kv_dtype=self.engine_cfg.kv_dtype or "bfloat16",
+                quantization=self.engine_cfg.quantization or "none")
+            mixed_s = cm.mixed_step_seconds(
+                self.model_cfg, self._hw,
+                chunk=sum(w.length for w in plan.prefill), **kw)
+            pure_s = cm.mixed_step_seconds(
+                self.model_cfg, self._hw, chunk=0, **kw)
+            if mixed_s > 0:
+                stall_share = max(mixed_s - pure_s, 0.0) / mixed_s
             hol = HolStall(
                 culprit=culprit.seq.request_id,
                 culprit_tokens=sum(w.length for w in plan.prefill),
@@ -2094,7 +1939,6 @@ class EngineCore:
                          for s in plan.decode],
                 stall_share=stall_share)
         return {
-            "decode_window": plan.decode_window,
             "budget_util": used / max(self.sched.max_tokens_per_step, 1),
             "hol": hol,
         }
@@ -2129,7 +1973,7 @@ class EngineCore:
                 continue
             sp = s.trace_span
             if sp is not None and sp.name == "engine.decode":
-                s.trace_tokens += plan.decode_window
+                s.trace_tokens += 1
                 if s.trace_tokens >= self._trace_stride:
                     tr = tr or get_tracer()
                     tr.end_span(sp, tokens=s.trace_tokens,
@@ -2145,7 +1989,7 @@ class EngineCore:
             s.trace_span = tr.start_span(
                 "engine.decode", ctx=s.trace_ctx, request_id=s.request_id,
                 batch=len(plan.decode))
-            s.trace_tokens = plan.decode_window
+            s.trace_tokens = 1
 
     def _trace_finish(self, seq: Seq, reason: FinishReason | None) -> None:
         sp = seq.trace_span
@@ -2165,9 +2009,9 @@ class EngineCore:
 
     def _record_step(self, t0: float, pending: "PendingStep") -> None:
         """Always-on step profile: one ring append per engine step."""
-        n_dec = sum(len(rows) for kind, rows, *_ in pending.batches
-                    if kind not in ("prefill", "mixed")
-                    ) + pending.mixed_dec_rows
+        n_dec = pending.dec_rows + sum(
+            len(rows) for sig, rows, *_ in pending.batches
+            if sig.kind == "verify")
         n_pf = sum(len(rows) for _, rows, *_ in pending.batches) - n_dec
         pc = self.sched.preemption_count
         wall = time.perf_counter() - t0
@@ -2184,13 +2028,11 @@ class EngineCore:
             info = pending.sched or {}
             self.sched_led.record_step(
                 wall_s=wall,
-                decode_window=info.get("decode_window", 1),
                 budget_util=info.get("budget_util", 0.0),
                 queue_depths=self.sched.waiting.depths(),
                 hol=info.get("hol"),
                 **step_geometry(self.model_cfg, self.engine_cfg,
-                                pending.batches,
-                                mixed_dec_rows=pending.mixed_dec_rows))
+                                pending.batches, dec_rows=pending.dec_rows))
         if self.mem_led.enabled:
             # Capacity forecast + leak audit cadence ride the step clock:
             # free-pool observations feed the per-QoS EWMA consumption
@@ -2249,7 +2091,7 @@ class EngineCore:
     def _emit_and_finish(self, seq, candidates: list[int], lps_row,
                          outputs: dict[str, LLMEngineOutput],
                          count_decode: bool) -> int:
-        """THE finalize tail, shared by decode/window and verify batches so
+        """THE finalize tail, shared by step and verify batches so
         the greedy-equivalence guarantee can't drift between them: append
         candidate tokens until a stop fires, commit blocks, transfer
         prefix-hit stats, assemble the output, run finish bookkeeping.
@@ -2311,17 +2153,20 @@ class EngineCore:
         t0 = time.perf_counter()
         clock = self.loop_clock
         outputs: dict[str, LLMEngineOutput] = {}
-        dec_left = pending.mixed_dec_rows
-        for kind, rows, sample_rows, toks_dev, lps_dev in pending.batches:
+        dec_left = pending.dec_rows
+        for sig, rows, sample_rows, toks_dev, lps_dev in pending.batches:
             with loop_phase(clock, "engine.finalize.wait"):
                 # The host blocks here until the device has run the step.
                 toks = np.asarray(toks_dev)
                 lps = np.asarray(lps_dev)
             with loop_phase(clock, "engine.finalize.host"):
-                self._finalize_batch(kind, rows, sample_rows, toks, lps,
+                if sig.kind == "verify":
+                    self._finalize_verify(rows, sample_rows, toks, lps,
+                                          outputs)
+                    continue
+                self._finalize_batch(rows, sample_rows, toks, lps,
                                      dec_left, outputs)
-            if kind == "mixed":
-                dec_left = max(dec_left - len(rows), 0)
+            dec_left = max(dec_left - len(rows), 0)
         with loop_phase(clock, "engine.record"):
             self._record_step(t0, pending)
         if self.kvbm is not None and not self.sched.has_work():
@@ -2348,29 +2193,18 @@ class EngineCore:
             m.ttft_prefill_s += now - t_first_plan
         self._first_tokens.clear()
 
-    def _finalize_batch(self, kind: str, rows, sample_rows, toks, lps,
-                        mixed_dec_rows: int,
+    def _finalize_batch(self, rows, sample_rows, toks, lps, dec_rows: int,
                         outputs: dict[str, LLMEngineOutput]) -> None:
-        """Apply one batch's materialized tokens (host arrays, padded to the
-        bucket)."""
-        if kind == "verify":
-            self._finalize_verify(rows, sample_rows, toks, lps, outputs)
-            return
-        n = len(rows)
-        # Normalize to [n, W]: single-step dispatches return [B], fused
-        # decode windows [B, W] — one finalize path serves both.
-        toks = toks[:n].reshape(n, -1)
-        lps = lps[:n].reshape(n, -1)
+        """Apply one step batch's materialized tokens (host arrays [B],
+        padded to the bucket). The first ``dec_rows`` rows are decode
+        rows, the rest prefill chunks."""
         for i, (seq, start, length) in enumerate(rows):
             if seq.phase is Phase.FINISHED:
                 # Finished (stop/abort) while this step was in flight:
                 # its speculative row is discarded.
                 continue
-            # A mixed batch's leading rows are decode rows (the split
-            # was captured at plan time); everything after them, and
-            # every row of a plain prefill batch, counts as prefill.
-            decode_row = (kind == "decode"
-                          or (kind == "mixed" and i < mixed_dec_rows))
+            # The split was captured at plan time.
+            decode_row = i < dec_rows
             if not decode_row:
                 self.metrics.num_prefill_tokens += length
             if sample_rows[i]:
@@ -2381,11 +2215,8 @@ class EngineCore:
                 # reset to 0 — commit is then a no-op.)
                 self.sched.commit_computed_blocks(seq)
                 continue
-            # Append window tokens until a stop fires; the rest of the
-            # window is discarded (its KV lives in blocks this seq owns,
-            # freed at finish).
             self._emit_and_finish(
-                seq, [int(x) for x in toks[i]], lps[i], outputs,
+                seq, [int(toks[i])], lps[i:i + 1], outputs,
                 count_decode=decode_row)
 
     def _finalize_verify(self, rows, chunks, toks, lps,

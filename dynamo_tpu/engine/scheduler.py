@@ -11,18 +11,16 @@ One step = decode rows AND at most a token-budgeted set of prefill chunks
 (decode first): decode streams advance every step, so a long prompt's
 prefill can stall ITL by at most one chunk's compute, not the whole prompt
 (the reference's engines mix within token-budgeted steps the same way,
-lib/llm/src/mocker/scheduler.rs:117-178). By default the engine dispatches
-the whole plan as ONE ragged mixed-phase XLA launch: the step program
+lib/llm/src/mocker/scheduler.rs:117-178). The engine dispatches the whole
+plan as ONE ragged mixed-phase XLA launch: the step program
 runs its dense layers over the rows' live tokens, packed end to end, so a
 decode row beside a chunk costs its one token there, not T (in the
 attention kernel, which runs over the [B, T] rows, it still costs padded
-grid steps). That removes the second launch's dispatch gap and lets XLA
-overlap decode attention with prefill matmuls. A plan whose chunks hold
-more tokens than one program's bucket goes out as several launches
-(obs/compile_ledger.py pack_rows).
-``--no-unified-step`` restores the legacy two-launch path; fused decode
-windows (decode_window > 1) are decode-only scans and also keep it.
-Static-shape buckets keep XLA compile counts bounded either way.
+grid steps). A plan whose chunks hold more tokens than one program's
+bucket goes out as several launches (obs/compile_ledger.py pack_rows); a
+plan without chunks is the decode program. Which program serves a batch is
+decided in one place, obs/compile_ledger.py sig_for_rows; static-shape
+buckets keep XLA compile counts bounded.
 
 Chunk size is cost-model-driven when ``prefill_chunk == 0``: the engine
 resolves a per-QoS-class cap (costmodel.auto_prefill_chunk — largest chunk
@@ -111,7 +109,7 @@ class Seq:
     # Tracing (obs/tracer.py): the wire TraceContext parsed off the
     # request annotations, the one currently-open phase span
     # (engine.queue → engine.prefill → engine.decode), and the token
-    # count inside the open decode-window span. The engine owns all
+    # count inside the open decode span. The engine owns all
     # transitions; the scheduler never touches these.
     trace_ctx: object | None = None
     trace_span: object | None = None
@@ -182,9 +180,6 @@ class PrefillWork:
 class StepPlan:
     prefill: list[PrefillWork] = field(default_factory=list)
     decode: list[Seq] = field(default_factory=list)
-    # Decode steps fused into this dispatch (power of two). Every decode seq
-    # has blocks allocated for `decode_window` more tokens.
-    decode_window: int = 1
 
     @property
     def empty(self) -> bool:
@@ -199,7 +194,6 @@ class Scheduler:
         prefill_chunk: int,
         max_model_len: int,
         max_tokens_per_step: int = 8192,
-        decode_window: int = 1,
         spec_lookahead: int = 0,
         qos_weights: dict[str, int] | None = None,
         chunk_by_qos: dict[str, int] | None = None,
@@ -213,7 +207,6 @@ class Scheduler:
         self.chunk_by_qos = dict(chunk_by_qos) if chunk_by_qos else {}
         self.max_model_len = max_model_len
         self.max_tokens_per_step = max_tokens_per_step
-        self.decode_window = max(decode_window, 1)
         # Speculative verify chunks write KV for up to spec_k proposed
         # positions ahead — block growth must cover them (engine/spec.py).
         self.spec_lookahead = spec_lookahead
@@ -387,24 +380,6 @@ class Scheduler:
 
         # Decode batch first (every decodable stream advances every step);
         # grow blocks, preempting from the back on pressure.
-        # Window: fuse up to decode_window steps into one dispatch. Shrink to
-        # (a) fit every seq under max_model_len (the block table must cover
-        # every fused position) and (b) the useful horizon — past the point
-        # every stream will have hit max_tokens, fused steps are pure waste
-        # (their tokens are discarded at finalize).
-        cands = [s for s in self.running
-                 if s.in_decode and s.num_computed < self.max_model_len]
-        w = self.decode_window
-        if w > 1 and cands:
-            cap = min(self.max_model_len - s.num_computed for s in cands)
-            useful = 1
-            for s in cands:
-                mt = s.req.stop_conditions.max_tokens
-                # decode positions already computed (incl. in-flight windows)
-                out_est = max(s.num_computed - s.prefill_target(), 0)
-                useful = max(useful, (mt - out_est) if mt is not None else cap)
-            w = max(1, min(w, cap, useful))
-            w = 1 << (w.bit_length() - 1)  # pow2 bucket bounds compile count
         decodable: list[Seq] = []
         for seq in list(self.running):
             if not seq.in_decode:
@@ -418,9 +393,9 @@ class Scheduler:
                 # Only verify-eligible seqs reserve lookahead blocks —
                 # sampled/penalized seqs never speculate, and over-reserving
                 # for them would trigger preemptions for capacity nobody uses.
-                grow_ahead = max(w, 1 + self.spec_lookahead)
+                grow_ahead = 1 + self.spec_lookahead
             else:
-                grow_ahead = w
+                grow_ahead = 1
             if seq.num_computed >= self.max_model_len:
                 # At capacity: the finalize of an in-flight step will finish
                 # this seq (pipelined stepping plans ahead of stop checks);
@@ -443,12 +418,10 @@ class Scheduler:
             # could not grow even after preemption: preempt seq itself
             self.preempt(seq)
         plan.decode = decodable[: self.max_batch_size]
-        plan.decode_window = w if plan.decode else 1
 
         # Prefill chunks for seqs short of their target, within what's left
-        # of the step token budget after the decode rows (a fused window
-        # computes window tokens per row).
-        budget = self.max_tokens_per_step - len(plan.decode) * plan.decode_window
+        # of the step token budget after the decode rows.
+        budget = self.max_tokens_per_step - len(plan.decode)
         for seq in self.running:
             target = seq.prefill_target()
             if seq.num_computed < target and budget > 0:

@@ -7,8 +7,7 @@ One module covers both halves of the loop:
   device is a *hit*; blocks actually scattered into the device pool count as
   *imported* and convert to *recompute-avoided tokens* at the engine's block
   size. ``import_seconds`` measures the whole onboard (tier fetch + device
-  inject), so "predicted vs measured import seconds" in tools/perf_report.py
-  compares against the cost model's ``pull_seconds``.
+  inject): the measured side of the cost model's ``pull_seconds``.
 * **decision** (router side): the route-vs-pull arbiter's verdict per
   scheduled request, labelled by action (``route`` | ``pull`` |
   ``recompute``).

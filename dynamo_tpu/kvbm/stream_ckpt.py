@@ -39,7 +39,7 @@ from dynamo_tpu.utils.metrics import MetricsRegistry
 # recompute accounting), and both count the request as a ckpt resume.
 CKPT_GENERATED_KEY = "stream_ckpt.generated"
 # Total sampler draws the stream had consumed before the crash (one draw
-# per emitted token at decode_window=1) — the fold/step counter the
+# per emitted token) — the fold/step counter the
 # engine advances the restored key by.
 CKPT_DRAWS_KEY = "stream_ckpt.draws"
 # Captured device PRNG key data (list of uint32 words) at checkpoint time

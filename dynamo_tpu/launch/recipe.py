@@ -69,8 +69,8 @@ def _engine_args(engine: dict[str, Any]) -> list[str]:
     flags = {
         "blockSize": "--block-size", "numBlocks": "--num-blocks",
         "maxBatchSize": "--max-batch-size", "maxModelLen": "--max-model-len",
-        "decodeWindow": "--decode-window", "hostKvBlocks": "--host-kv-blocks",
-        "diskKvPath": "--disk-kv-path", "remoteKvAddr": "--remote-kv-addr",
+        "hostKvBlocks": "--host-kv-blocks", "diskKvPath": "--disk-kv-path",
+        "remoteKvAddr": "--remote-kv-addr",
     }
     # Boolean switches: present-and-truthy emits the bare flag.
     switches = {"globalPrefixCache": "--global-prefix-cache"}

@@ -77,9 +77,6 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
                    help="paged KV cache storage dtype (int8: in-kernel "
                         "dequant, ~2x KV capacity; int4: packed nibbles, "
                         "~4x capacity, even head_dim only)")
-    p.add_argument("--decode-window", type=int, default=1,
-                   help="decode steps fused per device dispatch (stop checks "
-                        "lag by up to window-1 tokens; output is unchanged)")
     p.add_argument("--prefill-chunk", type=int, default=512,
                    help="prefill chunk tokens per step; 0 = SLO-driven auto "
                         "sizing (largest per-QoS chunk keeping predicted "
@@ -87,9 +84,6 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     p.add_argument("--itl-slo-ms", type=float, default=50.0,
                    help="decode ITL SLO budget for --prefill-chunk 0 auto "
                         "sizing (interactive 1x, standard 2x, batch 4x)")
-    p.add_argument("--no-unified-step", action="store_true",
-                   help="dispatch decode and prefill chunks as the legacy "
-                        "two XLA launches instead of one ragged mixed step")
     p.add_argument("--host-kv-blocks", type=int, default=0, help="G2 host KV tier capacity")
     p.add_argument("--session-ttl", type=float, default=0.0,
                    help="session-sticky KV retention: seconds a finished "
@@ -136,10 +130,8 @@ def build_local_engine(ns: argparse.Namespace) -> tuple[AsyncJaxEngine, EngineCo
         dp=ns.dp,
         ep=ns.ep,
         sp=ns.sp,
-        decode_window=ns.decode_window,
         prefill_chunk=ns.prefill_chunk,
         itl_slo_ms=ns.itl_slo_ms,
-        unified_step=not ns.no_unified_step,
         quantization=ns.quantization,
         kv_dtype=ns.kv_dtype,
         spec_ngram=ns.spec_ngram,

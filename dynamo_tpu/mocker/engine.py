@@ -75,7 +75,7 @@ class MockEngineArgs:
     session_ttl: float = 0.0
     # Compile-ledger mirror (obs/compile_ledger.py): each simulated
     # dispatch derives the bucket signature the JAX engine WOULD compile
-    # (same _bucket/_pow2_bucket math, device-free) and a first-touch
+    # (the same sig_for_rows, device-free) and a first-touch
     # bucket files a real ledger event — span, metrics — plus a simulated
     # step-loop stall, so coldstart benchmarks measure a cold-vs-warm TTFT
     # gap without a TPU. "off" disables the ledger; "full" pre-files the
@@ -84,13 +84,6 @@ class MockEngineArgs:
     # Simulated wall seconds one cold-bucket compile stalls the step loop
     # (divided by speedup_ratio like every other simulated time).
     compile_s: float = 0.5
-    # Unified mixed-phase step mirror (engine/engine.py step_begin): the
-    # prefill chunk and every decode row advance in ONE simulated step —
-    # sig_for_rows("mixed", ...), a single sched-ledger record whose HOL
-    # stall is the chunk's MARGINAL share of the step wall (decode rows no
-    # longer lose a whole serialized iteration). False = legacy two-step
-    # serialization, matching --no-unified-step.
-    unified_step: bool = True
     # Crash-consistent stream checkpoints mirror (kvbm/stream_ckpt.py):
     # every this-many committed decode blocks (QoS-degraded like the JAX
     # engine: interactive 1x, standard 2x, batch 4x) the stream's newly
@@ -207,8 +200,7 @@ class MockEngine:
             block_size=self.args.block_size,
             max_batch_size=self.args.max_batch_size,
             max_model_len=self.args.max_model_len,
-            warmup_mode=self.args.warmup_mode,
-            unified_step=self.args.unified_step)
+            warmup_mode=self.args.warmup_mode)
         self._ledger = get_compile_ledger()
         self._ledger.configure(self.args.warmup_mode)
         if self.args.warmup_mode != "off":
@@ -216,7 +208,7 @@ class MockEngine:
         # Scheduling-ledger mirror (obs/sched_ledger.py): each simulated
         # step files a device-free step record — token-ratio goodput at
         # the sig_for_rows bucket geometry, HOL victims (the running
-        # decode streams a serialized prefill makes wait), admission-block
+        # decode streams a co-scheduled chunk makes wait), admission-block
         # causes — so fleet/chaos scenarios exercise the dynamo_sched_*
         # family and the decode_stall SLI without a TPU.
         self._sled = get_sched_ledger()
@@ -273,17 +265,13 @@ class MockEngine:
         return {"mode": self.args.warmup_mode, "buckets": len(plan),
                 "compiled": compiled, "coverage": led.coverage()}
 
-    def _mock_compile(self, kind: str, n_rows: int, t_max: int,
-                      nblk_need: int, victim=None) -> float:
-        """Cold-bucket mirror: derive the signature the JAX dispatch would
-        hit (sig_for_rows) and, on first touch, file a serve-source ledger
+    def _mock_compile(self, sig, victim=None) -> float:
+        """Cold-bucket mirror: on the first touch of the signature the JAX
+        dispatch would hit (sig_for_rows), file a serve-source ledger
         event — engine.compile span under the victim's trace and all — and
         return the simulated stall the caller must sleep."""
         led = self._ledger
-        if not led.enabled:
-            return 0.0
-        sig = sig_for_rows(kind, n_rows, t_max, nblk_need, self._lattice_cfg)
-        if sig in led.inventory:
+        if not led.enabled or sig in led.inventory:
             return 0.0
         stall = self.args.compile_s / self.args.speedup_ratio
         led.record(sig, stall, trace_ctx=victim, source="serve")
@@ -534,37 +522,31 @@ class MockEngine:
                 self._mled.maybe_audit(time.time())
             prefills = [s for s in self.running if not s.prefilled and not s.done]
             decodes = [s for s in self.running if s.prefilled and not s.done]
-            if prefills and a.unified_step:
-                # Unified mixed-phase step: the chunk and every decode row
-                # advance in ONE simulated launch. The decode rows still pay
-                # the chunk's compute alongside their own ITL, but no longer
-                # lose a whole serialized iteration — HOL stall is the
-                # chunk's MARGINAL share of this step, not its full wall.
+            if prefills:
+                # Mixed-phase step (engine/engine.py step_begin): the chunk
+                # and every decode row advance in ONE simulated launch. The
+                # decode rows pay the chunk's compute alongside their own
+                # ITL — HOL stall is the chunk's MARGINAL share of this
+                # step, not its full wall.
                 seq = prefills[0]
                 new_tokens = len(seq.req.token_ids) - seq.cached_blocks * a.block_size
-                n_rows = 1 + len(decodes)
-                t_max = max(new_tokens, 1)
-                nblk = max(len(s.block_ids) for s in [seq] + decodes)
-                # Degenerate mixed batches (every live row one token) ARE
-                # the decode program — same rule as dispatch().
-                kind = "mixed" if t_max > 1 else "decode"
-                stall = self._mock_compile(kind, n_rows, t_max, nblk,
-                                           victim=seq.trace_ctx)
+                sig = sig_for_rows(
+                    "mixed", 1 + len(decodes), max(new_tokens, 1),
+                    max(len(s.block_ids) for s in [seq] + decodes),
+                    self._lattice_cfg)
+                stall = self._mock_compile(sig, victim=seq.trace_ctx)
                 pf_wall = new_tokens * a.prefill_us_per_token / 1e6 / a.speedup_ratio
                 dec_wall = (a.decode_itl_ms / 1e3 / a.speedup_ratio
                             if decodes else 0.0)
                 # One launch prices at the roofline MAX of the two phases
-                # (costmodel.mixed_step_seconds), not the serialized sum the
-                # legacy two-launch path below pays.
+                # (costmodel.mixed_step_seconds), not their sum.
                 wall = stall + max(pf_wall, dec_wall)
                 await asyncio.sleep(wall)
                 if self._sled.enabled:
-                    sig = sig_for_rows(kind, n_rows, t_max, nblk,
-                                       self._lattice_cfg)
                     share = (pf_wall / (pf_wall + dec_wall)
                              if pf_wall + dec_wall > 0 else None)
                     self._sled.record_step(
-                        wall_s=wall, kinds=(kind,), prefill_rows=1,
+                        wall_s=wall, kinds=(sig.kind,), prefill_rows=1,
                         decode_rows=len(decodes),
                         live_tokens=new_tokens + len(decodes),
                         sched_tokens=sig.n, rect_tokens=sig.b * sig.t,
@@ -599,54 +581,17 @@ class MockEngine:
                     self._emit_token(dseq)
                     self._commit(dseq, total - 1)
                 continue
-            if prefills:
-                seq = prefills[0]
-                new_tokens = len(seq.req.token_ids) - seq.cached_blocks * a.block_size
-                stall = self._mock_compile(
-                    "prefill", 1, new_tokens, len(seq.block_ids),
-                    victim=seq.trace_ctx)
-                wall = (stall + new_tokens * a.prefill_us_per_token
-                        / 1e6 / a.speedup_ratio)
-                await asyncio.sleep(wall)
-                if self._sled.enabled:
-                    # The mocker serializes prefill ahead of decode, so
-                    # every prefilled running stream literally waited this
-                    # whole iteration — the cleanest HOL victim set.
-                    victims = [s for s in self.running
-                               if s.prefilled and not s.done and s is not seq]
-                    sig = sig_for_rows("prefill", 1, max(new_tokens, 1),
-                                       len(seq.block_ids), self._lattice_cfg)
-                    self._sled.record_step(
-                        wall_s=wall, kinds=("prefill",), prefill_rows=1,
-                        live_tokens=new_tokens, sched_tokens=sig.n,
-                        rect_tokens=sig.b * sig.t,
-                        queue_depths=self._queue_depths(),
-                        hol=HolStall(
-                            culprit=seq.req.request_id,
-                            culprit_tokens=new_tokens,
-                            victims=[(v.trace_ctx, v.req.request_id,
-                                      v.priority) for v in victims])
-                        if victims else None)
-                seq.prefilled = True
-                self._trace_phase(seq, "engine.decode",
-                                  batch=len(self.running))
-                self._commit(seq, len(seq.req.token_ids))
-                self._emit_token(seq)
-                continue
-
             if decodes:
-                stall = self._mock_compile(
+                sig = sig_for_rows(
                     "decode", len(decodes), 1,
                     max(len(s.block_ids) for s in decodes),
-                    victim=next((s.trace_ctx for s in decodes
-                                 if s.trace_ctx is not None), None))
+                    self._lattice_cfg)
+                stall = self._mock_compile(
+                    sig, victim=next((s.trace_ctx for s in decodes
+                                      if s.trace_ctx is not None), None))
                 wall = stall + a.decode_itl_ms / 1e3 / a.speedup_ratio
                 await asyncio.sleep(wall)
                 if self._sled.enabled:
-                    sig = sig_for_rows(
-                        "decode", len(decodes), 1,
-                        max(len(s.block_ids) for s in decodes),
-                        self._lattice_cfg)
                     self._sled.record_step(
                         wall_s=wall, kinds=("decode",),
                         decode_rows=len(decodes),

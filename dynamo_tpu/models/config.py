@@ -120,7 +120,7 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         tie_word_embeddings=False,
     ),
     # 8-layer cut of llama-3-8b: real layer shapes, fits one v5e chip with
-    # ample KV cache headroom — used by bench.py and the compile-check entry.
+    # ample KV cache headroom — used by chip_smoke.py and the compile-check entry.
     "llama-3-8b-lite": ModelConfig(
         name="llama-3-8b-lite",
         vocab_size=128256,

@@ -1,8 +1,8 @@
 """XLA compile ledger: per-bucket compile events, warmup lattice, metrics.
 
 Every hot-path program the engine runs is a bucketed ``jax.jit`` compile —
-decode/prefill step, the fused decode window, spec verify, embed — and each
-compile blocks the engine-core thread for its full trace+compile wall. This
+the decode step, the mixed step, spec verify, embed — and each compile
+blocks the engine-core thread for its full trace+compile wall. This
 module makes those stalls observable and schedulable:
 
 * ``CompileLedger`` — process-global record of every compile event keyed by
@@ -17,10 +17,15 @@ module makes those stalls observable and schedulable:
   (lint-checked by tools/lint_metrics.py COMPILE_METRICS), re-homeable into
   a worker's runtime registry via ``install_compile_metrics`` exactly like
   the perf/ring-prefill families.
-* ``enumerate_buckets(EngineConfig)`` — the reachable bucket lattice,
-  computed with the SAME ``_bucket``/``_pow2_bucket`` math the dispatch
-  paths use (engine/engine.py), so AOT warmup precompiles exactly what
-  serving would mint lazily. Embed buckets are deliberately excluded from
+* ``sig_for_rows`` — THE step geometry: the one place that turns a batch
+  (rows, longest row, block need) into the ``(kind, b, t, nblk, n)`` of the
+  program that serves it. ``ModelRunner.dispatch`` / ``dispatch_verify``
+  (engine/engine.py) shape their inputs from it, the scheduling ledger
+  prices the signature they dispatched, the mocker and the benchmark's
+  warm-up list call it device-free.
+* ``enumerate_buckets(EngineConfig)`` — the reachable bucket lattice over
+  the same ladders, so AOT warmup precompiles exactly what serving would
+  mint lazily. Embed buckets are deliberately excluded from
   the warmup plan: embeddings are off the generate hot path and their
   ``b × t`` lattice would dominate the budget (their compiles are still
   ledgered when they happen).
@@ -48,10 +53,8 @@ WARMUP_MODES = ("off", "lazy", "full")
 _COMPILE_SECONDS_BUCKETS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
                             60.0, 120.0)
 
-# Mirrors of engine/engine.py's bucket helpers. Kept textually tiny and
-# import-free so the mocker and tests can compute signatures device-free;
-# tests/test_compile_obs.py pins these against hand-computed dispatch
-# geometry so they cannot drift from the engine silently.
+# The bucket ladders' two rules. Import-free, so the mocker and tests
+# compute signatures device-free.
 
 
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -74,18 +77,18 @@ def token_bucket(kind: str, b: int, t: int) -> int:
     the ``b x t`` rectangle. One N for each ``(kind, b, t)``, so the
     lattice has no dimension for it.
 
-    A step with prefill work ("mixed", "prefill") holds one chunk of up to
+    A step with prefill work ("mixed") holds one chunk of up to
     t tokens beside up to b - 1 one-token rows, so ``t + b`` tokens hold
     it; the rectangle where that is smaller (one row). A step whose chunks
     hold more is cut into several programs (``pack_rows``), never padded
     to the rectangle. Every other kind is the rectangle: a decode row is
     its one token (``b``), a verify row fills its chunk."""
-    if kind in ("mixed", "prefill"):
+    if kind == "mixed":
         return min(b * t, t + b)
     return b * t
 
 
-def pack_rows(lengths: list[int], ec, mixed: bool) -> list[int]:
+def pack_rows(lengths: list[int], ec) -> list[int]:
     """Cut a batch's rows, in dispatch order, into runs that each fit the
     token bucket of the program their own ``(b, t)`` buckets name; returns
     the rows in each run. ``lengths`` is each row's live tokens. A run
@@ -93,13 +96,12 @@ def pack_rows(lengths: list[int], ec, mixed: bool) -> list[int]:
     full chunks do not. One row always fits, and so do one chunk and the
     one-token rows before it (``t + b > t_max + n - 1``): a decode batch,
     or a mixed step with one chunk, is one run as it always was."""
-    ladder = ec.decode_bucket if mixed else (1, 2, 4, 8)
     runs: list[int] = []
     n = t_max = total = 0
     for length in lengths:
         n, t_max, total = n + 1, max(t_max, length), total + length
         if n > 1 and t_max > 1 and total > token_bucket(
-                "mixed", _bucket(n, ladder),
+                "mixed", _bucket(n, ec.decode_bucket),
                 _pow2_bucket(t_max, 16, ec.prefill_chunk)):
             runs.append(n - 1)
             n, t_max, total = 1, length, length
@@ -110,11 +112,11 @@ def pack_rows(lengths: list[int], ec, mixed: bool) -> list[int]:
 @dataclass(frozen=True)
 class BucketSig:
     """One compiled program's bucket signature. ``kind`` is one of
-    decode | window | prefill | mixed | verify | embed; ``greedy`` is the
-    argmax-only fast path variant (always True for verify/embed). "mixed"
-    is the unified ragged step (decode rows + a prefill chunk in one
-    launch): b buckets over the DECODE ladder, t over the prefill chunk
-    ladder — the program itself is the same ragged step fn either way.
+    decode | mixed | verify | embed; ``greedy`` is the argmax-only fast
+    path variant (always True for verify/embed). "decode" is the step
+    whose every row is one token, "mixed" the ragged step that carries a
+    prefill chunk (decode rows beside it or not): b buckets over the
+    decode ladder, t over the prefill chunk ladder.
     ``n`` is the program's token bucket, read off ``(kind, b, t)``."""
 
     kind: str
@@ -374,7 +376,7 @@ def get_compile_ledger() -> CompileLedger:
 
 
 # ---------------------------------------------------------------------------
-# Bucket lattice enumeration — the SAME math as engine/engine.py dispatch.
+# Bucket lattice enumeration — the ladders sig_for_rows picks from.
 # ---------------------------------------------------------------------------
 
 def _nblk_ladder(max_nblk: int) -> list[int]:
@@ -424,10 +426,15 @@ def _verify_t_ladder(spec_k: int) -> list[int]:
     return sorted({min(_pow2_bucket(t, 2, k1), k1) for t in range(1, k1 + 1)})
 
 
+#: Embed's row ladder. Bounded: client batch sizes must not mint unbounded
+#: compile-cache entries (each compile blocks the engine-core thread).
+_EMBED_ROWS = (1, 2, 4, 8, 16, 32, 64)
+
+
 def embed_bucket_ladders(ec) -> tuple[list[int], list[int]]:
     """Embed's (b, t) ladders — exported for tests/tools; embed buckets are
     NOT part of the warmup plan (off the generate hot path)."""
-    bs = [x for x in (1, 2, 4, 8, 16, 32, 64)]
+    bs = list(_EMBED_ROWS)
     ts = [16]
     t = 16
     while t < ec.max_model_len:
@@ -442,69 +449,53 @@ def enumerate_buckets(ec) -> list[BucketSig]:
     against. Excludes: embed (off-path), sp-prefill/multimodal/guided
     variants (workload-dependent; organic compiles, still ledgered).
 
-    Unified mode (``ec.unified_step``): every step carrying prefill work
-    dispatches as ONE ragged "mixed" program (decode-ladder b × prefill
-    t ladder), so the separate "prefill" rungs are unreachable and are
-    pruned from the plan — coverage stays honest. Pure-decode steps still
-    dispatch the decode/window rungs, which stay."""
+    A step without prefill work is a "decode" program; one that carries a
+    chunk is ONE ragged "mixed" program (decode-ladder b x prefill t
+    ladder)."""
     kv = ec.kv_dtype or "bfloat16"
-    max_nblk = -(-ec.max_model_len // ec.block_size)
-    nblks = _nblk_ladder(max_nblk)
-    out: list[BucketSig] = []
-    dec_bs = _reachable_batch_buckets(ec.max_batch_size, ec.decode_bucket)
-    greedy_variants = (True, False)
-    for b in dec_bs:
-        for nblk in nblks:
-            for g in greedy_variants:
-                out.append(BucketSig("decode", b, 1, nblk, g, kv))
-                if ec.decode_window > 1:
-                    out.append(BucketSig("window", b, 1, nblk, g, kv))
-    # Fused decode windows are a decode-only concept: a window>1 engine
-    # keeps the legacy two-launch path, so its prefill rungs stay.
-    unified = getattr(ec, "unified_step", False) and ec.decode_window == 1
-    pf_kind = "mixed" if unified else "prefill"
-    pf_bs = (dec_bs if unified else
-             [x for x in (1, 2, 4, 8) if x <= max(ec.max_batch_size, 1)])
-    for b in pf_bs:
-        for t in _prefill_t_ladder(ec):
-            for nblk in nblks:
-                for g in greedy_variants:
-                    out.append(BucketSig(pf_kind, b, t, nblk, g, kv))
+    nblks = _nblk_ladder(-(-ec.max_model_len // ec.block_size))
+    bs = _reachable_batch_buckets(ec.max_batch_size, ec.decode_bucket)
+    out = [BucketSig("decode", b, 1, nblk, g, kv)
+           for b in bs for nblk in nblks for g in (True, False)]
+    out += [BucketSig("mixed", b, t, nblk, g, kv)
+            for b in bs for t in _prefill_t_ladder(ec)
+            for nblk in nblks for g in (True, False)]
     if ec.spec_ngram > 0:
-        for b in dec_bs:
-            for t in _verify_t_ladder(ec.spec_k):
-                for nblk in nblks:
-                    out.append(BucketSig("verify", b, t, nblk, True, kv))
+        out += [BucketSig("verify", b, t, nblk, True, kv)
+                for b in bs for t in _verify_t_ladder(ec.spec_k)
+                for nblk in nblks]
     return out
 
 
 def sig_for_rows(kind: str, n_rows: int, t_max: int, nblk_need: int,
                  ec, greedy: bool = True) -> BucketSig:
-    """Bucket signature for a dispatched batch — the device-free mirror of
-    dispatch()'s geometry math, used by the mocker and tests. The batch is
-    one run of ``pack_rows``; the signature's ``n`` is the ``[N, H]`` its
-    dense layers compute, ``b x t`` the rows its attention sees."""
+    """The program that serves a batch of ``n_rows`` rows whose longest
+    holds ``t_max`` tokens and whose widest block table needs
+    ``nblk_need`` entries: THE step geometry, which dispatch() shapes its
+    inputs by and every ledger reads back. The batch is one run of
+    ``pack_rows``; the signature's ``n`` is the ``[N, H]`` its dense layers
+    compute, ``b x t`` the rows its attention sees.
+
+    ``kind`` says only whether the batch is a speculative "verify" chunk
+    or an "embed" call (its own ladders, no block table); any other batch
+    is a step, and a step whose longest row is one token IS the decode
+    program, whatever it was planned as."""
     kv = ec.kv_dtype if getattr(ec, "kv_dtype", None) else "bfloat16"
-    max_nblk = -(-ec.max_model_len // ec.block_size)
-    nblk = min(_pow2_bucket(max(nblk_need, 1), 4, max_nblk), max_nblk)
-    if kind in ("decode", "window"):
-        return BucketSig(kind, _bucket(n_rows, ec.decode_bucket), 1, nblk,
-                         greedy, kv)
-    if kind == "verify":
-        t = min(_pow2_bucket(t_max, 2, ec.spec_k + 1), ec.spec_k + 1)
-        return BucketSig(kind, _bucket(n_rows, ec.decode_bucket), t, nblk,
+    if kind == "embed":
+        return BucketSig("embed", _bucket(n_rows, _EMBED_ROWS),
+                         _pow2_bucket(t_max, 16, ec.max_model_len), 0,
                          True, kv)
-    if kind == "mixed":
-        # Unified ragged step: rows bucket over the DECODE ladder (the
-        # batch can carry up to max_batch_size decode rows), t over the
-        # prefill chunk ladder. Degenerate mixed batches (every live row
-        # one token) ARE the decode program — same rule as dispatch().
-        if t_max <= 1:
-            return BucketSig("decode", _bucket(n_rows, ec.decode_bucket),
-                             1, nblk, greedy, kv)
-        t = _pow2_bucket(t_max, 16, ec.prefill_chunk)
-        return BucketSig("mixed", _bucket(n_rows, ec.decode_bucket), t,
-                         nblk, greedy, kv)
+    max_nblk = -(-ec.max_model_len // ec.block_size)
+    # Block-table width from the batch's KV coverage, pow2-bucketed to
+    # bound the number of compiled programs.
+    nblk = min(_pow2_bucket(max(nblk_need, 1), 4, max_nblk), max_nblk)
+    b = _bucket(n_rows, ec.decode_bucket)
+    if kind == "verify":
+        # clamp: _pow2_bucket's hi stops further doubling but doesn't cap
+        # the result — a 5-token chunk must not mint (and pay for) T=8
+        t = min(_pow2_bucket(t_max, 2, ec.spec_k + 1), ec.spec_k + 1)
+        return BucketSig("verify", b, t, nblk, True, kv)
+    if t_max <= 1:
+        return BucketSig("decode", b, 1, nblk, greedy, kv)
     t = _pow2_bucket(t_max, 16, ec.prefill_chunk)
-    return BucketSig("prefill", _bucket(n_rows, (1, 2, 4, 8)), t, nblk,
-                     greedy, kv)
+    return BucketSig("mixed", b, t, nblk, greedy, kv)

@@ -4,9 +4,9 @@ Every hot path the engine dispatches — paged attention (bf16 or int8 KV),
 ring-attention prefill, and the dense matmuls around them — gets a closed-
 form cost as a function of the call's shapes and dtypes. The step profiler
 (obs/profiler.py) folds these into per-step MFU / HBM-bandwidth-utilization
-counters; tools/perf_report.py renders them as the docs/PERF.md scoreboard;
-bench.py uses them to *predict* device numbers when the probe can only
-reach a CPU.
+counters; the scheduling ledger (obs/sched_ledger.py) prices padding with
+them; the engine sizes chunks and the ring threshold from them. What they
+predict has not been checked against a chip (ROADMAP.md D3).
 
 Conventions (stated once, relied on by tests/test_perf_obs.py):
 
@@ -156,8 +156,7 @@ class KernelCost:
         return "compute" if self.intensity >= hw.ridge_intensity else "bandwidth"
 
 
-#: every KV storage mode the cache supports (engine/cache.py), in scoreboard
-#: order — perf_report rows and the bench kv_dtype sweep iterate this.
+#: every KV storage mode the cache supports (engine/cache.py), widest first.
 KV_DTYPES = ("bfloat16", "int8", "int4")
 
 
@@ -394,7 +393,7 @@ def decode_step_cost(
     attn_num_splits: int = 1,
 ) -> dict[str, KernelCost]:
     """Uniform-batch decode step (every row: 1 query token, same context) —
-    the bench / perf_report / prediction entry point."""
+    the prediction entry point."""
     nblk = _ceil_div(max(kv_len, 1), block_size)
     return model_step_cost(
         cfg, tokens=batch, logit_rows=batch,
@@ -458,8 +457,7 @@ def predicted_decode_perf(
     quantization: str = "none",
     attn_num_splits: int = 1,
 ) -> dict:
-    """Roofline prediction for a decode config on ``hw`` — what bench.py
-    attaches as the device forecast when only the CPU fallback could run."""
+    """Roofline prediction for a decode config on ``hw``."""
     phases = decode_step_cost(cfg, batch=batch, kv_len=kv_len,
                               block_size=block_size, kv_dtype=kv_dtype,
                               quantization=quantization,
@@ -550,8 +548,7 @@ class PrefixCacheCost:
 
     def break_even_blocks(self) -> float:
         """Prefix depth (blocks) above which pulling beats recomputing on an
-        otherwise idle worker — the docs/PERF.md formula:
-        ``pull_s(n) < recompute_s(n · bs)``."""
+        otherwise idle worker: ``pull_s(n) < recompute_s(n · bs)``."""
         per_block_pull = self.wire_bytes_per_block / max(self.dcn_bytes_per_s, 1.0)
         per_block_recompute = self.block_size * self.seconds_per_token
         gain = per_block_recompute - per_block_pull
@@ -810,8 +807,8 @@ def ring_vs_chunked_prefill(
     kv_dtype: str = "bfloat16",
     quantization: str = "none",
 ) -> RingPrefillDecision:
-    """Price both prefill modes for one prompt; the engine's auto-select
-    and tools/perf_report.py both read this one verdict."""
+    """Price both prefill modes for one prompt: the verdict the engine's
+    auto-select reads."""
     return RingPrefillDecision(
         prompt_tokens=prompt_tokens,
         sp=sp,
@@ -878,8 +875,7 @@ class SessionRetentionCost:
     """The session-retention trade: holding one conversation's KV costs
     ``bytes_per_token`` of cache capacity per retained context token and
     buys back ``seconds_per_token`` of turn-N+1 prefill per token NOT
-    recomputed. ``seconds_per_gb`` is the docs/PERF.md break-even figure:
-    prefill seconds one retained gigabyte saves at achieved MFU."""
+    recomputed. ``seconds_per_gb`` is the break-even figure: prefill seconds one retained gigabyte saves at achieved MFU."""
 
     bytes_per_token: float
     seconds_per_token: float

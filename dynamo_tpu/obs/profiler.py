@@ -245,41 +245,29 @@ class StepPerfProfiler:
 
     def measure(self, batches: list, wall_s: float) -> dict[str, Any]:
         """Perf fields for one finalized step. ``batches`` is
-        PendingStep.batches: (kind, rows, sample_rows, toks, lps) with rows
-        of (seq, start, length)."""
+        PendingStep.batches: (sig, rows, sample_rows, toks, lps) with rows
+        of (seq, start, length) and ``sig`` the dispatched ``BucketSig``."""
         if not self.enabled or not batches:
             return {}
         bs = self.block_size
         tokens = logit_rows = 0
         attn_q_ctx = kv_blocks = 0.0
         dec_tokens = pf_tokens = 0
-        for kind, rows, sample_rows, toks, _lps in batches:
-            window = toks.shape[1] if getattr(toks, "ndim", 1) == 2 else 1
-            for (seq, start, length) in rows:
-                if kind == "decode" or (length == 1 and window > 1):
-                    w = window
-                    dec_tokens += w
-                    tokens += w
-                    logit_rows += w
-                    for j in range(w):
-                        nblk = -(-(start + length + j) // bs)
-                        attn_q_ctx += nblk * bs
-                        kv_blocks += nblk
+        for sig, rows, _sample_rows, _toks, _lps in batches:
+            for (_seq, start, length) in rows:
+                tokens += length
+                logit_rows += 1
+                nblk = -(-(start + length) // bs)
+                attn_q_ctx += length * nblk * bs
+                kv_blocks += nblk
+                # "mixed" batches carry both phases: multi-token rows are
+                # prefill chunks, single-token rows decode. (A 1-token
+                # prefill tail lands on the decode counter — one token of
+                # split drift; the aggregate volumes above stay exact.)
+                if sig.kind == "mixed" and length > 1:
+                    pf_tokens += length
                 else:
-                    tokens += length
-                    logit_rows += 1
-                    nblk = -(-(start + length) // bs)
-                    attn_q_ctx += length * nblk * bs
-                    kv_blocks += nblk
-                    # Unified "mixed" batches carry both phases: multi-token
-                    # rows are prefill chunks, single-token rows decode.
-                    # (A 1-token prefill tail inside a mixed batch lands on
-                    # the decode counter — one token of split drift; the
-                    # aggregate volumes above stay exact.)
-                    if kind == "prefill" or (kind == "mixed" and length > 1):
-                        pf_tokens += length
-                    else:
-                        dec_tokens += length
+                    dec_tokens += length
         phases = cm.model_step_cost(
             self.cfg, tokens=tokens, logit_rows=logit_rows,
             attn_q_ctx=attn_q_ctx, kv_blocks=kv_blocks, block_size=bs,

@@ -5,15 +5,14 @@ for the *scheduler's* decisions. Every dispatched engine step files one
 ``SchedStepRecord``:
 
 * **Goodput** — the fraction of scheduled (bucket-padded) FLOPs that were
-  live tokens. The engine dispatches static-shape programs
-  (``_bucket``/``_pow2_bucket`` geometry, engine/engine.py dispatch()); the
-  gap between the ragged batch it planned and the padded batch it ran is
+  live tokens. The engine dispatches static-shape programs (each batch
+  carries the ``BucketSig`` dispatch() ran it under); the gap between the ragged batch it planned and the padded batch it ran is
   pure waste, priced through the same analytic cost model the perf
   profiler uses (obs/costmodel.py) and exported as
   ``dynamo_sched_goodput_fraction`` plus cumulative padding FLOPs/bytes.
 * **HOL interference** — when a prefill chunk shares a step with decode
-  streams, every decode row's token delivery is delayed by the whole
-  step's wall (outputs materialize only at finalize). Each victim stream
+  streams, every decode row's token delivery is delayed by the chunk's
+  marginal share of the step's wall. Each victim stream
   accrues an ``engine.hol_stall`` span in its OWN trace carrying the
   culprit request id, aggregated into
   ``dynamo_sched_hol_stall_seconds{qos_class}`` and a per-step
@@ -44,7 +43,6 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
-from dynamo_tpu.obs.compile_ledger import _bucket, _pow2_bucket, token_bucket
 from dynamo_tpu.utils.metrics import MetricsRegistry
 
 SCHED_ENV = "DYN_SCHED_LEDGER"
@@ -181,10 +179,9 @@ class HolStall:
     decode-ready streams whose token delivery its chunk delayed.
 
     ``stall_share`` scales the per-victim stall below the full step wall:
-    under the unified mixed step the chunk is not a separate launch, so
-    the engine passes the chunk's cost-model marginal share of the step
-    (mixed minus pure-decode over mixed). None = legacy two-launch
-    attribution (the whole wall)."""
+    the chunk is not a separate launch, so the engine passes the chunk's
+    cost-model marginal share of the step (mixed minus pure-decode over
+    mixed). None (the cost model priced nothing) charges the whole wall."""
 
     culprit: str                    # culprit request id (largest chunk)
     culprit_tokens: int             # prefill tokens the step carried
@@ -201,7 +198,6 @@ class SchedStepRecord:
     kinds: tuple                    # batch kinds dispatched, in order
     prefill_rows: int = 0
     decode_rows: int = 0
-    decode_window: int = 1
     live_tokens: int = 0            # tokens the plan actually needed
     sched_tokens: int = 0           # tokens the dense layers computed (N)
     rect_tokens: int = 0            # positions attention ran over (b x t)
@@ -216,8 +212,7 @@ class SchedStepRecord:
     preempt: dict = field(default_factory=dict)        # cause -> tokens
     hol_culprit: str = ""
     hol_victims: int = 0
-    hol_stall_s: float = 0.0        # per-victim stall (wall x stall_share;
-                                    # == full wall on the legacy path)
+    hol_stall_s: float = 0.0        # per-victim stall (wall x stall_share)
     interference_row_s: float = 0.0  # victims x stall
 
     def to_dict(self) -> dict:
@@ -227,7 +222,6 @@ class SchedStepRecord:
             "kinds": list(self.kinds),
             "prefill_rows": self.prefill_rows,
             "decode_rows": self.decode_rows,
-            "decode_window": self.decode_window,
             "live_tokens": self.live_tokens,
             "sched_tokens": self.sched_tokens,
             "rect_tokens": self.rect_tokens,
@@ -356,7 +350,6 @@ class SchedLedger:
         kinds: tuple | list,
         prefill_rows: int = 0,
         decode_rows: int = 0,
-        decode_window: int = 1,
         live_tokens: int = 0,
         sched_tokens: int = 0,
         rect_tokens: int = 0,
@@ -387,7 +380,6 @@ class SchedLedger:
         rec = SchedStepRecord(
             ts=end, wall_s=wall_s, kinds=tuple(kinds),
             prefill_rows=prefill_rows, decode_rows=decode_rows,
-            decode_window=decode_window,
             live_tokens=live_tokens, sched_tokens=sched_tokens,
             rect_tokens=rect_tokens,
             live_flops=live_flops, sched_flops=sched_flops,
@@ -399,9 +391,7 @@ class SchedLedger:
         pad_b = max(sched_bytes - live_bytes, 0.0)
         if hol is not None and hol.victims:
             # Every decode-ready stream in the step waited for its token
-            # (outputs materialize at finalize). Legacy two-launch steps
-            # charge the full step wall (the prefill program serialized
-            # after decode); unified mixed steps charge only the chunk's
+            # (outputs materialize at finalize); the chunk is charged its
             # marginal share of the single launch.
             stall = (wall_s * hol.stall_share
                      if hol.stall_share is not None else wall_s)
@@ -549,29 +539,24 @@ def get_sched_ledger() -> SchedLedger:
 
 
 # ---------------------------------------------------------------------------
-# Live-vs-scheduled step geometry — the SAME math as engine dispatch.
+# Live-vs-scheduled step geometry — the programs dispatch() ran.
 # ---------------------------------------------------------------------------
 
-def step_geometry(model_cfg, engine_cfg, batches, *,
-                  mixed_dec_rows: int = 0) -> dict:
+def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
     """Live and scheduled (bucket-padded) work for one finalized step.
 
-    ``batches`` is PendingStep.batches: (kind, rows, sample_rows, toks,
-    lps) with rows of (seq, start, length). The live walk mirrors
-    StepPerfProfiler.measure exactly; the padded walk prices the bucket
-    geometry dispatch() actually compiled (``_bucket``/``_pow2_bucket``
-    over rows/t_max/nblk_need — without dispatch's len(block_ids) clamp,
-    which can have shrunk by finalize time for finished seqs). Both sides
+    ``batches`` is PendingStep.batches: (sig, rows, sample_rows, toks,
+    lps) with rows of (seq, start, length) and ``sig`` the ``BucketSig``
+    dispatch() ran the batch under. The live walk mirrors
+    StepPerfProfiler.measure exactly; the padded side prices that
+    signature: its token bucket ``n`` through the dense layers, its
+    ``b x t`` rows through attention, ``nblk`` blocks a row. Both sides
     run through obs/costmodel.model_step_cost, so goodput is a pure FLOPs
     ratio hand-computable at any known bucket geometry.
 
-    Unified "mixed" batches (decode rows + prefill chunks in one launch)
-    price as: live = per-row exact tokens/contexts, scheduled = the mixed
-    program's token bucket N through the dense layers (``token_bucket`` of
-    its b over the DECODE row ladder and t over the prefill chunk ladder)
-    and its b × t rows through attention. ``mixed_dec_rows`` is the
-    plan-time decode-row count of the step's mixed batches (the leading
-    rows of the first), splitting prefill_rows/decode_rows.
+    ``dec_rows`` is the plan-time count of decode rows among the step
+    batches' rows, counted from the first (the rest are prefill chunks);
+    verify rows count as decode rows beside it.
 
     Returns {kinds, prefill_rows, decode_rows, live_tokens, sched_tokens,
     rect_tokens, live_flops, sched_flops, live_bytes, sched_bytes}:
@@ -584,94 +569,40 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
     bs = ec.block_size
     kv = ec.kv_dtype or "bfloat16"
     quant = ec.quantization or "none"
-    max_nblk = -(-ec.max_model_len // bs)
     live = {"tokens": 0, "logit_rows": 0, "attn_q_ctx": 0.0, "kv_blocks": 0.0}
     sched = {"tokens": 0, "logit_rows": 0, "attn_q_ctx": 0.0, "kv_blocks": 0.0}
     kinds: list[str] = []
-    pf_rows = dec_rows = rect = 0
-    dec_left = mixed_dec_rows
-    for kind, rows, _sample_rows, toks, _lps in batches:
+    pf_rows = n_dec = rect = 0
+    dec_left = dec_rows
+    for sig, rows, _sample_rows, _toks, _lps in batches:
         if not rows:
             continue
         n = len(rows)
-        window = toks.shape[1] if getattr(toks, "ndim", 1) == 2 else 1
-        t_max = max(length for _, _, length in rows)
-        # padded program geometry (engine/engine.py dispatch())
-        if kind == "verify":
-            b = _bucket(n, ec.decode_bucket)
-            t = min(_pow2_bucket(t_max, 2, ec.spec_k + 1), ec.spec_k + 1)
-            window = 1
-        elif t_max == 1:
-            # Includes degenerate "mixed" batches (every live row one token):
-            # dispatch reclassifies those to the decode program.
-            b, t = _bucket(n, ec.decode_bucket), 1
-        elif kind == "mixed":
-            # Unified step: decode-row ladder for b, prefill chunk ladder
-            # for t — the envelope dispatch() compiles for mixed batches.
-            b, t = _bucket(n, ec.decode_bucket), _pow2_bucket(
-                t_max, 16, ec.prefill_chunk)
-            window = 1
+        if sig.kind == "verify":
+            kinds.append("verify")
+            n_dec += n
         else:
-            b, t = _bucket(n, (1, 2, 4, 8)), _pow2_bucket(
-                t_max, 16, ec.prefill_chunk)
-            window = 1
-        nblk_need = max(
-            -(-(start + length + window - 1) // bs)
-            for _s, start, length in rows)
-        nblk = min(_pow2_bucket(max(nblk_need, 1), 4, max_nblk), max_nblk)
-        if kind == "prefill":
-            kinds.append("prefill")
-            pf_rows += n
-        elif kind == "mixed":
-            # Leading rows of a mixed batch are decode/guided by
+            # Leading rows of a step's batches are decode/guided by
             # construction; the split is captured at plan time because
             # prefill_target() moves as finalize appends tokens.
-            kinds.append("mixed" if t_max > 1 else "decode")
+            guided = (n <= dec_left and getattr(
+                rows[0][0], "guided", None) is not None)
+            kinds.append("guided" if guided else sig.kind)
             d = min(dec_left, n)
             dec_left -= d
-            dec_rows += d
+            n_dec += d
             pf_rows += n - d
-        elif kind == "verify":
-            kinds.append("verify")
-            dec_rows += n
-        elif window > 1:
-            kinds.append("window")
-            dec_rows += n
-        elif rows[0][0] is not None and getattr(
-                rows[0][0], "guided", None) is not None:
-            kinds.append("guided")
-            dec_rows += n
-        else:
-            kinds.append("decode")
-            dec_rows += n
-        if kind == "decode":
-            # live: each row decodes `window` positions
-            for _seq, start, length in rows:
-                live["tokens"] += window
-                live["logit_rows"] += window
-                for j in range(window):
-                    nb = -(-(start + length + j) // bs)
-                    live["attn_q_ctx"] += nb * bs
-                    live["kv_blocks"] += nb
-            # scheduled: b padded rows x window positions at the bucketed
-            # block-table width
-            sched["tokens"] += b * window
-            rect += b * window
-            sched["logit_rows"] += b * window
-            sched["attn_q_ctx"] += b * window * nblk * bs
-            sched["kv_blocks"] += b * window * nblk
-        else:
-            for _seq, start, length in rows:
-                live["tokens"] += length
-                live["logit_rows"] += 1
-                nb = -(-(start + length) // bs)
-                live["attn_q_ctx"] += length * nb * bs
-                live["kv_blocks"] += nb
-            sched["tokens"] += token_bucket(kind, b, t)
-            rect += b * t
-            sched["logit_rows"] += b
-            sched["attn_q_ctx"] += b * t * nblk * bs
-            sched["kv_blocks"] += b * nblk
+        for _seq, start, length in rows:
+            live["tokens"] += length
+            live["logit_rows"] += 1
+            nb = -(-(start + length) // bs)
+            live["attn_q_ctx"] += length * nb * bs
+            live["kv_blocks"] += nb
+        sched["tokens"] += sig.n
+        rect += sig.b * sig.t
+        sched["logit_rows"] += sig.b
+        sched["attn_q_ctx"] += sig.b * sig.t * sig.nblk * bs
+        sched["kv_blocks"] += sig.b * sig.nblk
 
     def _cost(agg: dict):
         phases = cm.model_step_cost(
@@ -685,7 +616,7 @@ def step_geometry(model_cfg, engine_cfg, batches, *,
     return {
         "kinds": tuple(kinds),
         "prefill_rows": pf_rows,
-        "decode_rows": dec_rows,
+        "decode_rows": n_dec,
         "live_tokens": live["tokens"],
         "sched_tokens": sched["tokens"],
         "rect_tokens": rect,

@@ -378,7 +378,7 @@ _HELLO_FIELDS = (
     "model", "dtype", "attn_impl", "allow_random_weights", "quantization",
     "kv_dtype", "num_blocks", "block_size",
     "max_batch_size", "max_model_len", "prefill_chunk", "max_tokens_per_step",
-    "decode_bucket", "decode_window", "seed", "enable_prefix_caching",
+    "decode_bucket", "seed", "enable_prefix_caching",
     "dp", "pp", "tp", "ep", "sp", "pp_microbatches",
     # KVBM tiers shape scheduling (onboarded blocks change prefill shapes):
     # every rank must run the same tier config in lockstep. remote_kv_addr

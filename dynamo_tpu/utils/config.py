@@ -100,17 +100,6 @@ class EngineConfig:
     # so bucket enumeration and warmup see real shapes).
     prefill_chunk: int = 512
     decode_bucket: tuple[int, ...] = (8, 16, 32, 64)
-    # Unified ragged mixed-phase steps: pack the step's decode rows (one
-    # live token each) and prefill-chunk rows (up to prefill_chunk live
-    # tokens) into ONE ragged XLA program per iteration. Its dense layers
-    # run over the rows' live tokens, packed into a bucket of t + b
-    # (models/llama.py forward, compile_ledger.token_bucket); only the
-    # attention kernel runs over the [b, t] rows, where a padded position
-    # still costs its grid step (on the [b, t] rectangle all through, the
-    # matmuls paid for 85 % padding: PERF.md section 6, PR 32). False =
-    # legacy two-launch path (decode program, then prefill program) for
-    # bisection.
-    unified_step: bool = True
     # Decode inter-token-latency SLO budget (milliseconds) that
     # costmodel.auto_prefill_chunk sizes chunks against when
     # prefill_chunk=0. Per-QoS ladder scales it: interactive 1x,
@@ -161,7 +150,7 @@ class EngineConfig:
     global_prefix_cache: bool = False
     # N-gram speculative decoding (engine/spec.py): 0 = off; n>0 proposes
     # continuations of the trailing n-gram, verified k at a time in one
-    # forward pass. Greedy-exact; mutually exclusive with decode_window>1.
+    # forward pass. Greedy-exact.
     spec_ngram: int = 0
     spec_k: int = 4
     seed: int = 0
@@ -178,14 +167,6 @@ class EngineConfig:
     # length and core count, decode only), 1 = sequential walk (off),
     # N>1 = forced split count (clamped to the block count).
     attn_num_splits: int = 0
-    # Fused decode window: run up to this many decode steps inside ONE
-    # compiled dispatch (lax.scan on device, sampled tokens feeding back
-    # without touching the host), amortizing the per-dispatch host cost
-    # over the window. Whether that wins on a host next to its chip is
-    # unmeasured (ROADMAP.md D1); > 1 also turns the unified step off.
-    # Stop conditions lag by at most window-1 tokens; overrun is discarded
-    # at finalize, so emitted streams are bit-identical to window=1.
-    decode_window: int = 1
     # Session-sticky KV retention (engine/session.py): when a stream with a
     # session.id annotation finishes, its committed KV blocks stay pinned
     # on device for this many seconds (leader-stamped step clock) so turn

@@ -41,7 +41,6 @@ def engine_cfg(kvbm: bool = False, remote_addr: str | None = None) -> EngineConf
         decode_bucket=(4, 8),
         tp=2,   # tiny-llama has 2 kv heads; model axis must divide them
         dp=2,
-        decode_window=2,   # exercise fused windows across hosts too
         host_kv_blocks=64 if kvbm else 0,
         # remote-only tier: every eviction rides to the shared G4 store
         # (per-rank shard namespaces), onboards come back from it.

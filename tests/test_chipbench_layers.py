@@ -39,30 +39,40 @@ POOL = [10, 2837, 16, 8, 128]            # the recorded cell's pool
 def test_manifest_names_files_that_exist():
     assert manifest.check() == []
     names = {m["name"] for m in BENCH["per_layer"]}
-    assert set(NEW_COUNTER + NEW_TRACE) - names == {
-        "mesh.collective_exposed_pct"}      # no four-chip cell to read it in
-    assert all(w["chips"] == 1 for w in BENCH["workloads"])
+    # What needs a four-chip cell is absent exactly while no cell has four
+    # chips (PERF.md section 7), and such cells stay few: a quarter of the
+    # cells at most, one always allowed.
+    four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
+    assert set(NEW_COUNTER + NEW_TRACE) - names == (
+        set() if four else {"mesh.collective_exposed_pct"})
+    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
 
 
 def test_the_four_chip_cells_files_are_ready_for_their_entries():
-    """``mistral-nemo-12b.tp4.chat`` was measured in PR 25 and not entered
-    in ``BENCHMARK.json`` (PERF.md section 7 says which edits of the harness
-    it waits for). Its files are data: with the entries PERF.md gives, the
+    """``mistral-nemo-12b.tp4.chat`` was measured in PR 25; until
+    ``BENCHMARK.json`` holds its entries (PERF.md section 7 gives them),
+    they are laid over it here. Its files are data: with the entries, the
     manifest finds them and has no fault."""
     about = ROOT / "chipbench/configs/mistral-nemo-12b-tp4/about.json"
+
+    def with_entry(key, entry):
+        have = {e["name"] for e in BENCH[key]}
+        return BENCH[key] + ([] if entry["name"] in have else [entry])
+
     bench = dict(
         BENCH,
-        configs=BENCH["configs"] + [{
+        configs=with_entry("configs", {
             "name": "mistral-nemo-12b-tp4",
             "source": json.loads(about.read_text())["source"],
             "file": "chipbench/configs/mistral-nemo-12b-tp4/config.json",
-            "reduced": []}],
-        workloads=BENCH["workloads"] + [{
+            "reduced": []}),
+        workloads=with_entry("workloads", {
             "name": "mistral-nemo-12b.tp4.chat",
-            "config": "mistral-nemo-12b-tp4", "traffic": "chat", "chips": 4}],
-        per_layer=BENCH["per_layer"] + [{
+            "config": "mistral-nemo-12b-tp4", "traffic": "chat", "chips": 4}),
+        per_layer=with_entry("per_layer", {
             "name": "mesh.collective_exposed_pct", "moves": "itl_p95_ms",
-            "workloads": ["mistral-nemo-12b.tp4.chat"]}])
+            "workloads": ["mistral-nemo-12b.tp4.chat"]}))
     assert manifest.check(bench) == []
     cell = manifest.load_cell("mistral-nemo-12b.tp4.chat", bench)
     assert cell.about["engine"]["tp"] == 4 and cell.about["reduced"] == {}

@@ -56,18 +56,18 @@ def sig(kind="decode", b=4, t=1, nblk=8, greedy=True, kv="bfloat16"):
 def test_event_schema_and_victim_attribution(clean_ledger):
     led = clean_ledger
     ctx = TraceContext.new()
-    ev = led.record(sig(kind="prefill", t=64), 1.25, trace_ctx=ctx,
+    ev = led.record(sig(kind="mixed", t=64), 1.25, trace_ctx=ctx,
                     ts=1000.0)
     assert ev is not None
     d = ev.to_dict()
-    assert d["kind"] == "prefill" and d["b"] == 4 and d["t"] == 64
+    assert d["kind"] == "mixed" and d["b"] == 4 and d["t"] == 64
     assert d["nblk"] == 8 and d["greedy"] is True
     assert d["kv_dtype"] == "bfloat16" and d["source"] == "serve"
     assert d["seconds"] == 1.25
     assert d["trace_id"] == ctx.trace_id
     # the event's start is the trigger: end minus the compile wall
     assert d["ts"] == pytest.approx(1000.0 - 1.25)
-    assert led.inventory == {sig(kind="prefill", t=64)}
+    assert led.inventory == {sig(kind="mixed", t=64)}
     # untraced warmup event: no trace_id key at all
     ev2 = led.record(sig(), 0.5, source="warmup")
     assert "trace_id" not in ev2.to_dict()
@@ -78,7 +78,7 @@ def test_serve_event_emits_span_warmup_does_not(clean_ledger):
 
     ctx = TraceContext.new()
     clean_ledger.record(sig(kind="decode"), 2.0, trace_ctx=ctx)
-    clean_ledger.record(sig(kind="prefill", t=32), 2.0, trace_ctx=ctx,
+    clean_ledger.record(sig(kind="mixed", t=32), 2.0, trace_ctx=ctx,
                         source="warmup")
     spans = [s for s in get_tracer().recorder.spans_for(ctx.trace_id)
              if s.name == "engine.compile"]
@@ -139,10 +139,10 @@ def test_by_bucket_totals(clean_ledger):
     led = clean_ledger
     led.record(sig(), 1.0)
     led.record(sig(), 0.5)
-    led.record(sig(kind="prefill", t=16), 2.0)
+    led.record(sig(kind="mixed", t=16), 2.0)
     bb = led.by_bucket()
     assert bb[sig()] == (2, 1.5)
-    assert bb[sig(kind="prefill", t=16)] == (1, 2.0)
+    assert bb[sig(kind="mixed", t=16)] == (1, 2.0)
 
 
 # ---------------------------------------------------------------------------
@@ -159,10 +159,9 @@ def tiny_ec(**kw) -> EngineConfig:
 
 def test_enumerate_tiny_config_hand_computed():
     """max_model_len=128/block=16 → max_nblk=8 → nblk ladder {4, 8}.
-    decode b ∈ {2, 4} (ladder covers max_batch_size); unified step is the
-    default, so prefill-carrying rungs enumerate as "mixed" over the
-    DECODE b ladder (the batch carries decode rows too), t ∈ {16, 32};
-    ×2 greedy variants, no window/spec:
+    decode b ∈ {2, 4} (ladder covers max_batch_size); prefill-carrying
+    rungs enumerate as "mixed" over the DECODE b ladder (the batch carries
+    decode rows too), t ∈ {16, 32}; ×2 greedy variants, no spec:
     decode 2×2×2=8, mixed 2×2×2×2=16 → 24."""
     sigs = enumerate_buckets(tiny_ec())
     assert len(sigs) == len(set(sigs)) == 24
@@ -178,28 +177,12 @@ def test_enumerate_tiny_config_hand_computed():
     assert BucketSig("mixed", 4, 32, 4, False, "bfloat16") in sigs
 
 
-def test_enumerate_legacy_path_keeps_prefill_rungs():
-    """--no-unified-step restores the two-launch lattice: prefill rungs
-    over the (1,2,4,8) ladder, no mixed rungs. Hand count: decode 8,
-    prefill b ∈ {1,2,4} × t {16,32} × nblk {4,8} × 2 greedy = 24 → 32."""
-    sigs = enumerate_buckets(tiny_ec(unified_step=False))
-    assert len(sigs) == len(set(sigs)) == 32
-    kinds = {}
-    for s in sigs:
-        kinds[s.kind] = kinds.get(s.kind, 0) + 1
-    assert kinds == {"decode": 8, "prefill": 24}
-    assert {s.b for s in sigs if s.kind == "prefill"} == {1, 2, 4}
-    assert BucketSig("prefill", 4, 32, 4, False, "bfloat16") in sigs
-
-
 def test_enumerate_default_config_size():
     """Default EngineConfig: max_nblk=-(-8192//16)=512 → nblk ladder
     {4,8,...,256,512} (8 rungs). decode b: ladder (1,2,4,8,...) through
-    max_batch_size → 4 rungs ≤ 64. Unified step (default): prefill rungs
-    become "mixed" over the same 4-rung decode b ladder × t ladder
-    {16..512} (6 rungs) × 8 nblk × 2 greedy = 384 — the total stays 448
-    because the decode b ladder has the same rung count as the legacy
-    (1,2,4,8) prefill ladder here."""
+    max_batch_size → 4 rungs ≤ 64. Prefill rungs are "mixed" over the
+    same 4-rung decode b ladder × t ladder {16..512} (6 rungs) × 8 nblk ×
+    2 greedy = 384 → 448."""
     ec = EngineConfig(model="tiny-llama")
     sigs = enumerate_buckets(ec)
     kinds = {}
@@ -209,7 +192,7 @@ def test_enumerate_default_config_size():
     assert len(sigs) == 448
 
 
-def test_enumerate_spec_and_window_variants():
+def test_enumerate_spec_variants():
     ec = tiny_ec(max_batch_size=8, decode_bucket=(4, 8), prefill_chunk=64,
                  spec_ngram=3, spec_k=4)
     sigs = enumerate_buckets(ec)
@@ -219,19 +202,10 @@ def test_enumerate_spec_and_window_variants():
     # verify t ladder for k=4: min(pow2(t,2,5),5) over t∈1..5 → {2,4,5}
     assert {s.t for s in sigs if s.kind == "verify"} == {2, 4, 5}
     assert all(s.greedy for s in sigs if s.kind == "verify")
-    # decode 2b×2nblk×2g=8, mixed (unified default; decode b ladder)
-    # 2b×3t×2nblk×2g=24 with t∈{16,32,64}
+    # decode 2b×2nblk×2g=8, mixed (decode b ladder) 2b×3t×2nblk×2g=24
+    # with t∈{16,32,64}
     assert kinds == {"decode": 8, "mixed": 24, "verify": 12}
     assert len(sigs) == 44
-    # fused window variant doubles the decode rungs — and windows are
-    # decode-only scans, so the engine keeps the legacy two-launch path:
-    # prefill rungs stay, no mixed rungs.
-    sigs_w = enumerate_buckets(tiny_ec(decode_window=4))
-    kw = {}
-    for s in sigs_w:
-        kw[s.kind] = kw.get(s.kind, 0) + 1
-    assert kw["window"] == kw["decode"] == 8
-    assert kw == {"decode": 8, "window": 8, "prefill": 24}
 
 
 def test_enumerate_excludes_embed_but_ladders_exported():
@@ -258,8 +232,8 @@ def test_sig_for_rows_lands_inside_enumeration():
     for n in (1, 2, 4):
         for t in (1, 7, 16, 30, 32):
             for need in (1, 5, 8):
-                # Unified step: prefill-carrying batches dispatch as
-                # "mixed"; t_max==1 degenerates to the decode program.
+                # Prefill-carrying batches dispatch as "mixed"; t_max==1
+                # degenerates to the decode program.
                 assert sig_for_rows("mixed", n, t, need, ec, True) in plan
     for n in range(1, ec.max_batch_size + 1):
         for t in (1, 2, 3, 5):
@@ -271,10 +245,8 @@ def test_sig_for_rows_matches_hand_computed_dispatch():
     # decode: b=_bucket(3,(2,4))=4, nblk=min(pow2(5,4,8),8)=8
     assert sig_for_rows("decode", 3, 1, 5, ec) == \
         BucketSig("decode", 4, 1, 8, True, "bfloat16")
-    # prefill: b ladder (1,2,4,8) → 3→4; t=pow2(20,16,32)=32; need 1→nblk 4
-    assert sig_for_rows("prefill", 3, 20, 1, ec) == \
-        BucketSig("prefill", 4, 32, 4, True, "bfloat16")
-    # mixed: b over the DECODE ladder (2,4) → 3→4; t=pow2(20,16,32)=32
+    # mixed: b over the DECODE ladder (2,4) → 3→4; t=pow2(20,16,32)=32;
+    # need 1→nblk 4
     assert sig_for_rows("mixed", 3, 20, 1, ec) == \
         BucketSig("mixed", 4, 32, 4, True, "bfloat16")
     # degenerate mixed (every live row one token) IS the decode program
@@ -392,8 +364,8 @@ def test_mocker_off_mode_is_silent(clean_ledger):
 
 def test_mocker_sig_mirror_matches_ledger_module(clean_ledger):
     """The mocker feeds sig_for_rows with its real dispatch geometry; the
-    recorded mixed sig (unified default: the prompt's chunk dispatches as
-    one ragged mixed step) must equal the hand-computed one."""
+    recorded mixed sig (the prompt's chunk dispatches as one ragged mixed
+    step) must equal the hand-computed one."""
     from dynamo_tpu.mocker.engine import MockEngine
 
     eng = MockEngine(_mock_args(warmup_mode="lazy"))
@@ -401,20 +373,7 @@ def test_mocker_sig_mirror_matches_ledger_module(clean_ledger):
     _run_mock(eng, ntok=24, max_tokens=2)
     mixed = [e.sig for e in led.events if e.sig.kind == "mixed"]
     assert mixed == [sig_for_rows("mixed", 1, 24, 6, eng._lattice_cfg)]
-    assert not any(e.sig.kind == "prefill" for e in led.events)
-
-
-def test_mocker_legacy_flag_keeps_prefill_sigs(clean_ledger):
-    """unified_step=False restores the serialized two-step mirror: the
-    prompt records a legacy prefill sig, never a mixed one."""
-    from dynamo_tpu.mocker.engine import MockEngine
-
-    eng = MockEngine(_mock_args(warmup_mode="lazy", unified_step=False))
-    led = get_compile_ledger()
-    _run_mock(eng, ntok=24, max_tokens=2)
-    prefills = [e.sig for e in led.events if e.sig.kind == "prefill"]
-    assert prefills == [sig_for_rows("prefill", 1, 24, 6, eng._lattice_cfg)]
-    assert not any(e.sig.kind == "mixed" for e in led.events)
+    assert {e.sig.kind for e in led.events} <= {"decode", "mixed"}
 
 
 # ---------------------------------------------------------------------------

@@ -288,83 +288,24 @@ def test_pipelined_mid_flight_abort():
     assert not core.has_work()
 
 
-# ---------------------------------------------------------------------------
-# Fused decode windows (decode_window > 1): emitted streams must be
-# bit-identical to single-step decoding — stop-condition lag and window
-# overrun are invisible to the client.
-# ---------------------------------------------------------------------------
-
-def _stream_pair(cfg_kw_a, cfg_kw_b, reqs_fn, pipelined=False):
+def _against_solo(cfg_kw, reqs_fn, pipelined=False):
+    """(reference, got): the streams of ``reqs_fn``'s requests given one at
+    a time to a fresh core — so no step holds a chunk beside a decode row,
+    nothing is preempted, and every program serves one row — and the same
+    requests given together to a core built with ``cfg_kw``."""
     reqs_a = reqs_fn("a")
-    core_a = EngineCore(tiny_config(**cfg_kw_a))
-    got_a, fin_a = run_to_completion(core_a, reqs_a)
+    solo = EngineCore(tiny_config())
+    got_a, fin_a = {}, set()
+    for r in reqs_a:
+        got, fin = run_to_completion(solo, [r])
+        got_a.update(got)
+        fin_a |= fin
     reqs_b = reqs_fn("b")
-    core_b = EngineCore(tiny_config(**cfg_kw_b))
+    core_b = EngineCore(tiny_config(**cfg_kw))
     runner = run_pipelined if pipelined else run_to_completion
     got_b, fin_b = runner(core_b, reqs_b)
     assert len(fin_a) == len(reqs_a) and len(fin_b) == len(reqs_b)
     return got_a, got_b
-
-
-def test_windowed_matches_sync_greedy():
-    def reqs(tag):
-        return [make_req(prompt=[3 * i + j for j in range(5 + i)],
-                         max_tokens=6 + 2 * i, rid=f"{tag}{i}") for i in range(4)]
-
-    got_a, got_b = _stream_pair({}, {"decode_window": 4}, reqs)
-    for i in range(4):
-        assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
-        assert len(got_b[f"b{i}"]) == 6 + 2 * i  # overrun discarded
-
-
-def test_windowed_sampled_reproducible():
-    """Seeded sampling with penalties advances per-slot PRNG keys once per
-    token in both modes — windowed must reproduce the sync stream."""
-    def reqs(tag):
-        return [make_req(prompt=[7 * i + j for j in range(6)], max_tokens=10,
-                         temperature=0.8, seed=42 + i,
-                         frequency_penalty=0.3, rid=f"{tag}{i}")
-                for i in range(3)]
-
-    got_a, got_b = _stream_pair({}, {"decode_window": 4}, reqs)
-    for i in range(3):
-        assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
-
-
-def test_windowed_pipelined_matches_sync():
-    """Window + one-step-in-flight pipelining (the production loop shape)."""
-    def reqs(tag):
-        return [make_req(prompt=[5 * i + j for j in range(4 + i)],
-                         max_tokens=7 + i, rid=f"{tag}{i}") for i in range(3)]
-
-    got_a, got_b = _stream_pair({}, {"decode_window": 4}, reqs, pipelined=True)
-    for i in range(3):
-        assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
-
-
-def test_windowed_under_block_pressure():
-    """A pool small enough to force preemption still converges to the same
-    streams: windowed growth (w blocks ahead) preempts and resumes cleanly."""
-    def reqs(tag):
-        return [make_req(prompt=[11 * i + j for j in range(8)], max_tokens=12,
-                         rid=f"{tag}{i}") for i in range(4)]
-
-    # 24 usable blocks: 4 seqs * (8 prompt + 12 out + window slack)/4 > pool
-    got_a, got_b = _stream_pair({"num_blocks": 25}, {"num_blocks": 25, "decode_window": 4}, reqs)
-    for i in range(4):
-        assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
-
-
-def test_windowed_max_model_len_cap():
-    """Windows shrink so the block table never outgrows max_model_len."""
-    def reqs(tag):
-        return [make_req(prompt=list(range(10, 22)), max_tokens=64, rid=f"{tag}0")]
-
-    # max_model_len 20 caps output at 8 tokens; window 8 must shrink near cap
-    kw = dict(max_model_len=20, num_blocks=16)
-    got_a, got_b = _stream_pair(kw, {**kw, "decode_window": 8}, reqs)
-    assert got_b["b0"] == got_a["a0"]
-    assert len(got_b["b0"]) == 20 - 12
 
 
 def test_pp_engine_matches_unsharded():
@@ -395,14 +336,14 @@ def test_fast_greedy_path_matches_general():
     co-batching a temperature request forces the general path as oracle)."""
     prompts = [[10 + i * 3 + j for j in range(9)] for i in range(2)]
 
-    fast_core = EngineCore(tiny_config(decode_window=2))
+    fast_core = EngineCore(tiny_config())
     fast, _ = run_to_completion(fast_core, [
         make_req(prompt=p, max_tokens=7, rid=f"g{i}")
         for i, p in enumerate(prompts)])
     assert fast_core.runner.used_fast_greedy(), \
         f"fast_greedy variant unused: {list(fast_core.runner._step_fns)}"
 
-    gen_core = EngineCore(tiny_config(decode_window=2))
+    gen_core = EngineCore(tiny_config())
     general, _ = run_to_completion(gen_core, [
         *(make_req(prompt=p, max_tokens=7, rid=f"g{i}")
           for i, p in enumerate(prompts)),
@@ -420,18 +361,20 @@ def test_fast_greedy_path_matches_general():
 
 
 # ---------------------------------------------------------------------------
-# Unified ragged mixed-phase steps: decode rows and prefill chunks dispatched
-# as ONE launch must emit streams identical to the legacy two-launch path
-# (--no-unified-step). Prompts span multiple chunks so decode rows genuinely
-# co-batch with in-flight prefill chunks mid-run.
+# Ragged mixed-phase steps: a stream does not depend on what shares its step.
+# Decode rows and prefill chunks dispatched as ONE launch must emit the
+# streams the same requests emit one at a time, where no step mixes phases
+# (the numerical reference is tests/test_token_major.py). Prompts span
+# multiple chunks so decode rows genuinely co-batch with in-flight prefill
+# chunks mid-run.
 # ---------------------------------------------------------------------------
 
-def test_unified_matches_legacy_greedy():
+def test_unified_matches_solo_greedy():
     def reqs(tag):
         return [make_req(prompt=[(3 * i + j) % 100 for j in range(5 + 17 * i)],
                          max_tokens=6 + 2 * i, rid=f"{tag}{i}") for i in range(4)]
 
-    got_a, got_b = _stream_pair({"unified_step": False}, {}, reqs)
+    got_a, got_b = _against_solo({}, reqs)
     for i in range(4):
         assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
         assert len(got_b[f"b{i}"]) == 6 + 2 * i
@@ -447,20 +390,19 @@ def test_unified_sampled_reproducible():
                          frequency_penalty=0.3, rid=f"{tag}{i}")
                 for i in range(3)]
 
-    got_a, got_b = _stream_pair({"unified_step": False}, {}, reqs)
+    got_a, got_b = _against_solo({}, reqs)
     for i in range(3):
         assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
 
 
 @pytest.mark.slow
-def test_unified_pipelined_matches_legacy():
+def test_unified_pipelined_matches_solo():
     """Unified steps under one-step-in-flight pipelining (production loop)."""
     def reqs(tag):
         return [make_req(prompt=[(5 * i + j) % 80 for j in range(4 + 16 * i)],
                          max_tokens=7 + i, rid=f"{tag}{i}") for i in range(3)]
 
-    got_a, got_b = _stream_pair({"unified_step": False}, {}, reqs,
-                                pipelined=True)
+    got_a, got_b = _against_solo({}, reqs, pipelined=True)
     for i in range(3):
         assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
 
@@ -472,8 +414,7 @@ def test_unified_under_block_pressure():
         return [make_req(prompt=[(11 * i + j) % 70 for j in range(8)],
                          max_tokens=12, rid=f"{tag}{i}") for i in range(4)]
 
-    got_a, got_b = _stream_pair({"num_blocks": 25, "unified_step": False},
-                                {"num_blocks": 25}, reqs)
+    got_a, got_b = _against_solo({"num_blocks": 25}, reqs)
     for i in range(4):
         assert got_b[f"b{i}"] == got_a[f"a{i}"], f"stream {i} diverged"
 
@@ -491,32 +432,36 @@ def test_unified_wildly_ragged_bench_geometry(kv, monkeypatch):
         intermediate_size=256, num_layers=1, num_heads=8, num_kv_heads=8,
         head_dim=128))
 
-    def run(unified):
-        core = EngineCore(tiny_config(model="tiny-kh8-d128", kv_dtype=kv,
-                                      unified_step=unified))
-        early = [make_req(prompt=[10 * i + j for j in range(3)],
-                          max_tokens=14, rid=f"d{i}") for i in range(3)]
-        for r in early:
-            core.add_request(r)
-        got = {r.request_id: [] for r in early}
-        for _ in range(4):  # establish pure decode before the prefill lands
-            for rid, out in core.step().items():
-                got[rid].extend(out.token_ids)
-        core.add_request(make_req(prompt=[(7 * j) % 200 for j in range(30)],
-                                  max_tokens=8, rid="pf"))
-        got["pf"] = []
-        fin = set()
-        for _ in range(200):
-            if not core.has_work():
-                break
-            for rid, out in core.step().items():
-                got[rid].extend(out.token_ids)
-                if out.finish_reason is not None:
-                    fin.add(rid)
-        assert len(fin) == 4
-        return got
+    cfg = dict(model="tiny-kh8-d128", kv_dtype=kv)
+    early = [dict(prompt=[10 * i + j for j in range(3)], max_tokens=14,
+                  rid=f"d{i}") for i in range(3)]
+    late = dict(prompt=[(7 * j) % 200 for j in range(30)], max_tokens=8,
+                rid="pf")
 
-    assert run(True) == run(False)
+    core = EngineCore(tiny_config(**cfg))
+    for kw in early:
+        core.add_request(make_req(**kw))
+    got = {kw["rid"]: [] for kw in early}
+    for _ in range(4):  # establish pure decode before the prefill lands
+        for rid, out in core.step().items():
+            got[rid].extend(out.token_ids)
+    core.add_request(make_req(**late))
+    got["pf"] = []
+    fin = set()
+    for _ in range(200):
+        if not core.has_work():
+            break
+        for rid, out in core.step().items():
+            got[rid].extend(out.token_ids)
+            if out.finish_reason is not None:
+                fin.add(rid)
+    assert len(fin) == 4
+
+    solo = EngineCore(tiny_config(**cfg))
+    want = {}
+    for kw in [*early, late]:
+        want.update(run_to_completion(solo, [make_req(**kw)])[0])
+    assert got == want
 
 
 def test_auto_prefill_chunk_engine_init():

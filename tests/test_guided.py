@@ -301,27 +301,26 @@ def test_guided_vocab_sentencepiece_byte_fallback():
 
 
 @pytest.mark.slow
-def test_guided_unified_matches_legacy():
-    """Guided rows join the unified mixed launch via per-row masks: the
-    guided stream AND its plain sibling (whose multi-chunk prompt forces
-    real mixed steps while the guided row decodes) match --no-unified-step
-    exactly."""
+def test_guided_in_a_mixed_step_matches_solo():
+    """Guided rows join the mixed launch via per-row masks: the guided
+    stream AND its plain sibling (whose multi-chunk prompt forces real
+    mixed steps while the guided row decodes) are the streams the two
+    requests emit one at a time, where no step mixes phases."""
     schema = {"type": "object",
               "properties": {"name": {"type": "string",
                                       "enum": ["ada", "bob"]},
                              "ok": {"type": "boolean"}},
               "required": ["name", "ok"]}
 
-    def run(unified):
-        core = EngineCore(tiny_config(unified_step=unified))
-        out, fin = run_to_completion(core, [
-            guided_req(schema, max_tokens=64),
-            make_req(prompt=[(3 * j) % 90 for j in range(40)],
-                     max_tokens=10, rid="p"),
-        ])
-        assert fin == {"g", "p"}
-        return out
+    def reqs():
+        return [guided_req(schema, max_tokens=64),
+                make_req(prompt=[(3 * j) % 90 for j in range(40)],
+                         max_tokens=10, rid="p")]
 
-    uni = run(True)
-    assert uni == run(False)
+    uni, fin = run_to_completion(EngineCore(tiny_config()), reqs())
+    assert fin == {"g", "p"}
+    solo_core, solo = EngineCore(tiny_config()), {}
+    for r in reqs():
+        solo.update(run_to_completion(solo_core, [r])[0])
+    assert uni == solo
     validate_json_output(decode_out(uni["g"]), schema)

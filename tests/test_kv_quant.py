@@ -141,10 +141,9 @@ def _greedy(kv_dtype, **kw):
 
 @pytest.mark.parametrize("variant", [
     {},                                    # plain decode
-    {"decode_window": 4},                  # fused windowed decode
     {"spec_ngram": 2, "spec_k": 4},        # verify path
     {"attn_impl": "pallas_interpret"},     # kernel path (interpreted)
-], ids=["dense", "windowed", "verify", "pallas_interpret"])
+], ids=["dense", "verify", "pallas_interpret"])
 def test_int8_engine_parity(variant):
     """int8 vs model-precision engines on the same greedy request: tokens
     may legitimately diverge once logits get close, but each variant must be
@@ -329,27 +328,62 @@ def test_int4_scatter_append_merges_scales():
     assert np.abs(got[2:4] - 4.0).max() < 0.3
 
 
-@pytest.mark.parametrize("variant", [
-    {},                                    # plain decode
-    {"decode_window": 4},                  # fused windowed decode
-    {"spec_ngram": 2, "spec_k": 4},        # verify path
-    {"attn_impl": "pallas_interpret"},     # kernel path (interpreted)
-    {"attn_impl": "pallas_interpret", "attn_num_splits": 2},  # split-K
-], ids=["dense", "windowed", "verify", "pallas_interpret", "split_k"])
-def test_int4_engine_parity(variant):
-    """int4 vs model-precision engines, same contract as the int8 twin:
-    internal determinism plus an agreeing initial prefix."""
-    toks_f = _greedy("bfloat16", **variant)
+def _logits_over_cache(kv_dtype, attn_impl, attn_num_splits, verify):
+    """Float32 logits of one forward that reads a ``kv_dtype`` cache: the
+    prompt is prefilled into it, then a fixed continuation (one decode
+    token, or a five-token verify chunk with logits at every position)
+    attends to it. No token is sampled in between, so the int4 and the
+    model-precision runs see the same inputs."""
+    from dynamo_tpu.models import llama
+
+    cfg = resolve_model_config("tiny-llama")
+    params = llama.init_params(cfg, jax.random.key(0))
+    spec = KVCacheSpec.for_model(cfg, 16, 4, kv_dtype=kv_dtype)
+    ck, cv = allocate_cache(spec, None)
+    bt = jnp.arange(1, 9, dtype=jnp.int32)[None, :]
+    kw = dict(attn_impl=attn_impl, attn_num_splits=attn_num_splits)
+    n = len(PROMPT)
+    _, ck, cv = llama.forward(
+        params, cfg, jnp.asarray([PROMPT], jnp.int32),
+        jnp.zeros((1,), jnp.int32), jnp.asarray([n], jnp.int32), bt, ck, cv,
+        **kw)
+    nxt = [7, 8, 9, 10, 11] if verify else [7]
+    hidden, _, _ = llama.forward(
+        params, cfg, jnp.asarray([nxt], jnp.int32),
+        jnp.asarray([n], jnp.int32), jnp.asarray([len(nxt)], jnp.int32),
+        bt, ck, cv, return_all_hidden=verify, **kw)
+    return np.asarray(llama.logits_from_hidden(params, cfg, hidden),
+                      np.float32)
+
+
+#: rms(logits over an int4 cache - logits over a model-precision cache) /
+#: std(logits). Read on this model: 0.17-0.21 in every variant, 8.5-20x the
+#: int8 cache's 0.010-0.021, which is what 7 levels against 127 give. The
+#: top two logits lie 0.08 apart, under that noise: that is why the greedy
+#: streams of the two engines part at the first token, and why no stream is
+#: compared here.
+INT4_LOGIT_RMS_TOL = 0.3
+
+
+@pytest.mark.parametrize("variant, fwd", [
+    ({}, ("dense", 0, False)),                                # plain decode
+    ({"spec_ngram": 2, "spec_k": 4}, ("dense", 0, True)),     # verify path
+    ({"attn_impl": "pallas_interpret"},                       # kernel path
+     ("pallas_interpret", 0, False)),
+    ({"attn_impl": "pallas_interpret", "attn_num_splits": 2},  # split-K
+     ("pallas_interpret", 2, False)),
+], ids=["dense", "verify", "pallas_interpret", "split_k"])
+def test_int4_engine_parity(variant, fwd):
+    """The int4 engine is deterministic, and one forward over an int4 cache
+    gives the model-precision logits within ``INT4_LOGIT_RMS_TOL`` — and
+    farther from them than an int8 cache does, as a 4-bit cache must."""
     toks_q = _greedy("int4", **variant)
-    assert toks_f == _greedy("bfloat16", **variant)  # determinism
-    assert toks_q == _greedy("int4", **variant)
-    assert len(toks_f) == len(toks_q) == 6
-    common = 0
-    for a, b in zip(toks_f, toks_q):
-        if a != b:
-            break
-        common += 1
-    assert common >= 1, (toks_f, toks_q)
+    assert toks_q == _greedy("int4", **variant)  # determinism
+    assert len(toks_q) == 6
+    ref = _logits_over_cache("bfloat16", *fwd)
+    err = {kv: float(np.sqrt(np.mean((_logits_over_cache(kv, *fwd) - ref) ** 2))
+                     / ref.std()) for kv in ("int8", "int4")}
+    assert err["int8"] < err["int4"] < INT4_LOGIT_RMS_TOL, err
 
 
 def test_int4_offload_onboard_determinism():

@@ -201,5 +201,3 @@ def test_step_program_name_carries_the_bucket(runner, t, greedy, expect):
 def test_verify_and_embed_programs_are_named(runner):
     assert runner._build_verify_fn(4, 4, 8).__name__ == "step_verify_b4_t4_n8"
     assert runner._build_embed_fn(2, 16).__name__ == "embed_b2_t16"
-    assert (runner._build_window_fn(4, 8, 4, fast_greedy=True).__name__
-            == "step_window_b4_n8_w4")

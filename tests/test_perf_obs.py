@@ -1,5 +1,5 @@
 """Performance observability: analytic cost model, step profiler, perf
-metrics family, perf_report regression diff, and the bench JSON contract.
+metrics family.
 
 The cost-model tests pin the conventions documented in
 obs/costmodel.py (matmul-only FLOPs, block-rounded attention, int8 KV
@@ -9,20 +9,17 @@ model or the convention fails loudly.
 
 from __future__ import annotations
 
-import json
-
 import pytest
 
-import bench
 from dynamo_tpu.models.config import MODEL_PRESETS, resolve_model_config
 from dynamo_tpu.obs import costmodel as cm
+from dynamo_tpu.obs.compile_ledger import BucketSig
 from dynamo_tpu.obs.profiler import (
     PerfMetrics,
     StepPerfProfiler,
     phase,
 )
 from dynamo_tpu.utils.metrics import MetricsRegistry
-from tools.perf_report import diff_benches, kernel_rows, load_bench
 
 from tests.test_engine import make_req, run_to_completion, tiny_config
 
@@ -344,7 +341,7 @@ def test_profiler_disabled_is_inert(monkeypatch):
     assert prof.enabled is False
     monkeypatch.setattr(cm, "model_step_cost",
                         _raise_if_called, raising=True)
-    assert prof.measure([("decode", [(0, 5, 1)], [0], _FakeArr((1,)), None)],
+    assert prof.measure([(_DECODE, [(0, 5, 1)], [0], _FakeArr((1,)), None)],
                         0.01) == {}
     del cfg
 
@@ -358,6 +355,9 @@ def test_profiler_disabled_is_inert(monkeypatch):
 
 def tiny_config_model():
     return resolve_model_config("tiny-llama")
+
+
+_DECODE = BucketSig("decode", 8, 1, 4, True, "bfloat16")
 
 
 class _FakeArr:
@@ -375,8 +375,9 @@ def test_profiler_charges_decode_and_prefill_rows():
     prof = StepPerfProfiler(tiny_config_model(), ecfg, device_kind="cpu",
                             enabled=True)
     batches = [
-        ("prefill", [(0, 0, 8)], [0], _FakeArr((1,)), None),
-        ("decode", [(1, 8, 1), (2, 12, 1)], [0, 1], _FakeArr((2,)), None),
+        (BucketSig("mixed", 8, 16, 4, True, "bfloat16"), [(0, 0, 8)], [0],
+         _FakeArr((1,)), None),
+        (_DECODE, [(1, 8, 1), (2, 12, 1)], [0, 1], _FakeArr((2,)), None),
     ]
     fields = prof.measure(batches, wall_s=0.05)
     assert fields["prefill_tokens"] == 8
@@ -397,89 +398,6 @@ def test_perf_metrics_family_exposed():
         assert name in text
 
 
-# ---------------------------------------------------------------------------
-# perf_report: BENCH parsing + regression diff
-# ---------------------------------------------------------------------------
-
-def _wrap(n, rc, parsed):
-    return {"n": n, "cmd": "python bench.py", "rc": rc, "tail": "",
-            "parsed": parsed}
-
-
-def test_load_bench_driver_wrapper_and_raw(tmp_path):
-    ok = tmp_path / "BENCH_r01.json"
-    ok.write_text(json.dumps(_wrap(1, 0, {
-        "metric": "m", "value": 123.4, "vs_baseline": 0.1})))
-    e = load_bench(ok)
-    assert e["run"] == 1 and e["value"] == 123.4 and e["error"] is None
-
-    failed = tmp_path / "BENCH_r02.json"
-    failed.write_text(json.dumps(_wrap(2, 1, None)))
-    e = load_bench(failed)
-    assert e["value"] is None and e["error"] == "no JSON parsed"
-
-    raw = tmp_path / "BENCH_r03.json"
-    raw.write_text(json.dumps({"metric": "m", "value": 99.0,
-                               "fallback": "cpu_probe"}))
-    e = load_bench(raw)
-    assert e["run"] == 3 and e["fallback"] == "cpu_probe"
-
-
-def test_diff_flags_regressions_within_comparable_class(tmp_path):
-    files = [
-        _wrap(1, 0, {"metric": "m", "value": 100.0, "fallback": None}),
-        _wrap(2, 0, {"metric": "m", "value": 95.0, "fallback": None}),
-        _wrap(3, 0, {"metric": "m", "value": 50.0, "fallback": None}),
-        # cpu_probe numbers never compare against device numbers:
-        _wrap(4, 0, {"metric": "m", "value": 8.0, "fallback": "cpu_probe"}),
-        _wrap(5, 1, {"metric": "m", "value": None, "error": "boom",
-                     "fallback": None}),
-    ]
-    paths = []
-    for i, w in enumerate(files, 1):
-        p = tmp_path / f"BENCH_r{i:02d}.json"
-        p.write_text(json.dumps(w))
-        paths.append(p)
-    entries = diff_benches([load_bench(p) for p in paths])
-    by_run = {e["run"]: e for e in entries}
-    assert by_run[1]["status"] == "ok"
-    assert by_run[2]["status"] == "ok"          # within 10% of best
-    assert by_run[3]["status"] == "regression"  # 50 << 100
-    assert by_run[3]["regressed_from"] == 100.0
-    assert by_run[4]["status"] == "fallback"    # own class, no comparison
-    assert by_run[5]["status"] == "failed"
-
-
-def test_perf_report_check_smoke():
-    from tools.perf_report import main as perf_main
-    assert perf_main(["--check"]) == 0
-
-
-def test_kernel_rows_cover_every_kv_mode():
-    cfg = MODEL_PRESETS["llama-3-8b-lite"]
-    rows = kernel_rows(cfg, cm.hw_spec_for("tpu v5 lite"), batch=32,
-                       context=160, block_size=16, quantization="none",
-                       measured_step_s=32 / 440.2)
-    pa = {r["kv_dtype"]: r for r in rows if r["kernel"] == "paged_attention"}
-    assert set(pa) == set(cm.KV_DTYPES)
-    for r in pa.values():
-        assert r["achieved"] and 0 < r["mfu"] < 1 and 0 < r["bw_util"] < 1
-
-
-def test_kernel_rows_split_variant_when_auto_engages():
-    """At a small-batch long-context geometry the auto policy splits, and
-    the scoreboard gains a split-K attention row per kv mode whose bytes
-    exceed the sequential row's (the combine overhead is visible)."""
-    cfg = MODEL_PRESETS["llama-3-8b-lite"]
-    rows = kernel_rows(cfg, cm.hw_spec_for("tpu v5 lite"), batch=2,
-                       context=4096, block_size=16, quantization="none")
-    by = {(r["kernel"], r["kv_dtype"]): r for r in rows}
-    split_rows = [k for k in by if k[0].startswith("paged_attention split=")]
-    assert {kv for _, kv in split_rows} == set(cm.KV_DTYPES)
-    for (kernel, kv) in split_rows:
-        assert by[(kernel, kv)]["hbm_bytes"] > by[("paged_attention", kv)]["hbm_bytes"]
-
-
 def test_perf_tok_s_gauge_labeled_by_kv_dtype():
     """The tokens/s gauge carries kind AND kv_dtype labels (the contract
     declared in tools/lint_metrics.py PERF_METRIC_LABELS)."""
@@ -489,7 +407,7 @@ def test_perf_tok_s_gauge_labeled_by_kv_dtype():
     install_perf_metrics(reg)
     prof = StepPerfProfiler(tiny_config_model(), tiny_config(kv_dtype="int4"),
                             device_kind="cpu", enabled=True)
-    prof.measure([("decode", [(0, 8, 1)], [0], _FakeArr((1,)), None)], 0.01)
+    prof.measure([(_DECODE, [(0, 8, 1)], [0], _FakeArr((1,)), None)], 0.01)
     text = reg.expose()
     assert 'kv_dtype="int4"' in text and 'kind="decode"' in text
 
@@ -514,91 +432,6 @@ def test_lint_flags_perf_label_drift(tmp_path):
     problems = lint_tree(tmp_path)
     assert any("PERF_METRIC_LABELS" in p and "kv_dtype" in p
                for p in problems), "\n".join(problems)
-
-
-# ---------------------------------------------------------------------------
-# bench.py JSON contract
-# ---------------------------------------------------------------------------
-
-def test_bench_fail_json_contract(capsys):
-    """A failure line always carries error + explicit fallback:null, value
-    null, and (when the cost model resolves) the predicted device perf."""
-    with pytest.raises(SystemExit) as exc:
-        bench.fail("unit_test", "synthetic failure", probe_log="tail text")
-    assert exc.value.code == 1
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["value"] is None
-    assert out["fallback"] is None
-    assert out["error"].startswith("unit_test:")
-    assert out["probe_log"] == "tail text"
-    assert out["metric"] == bench.METRIC
-    pred = out.get("predicted")
-    assert pred and pred["source"] == "costmodel" and pred["tok_s"] > 0
-
-
-def test_bench_predicted_perf_targets_device():
-    pred = bench._predicted_perf()
-    assert pred is not None
-    assert pred["device"] == "tpu-v5e"
-    assert pred["bound"] in ("bandwidth", "compute")
-
-
-def test_bench_longctx_metric_sweeps_kv_dtype_and_split():
-    """The long-context metric predicts bs16/ctx8k decode for every
-    kv_dtype x {split_off, split_on}; quantized KV beats bf16 in this
-    bandwidth-bound regime."""
-    lc = bench._longctx_metric()
-    assert lc["metric"] == "decode_throughput_llama_3_8b_lite_bs16_ctx8k"
-    assert lc["metric"] == bench.LONGCTX_METRIC
-    assert lc["source"] == "costmodel" and lc["unit"] == "tok/s/chip"
-    assert lc["batch"] == 16 and lc["context"] == 8192
-    assert lc["split_on_n"] > 1
-    pred = lc["predicted"]
-    want = {f"{kv}/{arm}" for kv in cm.KV_DTYPES
-            for arm in ("split_off", "split_on")}
-    assert set(pred) == want and len(pred) == 2 * len(cm.KV_DTYPES)
-    assert all(v > 0 for v in pred.values())
-    assert pred["int4/split_off"] > pred["int8/split_off"] > pred["bfloat16/split_off"]
-
-
-def test_bench_fail_line_carries_longctx(capsys):
-    """Even a failure line ships the long-context sweep — the metric is
-    always-green by contract."""
-    with pytest.raises(SystemExit):
-        bench.fail("unit_test", "synthetic failure")
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    lc = out.get("longctx")
-    assert lc and lc["metric"] == bench.LONGCTX_METRIC
-    assert len(lc["predicted"]) == 2 * len(cm.KV_DTYPES)
-
-
-def test_bench_session_metric_analytic_arm():
-    """The analytic session entry mirrors what a measured turn-2 run
-    reports: avoided tokens are the block-rounded turn-1 KV commit (the
-    final sampled token's KV is never written), priced by the retention
-    cost model."""
-    s = bench._session_metric()
-    assert s["metric"] == "session_turn2_prefill_avoided_frac"
-    assert s["metric"] == bench.SESSION_METRIC
-    assert s["source"] == "costmodel" and s["unit"] == "frac"
-    turn1 = bench.SESSION_T1_PROMPT + bench.SESSION_T1_DECODE
-    assert s["turn1_tokens"] == turn1
-    assert s["avoided_tokens"] == ((turn1 - 1) // 16) * 16
-    assert s["turn2_prompt_tokens"] == turn1 + bench.SESSION_SUFFIX
-    assert s["value"] == round(s["avoided_tokens"] / s["turn2_prompt_tokens"], 4)
-    assert 0.0 < s["value"] < 1.0
-    assert s["retained_kv_mib"] > 0 and s["recompute_seconds_saved"] > 0
-
-
-def test_bench_fail_line_carries_session(capsys):
-    """The session metric is always-green by the same contract as
-    longctx: even a failure line ships the analytic entry."""
-    with pytest.raises(SystemExit):
-        bench.fail("unit_test", "synthetic failure")
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    s = out.get("session")
-    assert s and s["metric"] == bench.SESSION_METRIC
-    assert s["source"] == "costmodel" and s["avoided_tokens"] > 0
 
 
 def test_costmodel_ring_vs_chunked_crossover_and_break_even():
@@ -637,55 +470,3 @@ def test_costmodel_session_retention_cost_scales_with_kv_dtype():
     assert bf16.retained_bytes(tokens) == bf16.bytes_per_token * tokens
     assert bf16.recompute_seconds(tokens) == pytest.approx(
         bf16.seconds_per_token * tokens)
-
-
-def test_bench_mixed_step_metric_analytic_arm():
-    """The mixed-step entry prices the unified one-launch ITL vs the legacy
-    two-launch sum at the longctx geometry — the unified step must predict
-    strictly cheaper (one roofline max vs a sum) — and reports the SLO-driven
-    per-QoS auto chunk, all from the pure cost model."""
-    m = bench._mixed_step_metric()
-    assert m["metric"] == "mixed_step_itl_ms_llama_3_8b_lite_bs16_ctx8k"
-    assert m["metric"] == bench.MIXED_METRIC
-    assert m["source"] == "costmodel" and m["unit"] == "ms/step"
-    assert m["decode_rows"] == 16 and m["context"] == 8192
-    assert m["chunk"] == bench.MIXED_CHUNK
-    assert 0 < m["unified_itl_ms"] < m["legacy_itl_ms"]
-    assert 0 < m["unified_over_legacy"] < 1
-    auto = m["auto_chunk_slo50ms"]
-    assert set(auto) == set(cm.QOS_ITL_SLO_SCALE)
-    assert auto["batch"] >= auto["standard"] >= auto["interactive"] >= 16
-
-
-def test_bench_fail_line_carries_mixed_step(capsys):
-    """Always-green by the longctx contract: even a failure line ships the
-    analytic mixed-step entry (agreement null — no engine ran here... unless
-    a sibling test's engine left mixed steps in the global ledger, in which
-    case a ratio is legitimately present)."""
-    with pytest.raises(SystemExit):
-        bench.fail("unit_test", "synthetic failure")
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    m = out.get("mixed_step")
-    assert m and m["metric"] == bench.MIXED_METRIC
-    assert m["unified_itl_ms"] < m["legacy_itl_ms"]
-
-
-def test_bench_mixed_step_agreement_from_recorded_steps():
-    """With mixed steps in the in-process scheduling ledger (jax is up in
-    the test process), the entry gains the measured-vs-predicted agreement
-    ratio — median of measured wall over the cost model's prediction for
-    each recorded geometry."""
-    from dynamo_tpu.obs.sched_ledger import SchedStepRecord, get_sched_ledger
-
-    led = get_sched_ledger()
-    rec = SchedStepRecord(ts=0.0, wall_s=0.25, kinds=("mixed",),
-                          prefill_rows=1, decode_rows=4,
-                          live_tokens=4 + 256, sched_tokens=8 * 512)
-    led.steps.append(rec)
-    try:
-        m = bench._mixed_step_metric()
-    finally:
-        led.steps.remove(rec)
-    assert m["agreement"] is not None and m["agreement"] > 0
-    assert m["agreement_steps"] >= 1
-    assert m["agreement_device"]

@@ -29,6 +29,7 @@ from dynamo_tpu.obs.sched_ledger import (
     sched_enabled,
     step_geometry,
 )
+from dynamo_tpu.obs.compile_ledger import BucketSig, sig_for_rows
 from dynamo_tpu.utils.config import EngineConfig
 from dynamo_tpu.utils.logging import TraceContext
 from dynamo_tpu.utils.metrics import (
@@ -108,7 +109,7 @@ def test_token_ratio_goodput_and_snapshot():
 
 
 # ---------------------------------------------------------------------------
-# step_geometry — pinned against hand-computed dispatch bucket math
+# step_geometry — prices the dispatched signature, hand-computed here
 # ---------------------------------------------------------------------------
 
 def tiny_ec(**kw) -> EngineConfig:
@@ -138,7 +139,10 @@ def test_step_geometry_decode_hand_computed():
     mc = resolve_model_config("tiny-llama")
     rows = [(None, 0, 1), (None, 16, 1), (None, 30, 1)]
     toks = np.zeros(3, dtype=np.int32)
-    g = step_geometry(mc, ec, [("decode", rows, [True] * 3, toks, None)])
+    sig = sig_for_rows("decode", 3, 1, 2, ec)
+    assert sig == BucketSig("decode", 4, 1, 4, True, "bfloat16")
+    g = step_geometry(mc, ec, [(sig, rows, [True] * 3, toks, None)],
+                      dec_rows=3)
     assert g["kinds"] == ("decode",)
     assert g["prefill_rows"] == 0 and g["decode_rows"] == 3
     assert g["live_tokens"] == 3 and g["sched_tokens"] == 4
@@ -157,34 +161,37 @@ def test_step_geometry_decode_hand_computed():
     assert 0.0 < rec.goodput < 1.0
 
 
-def test_step_geometry_prefill_hand_computed():
+def test_step_geometry_chunk_hand_computed():
     """One 20-token chunk: live prices 20 ragged tokens against 2 real
-    blocks; the padded program is b=1, t=pow2(20,16,32)=32, nblk=4."""
+    blocks; the padded program is the mixed one at b=2 (bucket of 1 in
+    (2,4)), t=pow2(20,16,32)=32, nblk=4, whose dense layers compute
+    N = min(2*32, 32+2) = 34 tokens and whose attention sees 2 x 32."""
     from dynamo_tpu.models.config import resolve_model_config
 
     ec = tiny_ec()
     mc = resolve_model_config("tiny-llama")
     rows = [(None, 0, 20)]
-    toks = np.zeros((1, 20), dtype=np.int32)
-    g = step_geometry(mc, ec, [("prefill", rows, [True], toks, None)])
-    assert g["kinds"] == ("prefill",)
+    toks = np.zeros(2, dtype=np.int32)
+    sig = sig_for_rows("mixed", 1, 20, 2, ec)
+    assert sig == BucketSig("mixed", 2, 32, 4, True, "bfloat16")
+    g = step_geometry(mc, ec, [(sig, rows, [True], toks, None)])
+    assert g["kinds"] == ("mixed",)
     assert g["prefill_rows"] == 1 and g["decode_rows"] == 0
-    assert g["live_tokens"] == 20 and g["sched_tokens"] == 32
+    assert g["live_tokens"] == 20 and g["sched_tokens"] == 34
+    assert g["rect_tokens"] == 64
     live = _cost(mc, ec, tokens=20, logit_rows=1,
                  attn_q_ctx=20 * 2 * 16, kv_blocks=2)
-    sched = _cost(mc, ec, tokens=32, logit_rows=1,
-                  attn_q_ctx=1 * 32 * 4 * 16, kv_blocks=4)
+    sched = _cost(mc, ec, tokens=34, logit_rows=2,
+                  attn_q_ctx=2 * 32 * 4 * 16, kv_blocks=8)
     assert g["live_flops"] == pytest.approx(live.flops)
     assert g["sched_flops"] == pytest.approx(sched.flops)
-    # a mixed step sums both batches' aggregates into the kinds tuple
+    # a decode row beside the chunk: the same program, one more live token
     mixed = step_geometry(mc, ec, [
-        ("decode", [(None, 0, 1)], [True], np.zeros(1, dtype=np.int32),
-         None),
-        ("prefill", rows, [True], toks, None),
-    ])
-    assert mixed["kinds"] == ("decode", "prefill")
+        (sig_for_rows("mixed", 2, 20, 2, ec), [(None, 0, 1)] + rows,
+         [True, True], toks, None)], dec_rows=1)
+    assert mixed["kinds"] == ("mixed",)
     assert mixed["prefill_rows"] == 1 and mixed["decode_rows"] == 1
-    assert mixed["live_tokens"] == 21
+    assert mixed["live_tokens"] == 21 and mixed["sched_tokens"] == 34
     assert mixed["live_flops"] > g["live_flops"]
 
 
@@ -453,7 +460,7 @@ def test_mocker_sched_parity(clean_ledger):
     assert sched["sched_tokens_total"] >= sched["live_tokens_total"]
     kinds = {k for r in led.steps for k in r.kinds}
     assert {"mixed", "decode"} <= kinds
-    assert "prefill" not in kinds  # unified default: no serialized prefill
+    assert kinds <= {"mixed", "decode"}
 
 
 def test_mocker_disabled_omits_stats_block(clean_ledger, monkeypatch):
@@ -594,38 +601,32 @@ def test_fleet_decode_stall_sli():
     assert out["decode_stall"]["total"] == 10.0
 
 
-async def test_mocker_unified_lowers_hol_stall(clean_ledger):
-    """Acceptance mirror, device-free: the SAME victim/culprit traffic
-    attributes strictly less HOL stall under unified mixed steps — one
-    co-scheduled launch priced at the phase roofline max, victims charged
-    only the chunk's marginal share — than under the legacy path, where the
-    serialized prefill's full wall lands on every co-resident stream."""
+async def test_mocker_hol_stall_is_the_chunks_marginal_share(clean_ledger):
+    """Acceptance mirror, device-free: a chunk co-scheduled with a decoding
+    stream is one launch priced at the phase roofline max, and its victim
+    is charged the chunk's marginal share of that step — more than nothing,
+    less than the step's whole wall."""
     from dynamo_tpu.mocker.engine import MockEngine
 
     led = clean_ledger
+    eng = MockEngine(_mock_args(speedup_ratio=100.0))
+    first = asyncio.Event()
 
-    async def run(unified):
-        led.reset()
-        eng = MockEngine(_mock_args(unified_step=unified,
-                                    speedup_ratio=100.0))
-        first = asyncio.Event()
+    async def victim():
+        async for _ in eng.generate(_req(range(5, 29), max_tokens=60,
+                                         rid="victim")):
+            first.set()
 
-        async def victim():
-            async for _ in eng.generate(_req(range(5, 29), max_tokens=60,
-                                             rid="victim")):
-                first.set()
-
-        vt = asyncio.create_task(victim())
-        await asyncio.wait_for(first.wait(), 10)
-        # victim is decoding: the culprit's 32-token prefill must share
-        # (unified) or preempt (legacy) its next iterations
-        await _gen_mock(eng, _req(range(200, 232), max_tokens=2,
-                                  rid="culprit"))
-        await asyncio.wait_for(vt, 30)
-        return led.snapshot()
-
-    uni = await run(True)
-    legacy = await run(False)
-    assert legacy["hol_stall_seconds_total"] > 0
-    assert (uni["hol_stall_seconds_total"]
-            < legacy["hol_stall_seconds_total"])
+    vt = asyncio.create_task(victim())
+    await asyncio.wait_for(first.wait(), 10)
+    # victim is decoding: the culprit's 32-token prefill shares its next
+    # iteration
+    await _gen_mock(eng, _req(range(200, 232), max_tokens=2, rid="culprit"))
+    await asyncio.wait_for(vt, 30)
+    stalled = [r for r in led.steps if r.hol_victims]
+    assert stalled and all(r.kinds == ("mixed",) for r in stalled)
+    for r in stalled:
+        assert r.hol_culprit == "culprit" and r.hol_victims == 1
+        assert 0.0 < r.hol_stall_s < r.wall_s
+    assert led.hol_stall_seconds_total == pytest.approx(
+        sum(r.interference_row_s for r in stalled))

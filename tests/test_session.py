@@ -335,12 +335,12 @@ async def test_mock_engine_session_retention():
 
 
 @pytest.mark.slow
-def test_session_turn2_unified_matches_legacy():
+def test_session_turn2_in_a_mixed_step_matches_solo():
     """Session-retained turn 2 (suffix-only prefill riding a mixed step next
-    to a live decode row) emits the same streams under the unified one-launch
-    path as --no-unified-step."""
-    def run(unified):
-        core = _make_core(unified_step=unified, max_batch_size=4)
+    to a live decode row) emits the streams it emits when the sibling has
+    finished before it arrives, so that no step mixes phases."""
+    def run(together):
+        core = _make_core(max_batch_size=4)
         p1 = list(range(1, 17))
         out1 = _generate(core, p1, "s1")
         # A sibling stream decodes while turn 2's suffix prefill lands.
@@ -350,8 +350,11 @@ def test_session_turn2_unified_matches_legacy():
             stop_conditions=StopConditions(max_tokens=16, ignore_eos=True))
         sib.request_id = "sib"
         core.add_request(sib)
-        core.step()
-        core.step()
+        got = {"sib": [], "t2": []}
+        for _ in range(2 if together else 100):
+            for rid, o in core.step().items():
+                got[rid].extend(o.token_ids)
+        assert core.has_work() == together
         p2 = p1 + out1 + [3, 1, 4, 1, 5, 9, 2, 6]
         t2 = PreprocessedRequest(
             token_ids=p2, annotations={SESSION_KEY: "s1"},
@@ -359,7 +362,6 @@ def test_session_turn2_unified_matches_legacy():
             stop_conditions=StopConditions(max_tokens=4, ignore_eos=True))
         t2.request_id = "t2"
         core.add_request(t2)
-        got = {"sib": [], "t2": []}
         while core.has_work():
             for rid, o in core.step().items():
                 got[rid].extend(o.token_ids)

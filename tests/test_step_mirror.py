@@ -5,7 +5,7 @@ reachable_buckets`` enumerates from the program's device-free mirror
 (``obs/compile_ledger.py sig_for_rows``). Held here, for each cell of
 ``BENCHMARK.json``: a replay of the cell's own trace through the real
 scheduler, cut into programs as ``EngineCore`` cuts it (``pack_rows``) and
-bucketed by ``ModelRunner.bucket_of`` (dispatch's own geometry), mints no
+bucketed by ``ModelRunner.bucket_of`` (what dispatch() calls), mints no
 ``(kind, b, t, nblk, N)`` outside that set; the set is no larger than it
 was; no program is sent more live tokens than its bucket N; and the
 scheduling ledger counts N a program. Nothing here runs a model: no number
@@ -82,18 +82,16 @@ def _replay(cell, ec, order: int, clock: tuple[float, float]):
         if plan.empty:
             now = reqs[nxt][0]
             continue
-        # EngineCore._dispatch_plan, unified: decode rows, then the chunks.
+        # EngineCore._dispatch_plan: decode rows, then the chunks.
         rows = ([(s, s.num_computed, 1) for s in plan.decode]
                 + [(w.seq, w.start, w.length) for w in plan.prefill])
-        mixed = bool(plan.prefill)
         batches, lo = [], 0
-        for k in pack_rows([r[2] for r in rows], ec, mixed):
+        for k in pack_rows([r[2] for r in rows], ec):
             run, lo = rows[lo:lo + k], lo + k
-            kind, b, t, nblk, _ = ModelRunner.bucket_of(runner, run, 1, mixed)
-            programs.append(((kind, b, t, nblk, token_bucket(kind, b, t)),
+            sig = ModelRunner.bucket_of(runner, run)
+            programs.append(((sig.kind, sig.b, sig.t, sig.nblk, sig.n),
                              sum(r[2] for r in run)))
-            batches.append(("mixed" if mixed else "decode", run,
-                            [True] * k, None, None))
+            batches.append((sig, run, [True] * k, None, None))
         steps.append((batches, len(plan.decode)))
         for seq, start, length in rows:
             samples = start + length >= seq.prefill_target()
@@ -153,8 +151,9 @@ def test_replayed_trace_reaches_only_warmed_programs(cells, name, order,
         assert sum(len(batches) > 1 for batches, _ in steps) >= 5
     model_cfg = resolve_model_config(str(cell.config_dir))
     for batches, dec_rows in steps[::7]:
-        g = step_geometry(model_cfg, ec, batches, mixed_dec_rows=dec_rows)
+        g = step_geometry(model_cfg, ec, batches, dec_rows=dec_rows)
         runs = [_bucket_of_batch(ec, batch) for batch in batches]
+        assert runs == [sig for sig, *_ in batches]
         assert g["sched_tokens"] == sum(s.n for s in runs)
         assert g["rect_tokens"] == sum(s.b * s.t for s in runs)
         assert g["live_tokens"] == sum(
@@ -163,33 +162,33 @@ def test_replayed_trace_reaches_only_warmed_programs(cells, name, order,
 
 
 def _bucket_of_batch(ec, batch):
-    kind, rows, *_ = batch
+    _, rows, *_ = batch
     need = max(-(-(start + length) // ec.block_size)
                for _, start, length in rows)
-    return sig_for_rows(kind, len(rows), max(r[2] for r in rows), need, ec)
+    return sig_for_rows("mixed", len(rows), max(r[2] for r in rows), need, ec)
 
 
-@pytest.mark.parametrize("lengths, mixed, want", [
-    ([1, 1, 1], True, [3]),                      # a decode batch is one run
-    ([1, 1, 512], True, [3]),                    # one chunk and its decoders
-    ([1, 512, 512], True, [2, 1]),               # two full chunks: two programs
-    ([1, 300, 100, 90], True, [4]),              # short chunks share one
-    ([1] * 7 + [512, 30], True, [8, 1]),         # the ninth row would not fit
-    ([512] * 3, False, [1, 1, 1]),               # the legacy prefill batch too
-    ([9, 9, 2], False, [3]),
-    ([9, 9, 3], False, [2, 1]),
+@pytest.mark.parametrize("lengths, want", [
+    ([1, 1, 1], [3]),                      # a decode batch is one run
+    ([1, 1, 512], [3]),                    # one chunk and its decoders
+    ([1, 512, 512], [2, 1]),               # two full chunks: two programs
+    ([1, 300, 100, 90], [4]),              # short chunks share one
+    ([1] * 7 + [512, 30], [8, 1]),         # the ninth row would not fit
+    ([512] * 3, [1, 1, 1]),                # chunks alone: b=8, t=512 holds 520
+    ([9, 9, 3], [3]),                      # b=8, t=16 holds 24: 21 fit
+    ([9, 9, 7], [2, 1]),                   # 25 do not
 ])
-def test_pack_rows_cuts_a_step_at_the_token_bucket(lengths, mixed, want):
+def test_pack_rows_cuts_a_step_at_the_token_bucket(lengths, want):
     from dynamo_tpu.utils.config import EngineConfig
 
     ec = EngineConfig(model="tiny-llama")
-    assert pack_rows(lengths, ec, mixed) == want
+    assert pack_rows(lengths, ec) == want
 
 
 @pytest.mark.parametrize("kind, b, t, want", [
     ("decode", 8, 1, 8), ("mixed", 8, 1, 8), ("mixed", 8, 512, 520),
-    ("mixed", 16, 16, 32), ("mixed", 64, 512, 576), ("prefill", 1, 512, 512),
-    ("prefill", 4, 16, 20), ("verify", 8, 4, 32), ("window", 16, 1, 16),
+    ("mixed", 16, 16, 32), ("mixed", 64, 512, 576), ("mixed", 1, 512, 512),
+    ("mixed", 4, 16, 20), ("verify", 8, 4, 32), ("embed", 2, 16, 32),
 ])
 def test_token_bucket_follows_from_the_signature(kind, b, t, want):
     assert token_bucket(kind, b, t) == want
