@@ -79,7 +79,12 @@ from dynamo_tpu.obs.profiler import (
     phase as _perf_phase,
 )
 from dynamo_tpu.obs.mem_ledger import get_mem_ledger, live_ids_of
-from dynamo_tpu.obs.sched_ledger import HolStall, get_sched_ledger, step_geometry
+from dynamo_tpu.obs.sched_ledger import (
+    HolStall,
+    get_sched_ledger,
+    kv_blocks_live,
+    step_geometry,
+)
 from dynamo_tpu.obs.tracer import get_tracer, trace_context_of
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 from dynamo_tpu.protocols.common import FinishReason, LLMEngineOutput, PreprocessedRequest
@@ -617,7 +622,6 @@ class ModelRunner:
         moe_impl = "ep" if self.engine_cfg.ep > 1 else "dense"
         mesh = self.mesh
         pp_micro = self.engine_cfg.pp_microbatches
-        attn_splits = self.engine_cfg.attn_num_splits
         # dispatch() sends no batch with more live tokens than this
         # (EngineCore cuts steps by pack_rows).
         n_tok = _step_tokens(b, t, sp_prefill)
@@ -641,7 +645,6 @@ class ModelRunner:
                                            embed_override=emb_override,
                                            embed_mask=emb_mask,
                                            pp_microbatches=pp_micro,
-                                           attn_num_splits=attn_splits,
                                            num_tokens=n_tok)
             logits = llama.logits_from_hidden(params, cfg, hidden).astype(jnp.float32)
             if masked:
@@ -734,11 +737,11 @@ class ModelRunner:
                   verify: bool = False) -> BucketSig:
         """The program that serves ``rows``, from the one place that knows
         (``sig_for_rows``, obs/compile_ledger.py)."""
-        # Block-table width from the batch's max KV coverage — NOT the max
-        # allocated table length: every query/context position this step
-        # touches is < start + length, so blocks past that are pure waste
-        # (the Pallas kernel still burns one HBM DMA per table entry per
-        # step, and the dense path gathers them).
+        # The batch's max KV coverage — NOT the max allocated table length:
+        # every query/context position this step touches is < start +
+        # length. The dense path gathers every table entry, so its table is
+        # bucketed from this; the kernel walks only what a row holds and
+        # takes one width (sig_for_rows decides).
         bsz = self.engine_cfg.block_size
         nblk_need = max(
             min(len(seq.block_ids), -(-(start + length) // bsz))
@@ -935,13 +938,12 @@ class ModelRunner:
         attn_impl = self.attn_impl
         moe_impl = "ep" if self.engine_cfg.ep > 1 else "dense"
         mesh = self.mesh
-        attn_splits = self.engine_cfg.attn_num_splits
 
         def verify(params, ck, cv, tokens, q_start, q_len, bt):
             hidden, ck, cv = llama.forward(
                 params, cfg, tokens, q_start, q_len, bt, ck, cv,
                 attn_impl=attn_impl, moe_impl=moe_impl, mesh=mesh,
-                return_all_hidden=True, attn_num_splits=attn_splits)
+                return_all_hidden=True)
             logits = llama.logits_from_hidden(params, cfg, hidden).astype(jnp.float32)
             toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # [B, t]
             lps = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
@@ -1197,10 +1199,6 @@ class EngineCore:
             raise ValueError(
                 f"unknown kv_dtype {engine_cfg.kv_dtype!r} "
                 "(supported: bfloat16 [model-precision cache], int8, int4)")
-        if engine_cfg.attn_num_splits < 0:
-            raise ValueError(
-                f"attn_num_splits must be >= 0 (0 = auto), "
-                f"got {engine_cfg.attn_num_splits}")
         if engine_cfg.warmup_mode not in WARMUP_MODES:
             raise ValueError(
                 f"unknown warmup_mode {engine_cfg.warmup_mode!r} "
@@ -1260,6 +1258,14 @@ class EngineCore:
             self.chunk_by_qos = {qos: engine_cfg.prefill_chunk
                                  for qos in cm.QOS_ITL_SLO_SCALE}
         self.sched_led.set_prefill_chunks(self.chunk_by_qos)
+        # "auto" likewise leaves nothing behind: where attention is the
+        # kernel a step's block table has ONE width (sig_for_rows reads the
+        # resolved name), so the lattice, the warm-up and dispatch() agree.
+        from dynamo_tpu.ops.paged_attention import select_attn_impl
+
+        engine_cfg = dataclasses.replace(
+            engine_cfg, attn_impl=select_attn_impl(engine_cfg.attn_impl))
+        self.engine_cfg = engine_cfg
         if mesh is None and any(v != 1 for v in engine_cfg.mesh_shape().values()):
             mesh = make_mesh(MeshConfig(dp=engine_cfg.dp, pp=engine_cfg.pp,
                                         sp=engine_cfg.sp, tp=engine_cfg.tp,
@@ -1746,7 +1752,9 @@ class EngineCore:
                 sig = pending.batches[-1][0]
                 span.set(kind=sig.kind, b=sig.b, t=sig.t, nblk=sig.nblk,
                          n=sig.n,
-                         rows=sum(len(x[1]) for x in pending.batches))
+                         rows=sum(len(x[1]) for x in pending.batches),
+                         kv_blocks_live=kv_blocks_live(
+                             pending.batches, self.engine_cfg.block_size))
         if self.sched_led.enabled:
             with loop_phase(self.loop_clock, "engine.plan"):
                 pending.sched = self._sched_context(plan)

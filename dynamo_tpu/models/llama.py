@@ -424,7 +424,7 @@ def token_layout(q_len: jax.Array, b: int, t: int, n: int) -> tuple[
 
 def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
            lay: TokenLayout, positions, slot, block_tables, q_start, kv_lens,
-           attn_impl: str = "dense", attn_num_splits: int = 0,
+           attn_impl: str = "dense",
            moe_impl: str = "dense", mesh=None, use_ring: bool = False):
     """One transformer layer over the WHOLE cache ([L,NB,BS,KH,D], or the
     stage-local part of it under pp): writes this step's K/V at
@@ -474,13 +474,12 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
                 # psum in the wo projection completes the TP contraction.
                 attn = paged_attention_sharded(
                     mesh, q, cache_k, cache_v, block_tables, q_start,
-                    kv_lens, layer=layer, num_splits=attn_num_splits,
-                    interpret=interp,
+                    kv_lens, layer=layer, interpret=interp,
                 )
             else:
                 attn = paged_attention_kernel(
                     q, cache_k, cache_v, block_tables, q_start, kv_lens,
-                    layer=layer, num_splits=attn_num_splits, interpret=interp,
+                    layer=layer, interpret=interp,
                 )
     else:
         with _perf_phase("gather"):
@@ -558,7 +557,6 @@ def forward(
     embed_override: jax.Array | None = None,  # [B, T, H] multimodal embeds
     embed_mask: jax.Array | None = None,      # [B, T] True → use override
     pp_microbatches: int = 0,                 # pp>1: schedule depth (0 = auto)
-    attn_num_splits: int = 0,                 # split-K: 0 auto, 1 off, N forced
     num_tokens: int | None = None,            # token bucket N (None = B*T)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One engine step. Returns (last_hidden [B,H], cache_k, cache_v) —
@@ -587,8 +585,7 @@ def forward(
         # Pipeline-parallel path: layer blocks sharded over "pipe".
         return forward_pp(params, cfg, token_ids, q_start, q_len, block_tables,
                           cache_k, cache_v, mesh, attn_impl=attn_impl,
-                          microbatches=pp_microbatches,
-                          attn_num_splits=attn_num_splits)
+                          microbatches=pp_microbatches)
     if attn_impl in ("pallas", "pallas_interpret") and tp > 1 and (
         cfg.num_kv_heads % tp != 0 or b % dp != 0
     ):
@@ -635,7 +632,7 @@ def forward(
         cfg, params["layers"], h, cache_k, cache_v, lay=lay,
         positions=positions, slot=slot, block_tables=block_tables,
         q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
-        attn_num_splits=attn_num_splits, moe_impl=moe_impl, mesh=mesh,
+        moe_impl=moe_impl, mesh=mesh,
         use_ring=use_ring)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
 
@@ -665,7 +662,6 @@ def forward_pp(
     mesh,
     attn_impl: str = "dense",
     microbatches: int = 0,
-    attn_num_splits: int = 0,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Pipeline-parallel forward: layer blocks sharded over the "pipe" axis.
 
@@ -769,8 +765,7 @@ def forward_pp(
             h_out, ck, cv = _run_layers(
                 cfg, lp_stack, h_in, ck, cv, lay=lay_mb, positions=pos_mb[mbc],
                 slot=slot_t, block_tables=bt_mb[mbc], q_start=qs_mb[mbc],
-                kv_lens=kl_mb[mbc], attn_impl=attn_impl,
-                attn_num_splits=attn_num_splits)
+                kv_lens=kl_mb[mbc], attn_impl=attn_impl)
             out = out.at[mbc].add(jnp.where((s == pp - 1) & live, h_out, 0))
             h_nxt = lax.ppermute(
                 h_out, "pipe", [(j, (j + 1) % pp) for j in range(pp)])

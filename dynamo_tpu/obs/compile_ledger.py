@@ -379,10 +379,25 @@ def get_compile_ledger() -> CompileLedger:
 # Bucket lattice enumeration — the ladders sig_for_rows picks from.
 # ---------------------------------------------------------------------------
 
-def _nblk_ladder(max_nblk: int) -> list[int]:
-    """Reachable block-table widths: dispatch computes
-    ``min(_pow2_bucket(need, 4, max_nblk), max_nblk)`` — the pow2 ladder
-    from 4, clamped to (and always including) max_nblk."""
+def walks_live_context(ec) -> bool:
+    """Whether this engine's attention is the paged kernel
+    (ops/paged_attention.py), which reads each row's live block count at
+    run time and fetches those blocks itself: the block table's width then
+    specialises nothing but an operand's shape. The dense gather builds a
+    ``[B, nblk x BS, KH, D]`` context and pays for every entry. Read off
+    the implementation the engine resolved into its config (``"auto"``
+    unresolved, a mocker's arguments: the gather's ladder, the superset)."""
+    return getattr(ec, "attn_impl", "") in ("pallas", "pallas_interpret")
+
+
+def _nblk_ladder(ec) -> list[int]:
+    """Reachable block-table widths. Under the kernel one, ``max_nblk``
+    (``walks_live_context``); under the dense gather ``sig_for_rows``
+    computes ``min(_pow2_bucket(need, 4, max_nblk), max_nblk)`` — the pow2
+    ladder from 4, clamped to (and always including) max_nblk."""
+    max_nblk = -(-ec.max_model_len // ec.block_size)
+    if walks_live_context(ec):
+        return [max_nblk]
     out: list[int] = []
     b = 4
     while b < max_nblk:
@@ -453,7 +468,7 @@ def enumerate_buckets(ec) -> list[BucketSig]:
     chunk is ONE ragged "mixed" program (decode-ladder b x prefill t
     ladder)."""
     kv = ec.kv_dtype or "bfloat16"
-    nblks = _nblk_ladder(-(-ec.max_model_len // ec.block_size))
+    nblks = _nblk_ladder(ec)
     bs = _reachable_batch_buckets(ec.max_batch_size, ec.decode_bucket)
     out = [BucketSig("decode", b, 1, nblk, g, kv)
            for b in bs for nblk in nblks for g in (True, False)]
@@ -474,7 +489,10 @@ def sig_for_rows(kind: str, n_rows: int, t_max: int, nblk_need: int,
     ``nblk_need`` entries: THE step geometry, which dispatch() shapes its
     inputs by and every ledger reads back. The batch is one run of
     ``pack_rows``; the signature's ``n`` is the ``[N, H]`` its dense layers
-    compute, ``b x t`` the rows its attention sees.
+    compute, ``b x t`` the rows its attention sees, ``nblk`` the width of
+    its block table: under the kernel ``max_nblk`` whatever the need (the
+    kernel walks a row's live blocks and no more), under the dense gather
+    the need's pow2 bucket.
 
     ``kind`` says only whether the batch is a speculative "verify" chunk
     or an "embed" call (its own ladders, no block table); any other batch
@@ -486,9 +504,12 @@ def sig_for_rows(kind: str, n_rows: int, t_max: int, nblk_need: int,
                          _pow2_bucket(t_max, 16, ec.max_model_len), 0,
                          True, kv)
     max_nblk = -(-ec.max_model_len // ec.block_size)
-    # Block-table width from the batch's KV coverage, pow2-bucketed to
-    # bound the number of compiled programs.
-    nblk = min(_pow2_bucket(max(nblk_need, 1), 4, max_nblk), max_nblk)
+    if walks_live_context(ec):
+        nblk = max_nblk
+    else:
+        # The gather pays for every entry: width from the batch's KV
+        # coverage, pow2-bucketed to bound the number of compiled programs.
+        nblk = min(_pow2_bucket(max(nblk_need, 1), 4, max_nblk), max_nblk)
     b = _bucket(n_rows, ec.decode_bucket)
     if kind == "verify":
         # clamp: _pow2_bucket's hi stops further doubling but doesn't cap

@@ -22,11 +22,6 @@ Conventions (stated once, relied on by tests/test_perf_obs.py):
 * int8 KV halves the KV payload, packed int4 quarters it (two nibbles per
   byte — 0.5 bytes/elem), and both add the per-(block, head) f32 scales;
   int8 weights count 1 byte/elem (models/quant.py streams them packed).
-* split-K (``num_splits > 1``) adds the combine step's traffic: each split
-  writes f32 partial state (acc rows of head_dim plus the lane-padded m
-  and l columns, 128 each) that the jnp combine reads back, plus its
-  elementwise merge FLOPs — so MFU/BW-util stay honest when the kernel
-  trades extra HBM round-trips for grid parallelism.
 
 This module is dependency-free on purpose — no jax import — so the bench
 parent process can compute predicted device numbers without touching a
@@ -45,7 +40,6 @@ __all__ = [
     "HW_SPECS",
     "KV_DTYPES",
     "hw_spec_for",
-    "auto_num_splits",
     "paged_attention_cost",
     "ring_attention_cost",
     "dense_matmul_cost",
@@ -107,7 +101,7 @@ HW_SPECS: dict[str, HardwareSpec] = {
 def hw_spec_for(device_kind: str) -> HardwareSpec:
     """Resolve a jax ``device_kind`` string (e.g. "TPU v5 lite") to a spec.
     A device that is not in the table is an error, not a default: the spec
-    feeds live decisions (chunk sizing, split-K, the ring threshold) and a
+    feeds live decisions (chunk sizing, the ring threshold) and a
     utilization computed against another device's peaks is a wrong number."""
     kind = device_kind.lower()
     for key, spec in HW_SPECS.items():
@@ -173,27 +167,6 @@ def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
 
 
-def auto_num_splits(num_blocks: int, *, batch: int, q_chunks: int = 1,
-                    core_count: int = 8, min_blocks_per_split: int = 4,
-                    max_splits: int = 16) -> int:
-    """Split-K split count for one paged-attention call (deterministic,
-    jax-free — callable at trace time from ops/paged_attention.py).
-
-    Picks the smallest split count that fills ``core_count`` parallel grid
-    streams given the ``batch × q_chunks`` programs that already exist,
-    without shrinking any split below ``min_blocks_per_split`` context
-    blocks (below that the combine's extra HBM round-trip outweighs the
-    latency win — each split's partial state costs ~(D + 256) f32 per row
-    against the ~BS·KH·D·itemsize bytes a block walk reads).
-    """
-    if num_blocks <= min_blocks_per_split:
-        return 1
-    streams = max(1, batch * q_chunks)
-    want = _ceil_div(core_count, streams)
-    cap = max(1, num_blocks // min_blocks_per_split)
-    return max(1, min(want, cap, max_splits))
-
-
 def paged_attention_cost(
     *,
     batch: int,
@@ -205,7 +178,6 @@ def paged_attention_cost(
     block_size: int,
     kv_dtype: str = "bfloat16",
     act_bytes: int = 2,
-    num_splits: int = 1,
 ) -> KernelCost:
     """One paged-attention invocation (Pallas kernel and the dense-gather
     fallback execute the same matmul volume over the same KV blocks).
@@ -215,15 +187,6 @@ def paged_attention_cost(
     plus both K and V caches streamed once per invocation; int8 caches move
     half the payload, packed int4 a quarter, both plus the per-(block,
     kv-head) f32 scales.
-
-    ``num_splits > 1`` (split-K flash decode) adds the combine step:
-    per split and per query row (B·T·H of them) the kernel writes f32
-    partial state — acc (head_dim) plus the lane-padded m and l columns
-    (128 each) — which the combine reads back, so
-    ``combine_bytes = 8 · NS · B · T · H · (D + 256)`` (4-byte elems,
-    write + read). The merge's elementwise work is charged as
-    ``combine_flops = NS · B · T · H · (2 · D + 8)`` (scale + sum of acc,
-    plus the exp/max/l bookkeeping per row).
     """
     nblk = _ceil_div(max(kv_len, 1), block_size)
     s = nblk * block_size
@@ -235,10 +198,6 @@ def paged_attention_cost(
     kv_bytes = 2.0 * batch * nblk * kv_block
     out_bytes = q_bytes
     hbm = q_bytes + kv_bytes + out_bytes
-    if num_splits > 1:
-        rows = batch * q_tokens * num_heads
-        hbm += 8.0 * num_splits * rows * (head_dim + 256)
-        flops += num_splits * rows * (2.0 * head_dim + 8)
     return KernelCost("paged_attention", flops, hbm)
 
 
@@ -285,7 +244,6 @@ def model_step_cost(
     block_size: int,
     kv_dtype: str = "bfloat16",
     quantization: str = "none",
-    attn_num_splits: int = 1,
 ) -> dict[str, KernelCost]:
     """Aggregate cost of ONE dispatched engine step, by phase.
 
@@ -338,12 +296,6 @@ def model_step_cost(
     attn_flops = 4.0 * cfg.num_heads * cfg.head_dim * attn_q_ctx * L
     attn_bytes = (2.0 * n * cfg.q_size * ab
                   + 2.0 * kv_blocks * kv_block_bytes) * L
-    if attn_num_splits > 1:
-        # Split-K combine (same per-row formula as paged_attention_cost):
-        # each query row's per-split f32 partial state round-trips HBM.
-        rows = n * cfg.num_heads
-        attn_bytes += 8.0 * attn_num_splits * rows * (cfg.head_dim + 256) * L
-        attn_flops += attn_num_splits * rows * (2.0 * cfg.head_dim + 8) * L
     attention = KernelCost("paged_attention", attn_flops, attn_bytes)
 
     if cfg.is_moe:
@@ -390,7 +342,6 @@ def decode_step_cost(
     block_size: int,
     kv_dtype: str = "bfloat16",
     quantization: str = "none",
-    attn_num_splits: int = 1,
 ) -> dict[str, KernelCost]:
     """Uniform-batch decode step (every row: 1 query token, same context) —
     the prediction entry point."""
@@ -399,8 +350,7 @@ def decode_step_cost(
         cfg, tokens=batch, logit_rows=batch,
         attn_q_ctx=float(batch * nblk * block_size),
         kv_blocks=float(batch * nblk), block_size=block_size,
-        kv_dtype=kv_dtype, quantization=quantization,
-        attn_num_splits=attn_num_splits)
+        kv_dtype=kv_dtype, quantization=quantization)
 
 
 def prefill_cost(
@@ -455,13 +405,11 @@ def predicted_decode_perf(
     block_size: int = 16,
     kv_dtype: str = "bfloat16",
     quantization: str = "none",
-    attn_num_splits: int = 1,
 ) -> dict:
     """Roofline prediction for a decode config on ``hw``."""
     phases = decode_step_cost(cfg, batch=batch, kv_len=kv_len,
                               block_size=block_size, kv_dtype=kv_dtype,
-                              quantization=quantization,
-                              attn_num_splits=attn_num_splits)
+                              quantization=quantization)
     cost = total_cost(phases)
     step_s = cost.time_bound(hw)
     tok_s = batch / step_s if step_s > 0 else 0.0
@@ -680,7 +628,6 @@ def mixed_step_cost(
     block_size: int,
     kv_dtype: str = "bfloat16",
     quantization: str = "none",
-    attn_num_splits: int = 1,
 ) -> dict[str, KernelCost]:
     """One unified ragged mixed step: ``decode_rows`` decode rows (one live
     query token attending ``decode_kv_len`` context each) packed with one
@@ -699,7 +646,7 @@ def mixed_step_cost(
                          + chunk * nblk_p * block_size),
         kv_blocks=float(decode_rows * nblk_d + (nblk_p if chunk > 0 else 0)),
         block_size=block_size, kv_dtype=kv_dtype,
-        quantization=quantization, attn_num_splits=attn_num_splits)
+        quantization=quantization)
 
 
 def mixed_step_seconds(
@@ -713,7 +660,6 @@ def mixed_step_seconds(
     block_size: int,
     kv_dtype: str = "bfloat16",
     quantization: str = "none",
-    attn_num_splits: int = 1,
     prefill_mfu: float = PREFILL_MFU,
 ) -> float:
     """Predicted wall time of one unified mixed step — decode ITL when a
@@ -726,8 +672,7 @@ def mixed_step_seconds(
     cost = total_cost(mixed_step_cost(
         cfg, decode_rows=decode_rows, decode_kv_len=decode_kv_len,
         chunk=chunk, chunk_kv_len=chunk_kv_len, block_size=block_size,
-        kv_dtype=kv_dtype, quantization=quantization,
-        attn_num_splits=attn_num_splits))
+        kv_dtype=kv_dtype, quantization=quantization))
     eff = hw.peak_flops * prefill_mfu
     return max(cost.flops / eff if eff > 0 else 0.0,
                cost.hbm_bytes / hw.hbm_bw if hw.hbm_bw > 0 else 0.0)

@@ -201,6 +201,7 @@ class SchedStepRecord:
     live_tokens: int = 0            # tokens the plan actually needed
     sched_tokens: int = 0           # tokens the dense layers computed (N)
     rect_tokens: int = 0            # positions attention ran over (b x t)
+    kv_blocks_live: int = 0         # KV blocks the rows hold (the kernel's walk)
     live_flops: float = 0.0
     sched_flops: float = 0.0
     live_bytes: float = 0.0
@@ -225,6 +226,7 @@ class SchedStepRecord:
             "live_tokens": self.live_tokens,
             "sched_tokens": self.sched_tokens,
             "rect_tokens": self.rect_tokens,
+            "kv_blocks_live": self.kv_blocks_live,
             "goodput": round(self.goodput, 4),
             "budget_util": round(self.budget_util, 4),
         }
@@ -266,6 +268,7 @@ class SchedLedger:
         self.live_tokens_total = 0
         self.sched_tokens_total = 0
         self.rect_tokens_total = 0
+        self.kv_blocks_live_total = 0
         self.padding_flops_total = 0.0
         self.padding_bytes_total = 0.0
         self.hol_stall_seconds_total = 0.0
@@ -296,6 +299,7 @@ class SchedLedger:
             self.live_tokens_total = 0
             self.sched_tokens_total = 0
             self.rect_tokens_total = 0
+            self.kv_blocks_live_total = 0
             self.padding_flops_total = 0.0
             self.padding_bytes_total = 0.0
             self.hol_stall_seconds_total = 0.0
@@ -353,6 +357,7 @@ class SchedLedger:
         live_tokens: int = 0,
         sched_tokens: int = 0,
         rect_tokens: int = 0,
+        kv_blocks_live: int = 0,
         live_flops: float = 0.0,
         sched_flops: float = 0.0,
         live_bytes: float = 0.0,
@@ -381,7 +386,7 @@ class SchedLedger:
             ts=end, wall_s=wall_s, kinds=tuple(kinds),
             prefill_rows=prefill_rows, decode_rows=decode_rows,
             live_tokens=live_tokens, sched_tokens=sched_tokens,
-            rect_tokens=rect_tokens,
+            rect_tokens=rect_tokens, kv_blocks_live=kv_blocks_live,
             live_flops=live_flops, sched_flops=sched_flops,
             live_bytes=live_bytes, sched_bytes=sched_bytes,
             goodput=goodput, budget_util=budget_util,
@@ -422,6 +427,7 @@ class SchedLedger:
             self.live_tokens_total += live_tokens
             self.sched_tokens_total += sched_tokens
             self.rect_tokens_total += rect_tokens
+            self.kv_blocks_live_total += kv_blocks_live
             self.padding_flops_total += pad_f
             self.padding_bytes_total += pad_b
             if rec.hol_victims:
@@ -470,6 +476,7 @@ class SchedLedger:
                 "live_tokens_total": self.live_tokens_total,
                 "sched_tokens_total": self.sched_tokens_total,
                 "rect_tokens_total": self.rect_tokens_total,
+                "kv_blocks_live_total": self.kv_blocks_live_total,
                 "padding_flops_total": self.padding_flops_total,
                 "padding_hbm_bytes_total": self.padding_bytes_total,
                 "admission_blocked": dict(self.blocked_totals),
@@ -542,6 +549,15 @@ def get_sched_ledger() -> SchedLedger:
 # Live-vs-scheduled step geometry — the programs dispatch() ran.
 # ---------------------------------------------------------------------------
 
+def kv_blocks_live(batches, block_size: int) -> int:
+    """KV blocks the rows of a step's batches hold, ``ceil((start + length)
+    / block_size)`` a row, from positions the host has: what the attention
+    kernel walks for them (once a query chunk of the row), beside the
+    ``b x nblk`` entries of the tables it was handed."""
+    return sum(-(-(start + length) // block_size)
+               for _sig, rows, *_ in batches for _seq, start, length in rows)
+
+
 def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
     """Live and scheduled (bucket-padded) work for one finalized step.
 
@@ -550,7 +566,9 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
     dispatch() ran the batch under. The live walk mirrors
     StepPerfProfiler.measure exactly; the padded side prices that
     signature: its token bucket ``n`` through the dense layers, its
-    ``b x t`` rows through attention, ``nblk`` blocks a row. Both sides
+    ``b x t`` rows through attention, ``nblk`` blocks a row where attention
+    is the dense gather (the kernel walks the live blocks whatever the
+    table's width: both sides then price those). Both sides
     run through obs/costmodel.model_step_cost, so goodput is a pure FLOPs
     ratio hand-computable at any known bucket geometry.
 
@@ -559,11 +577,13 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
     verify rows count as decode rows beside it.
 
     Returns {kinds, prefill_rows, decode_rows, live_tokens, sched_tokens,
-    rect_tokens, live_flops, sched_flops, live_bytes, sched_bytes}:
-    ``sched_tokens`` is what the dense layers computed, ``rect_tokens`` the
-    positions of the attention rectangles.
+    rect_tokens, kv_blocks_live, live_flops, sched_flops, live_bytes,
+    sched_bytes}: ``sched_tokens`` is what the dense layers computed,
+    ``rect_tokens`` the positions of the attention rectangles,
+    ``kv_blocks_live`` :func:`kv_blocks_live` of the same batches.
     """
     from dynamo_tpu.obs import costmodel as cm
+    from dynamo_tpu.obs.compile_ledger import walks_live_context
 
     ec = engine_cfg
     bs = ec.block_size
@@ -603,6 +623,10 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
         sched["logit_rows"] += sig.b
         sched["attn_q_ctx"] += sig.b * sig.t * sig.nblk * bs
         sched["kv_blocks"] += sig.b * sig.nblk
+    if walks_live_context(ec):
+        # The kernel fetches a row's live blocks and no table entry more.
+        sched["attn_q_ctx"] = live["attn_q_ctx"]
+        sched["kv_blocks"] = live["kv_blocks"]
 
     def _cost(agg: dict):
         phases = cm.model_step_cost(
@@ -620,6 +644,7 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
         "live_tokens": live["tokens"],
         "sched_tokens": sched["tokens"],
         "rect_tokens": rect,
+        "kv_blocks_live": int(live["kv_blocks"]),
         "live_flops": lc.flops if lc else 0.0,
         "sched_flops": sc.flops if sc else 0.0,
         "live_bytes": lc.hbm_bytes if lc else 0.0,

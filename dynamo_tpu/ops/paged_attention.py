@@ -3,10 +3,12 @@
 The portable path in models/llama.py gathers the whole paged context into a
 dense ``[B, S, KH, D]`` tensor in HBM before attending — correct, but it
 materializes S=NBLK*BS rows per sequence and streams them twice. This kernel
-instead walks the block table directly: for each (sequence, context block)
-grid step, Pallas DMAs exactly one KV block ``[BS, KH, D]`` from HBM into
-VMEM (double-buffered across grid steps via the index map) and folds it into
-a running online softmax. No gathered context tensor ever exists.
+instead walks the block table directly: for each (sequence, query chunk)
+grid step it loops over groups of the KV blocks that chunk can see, copies a
+group's blocks ``[BS, KH, D]`` from HBM into VMEM itself (one async copy a
+block, by table lookup, the next group landing while this one computes) and
+folds the group into a running online softmax. No gathered context tensor
+ever exists.
 
 Works for both prefill chunks (T>1 query tokens) and decode (T=1) with the
 same causal position masking as the dense path. Numerical equivalence is
@@ -17,51 +19,59 @@ the chip through the server.
 Design notes (reference has no TPU analog; its one kernel is a CUDA block
 copy, lib/llm/src/kernels/block_copy.cu — paged attention itself lives
 inside vLLM/TRT-LLM, which we replace):
-- grid = (B, NQ, NS, SPB): batch and q-chunk are parallel; the context-block
-  walk is partitioned into NS splits of SPB blocks each (split-K flash
-  decode). Within a split the block axis is sequential ("arbitrary"),
-  carrying the online-softmax state in VMEM scratch (acc, row-max m, row-sum
-  l) — one slab per kv head, re-initialized at each split's first step.
-- num_splits=1 IS the sequential kernel: one split walks all blocks and
-  normalizes in-kernel, exactly the pre-split-K code path. num_splits>1
-  emits per-split partial ``(acc, m, l)`` state as float32 outputs and a
-  small jnp combine (logsumexp-weighted merge) produces the final rows —
-  long-context decode latency drops from O(NBLK) sequential grid steps to
-  O(NBLK / NS).
-- ragged early-exit: per-row used-block counts ride the scalar-prefetch
-  channel; the K/V index maps clamp the context-block lookup at a row's last
-  real block, so every grid step past it re-requests the same HBM block and
-  Pallas elides the DMA (revisited block ⇒ no copy), while pl.when skips the
-  matmuls. Batch cost is proportional to total context, not B × max_blocks.
-  The same holds along a row's query chunks: the counts are per (row,
-  query chunk), so a chunk past the row's live tokens walks nothing (a
-  one-token row in a T=512 rectangle pays one chunk of eight), and a live
-  chunk stops at the last block its own last position can see (the causal
-  mask hides the rest). Both leave every live position's result as it
-  was, bit for bit: a block whose every score is masked changes no running
-  state. With one query chunk a row (decode) the counts are the row's own.
-- block tables + positions are scalar-prefetched (PrefetchScalarGridSpec)
-  so the K/V BlockSpec index maps can address HBM blocks by table lookup —
-  the DMA pipeline chases the page table, the kernel body never sees HBM.
-- the kernel takes the WHOLE cache ``[L, NB, BS, KH, Dp]`` and a layer
-  index (one more scalar-prefetch operand): the K/V index map is
-  ``(layer, table[b, j], 0, 0, 0)``, the same blocks from another base, so
-  the model's layer loop never cuts a layer out of the cache for it
-  (models/llama.py ``_layer``). A quantized pool's scales stay a per-layer
-  ``[NB, KH]`` operand: SMEM must not grow with L.
-- K/V blocks load ALL kv heads at once — block shape ``(1, BS, KH, Dp)``
-  equals the array's trailing dims, which always satisfies Mosaic's tiling
-  constraint (a per-head block ``(1, BS, 1, D)`` has a second-to-minor dim
-  of 1 against KH=8 and does not lower). The kv-head loop is a static
-  Python loop inside the kernel: KH small 2D matmuls on the MXU per block.
+- grid = (B, NQ), both parallel: a row, a chunk of its query rows. There is
+  no block axis, so the block table's width specialises nothing but an
+  operand's shape (a step program is compiled for ONE width, the longest
+  context the engine admits: obs/compile_ledger.py ``sig_for_rows``): the
+  walk is a ``fori_loop`` inside the grid step, over groups of G blocks
+  (``_group_blocks``: G*BS is 256 keys under a prefill chunk's rows and 512
+  under a decode row's, a multiple of the MXU's 128 lanes), with a trip
+  count read off the scalar prefetch. The online-softmax state (acc,
+  row-max m, row-sum l) lives in VMEM scratch, one slab per kv head,
+  initialized by each step that walks.
+- the ragged walk: per-(row, query chunk) used-block counts ride the
+  scalar-prefetch channel (``chunk_used_blocks``). A chunk walks
+  ceil(used / G) groups: a padding row, or a query chunk past the row's
+  live tokens (a one-token row in a T=512 rectangle has seven of eight),
+  costs one grid step that writes zeros; a live chunk stops at the last
+  block its own last position can see (the causal mask hides the rest), and
+  a block past that costs nothing. The last group of a walk names the last
+  used block again for the slots past it: their keys are masked by
+  position, and the buffer never holds VMEM nobody wrote. Batch cost is
+  proportional to total context, not B x max_blocks.
+- one walk a (row, query chunk), no split of it over grid steps: a v5e has
+  one core, every batch bucket fills the grid's parallel axes (the decode
+  ladder starts at 8 rows), and a walk that lives inside one grid step has
+  no steps to spread.
+- the kernel takes the WHOLE cache ``[L, NB, BS, KH, Dp]`` where it lies in
+  HBM (memory space ANY) and a layer index (one more scalar-prefetch
+  operand): a block is copied from ``cache[layer, table[b, j]]``, so the
+  model's layer loop never cuts a layer out of the cache for it
+  (models/llama.py ``_layer``), and the cache is only read. A quantized
+  pool's scales stay a per-layer ``[NB, KH]`` operand: SMEM must not grow
+  with L.
+- a block lands with ALL its kv heads, ``[BS, KH, Dp]`` as the pool holds
+  it, into a ``[G*BS, KH, Dp]`` buffer (two of them for K, two for V), so
+  one head's keys lie KH rows apart. For a bf16 pool the buffer is read as
+  uint32 ``[G*BS*KH/2, Dp]``: one sublane-strided load brings heads 2j and
+  2j+1 of every key in the two halves of a word, and a bf16 in the high
+  half of a word is its float32. Other pools (float32 in tests, int8,
+  packed int4) take the plain strided read. The kv-head loop is a static
+  Python loop inside the group's body: per head one ``[R, D] x [D, G*BS]``
+  and one ``[R, G*BS] x [G*BS, D]`` matmul on the MXU.
+- precision: bf16 queries and keys go to the MXU as bf16 with a float32
+  result (the products are exact, so the scores are those of a float32
+  widening up to summation order); max, exp, sum, alpha and the accumulator
+  are float32, and the probabilities stay float32 into P.V.
 - q rows are pre-laid-out ``[B, KH, T*REP, D]`` (rep = query heads per kv
   head) outside the kernel so each head's queries are one contiguous 2D
   slab — one MXU matmul covers all query heads of the kv head.
-- quantized caches: int8 payloads DMA at 1 byte/elem and the per-(block,
-  kv-head) scale folds into the MXU results; packed int4 payloads (uint8,
-  two nibbles per byte, trailing dim D/2 — engine/cache.py) additionally
-  unpack in VMEM via integer shifts before the matmuls, so KV streams from
-  HBM at half a byte per element.
+- quantized caches: int8 payloads copy at 1 byte/elem and go to the MXU as
+  they are (exact in bf16); the per-(block, kv-head) scale multiplies that
+  block's BS columns of the group's scores, and of its probabilities before
+  P.V. Packed int4 payloads (uint8, two nibbles per byte, trailing dim D/2 —
+  engine/cache.py) additionally unpack in VMEM via integer shifts before
+  the matmuls, so KV streams from HBM at half a byte per element.
 """
 
 from __future__ import annotations
@@ -76,7 +86,15 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
-_SCRATCH_CAP_BYTES = 4 * 2**20  # online-softmax VMEM scratch budget
+# A query chunk is as large as the 16 MiB of VMEM a kernel gets allow: its
+# float32 softmax state (all kv heads), the query and output blocks the
+# pipeline holds two of, and its float32 scores against a group of keys.
+# At 8 kv heads x 128 that is 512 rows (12 MiB with the K/V buffers).
+_SCRATCH_CAP_BYTES = 8 * 2**20  # online-softmax VMEM scratch budget
+_CHUNK_ROWS = 512               # rows x _GROUP_KEYS float32 scores: 512 KiB
+_GROUP_KEYS = 256       # keys a group of the walk holds under a prefill chunk
+_GROUP_KEYS_WIDE = 512  # ... and under at most _FEW_ROWS query rows (decode)
+_FEW_ROWS = 32
 
 # Mosaic min-tile sublane count by dtype itemsize (lane is always 128):
 # f32 → (8, 128), bf16 → (16, 128), int8/uint8/fp8 → (32, 128).
@@ -100,15 +118,17 @@ SMEM_USABLE_BYTES = (1 << 20) - (16 << 10)
 
 def scalar_prefetch_bytes(*, batch: int, nblk: int, num_blocks: int = 0,
                           kv_heads: int = 0) -> int:
-    """SMEM bytes of the kernel's scalar-prefetch operands. Each row of a
-    2-D operand pads to whole 128-lane words (512 B): the ``[B, NBLK]``
-    block table, three ``[B]`` vectors (one of them ``[B x query chunks]``
-    for a prefill rectangle: 2 KB at most, inside the slack of
-    ``SMEM_USABLE_BYTES``), the ``[1]`` layer index and, for a
+    """SMEM bytes of the kernel's scalar-prefetch operands: what the walk
+    reads to find its blocks. Each row of a 2-D operand pads to whole
+    128-lane words (512 B): the ``[B, NBLK]`` block table the copies look
+    blocks up in, three ``[B]`` vectors (first query position, context
+    length, and the used-block counts that set each walk's trip count:
+    ``[B x query chunks]`` for a prefill rectangle, 2 KB at most, inside
+    the slack of ``SMEM_USABLE_BYTES``), the ``[1]`` layer index and, for a
     quantized cache (``num_blocks`` and ``kv_heads`` given), the two
     ``[NB, KH]`` float32 scale sidecars of ONE layer — which is what bounds
     a quantized pool to ~1,000 blocks, and the block table to ~4k blocks a
-    row at 64 rows."""
+    row at 64 rows. The K/V buffers and semaphores are VMEM, not here."""
     def row(n: int) -> int:
         return -(-n // 128) * 512
 
@@ -148,163 +168,282 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     return jnp.concatenate([lo, hi], axis=-1)
 
 
-# ---------------------------------------------------------------------------
-# Split-K sizing
-# ---------------------------------------------------------------------------
-
-#: f32 per-split partial-state budget (acc + m + l outputs in HBM). The
-#: split-K prefill gate: partial state scales with ns·R (R = T·rep query
-#: rows), so a big prefill chunk that would emit hundreds of MB of state
-#: stays sequential even when the grid underfills the cores.
-_SPLIT_STATE_CAP_BYTES = 8 * 2**20
+def _i32(x: int):
+    """A Python int as an int32 constant (lax primitives take no weak types)."""
+    return jnp.int32(x)
 
 
-def resolve_num_splits(num_splits: int, *, nblk: int, batch: int,
-                       q_chunks: int, q_tokens: int,
-                       state_rows: int = 0, kv_heads: int = 0,
-                       head_dim: int = 0) -> int:
-    """Resolve a ``num_splits`` request to the split count actually used.
-
-    0 ("auto") defers to the cost model's :func:`auto_num_splits`. Decode
-    (q_tokens == 1) engages whenever the batch underfills the cores.
-    Chunked prefill (q_tokens > 1) engages under the SAME underfill signal —
-    ``batch × q_chunks`` grid programs vs core count — but only while the
-    f32 per-split partial state (which scales with ns·R, unlike decode's
-    R = rep) fits :data:`_SPLIT_STATE_CAP_BYTES`; callers that don't supply
-    the state geometry (``state_rows``/``kv_heads``/``head_dim``) keep the
-    conservative sequential walk. Explicit values are clamped to [1, nblk].
-    """
-    if num_splits <= 0:
-        from dynamo_tpu.obs.costmodel import auto_num_splits
-
-        want = auto_num_splits(nblk, batch=batch, q_chunks=q_chunks)
-        if q_tokens != 1 and want > 1:
-            if not (state_rows and kv_heads and head_dim):
-                return 1
-            bytes_per_split = (batch * kv_heads * state_rows
-                               * (head_dim + 256) * 4)
-            want = min(want, max(
-                _SPLIT_STATE_CAP_BYTES // max(bytes_per_split, 1), 1))
-        return max(1, min(want, nblk))
-    return max(1, min(num_splits, nblk))
+def _cast(x: jax.Array, dtype) -> jax.Array:
+    """``x`` as ``dtype``; an integer payload goes by float32 (Mosaic
+    converts int8 to bf16 in those two steps)."""
+    if x.dtype == dtype:
+        return x
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        x = lax.convert_element_type(x, jnp.float32)
+    return lax.convert_element_type(x, dtype)
 
 
-def _kernel(*refs, bs: int, kh: int, rep: int, spb: int, nq: int,
-            quant: bool, int4: bool, split: bool):
+def query_chunks(t: int, *, rep: int, kh: int, d: int,
+                 q_dtype=jnp.bfloat16) -> tuple[int, int]:
+    """(rows a query chunk holds, chunks a row) for ``t`` query tokens of
+    ``rep`` query heads a kv head. The rows are chunked (flash tiling) so
+    that what a grid step holds fits VMEM: the all-head softmax scratch,
+    KH * rchunk * (D + 256) * 4 bytes, and a group's float32 scores,
+    ``_CHUNK_ROWS`` rows at most. Every chunk of a row walks the row's
+    context again, and a one-token row beside a prefill chunk pays its
+    whole first chunk: 512 rows measured 5-14 % under 256 on a T=512
+    chunk at depths 0-3,584 and 10 % over at T=128 (PERF.md, PR 35).
+    Decode (T=1) always fits in one chunk."""
+    r = t * rep
+    rchunk = r
+    # Halving stops while the chunk stays Mosaic-legal: a partial block's
+    # second-to-minor dim must be a multiple of the dtype's min sublane
+    # count (rchunk == r needs no divisibility — whole-axis blocks are
+    # always legal). Better to overshoot the soft scratch cap than emit a
+    # block shape the TPU refuses to lower.
+    q_sub = _sublane(q_dtype)
+    while ((kh * rchunk * (d + 256) * 4 > _SCRATCH_CAP_BYTES
+            or rchunk > _CHUNK_ROWS)
+           and rchunk % 2 == 0 and rchunk > rep
+           and (rchunk // 2) % q_sub == 0):
+        rchunk //= 2
+    return rchunk, r // rchunk
+
+
+def chunk_used_blocks(q_start, kv_lens, *, nq: int, rchunk: int, rep: int,
+                      bs: int, nblk: int):
+    """``[B, NQ]`` int32: the blocks each query chunk of each row walks.
+    Chunk c holds rows ``c*rchunk ..`` of the row's ``[T*rep]`` slab (row r
+    is query token ``r // rep``): it sees the context up to its own last
+    position and within ``kv_len``, and nothing if it starts at or past
+    ``kv_len`` (the row's live tokens end there: the chunk is padding)."""
+    chunk = jnp.arange(nq, dtype=jnp.int32)
+    first = q_start[:, None] + (chunk * rchunk) // rep
+    last = q_start[:, None] + ((chunk + 1) * rchunk - 1) // rep
+    seen = jnp.where(first < kv_lens[:, None],
+                     jnp.minimum(kv_lens[:, None], last + 1), 0)
+    return jnp.clip((seen + bs - 1) // bs, 0, nblk)
+
+
+def _group_blocks(rows: int, bs: int, nblk: int) -> int:
+    """Blocks a group of the walk holds, G: what one online-softmax update
+    covers. ``G * bs`` keys is a multiple of the 128 lanes wherever the
+    table is that long: ``_GROUP_KEYS_WIDE`` keys under a few query rows
+    (decode: the group's float32 scores ``[rows, G*bs]`` are a vreg or
+    two), ``_GROUP_KEYS`` under a prefill chunk's (256 rows x 256 keys of
+    float32 is the whole register file)."""
+    keys = _GROUP_KEYS_WIDE if rows <= _FEW_ROWS else _GROUP_KEYS
+    return max(1, min(keys // bs, nblk))
+
+
+def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
+            quant: bool, int4: bool, mm_dtype):
     if quant:
         # Scales ride the scalar-prefetch channel with the block table, so
-        # dequant needs no extra DMA: the int8/int4 block is widened
-        # in-register and the per-(block, head) scale folds into the MXU
-        # results.
+        # dequant needs no extra DMA: the int8/int4 payload goes to the MXU
+        # as it is and the per-(block, head) scale multiplies that block's
+        # columns of the group's scores and of its probabilities.
         (bt_ref, qs_ref, kl_ref, ub_ref, ly_ref, ks_ref, vs_ref, *refs) = refs
     else:
         (bt_ref, qs_ref, kl_ref, ub_ref, ly_ref, *refs) = refs
         ks_ref = vs_ref = None
-    if split:
-        (q_ref, k_ref, v_ref, o_ref, mo_ref, lo_ref,
-         acc_ref, m_ref, l_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref) = refs
-    del ly_ref  # consumed by the index maps (layer), not the body
+    (q_ref, k_hbm, v_hbm, o_ref,
+     kbuf, vbuf, sems, acc_ref, m_ref, l_ref) = refs
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    si = pl.program_id(2)
-    jj = pl.program_id(3)
-    g = si * spb + jj  # global context-block index
+    r = q_ref.shape[2]          # rows in this q chunk (row = token*rep + q-head)
+    gk = gb * bs                # keys a group holds
 
-    @pl.when(jj == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    # Blocks this query chunk of the row can see: none past the row's
+    # context, none past the chunk's own last position, none at all for a
+    # chunk of padding. The walk is over groups of ``gb`` of them: the trip
+    # count is this chunk's own, whatever the table's width.
+    used = ub_ref[b if nq == 1 else lax.add(lax.mul(b, _i32(nq)), qi)]
+    groups = lax.div(lax.add(used, _i32(gb - 1)), _i32(gb))
+    last = lax.max(lax.sub(used, _i32(1)), _i32(0))
+    layer = ly_ref[0]
     kv_len = kl_ref[b]
+    q_pos0 = qs_ref[b]
 
-    # Blocks this query chunk of the row can see (the same count clamps the
-    # K/V DMAs in the index map): none past the row's context, none past
-    # the chunk's own last position, none at all for a chunk of padding.
-    @pl.when(g < ub_ref[b if nq == 1 else b * nq + qi])
-    def _compute():
-        r = q_ref.shape[2]  # rows in this q chunk (row = token*rep + q-head)
-        # Causal/visibility mask is head-independent: [R, BS].
-        row = lax.broadcasted_iota(jnp.int32, (r, bs), 0) + qi * r
-        row_t = row // rep                                            # query token idx
-        ctx = lax.broadcasted_iota(jnp.int32, (r, bs), 1) + g * bs    # context position
-        q_pos = qs_ref[b] + row_t
-        visible = (ctx <= q_pos) & (ctx < kv_len)
+    def block_id(g, i):
+        # The group's i-th block. Past the last used block the last one is
+        # named again: its keys are masked by position, and what the buffer
+        # holds there is KV that was written, never stale VMEM.
+        return bt_ref[b, lax.min(lax.add(lax.mul(g, _i32(gb)), i), last)]
 
-        if int4:
-            # Unpack once per block for all kv heads: uint8 [BS, KH, D/2]
-            # → f32 [BS, KH, D] signed nibbles, scales applied per head in
-            # the matmul results below.
-            k_wide = unpack_int4(k_ref[0]).astype(jnp.float32)
-            v_wide = unpack_int4(v_ref[0]).astype(jnp.float32)
+    def copies(g, slot, i, landing=False):
+        # A wait needs the copy's shape and semaphore, not its source.
+        blk = 0 if landing else block_id(g, i)
+        dst = pl.ds(pl.multiple_of(lax.mul(i, _i32(bs)), bs), bs)
+        return (
+            pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[slot, dst],
+                                  sems.at[0, slot]),
+            pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[slot, dst],
+                                  sems.at[1, slot]),
+        )
 
-        for ki in range(kh):
-            q = q_ref[0, ki].astype(jnp.float32)                      # [R, D]
-            if int4:
-                k = k_wide[:, ki]                                     # [BS, D]
-                v = v_wide[:, ki]
-            else:
-                k = k_ref[0, :, ki].astype(jnp.float32)               # [BS, D]
-                v = v_ref[0, :, ki].astype(jnp.float32)
+    def fetch(g, slot):
+        def start(i, c):
+            for cp in copies(g, slot, i):
+                cp.start()
+            return c
+        lax.fori_loop(0, gb, start, 0)
+
+    def land(g, slot):
+        def wait(i, c):
+            for cp in copies(g, slot, i, landing=True):
+                cp.wait()
+            return c
+        lax.fori_loop(0, gb, wait, 0)
+
+    def group(g, c):
+        # (lax primitives all through the walk: a jnp call is a nested jit
+        # to trace, and a step program is traced at every start.)
+        slot = lax.rem(g, _i32(2))
+        nxt = lax.add(g, _i32(1))
+
+        @pl.when(lax.lt(nxt, groups))
+        def _next():
+            fetch(nxt, lax.sub(_i32(1), slot))
+
+        land(g, slot)
+
+        # Causal/visibility mask is head-independent, [R, GK]: key c of the
+        # group is seen by chunk row w (query token w // rep) if
+        # g*gk + c <= q_pos0 + (qi*r + w) // rep and g*gk + c < kv_len.
+        base = lax.mul(g, _i32(gk))
+        ctx = lax.broadcasted_iota(jnp.int32, (r, gk), 1)
+        tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, gk), 0),
+                      lax.full((r, gk), rep, jnp.int32))
+        q_pos = lax.sub(lax.add(q_pos0, lax.mul(qi, _i32(r // rep))), base)
+        visible = lax.bitwise_and(
+            lax.le(lax.sub(ctx, tok), lax.broadcast(q_pos, (r, gk))),
+            lax.lt(ctx, lax.broadcast(lax.sub(kv_len, base), (r, gk))))
+
+        neg_inf = lax.full((r, gk), NEG_INF, jnp.float32)
+        zeros = lax.full((r, gk), 0.0, jnp.float32)
+
+        def cols(x, n):
+            # [R, 1] -> [R, n]
+            return lax.broadcast_in_dim(x, (r, n), (0, 1))
+
+        if quant:
+            col_blk = lax.broadcasted_iota(jnp.int32, (1, gk), 1) // bs
+
+            def scale_row(s_ref, ki):
+                # [1, GK]: block i's scale over its bs columns.
+                def put(i, acc):
+                    return jnp.where(col_blk == i, s_ref[block_id(g, i), ki],
+                                     acc)
+                return lax.fori_loop(0, gb, put,
+                                     jnp.zeros((1, gk), jnp.float32))
+
+        def head(ki, k, v):
+            """Fold kv head ``ki``'s keys and values [GK, D] of this group
+            into its running softmax."""
+            q = _cast(q_ref[0, ki], mm_dtype)                         # [R, D]
+            # bf16 x bf16 products are exact in float32: the scores the
+            # float32 widening gave, up to the order of summation.
             scores = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )                                                         # [R, BS]
+                q, _cast(k, mm_dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)                   # [R, GK]
             if quant:
                 # Symmetric per-(block, head) scale: constant over the
                 # contraction, so scaling the int matmul result is exact.
-                scores = scores * ks_ref[bt_ref[b, g], ki]
-            scores = jnp.where(visible, scores, NEG_INF)
+                scores = scores * scale_row(ks_ref, ki)
+            scores = lax.select(visible, scores, neg_inf)
 
+            # The online softmax in lax primitives: a jnp call is a nested
+            # jit to trace, which a step program pays at every start.
             m_prev = m_ref[ki, :, :1]                                 # [R, 1]
             l_prev = l_ref[ki, :, :1]
-            m_curr = jnp.max(scores, axis=1, keepdims=True)           # [R, 1]
-            m_new = jnp.maximum(m_prev, m_curr)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(scores - m_new)                               # [R, BS]
-            p = jnp.where(visible, p, 0.0)
-            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            pv = lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )                                                         # [R, D]
+            m_curr = lax.expand_dims(lax.reduce_max(scores, (1,)), (1,))
+            m_new = lax.max(m_prev, m_curr)
+            alpha = lax.exp(lax.sub(m_prev, m_new))
+            p = lax.exp(lax.sub(scores, cols(m_new, gk)))             # [R, GK]
+            p = lax.select(visible, p, zeros)
+            l_new = lax.add(lax.mul(alpha, l_prev),
+                            lax.expand_dims(lax.reduce_sum(p, (1,)), (1,)))
             if quant:
-                pv = pv * vs_ref[bt_ref[b, g], ki]
-            acc_ref[ki] = acc_ref[ki] * alpha + pv
-            m_ref[ki] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[ki] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+                p = p * scale_row(vs_ref, ki)
+            # The probabilities stay float32 into P.V.
+            pv = lax.dot_general(
+                p, _cast(v, jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)                   # [R, D]
+            acc_ref[ki] = lax.add(
+                lax.mul(acc_ref[ki], cols(alpha, acc_ref.shape[-1])), pv)
+            m_ref[ki] = cols(m_new, m_ref.shape[-1])
+            l_ref[ki] = cols(l_new, l_ref.shape[-1])
 
-    @pl.when(jj == spb - 1)
-    def _finish():
-        if split:
-            # Emit this split's raw flash state; the jnp combine outside the
-            # kernel merges splits. Empty splits (every block past kv_len)
-            # emit (m=NEG_INF, l=0, acc=0) and combine to zero weight.
+        # A block lands as it lies in the pool, [BS, KH, Dp], so one head's
+        # rows are KH apart in the group's buffer.
+        if int4:
+            # Unpack once per group for all kv heads: uint8 [GK, KH, D/2]
+            # → signed nibbles [GK, KH, D].
+            k_wide = unpack_int4(kbuf[slot]).astype(jnp.float32)
+            v_wide = unpack_int4(vbuf[slot]).astype(jnp.float32)
             for ki in range(kh):
-                o_ref[0, 0, ki] = acc_ref[ki]
-                mo_ref[0, 0, ki] = m_ref[ki]
-                lo_ref[0, 0, ki] = l_ref[ki]
+                head(ki, k_wide[:, ki], v_wide[:, ki])
+        elif kbuf.dtype == jnp.bfloat16 and kh % 2 == 0:
+            # bf16 packs two rows a 32-bit word, so as uint32 [GK*KH/2, Dp]
+            # one strided load brings heads 2j (low half) and 2j+1 (high
+            # half) of every key, eight keys a vreg; a bf16 in the high
+            # half of a word IS its float32. The pairs are a loop that is
+            # unrolled when the kernel is lowered: the body is traced once,
+            # not KH/2 times (a step program is traced at every start), and
+            # the heads still overlap on the device.
+            kw, vw = (buf.at[slot].reshape(gk * kh, buf.shape[-1])
+                      .bitcast(jnp.uint32) for buf in (kbuf, vbuf))
+            sixteen = lax.full((gk, kbuf.shape[-1]), 16, jnp.uint32)
+            hi = lax.full((gk, kbuf.shape[-1]), 0xFFFF0000, jnp.uint32)
+
+            def pair(j, c):
+                rows = pl.ds(j, gk, stride=kh // 2)
+                k2, v2 = kw[rows, :], vw[rows, :]
+                head(2 * j, pltpu.bitcast(lax.shift_left(k2, sixteen), jnp.float32),
+                     pltpu.bitcast(lax.shift_left(v2, sixteen), jnp.float32))
+                head(2 * j + 1, pltpu.bitcast(lax.bitwise_and(k2, hi), jnp.float32),
+                     pltpu.bitcast(lax.bitwise_and(v2, hi), jnp.float32))
+                return c
+            lax.fori_loop(0, kh // 2, pair, 0, unroll=True)
         else:
             for ki in range(kh):
-                l = l_ref[ki, :, :1]
-                l = jnp.where(l == 0.0, 1.0, l)                       # all-masked rows → 0
-                o_ref[0, ki] = (acc_ref[ki] / l).astype(o_ref.dtype)
+                head(ki, kbuf[slot, :, ki, :], vbuf[slot, :, ki, :])
+        return c
 
+    # Depth matters to set-up: a step program is traced at every start, and
+    # an operation costs the more to trace the deeper it sits in nested
+    # loops and conditionals (PERF.md, PR 34). So the walk's loop stands at
+    # the kernel's top level (no trip for a dead step) and only the short
+    # pieces around it are conditional.
+    live = lax.gt(groups, _i32(0))
 
-def _combine_splits(o_p: jax.Array, m_p: jax.Array, l_p: jax.Array,
-                    out_dtype) -> jax.Array:
-    """Merge per-split flash state [B, NS, KH, R, ·] → final rows
-    [B, KH, R, D]. Standard logsumexp-weighted combine; a row whose every
-    split is empty (kv_len 0 / fully masked) has l_tot 0 and yields 0,
-    matching the sequential kernel's guarded divide."""
-    m = m_p[..., :1]                                      # [B,NS,KH,R,1]
-    l = l_p[..., :1]
-    m_tot = jnp.max(m, axis=1, keepdims=True)             # [B,1,KH,R,1]
-    w = jnp.exp(m - m_tot)                                # [B,NS,KH,R,1]
-    l_tot = jnp.sum(w * l, axis=1)                        # [B,KH,R,1]
-    acc = jnp.sum(o_p * w, axis=1)                        # [B,KH,R,D]
-    l_tot = jnp.where(l_tot == 0.0, 1.0, l_tot)
-    return (acc / l_tot).astype(out_dtype)
+    @pl.when(live)
+    def _init():
+        m_ref[:] = lax.full(m_ref.shape, NEG_INF, m_ref.dtype)
+        l_ref[:] = lax.full(l_ref.shape, 0.0, l_ref.dtype)
+        acc_ref[:] = lax.full(acc_ref.shape, 0.0, acc_ref.dtype)
+        fetch(_i32(0), _i32(0))
+
+    lax.fori_loop(_i32(0), groups, group, 0)
+
+    @pl.when(live)
+    def _finish():
+        def one(ki, c):
+            l = l_ref[ki, :, :1]
+            l = lax.select(lax.eq(l, lax.full(l.shape, 0.0, l.dtype)),
+                           lax.full(l.shape, 1.0, l.dtype), l)    # all-masked rows → 0
+            o_ref[0, ki] = lax.convert_element_type(
+                lax.div(acc_ref[ki], lax.broadcast_in_dim(
+                    l, acc_ref.shape[1:], (0, 1))), o_ref.dtype)
+            return c
+        lax.fori_loop(0, kh, one, 0)
+
+    @pl.when(lax.eq(groups, _i32(0)))
+    def _dead():
+        # Nothing to walk (a padding row, a chunk of padding): the step
+        # costs its grid slot and zeros.
+        o_ref[...] = lax.full(o_ref.shape, 0.0, o_ref.dtype)
 
 
 def _layer_stack(k_cache, v_cache, layer):
@@ -329,27 +468,26 @@ def paged_attention_kernel(
     layer=None,               # int32 scalar (may be traced): which layer of
                               #   the cache; None = the cache IS one layer,
                               #   [NB, BS, KH, D]
-    num_splits: int = 0,      # 0 = auto (cost model), 1 = sequential, N = forced
     interpret: bool = False,
 ) -> jax.Array:
     """Flash paged attention over layer ``layer`` of a block-table cache.
     Returns [B, T, H, D].
 
-    The cache is only read, and only the blocks the tables name: the layer
-    index rides the scalar-prefetch channel into the K/V index maps, so a
-    caller that carries the whole cache through a loop hands it over as it
-    is, without cutting the layer out.
+    The cache is only read, and only the blocks the tables name: it stays
+    in HBM and the kernel copies those blocks itself, the layer index
+    riding the scalar-prefetch channel, so a caller that carries the whole
+    cache through a loop hands it over as it is, without cutting the layer
+    out.
 
-    Quantized caches (``{"q", "s"}`` — engine/cache.py) DMA int8 blocks
+    Quantized caches (``{"q", "s"}`` — engine/cache.py) copy int8 blocks
     (half the HBM bytes of bf16) or packed-int4 blocks (a quarter — uint8
     payload, two nibbles per byte) and fold the per-(block, kv-head) dequant
-    scale into the per-block MXU matmuls; no widened KV tensor ever exists
+    scale into the group's MXU matmuls; no widened KV tensor ever exists
     in HBM.
 
-    ``num_splits`` partitions each row's context-block walk across grid
-    programs (split-K flash decode); per-row used-block counts clamp the KV
-    index maps so ragged batches skip DMA + compute past each row's real
-    context.
+    Per-(row, query chunk) used-block counts end each walk at the chunk's
+    real context: the width of ``block_tables`` bounds what a row may hold
+    and costs nothing past what it does hold.
     """
     k_cache, v_cache, layer = _layer_stack(k_cache, v_cache, layer)
     quant = isinstance(k_cache, dict)
@@ -373,113 +511,66 @@ def paged_attention_kernel(
     qs = (q * (d ** -0.5)).reshape(b, t, kh, rep, d)
     qs = qs.transpose(0, 2, 1, 3, 4).reshape(b, kh, t * rep, d)
 
-    # Chunk the query rows (flash tiling) so the all-head softmax scratch
-    # stays within a few MB of VMEM for long prefill chunks: scratch bytes =
-    # KH * rchunk * (D + 256) * 4. Decode (T=1) always fits in one chunk, so
-    # each KV block is still DMA'd exactly once per step on the hot path.
     r = t * rep
-    rchunk = r
-    # Halving stops while the chunk stays Mosaic-legal: a partial block's
-    # second-to-minor dim must be a multiple of the dtype's min sublane
-    # count (rchunk == r needs no divisibility — whole-axis blocks are
-    # always legal). Better to overshoot the soft scratch cap than emit a
-    # block shape the TPU refuses to lower.
-    q_sub = _sublane(q.dtype)
-    while (kh * rchunk * (d + 256) * 4 > _SCRATCH_CAP_BYTES
-           and rchunk % 2 == 0 and rchunk > rep
-           and (rchunk // 2) % q_sub == 0):
-        rchunk //= 2
-    nq = r // rchunk
+    rchunk, nq = query_chunks(t, rep=rep, kh=kh, d=d, q_dtype=q.dtype)
 
-    ns = resolve_num_splits(num_splits, nblk=nblk, batch=b, q_chunks=nq,
-                            q_tokens=t, state_rows=r, kv_heads=kh,
-                            head_dim=d)
-    spb = -(-nblk // ns)  # context blocks walked per split
-    split = ns > 1
+    gb = _group_blocks(rchunk, bs, nblk)   # the walk is over groups of G blocks
 
-    # Ragged early-exit: each query chunk of a row sees DMAs only up to
-    # the last block it can see — past it the clamped index map re-requests
-    # the same block and Pallas elides the copy (compute is pl.when-gated
-    # on the same count). Chunk c holds rows c*rchunk.. of the row's slab,
-    # row r being query token r // rep: it sees context up to its own last
-    # position and within kv_len, and nothing if it starts at or past
-    # kv_len (a row's live query tokens end there: the chunk is padding).
-    # [B * NQ], row-major; with NQ == 1 that is the row's used blocks.
+    # Ragged walk: the blocks each query chunk of each row can see, [B * NQ]
+    # row-major (with NQ == 1 the row's used blocks).
     qs32, kl32 = q_start.astype(jnp.int32), kv_lens.astype(jnp.int32)
-    chunk = jnp.arange(nq, dtype=jnp.int32)
-    first = qs32[:, None] + (chunk * rchunk) // rep
-    last = qs32[:, None] + ((chunk + 1) * rchunk - 1) // rep
-    seen = jnp.where(first < kl32[:, None],
-                     jnp.minimum(kl32[:, None], last + 1), 0)
-    used_blocks = jnp.clip((seen + bs - 1) // bs, 0, nblk).reshape(-1)
+    used_blocks = chunk_used_blocks(qs32, kl32, nq=nq, rchunk=rchunk, rep=rep,
+                                    bs=bs, nblk=nblk).reshape(-1)
 
     # Index maps see all scalar-prefetch refs after the grid indices
     # (bt, q_start, kv_lens, used_blocks, layer[, k_scale, v_scale]).
-    def qmap(bi, qi, si, jj, *_prefetch):
+    def qmap(bi, qi, *_prefetch):
         return (bi, 0, qi, 0)
-
-    def kvmap(bi, qi, si, jj, *prefetch):
-        bt, ub, ly = prefetch[0], prefetch[3], prefetch[4]
-        g = si * spb + jj
-        used = ub[bi if nq == 1 else bi * nq + qi]
-        clamped = jnp.minimum(g, jnp.maximum(used - 1, 0))
-        return (ly[0], bt[bi, clamped], 0, 0, 0)
-
-    def omap_split(bi, qi, si, jj, *_prefetch):
-        return (bi, si, 0, qi, 0)
 
     scalars = (block_tables.astype(jnp.int32), qs32, kl32, used_blocks,
                layer.reshape(1))
     if quant:
         scalars = scalars + (k_scale, v_scale)
 
-    if split:
-        out_shape = (
-            jax.ShapeDtypeStruct((b, ns, kh, r, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, ns, kh, r, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, ns, kh, r, 128), jnp.float32),
-        )
-        out_specs = (
-            pl.BlockSpec((1, 1, kh, rchunk, d), omap_split),
-            pl.BlockSpec((1, 1, kh, rchunk, 128), omap_split),
-            pl.BlockSpec((1, 1, kh, rchunk, 128), omap_split),
-        )
-    else:
-        out_shape = jax.ShapeDtypeStruct((b, kh, r, d), q.dtype)
-        out_specs = pl.BlockSpec((1, kh, rchunk, d), qmap)
+    # bf16 operands go to the MXU as they are (int8 / int4 payloads are
+    # exact in bf16); anything wider keeps float32 matmuls.
+    narrow = quant or k_cache.dtype == jnp.bfloat16
+    mm_dtype = jnp.bfloat16 if narrow and q.dtype == jnp.bfloat16 \
+        else jnp.float32
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, nq, ns, spb),
+        grid=(b, nq),
         in_specs=[
             pl.BlockSpec((1, kh, rchunk, d), qmap),
-            # The layer axis is squeezed: the body sees (1, BS, KH, Dp).
-            pl.BlockSpec((None, 1, bs, kh, dp), kvmap),
-            pl.BlockSpec((None, 1, bs, kh, dp), kvmap),
+            # The cache stays where it is, [L, NB, BS, KH, Dp] in HBM: the
+            # kernel copies the blocks the table names, a group at a time.
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=out_specs,
+        out_specs=pl.BlockSpec((1, kh, rchunk, d), qmap),
         scratch_shapes=[
+            pltpu.VMEM((2, gb * bs, kh, dp), k_cache.dtype),
+            pltpu.VMEM((2, gb * bs, kh, dp), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((kh, rchunk, d), jnp.float32),
             pltpu.VMEM((kh, rchunk, 128), jnp.float32),
             pltpu.VMEM((kh, rchunk, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, kh=kh, rep=rep, spb=spb, nq=nq,
-                          quant=quant, int4=int4, split=split),
+        functools.partial(_kernel, bs=bs, kh=kh, rep=rep, gb=gb, nq=nq,
+                          quant=quant, int4=int4, mm_dtype=mm_dtype),
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((b, kh, r, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
         # A name of its own in the device trace (the custom call would
         # otherwise take it from whatever scope encloses it).
-        name="paged_attention_splitk" if split else "paged_attention",
+        name="paged_attention",
     )(*scalars, qs, k_cache, v_cache)
-    if split:
-        out = _combine_splits(*out, out_dtype=q.dtype)
     # [B, KH, T*REP, D] → [B, T, H, D]
     return out.reshape(b, kh, t, rep, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
 
@@ -494,7 +585,6 @@ def paged_attention_sharded(
     kv_lens: jax.Array,       # [B]
     *,
     layer=None,               # as paged_attention_kernel
-    num_splits: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
     """TP-sharded paged attention: shard_map the kernel over the "model"
@@ -515,7 +605,7 @@ def paged_attention_sharded(
     def local(q, k_cache, v_cache, block_tables, q_start, kv_lens, layer):
         return paged_attention_kernel(
             q, k_cache, v_cache, block_tables, q_start, kv_lens, layer=layer,
-            num_splits=num_splits, interpret=interpret)
+            interpret=interpret)
 
     fn = jax.shard_map(
         local,

@@ -161,12 +161,6 @@ class EngineConfig:
     # Attention implementation: "auto" (pallas on TPU, dense elsewhere),
     # "dense", "pallas", or "pallas_interpret" (CPU-testable kernel path).
     attn_impl: str = "auto"
-    # Split-K flash decode (ops/paged_attention.py): partition each row's
-    # context-block walk across this many grid programs, combining partial
-    # softmax state afterwards. 0 = auto (cost model picks from context
-    # length and core count, decode only), 1 = sequential walk (off),
-    # N>1 = forced split count (clamped to the block count).
-    attn_num_splits: int = 0
     # Session-sticky KV retention (engine/session.py): when a stream with a
     # session.id annotation finishes, its committed KV blocks stay pinned
     # on device for this many seconds (leader-stamped step clock) so turn

@@ -12,6 +12,7 @@ lattice, then a served request minting ZERO serve-path compile events.
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 
 import pytest
 
@@ -177,19 +178,47 @@ def test_enumerate_tiny_config_hand_computed():
     assert BucketSig("mixed", 4, 32, 4, False, "bfloat16") in sigs
 
 
-def test_enumerate_default_config_size():
-    """Default EngineConfig: max_nblk=-(-8192//16)=512 → nblk ladder
-    {4,8,...,256,512} (8 rungs). decode b: ladder (1,2,4,8,...) through
-    max_batch_size → 4 rungs ≤ 64. Prefill rungs are "mixed" over the
-    same 4-rung decode b ladder × t ladder {16..512} (6 rungs) × 8 nblk ×
-    2 greedy = 384 → 448."""
-    ec = EngineConfig(model="tiny-llama")
+@pytest.mark.parametrize("attn_impl, widths, want", [
+    ("dense", 8, {"decode": 64, "mixed": 384}),
+    ("auto", 8, {"decode": 64, "mixed": 384}),      # unresolved: the superset
+    ("pallas", 1, {"decode": 8, "mixed": 48}),
+    ("pallas_interpret", 1, {"decode": 8, "mixed": 48}),
+])
+def test_enumerate_default_config_size(attn_impl, widths, want):
+    """Default EngineConfig: max_nblk=-(-8192//16)=512. decode b: ladder
+    (1,2,4,8,...) through max_batch_size → 4 rungs ≤ 64. Prefill rungs are
+    "mixed" over the same 4-rung decode b ladder × t ladder {16..512} (6
+    rungs) × 2 greedy. Under the dense gather × the nblk ladder
+    {4,8,...,256,512} (8 rungs): 64 + 384 = 448. Under the kernel, which
+    walks a row's live blocks whatever the table's width, × the one width
+    512: 8 + 48 = 56, what ``--warmup-mode full`` compiles on a TPU."""
+    ec = EngineConfig(model="tiny-llama", attn_impl=attn_impl)
     sigs = enumerate_buckets(ec)
     kinds = {}
     for s in sigs:
         kinds[s.kind] = kinds.get(s.kind, 0) + 1
-    assert kinds == {"decode": 64, "mixed": 384}
-    assert len(sigs) == 448
+    assert kinds == want
+    assert len(sigs) == {8: 448, 1: 56}[widths]
+    assert len({s.nblk for s in sigs}) == widths and max(
+        s.nblk for s in sigs) == 512
+
+
+def test_the_wide_table_fits_smem_at_every_batch_bucket():
+    """One width means every step program prefetches a ``[b, max_nblk]``
+    table into SMEM: 16 KB at 8 rows, 128 KB at 64, of the ~1 MiB usable
+    (what ModelRunner._check_kernel_fits checks at construction)."""
+    from dynamo_tpu.ops.paged_attention import (
+        SMEM_USABLE_BYTES,
+        scalar_prefetch_bytes,
+    )
+
+    ec = EngineConfig(model="tiny-llama", attn_impl="pallas")
+    sigs = enumerate_buckets(ec)
+    (nblk,) = {s.nblk for s in sigs}
+    table = {b: scalar_prefetch_bytes(batch=b, nblk=nblk) - 3 * 512 - 512
+             for b in sorted({s.b for s in sigs})}
+    assert table == {8: 16 << 10, 16: 32 << 10, 32: 64 << 10, 64: 128 << 10}
+    assert scalar_prefetch_bytes(batch=64, nblk=nblk) < SMEM_USABLE_BYTES // 7
 
 
 def test_enumerate_spec_variants():
@@ -238,6 +267,42 @@ def test_sig_for_rows_lands_inside_enumeration():
     for n in range(1, ec.max_batch_size + 1):
         for t in (1, 2, 3, 5):
             assert sig_for_rows("verify", n, t, 4, ec) in plan
+
+
+@pytest.mark.parametrize("kind, n, t, need", [
+    ("decode", 3, 1, 1), ("decode", 3, 1, 5), ("decode", 1, 1, 8),
+    ("mixed", 3, 20, 1), ("mixed", 2, 32, 8), ("verify", 2, 3, 4),
+])
+def test_sig_for_rows_has_one_table_width_under_the_kernel(kind, n, t, need):
+    """Where attention is the kernel (the name EngineCore resolved into its
+    config), the block need picks nothing: the table is ``max_nblk`` wide,
+    and (kind, b, t) are what the gather's signature has."""
+    kernel = sig_for_rows(kind, n, t, need, tiny_ec(attn_impl="pallas"))
+    gather = sig_for_rows(kind, n, t, need, tiny_ec(attn_impl="dense"))
+    assert kernel.nblk == 8 and gather.nblk == (4 if need <= 4 else 8)
+    assert kernel == dataclasses.replace(gather, nblk=8)
+    assert kernel in set(enumerate_buckets(
+        tiny_ec(attn_impl="pallas", spec_ngram=3, spec_k=4)))
+
+
+def test_engine_writes_the_resolved_attention_into_its_config(clean_ledger):
+    """"auto" leaves nothing behind (as prefill_chunk's 0 does not): the
+    engine's config names the implementation that runs, so sig_for_rows,
+    the plan and dispatch() agree on the table's width. On the CPU that is
+    the gather and its ladder; asked for the kernel, one width."""
+    from dynamo_tpu.engine.engine import EngineCore
+
+    kw = dict(model="tiny-llama", block_size=16, num_blocks=16,
+              max_batch_size=2, max_model_len=128, prefill_chunk=16,
+              decode_bucket=(2,), allow_random_weights=True)
+    core = EngineCore(EngineConfig(**kw))
+    assert core.engine_cfg.attn_impl == core.runner.attn_impl == "dense"
+    assert {s.nblk for s in get_compile_ledger().plan} == {4, 8}
+    core = EngineCore(EngineConfig(attn_impl="pallas_interpret", **kw))
+    assert core.engine_cfg.attn_impl == "pallas_interpret"
+    assert core.runner.engine_cfg is core.engine_cfg
+    assert {s.nblk for s in get_compile_ledger().plan} == {8}
+    assert core.runner._widest_bucket().nblk == 8
 
 
 def test_sig_for_rows_matches_hand_computed_dispatch():

@@ -328,7 +328,7 @@ def test_int4_scatter_append_merges_scales():
     assert np.abs(got[2:4] - 4.0).max() < 0.3
 
 
-def _logits_over_cache(kv_dtype, attn_impl, attn_num_splits, verify):
+def _logits_over_cache(kv_dtype, attn_impl, verify):
     """Float32 logits of one forward that reads a ``kv_dtype`` cache: the
     prompt is prefilled into it, then a fixed continuation (one decode
     token, or a five-token verify chunk with logits at every position)
@@ -341,7 +341,7 @@ def _logits_over_cache(kv_dtype, attn_impl, attn_num_splits, verify):
     spec = KVCacheSpec.for_model(cfg, 16, 4, kv_dtype=kv_dtype)
     ck, cv = allocate_cache(spec, None)
     bt = jnp.arange(1, 9, dtype=jnp.int32)[None, :]
-    kw = dict(attn_impl=attn_impl, attn_num_splits=attn_num_splits)
+    kw = dict(attn_impl=attn_impl)
     n = len(PROMPT)
     _, ck, cv = llama.forward(
         params, cfg, jnp.asarray([PROMPT], jnp.int32),
@@ -366,13 +366,13 @@ INT4_LOGIT_RMS_TOL = 0.3
 
 
 @pytest.mark.parametrize("variant, fwd", [
-    ({}, ("dense", 0, False)),                                # plain decode
-    ({"spec_ngram": 2, "spec_k": 4}, ("dense", 0, True)),     # verify path
+    ({}, ("dense", False)),                                   # plain decode
+    ({"spec_ngram": 2, "spec_k": 4}, ("dense", True)),        # verify path
     ({"attn_impl": "pallas_interpret"},                       # kernel path
-     ("pallas_interpret", 0, False)),
-    ({"attn_impl": "pallas_interpret", "attn_num_splits": 2},  # split-K
-     ("pallas_interpret", 2, False)),
-], ids=["dense", "verify", "pallas_interpret", "split_k"])
+     ("pallas_interpret", False)),
+    ({"attn_impl": "pallas_interpret", "spec_ngram": 2, "spec_k": 4},
+     ("pallas_interpret", True)),              # a verify chunk in the kernel
+], ids=["dense", "verify", "pallas_interpret", "pallas_verify"])
 def test_int4_engine_parity(variant, fwd):
     """The int4 engine is deterministic, and one forward over an int4 cache
     gives the model-precision logits within ``INT4_LOGIT_RMS_TOL`` — and

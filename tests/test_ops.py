@@ -14,6 +14,17 @@ from dynamo_tpu.models.llama import paged_attention
 from dynamo_tpu.ops.paged_attention import paged_attention_kernel
 
 
+@pytest.fixture
+def small_groups(monkeypatch):
+    """Groups of 2 blocks of 16, so that the small tables here hold several
+    groups and odd block counts end in a partial one. The kernel picks
+    16-32 blocks for itself."""
+    import dynamo_tpu.ops.paged_attention as pa
+
+    monkeypatch.setattr(pa, "_GROUP_KEYS", 32)
+    monkeypatch.setattr(pa, "_GROUP_KEYS_WIDE", 32)
+
+
 def _make_case(rng, b, t, h, kh, d, nb, bs, nblk, dtype=jnp.float32):
     """Random paged-cache attention case with per-seq positions/lengths."""
     q = jnp.asarray(rng.standard_normal((b, t, h, d)), dtype)
@@ -50,6 +61,81 @@ def test_paged_attention_kernel_matches_dense(t, kh, h):
         q, k_cache, v_cache, block_tables, q_start, q_start + q_len, interpret=True
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def _assert_live_rows_match(out, ref, q_len, tol):
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    for row, n in enumerate(np.asarray(q_len)):
+        np.testing.assert_allclose(out[row, :n], ref[row, :n], atol=tol, rtol=tol)
+    assert np.isfinite(out).all()
+
+
+# The walk at the geometry the cells run (block 16, head 128, the groups of
+# 16 and 32 blocks the kernel picks for itself): rows are (q_start, q_len).
+_WALK_CASES = {
+    # Decode: ragged rows, a one-token context, a padding row; 9 and 38
+    # used blocks are no multiple of the group's 32.
+    "t1-ragged-zero-row": (1, 40, [(0, 1), (129, 1), (0, 0), (599, 1)]),
+    # A mixed step's rectangle: the chunk at its depth, a decode row deep
+    # in its context, a short chunk (its later query chunks are padding),
+    # a padding row. 8 / 40 / 104 blocks under the deepest query chunk.
+    "t512-depth0": (512, 40, [(0, 512), (599, 1), (37, 100), (0, 0)]),
+    "t512-depth512": (512, 64, [(512, 512), (599, 1), (37, 100), (0, 0)]),
+    "t512-depth1536": (512, 128, [(1536, 512), (599, 1), (37, 100), (0, 0)]),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("kh", [8, 2], ids=["kh8", "kh2"])
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_grouped_walk_matches_dense(case, kh, dtype, tol):
+    """One grid step a (row, query chunk), a loop over the groups of blocks
+    the chunk can see: every live position equals the dense path, float32
+    tight and in the cells' bf16 (the float32 cache takes the plain strided
+    load, bf16 the two-heads-a-word one)."""
+    t, nblk, rows = _WALK_CASES[case]
+    rng = np.random.default_rng(31)
+    b, rep, d, bs = len(rows), 4, 128, 16
+    q, k_cache, v_cache, block_tables, _, _ = _make_case(
+        rng, b, t, kh * rep, kh, d, nb=b * nblk + 1, bs=bs, nblk=nblk, dtype=dtype)
+    q_start = jnp.asarray([r[0] for r in rows], jnp.int32)
+    q_len = jnp.asarray([r[1] for r in rows], jnp.int32)
+    ref = _dense_ref(q, k_cache, v_cache, block_tables, q_start, q_len)
+    out = paged_attention_kernel(q, k_cache, v_cache, block_tables, q_start,
+                                 q_start + q_len, interpret=True)
+    _assert_live_rows_match(out, ref, q_len, tol)
+
+
+@pytest.mark.parametrize("kv", ["int8", "int4"])
+@pytest.mark.parametrize("case,kh", [
+    *((case, 2) for case in sorted(_WALK_CASES)),
+    ("t1-ragged-zero-row", 8), ("t512-depth1536", 8),
+], ids=lambda v: f"kh{v}" if isinstance(v, int) else v)
+def test_grouped_walk_quantized_pool_matches_dense(case, kh, kv):
+    """The same walks over an int8 and a packed-int4 pool (the payload goes
+    to the MXU as it is; a block's scale multiplies its 16 columns of the
+    group's scores and of its probabilities), against the dense gather of
+    the same quantized content."""
+    from dynamo_tpu.models.llama import _gather_kv
+
+    t, nblk, rows = _WALK_CASES[case]
+    rng = np.random.default_rng(37)
+    b, rep, d, bs = len(rows), 4, 128, 16
+    nb = b * nblk + 1
+    kc, vc = (_whole_cache(rng, kv, 1, nb, bs, kh, d) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((b, t, kh * rep, d)), jnp.bfloat16)
+    bt = jnp.asarray(rng.permutation(nb - 1)[: b * nblk].reshape(b, nblk) + 1,
+                     jnp.int32)
+    q_start = jnp.asarray([r[0] for r in rows], jnp.int32)
+    q_len = jnp.asarray([r[1] for r in rows], jnp.int32)
+    out = paged_attention_kernel(q, kc, vc, bt, q_start, q_start + q_len,
+                                 layer=0, interpret=True)
+    ref = paged_attention(
+        q.astype(jnp.float32), _gather_kv(kc, bt, 0).astype(jnp.float32),
+        _gather_kv(vc, bt, 0).astype(jnp.float32),
+        q_start[:, None] + jnp.arange(t)[None, :], q_start + q_len)
+    _assert_live_rows_match(out, ref, q_len, 2e-2)
 
 
 def test_paged_attention_kernel_ragged_lengths():
@@ -109,9 +195,10 @@ def test_paged_attention_kernel_qchunked_matches_dense(monkeypatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("num_splits", [1, 2], ids=["sequential", "split_k"])
-def test_paged_attention_kernel_leaves_padded_query_chunks(monkeypatch,
-                                                           num_splits):
+@pytest.mark.parametrize("group_keys", [32, None],
+                         ids=["groups-of-2", "one-group"])
+def test_paged_attention_kernel_leaves_padded_query_chunks(
+        monkeypatch, group_keys):
     """Rows of a mixed step in one [B, T] rectangle: a full chunk, a chunk
     cut short, a one-token row deep in its context, a padding row. Every
     live position equals the dense path; a query chunk that holds padding
@@ -128,6 +215,9 @@ def test_paged_attention_kernel_leaves_padded_query_chunks(monkeypatch,
     ref = np.asarray(_dense_ref(q, k_cache, v_cache, block_tables, q_start,
                                 q_len))
     monkeypatch.setattr(pa, "_SCRATCH_CAP_BYTES", 48 * 1024, raising=False)
+    if group_keys:      # two groups under the deepest chunk, not one
+        monkeypatch.setattr(pa, "_GROUP_KEYS", group_keys)
+        monkeypatch.setattr(pa, "_GROUP_KEYS_WIDE", group_keys)
     grids = []
     real_call = pa.pl.pallas_call
     monkeypatch.setattr(
@@ -137,9 +227,9 @@ def test_paged_attention_kernel_leaves_padded_query_chunks(monkeypatch,
             real_call(kernel, *a, grid_spec=grid_spec, **kw))[1])
     out = np.asarray(pa.paged_attention_kernel(
         q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=num_splits, interpret=True))
-    nq = grids[0][1]
-    assert nq == 4 and grids[0][2] == num_splits, grids
+        interpret=True))
+    (_, nq), = grids                      # (row, query chunk): no third axis
+    assert nq == 4, grids
     tq = t // nq
     for row, n in enumerate(np.asarray(q_len)):
         np.testing.assert_allclose(out[row, :n], ref[row, :n],
@@ -234,17 +324,23 @@ def _spoil_other_layers(cache, layer):
 
 
 @pytest.mark.parametrize("t", [1, 8], ids=["decode", "chunk"])
-@pytest.mark.parametrize("ns", [1, 2], ids=["nosplit", "split2"])
+@pytest.mark.parametrize("width", [5, 512], ids=["tight", "max_nblk"])
 @pytest.mark.parametrize("kv", ["bfloat16", "int8", "int4"])
 @pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
-def test_kernel_addresses_one_layer_of_the_whole_cache(layer, kv, ns, t):
+def test_kernel_addresses_one_layer_of_the_whole_cache(small_groups, layer,
+                                                       kv, width, t):
     """The kernel on the whole [L, NB, ...] cache with layer index ``l`` is
     the kernel on that layer's slice, bit for bit, and the dense reference
-    within tolerance; what the other layers hold does not matter."""
+    within tolerance; what the other layers hold does not matter, and
+    neither does the table's width: handed ``max_nblk`` entries a row (what
+    every step program is compiled for) of which 1, 2 and 5 are live, it
+    gives what the tight table gives."""
     from dynamo_tpu.models.llama import _gather_kv
 
-    nl, b, h, kh, d, nb, bs, nblk = 3, 3, 4, 2, 64, 16, 16, 4
-    rng = np.random.default_rng(100 * layer + 10 * ns + t)
+    # Five blocks a row in groups of two: 1, 2 and 5 used blocks (a group
+    # and a half group, one group, two and a half).
+    nl, b, h, kh, d, nb, bs, nblk = 3, 3, 4, 2, 64, 16, 16, 5
+    rng = np.random.default_rng(100 * layer + 10 + t)
     kc = _whole_cache(rng, kv, nl, nb, bs, kh, d)
     vc = _whole_cache(rng, kv, nl, nb, bs, kh, d)
     q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.bfloat16)
@@ -252,16 +348,18 @@ def test_kernel_addresses_one_layer_of_the_whole_cache(layer, kv, ns, t):
     bt = jnp.asarray(ids, jnp.int32)
     q_start = jnp.asarray([0, 21, nblk * bs - t], jnp.int32)    # ragged
     kv_lens = q_start + t
+    # As dispatch() fills a wide table: the row's blocks, then zeros.
+    wide = jnp.zeros((b, width), jnp.int32).at[:, :nblk].set(bt)
 
     def kernel(k, v, **kw):
         return np.asarray(paged_attention_kernel(
-            q, k, v, bt, q_start, kv_lens, num_splits=ns, interpret=True,
+            q, k, v, bt, q_start, kv_lens, interpret=True,
             **kw).astype(jnp.float32))
 
     # The layer index traced, as inside the model's scan.
     whole = np.asarray(jax.jit(
         lambda k, v, l: paged_attention_kernel(
-            q, k, v, bt, q_start, kv_lens, layer=l, num_splits=ns,
+            q, k, v, wide, q_start, kv_lens, layer=l,
             interpret=True))(kc, vc, jnp.int32(layer)).astype(jnp.float32))
     one = jax.tree.map(lambda a: a[layer], (kc, vc))
     np.testing.assert_array_equal(whole, kernel(*one))
@@ -285,34 +383,80 @@ def v5e_device():
         platform="tpu", topology_name="v5e:2x2").devices[0]
 
 
-@pytest.mark.parametrize("b,t,nblk,nb,kv,ns,compiles", [
-    pytest.param(32, 1, 512, 18000, "bfloat16", 1, True, id="bf16-decode"),
+#: What a Pallas kernel may hold in VMEM on a v5e unless it asks for more
+#: (``vmem_limit_bytes``, which this kernel does not set): 16 MiB of 128.
+V5E_SCOPED_VMEM_BYTES = 16 << 20
+
+
+def _vmem_bytes(grid_spec, blocks):
+    """VMEM the kernel holds: its scratch buffers, and two of each block
+    the pipeline moves for it (``blocks``: (shape, dtype)). The last two
+    dims pad to the dtype's (sublane, 128) tile."""
+    def padded(shape, dtype):
+        item = jnp.dtype(dtype).itemsize
+        sub = {4: 8, 2: 16, 1: 32}[item]
+        *lead, s2, s1 = shape
+        return (int(np.prod(lead)) * -(-s2 // sub) * sub
+                * -(-s1 // 128) * 128 * item)
+
+    scratch = sum(padded(m.shape, m.dtype) for m in grid_spec.scratch_shapes
+                  if "vmem" in str(m.memory_space).lower())
+    return scratch + 2 * sum(padded(shape, dtype) for shape, dtype in blocks)
+
+
+@pytest.mark.parametrize("b,t,nblk,nb,kv,kh,compiles", [
+    pytest.param(32, 1, 512, 18000, "bfloat16", 8, True, id="bf16-decode"),
     # The mixed step's shape: T = prefill_chunk, decode rows one token of it.
-    pytest.param(8, 512, 512, 18000, "bfloat16", 1, True, id="bf16-chunk512"),
-    pytest.param(1, 1, 512, 18000, "bfloat16", 8, True, id="bf16-split-k"),
-    pytest.param(32, 1, 16, 449, "int8", 1, True, id="int8-small-pool"),
+    pytest.param(8, 512, 512, 18000, "bfloat16", 8, True, id="bf16-chunk512"),
+    # The cells' own step programs, every one at the table's one width
+    # (max_model_len 8192 / 16): the first and the widest of the decode
+    # ladder and the full chunk, over the 7B cut's pool and the Nemo cut's
+    # (both 32 Q / 8 KV x 128), and at the two KV heads a chip holds under
+    # tp=4.
+    pytest.param(8, 1, 512, 6817, "bfloat16", 8, True, id="7b-b8-decode"),
+    pytest.param(8, 512, 512, 6817, "bfloat16", 8, True, id="7b-b8-t512"),
+    pytest.param(64, 1, 512, 6817, "bfloat16", 8, True, id="7b-b64-decode"),
+    pytest.param(8, 1, 512, 9915, "bfloat16", 8, True, id="nemo-b8-decode"),
+    pytest.param(8, 512, 512, 9915, "bfloat16", 8, True, id="nemo-b8-t512"),
+    pytest.param(64, 1, 512, 9915, "bfloat16", 8, True, id="nemo-b64-decode"),
+    pytest.param(8, 1, 512, 12279, "bfloat16", 2, True, id="tp4-b8-decode"),
+    pytest.param(8, 512, 512, 12279, "bfloat16", 2, True, id="tp4-b8-t512"),
+    pytest.param(32, 1, 16, 449, "int8", 8, True, id="int8-small-pool"),
     # The scale sidecars ride scalar prefetch into SMEM (1 MiB), 512 B a
     # block for K and for V: the compiler refuses the pool, and so must
     # the engine's own arithmetic, at construction.
-    pytest.param(32, 1, 16, 36000, "int8", 1, False, id="int8-pool-refused"),
+    pytest.param(32, 1, 16, 36000, "int8", 8, False, id="int8-pool-refused"),
 ])
-def test_kernel_compiles_for_v5e(v5e_device, b, t, nblk, nb, kv, ns, compiles):
+def test_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, nblk, nb, kv,
+                                 kh, compiles):
     """Mosaic itself, at the llama-3-8b geometry (32 Q / 8 KV heads x 128,
-    block 16), judges the kernel's block shapes and memory — and the
+    block 16: the benchmark's cuts have it too), judges the kernel's copies, loads and memory — and the
     engine's SMEM arithmetic (ModelRunner._check_kernel_fits) has to agree
-    with it on which pools fit."""
+    with it on which pools fit. The kernel's VMEM (two groups of K and of
+    V, the softmax state, the query and output blocks) stays under the
+    scoped limit it runs with."""
     from jax.sharding import SingleDeviceSharding
 
+    import dynamo_tpu.ops.paged_attention as pa
     from dynamo_tpu.ops.paged_attention import (
         SMEM_USABLE_BYTES,
         scalar_prefetch_bytes,
     )
 
-    kh, h, d, bs = 8, 32, 128, 16
+    h, d, bs = 4 * kh, 128, 16
     sh = SingleDeviceSharding(v5e_device)
 
     def abstract(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    calls = []
+    real_call = pa.pl.pallas_call
+    monkeypatch.setattr(
+        pa.pl, "pallas_call",
+        lambda kernel, *a, grid_spec=None, compiler_params=None, **kw: (
+            calls.append((grid_spec, compiler_params)),
+            real_call(kernel, *a, grid_spec=grid_spec,
+                      compiler_params=compiler_params, **kw))[1])
 
     # The whole cache and a traced layer index, as the model's layer loop
     # hands them over. What SMEM holds must not grow with the layers: the
@@ -324,7 +468,7 @@ def test_kernel_compiles_for_v5e(v5e_device, b, t, nblk, nb, kv, ns, compiles):
                  "s": abstract((nl, nb, kh), jnp.float32)}
     lowered = jax.jit(
         lambda q, k, v, bt, qs, kl, layer: paged_attention_kernel(
-            q, k, v, bt, qs, kl, layer=layer, num_splits=ns)
+            q, k, v, bt, qs, kl, layer=layer)
     ).lower(abstract((b, t, h, d), jnp.bfloat16), cache, cache,
             abstract((b, nblk), jnp.int32), abstract((b,), jnp.int32),
             abstract((b,), jnp.int32), abstract((), jnp.int32))
@@ -332,6 +476,12 @@ def test_kernel_compiles_for_v5e(v5e_device, b, t, nblk, nb, kv, ns, compiles):
         batch=b, nblk=nblk, num_blocks=nb if kv == "int8" else 0,
         kv_heads=kh) <= SMEM_USABLE_BYTES
     assert fits == compiles
+    (grid_spec, params), = calls
+    assert len(grid_spec.grid) == 2          # (row, query chunk): no walk axis
+    assert params.vmem_limit_bytes is None
+    rchunk = grid_spec.in_specs[0].block_shape[2]
+    blocks = 2 * [((kh, rchunk, d), jnp.bfloat16)]   # the query slab, the output
+    assert _vmem_bytes(grid_spec, blocks) < V5E_SCOPED_VMEM_BYTES
     if compiles:
         lowered.compile()
     else:
@@ -414,147 +564,6 @@ def test_paged_attention_kernel_parity_bench_shapes_int8_cache():
     assert err < 2e-4, err
 
 
-# -- Split-K flash decode -----------------------------------------------------
-
-@pytest.mark.parametrize("ns", [2, 4])
-def test_split_k_bitwise_equal_sequential_bf16(ns):
-    """The split-K combine must not perturb bf16 decode output at all:
-    partial flash state is f32 and the logsumexp-weighted merge reproduces
-    the sequential accumulator bit-for-bit after the bf16 round."""
-    rng = np.random.default_rng(11)
-    case = _make_case(rng, b=2, t=1, h=8, kh=8, d=128, nb=24, bs=16, nblk=4,
-                      dtype=jnp.bfloat16)
-    q, k_cache, v_cache, block_tables, q_start, q_len = case
-    seq = paged_attention_kernel(
-        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=1, interpret=True)
-    split = paged_attention_kernel(
-        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=ns, interpret=True)
-    np.testing.assert_array_equal(
-        np.asarray(split, np.float32), np.asarray(seq, np.float32))
-
-
-def test_split_k_matches_sequential_f32_tight():
-    """f32 split-K differs from sequential only by combine-order float
-    association — tight allclose, not bitwise."""
-    rng = np.random.default_rng(12)
-    case = _make_case(rng, b=3, t=1, h=4, kh=2, d=64, nb=32, bs=16, nblk=8)
-    q, k_cache, v_cache, block_tables, q_start, q_len = case
-    seq = paged_attention_kernel(
-        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=1, interpret=True)
-    split = paged_attention_kernel(
-        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=4, interpret=True)
-    np.testing.assert_allclose(np.asarray(split), np.asarray(seq),
-                               atol=2e-6, rtol=2e-6)
-
-
-def test_split_k_wildly_ragged_batch_matches_dense():
-    """Ragged rows spanning [1 block, max blocks] under forced split-K:
-    rows whose context ends before a split's range contribute empty
-    partials (m=-inf, l=0) that the combine must ignore."""
-    rng = np.random.default_rng(13)
-    b, t, h, kh, d, nb, bs, nblk = 4, 1, 4, 2, 64, 48, 16, 8
-    q, k_cache, v_cache, block_tables, _, _ = _make_case(
-        rng, b, t, h, kh, d, nb, bs, nblk)
-    # kv_lens 1 (one block, one token) .. 128 (all 8 blocks full)
-    kv_lens = jnp.asarray([1, 16, 63, nblk * bs], jnp.int32)
-    q_start = kv_lens - 1
-    q_len = jnp.ones((b,), jnp.int32)
-    ref = _dense_ref(q, k_cache, v_cache, block_tables, q_start, q_len)
-    for ns in (2, 4, 8):
-        out = paged_attention_kernel(
-            q, k_cache, v_cache, block_tables, q_start, kv_lens,
-            num_splits=ns, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
-
-
-def test_split_k_forced_beyond_nblk_clamps():
-    """An absurd forced num_splits clamps to nblk and still matches."""
-    from dynamo_tpu.ops.paged_attention import resolve_num_splits
-
-    assert resolve_num_splits(999, nblk=4, batch=1, q_chunks=1, q_tokens=1) == 4
-    assert resolve_num_splits(0, nblk=512, batch=1, q_chunks=1, q_tokens=8) == 1
-    rng = np.random.default_rng(14)
-    case = _make_case(rng, b=2, t=1, h=4, kh=2, d=64, nb=16, bs=16, nblk=2)
-    q, k_cache, v_cache, block_tables, q_start, q_len = case
-    seq = paged_attention_kernel(
-        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=1, interpret=True)
-    out = paged_attention_kernel(
-        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=999, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(seq),
-                               atol=2e-6, rtol=2e-6)
-
-
-# -- q-chunked split-K prefill ------------------------------------------------
-
-def test_split_k_prefill_chunk_parity_bench_geometry():
-    """Forced split-K with T>1 query rows (chunked prefill at the bench
-    attention geometry kh=8, d=128) matches the sequential block walk —
-    the satellite that lets long chunked prefills fill idle TensorCores."""
-    rng = np.random.default_rng(21)
-    b, t, h, kh, d, nb, bs, nblk = 1, 8, 8, 8, 128, 20, 16, 16
-    q, k_cache, v_cache, block_tables, _, _ = _make_case(
-        rng, b, t, h, kh, d, nb, bs, nblk)
-    q_start = jnp.asarray([nblk * bs - t], jnp.int32)  # full-context chunk
-    q_len = jnp.full((b,), t, jnp.int32)
-    seq = paged_attention_kernel(
-        q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-        num_splits=1, interpret=True)
-    for ns in (2, 4):
-        out = paged_attention_kernel(
-            q, k_cache, v_cache, block_tables, q_start, q_start + q_len,
-            num_splits=ns, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(seq),
-                                   atol=2e-5, rtol=2e-5)
-    ref = _dense_ref(q, k_cache, v_cache, block_tables, q_start, q_len)
-    np.testing.assert_allclose(np.asarray(seq), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_resolve_num_splits_prefill_cost_model():
-    """The auto gate prices splits with the cost model: q-chunked prefill
-    engages split-K exactly when batch × q-chunks underfills the cores,
-    stays sequential for callers without state geometry (legacy decode
-    call sites), and is clamped by the f32 partial-state VMEM budget."""
-    from dynamo_tpu.obs.costmodel import auto_num_splits
-    from dynamo_tpu.ops.paged_attention import (
-        _SPLIT_STATE_CAP_BYTES,
-        resolve_num_splits,
-    )
-
-    # Decode (t=1) auto behavior is unchanged by the prefill gate.
-    assert resolve_num_splits(
-        0, nblk=32, batch=1, q_chunks=1, q_tokens=1
-    ) == auto_num_splits(32, batch=1)
-    # One row-program on an 8-core chip underfills → the cost model's
-    # split count engages for the prefill chunk.
-    want = auto_num_splits(32, batch=1, q_chunks=1)
-    assert want > 1
-    assert resolve_num_splits(
-        0, nblk=32, batch=1, q_chunks=1, q_tokens=8,
-        state_rows=8, kv_heads=8, head_dim=128) == want
-    # batch × q-chunks already fills the cores → sequential.
-    assert resolve_num_splits(
-        0, nblk=32, batch=8, q_chunks=4, q_tokens=8,
-        state_rows=8, kv_heads=8, head_dim=128) == 1
-    # The f32 partial-state budget caps huge chunks back to sequential.
-    rows = 4096
-    assert rows * 8 * (128 + 256) * 4 > _SPLIT_STATE_CAP_BYTES
-    assert resolve_num_splits(
-        0, nblk=64, batch=1, q_chunks=1, q_tokens=rows,
-        state_rows=rows, kv_heads=8, head_dim=128) == 1
-    # Callers that pass no state geometry (pre-existing call sites) keep
-    # the sequential walk for t>1.
-    assert resolve_num_splits(0, nblk=512, batch=1, q_chunks=1,
-                              q_tokens=8) == 1
-
-
 # -- Packed int4 KV -----------------------------------------------------------
 
 def test_pack_unpack_int4_roundtrip_and_odd_dim():
@@ -604,30 +613,3 @@ def test_paged_attention_kernel_parity_bench_shapes_int4_cache():
                      jax.nn.softmax(scores, axis=-1), vg.astype(jnp.float32))
     err = np.abs(np.asarray(out_kernel) - np.asarray(ref.reshape(b, 1, h, d))).max()
     assert err < 5e-4, err
-
-
-def test_int4_cache_split_k_matches_sequential():
-    """Split-K over a packed-int4 cache matches the sequential kernel on
-    the same quantized content (float-association tolerance)."""
-    from dynamo_tpu.models.llama import _scatter_kv
-
-    rng = np.random.default_rng(17)
-    nb, bs, kh, d, b, h, nblk = 16, 16, 2, 64, 2, 4, 4
-    kc = {"q": jnp.zeros((nb, bs, kh, d // 2), jnp.uint8),
-          "s": jnp.zeros((nb, kh), jnp.float32)}
-    vc = {"q": jnp.zeros((nb, bs, kh, d // 2), jnp.uint8),
-          "s": jnp.zeros((nb, kh), jnp.float32)}
-    ctx = nblk * bs
-    slots = jnp.stack([jnp.arange(ctx), ctx + jnp.arange(ctx)]).astype(jnp.int32)
-    kc = _scatter_kv(kc, jnp.asarray(rng.normal(size=(b, ctx, kh, d)), jnp.float32), slots)
-    vc = _scatter_kv(vc, jnp.asarray(rng.normal(size=(b, ctx, kh, d)), jnp.float32), slots)
-    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
-    bt = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
-    q_start = jnp.full((b,), ctx - 1, jnp.int32)
-    kv_lens = jnp.full((b,), ctx, jnp.int32)
-    seq = paged_attention_kernel(q, kc, vc, bt, q_start, kv_lens,
-                                 num_splits=1, interpret=True)
-    split = paged_attention_kernel(q, kc, vc, bt, q_start, kv_lens,
-                                   num_splits=2, interpret=True)
-    np.testing.assert_allclose(np.asarray(split), np.asarray(seq),
-                               atol=2e-6, rtol=2e-6)

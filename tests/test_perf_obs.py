@@ -70,49 +70,6 @@ def test_paged_attention_cost_int4_quarters_kv_payload():
     assert int4_kv_payload == pytest.approx(0.25 * bf16_kv_payload)
 
 
-def test_paged_attention_cost_split_combine_hand_computed():
-    """num_splits > 1 charges exactly the documented combine formula:
-    8·NS·rows·(D+256) HBM bytes and NS·rows·(2D+8) FLOPs; ns=1 is free."""
-    kw = dict(batch=2, q_tokens=1, num_heads=4, num_kv_heads=2, head_dim=16,
-              kv_len=64, block_size=4)
-    seq = cm.paged_attention_cost(num_splits=1, **kw)
-    split = cm.paged_attention_cost(num_splits=4, **kw)
-    rows = 2 * 1 * 4
-    assert split.hbm_bytes == seq.hbm_bytes + 8 * 4 * rows * (16 + 256)
-    assert split.flops == seq.flops + 4 * rows * (2 * 16 + 8)
-    default = cm.paged_attention_cost(**kw)
-    assert (default.flops, default.hbm_bytes) == (seq.flops, seq.hbm_bytes)
-
-
-def test_model_step_cost_split_combine_scales_with_layers():
-    cfg = resolve_model_config("tiny-llama")
-    kw = dict(tokens=4, logit_rows=4, attn_q_ctx=4 * 16.0, kv_blocks=16.0,
-              block_size=4)
-    seq = cm.total_cost(cm.model_step_cost(cfg, **kw))
-    sp = cm.total_cost(cm.model_step_cost(cfg, attn_num_splits=2, **kw))
-    rows = 4 * cfg.num_heads
-    L = cfg.num_layers
-    assert sp.hbm_bytes == seq.hbm_bytes + 8 * 2 * rows * (cfg.head_dim + 256) * L
-    assert sp.flops == seq.flops + 2 * rows * (2 * cfg.head_dim + 8) * L
-
-
-def test_auto_num_splits_policy():
-    # Short context never splits (the combine would cost more than it saves).
-    assert cm.auto_num_splits(4, batch=1) == 1
-    assert cm.auto_num_splits(3, batch=32) == 1
-    # One long row: split to fill the cores.
-    assert cm.auto_num_splits(512, batch=1) == 8
-    # A batch that already fills the cores stays sequential.
-    assert cm.auto_num_splits(512, batch=8) == 1
-    assert cm.auto_num_splits(512, batch=32) == 1
-    # The split count never shrinks a split below min_blocks_per_split.
-    assert cm.auto_num_splits(8, batch=1) == 2
-    # Query chunks count as existing parallel streams.
-    assert cm.auto_num_splits(512, batch=2, q_chunks=4) == 1
-    # max_splits caps a huge core count.
-    assert cm.auto_num_splits(512, batch=1, core_count=64) == 16
-
-
 def test_dense_matmul_cost_hand_computed():
     c = cm.dense_matmul_cost(8, 16, 32)
     assert c.flops == 2 * 8 * 16 * 32

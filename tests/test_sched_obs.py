@@ -12,6 +12,7 @@ victim spans carrying the culprit request id.
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 
 import numpy as np
 import pytest
@@ -630,3 +631,94 @@ async def test_mocker_hol_stall_is_the_chunks_marginal_share(clean_ledger):
         assert 0.0 < r.hol_stall_s < r.wall_s
     assert led.hol_stall_seconds_total == pytest.approx(
         sum(r.interference_row_s for r in stalled))
+
+
+# ---------------------------------------------------------------------------
+# kv_blocks_live: the blocks a step's rows hold, beside the table's width
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module", params=["dense", "pallas_interpret"],
+                ids=["gather", "kernel"])
+def steps_with_blocks(request):
+    """One run of the tiny preset on each attention path: a decoder, then
+    two prompts of two full chunks each arriving together (their step
+    overflows one token bucket and goes out as two programs). Every step's
+    batches beside what the ledger filed and what the ``engine.dispatch``
+    span carried for it."""
+    import dynamo_tpu.engine.engine as eng
+    from dynamo_tpu.engine.engine import EngineCore
+    from dynamo_tpu.obs.profiler import loop_phase
+
+    mp = pytest.MonkeyPatch()
+    seen, spans = [], []
+    real_geometry, real_set = eng.step_geometry, loop_phase.set
+
+    def geometry(mc, ec, batches, **kw):
+        g = real_geometry(mc, ec, batches, **kw)
+        seen.append((batches, g))
+        return g
+
+    def span_set(self, **attrs):
+        if "kv_blocks_live" in attrs:
+            spans.append(attrs)
+        real_set(self, **attrs)
+
+    mp.setattr(eng, "step_geometry", geometry)
+    mp.setattr(loop_phase, "set", span_set)
+    led = get_sched_ledger()
+    led.reset()
+    led.configure(True)
+    ec = EngineConfig(model="tiny-llama", block_size=16, num_blocks=64,
+                      max_batch_size=4, max_model_len=128, prefill_chunk=16,
+                      attn_impl=request.param, allow_random_weights=True)
+    core = EngineCore(ec)
+    core.add_request(_req(range(10, 31), max_tokens=24))
+    for _ in range(8):
+        core.step()
+    core.add_request(_req(range(100, 140), max_tokens=3))
+    core.add_request(_req(range(200, 240), max_tokens=3))
+    for _ in range(200):
+        if not core.has_work():
+            break
+        core.step()
+    assert not core.has_work()
+    total = led.snapshot()["kv_blocks_live_total"]
+    mp.undo()
+    return core, seen, spans, total
+
+
+@pytest.mark.parametrize("step", ["decode", "one-chunk-mixed", "split"])
+def test_kv_blocks_live_counts_what_the_rows_hold(steps_with_blocks, step):
+    core, seen, spans, total = steps_with_blocks
+    ec, mc = core.engine_cfg, core.model_cfg
+    kernel = ec.attn_impl == "pallas_interpret"
+    pick = {
+        "decode": lambda b: len(b) == 1 and b[0][0].kind == "decode",
+        "one-chunk-mixed": lambda b: (
+            len(b) == 1 and b[0][0].kind == "mixed"
+            and sum(r[2] > 1 for r in b[0][1]) == 1),
+        "split": lambda b: len(b) > 1,
+    }[step]
+
+    def by_hand(batches):
+        return sum(-(-(start + length) // 16)
+                   for _, rows, *_ in batches for _, start, length in rows)
+
+    hits = [(b, g) for b, g in seen if pick(b)]
+    assert hits, [[(s.kind, s.b, s.t) for s, *_ in b] for b, _ in seen]
+    for batches, g in hits:
+        assert g["kv_blocks_live"] == by_hand(batches) > 0
+        # The table's width beside it: one under the kernel, and then it
+        # prices nothing (a wider table is the same step); the gather pays
+        # for every entry of its bucket.
+        assert {s.nblk for s, *_ in batches} <= ({8} if kernel else {4, 8})
+        wider = [(dataclasses.replace(b[0], nblk=800), *b[1:]) for b in batches]
+        again = step_geometry(mc, ec, wider)
+        assert (again["sched_flops"] == g["sched_flops"]) == kernel
+        assert g["sched_flops"] >= g["live_flops"] > 0
+    assert total == sum(by_hand(b) for b, _ in seen)
+    # The span is set at dispatch, the record filed at finalize: the same
+    # steps in the same order, the same count.
+    assert [a["kv_blocks_live"] for a in spans] == [
+        g["kv_blocks_live"] for _, g in seen]
+    assert all(a["nblk"] in (4, 8) for a in spans)

@@ -8,12 +8,16 @@ scheduler, cut into programs as ``EngineCore`` cuts it (``pack_rows``) and
 bucketed by ``ModelRunner.bucket_of`` (what dispatch() calls), mints no
 ``(kind, b, t, nblk, N)`` outside that set; the set is no larger than it
 was; no program is sent more live tokens than its bucket N; and the
-scheduling ledger counts N a program. Nothing here runs a model: no number
-is a measurement.
+scheduling ledger counts N a program. Held on both attention paths: the
+kernel walks a row's live blocks whatever the table's width, so every
+program has the one width ``max_nblk`` and a cell warms 14; the dense
+gather pays for every entry and keeps the pow2 ladder of widths (64 / 48 /
+64). Nothing here runs a model: no number is a measurement.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import sys
 import types
 from pathlib import Path
@@ -42,9 +46,14 @@ from dynamo_tpu.protocols.common import (  # noqa: E402
     StopConditions,
 )
 
-# Warmed programs a cell, as the accepted benchmark had them (PERF.md).
+# Warmed programs a cell under the dense gather's ladder of table widths, as
+# the accepted benchmark had them on the chip until PR 35 (PERF.md), and
+# under the kernel: (rows 8, 16) x (decode + six chunk buckets), one width.
 WARMED = {"mistral-7b.chat": 64, "mistral-7b.longprompt": 48,
           "mistral-nemo-12b.chat": 64}
+WARMED_KERNEL = 14
+# What EngineCore resolves EngineConfig.attn_impl to: on a TPU, elsewhere.
+PATHS = {"kernel": "pallas", "gather": "dense"}
 # Seconds a step takes in the replay: (a decode step, each chunk token on
 # top). Two clocks, because which prompts coincide in a step depends on how
 # long the steps before took: about the parent's step and about this one.
@@ -105,16 +114,18 @@ def _replay(cell, ec, order: int, clock: tuple[float, float]):
     return programs, steps
 
 
-@pytest.fixture(scope="module")
-def cells():
+@pytest.fixture(scope="module", params=sorted(PATHS))
+def cells(request):
     bench = manifest.load_benchmark()
     out = {}
     for name in WARMED:
         cell = manifest.load_cell(name, bench)
-        ec = sut.engine_config(cell.config_dir, cell.about)
+        ec = dataclasses.replace(sut.engine_config(cell.config_dir, cell.about),
+                                 attn_impl=PATHS[request.param])
         warmed = {(s.kind, s.b, s.t, s.nblk, s.n)
                   for s in sut.reachable_buckets(cell.traffic, ec)}
         out[name] = (cell, ec, warmed)
+    out["path"] = request.param
     return out
 
 
@@ -127,7 +138,13 @@ def test_benchmark_has_the_cells_held_here():
 def test_a_cell_warms_no_more_programs_than_before(cells, name):
     cell, ec, warmed = cells[name]
     assert len(warmed) == len(sut.reachable_buckets(cell.traffic, ec))
-    assert len(warmed) <= WARMED[name]
+    max_nblk = -(-ec.max_model_len // ec.block_size)
+    if cells["path"] == "kernel":
+        assert len(warmed) == WARMED_KERNEL
+        assert {s[3] for s in warmed} == {max_nblk}
+    else:
+        assert len(warmed) == WARMED[name]
+        assert len({s[3] for s in warmed}) > 3       # the ladder of widths
     # N follows from (kind, b, t): the lattice has no dimension for it.
     assert len({s[:4] for s in warmed}) == len(warmed)
 
