@@ -83,6 +83,7 @@ from dynamo_tpu.obs.sched_ledger import (
     HolStall,
     get_sched_ledger,
     kv_blocks_live,
+    kv_blocks_walked,
     step_geometry,
 )
 from dynamo_tpu.obs.tracer import get_tracer, trace_context_of
@@ -179,6 +180,11 @@ class EngineMetrics:
     # by tp (set once): what a reader of a device trace looks for to find
     # the operations that move the cache.
     kv_cache_shape: tuple[int, ...] = ()
+    # A routed model served as a chip's share (moe_impl "held"; set once):
+    # experts held, the router's width, experts a token, and the matrices'
+    # shapes a reader of a device trace finds the routed layer's operations
+    # by (one expert stack's, the router's, the shared expert's).
+    moe: dict | None = None
     # The pool as sized (set once): its blocks, one block's bytes on each
     # device (K and V), and the bytes a step holds for each block of the
     # pool BEYOND the pool itself, from XLA's buffer assignment of the
@@ -203,6 +209,7 @@ class EngineMetrics:
             "kv_cache_bytes": self.kv_cache_bytes,
             "kv_quant_enabled": self.kv_quant_enabled,
             "kv_cache_shape": list(self.kv_cache_shape),
+            **({"moe": self.moe} if self.moe else {}),
             "kv_pool_blocks": self.kv_pool_blocks,
             "kv_block_bytes": self.kv_block_bytes,
             "kv_step_copy_bytes_per_block": self.kv_step_copy_bytes_per_block,
@@ -236,6 +243,9 @@ class PendingStep:
     the rows' proposal chunks."""
 
     batches: list[tuple[BucketSig, list, list, Any, Any]] = field(default_factory=list)
+    # Beside each batch: the device int32 [3] of its routed layers' counts
+    # (models/moe.py held_rows), None where the program has none.
+    moe: list = field(default_factory=list)
     # Scheduling-ledger context captured at plan time (token-budget
     # utilization, HOL victim list) — consumed by _record_step at
     # finalize. None when DYN_SCHED_LEDGER=0.
@@ -410,6 +420,18 @@ class ModelRunner:
                      sp, self.ring_threshold,
                      "" if engine_cfg.ring_prefill_threshold
                      else " (cost-model auto)")
+
+    @property
+    def moe_impl(self) -> str:
+        """The routed layer's formulation (models/moe.py): across an
+        "expert" axis the dropless sharded one; on one chip that holds a
+        share of the published experts the grouped one, which computes the
+        rows routed to the held experts and counts them; else every held
+        expert for every token (llama.moe_mlp: exact, and E/k times the
+        work, for the tiny presets that hold all of a few experts)."""
+        if self.engine_cfg.ep > 1:
+            return "ep"
+        return "held" if self.cfg.holds_share else "dense"
 
     def _place(self, x):
         """Replicate onto the mesh (global array) or leave as-is off-mesh."""
@@ -619,7 +641,7 @@ class ModelRunner:
         trash_row = self.engine_cfg.max_batch_size
 
         attn_impl = self.attn_impl
-        moe_impl = "ep" if self.engine_cfg.ep > 1 else "dense"
+        moe_impl = self.moe_impl
         mesh = self.mesh
         pp_micro = self.engine_cfg.pp_microbatches
         # dispatch() sends no batch with more live tokens than this
@@ -639,13 +661,14 @@ class ModelRunner:
             emb_override = rest.pop(0) if mm else None
             emb_mask = rest.pop(0) if mm else None
             logit_mask = rest.pop(0) if masked else None
-            hidden, ck, cv = llama.forward(params, cfg, tokens, q_start, q_len, bt, ck, cv,
-                                           attn_impl=attn_impl, moe_impl=moe_impl,
-                                           mesh=mesh, sp_prefill=sp_prefill,
-                                           embed_override=emb_override,
-                                           embed_mask=emb_mask,
-                                           pp_microbatches=pp_micro,
-                                           num_tokens=n_tok)
+            hidden, ck, cv, *moe = llama.forward(
+                params, cfg, tokens, q_start, q_len, bt, ck, cv,
+                attn_impl=attn_impl, moe_impl=moe_impl,
+                mesh=mesh, sp_prefill=sp_prefill,
+                embed_override=emb_override,
+                embed_mask=emb_mask,
+                pp_microbatches=pp_micro,
+                num_tokens=n_tok, moe_counts=moe_impl == "held")
             logits = llama.logits_from_hidden(params, cfg, hidden).astype(jnp.float32)
             if masked:
                 # Structured output (engine/guided.py): the grammar's
@@ -674,7 +697,9 @@ class ModelRunner:
                     counts = counts.at[write_slots].set(new_counts)
                     keys = keys.at[write_slots].set(new_keys)
             slot_toks = slot_toks.at[write_slots].set(toks)
-            return ck, cv, counts, keys, slot_toks, toks, lps
+            # (*moe: the routed layers' counts under moe_impl="held", a
+            # last output that a program without them does not have.)
+            return ck, cv, counts, keys, slot_toks, toks, lps, *moe
 
         name = (f"step_decode_b{b}_n{nblk}" if t == 1
                 else f"step_mixed_b{b}_t{t}_k{n_tok}_n{nblk}")
@@ -693,7 +718,8 @@ class ModelRunner:
         if self.mesh is None:
             return {}
         repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
-        return {"out_shardings": (cache, cache, repl, repl, repl, repl, repl)}
+        return {"out_shardings": (cache, cache, repl, repl, repl, repl, repl)
+                + ((repl,) if self.moe_impl == "held" else ())}
 
     def step_fn(self, b: int, t: int, nblk: int, sp_prefill: bool = False,
                 fast_greedy: bool = False, mm: bool = False,
@@ -755,10 +781,11 @@ class ModelRunner:
         rows: list[tuple[Seq, int, int]],  # (seq, start, length) per row
         sample_rows: list[bool],
         masks: list | None = None,  # per-row bool[V] allow-masks (guided)
-    ) -> tuple[BucketSig, jax.Array, jax.Array]:
+    ) -> tuple[BucketSig, jax.Array, jax.Array, "jax.Array | None"]:
         """Enqueue one bucketed step on the device WITHOUT blocking; returns
         the signature of the program it ran and device arrays (tokens [B],
-        logprobs likewise) still being computed. The caller overlaps host
+        logprobs likewise, and the routed layers' counts int32 [3] or None)
+        still being computed. The caller overlaps host
         work (scheduling, output assembly for earlier steps) with the
         device, then materializes via ``np.asarray``. A batch whose longest
         row is one token is the decode program; anything else is the ragged
@@ -898,7 +925,7 @@ class ModelRunner:
             t_compile = time.perf_counter()
         with self._compile_phase(miss, kind, b, t, nblk):
             (self.cache_k, self.cache_v, self.counts, self.keys,
-             self.slot_toks, toks, lps) = fn(
+             self.slot_toks, toks, lps, *moe) = fn(
                 self.params, self.cache_k, self.cache_v, self.counts,
                 self.keys, self.slot_toks,
                 place(tokens), place(q_start), place(q_len),
@@ -914,7 +941,7 @@ class ModelRunner:
                 sig, dt,
                 trace_ctx=next((s.trace_ctx for s, _, _ in rows
                                 if s.trace_ctx is not None), None))
-        return sig, toks, lps
+        return sig, toks, lps, (moe[0] if moe else None)
 
     def _compile_phase(self, miss: bool, kind: str, b: int, t: int,
                        nblk: int):
@@ -936,7 +963,7 @@ class ModelRunner:
         written (rejected positions are overwritten by later true tokens)."""
         cfg = self.cfg
         attn_impl = self.attn_impl
-        moe_impl = "ep" if self.engine_cfg.ep > 1 else "dense"
+        moe_impl = self.moe_impl
         mesh = self.mesh
 
         def verify(params, ck, cv, tokens, q_start, q_len, bt):
@@ -1137,7 +1164,7 @@ class ModelRunner:
                 return True
             fn = self.step_fn(b, t, nblk, False, sig.greedy, False, False)
             (self.cache_k, self.cache_v, self.counts, self.keys,
-             self.slot_toks, toks, _lps) = fn(
+             self.slot_toks, toks, *_rest) = fn(
                 self.params, self.cache_k, self.cache_v, self.counts,
                 self.keys, self.slot_toks, *self._padding_inputs(b, t, nblk))
             np.asarray(toks)
@@ -1224,6 +1251,27 @@ class EngineCore:
                 f"kv_dtype=int4 packs two nibbles per byte along head_dim and "
                 f"needs it even; model {engine_cfg.model!r} has head_dim="
                 f"{self.model_cfg.head_dim}")
+        mc = self.model_cfg
+        patterned = bool(mc.first_k_dense or len(mc.layer_period) > 1)
+        if mc.holds_share and engine_cfg.ep > 1:
+            raise ValueError(
+                f"model {engine_cfg.model!r} holds {mc.num_experts} of "
+                f"{mc.router_width} routed experts: that is one chip's share "
+                f"of an expert-parallel deployment, and ep={engine_cfg.ep} "
+                "would divide it again; give the whole model to ep > 1")
+        if patterned and engine_cfg.pp > 1:
+            raise ValueError(
+                "pipeline stages take equal stacks of identical layers: a "
+                "model with leading dense layers or a pattern of sliding and "
+                f"full layers cannot run at pp={engine_cfg.pp}")
+        if mc.sliding_window and engine_cfg.sp > 1:
+            raise ValueError("ring prefill has no window: a model with "
+                             f"sliding layers cannot run at sp={engine_cfg.sp}")
+        # Each layer's window and the routed layers' count: what the
+        # dispatch span's kv_blocks_walked and the step's counts need.
+        self._windows = tuple(mc.window_of(i) for i in range(mc.num_layers))
+        self._routed_layers = (mc.num_layers - mc.first_k_dense
+                               if mc.is_moe else 0)
         # SLO-driven chunk sizing (prefill_chunk=0 = auto): resolve to
         # concrete per-QoS chunks BEFORE bucket enumeration and the
         # scheduler read the config — the prefill t ladder, warmup plan
@@ -1324,6 +1372,7 @@ class EngineCore:
             kv_pool_blocks=self.runner.spec.num_blocks,
             kv_block_bytes=self.runner._block_bytes_per_device(),
             kv_step_copy_bytes_per_block=self.runner.step_copy_bytes_per_block,
+            moe=self._moe_facts(),
         )
         # The engine thread's loop phases (obs/profiler.py loop_phase):
         # always-on seconds per phase, and profiler spans at the same
@@ -1725,6 +1774,23 @@ class EngineCore:
                    for s in list(self._seqs.values())
                    if s.phase is not Phase.FINISHED)
 
+    def _moe_facts(self) -> dict | None:
+        """``stats()["moe"]`` of a model served under ``moe_impl="held"``."""
+        if self.runner.moe_impl != "held":
+            return None
+        mc = self.model_cfg
+        h, m = mc.hidden_size, mc.moe_intermediate_size
+        sm = m * mc.num_shared_experts
+        return {"experts_held": mc.num_experts,
+                "router_width": mc.router_width,
+                "experts_per_token": mc.num_experts_per_tok,
+                "routed_layers": self._routed_layers,
+                "hidden_size": h, "expert_width": m,
+                "bytes_per_param": jnp.dtype(mc.dtype).itemsize,
+                "shapes": [[mc.num_experts, h, m], [mc.num_experts, m, h],
+                           [h, mc.router_width]]
+                + ([[h, sm], [sm, h]] if sm else [])}
+
     def step_begin(self) -> "PendingStep | None":
         """Plan one engine step and DISPATCH it to the device without
         blocking on results. Host-side state is advanced speculatively
@@ -1754,7 +1820,10 @@ class EngineCore:
                          n=sig.n,
                          rows=sum(len(x[1]) for x in pending.batches),
                          kv_blocks_live=kv_blocks_live(
-                             pending.batches, self.engine_cfg.block_size))
+                             pending.batches, self.engine_cfg.block_size),
+                         kv_blocks_walked=kv_blocks_walked(
+                             pending.batches, self.engine_cfg.block_size,
+                             self._windows))
         if self.sched_led.enabled:
             with loop_phase(self.loop_clock, "engine.plan"):
                 pending.sched = self._sched_context(plan)
@@ -1851,6 +1920,7 @@ class EngineCore:
                     seq.verify_inflight = True
                 pending.batches.append(
                     (sig, verify_rows, verify_chunks, toks, lps))
+                pending.moe.append(None)
         pf_rows, pf_sample_rows, pf_masks = [], [], None
         if plan.prefill:
             pf_rows = [(w.seq, w.start, w.length) for w in plan.prefill]
@@ -1897,7 +1967,7 @@ class EngineCore:
         for rows, sample_rows, b_masks in batches:
             for lo, k in _runs(pack_rows([r[2] for r in rows], ec)):
                 run, samples = rows[lo:lo + k], sample_rows[lo:lo + k]
-                sig, toks, lps = self.runner.dispatch(
+                sig, toks, lps, moe = self.runner.dispatch(
                     run, samples, masks=b_masks and b_masks[lo:lo + k])
                 # Value-independent bookkeeping, done at dispatch so the
                 # next plan() sees advanced positions. Token metrics count
@@ -1908,6 +1978,7 @@ class EngineCore:
                     if sampled:
                         seq.inflight_samples += 1
                 pending.batches.append((sig, run, samples, toks, lps))
+                pending.moe.append(moe)
         return pending
 
     def _sched_context(self, plan: StepPlan) -> dict:
@@ -2015,8 +2086,11 @@ class EngineCore:
             attrs["tokens"] = seq.trace_tokens
         get_tracer().end_span(sp, status=status, **attrs)
 
-    def _record_step(self, t0: float, pending: "PendingStep") -> None:
-        """Always-on step profile: one ring append per engine step."""
+    def _record_step(self, t0: float, pending: "PendingStep",
+                     moe: list | None = None) -> None:
+        """Always-on step profile: one ring append per engine step. ``moe``:
+        the step's routed-layer counts (layer steps, rows, experts touched,
+        largest groups), where its programs gave any."""
         n_dec = pending.dec_rows + sum(
             len(rows) for sig, rows, *_ in pending.batches
             if sig.kind == "verify")
@@ -2038,7 +2112,7 @@ class EngineCore:
                 wall_s=wall,
                 budget_util=info.get("budget_util", 0.0),
                 queue_depths=self.sched.waiting.depths(),
-                hol=info.get("hol"),
+                hol=info.get("hol"), moe=moe and tuple(moe),
                 **step_geometry(self.model_cfg, self.engine_cfg,
                                 pending.batches, dec_rows=pending.dec_rows))
         if self.mem_led.enabled:
@@ -2162,11 +2236,19 @@ class EngineCore:
         clock = self.loop_clock
         outputs: dict[str, LLMEngineOutput] = {}
         dec_left = pending.dec_rows
-        for sig, rows, sample_rows, toks_dev, lps_dev in pending.batches:
+        moe = None
+        for (sig, rows, sample_rows, toks_dev, lps_dev), moe_dev in zip(
+                pending.batches, pending.moe, strict=True):
             with loop_phase(clock, "engine.finalize.wait"):
                 # The host blocks here until the device has run the step.
                 toks = np.asarray(toks_dev)
                 lps = np.asarray(lps_dev)
+                if moe_dev is not None:
+                    # The same program's output: it is there with the tokens.
+                    moe = (moe or [0, 0, 0, 0])
+                    moe[0] += self._routed_layers
+                    for i, x in enumerate(np.asarray(moe_dev)):
+                        moe[i + 1] += int(x)
             with loop_phase(clock, "engine.finalize.host"):
                 if sig.kind == "verify":
                     self._finalize_verify(rows, sample_rows, toks, lps,
@@ -2176,7 +2258,7 @@ class EngineCore:
                                      dec_left, outputs)
             dec_left = max(dec_left - len(rows), 0)
         with loop_phase(clock, "engine.record"):
-            self._record_step(t0, pending)
+            self._record_step(t0, pending, moe)
         if self.kvbm is not None and not self.sched.has_work():
             # Engine going idle: this finalize's commits would otherwise sit
             # in the publish-on-commit queue until the next step_begin —

@@ -34,12 +34,87 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     num_shared_experts: int = 0
+    # The router's width where this is one chip's share of an
+    # expert-parallel deployment: it routes over the published experts and
+    # holds the first ``num_experts`` of them (0 = it holds them all).
+    num_experts_published: int = 0
+    # "softmax": softmax over the chosen logits. "sigmoid": sigmoid scores,
+    # chosen by score (+ ``router_bias``, a learned selection bias that
+    # weighs nothing), the chosen scores normalised to sum 1
+    # (``norm_topk_prob``) and scaled by ``routed_scaling_factor``.
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # Leading layers whose FFN is dense (width ``intermediate_size``) in a
+    # model whose other layers are routed.
+    first_k_dense: int = 0
+    # Per-layer attention kind, "full_attention" | "sliding_attention"
+    # (HF's words); () = every layer full. A sliding layer's query i sees
+    # the keys j with i - j < ``sliding_window``.
+    layer_types: tuple[str, ...] = ()
+    sliding_window: int = 0
+    # Layers in one period of the pattern where the model states it (HF's
+    # ``sliding_window_pattern`` "LLLG" is 4); 0 = the shortest that fits.
+    pattern_len: int = 0
+    qk_norm: bool = False        # RMSNorm over each head of q and k
+    rope_scope: str = "all"      # "sliding": full-attention layers carry no position
+    # "pre": h + f(norm(h)) (Llama). "post": h + norm(f(h)), the sub-layer's
+    # input not normalised (EXAONE 4.0's block); the same two leaves.
+    norm_placement: str = "pre"
     # Multimodal (vision encoder attached)
     vision: "VisionConfig | None" = None
+
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.num_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers of "
+                f"{self.num_layers}")
+        if self.num_experts_published and (
+                self.num_experts_published % self.num_experts):
+            raise ValueError(
+                f"{self.num_experts} experts held of "
+                f"{self.num_experts_published} published: the held count "
+                "has to divide the published one (equal shares of an "
+                "expert-parallel deployment)")
+        if self.first_k_dense and not self.is_moe:
+            raise ValueError("first_k_dense leading dense layers in a model "
+                             "with no routed layer: leave it 0")
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: the published count."""
+        return self.num_experts_published or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """Whether the expert layer holds fewer experts than it routes over."""
+        return 0 < self.num_experts < self.router_width
+
+    def window_of(self, layer: int) -> int:
+        """Layer ``layer``'s attention window, 0 = full."""
+        if self.layer_types and self.layer_types[layer] == "sliding_attention":
+            return self.sliding_window
+        return 0
+
+    @property
+    def layer_period(self) -> tuple[int, ...]:
+        """The windows of one period of the layers behind the leading dense
+        ones: the shortest pattern whose repetition (cut at the end) they
+        are. ``(0,)`` for a model of identical layers."""
+        kinds = [self.window_of(i)
+                 for i in range(self.first_k_dense, self.num_layers)]
+        # A depth cut to one period fits a shorter pattern too (L L G L is
+        # also L L G and one more): the stated length decides where it fits.
+        for p in (self.pattern_len, *range(1, len(kinds) + 1)):
+            if 0 < p <= len(kinds) and all(
+                    k == kinds[i % p] for i, k in enumerate(kinds)):
+                return tuple(kinds[:p])
+        return (0,)
 
     @property
     def q_size(self) -> int:
@@ -56,15 +131,54 @@ class ModelConfig:
         n_heads = cfg["num_attention_heads"]
         # MoE keys across HF families: mixtral (num_local_experts),
         # deepseek/qwen-moe (n_routed_experts, num_experts).
-        n_experts = (cfg.get("num_local_experts") or cfg.get("n_routed_experts")
-                     or cfg.get("num_experts") or 0)
+        expert_key = next((k for k in ("num_local_experts", "n_routed_experts",
+                                       "num_experts") if cfg.get(k)), None)
+        n_experts = cfg[expert_key] if expert_key else 0
+        # One chip's share of a deployment gives the count held under the
+        # model's own key and the published one beside it
+        # (chipbench/README.md): the router keeps the published width.
+        published = cfg.get(f"{expert_key}_published", 0) if expert_key else 0
+        n_layers = cfg["num_hidden_layers"]
+        if (cfg.get("n_group") or 1) > 1 or (cfg.get("topk_group") or 1) > 1:
+            raise ValueError(
+                "group-limited routing (n_group / topk_group above 1) is not "
+                "implemented: models/moe.py route() chooses over all experts")
+        first_dense = cfg.get("first_k_dense_replace", 0) if n_experts else 0
+        mlp_kinds = cfg.get("mlp_layer_types")
+        if mlp_kinds and list(mlp_kinds[:n_layers]) != (
+                ["dense"] * first_dense + ["sparse"] * (n_layers - first_dense)):
+            raise ValueError(
+                "mlp_layer_types is not first_k_dense_replace dense layers "
+                "followed by sparse ones: no other pattern is implemented")
+        # A window applies where layer_types names sliding layers; a bare
+        # ``sliding_window`` (Mistral v0.1) stays unread, as it always was.
+        kinds = tuple(cfg.get("layer_types") or ())[:n_layers]
+        sliding = "sliding_attention" in kinds
+        rope = cfg.get("rope_parameters") or {}
+        sigmoid = cfg.get("scoring_func") == "sigmoid"
         return cls(
             num_experts=n_experts,
+            num_experts_published=published or 0,
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2 if n_experts else 0),
             moe_intermediate_size=cfg.get(
                 "moe_intermediate_size",
                 cfg["intermediate_size"] if n_experts else 0),
-            num_shared_experts=cfg.get("n_shared_experts", 0) or 0,
+            num_shared_experts=(cfg.get("n_shared_experts")
+                                or cfg.get("num_shared_experts") or 0),
+            router_scoring="sigmoid" if sigmoid else "softmax",
+            router_bias=bool(cfg.get("router_bias", sigmoid)),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(
+                cfg.get("routed_scaling_factor") or 1.0) if sigmoid else 1.0,
+            first_k_dense=first_dense,
+            layer_types=kinds if sliding else (),
+            sliding_window=int(cfg.get("sliding_window") or 0) if sliding else 0,
+            pattern_len=len(cfg.get("sliding_window_pattern") or "")
+            if sliding and isinstance(cfg.get("sliding_window_pattern"), str)
+            else 0,
+            qk_norm=bool(cfg.get("qk_norm", False)),
+            rope_scope=cfg.get("rope_scope", "all"),
+            norm_placement=cfg.get("norm_placement", "pre"),
             name=cfg.get("_name_or_path", Path(path).name),
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
@@ -73,7 +187,8 @@ class ModelConfig:
             num_heads=n_heads,
             num_kv_heads=cfg.get("num_key_value_heads", n_heads),
             head_dim=cfg.get("head_dim", cfg["hidden_size"] // n_heads),
-            rope_theta=cfg.get("rope_theta", 10000.0),
+            rope_theta=float(cfg.get("rope_theta")
+                             or rope.get("rope_theta") or 10000.0),
             rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
             max_position_embeddings=cfg.get("max_position_embeddings", 8192),
             tie_word_embeddings=cfg.get("tie_word_embeddings", False),

@@ -54,8 +54,24 @@ def _dtype(cfg: ModelConfig):
 # Parameter init (shapes + logical sharding axes)
 # ---------------------------------------------------------------------------
 
-def param_logical_axes(cfg: ModelConfig) -> Params:
-    """Logical axis names per parameter leaf (for mesh sharding rules)."""
+LEAD = "lead_"   # prefix of the leading group's leaves under params["layers"]
+
+
+def layer_groups(layers: Params) -> tuple[Params, Params]:
+    """(leading group, repeated group) of ``params["layers"]``. The
+    repeated group is the stacked ``[L_rep, ...]`` leaves under their plain
+    names: the layers that ``_run_layers`` scans. A model whose first
+    ``cfg.first_k_dense`` layers are of another shape (a dense FFN before
+    routed ones) keeps those as a second stack ``[L_lead, ...]`` under
+    ``lead_<name>``, in the same flat dict: every leaf of ``layers`` stays
+    an array, which is what the loaders, the sharding rules and the
+    benchmark's weight rounding walk."""
+    lead = {k[len(LEAD):]: v for k, v in layers.items() if k.startswith(LEAD)}
+    rep = {k: v for k, v in layers.items() if not k.startswith(LEAD)}
+    return lead, rep
+
+
+def _layer_axes(cfg: ModelConfig, routed: bool) -> Params:
     layer = {
         "wq": ("layers", None, "heads"),
         "wk": ("layers", None, "kv_heads"),
@@ -64,13 +80,21 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         "attn_norm": ("layers", None),
         "mlp_norm": ("layers", None),
     }
-    if cfg.is_moe:
+    if cfg.qk_norm:
+        layer.update(q_norm=("layers", None), k_norm=("layers", None))
+    if routed:
         layer.update(
             router=("layers", None, "expert"),
             w_gate=("layers", "expert", None, "moe_mlp"),
             w_up=("layers", "expert", None, "moe_mlp"),
             w_down=("layers", "expert", "moe_mlp", None),
         )
+        if cfg.holds_share:
+            # The router is as wide as the published model; the experts
+            # held are this chip's and are not divided again.
+            layer["router"] = ("layers", None, None)
+        if cfg.router_bias:
+            layer["router_bias"] = ("layers", None)
         if cfg.num_shared_experts:
             layer.update(
                 shared_gate=("layers", None, "mlp"),
@@ -83,6 +107,15 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             w_up=("layers", None, "mlp"),
             w_down=("layers", "mlp", None),
         )
+    return layer
+
+
+def param_logical_axes(cfg: ModelConfig) -> Params:
+    """Logical axis names per parameter leaf (for mesh sharding rules)."""
+    layer = _layer_axes(cfg, cfg.is_moe)
+    if cfg.first_k_dense:
+        layer.update({LEAD + k: v
+                      for k, v in _layer_axes(cfg, False).items()})
     axes: Params = {"embed": ("vocab", None), "final_norm": (None,), "layers": layer}
     if not cfg.tie_word_embeddings:
         axes["lm_head"] = (None, "vocab")
@@ -93,23 +126,37 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random-init params (tests/tiny models; real weights come from loaders)."""
     dt = _dtype(cfg)
     k = iter(jax.random.split(key, 24))
-    h, L = cfg.hidden_size, cfg.num_layers
+    h = cfg.hidden_size
+    L = cfg.num_layers - cfg.first_k_dense     # the repeated group
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
 
-    layer: Params = {
-        "wq": dense(next(k), (L, h, cfg.q_size), h),
-        "wk": dense(next(k), (L, h, cfg.kv_size), h),
-        "wv": dense(next(k), (L, h, cfg.kv_size), h),
-        "wo": dense(next(k), (L, cfg.q_size, h), cfg.q_size),
-        "attn_norm": jnp.ones((L, h), dt),
-        "mlp_norm": jnp.ones((L, h), dt),
-    }
+    def attention(L):
+        out = {
+            "wq": dense(next(k), (L, h, cfg.q_size), h),
+            "wk": dense(next(k), (L, h, cfg.kv_size), h),
+            "wv": dense(next(k), (L, h, cfg.kv_size), h),
+            "wo": dense(next(k), (L, cfg.q_size, h), cfg.q_size),
+            "attn_norm": jnp.ones((L, h), dt),
+            "mlp_norm": jnp.ones((L, h), dt),
+        }
+        if cfg.qk_norm:
+            out.update(q_norm=jnp.ones((L, cfg.head_dim), dt),
+                       k_norm=jnp.ones((L, cfg.head_dim), dt))
+        return out
+
+    def dense_ffn(L):
+        i = cfg.intermediate_size
+        return {"w_gate": dense(next(k), (L, h, i), h),
+                "w_up": dense(next(k), (L, h, i), h),
+                "w_down": dense(next(k), (L, i, h), i)}
+
+    layer: Params = attention(L)
     if cfg.is_moe:
         E, m = cfg.num_experts, cfg.moe_intermediate_size
         layer.update(
-            router=dense(next(k), (L, h, E), h),
+            router=dense(next(k), (L, h, cfg.router_width), h),
             w_gate=dense(next(k), (L, E, h, m), h),
             w_up=dense(next(k), (L, E, h, m), h),
             w_down=dense(next(k), (L, E, m, h), m),
@@ -122,12 +169,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 shared_down=dense(next(k), (L, sm, h), sm),
             )
     else:
-        i = cfg.intermediate_size
-        layer.update(
-            w_gate=dense(next(k), (L, h, i), h),
-            w_up=dense(next(k), (L, h, i), h),
-            w_down=dense(next(k), (L, i, h), i),
-        )
+        layer.update(dense_ffn(L))
     params: Params = {
         "embed": dense(next(k), (cfg.vocab_size, h), h),
         "final_norm": jnp.ones((h,), dt),
@@ -135,6 +177,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = dense(next(k), (h, cfg.vocab_size), h)
+    # Drawn behind everything above, so that a model without them keeps
+    # the weights its seed always gave.
+    if cfg.is_moe and cfg.router_bias:
+        # A selection bias is learned to even the experts' load: small
+        # beside the scores it is added to, and not zero, so that a choice
+        # by score alone reads otherwise.
+        layer["router_bias"] = 0.02 * jax.random.normal(
+            next(k), (L, cfg.router_width), jnp.float32)
+    if cfg.first_k_dense:
+        lead = {**attention(cfg.first_k_dense), **dense_ffn(cfg.first_k_dense)}
+        layer.update({LEAD + name: v for name, v in lead.items()})
     return params
 
 
@@ -297,6 +350,7 @@ def paged_attention(
     ctx_v: jax.Array,       # [B, S, KH, D]
     q_positions: jax.Array,  # [B, T]
     kv_lens: jax.Array,      # [B] total valid context length
+    window: int = 0,         # static; > 0: query i sees keys j, i - j < window
 ) -> jax.Array:
     """Dense attention over gathered paged context with causal position mask.
 
@@ -312,6 +366,8 @@ def paged_attention(
     scores = jnp.einsum("btkrd,bskd->btkrs", qf, ctx_k.astype(jnp.float32))
     ctx_idx = jnp.arange(s)[None, None, :]                      # [1,1,S]
     visible = (ctx_idx <= q_positions[:, :, None]) & (ctx_idx < kv_lens[:, None, None])
+    if window:
+        visible = visible & (ctx_idx > q_positions[:, :, None] - window)
     scores = jnp.where(visible[:, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("btkrs,bskd->btkrd", probs, ctx_v.astype(jnp.float32))
@@ -341,21 +397,24 @@ def swiglu(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
 
 
 def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
-    """MoE FFN, dense-dispatch formulation (every expert computed, combined by
-    top-k router weights). Exact for any E; the EP-sharded ragged-dispatch
-    version lives in models/moe.py and is numerically equivalent.
+    """MoE FFN, all-experts formulation (every held expert computed for
+    every token, combined by the router's weights). Exact for any E and
+    E/k times the work: the plain form the grouped ones are tested against
+    (tests/test_moe.py) and what a tiny preset that holds every expert runs
+    at ep == 1; models/moe.py has the grouped formulations (``held_rows``,
+    what a chip that holds a share runs; the ep-sharded dropless one).
 
     x: [..., H] (token-major [N, H] in the step)
     """
+    from dynamo_tpu.models.moe import route
+
     h = x.shape[-1]
     xt = x.reshape(-1, h)                                     # [N, H]
-    logits = (xt.astype(jnp.float32)) @ lp["router"].astype(jnp.float32)  # [N, E]
-    k = cfg.num_experts_per_tok
-    topv, topi = lax.top_k(logits, k)
-    weights = jax.nn.softmax(topv, axis=-1)                   # [N, k]
-    e = cfg.num_experts
-    gate_mask = jnp.zeros((xt.shape[0], e), jnp.float32)
-    gate_mask = gate_mask.at[jnp.arange(xt.shape[0])[:, None], topi].add(weights)  # [N, E]
+    topi, weights = route(xt, lp, cfg)                        # [N, k]
+    e = lp["w_gate"].shape[0]                                 # experts held
+    gate_mask = jnp.zeros((xt.shape[0], cfg.router_width), jnp.float32)
+    gate_mask = gate_mask.at[jnp.arange(xt.shape[0])[:, None], topi].add(
+        weights)[:, :e]                                       # [N, E]
     # all-experts compute: [N,E,m]
     up = jnp.einsum("nh,ehm->nem", xt, lp["w_up"])
     gate = jnp.einsum("nh,ehm->nem", xt, lp["w_gate"])
@@ -425,29 +484,43 @@ def token_layout(q_len: jax.Array, b: int, t: int, n: int) -> tuple[
 def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
            lay: TokenLayout, positions, slot, block_tables, q_start, kv_lens,
            attn_impl: str = "dense",
-           moe_impl: str = "dense", mesh=None, use_ring: bool = False):
+           moe_impl: str = "dense", mesh=None, use_ring: bool = False,
+           window: int = 0, live=None):
     """One transformer layer over the WHOLE cache ([L,NB,BS,KH,D], or the
     stage-local part of it under pp): writes this step's K/V at
     ``(layer, slot)``, attends over layer ``layer``, returns
-    (hidden, cache_k, cache_v). The one layer body of ``forward`` and of
-    both pp schedules. Nothing here materialises a layer of the cache: the
-    scatter, the kernel's DMAs and the dense gather all address the carried
-    buffer by layer index.
+    (hidden, cache_k, cache_v, counts). The one layer body of ``forward``
+    and of both pp schedules. Nothing here materialises a layer of the
+    cache: the scatter, the kernel's DMAs and the dense gather all address
+    the carried buffer by layer index.
 
     Token-major: ``hid [N, H]``, ``positions`` and ``slot [N]``. Norms,
     the Q/K/V/O projections, rope, the scatter and the MLP run over the N
     tokens; ``q`` alone is laid out as rows ``[B, T, heads, D]`` (``lay``)
     for attention, and the attention output packed back to ``[N, q_size]``.
     Ring attention takes K and V as rows too, and only a rectangle
-    (``N == B*T``), where those moves are reshapes."""
+    (``N == B*T``), where those moves are reshapes.
+
+    What differs from layer to layer is static: ``window`` (> 0: a sliding
+    layer, query i sees the keys j with i - j < window), the FFN's kind
+    (``lp`` has a ``router`` or it is dense) and with them, by the
+    configuration, whether the layer carries positions at all
+    (``rope_scope``). ``counts`` is the routed layer's int32 [3] under
+    ``moe_impl="held"`` (models/moe.py ``held_rows``), else None; ``live``
+    [N] names the bucket's live tokens for it."""
     n = hid.shape[0]
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
-    x = rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
+    post = cfg.norm_placement == "post"
+    x = hid if post else rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
     q = mm(x, lp["wq"]).reshape(n, cfg.num_heads, cfg.head_dim)
     k = mm(x, lp["wk"]).reshape(n, cfg.num_kv_heads, cfg.head_dim)
     v = mm(x, lp["wv"]).reshape(n, cfg.num_kv_heads, cfg.head_dim)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+    if cfg.rope_scope == "all" or window:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     # Phase hooks (obs/profiler.py): jax.named_scope annotations for
     # XLA profiles, plus wall capture in eager profiling runs. Under
     # jit they execute at trace time only — zero ops in the program.
@@ -458,6 +531,9 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     if use_ring:
         from dynamo_tpu.ops.ring_attention import ring_attention_prefill
 
+        if window:
+            raise ValueError("ring attention has no window: a model with "
+                             "sliding layers cannot prefill over 'seq'")
         with _perf_phase("attention"):
             attn = ring_attention_prefill(
                 mesh, q, lay.to_rows(k), lay.to_rows(v), kv_lens)
@@ -474,12 +550,12 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
                 # psum in the wo projection completes the TP contraction.
                 attn = paged_attention_sharded(
                     mesh, q, cache_k, cache_v, block_tables, q_start,
-                    kv_lens, layer=layer, interpret=interp,
+                    kv_lens, layer=layer, interpret=interp, window=window,
                 )
             else:
                 attn = paged_attention_kernel(
                     q, cache_k, cache_v, block_tables, q_start, kv_lens,
-                    layer=layer, interpret=interp,
+                    layer=layer, interpret=interp, window=window,
                 )
     else:
         with _perf_phase("gather"):
@@ -488,12 +564,22 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
         with _perf_phase("attention"):
             attn = paged_attention(
                 q, ctx_k, ctx_v,
-                q_start[:, None] + jnp.arange(lay.t)[None, :], kv_lens)
+                q_start[:, None] + jnp.arange(lay.t)[None, :], kv_lens,
+                window=window)
     attn = lay.to_tokens(attn).reshape(n, cfg.q_size)
-    hid = hid + mm(attn, lp["wo"])
-    x = rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
-    if cfg.is_moe:
-        if moe_impl == "ep":
+    attn = mm(attn, lp["wo"])
+    if post:
+        attn = rms_norm(attn, lp["attn_norm"], cfg.rms_norm_eps)
+    hid = hid + attn
+    x = hid if post else rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
+    counts = None
+    if "router" in lp:
+        if moe_impl == "held":
+            # One chip told which experts it holds: grouped, with counts.
+            from dynamo_tpu.models.moe import moe_mlp_held
+
+            mlp_out, counts = moe_mlp_held(x, lp, cfg, live)
+        elif moe_impl == "ep":
             # Dropless ragged dispatch (serving default for ep>1): exact
             # under any routing skew — see models/moe.py.
             from dynamo_tpu.models.moe import moe_mlp_dropless
@@ -507,25 +593,82 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
             mlp_out = moe_mlp(x, lp, cfg)
     else:
         mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-    return hid + mlp_out, cache_k, cache_v
+    if post:
+        mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.rms_norm_eps)
+    return hid + mlp_out, cache_k, cache_v, counts
 
 
 def _run_layers(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, **kw):
-    """Scan :func:`_layer` over the stacked layer params. The cache enters
-    the loop once, whole, as carried state beside the hidden state; only
-    the params and the layer index ride xs. (The cache must not ride xs→ys:
-    XLA then cuts each layer out, stacks it back and keeps a second K and V
-    as a temporary, which cost over half of a decode step on the v5e —
-    PERF.md section 6.)"""
-    n = jax.tree.leaves(layers)[0].shape[0]
+    """Run the layers: the leading group one by one, then a ``lax.scan``
+    over whole periods of the repeated group's pattern with one period's
+    layers unrolled in the body, then what is left of a last period.
+    Returns (hidden, cache_k, cache_v, counts).
 
-    def layer_fn(carry, xs):
-        lp, layer = xs
-        return _layer(cfg, lp, layer, *carry, **kw), None
+    A model of identical layers is a period of one: the scan of
+    :func:`_layer` over the stacked params that this always was. Where the
+    layers of a period differ (sliding and full attention), the difference
+    is static structure of the body, never a traced branch. The cache
+    enters the loop once, whole, as carried state beside the hidden state,
+    addressed by absolute layer index; only the params and the index ride
+    xs. (The cache must not ride xs→ys: XLA then cuts each layer out, stacks
+    it back and keeps a second K and V as a temporary, which cost over half
+    of a decode step on the v5e — PERF.md section 6.)
 
-    carry, _ = lax.scan(layer_fn, (h, cache_k, cache_v),
-                        (layers, jnp.arange(n, dtype=jnp.int32)))
-    return carry
+    ``counts``: under ``moe_impl="held"`` the routed layers' int32 [3]
+    summed over them, carried beside the cache; else None. There the
+    experts' stacks do not ride xs either and are never cut by layer: the
+    grouped matmul takes the stack whole and the layer's place in it
+    (models/moe.py ``held_rows``)."""
+    lead, rep = layer_groups(layers)
+    n_lead = jax.tree.leaves(lead)[0].shape[0] if lead else 0
+    n = jax.tree.leaves(rep)[0].shape[0]
+    period = cfg.layer_period
+    p = len(period)
+    counted = kw.get("moe_impl") == "held" and "router" in rep
+    carry = (h, cache_k, cache_v)
+    experts = {}
+    if counted:
+        carry += (jnp.zeros((3,), jnp.int32),)
+        experts = {k: rep.pop(k) for k in ("w_gate", "w_up", "w_down")}
+
+    def one(carry, lp, layer, window):
+        if experts and "router" in lp:
+            lp = {**lp, **experts, "expert_layer": layer - n_lead}
+        *state, counts = _layer(cfg, lp, layer, *carry[:3], window=window, **kw)
+        return (*state, *((carry[3] + counts,) if counts is not None
+                          else carry[3:]))
+
+    def at(tree, i):
+        return jax.tree.map(lambda a: a[i], tree)
+
+    for i in range(n_lead):
+        carry = one(carry, at(lead, i), i, cfg.window_of(i))
+    if p == 1:
+        index = jnp.arange(n, dtype=jnp.int32)
+
+        def layer_fn(carry, xs):
+            lp, layer = xs
+            return one(carry, lp, layer, period[0]), None
+
+        carry, _ = lax.scan(layer_fn, carry,
+                            (rep, index + n_lead if n_lead else index))
+    else:
+        whole = n // p
+        periods = jax.tree.map(
+            lambda a: a[:whole * p].reshape(whole, p, *a.shape[1:]), rep)
+
+        def period_fn(carry, xs):
+            lps, i = xs
+            for j, window in enumerate(period):
+                carry = one(carry, at(lps, j), n_lead + i * p + j, window)
+            return carry, None
+
+        if whole:
+            carry, _ = lax.scan(period_fn, carry,
+                                (periods, jnp.arange(whole, dtype=jnp.int32)))
+        for j in range(whole * p, n):
+            carry = one(carry, at(rep, j), n_lead + j, period[j % p])
+    return (*carry, None) if not counted else carry
 
 
 def _positions_and_slots(lay: TokenLayout, valid, q_start, block_tables,
@@ -558,10 +701,14 @@ def forward(
     embed_mask: jax.Array | None = None,      # [B, T] True → use override
     pp_microbatches: int = 0,                 # pp>1: schedule depth (0 = auto)
     num_tokens: int | None = None,            # token bucket N (None = B*T)
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+    moe_counts: bool = False,                 # also return the routed layers' counts
+) -> tuple[jax.Array, ...]:
     """One engine step. Returns (last_hidden [B,H], cache_k, cache_v) —
     or (hidden [B,T,H], ...) with ``return_all_hidden`` (the speculative
-    verify step needs logits at every chunk position).
+    verify step needs logits at every chunk position). With ``moe_counts``
+    (``moe_impl="held"`` only) a fourth: int32 [3], over the step's routed
+    layers the (token, choice) rows computed here, the experts that had
+    rows, and the rows of each layer's largest group, summed.
 
     Query token j of sequence b sits at position q_start[b]+j; its KV is
     written into the cache slot named by the block table; attention sees all
@@ -628,17 +775,18 @@ def forward(
         h = jnp.where(lay.to_tokens(embed_mask)[:, None],
                       lay.to_tokens(embed_override).astype(h.dtype), h)
 
-    h, cache_k, cache_v = _run_layers(
+    held = {"live": valid} if moe_impl == "held" else {}
+    h, cache_k, cache_v, counts = _run_layers(
         cfg, params["layers"], h, cache_k, cache_v, lay=lay,
         positions=positions, slot=slot, block_tables=block_tables,
         q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
         moe_impl=moe_impl, mesh=mesh,
-        use_ring=use_ring)
+        use_ring=use_ring, **held)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
 
-    if return_all_hidden:
-        return lay.to_rows(h), cache_k, cache_v                    # [B, T, H]
-    return _last_hidden(h, lay, q_len), cache_k, cache_v
+    out = (lay.to_rows(h) if return_all_hidden                     # [B, T, H]
+           else _last_hidden(h, lay, q_len)), cache_k, cache_v
+    return (*out, counts) if moe_counts else out
 
 
 def _last_hidden(h: jax.Array, lay: TokenLayout, q_len: jax.Array) -> jax.Array:
@@ -762,7 +910,7 @@ def forward_pp(
             # and the output contribution is masked.
             slot_t = jnp.where(live, slot_mb[mbc], 0)
             h_in = jnp.where(s == 0, h0_mb[mbc], h_cur)
-            h_out, ck, cv = _run_layers(
+            h_out, ck, cv, _ = _run_layers(
                 cfg, lp_stack, h_in, ck, cv, lay=lay_mb, positions=pos_mb[mbc],
                 slot=slot_t, block_tables=bt_mb[mbc], q_start=qs_mb[mbc],
                 kv_lens=kl_mb[mbc], attn_impl=attn_impl)
@@ -802,7 +950,7 @@ def _forward_pp_sequential(params, cfg, lay, positions, q_start, kv_lens, slot,
     def pp_fn(lp_stack, ck_local, cv_local, h):
         s = lax.axis_index("pipe")
         for i in range(pp):
-            h_out, ck_new, cv_new = _run_layers(
+            h_out, ck_new, cv_new, _ = _run_layers(
                 cfg, lp_stack, h, ck_local, cv_local, lay=lay,
                 positions=positions, slot=slot, block_tables=block_tables,
                 q_start=q_start, kv_lens=kv_lens)

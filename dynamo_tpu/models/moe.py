@@ -4,18 +4,22 @@ The reference only passes wide-EP flags through to SGLang/vLLM
 (SURVEY.md §2.7: TEP16/DEP16 recipes, e.g. recipes/deepseek-r1/sglang-wideep);
 the expert math itself is ours.
 
-Two formulations:
+One router (:func:`route`) and one grouped core (:func:`held_rows`: the
+(token, choice) rows of the experts held here, sorted by expert so that each
+expert's tokens form one contiguous ragged group of one ``lax.ragged_dot``;
+static shapes, no capacity, nothing dropped; rows of experts held elsewhere
+belong to no group) under three layers:
 
-- :func:`moe_mlp_dropless` (the serving default, ``moe_impl="ep"``) — EXACT
-  under any load: (token, choice) rows are sorted by expert id so each
-  expert's tokens form one contiguous ragged group feeding one MXU matmul
-  (``lax.ragged_dot`` — static shapes, no capacity, nothing dropped).
-  EP sharding is an explicit ``shard_map`` over the "expert" axis with the
-  batch staying on "data": each device computes the rows of ITS experts
-  (non-local rows route through an appended all-zero "void" expert, so
-  shapes stay static) and partial outputs ``psum`` over the axis. A
-  serving engine cannot ship an output-changing dispatch — vLLM-class
-  engines are dropless for the same reason.
+- :func:`moe_mlp_held` (``moe_impl="held"``): one chip told which experts
+  it holds of a wider router, as one chip's share of an expert-parallel
+  deployment: no exchange, and counts of what it computed.
+
+- :func:`moe_mlp_dropless` (the serving default for ep > 1,
+  ``moe_impl="ep"``) — EXACT under any load. EP sharding is an explicit
+  ``shard_map`` over the "expert" axis with the batch staying on "data":
+  each device computes the rows of ITS experts and partial outputs ``psum``
+  over the axis. A serving engine cannot ship an output-changing dispatch —
+  vLLM-class engines are dropless for the same reason.
 
 - :func:`moe_mlp_ep` (``moe_impl="ep_capacity"``) — the classic
   Switch/GShard capacity-bounded dispatch/combine einsum formulation, kept
@@ -35,48 +39,118 @@ from dynamo_tpu.models.config import ModelConfig
 Params = dict
 
 
-def _router_topk(xt: jax.Array, lp: Params, cfg: ModelConfig):
-    """Top-k routing shared by both formulations: returns ([N,k] expert ids,
-    [N,k] softmax weights) — identical math to the dense reference
-    (models.llama.moe_mlp), so dispatch equivalence is purely about which
-    chosen pairs get computed."""
+def route(xt: jax.Array, lp: Params, cfg: ModelConfig):
+    """The router, one function for every formulation and both scorings:
+    ([N,k] expert ids over the router's whole width, [N,k] float32 weights).
+
+    "softmax" (Mixtral): the k largest logits, weighted by the softmax over
+    those k alone. "sigmoid" (DeepSeek-V3's, which K-EXAONE's keys name):
+    scores ``sigmoid(logits)``; the k largest of ``score + bias`` are chosen
+    (``router_bias``: it steers the choice and weighs nothing); the chosen
+    scores, normalised to sum 1 under ``norm_topk_prob``, times
+    ``routed_scaling_factor``. The router is as wide as the published model
+    (``cfg.router_width``) also where this chip holds a share of the
+    experts: which experts a token goes to does not depend on who holds
+    them."""
     logits = xt.astype(jnp.float32) @ lp["router"].astype(jnp.float32)   # [N, E]
-    topv, topi = lax.top_k(logits, cfg.num_experts_per_tok)
-    return topi, jax.nn.softmax(topv, axis=-1)
+    k = cfg.num_experts_per_tok
+    if cfg.router_scoring != "sigmoid":
+        topv, topi = lax.top_k(logits, k)
+        return topi, jax.nn.softmax(topv, axis=-1)
+    scores = jax.nn.sigmoid(logits)
+    # (a biased model without the leaf is a KeyError, not a choice by score)
+    biased = scores + lp["router_bias"].astype(jnp.float32) \
+        if cfg.router_bias else scores
+    _, topi = lax.top_k(biased, k)
+    weights = jnp.take_along_axis(scores, topi, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return topi, weights * cfg.routed_scaling_factor
 
 
-def _dropless_rows(xt, topi, weights, w_gate, w_up, w_down, e_lo, e_local):
-    """Compute this device's expert rows. xt [N,H]; topi/weights [N,k];
-    w_* [E_local(+0), H|M, M|H] local expert slabs. Returns [N, H] partial
-    output (zero contribution for rows owned by other devices)."""
+def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
+              layer=None):
+    """The grouped formulation for the experts held here, the first
+    ``E_held`` of the router's (a shard that holds others hands in ``topi``
+    less its first expert's index: what falls outside ``0 .. E_held - 1`` is
+    held elsewhere): the (token, choice) rows routed to them,
+    sorted by expert so that each expert's rows are one ragged group of one
+    grouped matmul (``lax.ragged_dot``: on a TPU a native grouped matmul
+    that reads the weights of the groups that have rows). Rows routed to
+    experts held elsewhere sort behind the groups and belong to none:
+    nothing is computed for them and nothing stands in for the chips that
+    hold them. ``live`` [N] bool drops the rows of a bucket's padding tokens
+    the same way.
+
+    ``w_*`` are one layer's slabs ``[E_held, H|M, M|H]`` or, with ``layer``
+    (an index, may be traced), the whole stack ``[L, E_held, ...]`` as the
+    parameters hold it: the stack is then the grouped matmul's operand as
+    it lies (``[L * E_held, ...]``, a free reshape) and the other layers'
+    groups are empty. A grouped matmul is a custom call, and a slab cut out
+    of the stack for it would be copied, every step.
+
+    Returns ([N, H] float32: the held experts' part of the layer's result,
+    int32 [3]: rows computed, experts touched, rows of the largest group)."""
     n, h = xt.shape
     k = topi.shape[1]
+    first = 0
+    if layer is not None:
+        n_layers, held = w_gate.shape[:2]
+        w_gate, w_up, w_down = (w.reshape(n_layers * held, *w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+        first = layer * held
+    else:
+        held = w_gate.shape[0]
+    groups = w_gate.shape[0]
     flat_e = topi.reshape(-1)                         # [Nk] token-major
     flat_t = jnp.repeat(jnp.arange(n), k)             # [Nk]
-    local_e = flat_e - e_lo
-    is_local = (local_e >= 0) & (local_e < e_local)
-    # Sort rows by local expert; foreign rows collect in a trailing "void"
-    # group whose weights are zero, keeping every shape static.
-    key = jnp.where(is_local, local_e, e_local)
+    here = (flat_e >= 0) & (flat_e < held)
+    if live is not None:
+        here = here & jnp.repeat(live, k)
+    key = jnp.where(here, flat_e, held)
     perm = jnp.argsort(key, stable=True)
-    xs = xt[flat_t[perm]]                             # [Nk, H]
-    group_sizes = jnp.zeros((e_local + 1,), jnp.int32).at[key].add(1)
+    rows = flat_t[perm]
+    # (an index past the last group is dropped: a row of no group)
+    group_sizes = jnp.zeros((groups,), jnp.int32).at[
+        jnp.where(here, first + flat_e, groups)].add(1, mode="drop")
+    xs = xt[rows]                                     # [Nk, H]
+    gate = lax.ragged_dot(xs, w_gate, group_sizes)    # [Nk, M]
+    up = lax.ragged_dot(xs, w_up, group_sizes)
+    out = lax.ragged_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
+    # What a row behind the last group holds is not defined: select, do
+    # not multiply.
+    contrib = jnp.where(here[perm][:, None],
+                        out.astype(jnp.float32)
+                        * weights.reshape(-1)[perm][:, None], 0.0)
+    y = jnp.zeros((n, h), jnp.float32).at[rows].add(contrib)
+    counts = jnp.stack([jnp.sum(group_sizes),
+                        jnp.sum((group_sizes > 0).astype(jnp.int32)),
+                        jnp.max(group_sizes)])
+    return y, counts
 
-    void = jnp.zeros_like(w_gate[:1])
-    wg = jnp.concatenate([w_gate, void], axis=0)
-    wu = jnp.concatenate([w_up, void], axis=0)
-    wd = jnp.concatenate([w_down, jnp.zeros_like(w_down[:1])], axis=0)
 
-    gate = lax.ragged_dot(xs, wg, group_sizes)        # [Nk, M]
-    up = lax.ragged_dot(xs, wu, group_sizes)
-    act = jax.nn.silu(gate) * up
-    out = lax.ragged_dot(act, wd, group_sizes)        # [Nk, H]
+def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None):
+    """The routed FFN of a chip that is told which experts it holds
+    (``lp["w_gate"]`` is ``[E_held, H, M]``: the first ``E_held`` of the
+    ``cfg.router_width`` the router scores; or, with ``lp["expert_layer"]``,
+    the layers' whole stack and this layer's place in it): route over all
+    of them, compute the held experts' rows (:func:`held_rows`) and add the
+    shared expert once. With every expert held it is the whole layer. x
+    [N, H] -> ([N, H], int32 [3] counts). One chip: no exchange."""
+    from dynamo_tpu.models.llama import swiglu
+    from dynamo_tpu.obs.profiler import phase
 
-    contrib = out.astype(jnp.float32) * weights.reshape(-1)[perm][:, None]
-    # Stays fp32: under EP sharding this is a PARTIAL sum — the caller must
-    # psum across devices in fp32 and cast once, like the dense reference's
-    # single fp32 accumulation (bf16 partials would compound per expert).
-    return jnp.zeros((n, h), jnp.float32).at[flat_t[perm]].add(contrib)
+    xt = x.reshape(-1, x.shape[-1])
+    with phase("moe_route"):
+        topi, weights = route(xt, lp, cfg)
+    with phase("moe_experts"):
+        y, counts = held_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
+                              lp["w_down"], live, lp.get("expert_layer"))
+    if cfg.num_shared_experts:
+        with phase("moe_shared"):
+            y = y + swiglu(xt, lp["shared_gate"], lp["shared_up"],
+                           lp["shared_down"]).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(x.shape), counts
 
 
 def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
@@ -94,9 +168,9 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
     )
     if ep <= 1 or e % ep != 0:
         xt = x.reshape(-1, h)
-        topi, weights = _router_topk(xt, lp, cfg)
-        y = _dropless_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
-                           lp["w_down"], 0, e)
+        topi, weights = route(xt, lp, cfg)
+        y, _ = held_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
+                         lp["w_down"])
         if shared is not None:
             from dynamo_tpu.models.llama import swiglu
 
@@ -105,7 +179,11 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
 
     e_local = e // ep
 
-    def shard_fn(x3, router, wg, wu, wd, *shared_w):
+    def shard_fn(x3, router, wg, wu, wd, *rest):
+        rlp = {"router": router}
+        if cfg.router_bias:
+            rlp["router_bias"], *rest = rest
+        shared_w = tuple(rest)
         # Each device owns (its expert slab) x (its slice of the expert
         # intermediate dim, on TEP meshes where "model" also shards M).
         # gate/up slice M locally (silu is columnwise-exact); w_down
@@ -113,8 +191,10 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
         # axes — one fp32 psum completes expert combine and TEP contraction.
         e_lo = lax.axis_index("expert") * e_local
         xt = x3.reshape(-1, h)
-        topi, weights = _router_topk(xt, {"router": router}, cfg)
-        y = _dropless_rows(xt, topi, weights, wg, wu, wd, e_lo, e_local)
+        topi, weights = route(xt, rlp, cfg)
+        # Partial over the axis and float32: the psum below sums the
+        # shards in float32 and the cast is made once.
+        y, _ = held_rows(xt, topi - e_lo, weights, wg, wu, wd)
         if shared_w:
             from dynamo_tpu.models.llama import swiglu
 
@@ -139,6 +219,10 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
     in_specs = [batch_spec, P(),
                 P("expert", None, "model"), P("expert", None, "model"),
                 P("expert", "model", None)]
+    if cfg.router_bias:
+        # like the router it steers, whole on every device ([E])
+        args.append(lp["router_bias"])
+        in_specs.append(P())
     if shared is not None:
         args.extend(shared)
         in_specs.extend([P(None, "model"), P(None, "model"), P("model", None)])
@@ -170,7 +254,7 @@ def moe_mlp_ep(x: jax.Array, lp: Params, cfg: ModelConfig,
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     xt = x.reshape(-1, h)
     n = xt.shape[0]
-    topi, weights = _router_topk(xt, lp, cfg)                            # [N, k]
+    topi, weights = route(xt, lp, cfg)                            # [N, k]
 
     cap = expert_capacity(n, e, k, capacity_factor)
     # Position of each (choice, token) within its expert's buffer. Flatten

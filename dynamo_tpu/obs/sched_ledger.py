@@ -40,7 +40,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 
 from dynamo_tpu.utils.metrics import MetricsRegistry
@@ -201,7 +201,14 @@ class SchedStepRecord:
     live_tokens: int = 0            # tokens the plan actually needed
     sched_tokens: int = 0           # tokens the dense layers computed (N)
     rect_tokens: int = 0            # positions attention ran over (b x t)
-    kv_blocks_live: int = 0         # KV blocks the rows hold (the kernel's walk)
+    kv_blocks_live: int = 0         # KV blocks the rows hold (a full layer's walk)
+    kv_blocks_walked: int = 0       # ... the kernel walks over all layers, windows counted
+    # A routed model under moe_impl="held", summed over the step's routed
+    # layers (device counts, fetched with the step's tokens):
+    moe_layer_steps: int = 0        # routed layers x programs run
+    moe_rows: int = 0               # (token, choice) rows computed here
+    moe_experts_touched: int = 0    # held experts that had rows
+    moe_largest_group: int = 0      # rows of each layer's largest group
     live_flops: float = 0.0
     sched_flops: float = 0.0
     live_bytes: float = 0.0
@@ -227,9 +234,15 @@ class SchedStepRecord:
             "sched_tokens": self.sched_tokens,
             "rect_tokens": self.rect_tokens,
             "kv_blocks_live": self.kv_blocks_live,
+            "kv_blocks_walked": self.kv_blocks_walked,
             "goodput": round(self.goodput, 4),
             "budget_util": round(self.budget_util, 4),
         }
+        if self.moe_layer_steps:
+            d.update(moe_layer_steps=self.moe_layer_steps,
+                     moe_rows=self.moe_rows,
+                     moe_experts_touched=self.moe_experts_touched,
+                     moe_largest_group=self.moe_largest_group)
         if self.queue_depths:
             d["queue_depths"] = dict(self.queue_depths)
         if self.blocked:
@@ -269,6 +282,8 @@ class SchedLedger:
         self.sched_tokens_total = 0
         self.rect_tokens_total = 0
         self.kv_blocks_live_total = 0
+        self.kv_blocks_walked_total = 0
+        self.moe_totals = [0, 0, 0, 0]   # layer steps, rows, touched, largest
         self.padding_flops_total = 0.0
         self.padding_bytes_total = 0.0
         self.hol_stall_seconds_total = 0.0
@@ -300,6 +315,8 @@ class SchedLedger:
             self.sched_tokens_total = 0
             self.rect_tokens_total = 0
             self.kv_blocks_live_total = 0
+            self.kv_blocks_walked_total = 0
+            self.moe_totals = [0, 0, 0, 0]
             self.padding_flops_total = 0.0
             self.padding_bytes_total = 0.0
             self.hol_stall_seconds_total = 0.0
@@ -358,6 +375,8 @@ class SchedLedger:
         sched_tokens: int = 0,
         rect_tokens: int = 0,
         kv_blocks_live: int = 0,
+        kv_blocks_walked: int = 0,
+        moe: tuple[int, int, int, int] | None = None,
         live_flops: float = 0.0,
         sched_flops: float = 0.0,
         live_bytes: float = 0.0,
@@ -387,6 +406,9 @@ class SchedLedger:
             prefill_rows=prefill_rows, decode_rows=decode_rows,
             live_tokens=live_tokens, sched_tokens=sched_tokens,
             rect_tokens=rect_tokens, kv_blocks_live=kv_blocks_live,
+            kv_blocks_walked=kv_blocks_walked,
+            **(dict(zip(("moe_layer_steps", "moe_rows", "moe_experts_touched",
+                         "moe_largest_group"), moe)) if moe else {}),
             live_flops=live_flops, sched_flops=sched_flops,
             live_bytes=live_bytes, sched_bytes=sched_bytes,
             goodput=goodput, budget_util=budget_util,
@@ -428,6 +450,9 @@ class SchedLedger:
             self.sched_tokens_total += sched_tokens
             self.rect_tokens_total += rect_tokens
             self.kv_blocks_live_total += kv_blocks_live
+            self.kv_blocks_walked_total += kv_blocks_walked
+            if moe:
+                self.moe_totals = [a + b for a, b in zip(self.moe_totals, moe)]
             self.padding_flops_total += pad_f
             self.padding_bytes_total += pad_b
             if rec.hol_victims:
@@ -477,6 +502,11 @@ class SchedLedger:
                 "sched_tokens_total": self.sched_tokens_total,
                 "rect_tokens_total": self.rect_tokens_total,
                 "kv_blocks_live_total": self.kv_blocks_live_total,
+                "kv_blocks_walked_total": self.kv_blocks_walked_total,
+                "moe_layer_steps_total": self.moe_totals[0],
+                "moe_rows_total": self.moe_totals[1],
+                "moe_experts_touched_total": self.moe_totals[2],
+                "moe_largest_group_total": self.moe_totals[3],
                 "padding_flops_total": self.padding_flops_total,
                 "padding_hbm_bytes_total": self.padding_bytes_total,
                 "admission_blocked": dict(self.blocked_totals),
@@ -556,6 +586,25 @@ def kv_blocks_live(batches, block_size: int) -> int:
     ``b x nblk`` entries of the tables it was handed."""
     return sum(-(-(start + length) // block_size)
                for _sig, rows, *_ in batches for _seq, start, length in rows)
+
+
+def kv_blocks_walked(batches, block_size: int, windows) -> int:
+    """KV blocks the attention kernel walks for a step's rows over all the
+    layers, ``windows`` giving each layer's window (0: a full layer, which
+    walks what :func:`kv_blocks_live` counts). A sliding layer's walk of a
+    row begins at the block that holds the oldest key the row's first query
+    token sees (ops/paged_attention.py ``chunk_first_blocks``). Host
+    arithmetic, counted once a row as ``kv_blocks_live`` is."""
+    kinds = Counter(windows).items()     # a few kinds of layer, many rows
+    total = 0
+    for _sig, rows, *_ in batches:
+        for _seq, start, length in rows:
+            used = -(-(start + length) // block_size)
+            total += sum(
+                n * (used - (min(max(start - (w - 1), 0) // block_size,
+                                 used - 1) if w else 0))
+                for w, n in kinds)
+    return total
 
 
 def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
@@ -645,6 +694,9 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
         "sched_tokens": sched["tokens"],
         "rect_tokens": rect,
         "kv_blocks_live": int(live["kv_blocks"]),
+        "kv_blocks_walked": kv_blocks_walked(
+            batches, bs, [model_cfg.window_of(i)
+                          for i in range(model_cfg.num_layers)]),
         "live_flops": lc.flops if lc else 0.0,
         "sched_flops": sc.flops if sc else 0.0,
         "live_bytes": lc.hbm_bytes if lc else 0.0,

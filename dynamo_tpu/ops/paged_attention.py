@@ -225,6 +225,19 @@ def chunk_used_blocks(q_start, kv_lens, *, nq: int, rchunk: int, rep: int,
     return jnp.clip((seen + bs - 1) // bs, 0, nblk)
 
 
+def chunk_first_blocks(q_start, *, nq: int, rchunk: int, rep: int, bs: int,
+                       window: int):
+    """``[B, NQ]`` int32: under a window, the block at which each query
+    chunk's walk begins: the one that holds the oldest key the chunk's
+    first query token can see, position ``first - window + 1``. Blocks
+    before it are out of every query's window in the chunk and are not
+    copied; inside the walk the mask hides what the later queries no
+    longer see."""
+    chunk = jnp.arange(nq, dtype=jnp.int32)
+    first = q_start[:, None] + (chunk * rchunk) // rep
+    return jnp.maximum(first - (window - 1), 0) // bs
+
+
 def _group_blocks(rows: int, bs: int, nblk: int) -> int:
     """Blocks a group of the walk holds, G: what one online-softmax update
     covers. ``G * bs`` keys is a multiple of the 128 lanes wherever the
@@ -237,7 +250,12 @@ def _group_blocks(rows: int, bs: int, nblk: int) -> int:
 
 
 def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
-            quant: bool, int4: bool, mm_dtype):
+            quant: bool, int4: bool, mm_dtype, window: int = 0):
+    # A window (static, > 0) adds one scalar-prefetch operand, the block
+    # each walk begins at, ahead of the others, and a lower bound in the
+    # mask; with window == 0 nothing below is traced that was not before.
+    if window:
+        fb_ref, *refs = refs
     if quant:
         # Scales ride the scalar-prefetch channel with the block table, so
         # dequant needs no extra DMA: the int8/int4 payload goes to the MXU
@@ -258,9 +276,16 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
     # context, none past the chunk's own last position, none at all for a
     # chunk of padding. The walk is over groups of ``gb`` of them: the trip
     # count is this chunk's own, whatever the table's width.
-    used = ub_ref[b if nq == 1 else lax.add(lax.mul(b, _i32(nq)), qi)]
-    groups = lax.div(lax.add(used, _i32(gb - 1)), _i32(gb))
+    chunk = b if nq == 1 else lax.add(lax.mul(b, _i32(nq)), qi)
+    used = ub_ref[chunk]
     last = lax.max(lax.sub(used, _i32(1)), _i32(0))
+    if window:
+        # The walk is over the blocks [first, used): what lies before is
+        # out of the window of every query in the chunk.
+        first = lax.min(fb_ref[chunk], last)
+        groups = lax.div(lax.add(lax.sub(used, first), _i32(gb - 1)), _i32(gb))
+    else:
+        groups = lax.div(lax.add(used, _i32(gb - 1)), _i32(gb))
     layer = ly_ref[0]
     kv_len = kl_ref[b]
     q_pos0 = qs_ref[b]
@@ -269,7 +294,10 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
         # The group's i-th block. Past the last used block the last one is
         # named again: its keys are masked by position, and what the buffer
         # holds there is KV that was written, never stale VMEM.
-        return bt_ref[b, lax.min(lax.add(lax.mul(g, _i32(gb)), i), last)]
+        j = lax.add(lax.mul(g, _i32(gb)), i)
+        if window:
+            j = lax.add(j, first)
+        return bt_ref[b, lax.min(j, last)]
 
     def copies(g, slot, i, landing=False):
         # A wait needs the copy's shape and semaphore, not its source.
@@ -312,6 +340,8 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
         # group is seen by chunk row w (query token w // rep) if
         # g*gk + c <= q_pos0 + (qi*r + w) // rep and g*gk + c < kv_len.
         base = lax.mul(g, _i32(gk))
+        if window:
+            base = lax.add(base, lax.mul(first, _i32(bs)))
         ctx = lax.broadcasted_iota(jnp.int32, (r, gk), 1)
         tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, gk), 0),
                       lax.full((r, gk), rep, jnp.int32))
@@ -319,6 +349,12 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
         visible = lax.bitwise_and(
             lax.le(lax.sub(ctx, tok), lax.broadcast(q_pos, (r, gk))),
             lax.lt(ctx, lax.broadcast(lax.sub(kv_len, base), (r, gk))))
+        if window:
+            # ... and by a sliding layer's query only if fewer than
+            # ``window`` positions back: g*gk + c > q_pos - window.
+            visible = lax.bitwise_and(visible, lax.gt(
+                lax.sub(ctx, tok),
+                lax.broadcast(lax.sub(q_pos, _i32(window)), (r, gk))))
 
         neg_inf = lax.full((r, gk), NEG_INF, jnp.float32)
         zeros = lax.full((r, gk), 0.0, jnp.float32)
@@ -469,9 +505,16 @@ def paged_attention_kernel(
                               #   the cache; None = the cache IS one layer,
                               #   [NB, BS, KH, D]
     interpret: bool = False,
+    window: int = 0,          # static; > 0: a sliding layer, query i sees
+                              #   the keys j with i - j < window
 ) -> jax.Array:
     """Flash paged attention over layer ``layer`` of a block-table cache.
     Returns [B, T, H, D].
+
+    Under a ``window`` the walk of a (row, query chunk) starts at the first
+    block any of its queries can see (``chunk_first_blocks``, one more
+    scalar-prefetch operand) and the mask gains the lower bound: a sliding
+    layer's decode row copies ``window / BS + 1`` blocks, not its context.
 
     The cache is only read, and only the blocks the tables name: it stays
     in HBM and the kernel copies those blocks itself, the layer index
@@ -531,6 +574,10 @@ def paged_attention_kernel(
                layer.reshape(1))
     if quant:
         scalars = scalars + (k_scale, v_scale)
+    if window:
+        scalars = (chunk_first_blocks(
+            qs32, nq=nq, rchunk=rchunk, rep=rep, bs=bs,
+            window=window).reshape(-1),) + scalars
 
     # bf16 operands go to the MXU as they are (int8 / int4 payloads are
     # exact in bf16); anything wider keeps float32 matmuls.
@@ -560,7 +607,8 @@ def paged_attention_kernel(
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, kh=kh, rep=rep, gb=gb, nq=nq,
-                          quant=quant, int4=int4, mm_dtype=mm_dtype),
+                          quant=quant, int4=int4, mm_dtype=mm_dtype,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, r, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -586,6 +634,7 @@ def paged_attention_sharded(
     *,
     layer=None,               # as paged_attention_kernel
     interpret: bool = False,
+    window: int = 0,          # as paged_attention_kernel
 ) -> jax.Array:
     """TP-sharded paged attention: shard_map the kernel over the "model"
     (head) axis so each device runs the kernel on its local heads. Heads are
@@ -605,7 +654,7 @@ def paged_attention_sharded(
     def local(q, k_cache, v_cache, block_tables, q_start, kv_lens, layer):
         return paged_attention_kernel(
             q, k_cache, v_cache, block_tables, q_start, kv_lens, layer=layer,
-            interpret=interpret)
+            interpret=interpret, window=window)
 
     fn = jax.shard_map(
         local,

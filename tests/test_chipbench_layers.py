@@ -264,3 +264,154 @@ def test_newest_xplane_takes_only_this_runs_trace(tmp_path):
     assert xevents.newest_xplane(tmp_path, since=2000.0) == new
     assert xevents.newest_xplane(tmp_path, since=0.0) == new
     assert xevents.process_start() > 0
+
+
+# ---------------------------------------------------------------------------
+# the routed configuration's cell and its four readers (PR 39)
+# ---------------------------------------------------------------------------
+
+ROUTED_CELL = "k-exaone-236b.reasoning"
+ROUTED = ("moe.rows_per_expert_step", "device.moe_pct",
+          "moe.expert_gemm_roofline_pct", "attn.blocks_walked_pct")
+MOE = {"experts_held": 16, "router_width": 128, "experts_per_token": 8,
+       "routed_layers": 4, "hidden_size": 6144, "expert_width": 2048,
+       "bytes_per_param": 2,
+       "shapes": [[16, 6144, 2048], [16, 2048, 6144], [6144, 128],
+                  [6144, 2048], [2048, 6144]]}
+
+
+def _routed_counters() -> tuple[dict, dict]:
+    """A window of 1,000 steps of 4 routed layers at 32 rows: 4,000
+    layer-steps, 32 rows and 14 experts touched a layer-step; 5 layers, one
+    full, walking a fifth of what they hold and all of it."""
+    sched0 = {"moe_layer_steps_total": 400, "moe_rows_total": 10_000,
+              "moe_experts_touched_total": 5_000,
+              "moe_largest_group_total": 2_000,
+              "kv_blocks_live_total": 1_000, "kv_blocks_walked_total": 1_500}
+    sched1 = {"moe_layer_steps_total": 4_400, "moe_rows_total": 138_000,
+              "moe_experts_touched_total": 61_000,
+              "moe_largest_group_total": 22_000,
+              "kv_blocks_live_total": 101_000,
+              "kv_blocks_walked_total": 141_500}
+    dev = {"device_kind": "TPU v5 lite"}
+    return ({"sched": sched0}, {"sched": sched1, "moe": MOE, "device": dev,
+                                "kv_cache_shape": [5, 19291, 16, 8, 128]})
+
+
+def test_the_routed_cell_reports_what_it_is_judged_on():
+    cell = manifest.load_cell(ROUTED_CELL)
+    assert cell.chips == 1 and cell.config_name == "k-exaone-236b-a23b-ep8-l5"
+    assert cell.end_to_end == ["itl_p95_ms", "tokens_per_s", "setup_s"]
+    assert set(ROUTED) <= set(cell.per_layer)
+    for other in ("mistral-7b.chat", "mistral-7b.longprompt",
+                  "mistral-nemo-12b.chat"):
+        assert not set(ROUTED) & set(manifest.load_cell(other).per_layer)
+    tr = cell.traffic
+    assert tr["prompt_tokens"]["max"] + tr["output_tokens"]["max"] <= 6144
+    assert tr["max_rows"] <= 64 and tr["rate_per_s"] > 0
+    # The cell follows its sweep by the issue's rule: 0.8 x the highest rate
+    # that kept up, and the decode bucket above the rows in flight at that
+    # rate in the cell's own window: no row bucket is warmed that the
+    # traffic does not reach.
+    from harness import sut
+
+    from dynamo_tpu.obs.compile_ledger import sig_for_rows
+    sweep = json.loads((ROOT / "chipbench/sweeps"
+                        / f"{ROUTED_CELL}.json").read_text())
+    kept_up = [r["rate_per_s"] for r in sweep["rates"] if r["kept_up"]]
+    assert sweep["knee_per_s"] == max(kept_up)
+    assert tr["rate_per_s"] == pytest.approx(0.8 * sweep["knee_per_s"])
+    at = sweep["at_cell_rate_51s"]
+    assert at["rate_per_s"] == tr["rate_per_s"] and not at["failed"]
+    ec = sut.engine_config(cell.config_dir, cell.about)
+    assert tr["max_rows"] == sig_for_rows(
+        "decode", at["in_flight_max"], 1, 1, ec).b
+    # the share, as config.json states it
+    assert (cell.model["num_experts"], cell.model["num_experts_published"],
+            cell.model["num_experts_per_tok"]) == (16, 128, 8)
+    assert sorted(cell.about["reduced"]) == sorted(
+        next(c for c in BENCH["configs"]
+             if c["name"] == cell.config_name)["reduced"])
+    assert set(cell.model["assumed"]) == set(cell.about["assumed"])
+
+
+@pytest.mark.parametrize("name, expect", [
+    # 128,000 rows over 4,000 layer-steps x 16 experts held
+    ("moe.rows_per_expert_step", 2.0),
+    # 140,000 blocks walked of 100,000 held x 5 layers
+    ("attn.blocks_walked_pct", 28.0),
+])
+def test_routed_counter_reader_on_a_hand_made_context(name, expect):
+    value = measure.load_reader(name).read(_ctx(*_routed_counters()))
+    assert value == pytest.approx(expect)
+
+
+def _routed_events():
+    """Two decode steps of one routed layer each: route, three grouped
+    matmuls with their metadata op, the activation between them, the shared
+    expert, and a dense matmul and a kernel call that are not the layer's."""
+    step = [
+        ("%fusion.1 = f32[32,128]{1,0} fusion(bf16[32,6144]{1,0} %x, "
+         "bf16[4,6144,128]{2,1,0} %router)", 10),
+        ("%ragged-dot-metadata.1 = (s32[65]{0}, s32[8]{0}) custom-call("
+         "s32[64]{0} %gs)", 2),
+        ("%ragged-dot-none.1 = bf16[256,2048]{1,0} custom-call(bf16[256,6144]"
+         "{1,0} %xs, bf16[64,6144,2048]{2,1,0} %w)", 100),
+        ("%ragged-dot-none.2 = bf16[256,2048]{1,0} custom-call(bf16[256,6144]"
+         "{1,0} %xs, bf16[64,6144,2048]{2,1,0} %w)", 100),
+        ("%multiply_fusion.3 = bf16[256,2048]{1,0} fusion(bf16[256,2048]{1,0} "
+         "%ragged-dot-none.1, bf16[256,2048]{1,0} %ragged-dot-none.2)", 8),
+        ("%ragged-dot-none.3 = bf16[256,6144]{1,0} custom-call(bf16[256,2048]"
+         "{1,0} %act, bf16[64,2048,6144]{2,1,0} %w)", 98),
+        ("%fusion.9 = bf16[32,2048]{1,0} fusion(bf16[32,6144]{1,0} %x, "
+         "bf16[1,6144,2048]{2,1,0} %shared_gate)", 30),
+        ("%fusion.11 = bf16[32,18432]{1,0} fusion(bf16[32,6144]{1,0} %x, "
+         "bf16[6144,18432]{1,0} %w_gate)", 400),
+        ("%paged_attention.1 = bf16[32,8,8,128]{3,2,1,0} custom-call()", 252),
+    ]
+    ops, at = [], 0
+    for _ in range(2):
+        for hlo, ns in step:
+            ops.append((hlo, at, at + ns))
+            at += ns
+    return xevents.Events(ops=[ops], async_ops=[[]])
+
+
+def test_routed_trace_readers_on_hand_made_events(monkeypatch):
+    monkeypatch.setattr(xevents, "current", _routed_events)
+    ctx = _ctx(*_routed_counters(), trace={"busy_s": 1.0, "window_s": 5.0})
+    # route 10 + metadata 2 + matmuls 298 + activation 8 + shared 30 of 1,000
+    assert measure.load_reader("device.moe_pct").read(ctx) == \
+        pytest.approx(34.8)
+    reader = measure.load_reader("moe.expert_gemm_roofline_pct")
+    # 300 ns of grouped matmuls a layer-step in the slice (metadata with them)
+    assert reader.gemm_seconds_per_layer_step(_routed_events()) == \
+        pytest.approx(300e-9)
+    # 32 rows and 14 experts touched a layer-step, by the counting function:
+    nbytes, flop = reader.counts.layer_step(32, 14, 6144, 2048, 2)
+    assert nbytes == (14 * 3 * 6144 * 2048 + 32 * 3 * (6144 + 2048)) * 2
+    assert flop == 32 * 3 * 2 * 6144 * 2048
+    ideal = max(nbytes / 819e9, flop / 197e12)
+    assert reader.read(ctx) == pytest.approx(100.0 * ideal / 300e-9)
+    # a slab of another layer count, or one layer cut out, is the same matrix
+    keep = measure.load_reader("device.moe_pct").matcher(MOE["shapes"])
+    assert keep("%copy.1 = bf16[1,16,6144,2048]{3,2,1,0} copy(%p)")
+    assert keep("%f = f32[576,128]{1,0} fusion(bf16[4,6144,128]{2,1,0} %r)")
+    assert not keep("%f = bf16[32,6144]{1,0} fusion(bf16[6144,8192]{1,0} %wq)")
+    assert not keep("%f = bf16[32,19200]{1,0} fusion(bf16[6144,19200] %head)")
+
+
+@pytest.mark.parametrize("name", ROUTED)
+def test_routed_reader_finds_nothing_on_a_program_without_the_counters(
+        name, monkeypatch):
+    """The parent commit has none of the counters, and a dense model's
+    program none of the routed layer's: every reader returns None."""
+    monkeypatch.setattr(xevents, "newest_xplane", lambda *a, **k: CHIP_TRACE)
+    trace = {"busy_s": 0.2, "window_s": 0.25}
+    reader = measure.load_reader(name)
+    assert reader.read(_ctx({"num_steps": 1}, {"num_steps": 9},
+                            trace=trace)) is None
+    assert reader.read(_ctx(*_counters(), trace=trace)) is None
+    c0, c1 = _routed_counters()     # the counters, and a trace without the ops
+    if name == "moe.expert_gemm_roofline_pct":
+        assert reader.read(_ctx(c0, c1, trace=trace)) is None

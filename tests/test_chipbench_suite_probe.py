@@ -1,1 +1,56 @@
+import json
+
+import pytest
+
+from chipbench.tests import test_probe as _bench
 from chipbench.tests.test_probe import *  # noqa: F401,F403
+
+# ``chipbench/tests/test_probe.py:35`` takes ``ACCEPTED`` to be every
+# configuration of ``BENCHMARK.json`` and pins that none brings a reference or
+# a ``probe`` block of its own: true of the dense configurations it was
+# written over (PR 30). A configuration of another architecture brings both,
+# by the contract of ``chipbench/README.md``, so its case of the pinned test
+# fails, here and where the benchmark's file is run directly. That file is the
+# benchmark's own: the repair (``ACCEPTED`` names the dense configurations)
+# takes a ``benchmark`` PR (PERF.md section 7). Until it lands the case stays
+# in the run as a strict expected failure, and such a configuration is held
+# to the contract below instead.
+
+
+def _own_reference(cfg) -> bool:
+    return ((_bench.manifest.ROOT / cfg["file"]).parent / "reference.py").exists()
+
+
+OWN_REFERENCE = {c["name"] for c in _bench.ACCEPTED if _own_reference(c)}
+
+
+@pytest.fixture(autouse=True)
+def _pinned_case_of_a_configuration_with_its_own_reference(request):
+    if (request.node.originalname
+            == "test_accepted_configurations_keep_their_comparison"
+            and request.node.callspec.id in OWN_REFERENCE):
+        request.applymarker(pytest.mark.xfail(
+            strict=True,
+            reason="chipbench/tests/test_probe.py:35: ACCEPTED should name "
+                   "the dense configurations (a benchmark PR's repair)"))
+
+
+@pytest.mark.parametrize("cfg", [c for c in _bench.ACCEPTED if _own_reference(c)],
+                         ids=lambda c: c["name"])
+def test_a_configuration_with_its_own_reference_keeps_the_contract(cfg):
+    """Its reference is the one the probe finds, its block has no fault, and
+    where the reference names tied positions the block says how close is
+    tied and caps their share."""
+    d = (_bench.manifest.ROOT / cfg["file"]).parent
+    about = json.loads((d / "about.json").read_text())
+    assert _bench.probe.reference_path(d) == d / "reference.py"
+    assert _bench.manifest.probe_faults(d, about) == []
+    block, lim = about["probe"], _bench.probe.limits(about)
+    assert block["why"] and "PROVISIONAL" not in block["why"]
+    assert (lim.logprob_tol, lim.argmax_tol, lim.rms_tol) == (
+        block["logprob_tol"], block["argmax_tol"], block["rms_tol"])
+    assert lim.margin > 0 and 0 < lim.max_tied_share < 1
+    down = block["readings"]["one_precision_down"]
+    assert (lim.logprob_tol < down["worst_logprob_diff"]
+            or lim.argmax_tol < down["worst_argmax_gap"]
+            or lim.rms_tol < down["rms_logprob_diff"])
