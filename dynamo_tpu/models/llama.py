@@ -512,9 +512,16 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
     post = cfg.norm_placement == "post"
     x = hid if post else rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
-    q = mm(x, lp["wq"]).reshape(n, cfg.num_heads, cfg.head_dim)
-    k = mm(x, lp["wk"]).reshape(n, cfg.num_kv_heads, cfg.head_dim)
-    v = mm(x, lp["wv"]).reshape(n, cfg.num_kv_heads, cfg.head_dim)
+    # The three products stay [N, out] up to the barrier and get their head
+    # axis after it. A reshape XLA can fold into the dot makes the weight
+    # operand [heads, D, H], the stored matrix transposed, and the compiler
+    # then cuts the layer's matrix out of its stack and copies it in that
+    # layout in every layer of every step (PERF.md section 6, PR 40).
+    q, k, v = jax.lax.optimization_barrier(
+        (mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])))
+    q = q.reshape(n, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(n, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(n, cfg.num_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
@@ -527,7 +534,12 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     with _perf_phase("scatter"):
         cache_k = _scatter_kv(cache_k, k, slot, layer)
         cache_v = _scatter_kv(cache_v, v, slot, layer)
-    q = lay.to_rows(q)                                       # [B,T,heads,D]
+    # The rows are gathered from q's grouped view [N, KH, REP, D], the split
+    # the kernel's wrapper makes of them anyway: from [N, heads, D] the
+    # compiler moves a chunk step's [B, T] rectangle twice on its way to
+    # the kernel's [B, KH, T*REP, D] (PERF.md section 6, PR 40).
+    q = lay.to_rows(q.reshape(n, cfg.num_kv_heads, -1, cfg.head_dim))
+    q = q.reshape(lay.b, lay.t, cfg.num_heads, cfg.head_dim)  # [B,T,heads,D]
     if use_ring:
         from dynamo_tpu.ops.ring_attention import ring_attention_prefill
 

@@ -215,3 +215,76 @@ def test_stats_say_how_the_pool_was_sized(f32_core):
     n = runner._fit_pool(64 * 1024 * 1024)
     assert n > runner.max_nblk
     assert abs(runner.step_copy_bytes_per_block) < 0.05 * stats["kv_block_bytes"]
+
+
+# -- The Q/K/V projections ------------------------------------------------------
+
+def _one_layer(kind, dtype):
+    """(cfg, one layer's params, window): a dense layer, a sliding layer
+    with QK norm, a layer whose matrices are int8 ``{"q", "so"}`` leaves."""
+    cfg = dataclasses.replace(MODEL_PRESETS["tiny-llama"], dtype=dtype)
+    window = 0
+    if kind == "qk_norm-window":
+        window = 6
+        cfg = dataclasses.replace(
+            cfg, qk_norm=True, sliding_window=window,
+            layer_types=("sliding_attention",) * cfg.num_layers)
+    params = llama.init_params(cfg, jax.random.key(11))
+    if kind == "int8":
+        from dynamo_tpu.models.quant import quantize_params_int8
+
+        params = quantize_params_int8(params, cfg, quantize_embed=False)
+        assert set(params["layers"]["wq"]) == {"q", "so"}
+    return cfg, jax.tree.map(lambda a: a[1], params["layers"]), window
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["dense", "qk_norm-window", "int8"])
+def test_layer_equals_the_projections_reshaped_in_place(monkeypatch, kind,
+                                                        dtype):
+    """``_layer`` holds its Q, K and V products two-dimensional up to an
+    optimization barrier and splits the heads after it, so that the chip's
+    compiler reads each matrix where it lies (tests/test_ops.py holds the
+    compiled text to that). It is the arithmetic it was: with the barrier
+    taken away the text is ``mm(x, w).reshape(n, heads, D)`` again, and a
+    mixed step over a warm cache gives the same hidden state and the same
+    cache, bit for bit in float32 and to a bf16 rounding in bfloat16."""
+    cfg, lp, window = _one_layer(kind, dtype)
+    dt = jnp.dtype(dtype)
+    rng = np.random.default_rng(5)
+    spec = KVCacheSpec.for_model(cfg, NB, BS, kv_dtype=dtype)
+    cache_k, cache_v = _warm_cache(rng, spec), _warm_cache(rng, spec)
+    _, q_start, q_len, bt = _step_inputs(rng, cfg, "mixed")
+    b, t = len(q_start), 8
+    lay, valid = llama.token_layout(q_len, b, t, b * t)
+    positions, slot = llama._positions_and_slots(lay, valid, q_start, bt, BS)
+    hid = jnp.asarray(rng.standard_normal((b * t, cfg.hidden_size)), dt)
+
+    def layer(lp, hid, ck, cv):
+        return llama._layer(
+            cfg, lp, 1, hid, ck, cv, lay=lay, positions=positions, slot=slot,
+            block_tables=bt, q_start=q_start, kv_lens=q_start + q_len,
+            window=window)[:3]
+
+    barriers = []
+    real = jax.lax.optimization_barrier
+    monkeypatch.setattr(jax.lax, "optimization_barrier",
+                        lambda x: (barriers.append(x), real(x))[1])
+    got = jax.jit(layer)(lp, hid, cache_k, cache_v)
+    n = b * t
+    (held,) = barriers
+    assert [a.shape for a in held] == [
+        (n, cfg.q_size), (n, cfg.kv_size), (n, cfg.kv_size)]
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    want = jax.jit(layer)(lp, hid, cache_k, cache_v)
+    assert len(barriers) == 1
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype == dt
+        if dtype == "float32":
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        else:
+            # One unit in the last place of bf16 (2**-8 relative), where
+            # a backend rounds the product before rope on one side only.
+            np.testing.assert_allclose(
+                np.asarray(g, np.float32), np.asarray(w, np.float32),
+                rtol=2 ** -7, atol=2 ** -7)

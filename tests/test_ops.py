@@ -489,11 +489,9 @@ def test_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, nblk, nb, kv,
             lowered.compile()
 
 
-def test_step_holds_no_copy_of_the_pool_on_v5e(v5e_device):
-    """The benchmark's 16-layer Mistral-7B cut, a decode step compiled for
-    the described v5e at two pool sizes (what ModelRunner._fit_pool does on
-    the chip): the bytes beyond the arguments do not grow with the bf16
-    pool. Memory only: no time is read here."""
+def _aot_parts(config: str):
+    """chipbench/aot_check.py and its shell of a runner over one of the
+    benchmark's configurations, shapes on the described v5e."""
     import sys
     from pathlib import Path
 
@@ -501,8 +499,16 @@ def test_step_holds_no_copy_of_the_pool_on_v5e(v5e_device):
     sys.path.insert(0, str(root / "chipbench"))
     import aot_check
 
-    config_dir = root / "chipbench" / "configs" / "mistral-7b-v0.3-l16"
-    parts = aot_check.build_abstract_runner(config_dir, {})
+    config_dir = root / "chipbench" / "configs" / config
+    return aot_check, aot_check.build_abstract_runner(config_dir, {})
+
+
+def test_step_holds_no_copy_of_the_pool_on_v5e(v5e_device):
+    """The benchmark's 16-layer Mistral-7B cut, a decode step compiled for
+    the described v5e at two pool sizes (what ModelRunner._fit_pool does on
+    the chip): the bytes beyond the arguments do not grow with the bf16
+    pool. Memory only: no time is read here."""
+    aot_check, parts = _aot_parts("mistral-7b-v0.3-l16")
     n0, n1 = 1024, 2048
     r0, r1 = (aot_check.compile_bucket(*parts, 8, 1, 64, True, n)
               for n in (n0, n1))
@@ -511,6 +517,52 @@ def test_step_holds_no_copy_of_the_pool_on_v5e(v5e_device):
     copies = (r1["beyond_arguments_bytes"] - r0["beyond_arguments_bytes"]) \
         / (n1 - n0)
     assert copies < 0.05 * block, (r0, r1)
+
+
+@pytest.mark.parametrize("config,b,t", [
+    ("mistral-7b-v0.3-l16", 8, 1),
+    ("mistral-7b-v0.3-l16", 8, 512),
+    ("mistral-nemo-12b-l10", 8, 1),
+    ("k-exaone-236b-a23b-ep8-l5", 32, 1),
+])
+def test_step_reads_the_qkv_matrices_in_place_on_v5e(v5e_device, monkeypatch,
+                                                     config, b, t):
+    """A step of each of the benchmark's configurations, compiled for the
+    described v5e: nothing in it cuts a layer's ``wq``, ``wk`` or ``wv`` out
+    of its stack or copies one into another layout. The three products
+    reach the compiler as ``[N, H] x [H, out]`` and read the stack where it
+    lies, as ``wo`` and the MLP's three do (models/llama.py ``_layer``);
+    with the head split folded into the dot the weight operand was the
+    matrix transposed, and a slice and a copy of it, 15 % of a decode
+    step's device time, ran in every layer (PERF.md section 6, PR 40).
+    The program's text only: no time is read here."""
+    import re
+
+    aot_check, parts = _aot_parts(config)
+    runner, cfg = parts[:2]
+    texts = []
+    as_text = jax.stages.Compiled.as_text
+    monkeypatch.setattr(
+        jax.stages.Compiled, "as_text",
+        lambda self, *a, **kw: (texts.append(as_text(self, *a, **kw)),
+                                texts[-1])[1])
+    assert aot_check.compile_bucket(
+        *parts, b, t, runner.max_nblk, True, 2048)["kernel"]
+    (text,) = texts
+    mats = {(cfg.hidden_size, out) for out in (cfg.q_size, cfg.kv_size)}
+    mats |= {(out, h) for h, out in mats}
+    moved = []
+    for name, dims in re.findall(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]",
+                                 text, re.M):
+        shape = tuple(int(d) for d in dims.split(","))
+        if shape[0] == 1:
+            shape = shape[1:]
+        # ``copy-start`` / ``copy-done`` are a prefetch of a whole argument,
+        # beside the work, and not these.
+        if shape in mats and (name.split(".")[0] == "copy"
+                              or "slice" in name and "fusion" in name):
+            moved.append(f"{name} [{dims}]")
+    assert not moved, moved
 
 
 def test_paged_attention_kernel_parity_at_bench_shapes():
