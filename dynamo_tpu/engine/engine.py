@@ -180,7 +180,7 @@ class EngineMetrics:
     # by tp (set once): what a reader of a device trace looks for to find
     # the operations that move the cache.
     kv_cache_shape: tuple[int, ...] = ()
-    # A routed model served as a chip's share (moe_impl "held"; set once):
+    # A routed model on one chip (moe_impl "held"; set once):
     # experts held, the router's width, experts a token, and the matrices'
     # shapes a reader of a device trace finds the routed layer's operations
     # by (one expert stack's, the router's, the shared expert's).
@@ -424,14 +424,16 @@ class ModelRunner:
     @property
     def moe_impl(self) -> str:
         """The routed layer's formulation (models/moe.py): across an
-        "expert" axis the dropless sharded one; on one chip that holds a
-        share of the published experts the grouped one, which computes the
-        rows routed to the held experts and counts them; else every held
-        expert for every token (llama.moe_mlp: exact, and E/k times the
-        work, for the tiny presets that hold all of a few experts)."""
+        "expert" axis the dropless sharded one; on one chip the grouped one
+        (``held_rows`` over the layers' whole expert stack), which computes
+        the rows routed to the experts held here and counts them, whether
+        the chip holds a share of the published experts or all of them. No
+        engine serves the all-experts form (llama.moe_mlp: E/k times the
+        work); it is what the tests hold the grouped ones to. A model with
+        no routed layer reads "dense" and its programs return no counts."""
         if self.engine_cfg.ep > 1:
             return "ep"
-        return "held" if self.cfg.holds_share else "dense"
+        return "held" if self.cfg.is_moe else "dense"
 
     def _place(self, x):
         """Replicate onto the mesh (global array) or leave as-is off-mesh."""
@@ -1775,7 +1777,8 @@ class EngineCore:
                    if s.phase is not Phase.FINISHED)
 
     def _moe_facts(self) -> dict | None:
-        """``stats()["moe"]`` of a model served under ``moe_impl="held"``."""
+        """``stats()["moe"]`` of a routed model on one chip
+        (``moe_impl="held"``): a share of the experts or all of them."""
         if self.runner.moe_impl != "held":
             return None
         mc = self.model_cfg
@@ -1784,6 +1787,8 @@ class EngineCore:
         return {"experts_held": mc.num_experts,
                 "router_width": mc.router_width,
                 "experts_per_token": mc.num_experts_per_tok,
+                "expert_act": mc.expert_act,
+                "router_input": mc.router_input,
                 "routed_layers": self._routed_layers,
                 "hidden_size": h, "expert_width": m,
                 "bytes_per_param": jnp.dtype(mc.dtype).itemsize,

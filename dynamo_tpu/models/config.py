@@ -62,6 +62,13 @@ class ModelConfig:
     # "pre": h + f(norm(h)) (Llama). "post": h + norm(f(h)), the sub-layer's
     # input not normalised (EXAONE 4.0's block); the same two leaves.
     norm_placement: str = "pre"
+    # A routed expert's gate activation: "silu" (SwiGLU) | "relu" (ReGLU).
+    expert_act: str = "silu"
+    # What the router reads: "mlp_norm", the expert layer's own input, or
+    # "attn_norm", the state that enters attention (the attention norm's
+    # output under "pre"): the choice is made before attention and carried
+    # past it (SmallThinker's "router placed before attention").
+    router_input: str = "mlp_norm"
     # Multimodal (vision encoder attached)
     vision: "VisionConfig | None" = None
 
@@ -80,6 +87,12 @@ class ModelConfig:
         if self.first_k_dense and not self.is_moe:
             raise ValueError("first_k_dense leading dense layers in a model "
                              "with no routed layer: leave it 0")
+        if self.expert_act not in ("silu", "relu"):
+            raise ValueError(f"expert_act {self.expert_act!r}: the routed "
+                             "experts gate by 'silu' or 'relu'")
+        if self.router_input not in ("mlp_norm", "attn_norm"):
+            raise ValueError(f"router_input {self.router_input!r}: the "
+                             "router reads 'mlp_norm' or 'attn_norm'")
 
     @property
     def is_moe(self) -> bool:
@@ -128,6 +141,7 @@ class ModelConfig:
     def from_hf_config(cls, path: str) -> "ModelConfig":
         """Read a local HF config.json (llama-family keys)."""
         cfg = json.loads((Path(path) / "config.json").read_text())
+        cfg = _smallthinker_keys(cfg)
         n_heads = cfg["num_attention_heads"]
         # MoE keys across HF families: mixtral (num_local_experts),
         # deepseek/qwen-moe (n_routed_experts, num_experts).
@@ -160,9 +174,8 @@ class ModelConfig:
             num_experts=n_experts,
             num_experts_published=published or 0,
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2 if n_experts else 0),
-            moe_intermediate_size=cfg.get(
-                "moe_intermediate_size",
-                cfg["intermediate_size"] if n_experts else 0),
+            moe_intermediate_size=cfg.get("moe_intermediate_size")
+            or (cfg["intermediate_size"] if n_experts else 0),
             num_shared_experts=(cfg.get("n_shared_experts")
                                 or cfg.get("num_shared_experts") or 0),
             router_scoring="sigmoid" if sigmoid else "softmax",
@@ -179,10 +192,14 @@ class ModelConfig:
             qk_norm=bool(cfg.get("qk_norm", False)),
             rope_scope=cfg.get("rope_scope", "all"),
             norm_placement=cfg.get("norm_placement", "pre"),
+            expert_act=cfg.get("expert_act", "silu"),
+            router_input=cfg.get("router_input", "mlp_norm"),
             name=cfg.get("_name_or_path", Path(path).name),
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
-            intermediate_size=cfg["intermediate_size"],
+            # (a model with no dense layer need state no dense width)
+            intermediate_size=cfg.get("intermediate_size", 0)
+            if n_experts and not first_dense else cfg["intermediate_size"],
             num_layers=cfg["num_hidden_layers"],
             num_heads=n_heads,
             num_kv_heads=cfg.get("num_key_value_heads", n_heads),
@@ -193,6 +210,60 @@ class ModelConfig:
             max_position_embeddings=cfg.get("max_position_embeddings", 8192),
             tie_word_embeddings=cfg.get("tie_word_embeddings", False),
         )
+
+
+def _smallthinker_keys(cfg: dict) -> dict:
+    """``cfg`` with SmallThinker's own keys (PowerInfer's ``config.json``:
+    ``moe_num_primary_experts``, ``moe_num_active_primary_experts``,
+    ``moe_ffn_hidden_size``, ``sliding_window_layout``,
+    ``sliding_window_size``, ``rope_layout``) under the names
+    ``from_hf_config`` reads; any other config comes back as it is. The two
+    layouts give a layer's kind (1: windowed) and whether it carries rotary
+    positions (1: yes); the model has them equal, which ``rope_scope:
+    "sliding"`` states. What cannot be served is refused by its key."""
+    if "moe_num_primary_experts" not in cfg:
+        return cfg
+    secondary = [k for k, v in cfg.items() if "secondary" in k and v]
+    if secondary:
+        raise ValueError(
+            f"{secondary[0]}: secondary experts are not implemented "
+            "(models/moe.py routes over one set of primary experts)")
+    if not cfg.get("moe_primary_router_apply_softmax", True):
+        raise ValueError(
+            "moe_primary_router_apply_softmax: false (sigmoid scores "
+            "normalised over the chosen) is not implemented: models/moe.py "
+            "route() scores this family by softmax")
+    if not cfg.get("norm_topk_prob", True):
+        raise ValueError(
+            "norm_topk_prob: false (the softmax over all experts, not "
+            "renormalised over the chosen) is not implemented: models/moe.py "
+            "route() weighs by the softmax over the chosen logits")
+    if cfg.get("attention_bias"):
+        raise ValueError("attention_bias: true is not implemented: "
+                         "models/llama.py projects q, k, v and o without bias")
+    n_layers = cfg["num_hidden_layers"]
+    sliding = [int(x) for x in cfg.get("sliding_window_layout")
+               or [0] * n_layers][:n_layers]
+    rope = [int(x) for x in cfg.get("rope_layout") or [1] * n_layers][:n_layers]
+    if rope == [1] * n_layers:
+        scope = "all"
+    elif rope == sliding:
+        scope = "sliding"
+    else:
+        raise ValueError(
+            "rope_layout is neither every layer nor the sliding layers of "
+            "sliding_window_layout: positions by a layout of their own are "
+            "not implemented (models/llama.py _layer ropes by rope_scope)")
+    return {
+        **cfg,
+        "num_experts": cfg["moe_num_primary_experts"],
+        "num_experts_per_tok": cfg["moe_num_active_primary_experts"],
+        "moe_intermediate_size": cfg["moe_ffn_hidden_size"],
+        "layer_types": ["sliding_attention" if s else "full_attention"
+                        for s in sliding],
+        "sliding_window": cfg.get("sliding_window_size", 0),
+        "rope_scope": scope,
+    }
 
 
 @dataclass(frozen=True)

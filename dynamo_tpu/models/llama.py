@@ -30,6 +30,7 @@ and MLP intermediate on the "model" mesh axis, experts on "expert".
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, NamedTuple
 
@@ -122,15 +123,35 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     return axes
 
 
+#: A leaf of more elements than this is drawn a layer at a time: its
+#: float32 form (4 GiB and up) would not fit beside the finished weights.
+INIT_WHOLE_MAX = 2**30
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Random-init params (tests/tiny models; real weights come from loaders)."""
+    """Random-init params (tests/tiny models; real weights come from loaders).
+
+    Each leaf is drawn whole, as float32 normals, scaled and cast. One
+    static rule on a leaf's size: a stack of more than ``INIT_WHOLE_MAX``
+    elements is drawn a layer at a time instead, from its key split by
+    layer, so that the float32 temporary is one layer's (64 experts of
+    2560 x 768 over 12 layers are 6 GB of float32 whole, on a 16 GB chip
+    beside two finished stacks). Such a stack's values differ from what a
+    whole draw would give; every leaf under the limit keeps, bit for bit,
+    what its seed always gave."""
     dt = _dtype(cfg)
     k = iter(jax.random.split(key, 24))
     h = cfg.hidden_size
     L = cfg.num_layers - cfg.first_k_dense     # the repeated group
 
-    def dense(key, shape, fan_in):
+    def whole(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
+
+    def dense(key, shape, fan_in):
+        if math.prod(shape) <= INIT_WHOLE_MAX:
+            return whole(key, shape, fan_in)
+        return lax.map(lambda k1: whole(k1, shape[1:], fan_in),
+                       jax.random.split(key, shape[0]))
 
     def attention(L):
         out = {
@@ -396,21 +417,23 @@ def swiglu(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
     return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
 
 
-def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
+def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
+            routing=None) -> jax.Array:
     """MoE FFN, all-experts formulation (every held expert computed for
     every token, combined by the router's weights). Exact for any E and
-    E/k times the work: the plain form the grouped ones are tested against
-    (tests/test_moe.py) and what a tiny preset that holds every expert runs
-    at ep == 1; models/moe.py has the grouped formulations (``held_rows``,
-    what a chip that holds a share runs; the ep-sharded dropless one).
+    E/k times the work: the plain form the grouped ones are held to
+    (tests/test_moe.py, tests/test_smallthinker.py). No engine serves it:
+    on one chip every routed model runs the grouped ``held_rows``, share
+    or whole, and across an "expert" axis the dropless one (models/moe.py;
+    ``ModelRunner.moe_impl``). ``routing``: as ``moe_mlp_held``'s.
 
     x: [..., H] (token-major [N, H] in the step)
     """
-    from dynamo_tpu.models.moe import route
+    from dynamo_tpu.models.moe import gate_act, route
 
     h = x.shape[-1]
     xt = x.reshape(-1, h)                                     # [N, H]
-    topi, weights = route(xt, lp, cfg)                        # [N, k]
+    topi, weights = routing or route(xt, lp, cfg)             # [N, k]
     e = lp["w_gate"].shape[0]                                 # experts held
     gate_mask = jnp.zeros((xt.shape[0], cfg.router_width), jnp.float32)
     gate_mask = gate_mask.at[jnp.arange(xt.shape[0])[:, None], topi].add(
@@ -418,7 +441,7 @@ def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig) -> jax.Array:
     # all-experts compute: [N,E,m]
     up = jnp.einsum("nh,ehm->nem", xt, lp["w_up"])
     gate = jnp.einsum("nh,ehm->nem", xt, lp["w_gate"])
-    act = jax.nn.silu(gate) * up
+    act = gate_act(cfg)(gate) * up
     per_expert = jnp.einsum("nem,emh->neh", act, lp["w_down"])
     out = jnp.einsum("neh,ne->nh", per_expert.astype(jnp.float32), gate_mask).astype(x.dtype)
     if cfg.num_shared_experts:
@@ -505,13 +528,24 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     layer, query i sees the keys j with i - j < window), the FFN's kind
     (``lp`` has a ``router`` or it is dense) and with them, by the
     configuration, whether the layer carries positions at all
-    (``rope_scope``). ``counts`` is the routed layer's int32 [3] under
-    ``moe_impl="held"`` (models/moe.py ``held_rows``), else None; ``live``
-    [N] names the bucket's live tokens for it."""
+    (``rope_scope``), which state the router reads (``router_input``: the
+    expert layer's input, or the attention's, routed before attention and
+    carried past it) and the experts' activation (``expert_act``).
+    ``counts`` is the routed layer's int32 [3] under ``moe_impl="held"``
+    (models/moe.py ``held_rows``), else None; ``live`` [N] names the
+    bucket's live tokens for it."""
     n = hid.shape[0]
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
     post = cfg.norm_placement == "post"
     x = hid if post else rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
+    routing = None
+    if "router" in lp and cfg.router_input == "attn_norm":
+        # The router reads the state that enters attention; its choice
+        # rides past attention to the expert layer.
+        from dynamo_tpu.models.moe import route
+
+        with _perf_phase("moe_route"):
+            routing = route(x, lp, cfg)
     # The three products stay [N, out] up to the barrier and get their head
     # axis after it. A reshape XLA can fold into the dot makes the weight
     # operand [heads, D, H], the stored matrix transposed, and the compiler
@@ -590,19 +624,20 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
             # One chip told which experts it holds: grouped, with counts.
             from dynamo_tpu.models.moe import moe_mlp_held
 
-            mlp_out, counts = moe_mlp_held(x, lp, cfg, live)
+            mlp_out, counts = moe_mlp_held(x, lp, cfg, live, routing)
         elif moe_impl == "ep":
             # Dropless ragged dispatch (serving default for ep>1): exact
             # under any routing skew — see models/moe.py.
             from dynamo_tpu.models.moe import moe_mlp_dropless
 
-            mlp_out = moe_mlp_dropless(x, lp, cfg, mesh=mesh)
+            mlp_out = moe_mlp_dropless(x, lp, cfg, mesh=mesh,
+                                       routing=routing)
         elif moe_impl == "ep_capacity":
             from dynamo_tpu.models.moe import moe_mlp_ep
 
-            mlp_out = moe_mlp_ep(x, lp, cfg)
+            mlp_out = moe_mlp_ep(x, lp, cfg, routing=routing)
         else:
-            mlp_out = moe_mlp(x, lp, cfg)
+            mlp_out = moe_mlp(x, lp, cfg, routing)
     else:
         mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
     if post:
@@ -742,9 +777,11 @@ def forward(
     sp = mesh.shape.get("seq", 1) if mesh is not None else 1
     if mesh is not None and mesh.shape.get("pipe", 1) > 1:
         # Pipeline-parallel path: layer blocks sharded over "pipe".
-        return forward_pp(params, cfg, token_ids, q_start, q_len, block_tables,
-                          cache_k, cache_v, mesh, attn_impl=attn_impl,
-                          microbatches=pp_microbatches)
+        out = forward_pp(params, cfg, token_ids, q_start, q_len, block_tables,
+                         cache_k, cache_v, mesh, attn_impl=attn_impl,
+                         moe_impl=moe_impl, microbatches=pp_microbatches)
+        # (the stages' counts are not gathered: a program without them)
+        return (*out, None) if moe_counts else out
     if attn_impl in ("pallas", "pallas_interpret") and tp > 1 and (
         cfg.num_kv_heads % tp != 0 or b % dp != 0
     ):
@@ -822,6 +859,7 @@ def forward_pp(
     mesh,
     attn_impl: str = "dense",
     microbatches: int = 0,
+    moe_impl: str = "dense",
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Pipeline-parallel forward: layer blocks sharded over the "pipe" axis.
 
@@ -882,7 +920,7 @@ def forward_pp(
                 "the sequential dense-attention pipeline", b, t)
         return _forward_pp_sequential(
             params, cfg, lay, positions, q_start, q_start + q_len, slot,
-            block_tables, cache_k, cache_v, mesh, h0, q_len, pp)
+            block_tables, cache_k, cache_v, mesh, h0, q_len, pp, moe_impl)
 
     # Per-microbatch statics, uniformly [M, B'*T', ...] (token-major).
     if split_t:
@@ -925,7 +963,7 @@ def forward_pp(
             h_out, ck, cv, _ = _run_layers(
                 cfg, lp_stack, h_in, ck, cv, lay=lay_mb, positions=pos_mb[mbc],
                 slot=slot_t, block_tables=bt_mb[mbc], q_start=qs_mb[mbc],
-                kv_lens=kl_mb[mbc], attn_impl=attn_impl)
+                kv_lens=kl_mb[mbc], attn_impl=attn_impl, moe_impl=moe_impl)
             out = out.at[mbc].add(jnp.where((s == pp - 1) & live, h_out, 0))
             h_nxt = lax.ppermute(
                 h_out, "pipe", [(j, (j + 1) % pp) for j in range(pp)])
@@ -951,7 +989,8 @@ def forward_pp(
 
 
 def _forward_pp_sequential(params, cfg, lay, positions, q_start, kv_lens, slot,
-                           block_tables, cache_k, cache_v, mesh, h0, q_len, pp):
+                           block_tables, cache_k, cache_v, mesh, h0, q_len, pp,
+                           moe_impl="dense"):
     """Fallback pipeline for shapes too small to microbatch (e.g. a lone
     decode row): pp select-and-broadcast rounds — every stage computes the
     full batch each round, round i keeps stage i's result. Efficiency 1/pp;
@@ -965,7 +1004,7 @@ def _forward_pp_sequential(params, cfg, lay, positions, q_start, kv_lens, slot,
             h_out, ck_new, cv_new, _ = _run_layers(
                 cfg, lp_stack, h, ck_local, cv_local, lay=lay,
                 positions=positions, slot=slot, block_tables=block_tables,
-                q_start=q_start, kv_lens=kv_lens)
+                q_start=q_start, kv_lens=kv_lens, moe_impl=moe_impl)
             keep = s == i
             # tree_map: quantized caches are {"q","s"} pytrees.
             ck_local = jax.tree.map(lambda a, b: jnp.where(keep, a, b),

@@ -39,9 +39,19 @@ from dynamo_tpu.models.config import ModelConfig
 Params = dict
 
 
+def gate_act(cfg: ModelConfig):
+    """The routed experts' gate activation, ``cfg.expert_act``: every
+    formulation computes ``act(x W_gate) * (x W_up)`` with this one."""
+    return {"silu": jax.nn.silu, "relu": jax.nn.relu}[cfg.expert_act]
+
+
 def route(xt: jax.Array, lp: Params, cfg: ModelConfig):
     """The router, one function for every formulation and both scorings:
     ([N,k] expert ids over the router's whole width, [N,k] float32 weights).
+    ``xt`` [N, H] is the state the router reads, which need not be the
+    state the experts read: under ``cfg.router_input == "attn_norm"`` the
+    layer calls this on the attention's input and hands the result past
+    attention to the expert layer (models/llama.py ``_layer``).
 
     "softmax" (Mixtral): the k largest logits, weighted by the softmax over
     those k alone. "sigmoid" (DeepSeek-V3's, which K-EXAONE's keys name):
@@ -69,7 +79,7 @@ def route(xt: jax.Array, lp: Params, cfg: ModelConfig):
 
 
 def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
-              layer=None):
+              layer=None, act=jax.nn.silu):
     """The grouped formulation for the experts held here, the first
     ``E_held`` of the router's (a shard that holds others hands in ``topi``
     less its first expert's index: what falls outside ``0 .. E_held - 1`` is
@@ -80,7 +90,7 @@ def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
     experts held elsewhere sort behind the groups and belong to none:
     nothing is computed for them and nothing stands in for the chips that
     hold them. ``live`` [N] bool drops the rows of a bucket's padding tokens
-    the same way.
+    the same way. ``act`` is the gate's activation (:func:`gate_act`).
 
     ``w_*`` are one layer's slabs ``[E_held, H|M, M|H]`` or, with ``layer``
     (an index, may be traced), the whole stack ``[L, E_held, ...]`` as the
@@ -116,7 +126,7 @@ def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
     xs = xt[rows]                                     # [Nk, H]
     gate = lax.ragged_dot(xs, w_gate, group_sizes)    # [Nk, M]
     up = lax.ragged_dot(xs, w_up, group_sizes)
-    out = lax.ragged_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
+    out = lax.ragged_dot(act(gate) * up, w_down, group_sizes)
     # What a row behind the last group holds is not defined: select, do
     # not multiply.
     contrib = jnp.where(here[perm][:, None],
@@ -129,23 +139,30 @@ def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
     return y, counts
 
 
-def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None):
-    """The routed FFN of a chip that is told which experts it holds
+def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None,
+                 routing=None):
+    """The routed FFN of one chip, which is told which experts it holds
     (``lp["w_gate"]`` is ``[E_held, H, M]``: the first ``E_held`` of the
     ``cfg.router_width`` the router scores; or, with ``lp["expert_layer"]``,
     the layers' whole stack and this layer's place in it): route over all
     of them, compute the held experts' rows (:func:`held_rows`) and add the
-    shared expert once. With every expert held it is the whole layer. x
-    [N, H] -> ([N, H], int32 [3] counts). One chip: no exchange."""
+    shared expert once. With every expert held (``E_held`` is the router's
+    width) it is the whole layer. ``routing``: the ``(topi, weights)`` of a
+    router that read another state, earlier (:func:`route`); None routes
+    from ``x``. x [N, H] -> ([N, H], int32 [3] counts). One chip: no
+    exchange."""
     from dynamo_tpu.models.llama import swiglu
     from dynamo_tpu.obs.profiler import phase
 
     xt = x.reshape(-1, x.shape[-1])
-    with phase("moe_route"):
-        topi, weights = route(xt, lp, cfg)
+    if routing is None:
+        with phase("moe_route"):
+            routing = route(xt, lp, cfg)
+    topi, weights = routing
     with phase("moe_experts"):
         y, counts = held_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
-                              lp["w_down"], live, lp.get("expert_layer"))
+                              lp["w_down"], live, lp.get("expert_layer"),
+                              gate_act(cfg))
     if cfg.num_shared_experts:
         with phase("moe_shared"):
             y = y + swiglu(xt, lp["shared_gate"], lp["shared_up"],
@@ -154,11 +171,13 @@ def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None):
 
 
 def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
-                     mesh=None) -> jax.Array:
+                     mesh=None, routing=None) -> jax.Array:
     """Dropless MoE FFN. x: [..., H] → [..., H] (token-major [N, H] in the
     step); exact vs the dense reference under ANY routing skew
-    (tests/test_moe.py pressure tests)."""
+    (tests/test_moe.py pressure tests). ``routing``: as
+    :func:`moe_mlp_held`'s, [N, k] each, sharded with the tokens."""
     b, h = x.shape[0], x.shape[-1]
+    act = gate_act(cfg)
     e = cfg.num_experts
     ep = mesh.shape.get("expert", 1) if mesh is not None else 1
 
@@ -168,9 +187,9 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
     )
     if ep <= 1 or e % ep != 0:
         xt = x.reshape(-1, h)
-        topi, weights = route(xt, lp, cfg)
+        topi, weights = routing or route(xt, lp, cfg)
         y, _ = held_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
-                         lp["w_down"])
+                         lp["w_down"], act=act)
         if shared is not None:
             from dynamo_tpu.models.llama import swiglu
 
@@ -183,18 +202,21 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
         rlp = {"router": router}
         if cfg.router_bias:
             rlp["router_bias"], *rest = rest
+        routed = None
+        if routing is not None:
+            routed, rest = rest[:2], rest[2:]
         shared_w = tuple(rest)
         # Each device owns (its expert slab) x (its slice of the expert
         # intermediate dim, on TEP meshes where "model" also shards M).
-        # gate/up slice M locally (silu is columnwise-exact); w_down
+        # gate/up slice M locally (the activation is columnwise); w_down
         # contracts the local M slice, so y is a partial sum over BOTH
         # axes — one fp32 psum completes expert combine and TEP contraction.
         e_lo = lax.axis_index("expert") * e_local
         xt = x3.reshape(-1, h)
-        topi, weights = route(xt, rlp, cfg)
+        topi, weights = routed or route(xt, rlp, cfg)
         # Partial over the axis and float32: the psum below sums the
         # shards in float32 and the cast is made once.
-        y, _ = held_rows(xt, topi - e_lo, weights, wg, wu, wd)
+        y, _ = held_rows(xt, topi - e_lo, weights, wg, wu, wd, act=act)
         if shared_w:
             from dynamo_tpu.models.llama import swiglu
 
@@ -223,6 +245,9 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
         # like the router it steers, whole on every device ([E])
         args.append(lp["router_bias"])
         in_specs.append(P())
+    if routing is not None:
+        args.extend(routing)
+        in_specs.extend([batch_spec, batch_spec])
     if shared is not None:
         args.extend(shared)
         in_specs.extend([P(None, "model"), P(None, "model"), P("model", None)])
@@ -242,7 +267,7 @@ def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
 
 
 def moe_mlp_ep(x: jax.Array, lp: Params, cfg: ModelConfig,
-               capacity_factor: float = 2.0) -> jax.Array:
+               capacity_factor: float = 2.0, routing=None) -> jax.Array:
     """Capacity-based EP MoE FFN. x: [..., H] → [..., H].
 
     The dispatch/combine tensors route each token's top-k expert choices to
@@ -254,7 +279,7 @@ def moe_mlp_ep(x: jax.Array, lp: Params, cfg: ModelConfig,
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     xt = x.reshape(-1, h)
     n = xt.shape[0]
-    topi, weights = route(xt, lp, cfg)                            # [N, k]
+    topi, weights = routing or route(xt, lp, cfg)                 # [N, k]
 
     cap = expert_capacity(n, e, k, capacity_factor)
     # Position of each (choice, token) within its expert's buffer. Flatten
@@ -283,7 +308,7 @@ def moe_mlp_ep(x: jax.Array, lp: Params, cfg: ModelConfig,
     expert_in = expert_in.astype(x.dtype)
     gate = jnp.einsum("ech,ehm->ecm", expert_in, lp["w_gate"])
     up = jnp.einsum("ech,ehm->ecm", expert_in, lp["w_up"])
-    act = jax.nn.silu(gate) * up
+    act = gate_act(cfg)(gate) * up
     out_e = jnp.einsum("ecm,emh->ech", act, lp["w_down"])                # [E, C, H]
     y = jnp.einsum("nec,ech->nh", combine, out_e.astype(jnp.float32)).astype(x.dtype)
 

@@ -271,8 +271,21 @@ def test_newest_xplane_takes_only_this_runs_trace(tmp_path):
 # ---------------------------------------------------------------------------
 
 ROUTED_CELL = "k-exaone-236b.reasoning"
+# Both routed cells: one chip's share of a wider router (PR 39) and a model
+# whose every expert is held here (PR 41). What each states of itself.
+ROUTED_CELLS = {
+    ROUTED_CELL: {
+        "config": "k-exaone-236b-a23b-ep8-l5",
+        "experts": ("num_experts", 16, "num_experts_published", 128,
+                    "num_experts_per_tok", 8)},
+    "smallthinker-21b.reasoning": {
+        "config": "smallthinker-21b-a3b-l12",
+        "experts": ("moe_num_primary_experts", 64, None, None,
+                    "moe_num_active_primary_experts", 6)},
+}
 ROUTED = ("moe.rows_per_expert_step", "device.moe_pct",
-          "moe.expert_gemm_roofline_pct", "attn.blocks_walked_pct")
+          "moe.expert_gemm_roofline_pct", "attn.blocks_walked_pct",
+          "moe.experts_touched_pct")
 MOE = {"experts_held": 16, "router_width": 128, "experts_per_token": 8,
        "routed_layers": 4, "hidden_size": 6144, "expert_width": 2048,
        "bytes_per_param": 2,
@@ -298,9 +311,11 @@ def _routed_counters() -> tuple[dict, dict]:
                                 "kv_cache_shape": [5, 19291, 16, 8, 128]})
 
 
-def test_the_routed_cell_reports_what_it_is_judged_on():
-    cell = manifest.load_cell(ROUTED_CELL)
-    assert cell.chips == 1 and cell.config_name == "k-exaone-236b-a23b-ep8-l5"
+@pytest.mark.parametrize("name", sorted(ROUTED_CELLS))
+def test_the_routed_cell_reports_what_it_is_judged_on(name):
+    facts = ROUTED_CELLS[name]
+    cell = manifest.load_cell(name)
+    assert cell.chips == 1 and cell.config_name == facts["config"]
     assert cell.end_to_end == ["itl_p95_ms", "tokens_per_s", "setup_s"]
     assert set(ROUTED) <= set(cell.per_layer)
     for other in ("mistral-7b.chat", "mistral-7b.longprompt",
@@ -316,8 +331,7 @@ def test_the_routed_cell_reports_what_it_is_judged_on():
     from harness import sut
 
     from dynamo_tpu.obs.compile_ledger import sig_for_rows
-    sweep = json.loads((ROOT / "chipbench/sweeps"
-                        / f"{ROUTED_CELL}.json").read_text())
+    sweep = json.loads((ROOT / "chipbench/sweeps" / f"{name}.json").read_text())
     kept_up = [r["rate_per_s"] for r in sweep["rates"] if r["kept_up"]]
     assert sweep["knee_per_s"] == max(kept_up)
     assert tr["rate_per_s"] == pytest.approx(0.8 * sweep["knee_per_s"])
@@ -326,13 +340,57 @@ def test_the_routed_cell_reports_what_it_is_judged_on():
     ec = sut.engine_config(cell.config_dir, cell.about)
     assert tr["max_rows"] == sig_for_rows(
         "decode", at["in_flight_max"], 1, 1, ec).b
-    # the share, as config.json states it
-    assert (cell.model["num_experts"], cell.model["num_experts_published"],
-            cell.model["num_experts_per_tok"]) == (16, 128, 8)
+    # the experts held, the router's width and the choices a token, as
+    # config.json states them under the model's own keys
+    held_key, held, wide_key, wide, k_key, k = facts["experts"]
+    assert (cell.model[held_key], cell.model[k_key]) == (held, k)
+    assert wide_key is None or cell.model[wide_key] == wide
     assert sorted(cell.about["reduced"]) == sorted(
         next(c for c in BENCH["configs"]
              if c["name"] == cell.config_name)["reduced"])
     assert set(cell.model["assumed"]) == set(cell.about["assumed"])
+
+
+def test_every_cell_reports_what_its_per_layer_metrics_move():
+    """A per-layer entry names one end-to-end metric, and every cell it is
+    read in has to report that metric (``manifest.cell_metrics``): the new
+    cell's name in a ``workloads`` list is held to it like the others."""
+    entries = {m["name"]: m for m in BENCH["per_layer"]}
+    for w in BENCH["workloads"]:
+        cell = manifest.load_cell(w["name"])
+        assert cell.per_layer, w["name"]
+        for metric in cell.per_layer:
+            assert entries[metric]["moves"] in cell.end_to_end, (
+                w["name"], metric)
+        listed = {m for m, e in entries.items()
+                  if w["name"] in e.get("workloads", ())}
+        assert listed <= set(cell.per_layer), w["name"]
+
+
+def _touched(steps: int, touched: int | None, held: int | None = 64):
+    sched0 = {"moe_layer_steps_total": 100, "moe_experts_touched_total": 700}
+    sched1 = {"moe_layer_steps_total": 100 + steps}
+    if touched is not None:
+        sched1["moe_experts_touched_total"] = 700 + touched
+    return _ctx({"sched": sched0},
+                {"sched": sched1, **({"moe": {"experts_held": held}}
+                                     if held else {})})
+
+
+@pytest.mark.parametrize("ctx, expect", [
+    # 1,200 layer-steps of 64 held experts: none, 51 of 64, every one
+    (_touched(1_200, 0), 0.0),
+    (_touched(1_200, 61_200), 100.0 * 51 / 64),
+    (_touched(1_200, 76_800), 100.0),
+    # no routed layer-step in the window; a program without the counter; a
+    # model without a routed layer
+    (_touched(0, 0), None),
+    (_touched(1_200, None), None),
+    (_touched(1_200, 61_200, held=None), None),
+], ids=["none", "a_fraction", "all", "no_steps", "no_counter", "no_facts"])
+def test_experts_touched_share_on_a_hand_made_context(ctx, expect):
+    value = measure.load_reader("moe.experts_touched_pct").read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))
 
 
 @pytest.mark.parametrize("name, expect", [
@@ -340,6 +398,8 @@ def test_the_routed_cell_reports_what_it_is_judged_on():
     ("moe.rows_per_expert_step", 2.0),
     # 140,000 blocks walked of 100,000 held x 5 layers
     ("attn.blocks_walked_pct", 28.0),
+    # 56,000 experts touched of 4,000 layer-steps x 16 held
+    ("moe.experts_touched_pct", 87.5),
 ])
 def test_routed_counter_reader_on_a_hand_made_context(name, expect):
     value = measure.load_reader(name).read(_ctx(*_routed_counters()))

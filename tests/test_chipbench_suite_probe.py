@@ -54,3 +54,64 @@ def test_a_configuration_with_its_own_reference_keeps_the_contract(cfg):
     assert (lim.logprob_tol < down["worst_logprob_diff"]
             or lim.argmax_tol < down["worst_argmax_gap"]
             or lim.rms_tol < down["rms_logprob_diff"])
+
+
+SMALLTHINKER = (_bench.manifest.ROOT / "chipbench" / "configs"
+                / "smallthinker-21b-a3b-l12")
+
+
+def test_smallthinkers_reference_is_found_accepted_and_agrees_at_a_toy_size(
+        tmp_path):
+    """The probe's own finder loads the configuration's ``reference.py``,
+    ``manifest.check`` has no fault with the directory (``logits_at`` and
+    ``routing_margin_at`` with ``margin`` and ``max_tied_share``, no import
+    of ``dynamo_tpu``), and at a toy size of the same keys on the CPU its
+    logits are the program's and its margins have the probe's shape."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+
+    path = _bench.probe.reference_path(SMALLTHINKER)
+    assert path == SMALLTHINKER / "reference.py"
+    reference = _bench.probe.load_reference(path)
+    assert callable(reference.routing_margin_at)
+    about = json.loads((SMALLTHINKER / "about.json").read_text())
+    assert _bench.manifest.probe_faults(SMALLTHINKER, about) == []
+    assert _bench.manifest.check() == []
+
+    model = {**json.loads((SMALLTHINKER / "config.json").read_text()),
+             "hidden_size": 64, "head_dim": 16, "num_attention_heads": 4,
+             "num_key_value_heads": 2, "moe_ffn_hidden_size": 32,
+             "moe_num_primary_experts": 8, "moe_num_active_primary_experts": 3,
+             "num_hidden_layers": 4, "rope_layout": [0, 1, 1, 1],
+             "sliding_window_layout": [0, 1, 1, 1], "sliding_window_size": 12,
+             "vocab_size": 128}
+    (tmp_path / "config.json").write_text(json.dumps(model))
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(str(tmp_path)),
+                              dtype="float32")
+    params = llama.init_params(cfg, jax.random.key(2))
+    n, at = 40, [5, 20, 38, 39]
+    tokens = np.random.default_rng(3).integers(0, 128, n).tolist()
+    ids = jnp.asarray([tokens], jnp.int32)
+    shape = (cfg.num_layers, 5, 16, cfg.num_kv_heads, cfg.head_dim)
+    hid, *_ = llama.forward(
+        params, cfg, ids, jnp.zeros((1,), jnp.int32),
+        jnp.asarray([n], jnp.int32), jnp.arange(1, 4, dtype=jnp.int32)[None],
+        jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32),
+        moe_impl="held", return_all_hidden=True)
+    got = np.asarray(llama.logits_from_hidden(params, cfg, hid[0]))[at]
+    want = reference.logits_at(params, model, tokens, at, pad_to=64)
+    assert want.shape == (len(at), 128) and want.dtype == np.float32
+    # float32 on both sides over four layers, other orders of summation
+    assert np.max(np.abs(got - want)) < 2e-4
+    margins = reference.routing_margin_at(params, model, tokens, at, pad_to=64)
+    assert margins.shape == (len(at),) and margins.dtype == np.float32
+    assert (margins >= 0).all() and np.isfinite(margins).all()
+    # a later position can only have met more near-ties on its way
+    own = reference.routing_margin_at(params, model, tokens[:6], [5])
+    assert margins[0] == pytest.approx(float(own[0]), abs=1e-5)
