@@ -656,10 +656,17 @@ def _run_layers(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, **kw):
     layers of a period differ (sliding and full attention), the difference
     is static structure of the body, never a traced branch. The cache
     enters the loop once, whole, as carried state beside the hidden state,
-    addressed by absolute layer index; only the params and the index ride
-    xs. (The cache must not ride xs→ys: XLA then cuts each layer out, stacks
-    it back and keeps a second K and V as a temporary, which cost over half
-    of a decode step on the v5e — PERF.md section 6.)
+    addressed by absolute layer index. (The cache must not ride xs→ys: XLA
+    then cuts each layer out, stacks it back and keeps a second K and V as a
+    temporary, which cost over half of a decode step on the v5e — PERF.md
+    section 6.) A period of one carries its layer's params and index on xs.
+    A longer period carries the period's index alone: the body takes each
+    of its layers' params from the repeated group's stack at that layer's
+    own place, so every matrix has the one dot that reads it for a consumer
+    and is read where it lies. (A period's ``[p, ...]`` slice on xs has
+    ``p`` consumers: XLA then copies the period's ``wq``, ``wo``, ``wk`` and
+    ``wv`` out of the stack in every trip and the dots read the copy, 8 %
+    of a decode step at four layers a period — PERF.md section 6, PR 42.)
 
     ``counts``: under ``moe_impl="held"`` the routed layers' int32 [3]
     summed over them, carried beside the cache; else None. There the
@@ -701,18 +708,16 @@ def _run_layers(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, **kw):
                             (rep, index + n_lead if n_lead else index))
     else:
         whole = n // p
-        periods = jax.tree.map(
-            lambda a: a[:whole * p].reshape(whole, p, *a.shape[1:]), rep)
 
-        def period_fn(carry, xs):
-            lps, i = xs
+        def period_fn(carry, i):
             for j, window in enumerate(period):
-                carry = one(carry, at(lps, j), n_lead + i * p + j, window)
+                k = i * p + j
+                carry = one(carry, at(rep, k), n_lead + k, window)
             return carry, None
 
         if whole:
             carry, _ = lax.scan(period_fn, carry,
-                                (periods, jnp.arange(whole, dtype=jnp.int32)))
+                                jnp.arange(whole, dtype=jnp.int32))
         for j in range(whole * p, n):
             carry = one(carry, at(rep, j), n_lead + j, period[j % p])
     return (*carry, None) if not counted else carry

@@ -524,17 +524,24 @@ def test_step_holds_no_copy_of_the_pool_on_v5e(v5e_device):
     ("mistral-7b-v0.3-l16", 8, 512),
     ("mistral-nemo-12b-l10", 8, 1),
     ("k-exaone-236b-a23b-ep8-l5", 32, 1),
+    ("smallthinker-21b-a3b-l12", 16, 1),
+    ("smallthinker-21b-a3b-l12", 16, 512),
 ])
 def test_step_reads_the_qkv_matrices_in_place_on_v5e(v5e_device, monkeypatch,
                                                      config, b, t):
     """A step of each of the benchmark's configurations, compiled for the
-    described v5e: nothing in it cuts a layer's ``wq``, ``wk`` or ``wv`` out
-    of its stack or copies one into another layout. The three products
-    reach the compiler as ``[N, H] x [H, out]`` and read the stack where it
-    lies, as ``wo`` and the MLP's three do (models/llama.py ``_layer``);
-    with the head split folded into the dot the weight operand was the
-    matrix transposed, and a slice and a copy of it, 15 % of a decode
-    step's device time, ran in every layer (PERF.md section 6, PR 40).
+    described v5e: nothing in it cuts a layer's ``wq``, ``wk``, ``wv`` or
+    ``wo`` out of its stack, alone or a period of them at a time, or copies
+    one into another layout. The three products reach the compiler as
+    ``[N, H] x [H, out]`` and read the stack where it lies, as ``wo`` and
+    the MLP's three do (models/llama.py ``_layer``); with the head split
+    folded into the dot the weight operand was the matrix transposed, and a
+    slice and a copy of it, 15 % of a decode step's device time, ran in
+    every layer (PERF.md section 6, PR 40). A scan over periods of several
+    layers takes each layer's matrices from the stack by the layer's own
+    index (``_run_layers``); with a period's slice on the scan's xs a
+    ``[4, 3584, 2560]``, a ``[4, 2560, 3584]`` and two ``[4, 2560, 512]``
+    were copied out in every trip, 8 % (PR 42).
     The program's text only: no time is read here."""
     import re
 
@@ -549,20 +556,33 @@ def test_step_reads_the_qkv_matrices_in_place_on_v5e(v5e_device, monkeypatch,
     assert aot_check.compile_bucket(
         *parts, b, t, runner.max_nblk, True, 2048)["kernel"]
     (text,) = texts
-    mats = {(cfg.hidden_size, out) for out in (cfg.q_size, cfg.kv_size)}
-    mats |= {(out, h) for h, out in mats}
+    h, q, kv = cfg.hidden_size, cfg.q_size, cfg.kv_size
+    mats = {(h, q), (h, kv), (q, h)}              # wq; wk and wv; wo
+    mats |= {shape[::-1] for shape in mats}
     moved = []
     for name, dims in re.findall(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]",
                                  text, re.M):
         shape = tuple(int(d) for d in dims.split(","))
-        if shape[0] == 1:
-            shape = shape[1:]
-        # ``copy-start`` / ``copy-done`` are a prefetch of a whole argument,
-        # beside the work, and not these.
-        if shape in mats and (name.split(".")[0] == "copy"
-                              or "slice" in name and "fusion" in name):
+        # A matrix behind any leading dimensions: one layer's ``1``, a
+        # period's ``p``, a stack's ``L``. ``copy-start`` / ``copy-done``
+        # are a prefetch of a whole argument, beside the work, and not these.
+        if shape[-2:] in mats and (name.split(".")[0] == "copy"
+                                   or "slice" in name and "fusion" in name):
             moved.append(f"{name} [{dims}]")
     assert not moved, moved
+
+
+def test_step_keeps_no_copy_of_a_period_on_v5e(v5e_device):
+    """The benchmark's 12-layer SmallThinker cut (three periods of four
+    layers), a 16-row decode step compiled for the described v5e: beyond
+    its arguments the program keeps a few megabytes, not a period's
+    attention matrices (184 MB with a period's slice on the scan's xs,
+    5.5 MB with each layer indexed in the stack: PERF.md section 6, PR 42).
+    Memory only: no time is read here."""
+    aot_check, parts = _aot_parts("smallthinker-21b-a3b-l12")
+    r = aot_check.compile_bucket(*parts, 16, 1, parts[0].max_nblk, True, 2048)
+    assert r["kernel"]
+    assert r["beyond_arguments_bytes"] < 32 * 2**20, r
 
 
 def test_paged_attention_kernel_parity_at_bench_shapes():
