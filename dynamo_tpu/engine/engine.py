@@ -19,6 +19,7 @@ import collections
 import contextlib
 import dataclasses
 import itertools
+import json
 import math
 import queue as thread_queue
 import threading
@@ -75,15 +76,18 @@ from dynamo_tpu.obs.compile_ledger import (
 from dynamo_tpu.obs.profiler import (
     LoopClock,
     StepPerfProfiler,
+    loop_iteration,
     loop_phase,
     phase as _perf_phase,
+    phase_table,
+    phase_table_path,
+    register_phase_source,
 )
 from dynamo_tpu.obs.mem_ledger import get_mem_ledger, live_ids_of
 from dynamo_tpu.obs.sched_ledger import (
     HolStall,
     get_sched_ledger,
-    kv_blocks_live,
-    kv_blocks_walked,
+    step_counts,
     step_geometry,
 )
 from dynamo_tpu.obs.tracer import get_tracer, trace_context_of
@@ -118,6 +122,13 @@ def _named(fn: Callable, name: str) -> Callable:
 
 
 _NO_PHASE = contextlib.nullcontext()
+
+# What a step's ``engine.record`` span carries of its one count
+# (obs/sched_ledger.py step_counts), and of the routed layers' device counts:
+# what chipbench/layers/step_work_counts.py prices, and no more.
+_RECORD_SPAN_COUNTS = (
+    "programs", "live_tokens", "logit_rows", "attn_q_ctx", "kv_blocks_walked")
+_RECORD_SPAN_MOE = ("moe_layer_steps", "moe_rows", "moe_experts_touched")
 
 
 @jax.jit
@@ -185,6 +196,11 @@ class EngineMetrics:
     # shapes a reader of a device trace finds the routed layer's operations
     # by (one expert stack's, the router's, the shared expert's).
     moe: dict | None = None
+    # The shapes that price a step's counts (set once;
+    # obs/costmodel.py step_shapes): per kind of layer the parameters a
+    # program reads whatever its rows, one expert's, the head's,
+    # bytes_per_param, the bytes of a KV block of one layer.
+    step_shapes: dict | None = None
     # The pool as sized (set once): its blocks, one block's bytes on each
     # device (K and V), and the bytes a step holds for each block of the
     # pool BEYOND the pool itself, from XLA's buffer assignment of the
@@ -210,6 +226,7 @@ class EngineMetrics:
             "kv_quant_enabled": self.kv_quant_enabled,
             "kv_cache_shape": list(self.kv_cache_shape),
             **({"moe": self.moe} if self.moe else {}),
+            **({"step_shapes": self.step_shapes} if self.step_shapes else {}),
             "kv_pool_blocks": self.kv_pool_blocks,
             "kv_block_bytes": self.kv_block_bytes,
             "kv_step_copy_bytes_per_block": self.kv_step_copy_bytes_per_block,
@@ -243,6 +260,12 @@ class PendingStep:
     the rows' proposal chunks."""
 
     batches: list[tuple[BucketSig, list, list, Any, Any]] = field(default_factory=list)
+    # The step's ordinal (what ``num_steps`` counts): rides the spans a
+    # reader joins by it, the step's programs, its wait and its record.
+    step: int = 0
+    # Beside each batch: the name its program was built under
+    # (``BucketSig.program``), as the device trace shows it less the hash.
+    programs: list[str] = field(default_factory=list)
     # Beside each batch: the device int32 [3] of its routed layers' counts
     # (models/moe.py held_rows), None where the program has none.
     moe: list = field(default_factory=list)
@@ -657,8 +680,9 @@ class ModelRunner:
             # by an in-flight step read it from slot_toks instead of the host
             # tokens array (which holds 0 for them) — XLA's execution order
             # guarantees the producing step has run.
-            first = jnp.where(from_slot, slot_toks[slots], tokens[:, 0])
-            tokens = tokens.at[:, 0].set(first)
+            with _perf_phase("layout"):
+                first = jnp.where(from_slot, slot_toks[slots], tokens[:, 0])
+                tokens = tokens.at[:, 0].set(first)
             rest = list(mm_args)
             emb_override = rest.pop(0) if mm else None
             emb_mask = rest.pop(0) if mm else None
@@ -671,14 +695,16 @@ class ModelRunner:
                 embed_mask=emb_mask,
                 pp_microbatches=pp_micro,
                 num_tokens=n_tok, moe_counts=moe_impl == "held")
-            logits = llama.logits_from_hidden(params, cfg, hidden).astype(jnp.float32)
-            if masked:
-                # Structured output (engine/guided.py): the grammar's
-                # per-row allow-mask, additive in log space. The model
-                # program is untouched — only the sampling input shifts.
-                logits = logits + logit_mask
-            write_slots = jnp.where(do_sample, slots, trash_row)
+            with _perf_phase("logits"):
+                logits = llama.logits_from_hidden(
+                    params, cfg, hidden).astype(jnp.float32)
+                if masked:
+                    # Structured output (engine/guided.py): the grammar's
+                    # per-row allow-mask, additive in log space. The model
+                    # program is untouched — only the sampling input shifts.
+                    logits = logits + logit_mask
             with _perf_phase("sampling"):
+                write_slots = jnp.where(do_sample, slots, trash_row)
                 if fast_greedy:
                     # Whole batch greedy + penalty-free (host-verified at
                     # dispatch): argmax over raw logits is bit-identical to
@@ -698,19 +724,24 @@ class ModelRunner:
                     # Only sampling rows persist state; others write to trash.
                     counts = counts.at[write_slots].set(new_counts)
                     keys = keys.at[write_slots].set(new_keys)
-            slot_toks = slot_toks.at[write_slots].set(toks)
+                slot_toks = slot_toks.at[write_slots].set(toks)
             # (*moe: the routed layers' counts under moe_impl="held", a
             # last output that a program without them does not have.)
             return ck, cv, counts, keys, slot_toks, toks, lps, *moe
 
-        name = (f"step_decode_b{b}_n{nblk}" if t == 1
-                else f"step_mixed_b{b}_t{t}_k{n_tok}_n{nblk}")
-        for flag, suffix in ((sp_prefill, "_sp"), (not fast_greedy, "_sampled"),
-                             (mm, "_mm"), (masked, "_masked")):
-            if flag:
-                name += suffix
+        name = self._step_program(b, t, nblk, sp_prefill, fast_greedy, mm,
+                                  masked)
         return jax.jit(_named(step, name), donate_argnums=(1, 2, 3, 4, 5),
                        **self._jit_shardings())
+
+    def _step_program(self, b: int, t: int, nblk: int, sp_prefill: bool,
+                      fast_greedy: bool, mm: bool, masked: bool) -> str:
+        """The name of the step program of a ``_step_fns`` key, from the one
+        place that names programs (``BucketSig.program``)."""
+        return BucketSig(
+            "decode" if t == 1 else "mixed", b, t, nblk, fast_greedy,
+            self.engine_cfg.kv_dtype or "bfloat16").program(
+                sp_prefill=sp_prefill, mm=mm, masked=masked)
 
     def _jit_shardings(self) -> dict:
         """Pin step-output shardings on a mesh: cache keeps its TP layout;
@@ -734,6 +765,47 @@ class ModelRunner:
             self._step_fns[key] = self._build_step_fn(
                 b, t, nblk, sp_prefill, fast_greedy, mm, masked)
         return self._step_fns[key]
+
+    def phase_tables(self, programs=None) -> dict[str, dict[str, str]]:
+        """``{program: {instruction: innermost phase}}`` of the step
+        programs built so far (of those named in ``programs``, where given):
+        each lowered again with padding inputs beside the live state, which
+        jit answers from what it holds for the serving call, and its text
+        read by obs/profiler.py ``phase_table``. Tens of milliseconds a
+        program: for after a traced run (``AsyncJaxEngine.shutdown``) or an
+        operator's question (``/debug/phases``), not for the serving path. A
+        program that does not lower again (a live engine donated the cache
+        under it) is left out, with a warning: ask again."""
+        place = self._place
+        out: dict[str, dict[str, str]] = {}
+        for key in list(self._step_fns):
+            if isinstance(key[0], str):
+                continue                  # a verify or an embed program
+            name = "jit_" + self._step_program(*key)
+            if programs is not None and name not in programs:
+                continue
+            b, t, nblk, _sp, _greedy, mm, masked = key
+            extra = ((place(np.zeros((b, t, self.cfg.hidden_size), np.float32)),
+                      place(np.zeros((b, t), bool))) if mm else ())
+            if masked:
+                extra += (place(np.zeros((b, self.cfg.vocab_size),
+                                         np.float32)),)
+            try:
+                # The arrays themselves, as the serving call hands them
+                # in: the lowering and the executable are then the ones jit
+                # holds already (0.05 s a program on the chip; from shapes
+                # with the same shardings it is another module, a compile
+                # of its own: 14 s for a routed chunk step, PERF.md).
+                text = self._step_fns[key].lower(
+                    self.params, self.cache_k, self.cache_v, self.counts,
+                    self.keys, self.slot_toks,
+                    *self._padding_inputs(b, t, nblk), *extra
+                ).compile().as_text()
+            except Exception:
+                log.warning("no phase table for %s", name, exc_info=True)
+                continue
+            out[name] = phase_table(text)
+        return out
 
     def used_fast_greedy(self) -> bool:
         """Whether any compiled step so far took the argmax-only greedy
@@ -783,11 +855,18 @@ class ModelRunner:
         rows: list[tuple[Seq, int, int]],  # (seq, start, length) per row
         sample_rows: list[bool],
         masks: list | None = None,  # per-row bool[V] allow-masks (guided)
-    ) -> tuple[BucketSig, jax.Array, jax.Array, "jax.Array | None"]:
+        *, step: int = 0,
+    ) -> tuple[BucketSig, str, jax.Array, jax.Array, "jax.Array | None"]:
         """Enqueue one bucketed step on the device WITHOUT blocking; returns
-        the signature of the program it ran and device arrays (tokens [B],
-        logprobs likewise, and the routed layers' counts int32 [3] or None)
-        still being computed. The caller overlaps host
+        the signature of the program it ran, the program's name and device
+        arrays (tokens [B], logprobs likewise, and the routed layers' counts
+        int32 [3] or None) still being computed. ``step`` (the step's
+        ordinal) rides the ``engine.program`` span around the call with the
+        program's name, which holds its bucket: what a reader joins the
+        device's ``XLA Modules`` event to.
+        Inside it the host's work has three parts, ``engine.dispatch.fill``
+        (the numpy inputs), ``.place`` (host to device) and ``.launch`` (the
+        jitted call). The caller overlaps host
         work (scheduling, output assembly for earlier steps) with the
         device, then materializes via ``np.asarray``. A batch whose longest
         row is one token is the decode program; anything else is the ragged
@@ -798,9 +877,63 @@ class ModelRunner:
         follows from the (b, t) picked here (``token_bucket``); the rows
         hold no more live tokens than that, which the caller sees to by
         cutting a step with ``pack_rows``."""
+        span = jax.profiler.TraceAnnotation("engine.program")
+        clock = self.loop_clock
+        with span:
+            with loop_phase(clock, "engine.dispatch.fill"):
+                sig, sp_prefill, arrays, mm, masked = self._fill_inputs(
+                    rows, sample_rows, masks)
+            kind, b, t, nblk = sig.kind, sig.b, sig.t, sig.nblk
+            fast_greedy = sig.greedy
+            led = self._ledger
+            n_tok = _step_tokens(b, t, sp_prefill)
+            live = sum(length for _, _, length in rows)
+            if live > n_tok:
+                raise ValueError(
+                    f"{live} live tokens in a {kind} batch whose "
+                    f"bucket (b={b}, t={t}) holds {n_tok}: cut it with "
+                    "pack_rows")
+            miss = ((b, t, nblk, sp_prefill, fast_greedy, mm, masked)
+                    not in self._step_fns)
+            cold = led.enabled and miss
+            fn = self.step_fn(b, t, nblk, sp_prefill, fast_greedy, mm, masked)
+            program = "jit_" + sig.program(sp_prefill=sp_prefill, mm=mm,
+                                           masked=masked)
+            if span.is_enabled():    # a profiler session is recording
+                span.set_metadata(step=step, program=program)
+            with loop_phase(clock, "engine.dispatch.place"):
+                inputs = [self._place(x) for x in arrays]
+            if cold:
+                # jit compiles lazily: the cache miss pays its trace+compile
+                # wall INSIDE the fn(...) call below (only execution stays
+                # async), so timing the call measures the engine-thread
+                # stall.
+                led.mark_inflight(True)
+                t_compile = time.perf_counter()
+            with loop_phase(clock, "engine.dispatch.launch"), \
+                    self._compile_phase(miss, kind, b, t, nblk):
+                (self.cache_k, self.cache_v, self.counts, self.keys,
+                 self.slot_toks, toks, lps, *moe) = fn(
+                    self.params, self.cache_k, self.cache_v, self.counts,
+                    self.keys, self.slot_toks, *inputs)
+            if cold:
+                dt = time.perf_counter() - t_compile
+                led.mark_inflight(False)
+                led.record(
+                    sig, dt,
+                    trace_ctx=next((s.trace_ctx for s, _, _ in rows
+                                    if s.trace_ctx is not None), None))
+        return sig, program, toks, lps, (moe[0] if moe else None)
+
+    def _fill_inputs(self, rows, sample_rows, masks):
+        """The numpy inputs of the step program that serves ``rows``, filled
+        row by row (``engine.dispatch.fill``): the program's signature
+        (``greedy`` as the rows turned out), whether it is a ring prefill,
+        the arrays in the program's argument order, and whether the
+        multimodal pair and the logit mask are among them."""
         t_max = max(length for _, _, length in rows)
         sig = self.bucket_of(rows)
-        kind, b, t, nblk = sig.kind, sig.b, sig.t, sig.nblk
+        b, t, nblk = sig.b, sig.t, sig.nblk
         # Sequence-parallel prefill: a batch of fresh full-prompt chunks
         # (every row starts at 0) on a seq>1 mesh rides ring attention —
         # but only past the ring-vs-chunked threshold (explicit knob or
@@ -903,47 +1036,15 @@ class ModelRunner:
             for i, m in enumerate(masks):
                 if m is not None:
                     logit_mask[i, ~m] = -1e30
-        led = self._ledger
-        n_tok = _step_tokens(b, t, sp_prefill)
-        if int(q_len.sum()) > n_tok:
-            raise ValueError(
-                f"{int(q_len.sum())} live tokens in a {kind} batch whose "
-                f"bucket (b={b}, t={t}) holds {n_tok}: cut it with pack_rows")
         if not fast_greedy:
             sig = dataclasses.replace(sig, greedy=False)
-        miss = ((b, t, nblk, sp_prefill, fast_greedy, mm, masked)
-                not in self._step_fns)
-        cold = led.enabled and miss
-        fn = self.step_fn(b, t, nblk, sp_prefill, fast_greedy, mm, masked)
-        place = self._place
-        extra = ((place(emb_override), place(emb_mask)) if mm else ())
+        arrays = [tokens, q_start, q_len, bt, slots, temp, top_k, top_p, fp,
+                  pp, rp, do_sample, from_slot]
+        if mm:
+            arrays += [emb_override, emb_mask]
         if masked:
-            extra = (*extra, place(logit_mask))
-        if cold:
-            # jit compiles lazily: the cache miss pays its trace+compile
-            # wall INSIDE the fn(...) call below (only execution stays
-            # async), so timing the call measures the engine-thread stall.
-            led.mark_inflight(True)
-            t_compile = time.perf_counter()
-        with self._compile_phase(miss, kind, b, t, nblk):
-            (self.cache_k, self.cache_v, self.counts, self.keys,
-             self.slot_toks, toks, lps, *moe) = fn(
-                self.params, self.cache_k, self.cache_v, self.counts,
-                self.keys, self.slot_toks,
-                place(tokens), place(q_start), place(q_len),
-                place(bt), place(slots), place(temp),
-                place(top_k), place(top_p), place(fp),
-                place(pp), place(rp), place(do_sample),
-                place(from_slot), *extra,
-            )
-        if cold:
-            dt = time.perf_counter() - t_compile
-            led.mark_inflight(False)
-            led.record(
-                sig, dt,
-                trace_ctx=next((s.trace_ctx for s, _, _ in rows
-                                if s.trace_ctx is not None), None))
-        return sig, toks, lps, (moe[0] if moe else None)
+            arrays.append(logit_mask)
+        return sig, sp_prefill, arrays, mm, masked
 
     def _compile_phase(self, miss: bool, kind: str, b: int, t: int,
                        nblk: int):
@@ -983,16 +1084,23 @@ class ModelRunner:
         if self.mesh is not None:
             repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
             kw["out_shardings"] = (cache, cache, repl, repl)
-        return jax.jit(_named(verify, f"step_verify_b{b}_t{t}_n{nblk}"),
-                       donate_argnums=(1, 2), **kw)
+        name = BucketSig("verify", b, t, nblk, True, "").program()
+        return jax.jit(_named(verify, name), donate_argnums=(1, 2), **kw)
 
     def dispatch_verify(self, rows: list[tuple[Seq, int, int]],
-                        chunks: list[list[int]]
+                        chunks: list[list[int]], *, step: int = 0
                         ) -> tuple[BucketSig, jax.Array, jax.Array]:
         """Enqueue one verify step; chunk tokens are EXPLICIT (the proposals
         are not in seq.tokens yet) and each row's length is its chunk's.
-        Returns the signature and ([B, t] argmax tokens, lps)."""
+        Returns the signature and ([B, t] argmax tokens, lps). One
+        ``engine.program`` span, as ``dispatch`` has."""
         sig = self.bucket_of(rows, verify=True)
+        b, t, nblk = sig.b, sig.t, sig.nblk
+        with jax.profiler.TraceAnnotation(
+                "engine.program", step=step, program="jit_" + sig.program()):
+            return self._dispatch_verify(sig, rows, chunks)
+
+    def _dispatch_verify(self, sig: BucketSig, rows, chunks):
         b, t, nblk = sig.b, sig.t, sig.nblk
 
         tokens = np.zeros((b, t), np.int32)
@@ -1060,7 +1168,8 @@ class ModelRunner:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             kw["out_shardings"] = NamedSharding(self.mesh, P())
-        return jax.jit(_named(embed, f"embed_b{b}_t{t}"), **kw)
+        name = BucketSig("embed", b, t, 0, True, "").program()
+        return jax.jit(_named(embed, name), **kw)
 
     def embed(self, token_lists: list[list[int]]) -> np.ndarray:
         """Embed a batch of token sequences → [N, H] float32 (last-token
@@ -1269,8 +1378,8 @@ class EngineCore:
         if mc.sliding_window and engine_cfg.sp > 1:
             raise ValueError("ring prefill has no window: a model with "
                              f"sliding layers cannot run at sp={engine_cfg.sp}")
-        # Each layer's window and the routed layers' count: what the
-        # dispatch span's kv_blocks_walked and the step's counts need.
+        # Each layer's window and the routed layers' count: what the one
+        # count of a step's work needs (obs/sched_ledger.py step_counts).
         self._windows = tuple(mc.window_of(i) for i in range(mc.num_layers))
         self._routed_layers = (mc.num_layers - mc.first_k_dense
                                if mc.is_moe else 0)
@@ -1375,7 +1484,17 @@ class EngineCore:
             kv_block_bytes=self.runner._block_bytes_per_device(),
             kv_step_copy_bytes_per_block=self.runner.step_copy_bytes_per_block,
             moe=self._moe_facts(),
+            step_shapes=cm.step_shapes(
+                mc, block_size=engine_cfg.block_size,
+                kv_dtype=engine_cfg.kv_dtype or "bfloat16",
+                quantization=engine_cfg.quantization or "none",
+                devices=self.runner.mesh.size if self.runner.mesh else 1),
         )
+        # The step programs that ran while a profiler session was open:
+        # their phase tables are built when the engine shuts down
+        # (AsyncJaxEngine.shutdown), and for nobody else.
+        self.traced_programs: set[str] = set()
+        register_phase_source(self.runner.phase_tables)
         # The engine thread's loop phases (obs/profiler.py loop_phase):
         # always-on seconds per phase, and profiler spans at the same
         # boundaries. The runner times its serve-path compiles into it.
@@ -1387,7 +1506,8 @@ class EngineCore:
         # Hardware counters: analytic FLOPs/bytes + MFU/BW-util per step
         # (obs/profiler.py). DYN_PERF_PROFILE=0 turns the whole thing into
         # a no-op dict lookup per step.
-        self.perf = StepPerfProfiler(self.model_cfg, engine_cfg)
+        self.perf = StepPerfProfiler(self.model_cfg, engine_cfg,
+                                     shapes=self.metrics.step_shapes)
         self._seqs: dict[str, Seq] = {}
         self.default_eos: list[int] = []
         # Tracing: decode spans rotate every N generated tokens — one span
@@ -1815,20 +1935,11 @@ class EngineCore:
             plan = self._plan_step()
         if plan is None:
             return None
-        with loop_phase(self.loop_clock, "engine.dispatch") as span:
-            pending = self._dispatch_plan(plan)
-            # The last program enqueued (n: its signature's token bucket;
-            # a ring-prefill step runs the whole b x t instead).
-            if pending.batches:
-                sig = pending.batches[-1][0]
-                span.set(kind=sig.kind, b=sig.b, t=sig.t, nblk=sig.nblk,
-                         n=sig.n,
-                         rows=sum(len(x[1]) for x in pending.batches),
-                         kv_blocks_live=kv_blocks_live(
-                             pending.batches, self.engine_cfg.block_size),
-                         kv_blocks_walked=kv_blocks_walked(
-                             pending.batches, self.engine_cfg.block_size,
-                             self._windows))
+        # The step's ordinal rides the spans a reader joins by it: each
+        # program's ``engine.program`` inside this one (ModelRunner.dispatch),
+        # the step's ``engine.finalize.wait`` and its ``engine.record``.
+        with loop_phase(self.loop_clock, "engine.dispatch"):
+            pending = self._dispatch_plan(plan, self.metrics.num_steps)
         if self.sched_led.enabled:
             with loop_phase(self.loop_clock, "engine.plan"):
                 pending.sched = self._sched_context(plan)
@@ -1885,13 +1996,17 @@ class EngineCore:
                     - seq.prefix_hit_blocks * seq.block_size, 0))
         return plan
 
-    def _dispatch_plan(self, plan: StepPlan) -> "PendingStep":
+    def _dispatch_plan(self, plan: StepPlan, step: int = 0) -> "PendingStep":
         """The device half of :meth:`step_begin`: slot init, batch
         building, input prep and the jitted call(s)."""
-        for seq in [w.seq for w in plan.prefill] + plan.decode:
-            if not seq.slot_initialized and seq.slot >= 0:
-                self._init_slot(seq)
-                seq.slot_initialized = True
+        fresh = [seq for seq in [w.seq for w in plan.prefill] + plan.decode
+                 if not seq.slot_initialized and seq.slot >= 0]
+        if fresh:
+            # reset_slot's eager programs, a few a new sequence.
+            with loop_phase(self.loop_clock, "engine.dispatch.reset"):
+                for seq in fresh:
+                    self._init_slot(seq)
+                    seq.slot_initialized = True
 
         # A plan with chunks packs its decode rows and the chunks into ONE
         # ragged "mixed" program, whose dense layers run over the rows'
@@ -1899,7 +2014,7 @@ class EngineCore:
         # the scheduler module docstring. A batch whose chunks hold more
         # tokens than its program's token bucket goes out as several
         # programs, below.
-        pending = PendingStep()
+        pending = PendingStep(step=step)
         batches: list[tuple[list, list[bool], list | None]] = []
         decode_seqs = plan.decode
         guided_rows: list = []
@@ -1918,13 +2033,14 @@ class EngineCore:
             verify_rows, verify_chunks, decode_seqs = self._plan_verify(decode_seqs)
             if verify_rows:
                 sig, toks, lps = self.runner.dispatch_verify(
-                    verify_rows, verify_chunks)
+                    verify_rows, verify_chunks, step=step)
                 for seq, start, length in verify_rows:
                     seq.num_computed = start + length
                     seq.inflight_samples += 1
                     seq.verify_inflight = True
                 pending.batches.append(
                     (sig, verify_rows, verify_chunks, toks, lps))
+                pending.programs.append("jit_" + sig.program())
                 pending.moe.append(None)
         pf_rows, pf_sample_rows, pf_masks = [], [], None
         if plan.prefill:
@@ -1972,8 +2088,9 @@ class EngineCore:
         for rows, sample_rows, b_masks in batches:
             for lo, k in _runs(pack_rows([r[2] for r in rows], ec)):
                 run, samples = rows[lo:lo + k], sample_rows[lo:lo + k]
-                sig, toks, lps, moe = self.runner.dispatch(
-                    run, samples, masks=b_masks and b_masks[lo:lo + k])
+                sig, program, toks, lps, moe = self.runner.dispatch(
+                    run, samples, masks=b_masks and b_masks[lo:lo + k],
+                    step=step)
                 # Value-independent bookkeeping, done at dispatch so the
                 # next plan() sees advanced positions. Token metrics count
                 # at finalize, so discarded speculative rows don't inflate
@@ -1983,6 +2100,7 @@ class EngineCore:
                     if sampled:
                         seq.inflight_samples += 1
                 pending.batches.append((sig, run, samples, toks, lps))
+                pending.programs.append(program)
                 pending.moe.append(moe)
         return pending
 
@@ -2092,25 +2210,34 @@ class EngineCore:
         get_tracer().end_span(sp, status=status, **attrs)
 
     def _record_step(self, t0: float, pending: "PendingStep",
-                     moe: list | None = None) -> None:
+                     moe: list | None = None, span=None) -> None:
         """Always-on step profile: one ring append per engine step. ``moe``:
         the step's routed-layer counts (layer steps, rows, experts touched,
-        largest groups), where its programs gave any."""
-        n_dec = pending.dec_rows + sum(
-            len(rows) for sig, rows, *_ in pending.batches
-            if sig.kind == "verify")
-        n_pf = sum(len(rows) for _, rows, *_ in pending.batches) - n_dec
+        largest groups), where its programs gave any. The step's rows are
+        walked once, here (``step_counts``): the profiler prices that count,
+        the scheduling ledger files it, and ``span`` (the step's
+        ``engine.record``) carries it with the device's counts, for a reader
+        of a trace to join to the step's programs by ``step``."""
+        counts = step_counts(pending.batches, self.engine_cfg.block_size,
+                             self._windows, dec_rows=pending.dec_rows)
         pc = self.sched.preemption_count
         wall = time.perf_counter() - t0
+        perf = self.perf.measure(counts, wall, moe)
         get_tracer().recorder.steps.record(
             time.time(), wall,
-            num_prefill=n_pf, num_decode=n_dec,
+            num_prefill=counts["prefill_rows"],
+            num_decode=counts["decode_rows"],
             num_waiting=self.sched.num_waiting,
             num_preempted=pc - self._trace_last_preempt,
             occupancy=(self.sched.num_running
                        / max(self.engine_cfg.max_batch_size, 1)),
-            **self.perf.measure(pending.batches, wall))
+            **perf)
         self._trace_last_preempt = pc
+        # (one static call, ~60 ns: is a profiler session recording?)
+        if span is not None and jax.profiler.TraceAnnotation.is_enabled():
+            span.set(**{k: counts[k] for k in _RECORD_SPAN_COUNTS},
+                     **(dict(zip(_RECORD_SPAN_MOE, moe)) if moe else {}))
+            self.traced_programs.update(pending.programs)
         if self.sched_led.enabled:
             info = pending.sched or {}
             self.sched_led.record_step(
@@ -2119,7 +2246,9 @@ class EngineCore:
                 queue_depths=self.sched.waiting.depths(),
                 hol=info.get("hol"), moe=moe and tuple(moe),
                 **step_geometry(self.model_cfg, self.engine_cfg,
-                                pending.batches, dec_rows=pending.dec_rows))
+                                pending.batches, counts=counts, moe=moe,
+                                shapes=self.metrics.step_shapes,
+                                live_cost=self.perf.last_cost))
         if self.mem_led.enabled:
             # Capacity forecast + leak audit cadence ride the step clock:
             # free-pool observations feed the per-QoS EWMA consumption
@@ -2244,7 +2373,7 @@ class EngineCore:
         moe = None
         for (sig, rows, sample_rows, toks_dev, lps_dev), moe_dev in zip(
                 pending.batches, pending.moe, strict=True):
-            with loop_phase(clock, "engine.finalize.wait"):
+            with loop_phase(clock, "engine.finalize.wait", step=pending.step):
                 # The host blocks here until the device has run the step.
                 toks = np.asarray(toks_dev)
                 lps = np.asarray(lps_dev)
@@ -2262,8 +2391,8 @@ class EngineCore:
                 self._finalize_batch(rows, sample_rows, toks, lps,
                                      dec_left, outputs)
             dec_left = max(dec_left - len(rows), 0)
-        with loop_phase(clock, "engine.record"):
-            self._record_step(t0, pending, moe)
+        with loop_phase(clock, "engine.record", step=pending.step) as span:
+            self._record_step(t0, pending, moe, span)
         if self.kvbm is not None and not self.sched.has_work():
             # Engine going idle: this finalize's commits would otherwise sit
             # in the publish-on-commit queue until the next step_begin —
@@ -3038,9 +3167,34 @@ class AsyncJaxEngine:
         self._wake.set()
         if self._started:
             await asyncio.get_running_loop().run_in_executor(None, self._thread.join, 5.0)
+        if self.core.traced_programs and not self._thread.is_alive():
+            # A profiler session was open while this engine served: leave
+            # the phase tables of the programs it ran then where a reader
+            # of that trace, in this process, looks for them.
+            await asyncio.get_running_loop().run_in_executor(
+                None, self.write_phase_tables)
         # A dead engine must not keep vouching for its pins: drop its
         # live-id source so anything it leaked surfaces in the next audit.
         self.core.mem_led.unregister_live_source(self.core._mem_source_key)
+
+    def write_phase_tables(self) -> "Path | None":
+        """``{program: {instruction: phase}}`` of the step programs that ran
+        under a profiler session (``EngineCore.traced_programs``), as JSON
+        at obs/profiler.py ``phase_table_path()`` (found by the process
+        id). After the last request: the engine thread has ended. None, and
+        a warning, where it cannot be done: a trace without its tables is
+        still a trace."""
+        t0 = time.perf_counter()
+        try:
+            tables = self.core.runner.phase_tables(self.core.traced_programs)
+            path = phase_table_path()
+            path.write_text(json.dumps(tables))
+        except Exception:
+            log.warning("phase tables not written", exc_info=True)
+            return None
+        log.info("phase tables of %d programs at %s in %.1f s", len(tables),
+                 path, time.perf_counter() - t0)
+        return path
 
     def _emit_op(self, op: dict) -> None:
         """Broadcast one op to follower ranks; a failed broadcast is fatal
@@ -3087,84 +3241,87 @@ class AsyncJaxEngine:
         # from async scheduling (see EngineCore.step_begin). The loop's
         # phases are cut by ``loop_phase`` (obs/profiler.py LOOP_PHASES):
         # idle wait, inbox and post here; plan, dispatch, finalize and
-        # record inside step_begin / step_finalize.
+        # record inside step_begin / step_finalize. What of an iteration's
+        # wall no phase holds is ``loop["engine.unphased"]``
+        # (``loop_iteration``).
         pending: PendingStep | None = None
         clock = self.core.loop_clock
         while not self._stop:
-            if not self._inbox.empty():
-                with loop_phase(clock, "engine.inbox"):
-                    self._drain_inbox()
-            if self._channel_down:
-                # Op channel died mid-drain: fail everything in flight
-                # (checked before the idle-continue so an idle engine still
-                # reports the failure to its streams).
-                self.core.fail_all("multi-host op channel down")
-                for rid in list(self._streams):
-                    self._post(rid, LLMEngineOutput(
-                        finish_reason=FinishReason.ERROR,
-                        error="multi-host op channel down"))
-                break
-            if not self.core.has_work() and pending is None:
-                # One span for the whole wait, however many timeouts it
-                # takes, so that a gap of the device trace is covered by
-                # one host span. Only the inbox (or shutdown) can give this
-                # thread work, so that is all the wait looks at.
-                with loop_phase(clock, "engine.idle_wait"):
-                    while self._inbox.empty() and not self._stop:
-                        self._wake.wait(timeout=0.05)
-                        self._wake.clear()
-                continue
-            try:
-                # Chaos: inside the try so an error-kind injection exercises
-                # the engine-fatal path (fail_all + drain), and a delay is a
-                # straggling device step.
-                chaos.inject("engine.step")
-                t_step = time.time()
-                if self.core.has_expired_waiting(t_step):
-                    # Broadcast-then-apply, like every state-changing op:
-                    # followers reap the same seqs at the same instant.
-                    self._emit_op({"op": "reap", "now": t_step})
-                    for rid, out in self.core.reap_expired(t_step).items():
-                        self._post(rid, out)
-                self._emit_op({"op": "step", "now": t_step})
-                self.core.set_step_time(t_step)
-                nxt = self.core.step_begin() if self.core.has_work() else None
-                outputs = (self.core.step_finalize(pending)
-                           if pending is not None else {})
-                pending = nxt
-                with loop_phase(clock, "engine.post"):
-                    for rid, out in outputs.items():
-                        self._post(rid, out)
-                    self.core.first_tokens_posted()
-                    self._stage_stream_waves()
-            except Exception as exc:
-                # Engine-fatal: fail + drain all in-flight state so the loop
-                # doesn't spin hot retrying the same failing step.
-                log.exception("engine step failed; failing all in-flight requests")
-                pending = None
-                self.core.fail_all(str(exc))
-                if self._op_sink is not None and not isinstance(exc, OpChannelDown):
-                    # Followers must mirror the wipe or their replayed state
-                    # machines diverge from ours. (If the channel itself died,
-                    # _stop is already set and there is no one to tell.)
-                    try:
-                        self._emit_op({"op": "fail_all", "error": str(exc)})
-                    except OpChannelDown:
-                        pass
-                if isinstance(exc, jax.errors.JaxRuntimeError):
-                    # Out of device memory, a kernel that does not lower: the
-                    # next request to reach this bucket fails the same way.
-                    # Stop, rather than answer every request with an error
-                    # from a process that looks healthy and exits 0. Set
-                    # before the streams are told, so that generate() either
-                    # is among them or sees it.
-                    log.error("device error is fatal: engine stopped serving")
-                    self.fatal = exc
-                for rid in list(self._streams):
-                    self._post(rid, LLMEngineOutput(finish_reason=FinishReason.ERROR, error=str(exc)))
-                if self.fatal is not None:
+            with loop_iteration(clock):
+                if not self._inbox.empty():
+                    with loop_phase(clock, "engine.inbox"):
+                        self._drain_inbox()
+                if self._channel_down:
+                    # Op channel died mid-drain: fail everything in flight
+                    # (checked before the idle-continue so an idle engine still
+                    # reports the failure to its streams).
+                    self.core.fail_all("multi-host op channel down")
+                    for rid in list(self._streams):
+                        self._post(rid, LLMEngineOutput(
+                            finish_reason=FinishReason.ERROR,
+                            error="multi-host op channel down"))
                     break
-                continue
+                if not self.core.has_work() and pending is None:
+                    # One span for the whole wait, however many timeouts it
+                    # takes, so that a gap of the device trace is covered by
+                    # one host span. Only the inbox (or shutdown) can give this
+                    # thread work, so that is all the wait looks at.
+                    with loop_phase(clock, "engine.idle_wait"):
+                        while self._inbox.empty() and not self._stop:
+                            self._wake.wait(timeout=0.05)
+                            self._wake.clear()
+                    continue
+                try:
+                    # Chaos: inside the try so an error-kind injection exercises
+                    # the engine-fatal path (fail_all + drain), and a delay is a
+                    # straggling device step.
+                    chaos.inject("engine.step")
+                    t_step = time.time()
+                    if self.core.has_expired_waiting(t_step):
+                        # Broadcast-then-apply, like every state-changing op:
+                        # followers reap the same seqs at the same instant.
+                        self._emit_op({"op": "reap", "now": t_step})
+                        for rid, out in self.core.reap_expired(t_step).items():
+                            self._post(rid, out)
+                    self._emit_op({"op": "step", "now": t_step})
+                    self.core.set_step_time(t_step)
+                    nxt = self.core.step_begin() if self.core.has_work() else None
+                    outputs = (self.core.step_finalize(pending)
+                               if pending is not None else {})
+                    pending = nxt
+                    with loop_phase(clock, "engine.post"):
+                        for rid, out in outputs.items():
+                            self._post(rid, out)
+                        self.core.first_tokens_posted()
+                        self._stage_stream_waves()
+                except Exception as exc:
+                    # Engine-fatal: fail + drain all in-flight state so the loop
+                    # doesn't spin hot retrying the same failing step.
+                    log.exception("engine step failed; failing all in-flight requests")
+                    pending = None
+                    self.core.fail_all(str(exc))
+                    if self._op_sink is not None and not isinstance(exc, OpChannelDown):
+                        # Followers must mirror the wipe or their replayed state
+                        # machines diverge from ours. (If the channel itself died,
+                        # _stop is already set and there is no one to tell.)
+                        try:
+                            self._emit_op({"op": "fail_all", "error": str(exc)})
+                        except OpChannelDown:
+                            pass
+                    if isinstance(exc, jax.errors.JaxRuntimeError):
+                        # Out of device memory, a kernel that does not lower: the
+                        # next request to reach this bucket fails the same way.
+                        # Stop, rather than answer every request with an error
+                        # from a process that looks healthy and exits 0. Set
+                        # before the streams are told, so that generate() either
+                        # is among them or sees it.
+                        log.error("device error is fatal: engine stopped serving")
+                        self.fatal = exc
+                    for rid in list(self._streams):
+                        self._post(rid, LLMEngineOutput(finish_reason=FinishReason.ERROR, error=str(exc)))
+                    if self.fatal is not None:
+                        break
+                    continue
 
     def _drain_inbox(self) -> None:
         """Apply everything that has arrived: requests (admission, prefix

@@ -504,11 +504,20 @@ def token_layout(q_len: jax.Array, b: int, t: int, n: int) -> tuple[
     return TokenLayout(b, t, tok_row, tok_off, row_tok), i < ends[-1]
 
 
-def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
-           lay: TokenLayout, positions, slot, block_tables, q_start, kv_lens,
-           attn_impl: str = "dense",
-           moe_impl: str = "dense", mesh=None, use_ring: bool = False,
-           window: int = 0, live=None):
+def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, **kw):
+    """:func:`_layer_body` under the ``layer`` scope: what no inner phase
+    names (norms, rope, residual adds, the moves of ``q`` and of the
+    attention output around the kernel) is the layer's rest
+    (obs/profiler.py ``DEVICE_PHASES``)."""
+    with _perf_phase("layer"):
+        return _layer_body(cfg, lp, layer, hid, cache_k, cache_v, **kw)
+
+
+def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
+                lay: TokenLayout, positions, slot, block_tables, q_start,
+                kv_lens, attn_impl: str = "dense",
+                moe_impl: str = "dense", mesh=None, use_ring: bool = False,
+                window: int = 0, live=None):
     """One transformer layer over the WHOLE cache ([L,NB,BS,KH,D], or the
     stage-local part of it under pp): writes this step's K/V at
     ``(layer, slot)``, attends over layer ``layer``, returns
@@ -551,8 +560,9 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     # operand [heads, D, H], the stored matrix transposed, and the compiler
     # then cuts the layer's matrix out of its stack and copies it in that
     # layout in every layer of every step (PERF.md section 6, PR 40).
-    q, k, v = jax.lax.optimization_barrier(
-        (mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])))
+    with _perf_phase("proj"):
+        q, k, v = jax.lax.optimization_barrier(
+            (mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])))
     q = q.reshape(n, cfg.num_heads, cfg.head_dim)
     k = k.reshape(n, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(n, cfg.num_kv_heads, cfg.head_dim)
@@ -613,7 +623,8 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
                 q_start[:, None] + jnp.arange(lay.t)[None, :], kv_lens,
                 window=window)
     attn = lay.to_tokens(attn).reshape(n, cfg.q_size)
-    attn = mm(attn, lp["wo"])
+    with _perf_phase("proj"):
+        attn = mm(attn, lp["wo"])
     if post:
         attn = rms_norm(attn, lp["attn_norm"], cfg.rms_norm_eps)
     hid = hid + attn
@@ -639,7 +650,8 @@ def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
         else:
             mlp_out = moe_mlp(x, lp, cfg, routing)
     else:
-        mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+        with _perf_phase("mlp"):
+            mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
     if post:
         mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.rms_norm_eps)
     return hid + mlp_out, cache_k, cache_v, counts
@@ -815,19 +827,21 @@ def forward(
         and cfg.num_kv_heads % tp == 0 and b % dp == 0
     )
     n = b * t if num_tokens is None or use_ring else num_tokens
-    lay, valid = token_layout(q_len, b, t, n)
-    positions, slot = _positions_and_slots(
-        lay, valid, q_start, block_tables, bs)                     # [N]
-    kv_lens = q_start + q_len                                      # [B]
+    with _perf_phase("layout"):
+        lay, valid = token_layout(q_len, b, t, n)
+        positions, slot = _positions_and_slots(
+            lay, valid, q_start, block_tables, bs)                 # [N]
+        kv_lens = q_start + q_len                                  # [B]
 
-    h = embed_lookup(params["embed"], lay.to_tokens(token_ids),
-                     _dtype(cfg))                                  # [N, H]
-    if embed_override is not None:
-        # Multimodal positions carry encoder outputs instead of token
-        # embeddings (their placeholder ids exist only for position/hash
-        # bookkeeping — see preprocessor digest-salted placeholders).
-        h = jnp.where(lay.to_tokens(embed_mask)[:, None],
-                      lay.to_tokens(embed_override).astype(h.dtype), h)
+    with _perf_phase("embed"):
+        h = embed_lookup(params["embed"], lay.to_tokens(token_ids),
+                         _dtype(cfg))                              # [N, H]
+        if embed_override is not None:
+            # Multimodal positions carry encoder outputs instead of token
+            # embeddings (their placeholder ids exist only for position/hash
+            # bookkeeping — see preprocessor digest-salted placeholders).
+            h = jnp.where(lay.to_tokens(embed_mask)[:, None],
+                          lay.to_tokens(embed_override).astype(h.dtype), h)
 
     held = {"live": valid} if moe_impl == "held" else {}
     h, cache_k, cache_v, counts = _run_layers(
@@ -836,10 +850,12 @@ def forward(
         q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
         moe_impl=moe_impl, mesh=mesh,
         use_ring=use_ring, **held)
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-
-    out = (lay.to_rows(h) if return_all_hidden                     # [B, T, H]
-           else _last_hidden(h, lay, q_len)), cache_k, cache_v
+    # The head's own preparation: the final norm and each row's last token.
+    with _perf_phase("logits"):
+        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+        last = (lay.to_rows(h) if return_all_hidden                # [B, T, H]
+                else _last_hidden(h, lay, q_len))
+    out = last, cache_k, cache_v
     return (*out, counts) if moe_counts else out
 
 

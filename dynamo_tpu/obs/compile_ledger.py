@@ -130,6 +130,29 @@ class BucketSig:
     def n(self) -> int:
         return token_bucket(self.kind, self.b, self.t)
 
+    def program(self, *, sp_prefill: bool = False, mm: bool = False,
+                masked: bool = False) -> str:
+        """The name the program is built under (``ModelRunner``'s builders
+        jit a function of this name): a device trace's ``XLA Modules`` line
+        shows it as ``jit_<name>(<hash>)``, the engine's ``engine.program``
+        span as ``jit_<name>``. The variants that are no part of the
+        signature ride as suffixes: ring prefill over "seq" (its dense
+        layers run the whole ``b x t``), multimodal embeds, a logit mask."""
+        if self.kind == "embed":
+            return f"embed_b{self.b}_t{self.t}"
+        if self.kind == "verify":
+            return f"step_verify_b{self.b}_t{self.t}_n{self.nblk}"
+        if self.t == 1:
+            name = f"step_decode_b{self.b}_n{self.nblk}"
+        else:
+            n = self.b * self.t if sp_prefill else self.n
+            name = f"step_mixed_b{self.b}_t{self.t}_k{n}_n{self.nblk}"
+        for flag, suffix in ((sp_prefill, "_sp"), (not self.greedy, "_sampled"),
+                             (mm, "_mm"), (masked, "_masked")):
+            if flag:
+                name += suffix
+        return name
+
     def to_dict(self) -> dict:
         return {"kind": self.kind, "b": self.b, "t": self.t,
                 "nblk": self.nblk, "n": self.n, "greedy": self.greedy,

@@ -303,6 +303,10 @@ def model_step_cost(
         k = max(cfg.num_experts_per_tok, 1)
         mlp_flops = (2.0 * n * h * cfg.num_experts  # router
                      + 6.0 * n * h * m * k) * L
+        # An estimate, for forecasts (the planner, the chunk sizing): every
+        # layer routed over the experts held, a token's choices all distinct
+        # experts. What a step that ran did is priced by step_work() from
+        # the counts the device returned.
         experts_touched = min(n * k, cfg.num_experts)
         mlp_w = (h * cfg.num_experts + 3 * h * m * experts_touched) * wb * L
         if cfg.num_shared_experts:
@@ -325,6 +329,90 @@ def model_step_cost(
 
     return {"embed": embed, "scatter": scatter, "attention": attention,
             "proj": proj, "mlp": mlp, "logits": logits, "sampling": sampling}
+
+
+# ---------------------------------------------------------------------------
+# What a step that ran did: its counts priced by the program's shapes
+# ---------------------------------------------------------------------------
+
+def step_shapes(cfg: ModelConfig, *, block_size: int,
+                kv_dtype: str = "bfloat16", quantization: str = "none",
+                devices: int = 1) -> dict:
+    """The shapes that price a step's counts (``stats()["step_shapes"]``),
+    of the whole model: per kind of layer the matmul parameters a program
+    reads whatever its rows (attention's four matrices, a dense FFN, the
+    shared expert, the router), one routed expert's, the head's,
+    ``bytes_per_param`` as the program holds them, and the bytes of one KV
+    block of one layer (K and V). ``devices`` is what the model is divided
+    over. Norm weights and biases are left out: thousands, not millions."""
+    h, L = cfg.hidden_size, cfg.num_layers
+    routed = L - cfg.first_k_dense if cfg.is_moe else 0
+    m = cfg.moe_intermediate_size
+    kvb = _kv_itemsize(kv_dtype)
+    block = 2 * block_size * cfg.num_kv_heads * cfg.head_dim * kvb
+    if kv_dtype in ("int8", "int4"):
+        block += 2 * cfg.num_kv_heads * 4
+    return {
+        "layers": L, "routed_layers": routed, "dense_ffn_layers": L - routed,
+        "hidden_size": h, "num_heads": cfg.num_heads,
+        "head_dim": cfg.head_dim, "q_size": cfg.q_size,
+        "attn_params": 2 * h * cfg.q_size + 2 * h * cfg.kv_size,
+        "dense_ffn_params": 3 * h * cfg.intermediate_size if routed < L else 0,
+        "shared_expert_params": 3 * h * m * cfg.num_shared_experts,
+        "router_params": h * cfg.router_width if routed else 0,
+        "expert_params": 3 * h * m,
+        "experts_held": cfg.num_experts, "router_width": cfg.router_width,
+        "experts_per_token": cfg.num_experts_per_tok,
+        "head_params": cfg.vocab_size * h,
+        "bytes_per_param": _weight_itemsize(quantization),
+        "kv_block_bytes_per_layer": int(block),
+        "block_size": block_size, "devices": devices,
+    }
+
+
+def step_work(shapes: dict, counts: dict,
+              moe: tuple | list | None = None) -> KernelCost:
+    """FLOP and HBM bytes of one step that ran, from the one count of its
+    rows (obs/sched_ledger.py ``step_counts``) and, for a routed model, the
+    counts the device returned for it (``moe``: layer steps, (token, choice)
+    rows computed here, experts that had rows). The least the step has to
+    move and compute, nothing of how it is programmed:
+
+    - bytes: the parameters a program reads once whatever its rows (every
+      layer's attention matrices, dense FFNs, shared experts, routers, and
+      the head), once a program of the step; one expert's times the experts
+      touched; the KV blocks walked (each layer's window counted) times a
+      block's bytes; the embedding's gathered rows, not its table;
+    - FLOP: the matmuls of the live tokens (2 a parameter a token; a routed
+      expert's a computed row), the head's of the rows that sample, and
+      attention's ``4 x heads x head_dim x`` sum of ``length x context``.
+
+    Without device counts a routed step is priced as ``model_step_cost``
+    estimates it: the held share of every token's choices, all distinct."""
+    s = shapes
+    n, rows = counts["live_tokens"], counts["logit_rows"]
+    routed = s["routed_layers"]
+    fixed = (s["layers"] * s["attn_params"]
+             + s["dense_ffn_layers"] * s["dense_ffn_params"]
+             + routed * (s["shared_expert_params"] + s["router_params"]))
+    if moe:
+        _, moe_rows, touched = moe[:3]
+    elif routed:
+        share = s["experts_held"] / max(s["router_width"], 1)
+        per_layer = n * s["experts_per_token"] * share
+        moe_rows = per_layer * routed
+        touched = min(per_layer, s["experts_held"]) * routed
+    else:
+        moe_rows = touched = 0
+    params = (counts.get("programs", 1) * (fixed + s["head_params"])
+              + touched * s["expert_params"])
+    nbytes = (params * s["bytes_per_param"]
+              + counts["kv_blocks_walked"] * s["kv_block_bytes_per_layer"]
+              + n * s["hidden_size"] * 2)
+    flops = (2.0 * n * fixed + 2.0 * moe_rows * s["expert_params"]
+             + 2.0 * rows * s["head_params"]
+             + 4.0 * s["num_heads"] * s["head_dim"] * counts["attn_q_ctx"])
+    return KernelCost("step", flops, float(nbytes))
 
 
 def total_cost(phases: dict[str, KernelCost]) -> KernelCost:

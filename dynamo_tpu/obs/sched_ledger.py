@@ -576,76 +576,58 @@ def get_sched_ledger() -> SchedLedger:
 
 
 # ---------------------------------------------------------------------------
-# Live-vs-scheduled step geometry — the programs dispatch() ran.
+# A step's work, counted once — and its live-vs-scheduled geometry
 # ---------------------------------------------------------------------------
 
-def kv_blocks_live(batches, block_size: int) -> int:
-    """KV blocks the rows of a step's batches hold, ``ceil((start + length)
-    / block_size)`` a row, from positions the host has: what the attention
-    kernel walks for them (once a query chunk of the row), beside the
-    ``b x nblk`` entries of the tables it was handed."""
-    return sum(-(-(start + length) // block_size)
-               for _sig, rows, *_ in batches for _seq, start, length in rows)
+def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0) -> dict:
+    """What one step did, in the program's own terms and nothing priced:
+    THE walk over a step's rows, made once between plan and record
+    (EngineCore._record_step). The profiler prices it, the ledger's goodput
+    and totals read it, the ``engine.record`` span carries it.
 
+    ``batches`` is PendingStep.batches: (sig, rows, sample_rows, toks, lps)
+    with rows of (seq, start, length) and ``sig`` the ``BucketSig``
+    dispatch() ran the batch under. ``windows`` gives each layer's window
+    (0: a full layer). ``dec_rows`` is the plan-time count of decode rows
+    among the step batches' rows, counted from the first (the rest are
+    prefill chunks); verify rows count as decode rows beside it.
 
-def kv_blocks_walked(batches, block_size: int, windows) -> int:
-    """KV blocks the attention kernel walks for a step's rows over all the
-    layers, ``windows`` giving each layer's window (0: a full layer, which
-    walks what :func:`kv_blocks_live` counts). A sliding layer's walk of a
-    row begins at the block that holds the oldest key the row's first query
-    token sees (ops/paged_attention.py ``chunk_first_blocks``). Host
-    arithmetic, counted once a row as ``kv_blocks_live`` is."""
-    kinds = Counter(windows).items()     # a few kinds of layer, many rows
-    total = 0
-    for _sig, rows, *_ in batches:
-        for _seq, start, length in rows:
-            used = -(-(start + length) // block_size)
-            total += sum(
-                n * (used - (min(max(start - (w - 1), 0) // block_size,
-                                 used - 1) if w else 0))
-                for w, n in kinds)
-    return total
+    Returns
 
-
-def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
-    """Live and scheduled (bucket-padded) work for one finalized step.
-
-    ``batches`` is PendingStep.batches: (sig, rows, sample_rows, toks,
-    lps) with rows of (seq, start, length) and ``sig`` the ``BucketSig``
-    dispatch() ran the batch under. The live walk mirrors
-    StepPerfProfiler.measure exactly; the padded side prices that
-    signature: its token bucket ``n`` through the dense layers, its
-    ``b x t`` rows through attention, ``nblk`` blocks a row where attention
-    is the dense gather (the kernel walks the live blocks whatever the
-    table's width: both sides then price those). Both sides
-    run through obs/costmodel.model_step_cost, so goodput is a pure FLOPs
-    ratio hand-computable at any known bucket geometry.
-
-    ``dec_rows`` is the plan-time count of decode rows among the step
-    batches' rows, counted from the first (the rest are prefill chunks);
-    verify rows count as decode rows beside it.
-
-    Returns {kinds, prefill_rows, decode_rows, live_tokens, sched_tokens,
-    rect_tokens, kv_blocks_live, live_flops, sched_flops, live_bytes,
-    sched_bytes}: ``sched_tokens`` is what the dense layers computed,
-    ``rect_tokens`` the positions of the attention rectangles,
-    ``kv_blocks_live`` :func:`kv_blocks_live` of the same batches.
+    - ``kinds``, ``programs``: the batches' kinds, and how many there were;
+    - ``prefill_rows``, ``decode_rows``, and ``prefill_tokens`` /
+      ``decode_tokens`` (a "mixed" batch's rows of several tokens are
+      prefill chunks, every other row decodes; a one-token prefill tail
+      lands on the decode side);
+    - ``live_tokens``; ``sched_tokens``, the dense layers' token bucket
+      (``sig.n`` a program); ``rect_tokens``, the ``b x t`` positions of the
+      attention rectangles; ``logit_rows`` (one a row) and
+      ``sched_logit_rows`` (``sig.b`` a program);
+    - ``kv_blocks_live``: ``ceil((start + length) / block_size)`` a row,
+      the blocks the rows hold, what a full layer's kernel walks for them;
+    - ``kv_blocks_walked``: the same over all the layers, a sliding layer's
+      walk of a row beginning at the block that holds the oldest key the
+      row's first query sees (ops/paged_attention.py
+      ``chunk_first_blocks``);
+    - ``attn_q_ctx``: the attention volume, the (query, key) pairs the
+      rows' queries see, over all the layers: query ``p`` of a full layer
+      sees ``p + 1`` keys, of a layer of window ``w`` ``min(p + 1, w)``;
+    - ``table_q_ctx``, ``table_blocks``: what the programs' block tables
+      span over all the layers (``b x t x nblk`` entries' keys, ``b x nblk``
+      blocks): the dense gather pays for that, the kernel for the two above.
     """
-    from dynamo_tpu.obs import costmodel as cm
-    from dynamo_tpu.obs.compile_ledger import walks_live_context
-
-    ec = engine_cfg
-    bs = ec.block_size
-    kv = ec.kv_dtype or "bfloat16"
-    quant = ec.quantization or "none"
-    live = {"tokens": 0, "logit_rows": 0, "attn_q_ctx": 0.0, "kv_blocks": 0.0}
-    sched = {"tokens": 0, "logit_rows": 0, "attn_q_ctx": 0.0, "kv_blocks": 0.0}
+    bs = block_size
+    layer_kinds = tuple(Counter(windows).items())   # a few kinds, many rows
+    layers = len(windows)
     kinds: list[str] = []
-    pf_rows = n_dec = rect = 0
+    programs = pf_rows = n_dec = pf_tokens = dec_tokens = 0
+    live = logit_rows = sched = rect = sched_rows = 0
+    blocks = walked = q_ctx = table_q = table_blocks = 0
     dec_left = dec_rows
-    for sig, rows, _sample_rows, _toks, _lps in batches:
+    for sig, rows, *_ in batches:
         if not rows:
             continue
+        programs += 1
         n = len(rows)
         if sig.kind == "verify":
             kinds.append("verify")
@@ -661,42 +643,94 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0) -> dict:
             dec_left -= d
             n_dec += d
             pf_rows += n - d
+        chunks = sig.kind == "mixed"
+        logit_rows += n
         for _seq, start, length in rows:
-            live["tokens"] += length
-            live["logit_rows"] += 1
-            nb = -(-(start + length) // bs)
-            live["attn_q_ctx"] += length * nb * bs
-            live["kv_blocks"] += nb
-        sched["tokens"] += sig.n
+            end = start + length
+            live += length
+            used = -(-end // bs)
+            blocks += used
+            for w, count in layer_kinds:
+                if not w:
+                    walked += count * used
+                    q_ctx += count * (length * start
+                                      + length * (length + 1) // 2)
+                    continue
+                first = min(max(start - (w - 1), 0) // bs, used - 1)
+                walked += count * (used - first)
+                # The first ``whole`` queries see every key before them.
+                whole = max(0, min(end, w) - start)
+                q_ctx += count * (whole * start + whole * (whole + 1) // 2
+                                  + (length - whole) * w)
+            if chunks and length > 1:
+                pf_tokens += length
+            else:
+                dec_tokens += length
+        sched += sig.n
         rect += sig.b * sig.t
-        sched["logit_rows"] += sig.b
-        sched["attn_q_ctx"] += sig.b * sig.t * sig.nblk * bs
-        sched["kv_blocks"] += sig.b * sig.nblk
-    if walks_live_context(ec):
-        # The kernel fetches a row's live blocks and no table entry more.
-        sched["attn_q_ctx"] = live["attn_q_ctx"]
-        sched["kv_blocks"] = live["kv_blocks"]
-
-    def _cost(agg: dict):
-        phases = cm.model_step_cost(
-            model_cfg, tokens=agg["tokens"], logit_rows=agg["logit_rows"],
-            attn_q_ctx=agg["attn_q_ctx"], kv_blocks=agg["kv_blocks"],
-            block_size=bs, kv_dtype=kv, quantization=quant)
-        return cm.total_cost(phases)
-
-    lc = _cost(live) if live["tokens"] else None
-    sc = _cost(sched) if sched["tokens"] else None
+        sched_rows += sig.b
+        table_q += layers * sig.b * sig.t * sig.nblk * bs
+        table_blocks += layers * sig.b * sig.nblk
     return {
-        "kinds": tuple(kinds),
-        "prefill_rows": pf_rows,
-        "decode_rows": n_dec,
-        "live_tokens": live["tokens"],
-        "sched_tokens": sched["tokens"],
-        "rect_tokens": rect,
-        "kv_blocks_live": int(live["kv_blocks"]),
-        "kv_blocks_walked": kv_blocks_walked(
-            batches, bs, [model_cfg.window_of(i)
-                          for i in range(model_cfg.num_layers)]),
+        "kinds": tuple(kinds), "programs": programs,
+        "prefill_rows": pf_rows, "decode_rows": n_dec,
+        "prefill_tokens": pf_tokens, "decode_tokens": dec_tokens,
+        "live_tokens": live, "sched_tokens": sched, "rect_tokens": rect,
+        "logit_rows": logit_rows, "sched_logit_rows": sched_rows,
+        "kv_blocks_live": blocks, "kv_blocks_walked": walked,
+        "attn_q_ctx": q_ctx,
+        "table_q_ctx": table_q, "table_blocks": table_blocks,
+    }
+
+
+def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
+                  counts: dict | None = None, moe=None,
+                  shapes: dict | None = None, live_cost=None) -> dict:
+    """Live and scheduled (bucket-padded) work for one finalized step, as
+    ``SchedLedger.record_step`` takes it.
+
+    The live side is the step's one count (``counts``: ``step_counts`` of
+    ``batches``, made here where the caller has none); the padded side is
+    the signatures the batches ran under: their token bucket ``n`` through
+    the dense layers, ``b`` rows through the head, and through attention
+    what their block tables span where attention is the dense gather (the
+    kernel walks the live blocks whatever the table's width: both sides
+    then price those). Both sides run through obs/costmodel.step_work over
+    ``shapes`` (``costmodel.step_shapes``), with the routed layers' device
+    counts ``moe`` where the step has them, so goodput is a pure FLOPs
+    ratio hand-computable at any known bucket geometry. ``live_cost``: the
+    live side where the profiler has priced it already.
+    """
+    from dynamo_tpu.obs import costmodel as cm
+    from dynamo_tpu.obs.compile_ledger import walks_live_context
+
+    ec = engine_cfg
+    if counts is None:
+        counts = step_counts(
+            batches, ec.block_size,
+            [model_cfg.window_of(i) for i in range(model_cfg.num_layers)],
+            dec_rows=dec_rows)
+    if shapes is None:
+        shapes = cm.step_shapes(
+            model_cfg, block_size=ec.block_size,
+            kv_dtype=ec.kv_dtype or "bfloat16",
+            quantization=ec.quantization or "none")
+    lc = sc = None
+    if counts["live_tokens"]:
+        lc = live_cost or cm.step_work(shapes, counts, moe)
+        kernel = walks_live_context(ec)
+        sc = cm.step_work(shapes, {
+            "programs": counts["programs"],
+            "live_tokens": counts["sched_tokens"],
+            "logit_rows": counts["sched_logit_rows"],
+            "attn_q_ctx": counts["attn_q_ctx" if kernel else "table_q_ctx"],
+            "kv_blocks_walked": counts[
+                "kv_blocks_walked" if kernel else "table_blocks"]}, moe)
+    return {
+        **{k: counts[k] for k in (
+            "kinds", "prefill_rows", "decode_rows", "live_tokens",
+            "sched_tokens", "rect_tokens", "kv_blocks_live",
+            "kv_blocks_walked")},
         "live_flops": lc.flops if lc else 0.0,
         "sched_flops": sc.flops if sc else 0.0,
         "live_bytes": lc.hbm_bytes if lc else 0.0,

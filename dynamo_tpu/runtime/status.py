@@ -57,6 +57,7 @@ class SystemStatusServer:
         app.router.add_get("/metrics", self._metrics)
         app.router.add_get("/debug/sched", self._debug_sched)
         app.router.add_get("/debug/mem", self._debug_mem)
+        app.router.add_get("/debug/phases", self._debug_phases)
         self._runner = web.AppRunner(app)
         await self._runner.setup()
         site = web.TCPSite(self._runner, host, self.port)
@@ -103,6 +104,18 @@ class SystemStatusServer:
         from dynamo_tpu.obs.mem_ledger import get_mem_ledger
 
         return web.json_response(get_mem_ledger().debug_info())
+
+    async def _debug_phases(self, request: web.Request) -> web.Response:
+        """``{program: {instruction: phase}}`` of the step programs this
+        process's engine has built (obs/profiler.py ``debug_phases``): each
+        is lowered again and its compiled text read (jit holds both: tens of
+        milliseconds a program), in a thread of its own; ``{}`` where no engine serves here."""
+        import asyncio
+
+        from dynamo_tpu.obs.profiler import debug_phases
+
+        return web.json_response(await asyncio.get_running_loop()
+                                 .run_in_executor(None, debug_phases))
 
     async def _metrics(self, request: web.Request) -> web.Response:
         text = self.metrics.expose()

@@ -525,17 +525,21 @@ def test_engine_refuses_what_it_cannot_run(tmp_path):
 
 
 def test_kv_blocks_walked_by_hand():
-    from dynamo_tpu.obs.sched_ledger import kv_blocks_live, kv_blocks_walked
+    from dynamo_tpu.obs.compile_ledger import BucketSig
+    from dynamo_tpu.obs.sched_ledger import step_counts
 
     # one decode row at position 700 (45 blocks of 16) and a 512 chunk at 300
-    batches = [(None, [(None, 700, 1), (None, 300, 512)], None, None, None)]
-    assert kv_blocks_live(batches, 16) == 44 + 51
+    sig = BucketSig("mixed", 8, 512, 512, True, "bfloat16")
+    batches = [(sig, [(None, 700, 1), (None, 300, 512)], None, None, None)]
+    count = lambda windows, key: step_counts(batches, 16, windows)[key]
+    assert count([0], "kv_blocks_live") == 44 + 51
     # full layer: what is held. Window 128: the decode row walks from the
     # block of position 573 (35) to 43, nine blocks; the chunk from the
     # block of position 173 (10) to 50, 41 blocks.
-    assert kv_blocks_walked(batches, 16, [0]) == 44 + 51
-    assert kv_blocks_walked(batches, 16, [128]) == 9 + 41
-    assert kv_blocks_walked(batches, 16, [128, 0, 128]) == 2 * 50 + 95
+    assert count([0], "kv_blocks_walked") == 44 + 51
+    assert count([128], "kv_blocks_walked") == 9 + 41
+    assert count([128, 0, 128], "kv_blocks_walked") == 2 * 50 + 95
+    assert count([128, 0, 128], "kv_blocks_live") == 44 + 51
 
 
 # ---------------------------------------------------------------------------

@@ -6,13 +6,21 @@ and the names the step programs carry into a device trace."""
 from __future__ import annotations
 
 import asyncio
+import json
 import time
 
 import jax
 import pytest
 
 from dynamo_tpu.engine.engine import AsyncJaxEngine, EngineCore
-from dynamo_tpu.obs.profiler import LOOP_PHASES, LoopClock, loop_phase
+from dynamo_tpu.obs.profiler import (
+    DEVICE_PHASES,
+    LOOP_PHASES,
+    NESTED_PHASES,
+    LoopClock,
+    loop_iteration,
+    loop_phase,
+)
 
 from tests.test_engine import make_req, run_to_completion, tiny_config
 
@@ -63,6 +71,32 @@ def test_loop_clock_adds_up_and_spans_do_not_raise():
     assert clock.seconds["engine.plan"] == 0.0       # a copy, not the dict
 
 
+def test_nested_phases_and_the_iterations_unphased_time():
+    """``engine.dispatch``'s parts nest in it and count beside it, not on
+    top of it: what an iteration's wall holds outside every top-level phase
+    is ``engine.unphased``, whatever nests."""
+    clock = LoopClock()
+    with loop_iteration(clock):
+        time.sleep(0.01)                               # between the phases
+        with loop_phase(clock, "engine.dispatch"):
+            with loop_phase(clock, "engine.dispatch.fill"):
+                time.sleep(0.01)
+            with loop_phase(clock, "engine.dispatch.launch"), \
+                    loop_phase(clock, "engine.compile"):
+                time.sleep(0.01)
+        with loop_phase(clock, "engine.record", step=7) as span:
+            span.set(live_tokens=3)
+    sec = clock.seconds
+    parts = sum(sec[k] for k in NESTED_PHASES if k != "engine.compile")
+    assert 0.02 <= parts <= sec["engine.dispatch"]
+    assert sec["engine.compile"] <= sec["engine.dispatch.launch"]
+    assert clock.depth == 0
+    assert clock.phased == pytest.approx(
+        sec["engine.dispatch"] + sec["engine.record"])
+    # (lower bounds alone: a sleep under a loaded machine runs long)
+    assert 0.01 <= sec["engine.unphased"] < 0.5
+
+
 def test_stats_loop_has_every_phase(served):
     out, stats, _final, _wall = served
     assert all(len(toks) == 12 for toks in out.values())
@@ -78,10 +112,14 @@ def test_stats_loop_has_every_phase(served):
 def test_phases_cover_the_loops_wall_time(served):
     _out, _stats, final, wall = served
     loop = final["loop"]
-    total = sum(v for k, v in loop.items() if k != "engine.compile")
-    # The phases do not nest (compile apart) and leave out only the few
-    # statements between them, thread start-up and the join.
+    total = sum(v for k, v in loop.items() if k not in NESTED_PHASES)
+    # The top-level phases do not nest, and with what the iterations held
+    # outside them (engine.unphased) leave out only thread start-up and the
+    # join.
     assert 0.9 * wall <= total <= 1.001 * wall, (total, wall, loop)
+    assert loop["engine.unphased"] < 0.1 * wall
+    parts = sum(loop[k] for k in NESTED_PHASES if k != "engine.compile")
+    assert 0.5 * loop["engine.dispatch"] <= parts <= loop["engine.dispatch"]
 
 
 def test_ttft_parts_count_every_request_once(served):
@@ -176,7 +214,18 @@ def test_profiler_trace_holds_the_spans_on_the_engine_thread(tmp_path):
     names = next(iter(lines.values()))
     assert {"engine.inbox", "engine.plan", "engine.dispatch",
             "engine.finalize.wait", "engine.finalize.host", "engine.record",
-            "engine.post", "engine.compile"} <= names, names
+            "engine.post", "engine.compile", "engine.program",
+            "engine.dispatch.reset", "engine.dispatch.fill",
+            "engine.dispatch.place", "engine.dispatch.launch"} <= names, names
+    # The session was seen: the programs that ran under it are noted, and
+    # their tables are what shutdown() left for a reader in this process.
+    from dynamo_tpu.obs.profiler import phase_table_path
+
+    assert engine.core.traced_programs
+    tables = json.loads(phase_table_path().read_text())
+    assert set(tables) == engine.core.traced_programs
+    assert all(t and set(t.values()) <= set(DEVICE_PHASES)
+               for t in tables.values())
 
 
 @pytest.fixture(scope="module")

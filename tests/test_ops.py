@@ -572,6 +572,94 @@ def test_step_reads_the_qkv_matrices_in_place_on_v5e(v5e_device, monkeypatch,
     assert not moved, moved
 
 
+def _rehearsal_step_text(tmp_path, monkeypatch, model: str, b: int, t: int) -> str:
+    """A step program of the rehearsal's ``tiny`` or ``tiny-moe`` at the
+    kernel's head size (128: the v5e's kernel takes no other), compiled for
+    the described v5e; its text."""
+    import json
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root / "chipbench"))
+    import aot_check
+
+    hf = json.loads(
+        (root / "chipbench/rehearsal" / model / "config.json").read_text())
+    hf.update(head_dim=128, hidden_size=256)
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    parts = aot_check.build_abstract_runner(tmp_path, {"max_model_len": 1024})
+    texts = []
+    as_text = jax.stages.Compiled.as_text
+    monkeypatch.setattr(
+        jax.stages.Compiled, "as_text",
+        lambda self, *a, **kw: (texts.append(as_text(self, *a, **kw)),
+                                texts[-1])[1])
+    assert aot_check.compile_bucket(
+        *parts, b, t, parts[0].max_nblk, True, 256)["kernel"]
+    (text,) = texts
+    return text
+
+
+def _scan_body_instructions(text: str) -> list[tuple[str, str]]:
+    """(name, opcode) of the instructions of the scan's body: the ``while``
+    body and what it calls, fused computations and reducers apart."""
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w\-.]+) \(.*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and " = " in line:
+            m = re.match(r"^\s*(?:ROOT )?%?([\w\-.]+) = .*?\s([\w\-]+)\(", line)
+            if m:
+                cur.append((m.group(1), m.group(2), line))
+    body = re.search(r"while\(.*body=%?([\w\-.]+)", text).group(1)
+    todo, seen = [body], []
+    while todo:
+        c = todo.pop()
+        for name, opcode, line in comps[c]:
+            seen.append((name, opcode))
+            if opcode == "call":
+                todo.append(re.search(r"to_apply=%?([\w\-.]+)", line).group(1))
+    return seen
+
+
+@pytest.mark.parametrize("model, t", [("tiny", 1), ("tiny", 16),
+                                      ("tiny-moe", 1), ("tiny-moe", 16)])
+def test_phase_table_of_a_step_compiled_for_a_v5e(v5e_device, tmp_path,
+                                                  monkeypatch, model, t):
+    """The phase table (obs/profiler.py ``phase_table``) of a decode and a
+    mixed step of a dense and a routed model, read off the text compiled for
+    the described v5e: the kernel's calls are ``attention``, the grouped
+    matmuls the compiler made of ``lax.ragged_dot`` and gave no scope are
+    ``moe_experts`` through what reads them, and every instruction of the
+    scan's body that does work has a phase. The program's text only: no
+    time is read here."""
+    from dynamo_tpu.obs.profiler import DEVICE_PHASES, phase_table
+
+    text = _rehearsal_step_text(tmp_path, monkeypatch, model, 8, t)
+    table = phase_table(text)
+    assert set(table.values()) <= set(DEVICE_PHASES)
+    kernels = [n for n in table if n.startswith("paged_attention")]
+    assert kernels and all(table[n] == "attention" for n in kernels)
+    idle = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+            "call", "while", "copy-start", "copy-done")
+    unnamed = [(n, o) for n, o in _scan_body_instructions(text)
+               if o not in idle and n not in table]
+    assert not unnamed, unnamed
+    phases = set(table.values())
+    assert {"layer", "proj", "scatter", "attention", "logits"} <= phases
+    if model == "tiny-moe":
+        grouped = [n for n in table if n.startswith("ragged-dot")]
+        assert len(grouped) >= 3
+        assert all(table[n] == "moe_experts" for n in grouped)
+        assert {"moe_route", "moe_experts", "moe_shared"} <= phases
+    else:
+        assert "mlp" in phases and "moe_experts" not in phases
+
+
 def test_step_keeps_no_copy_of_a_period_on_v5e(v5e_device):
     """The benchmark's 12-layer SmallThinker cut (three periods of four
     layers), a 16-row decode step compiled for the described v5e: beyond

@@ -298,8 +298,9 @@ def test_profiler_disabled_is_inert(monkeypatch):
     assert prof.enabled is False
     monkeypatch.setattr(cm, "model_step_cost",
                         _raise_if_called, raising=True)
-    assert prof.measure([(_DECODE, [(0, 5, 1)], [0], _FakeArr((1,)), None)],
-                        0.01) == {}
+    monkeypatch.setattr(cm, "step_work", _raise_if_called, raising=True)
+    assert prof.measure(_counts(
+        [(_DECODE, [(0, 5, 1)], [0], _FakeArr((1,)), None)]), 0.01) == {}
     del cfg
 
     from dynamo_tpu.engine.engine import EngineCore
@@ -323,6 +324,14 @@ class _FakeArr:
         self.ndim = len(shape)
 
 
+def _counts(batches, windows=(0, 0), dec_rows=0):
+    """The one count of a step's rows, as EngineCore._record_step makes it
+    for the profiler (block size 16, the tiny preset's two full layers)."""
+    from dynamo_tpu.obs.sched_ledger import step_counts
+
+    return step_counts(batches, 16, windows, dec_rows=dec_rows)
+
+
 def _raise_if_called(*a, **k):
     raise AssertionError("cost model must not run when profiler disabled")
 
@@ -336,11 +345,24 @@ def test_profiler_charges_decode_and_prefill_rows():
          _FakeArr((1,)), None),
         (_DECODE, [(1, 8, 1), (2, 12, 1)], [0, 1], _FakeArr((2,)), None),
     ]
-    fields = prof.measure(batches, wall_s=0.05)
+    counts = _counts(batches)
+    # The walk measure() made itself until PR 43, now the step's one count:
+    # a chunk of 8 from 0, decode rows at 8 and 12; two full layers.
+    assert counts["live_tokens"] == 10 and counts["logit_rows"] == 3
+    assert counts["programs"] == 2 and counts["kv_blocks_live"] == 3
+    assert counts["kv_blocks_walked"] == 2 * 3
+    assert counts["attn_q_ctx"] == 2 * (8 * 9 // 2 + 9 + 13)
+    fields = prof.measure(counts, wall_s=0.05)
     assert fields["prefill_tokens"] == 8
     assert fields["decode_tokens"] == 2
     assert fields["flops"] > 0 and fields["hbm_bytes"] > 0
     assert fields["tok_s"] == pytest.approx(2 / 0.05)  # generated tokens/s
+    # Priced by the program's shapes from that count, and by nothing else:
+    # the ledger's goodput takes the same cost for its live side.
+    cost = cm.step_work(prof.shapes, counts)
+    assert (fields["flops"], fields["hbm_bytes"]) == (cost.flops,
+                                                      cost.hbm_bytes)
+    assert prof.last_cost == cost
 
 
 def test_perf_metrics_family_exposed():
@@ -364,7 +386,8 @@ def test_perf_tok_s_gauge_labeled_by_kv_dtype():
     install_perf_metrics(reg)
     prof = StepPerfProfiler(tiny_config_model(), tiny_config(kv_dtype="int4"),
                             device_kind="cpu", enabled=True)
-    prof.measure([(_DECODE, [(0, 8, 1)], [0], _FakeArr((1,)), None)], 0.01)
+    prof.measure(_counts(
+        [(_DECODE, [(0, 8, 1)], [0], _FakeArr((1,)), None)]), 0.01)
     text = reg.expose()
     assert 'kv_dtype="int4"' in text and 'kind="decode"' in text
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -28,6 +29,7 @@ from dynamo_tpu.obs.sched_ledger import (
     hol_span_culprits,
     install_sched_metrics,
     sched_enabled,
+    step_counts,
     step_geometry,
 )
 from dynamo_tpu.obs.compile_ledger import BucketSig, sig_for_rows
@@ -122,18 +124,23 @@ def tiny_ec(**kw) -> EngineConfig:
 
 
 def _cost(model_cfg, ec, *, tokens, logit_rows, attn_q_ctx, kv_blocks):
+    """One program's work priced as the step's one count is
+    (obs/costmodel.py step_work): ``attn_q_ctx`` the (query, key) pairs and
+    ``kv_blocks`` the blocks walked, both over all the layers."""
     from dynamo_tpu.obs import costmodel as cm
 
-    return cm.total_cost(cm.model_step_cost(
-        model_cfg, tokens=tokens, logit_rows=logit_rows,
-        attn_q_ctx=attn_q_ctx, kv_blocks=kv_blocks,
-        block_size=ec.block_size, kv_dtype="bfloat16", quantization="none"))
+    return cm.step_work(
+        cm.step_shapes(model_cfg, block_size=ec.block_size),
+        {"programs": 1, "live_tokens": tokens, "logit_rows": logit_rows,
+         "attn_q_ctx": attn_q_ctx, "kv_blocks_walked": kv_blocks})
 
 
 def test_step_geometry_decode_hand_computed():
-    """3 decode rows at contexts 1/17/31 (block=16): live attn walks the
-    real block tables (1+2+2 blocks ×16); the padded program is b=4
-    (bucket of 3 in (2,4)), nblk=4 (pow2 of need 2, floor 4)."""
+    """3 decode rows at contexts 1/17/31 (block=16): live attn sees the
+    keys up to each row's own (1+17+31 pairs a layer) and walks the real
+    block tables (1+2+2 blocks a layer); the padded program is b=4
+    (bucket of 3 in (2,4)), nblk=4 (pow2 of need 2, floor 4), and the
+    gather pays for every entry of its tables. Two layers."""
     from dynamo_tpu.models.config import resolve_model_config
 
     ec = tiny_ec()
@@ -147,10 +154,14 @@ def test_step_geometry_decode_hand_computed():
     assert g["kinds"] == ("decode",)
     assert g["prefill_rows"] == 0 and g["decode_rows"] == 3
     assert g["live_tokens"] == 3 and g["sched_tokens"] == 4
+    c = step_counts([(sig, rows, [True] * 3, toks, None)], ec.block_size,
+                    [0, 0], dec_rows=3)
+    assert c["logit_rows"] == 3 and c["attn_q_ctx"] == 2 * (1 + 17 + 31)
+    assert g["kv_blocks_live"] == 5 and g["kv_blocks_walked"] == 2 * 5
     live = _cost(mc, ec, tokens=3, logit_rows=3,
-                 attn_q_ctx=(1 + 2 + 2) * 16, kv_blocks=5)
+                 attn_q_ctx=2 * (1 + 17 + 31), kv_blocks=2 * 5)
     sched = _cost(mc, ec, tokens=4, logit_rows=4,
-                  attn_q_ctx=4 * 1 * 4 * 16, kv_blocks=16)
+                  attn_q_ctx=2 * 4 * 1 * 4 * 16, kv_blocks=2 * 16)
     assert g["live_flops"] == pytest.approx(live.flops)
     assert g["sched_flops"] == pytest.approx(sched.flops)
     assert g["live_bytes"] == pytest.approx(live.hbm_bytes)
@@ -180,10 +191,11 @@ def test_step_geometry_chunk_hand_computed():
     assert g["prefill_rows"] == 1 and g["decode_rows"] == 0
     assert g["live_tokens"] == 20 and g["sched_tokens"] == 34
     assert g["rect_tokens"] == 64
+    # query p of the chunk sees p + 1 keys: 20 x 21 / 2 pairs a layer
     live = _cost(mc, ec, tokens=20, logit_rows=1,
-                 attn_q_ctx=20 * 2 * 16, kv_blocks=2)
+                 attn_q_ctx=2 * 210, kv_blocks=2 * 2)
     sched = _cost(mc, ec, tokens=34, logit_rows=2,
-                  attn_q_ctx=2 * 32 * 4 * 16, kv_blocks=8)
+                  attn_q_ctx=2 * 2 * 32 * 4 * 16, kv_blocks=2 * 8)
     assert g["live_flops"] == pytest.approx(live.flops)
     assert g["sched_flops"] == pytest.approx(sched.flops)
     # a decode row beside the chunk: the same program, one more live token
@@ -643,28 +655,46 @@ def steps_with_blocks(request):
     """One run of the tiny preset on each attention path: a decoder, then
     two prompts of two full chunks each arriving together (their step
     overflows one token bucket and goes out as two programs). Every step's
-    batches beside what the ledger filed and what the ``engine.dispatch``
-    span carried for it."""
+    batches beside what the ledger filed, what the step's ``engine.record``
+    span carried of its one count, and the ``engine.program`` spans'
+    buckets."""
     import dynamo_tpu.engine.engine as eng
     from dynamo_tpu.engine.engine import EngineCore
     from dynamo_tpu.obs.profiler import loop_phase
 
     mp = pytest.MonkeyPatch()
-    seen, spans = [], []
+    seen, spans, programs, walks = [], [], [], []
     real_geometry, real_set = eng.step_geometry, loop_phase.set
+    real_counts = eng.step_counts
+    real_meta = jax.profiler.TraceAnnotation.set_metadata
 
     def geometry(mc, ec, batches, **kw):
         g = real_geometry(mc, ec, batches, **kw)
         seen.append((batches, g))
         return g
 
+    def counts(batches, *a, **kw):
+        walks.append((batches, real_counts(batches, *a, **kw)))
+        return walks[-1][1]
+
     def span_set(self, **attrs):
-        if "kv_blocks_live" in attrs:
+        if "kv_blocks_walked" in attrs:
             spans.append(attrs)
         real_set(self, **attrs)
 
+    class Program(jax.profiler.TraceAnnotation):
+        # As under a profiler session: an untraced step sets no attribute.
+        is_enabled = staticmethod(lambda: True)
+
+        def set_metadata(self, **attrs):
+            if "program" in attrs:
+                programs.append(attrs)
+            real_meta(self, **attrs)
+
     mp.setattr(eng, "step_geometry", geometry)
+    mp.setattr(eng, "step_counts", counts)
     mp.setattr(loop_phase, "set", span_set)
+    mp.setattr(eng.jax.profiler, "TraceAnnotation", Program)
     led = get_sched_ledger()
     led.reset()
     led.configure(True)
@@ -684,12 +714,14 @@ def steps_with_blocks(request):
     assert not core.has_work()
     total = led.snapshot()["kv_blocks_live_total"]
     mp.undo()
-    return core, seen, spans, total
+    # A step's rows are walked once between plan and record.
+    assert [id(b) for b, _ in walks] == [id(b) for b, _ in seen]
+    return core, seen, (spans, programs, [c for _, c in walks]), total
 
 
 @pytest.mark.parametrize("step", ["decode", "one-chunk-mixed", "split"])
 def test_kv_blocks_live_counts_what_the_rows_hold(steps_with_blocks, step):
-    core, seen, spans, total = steps_with_blocks
+    core, seen, (spans, programs, counts), total = steps_with_blocks
     ec, mc = core.engine_cfg, core.model_cfg
     kernel = ec.attn_impl == "pallas_interpret"
     pick = {
@@ -717,8 +749,24 @@ def test_kv_blocks_live_counts_what_the_rows_hold(steps_with_blocks, step):
         assert (again["sched_flops"] == g["sched_flops"]) == kernel
         assert g["sched_flops"] >= g["live_flops"] > 0
     assert total == sum(by_hand(b) for b, _ in seen)
-    # The span is set at dispatch, the record filed at finalize: the same
-    # steps in the same order, the same count.
-    assert [a["kv_blocks_live"] for a in spans] == [
-        g["kv_blocks_live"] for _, g in seen]
-    assert all(a["nblk"] in (4, 8) for a in spans)
+    # The span is set and the record filed from the one count: the same
+    # steps in the same order, the same numbers; the span carries what the
+    # benchmark's step_work_counts.py prices and nothing else.
+    assert all(set(a) == {"programs", "live_tokens", "logit_rows",
+                          "attn_q_ctx", "kv_blocks_walked"} for a in spans)
+    for key in ("programs", "live_tokens", "logit_rows", "attn_q_ctx",
+                "kv_blocks_walked"):
+        assert [a[key] for a in spans] == [c[key] for c in counts], key
+    for key in ("kv_blocks_live", "kv_blocks_walked", "live_tokens"):
+        assert [c[key] for c in counts] == [g[key] for _, g in seen], key
+    # One engine.program span a program, in dispatch order, under the
+    # name its program was built by (which holds its bucket) and with the
+    # ordinal of its step: what step_join.py reads, and nothing else.
+    sigs = [sig for b, _ in seen for sig, *_ in b]
+    assert [a["program"] for a in programs] == [
+        "jit_" + s.program() for s in sigs]
+    assert all(set(a) == {"step", "program"} for a in programs)
+    first = programs[0]["step"]
+    assert [a["step"] - first for a in programs] == [
+        i for i, (b, _) in enumerate(seen) for _ in b]
+    assert all(s.nblk in (4, 8) for s in sigs)
