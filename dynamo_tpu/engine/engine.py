@@ -1383,6 +1383,14 @@ class EngineCore:
         self._windows = tuple(mc.window_of(i) for i in range(mc.num_layers))
         self._routed_layers = (mc.num_layers - mc.first_k_dense
                                if mc.is_moe else 0)
+        # Whether a program of n tokens streams its experts: the routed
+        # layer's own predicate at this model's expert shape.
+        from dynamo_tpu.models.moe import streams_experts
+
+        expert = (mc.hidden_size, mc.moe_intermediate_size,
+                  jnp.dtype(mc.dtype).itemsize)
+        self._streams_experts = lambda n: streams_experts(
+            n, *expert, self.runner.mesh)
         # SLO-driven chunk sizing (prefill_chunk=0 = auto): resolve to
         # concrete per-QoS chunks BEFORE bucket enumeration and the
         # scheduler read the config — the prefill t ladder, warmup plan
@@ -2213,8 +2221,8 @@ class EngineCore:
                      moe: list | None = None, span=None) -> None:
         """Always-on step profile: one ring append per engine step. ``moe``:
         the step's routed-layer counts (layer steps, rows, experts touched,
-        largest groups), where its programs gave any. The step's rows are
-        walked once, here (``step_counts``): the profiler prices that count,
+        largest groups, streamed layer steps), where its programs gave any.
+        The step's rows are walked once, here (``step_counts``): the profiler prices that count,
         the scheduling ledger files it, and ``span`` (the step's
         ``engine.record``) carries it with the device's counts, for a reader
         of a trace to join to the step's programs by ``step``."""
@@ -2379,10 +2387,12 @@ class EngineCore:
                 lps = np.asarray(lps_dev)
                 if moe_dev is not None:
                     # The same program's output: it is there with the tokens.
-                    moe = (moe or [0, 0, 0, 0])
+                    moe = (moe or [0, 0, 0, 0, 0])
                     moe[0] += self._routed_layers
                     for i, x in enumerate(np.asarray(moe_dev)):
                         moe[i + 1] += int(x)
+                    if self._streams_experts(sig.n):
+                        moe[4] += self._routed_layers
             with loop_phase(clock, "engine.finalize.host"):
                 if sig.kind == "verify":
                     self._finalize_verify(rows, sample_rows, toks, lps,

@@ -635,7 +635,7 @@ def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
             # One chip told which experts it holds: grouped, with counts.
             from dynamo_tpu.models.moe import moe_mlp_held
 
-            mlp_out, counts = moe_mlp_held(x, lp, cfg, live, routing)
+            mlp_out, counts = moe_mlp_held(x, lp, cfg, live, routing, mesh)
         elif moe_impl == "ep":
             # Dropless ragged dispatch (serving default for ep>1): exact
             # under any routing skew — see models/moe.py.

@@ -8,7 +8,9 @@ One router (:func:`route`) and one grouped core (:func:`held_rows`: the
 (token, choice) rows of the experts held here, sorted by expert so that each
 expert's tokens form one contiguous ragged group of one ``lax.ragged_dot``;
 static shapes, no capacity, nothing dropped; rows of experts held elsewhere
-belong to no group) under three layers:
+belong to no group; in a decode-sized program on a TPU whose experts fit
+VMEM, :func:`streams_experts`, the same result by one kernel that streams
+each touched expert's matrices once, ops/moe_stream.py) under three layers:
 
 - :func:`moe_mlp_held` (``moe_impl="held"``): one chip told which experts
   it holds of a wider router, as one chip's share of an expert-parallel
@@ -78,8 +80,44 @@ def route(xt: jax.Array, lp: Params, cfg: ModelConfig):
     return topi, weights * cfg.routed_scaling_factor
 
 
+# The most rows a program may hold for its experts to be streamed
+# (ops/moe_stream.py computes every row against every touched expert):
+# an expert's three products are N x 6HM FLOP against 6HM bytes of bf16
+# weights, N FLOP a byte whatever H and M, and the chip's two peaks
+# (obs/costmodel.py HW_SPECS, v5e: 197 TFLOP/s over 819 GB/s) meet at 240.
+# The MXU takes a weight tile's 128 rows in the time of one, so up to 128
+# rows an expert computes in about half the time it takes to read; 64
+# leaves that room, covers the decode ladder, and leaves a chunk program's
+# hundreds of rows, dozens to each of all the experts, to the grouped form.
+STREAM_MAX_ROWS = 64
+
+
+def streams_experts(n: int, h: int, m: int, itemsize: int,
+                    mesh=None) -> bool:
+    """Whether a program of ``n`` tokens computes its held experts
+    (``[h, m]``, ``[h, m]`` and ``[m, h]`` of ``itemsize`` bytes each) by
+    the streaming kernel and not by groups: the program is decode-sized
+    (``STREAM_MAX_ROWS``), one expert's three matrices fit the kernel's
+    VMEM twice (the pipeline holds the next beside the current), and the
+    backend is a TPU (the kernel is Mosaic's; on the CPU the tests run it
+    interpreted and every engine keeps the grouped form) on which the
+    program is one chip's: under a ``mesh`` of several the compiler
+    partitions the grouped form over the matrices' shards and has no rule
+    for a kernel (inside a ``shard_map`` the slab is local and there is no
+    mesh to hand in). Shapes alone: no option, no model's name.
+    :func:`held_rows` asks it while a program is traced, the engine for
+    each program it records (``moe_streamed_layer_steps_total``)."""
+    from dynamo_tpu.ops import moe_stream
+
+    return (n <= STREAM_MAX_ROWS
+            and moe_stream.vmem_bytes(n, h, m, itemsize)
+            <= moe_stream.VMEM_LIMIT_BYTES
+            and jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1))
+
+
 def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
-              layer=None, act=jax.nn.silu):
+              layer=None, act=jax.nn.silu, mesh=None):
     """The grouped formulation for the experts held here, the first
     ``E_held`` of the router's (a shard that holds others hands in ``topi``
     less its first expert's index: what falls outside ``0 .. E_held - 1`` is
@@ -99,10 +137,20 @@ def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
     groups are empty. A grouped matmul is a custom call, and a slab cut out
     of the stack for it would be copied, every step.
 
+    Where :func:`streams_experts` says so (``mesh``: the one that
+    partitions the program, if any), the same results come from
+    ops/moe_stream.py ``stream_rows``: no sort, gather or scatter, every
+    row against each touched expert, whose matrices are read once.
+
     Returns ([N, H] float32: the held experts' part of the layer's result,
     int32 [3]: rows computed, experts touched, rows of the largest group)."""
     n, h = xt.shape
     k = topi.shape[1]
+    if streams_experts(n, h, w_gate.shape[-1], w_gate.dtype.itemsize, mesh):
+        from dynamo_tpu.ops.moe_stream import stream_rows
+
+        return stream_rows(xt, topi, weights, w_gate, w_up, w_down, live,
+                           layer, act)
     first = 0
     if layer is not None:
         n_layers, held = w_gate.shape[:2]
@@ -140,7 +188,7 @@ def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
 
 
 def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None,
-                 routing=None):
+                 routing=None, mesh=None):
     """The routed FFN of one chip, which is told which experts it holds
     (``lp["w_gate"]`` is ``[E_held, H, M]``: the first ``E_held`` of the
     ``cfg.router_width`` the router scores; or, with ``lp["expert_layer"]``,
@@ -149,8 +197,8 @@ def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None,
     shared expert once. With every expert held (``E_held`` is the router's
     width) it is the whole layer. ``routing``: the ``(topi, weights)`` of a
     router that read another state, earlier (:func:`route`); None routes
-    from ``x``. x [N, H] -> ([N, H], int32 [3] counts). One chip: no
-    exchange."""
+    from ``x``. ``mesh``: the step's, if it has one (:func:`held_rows`).
+    x [N, H] -> ([N, H], int32 [3] counts). One chip: no exchange."""
     from dynamo_tpu.models.llama import swiglu
     from dynamo_tpu.obs.profiler import phase
 
@@ -162,7 +210,7 @@ def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None,
     with phase("moe_experts"):
         y, counts = held_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
                               lp["w_down"], live, lp.get("expert_layer"),
-                              gate_act(cfg))
+                              gate_act(cfg), mesh)
     if cfg.num_shared_experts:
         with phase("moe_shared"):
             y = y + swiglu(xt, lp["shared_gate"], lp["shared_up"],
@@ -189,7 +237,7 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
         xt = x.reshape(-1, h)
         topi, weights = routing or route(xt, lp, cfg)
         y, _ = held_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
-                         lp["w_down"], act=act)
+                         lp["w_down"], act=act, mesh=mesh)
         if shared is not None:
             from dynamo_tpu.models.llama import swiglu
 

@@ -209,6 +209,9 @@ class SchedStepRecord:
     moe_rows: int = 0               # (token, choice) rows computed here
     moe_experts_touched: int = 0    # held experts that had rows
     moe_largest_group: int = 0      # rows of each layer's largest group
+    # ... of them, those of programs whose experts the streaming kernel
+    # computed (models/moe.py streams_experts, asked of each program's N)
+    moe_streamed_layer_steps: int = 0
     live_flops: float = 0.0
     sched_flops: float = 0.0
     live_bytes: float = 0.0
@@ -242,7 +245,8 @@ class SchedStepRecord:
             d.update(moe_layer_steps=self.moe_layer_steps,
                      moe_rows=self.moe_rows,
                      moe_experts_touched=self.moe_experts_touched,
-                     moe_largest_group=self.moe_largest_group)
+                     moe_largest_group=self.moe_largest_group,
+                     moe_streamed_layer_steps=self.moe_streamed_layer_steps)
         if self.queue_depths:
             d["queue_depths"] = dict(self.queue_depths)
         if self.blocked:
@@ -283,7 +287,8 @@ class SchedLedger:
         self.rect_tokens_total = 0
         self.kv_blocks_live_total = 0
         self.kv_blocks_walked_total = 0
-        self.moe_totals = [0, 0, 0, 0]   # layer steps, rows, touched, largest
+        # layer steps, rows, touched, largest, streamed layer steps
+        self.moe_totals = [0, 0, 0, 0, 0]
         self.padding_flops_total = 0.0
         self.padding_bytes_total = 0.0
         self.hol_stall_seconds_total = 0.0
@@ -316,7 +321,7 @@ class SchedLedger:
             self.rect_tokens_total = 0
             self.kv_blocks_live_total = 0
             self.kv_blocks_walked_total = 0
-            self.moe_totals = [0, 0, 0, 0]
+            self.moe_totals = [0, 0, 0, 0, 0]
             self.padding_flops_total = 0.0
             self.padding_bytes_total = 0.0
             self.hol_stall_seconds_total = 0.0
@@ -376,7 +381,7 @@ class SchedLedger:
         rect_tokens: int = 0,
         kv_blocks_live: int = 0,
         kv_blocks_walked: int = 0,
-        moe: tuple[int, int, int, int] | None = None,
+        moe: tuple[int, int, int, int, int] | None = None,
         live_flops: float = 0.0,
         sched_flops: float = 0.0,
         live_bytes: float = 0.0,
@@ -408,7 +413,8 @@ class SchedLedger:
             rect_tokens=rect_tokens, kv_blocks_live=kv_blocks_live,
             kv_blocks_walked=kv_blocks_walked,
             **(dict(zip(("moe_layer_steps", "moe_rows", "moe_experts_touched",
-                         "moe_largest_group"), moe)) if moe else {}),
+                         "moe_largest_group", "moe_streamed_layer_steps"),
+                        moe)) if moe else {}),
             live_flops=live_flops, sched_flops=sched_flops,
             live_bytes=live_bytes, sched_bytes=sched_bytes,
             goodput=goodput, budget_util=budget_util,
@@ -507,6 +513,7 @@ class SchedLedger:
                 "moe_rows_total": self.moe_totals[1],
                 "moe_experts_touched_total": self.moe_totals[2],
                 "moe_largest_group_total": self.moe_totals[3],
+                "moe_streamed_layer_steps_total": self.moe_totals[4],
                 "padding_flops_total": self.padding_flops_total,
                 "padding_hbm_bytes_total": self.padding_bytes_total,
                 "admission_blocked": dict(self.blocked_totals),

@@ -6,6 +6,8 @@ kernels with numerically-equivalent XLA fallbacks for CPU tests:
 
 - paged_attention: flash-style attention over a block-table-paged KV cache.
 - ring_attention: blockwise attention sharded over the "seq" mesh axis.
+- moe_stream: a decode step's routed experts, each touched expert's three
+  matrices streamed once (the fallback is models/moe.py's grouped form).
 """
 
 from dynamo_tpu.ops.paged_attention import (

@@ -285,7 +285,7 @@ ROUTED_CELLS = {
 }
 ROUTED = ("moe.rows_per_expert_step", "device.moe_pct",
           "moe.expert_gemm_roofline_pct", "attn.blocks_walked_pct",
-          "moe.experts_touched_pct")
+          "moe.experts_touched_pct", "moe.streamed_layer_steps_pct")
 MOE = {"experts_held": 16, "router_width": 128, "experts_per_token": 8,
        "routed_layers": 4, "hidden_size": 6144, "expert_width": 2048,
        "bytes_per_param": 2,
@@ -390,6 +390,33 @@ def _touched(steps: int, touched: int | None, held: int | None = 64):
 ], ids=["none", "a_fraction", "all", "no_steps", "no_counter", "no_facts"])
 def test_experts_touched_share_on_a_hand_made_context(ctx, expect):
     value = measure.load_reader("moe.experts_touched_pct").read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))
+
+
+def _streamed(steps: int, streamed: int | None):
+    sched0 = {"moe_layer_steps_total": 100}
+    sched1 = {"moe_layer_steps_total": 100 + steps}
+    if streamed is not None:
+        sched0["moe_streamed_layer_steps_total"] = 60
+        sched1["moe_streamed_layer_steps_total"] = 60 + streamed
+    return _ctx({"sched": sched0}, {"sched": sched1})
+
+
+@pytest.mark.parametrize("ctx, expect", [
+    # 1,200 layer-steps: none of a program that streams its experts (every
+    # expert too large, or every step a chunk's), all but the five chunk
+    # steps' twelve layers, every one
+    (_streamed(1_200, 0), 0.0),
+    (_streamed(1_200, 1_140), 95.0),
+    (_streamed(1_200, 1_200), 100.0),
+    # no routed layer-step in the window; a program without the counter
+    # (the parent's); a model without a routed layer
+    (_streamed(0, 0), None),
+    (_streamed(1_200, None), None),
+    (_ctx({"sched": {}}, {"sched": {}}), None),
+], ids=["none", "a_fraction", "all", "no_steps", "no_counter", "no_routed_layer"])
+def test_streamed_layer_steps_share_on_a_hand_made_context(ctx, expect):
+    value = measure.load_reader("moe.streamed_layer_steps_pct").read(ctx)
     assert value == (None if expect is None else pytest.approx(expect))
 
 

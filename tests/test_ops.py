@@ -503,6 +503,19 @@ def _aot_parts(config: str):
     return aot_check, aot_check.build_abstract_runner(config_dir, {})
 
 
+def _compiled_texts(monkeypatch) -> list[str]:
+    """The text of every program compiled from here on, as
+    ``aot_check.compile_bucket`` asks for it (``Compiled.as_text``): the
+    list fills as they compile."""
+    texts = []
+    as_text = jax.stages.Compiled.as_text
+    monkeypatch.setattr(
+        jax.stages.Compiled, "as_text",
+        lambda self, *a, **kw: (texts.append(as_text(self, *a, **kw)),
+                                texts[-1])[1])
+    return texts
+
+
 def test_step_holds_no_copy_of_the_pool_on_v5e(v5e_device):
     """The benchmark's 16-layer Mistral-7B cut, a decode step compiled for
     the described v5e at two pool sizes (what ModelRunner._fit_pool does on
@@ -547,12 +560,7 @@ def test_step_reads_the_qkv_matrices_in_place_on_v5e(v5e_device, monkeypatch,
 
     aot_check, parts = _aot_parts(config)
     runner, cfg = parts[:2]
-    texts = []
-    as_text = jax.stages.Compiled.as_text
-    monkeypatch.setattr(
-        jax.stages.Compiled, "as_text",
-        lambda self, *a, **kw: (texts.append(as_text(self, *a, **kw)),
-                                texts[-1])[1])
+    texts = _compiled_texts(monkeypatch)
     assert aot_check.compile_bucket(
         *parts, b, t, runner.max_nblk, True, 2048)["kernel"]
     (text,) = texts
@@ -589,12 +597,7 @@ def _rehearsal_step_text(tmp_path, monkeypatch, model: str, b: int, t: int) -> s
     hf.update(head_dim=128, hidden_size=256)
     (tmp_path / "config.json").write_text(json.dumps(hf))
     parts = aot_check.build_abstract_runner(tmp_path, {"max_model_len": 1024})
-    texts = []
-    as_text = jax.stages.Compiled.as_text
-    monkeypatch.setattr(
-        jax.stages.Compiled, "as_text",
-        lambda self, *a, **kw: (texts.append(as_text(self, *a, **kw)),
-                                texts[-1])[1])
+    texts = _compiled_texts(monkeypatch)
     assert aot_check.compile_bucket(
         *parts, b, t, parts[0].max_nblk, True, 256)["kernel"]
     (text,) = texts
@@ -671,6 +674,160 @@ def test_step_keeps_no_copy_of_a_period_on_v5e(v5e_device):
     r = aot_check.compile_bucket(*parts, 16, 1, parts[0].max_nblk, True, 2048)
     assert r["kernel"]
     assert r["beyond_arguments_bytes"] < 32 * 2**20, r
+
+
+# -- the streaming expert kernel (ops/moe_stream.py) on the described v5e ------
+
+def _on_a_tpu(monkeypatch):
+    """The routed layer asks the backend whether its kernel can run
+    (models/moe.py ``streams_experts``); a program compiled here for the
+    described chip is traced as the chip's engine traces it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _step_text(monkeypatch, config: str, b: int, t: int) -> str:
+    """The text of one of the benchmark's configurations' step programs,
+    compiled for the described v5e as an engine on the chip builds it."""
+    aot_check, parts = _aot_parts(config)
+    with monkeypatch.context() as m:
+        _on_a_tpu(m)
+        texts = _compiled_texts(m)
+        assert aot_check.compile_bucket(
+            *parts, b, t, parts[0].max_nblk, True, 2048)["kernel"]
+    (text,) = texts
+    return text
+
+
+def _without_metadata(text: str) -> str:
+    """A program's text less what names its source: the header's tables of
+    files, functions, locations and frames, and each instruction's
+    ``metadata``."""
+    import re
+
+    if "StackFrames" in text:
+        text = text[text.index("\n\n", text.index("StackFrames")):]
+    return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+
+
+@pytest.mark.parametrize("n", [8, 16, 64], ids=["b8", "b16", "b64"])
+def test_expert_stream_kernel_compiles_for_v5e(v5e_device, monkeypatch, n):
+    """Mosaic itself, at SmallThinker's decode shapes (hidden 2560, 64
+    experts x 768, 6 a token) over the twelve layers' stack and a traced
+    layer index: the kernel lowers, and within the VMEM it asks for. Held
+    to its own arithmetic (``vmem_bytes``, what the predicate reads): it
+    compiles with no more than that."""
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.ops import moe_stream
+
+    nl, e, h, m, k = 12, 64, 2560, 768, 6
+    need = moe_stream.vmem_bytes(n, h, m, 2)
+    assert 2 * 3 * h * m * 2 < need <= moe_stream.VMEM_LIMIT_BYTES
+    monkeypatch.setattr(moe_stream, "VMEM_LIMIT_BYTES", need)
+    sh = SingleDeviceSharding(v5e_device)
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    compiled = jax.jit(
+        lambda x, topi, w, wg, wu, wd, live, layer: moe_stream.stream_rows(
+            x, topi, w, wg, wu, wd, live, layer, jax.nn.relu)
+    ).lower(abstract((n, h), jnp.bfloat16), abstract((n, k), jnp.int32),
+            abstract((n, k), jnp.float32),
+            abstract((nl, e, h, m), jnp.bfloat16),
+            abstract((nl, e, h, m), jnp.bfloat16),
+            abstract((nl, e, m, h), jnp.bfloat16),
+            abstract((n,), jnp.bool_), abstract((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged" not in text
+    # nothing of an expert stack's size beside the arguments
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3 * h * m * 2
+
+
+@pytest.mark.parametrize("config, n, streams", [
+    ("smallthinker-21b-a3b-l12", 8, True),        # a decode program
+    ("smallthinker-21b-a3b-l12", 16, True),
+    ("smallthinker-21b-a3b-l12", 64, True),       # the decode ladder's top
+    ("smallthinker-21b-a3b-l12", 264, False),     # a chunk program: b8 t256
+    ("smallthinker-21b-a3b-l12", 520, False),     # b8 t512
+    ("k-exaone-236b-a23b-ep8-l5", 16, False),     # 75.5 MB an expert
+    ("k-exaone-236b-a23b-ep8-l5", 8, False),
+])
+def test_which_programs_stream_their_experts(monkeypatch, config, n, streams):
+    """The routed layer's one predicate (models/moe.py ``streams_experts``)
+    at the benchmark's two routed configurations: SmallThinker's decode
+    programs yes, its chunk programs no (hundreds of rows: the grouped form
+    is the right one), K-EXAONE's no (an expert does not fit VMEM twice);
+    and nowhere but on a TPU, in a program that is one chip's (a mesh of
+    several partitions the grouped form; a kernel it could only replicate)."""
+    from pathlib import Path
+
+    from dynamo_tpu.models import moe
+    from dynamo_tpu.models.config import resolve_model_config
+
+    cfg = resolve_model_config(str(
+        Path(__file__).resolve().parents[1] / "chipbench/configs" / config))
+    shape = (n, cfg.hidden_size, cfg.moe_intermediate_size,
+             jnp.dtype(cfg.dtype).itemsize)
+    assert not moe.streams_experts(*shape)             # the CPU's answer
+    _on_a_tpu(monkeypatch)
+    assert moe.streams_experts(*shape) == streams
+    devices = np.array(jax.devices())
+    assert moe.streams_experts(
+        *shape, jax.sharding.Mesh(devices[:1], ("model",))) == streams
+    assert not moe.streams_experts(
+        *shape, jax.sharding.Mesh(devices[:2], ("model",)))
+
+
+def test_decode_step_streams_its_experts_and_a_chunk_step_groups_them_on_v5e(
+        v5e_device, monkeypatch):
+    """SmallThinker's ``b8 t1`` step compiled for the described v5e holds the
+    streaming kernel, under the ``moe_experts`` phase by the scope the
+    program gives, and no grouped matmul, and nothing in it copies, slices
+    or transposes an expert stack or a layer's slab of one; its ``b8 t512``
+    step keeps ``lax.ragged_dot``. The program's text only: no time is read
+    here."""
+    import re
+
+    from dynamo_tpu.obs.profiler import phase_table
+
+    text = _step_text(monkeypatch, "smallthinker-21b-a3b-l12", 8, 1)
+    assert "ragged" not in text
+    kernels = {n: p for n, p in phase_table(text).items()
+               if n.startswith("moe_stream")}
+    assert len(kernels) == 4 and set(kernels.values()) == {"moe_experts"}, \
+        kernels                                   # a period's four layers
+    h, m = 2560, 768
+    moved = []
+    for name, dims in re.findall(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]",
+                                 text, re.M):
+        shape = tuple(int(d) for d in dims.split(","))
+        # an expert's matrix behind any leading dimensions: a layer's 64,
+        # the stack's [12, 64]
+        if shape[-2:] in {(h, m), (m, h)} and re.match(
+                r"copy|slice|dynamic-slice|transpose|.*fusion", name) \
+                and not name.startswith(("copy-start", "copy-done")):
+            moved.append(f"{name} [{dims}]")
+    assert not moved, moved
+
+    chunk = _step_text(monkeypatch, "smallthinker-21b-a3b-l12", 8, 512)
+    assert "ragged-dot" in chunk and "moe_stream" not in chunk
+
+
+def test_kexaone_decode_step_keeps_the_grouped_form_on_v5e(v5e_device,
+                                                           monkeypatch):
+    """K-EXAONE's ``b16 t1`` step compiled for the described v5e is the
+    program it was before the streaming kernel: the predicate says no (an
+    expert of 75.5 MB), so its text, metadata stripped, equals the text
+    built with the kernel out of reach."""
+    from dynamo_tpu.models import moe
+
+    text = _step_text(monkeypatch, "k-exaone-236b-a23b-ep8-l5", 16, 1)
+    assert "ragged-dot" in text and "moe_stream" not in text
+    monkeypatch.setattr(moe, "streams_experts", lambda *a: False)
+    assert _without_metadata(text) == _without_metadata(
+        _step_text(monkeypatch, "k-exaone-236b-a23b-ep8-l5", 16, 1))
 
 
 def test_paged_attention_kernel_parity_at_bench_shapes():

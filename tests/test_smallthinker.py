@@ -450,13 +450,26 @@ def _engine_config(tmp_path, **kw):
                         prefill_chunk=64, decode_bucket=(4, 8), **kw)
 
 
-@pytest.mark.parametrize("attn_impl", ["dense", "pallas_interpret"])
-def test_engine_serves_it_and_counts(tmp_path, attn_impl):
+@pytest.mark.parametrize("attn_impl, experts", [
+    ("dense", "grouped"), ("pallas_interpret", "grouped"),
+    ("dense", "streamed")])
+def test_engine_serves_it_and_counts(tmp_path, monkeypatch, attn_impl, experts):
     """Through ``EngineCore`` as any model: the scheduler, the pool, the
     lattice. The engine computes in bf16, so the logprobs it reports are
     held to the reference loosely here (the chip's probe has the limits);
-    the counters are exact."""
+    the counters are exact. ``streamed``: the decode programs' experts by
+    the streaming kernel (ops/moe_stream.py, interpreted; the predicate
+    bent to this size and backend), the chunk programs' by groups, as on
+    the chip."""
+    import functools
+
     from dynamo_tpu.engine.engine import EngineCore
+    from dynamo_tpu.ops import moe_stream
+
+    if experts == "streamed":
+        monkeypatch.setattr(moe, "streams_experts", lambda n, *shape: n <= 8)
+        monkeypatch.setattr(moe_stream, "stream_rows", functools.partial(
+            moe_stream.stream_rows, interpret=True))
     from dynamo_tpu.obs.sched_ledger import get_sched_ledger
     from dynamo_tpu.protocols.common import (
         PreprocessedRequest,
@@ -508,6 +521,12 @@ def test_engine_serves_it_and_counts(tmp_path, attn_impl):
     assert d["moe_rows_total"] == d["live_tokens_total"] * k * routed
     assert 0 < d["moe_experts_touched_total"] <= held * d["moe_layer_steps_total"]
     assert d["moe_largest_group_total"] <= d["moe_rows_total"]
+    # the layer steps of the programs the predicate says yes to: none on the
+    # CPU; bent, the decode programs' and not the chunk programs'
+    streamed = d["moe_streamed_layer_steps_total"]
+    assert streamed % routed == 0
+    assert (0 < streamed < d["moe_layer_steps_total"]
+            if experts == "streamed" else streamed == 0)
     # six of the eight layers slide (window 24): they walk less than they hold
     assert d["kv_blocks_live_total"] * 2 < d["kv_blocks_walked_total"] \
         < d["kv_blocks_live_total"] * 8

@@ -55,13 +55,22 @@ def test_innermost_phase_reads_scopes_not_primitives():
     assert innermost_phase("ragged-dot-none") is None
 
 
-def test_another_grouped_matmul_under_the_scope_keeps_the_phase(monkeypatch):
+@pytest.mark.parametrize("experts", ["another_grouped_matmul",
+                                     "the_streaming_kernel"])
+def test_another_grouped_matmul_under_the_scope_keeps_the_phase(monkeypatch,
+                                                                experts):
     """``lax.ragged_dot`` in ``moe.held_rows`` replaced by another grouped
-    matmul (each row against its group's matrix, gathered): the phase table's
-    ``moe_experts`` set is still there, and names no ``ragged-dot``: the new
-    readers do not depend on the instruction's name."""
+    matmul (each row against its group's matrix, gathered), or the whole of
+    ``held_rows`` by the streaming kernel (``ops/moe_stream.py``,
+    interpreted here: its custom call on a v5e is held to the phase in
+    tests/test_ops.py): the phase table's ``moe_experts`` set is still
+    there, and names no ``ragged-dot``: the new readers do not depend on
+    the instruction's name."""
+    import functools
+
     from dynamo_tpu.engine.engine import EngineCore
     from dynamo_tpu.models import moe
+    from dynamo_tpu.ops import moe_stream
     from tests.test_engine import tiny_config
 
     def grouped(xs, w, sizes):
@@ -70,7 +79,12 @@ def test_another_grouped_matmul_under_the_scope_keeps_the_phase(monkeypatch):
         return jnp.einsum("rk,rkn->rn", xs,
                           w[jnp.clip(gid, 0, w.shape[0] - 1)])
 
-    monkeypatch.setattr(moe.lax, "ragged_dot", grouped)
+    if experts == "another_grouped_matmul":
+        monkeypatch.setattr(moe.lax, "ragged_dot", grouped)
+    else:
+        monkeypatch.setattr(moe, "streams_experts", lambda *shape: True)
+        monkeypatch.setattr(moe_stream, "stream_rows", functools.partial(
+            moe_stream.stream_rows, interpret=True))
     runner = EngineCore(tiny_config(model="tiny-moe")).runner
     assert runner.moe_impl == "held"
     fn = runner._build_step_fn(4, 1, 4, fast_greedy=True)
