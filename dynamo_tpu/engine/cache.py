@@ -4,7 +4,11 @@ The device tier (G1) of the KV block story: cache tensors are
 ``[layers, num_blocks, block_size, kv_heads, head_dim]`` jax.Arrays, sharded
 over the mesh "model" axis on kv_heads. Block 0 is reserved as the trash
 block for padding writes (models/llama.py). Host/disk tiers and offload live
-in dynamo_tpu.kvbm (reference: lib/llm/src/block_manager/).
+in dynamo_tpu.kvbm (reference: lib/llm/src/block_manager/). ``layers`` counts
+the layers that have attention: a model whose layers are one mixer each
+(``ModelConfig.hybrid_pattern``) keeps K and V for its attention layers
+alone, and for its recurrent layers a second kind of cache beside this one,
+a pool of fixed-size state a sequence (models/mamba.py; ``ModelRunner.ssm``).
 
 With ``kv_dtype="int8"`` each cache becomes a two-leaf pytree
 ``{"q": int8 payload [L, NB, BS, KH, D], "s": float32 scales [L, NB, KH]}``
@@ -55,7 +59,8 @@ class KVCacheSpec:
         return cls(
             num_blocks=num_blocks,
             block_size=block_size,
-            num_layers=cfg.num_layers,
+            # (a layer of another mixer has no K and V: models/mamba.py)
+            num_layers=cfg.attn_layers,
             num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.head_dim,
             dtype=cfg.dtype,

@@ -26,6 +26,7 @@ import threading
 import time
 from pathlib import Path
 from dataclasses import dataclass, field
+import functools
 from functools import partial
 from typing import TYPE_CHECKING, Any, AsyncIterator, Callable
 
@@ -62,7 +63,7 @@ from dynamo_tpu.kvbm.stream_ckpt import (
     build_ckpt_record,
     get_stream_ckpt_metrics,
 )
-from dynamo_tpu.models import llama
+from dynamo_tpu.models import llama, mamba
 from dynamo_tpu.models.config import ModelConfig, resolve_model_config
 from dynamo_tpu.obs.compile_ledger import (
     WARMUP_MODES,
@@ -85,6 +86,7 @@ from dynamo_tpu.obs.profiler import (
 )
 from dynamo_tpu.obs.mem_ledger import get_mem_ledger, live_ids_of
 from dynamo_tpu.obs.sched_ledger import (
+    SSM_COUNTS,
     HolStall,
     get_sched_ledger,
     step_counts,
@@ -110,6 +112,61 @@ def _step_tokens(b: int, t: int, sp_prefill: bool) -> int:
 def _runs(sizes: list[int]) -> list[tuple[int, int]]:
     """(first index, size) of consecutive runs of the given sizes."""
     return list(zip(itertools.accumulate(sizes, initial=0), sizes))
+
+
+def _recurrent_engine_config(ec: EngineConfig) -> EngineConfig:
+    """``ec`` for a model with recurrent layers (models/mamba.py): what
+    keeps or moves a sequence's cache as blocks alone has nothing to resume
+    from without the state at the blocks' end, so prefix matching is off
+    (no block is committed for reuse, ``PrefixPool.match_prefix`` gives
+    nothing; session retention, which claims its blocks through it, falls
+    back to recomputing the prompt) and the paths that would move blocks
+    are refused, each by its option."""
+    refused = {
+        "spec_ngram": (ec.spec_ngram > 0,
+                       "a rejected draft would need the state rolled back"),
+        "tp": (ec.tp > 1, "the state and the mixer are not sharded"),
+        "pp": (ec.pp > 1, "the stages' layers are not of one kind"),
+        "sp": (ec.sp > 1, "the scan does not run over a 'seq' axis"),
+        "ep": (ec.ep > 1, "the experts' exchange is not wired to a pattern"),
+        "kv_dtype": (ec.kv_dtype in ("int8", "int4"),
+                     "a quantized cache beside a float32 state is not "
+                     "implemented"),
+        "host_kv_blocks / disk_kv_path / remote_kv_addr": (
+            ec.host_kv_blocks > 0 or bool(ec.disk_kv_path)
+            or bool(ec.remote_kv_addr),
+            "offloaded blocks come back without the state at their end"),
+        "stream_ckpt_blocks": (
+            ec.stream_ckpt_blocks > 0,
+            "a checkpoint of blocks cannot resume the state"),
+    }
+    for option, (hit, why) in refused.items():
+        if hit:
+            raise ValueError(
+                f"{option} is refused for a model with recurrent layers "
+                f"({ec.model!r}): {why}")
+    if ec.enable_prefix_caching:
+        log.info("prefix matching is off for %s: a model with recurrent "
+                 "layers cannot reuse a prefix's blocks without the state "
+                 "at their end, which is not kept", ec.model)
+    return dataclasses.replace(ec, enable_prefix_caching=False)
+
+
+def _moves_blocks_alone(op: Callable) -> Callable:
+    """An ``EngineCore`` operation that hands a sequence's cache on as
+    blocks (disaggregated transfer in either direction): refused for a
+    model with recurrent layers, whose sequence is not resumable from
+    blocks without its state."""
+    @functools.wraps(op)
+    def guarded(self, *args, **kwargs):
+        if self.model_cfg.has_ssm:
+            raise ValueError(
+                f"{op.__name__} is refused for a model with recurrent "
+                f"layers ({self.engine_cfg.model!r}): its blocks cannot "
+                "resume a sequence without the state at their end; the "
+                "prompt has to be recomputed where the sequence runs")
+        return op(self, *args, **kwargs)
+    return guarded
 
 
 def _named(fn: Callable, name: str) -> Callable:
@@ -196,6 +253,10 @@ class EngineMetrics:
     # shapes a reader of a device trace finds the routed layer's operations
     # by (one expert stack's, the router's, the shared expert's).
     moe: dict | None = None
+    # A model with recurrent layers (models/mamba.py; set once): the state
+    # pool's layers, slots, the two leaves' shapes and dtypes, the bytes of
+    # one sequence's state in one layer, and that prefix matching is off.
+    ssm: dict | None = None
     # The shapes that price a step's counts (set once;
     # obs/costmodel.py step_shapes): per kind of layer the parameters a
     # program reads whatever its rows, one expert's, the head's,
@@ -226,6 +287,7 @@ class EngineMetrics:
             "kv_quant_enabled": self.kv_quant_enabled,
             "kv_cache_shape": list(self.kv_cache_shape),
             **({"moe": self.moe} if self.moe else {}),
+            **({"ssm": self.ssm} if self.ssm else {}),
             **({"step_shapes": self.step_shapes} if self.step_shapes else {}),
             "kv_pool_blocks": self.kv_pool_blocks,
             "kv_block_bytes": self.kv_block_bytes,
@@ -404,6 +466,10 @@ class ModelRunner:
         self.spec = KVCacheSpec.for_model(
             cfg, engine_cfg.num_blocks or 1, engine_cfg.block_size,
             kv_dtype=engine_cfg.kv_dtype)
+        # The second kind of cache, for a model with recurrent layers
+        # (models/mamba.py): a row a slot and a trash row, as the sampling
+        # state has; allocated with the KV pool, whose sizing counts it.
+        self.ssm: dict | None = None
         # What _fit_pool measured (stats()["kv_step_copy_bytes_per_block"]);
         # None while nothing was probed: a given pool, the CPU backend.
         self.step_copy_bytes_per_block: float | None = None
@@ -411,6 +477,8 @@ class ModelRunner:
             self.spec = dataclasses.replace(
                 self.spec, num_blocks=self._auto_num_blocks())
         self.cache_k, self.cache_v = allocate_cache(self.spec, mesh)
+        if cfg.has_ssm:
+            self.ssm = mamba.zeros_state(cfg, maxb)
         # Context-parallel ring prefill gate (ops/ring_attention.py promoted
         # to a serving mode): None = ring off (sp=1 mesh, or the knob set to
         # -1); otherwise the minimum prompt tokens before a fresh
@@ -565,6 +633,13 @@ class ModelRunner:
         ec = self.engine_cfg
         sig = self._widest_bucket()
         t0 = time.perf_counter()
+        if self.cfg.has_ssm:
+            # The state pool is resident beside the blocks, whatever their
+            # number (the probe below hands it in as an argument).
+            state = mamba.state_bytes(self.cfg, ec.max_batch_size)
+            log.info("kv pool sizing: %.2f GB of recurrent state for %d "
+                     "slots", state / 1e9, ec.max_batch_size)
+            budget -= state
         # Probe near where the answer lies: over its whole range the curve
         # is not a line (small pools are assigned differently), close to
         # the answer it is. With one copy a block the budget would hold
@@ -635,13 +710,37 @@ class ModelRunner:
         cache = abstract_cache(
             dataclasses.replace(self.spec, num_blocks=num_blocks), self.mesh)
         fn = self._build_step_fn(sig.b, sig.t, sig.nblk, fast_greedy=True)
+        maxb = self.engine_cfg.max_batch_size
+        ssm = ({"ssm": mamba.state_shapes(self.cfg, maxb)}
+               if self.cfg.has_ssm else {})
         mem = fn.lower(
             self.params, cache, cache, self.counts, self.keys, self.slot_toks,
-            *self._padding_inputs(sig.b, sig.t, sig.nblk),
+            *self._padding_inputs(sig.b, sig.t, sig.nblk), **ssm,
         ).compile().memory_analysis()
         extra = (mem.temp_size_in_bytes + mem.output_size_in_bytes
                  - mem.alias_size_in_bytes)
-        return mem.argument_size_in_bytes + extra, extra
+        # (the state pool is _fit_pool's to count, not the step's own)
+        pool = mamba.state_bytes(self.cfg, maxb) if ssm else 0
+        return mem.argument_size_in_bytes - pool + extra, extra
+
+    def _ssm_kw(self) -> dict:
+        """The state pool as a step program takes it: by keyword, and only
+        where the model has one."""
+        return {"ssm": self.ssm} if self.ssm is not None else {}
+
+    def _run_step(self, fn, inputs) -> tuple:
+        """Call a step program on the device state it carries (K, V, the
+        sampling state and, where the model has one, the recurrent state's
+        pool, all donated) and keep what it hands back in their place;
+        returns the rest of its outputs (tokens, logprobs, counts)."""
+        ssm = self._ssm_kw()
+        (self.cache_k, self.cache_v, self.counts, self.keys,
+         self.slot_toks, *rest) = fn(
+            self.params, self.cache_k, self.cache_v, self.counts,
+            self.keys, self.slot_toks, *inputs, **ssm)
+        if ssm:
+            self.ssm, *rest = rest
+        return tuple(rest)
 
     def _padding_inputs(self, b: int, t: int, nblk: int) -> tuple:
         """The per-step inputs of a (b, t, nblk) step, all padding: q_len=0
@@ -673,9 +772,14 @@ class ModelRunner:
         # (EngineCore cuts steps by pack_rows).
         n_tok = _step_tokens(b, t, sp_prefill)
 
+        has_ssm = cfg.has_ssm
+
         def step(params, ck, cv, counts, keys, slot_toks, tokens, q_start, q_len,
                  bt, slots, temp, top_k, top_p, fp, pp, rp, do_sample, from_slot,
-                 *mm_args):
+                 *mm_args, ssm=None):
+            # (ssm: the recurrent state's pool, by keyword and donated by
+            # name, where the model has one; every other model's program
+            # has the arguments it always had.)
             # Device-fed decode input: rows whose previous token was sampled
             # by an in-flight step read it from slot_toks instead of the host
             # tokens array (which holds 0 for them) — XLA's execution order
@@ -687,6 +791,12 @@ class ModelRunner:
             emb_override = rest.pop(0) if mm else None
             emb_mask = rest.pop(0) if mm else None
             logit_mask = rest.pop(0) if masked else None
+            state = {}
+            if has_ssm:
+                # A row's state is its slot's row of the pool; a padded
+                # row (no live token) reads and writes the trash row.
+                state = {"ssm": ssm,
+                         "ssm_slots": jnp.where(q_len > 0, slots, trash_row)}
             hidden, ck, cv, *moe = llama.forward(
                 params, cfg, tokens, q_start, q_len, bt, ck, cv,
                 attn_impl=attn_impl, moe_impl=moe_impl,
@@ -694,7 +804,9 @@ class ModelRunner:
                 embed_override=emb_override,
                 embed_mask=emb_mask,
                 pp_microbatches=pp_micro,
-                num_tokens=n_tok, moe_counts=moe_impl == "held")
+                num_tokens=n_tok, moe_counts=moe_impl == "held", **state)
+            if has_ssm:
+                ssm, *moe = moe
             with _perf_phase("logits"):
                 logits = llama.logits_from_hidden(
                     params, cfg, hidden).astype(jnp.float32)
@@ -727,12 +839,14 @@ class ModelRunner:
                 slot_toks = slot_toks.at[write_slots].set(toks)
             # (*moe: the routed layers' counts under moe_impl="held", a
             # last output that a program without them does not have.)
-            return ck, cv, counts, keys, slot_toks, toks, lps, *moe
+            return (ck, cv, counts, keys, slot_toks,
+                    *((ssm,) if has_ssm else ()), toks, lps, *moe)
 
         name = self._step_program(b, t, nblk, sp_prefill, fast_greedy, mm,
                                   masked)
+        by_name = {"donate_argnames": ("ssm",)} if has_ssm else {}
         return jax.jit(_named(step, name), donate_argnums=(1, 2, 3, 4, 5),
-                       **self._jit_shardings())
+                       **by_name, **self._jit_shardings())
 
     def _step_program(self, b: int, t: int, nblk: int, sp_prefill: bool,
                       fast_greedy: bool, mm: bool, masked: bool) -> str:
@@ -751,7 +865,9 @@ class ModelRunner:
         if self.mesh is None:
             return {}
         repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
-        return {"out_shardings": (cache, cache, repl, repl, repl, repl, repl)
+        return {"out_shardings": (cache, cache, repl, repl, repl)
+                # (the recurrent state's pool: every device holds it whole)
+                + ((repl,) if self.cfg.has_ssm else ()) + (repl, repl)
                 + ((repl,) if self.moe_impl == "held" else ())}
 
     def step_fn(self, b: int, t: int, nblk: int, sp_prefill: bool = False,
@@ -799,7 +915,8 @@ class ModelRunner:
                 text = self._step_fns[key].lower(
                     self.params, self.cache_k, self.cache_v, self.counts,
                     self.keys, self.slot_toks,
-                    *self._padding_inputs(b, t, nblk), *extra
+                    *self._padding_inputs(b, t, nblk), *extra,
+                    **self._ssm_kw()
                 ).compile().as_text()
             except Exception:
                 log.warning("no phase table for %s", name, exc_info=True)
@@ -912,10 +1029,7 @@ class ModelRunner:
                 t_compile = time.perf_counter()
             with loop_phase(clock, "engine.dispatch.launch"), \
                     self._compile_phase(miss, kind, b, t, nblk):
-                (self.cache_k, self.cache_v, self.counts, self.keys,
-                 self.slot_toks, toks, lps, *moe) = fn(
-                    self.params, self.cache_k, self.cache_v, self.counts,
-                    self.keys, self.slot_toks, *inputs)
+                toks, lps, *moe = self._run_step(fn, inputs)
             if cold:
                 dt = time.perf_counter() - t_compile
                 led.mark_inflight(False)
@@ -1151,16 +1265,20 @@ class ModelRunner:
         nblk = -(-t // ec.block_size) + 1
 
         def embed(params, tokens, q_len):
-            shape = (cfg.num_layers, nblk + 1, ec.block_size,
+            shape = (cfg.attn_layers, nblk + 1, ec.block_size,
                      cfg.num_kv_heads, cfg.head_dim)
             ck = jnp.zeros(shape, jnp.dtype(cfg.dtype))
             cv = jnp.zeros(shape, jnp.dtype(cfg.dtype))
             bt = jnp.tile(jnp.arange(1, nblk + 1, dtype=jnp.int32)[None, :],
                           (tokens.shape[0], 1))
             q_start = jnp.zeros((tokens.shape[0],), jnp.int32)
-            hidden, _, _ = llama.forward(
+            state = {}
+            if cfg.has_ssm:    # transient too: a row a sequence, from zeros
+                state = {"ssm": mamba.zeros_state(cfg, tokens.shape[0]),
+                         "ssm_slots": jnp.arange(tokens.shape[0])}
+            hidden, *_ = llama.forward(
                 params, cfg, tokens, q_start, q_len, bt, ck, cv,
-                attn_impl="dense", mesh=self.mesh)
+                attn_impl="dense", mesh=self.mesh, **state)
             return hidden.astype(jnp.float32)
 
         kw = {}
@@ -1274,10 +1392,7 @@ class ModelRunner:
             if key in self._step_fns:
                 return True
             fn = self.step_fn(b, t, nblk, False, sig.greedy, False, False)
-            (self.cache_k, self.cache_v, self.counts, self.keys,
-             self.slot_toks, toks, *_rest) = fn(
-                self.params, self.cache_k, self.cache_v, self.counts,
-                self.keys, self.slot_toks, *self._padding_inputs(b, t, nblk))
+            toks, *_rest = self._run_step(fn, self._padding_inputs(b, t, nblk))
             np.asarray(toks)
         self._ledger.record(sig, time.perf_counter() - t0, source="warmup")
         return False
@@ -1378,19 +1493,23 @@ class EngineCore:
         if mc.sliding_window and engine_cfg.sp > 1:
             raise ValueError("ring prefill has no window: a model with "
                              f"sliding layers cannot run at sp={engine_cfg.sp}")
-        # Each layer's window and the routed layers' count: what the one
-        # count of a step's work needs (obs/sched_ledger.py step_counts).
-        self._windows = tuple(mc.window_of(i) for i in range(mc.num_layers))
-        self._routed_layers = (mc.num_layers - mc.first_k_dense
-                               if mc.is_moe else 0)
+        if mc.has_ssm:
+            engine_cfg = self.engine_cfg = _recurrent_engine_config(
+                engine_cfg)
+        # Each attention layer's window, the routed and the recurrent
+        # layers' counts: what the one count of a step's work needs
+        # (obs/sched_ledger.py step_counts).
+        self._windows = mc.attn_windows
+        self._routed_layers = mc.routed_layers
+        self._ssm_layers = mc.layers_of("M")
         # Whether a program of n tokens streams its experts: the routed
         # layer's own predicate at this model's expert shape.
         from dynamo_tpu.models.moe import streams_experts
 
-        expert = (mc.hidden_size, mc.moe_intermediate_size,
+        expert = (mc.hidden_size, mc.expert_store_width,
                   jnp.dtype(mc.dtype).itemsize)
         self._streams_experts = lambda n: streams_experts(
-            n, *expert, self.runner.mesh)
+            n, *expert, self.runner.mesh, 3 if mc.expert_gated else 2)
         # SLO-driven chunk sizing (prefill_chunk=0 = auto): resolve to
         # concrete per-QoS chunks BEFORE bucket enumeration and the
         # scheduler read the config — the prefill t ladder, warmup plan
@@ -1492,6 +1611,7 @@ class EngineCore:
             kv_block_bytes=self.runner._block_bytes_per_device(),
             kv_step_copy_bytes_per_block=self.runner.step_copy_bytes_per_block,
             moe=self._moe_facts(),
+            ssm=self._ssm_facts(),
             step_shapes=cm.step_shapes(
                 mc, block_size=engine_cfg.block_size,
                 kv_dtype=engine_cfg.kv_dtype or "bfloat16",
@@ -1911,18 +2031,46 @@ class EngineCore:
             return None
         mc = self.model_cfg
         h, m = mc.hidden_size, mc.moe_intermediate_size
-        sm = m * mc.num_shared_experts
+        sm = mc.shared_expert_width
         return {"experts_held": mc.num_experts,
                 "router_width": mc.router_width,
                 "experts_per_token": mc.num_experts_per_tok,
                 "expert_act": mc.expert_act,
+                # 3: gate, up and down; 2: an expert without a gate. Two
+                # shapes either way: gate and up are of one.
+                "expert_matrices": 3 if mc.expert_gated else 2,
                 "router_input": mc.router_input,
                 "routed_layers": self._routed_layers,
                 "hidden_size": h, "expert_width": m,
                 "bytes_per_param": jnp.dtype(mc.dtype).itemsize,
-                "shapes": [[mc.num_experts, h, m], [mc.num_experts, m, h],
+                # (as stored: ModelConfig.expert_store_width)
+                "shapes": [[mc.num_experts, h, mc.expert_store_width],
+                           [mc.num_experts, mc.expert_store_width, h],
                            [h, mc.router_width]]
                 + ([[h, sm], [sm, h]] if sm else [])}
+
+    def _ssm_facts(self) -> dict | None:
+        """``stats()["ssm"]`` of a model with recurrent layers."""
+        mc = self.model_cfg
+        if not mc.has_ssm:
+            return None
+        slots = self.engine_cfg.max_batch_size
+        pool = mamba.state_shapes(mc, slots)
+        return {"layers": mc.layers_of("M"), "slots": slots,
+                "shapes": {k: list(v.shape) for k, v in pool.items()},
+                "dtypes": {k: str(v.dtype) for k, v in pool.items()},
+                "slot_layer_bytes": mamba.slot_layer_bytes(mc),
+                "pool_bytes": mamba.state_bytes(mc, slots),
+                # per live token of a layer: z, xBC and dt in, y out
+                "token_bytes": (2 * mc.ssm_inner + mc.ssm_conv_dim
+                                + mc.mamba_num_heads)
+                * jnp.dtype(mc.dtype).itemsize,
+                "heads": mc.mamba_num_heads, "head_dim": mc.mamba_head_dim,
+                "state_size": mc.ssm_state_size, "groups": mc.ssm_groups,
+                "conv_kernel": mc.conv_kernel, "conv_dim": mc.ssm_conv_dim,
+                "chunk": mc.ssm_chunk,
+                "prefix_matching": "off: a prefix's blocks are useless "
+                "without the state at their end, which is not kept"}
 
     def step_begin(self) -> "PendingStep | None":
         """Plan one engine step and DISPATCH it to the device without
@@ -2227,7 +2375,10 @@ class EngineCore:
         ``engine.record``) carries it with the device's counts, for a reader
         of a trace to join to the step's programs by ``step``."""
         counts = step_counts(pending.batches, self.engine_cfg.block_size,
-                             self._windows, dec_rows=pending.dec_rows)
+                             self._windows, dec_rows=pending.dec_rows,
+                             ssm_layers=self._ssm_layers)
+        ssm = (tuple(counts[k] for k in SSM_COUNTS) if self._ssm_layers
+               else None)
         pc = self.sched.preemption_count
         wall = time.perf_counter() - t0
         perf = self.perf.measure(counts, wall, moe)
@@ -2244,7 +2395,8 @@ class EngineCore:
         # (one static call, ~60 ns: is a profiler session recording?)
         if span is not None and jax.profiler.TraceAnnotation.is_enabled():
             span.set(**{k: counts[k] for k in _RECORD_SPAN_COUNTS},
-                     **(dict(zip(_RECORD_SPAN_MOE, moe)) if moe else {}))
+                     **(dict(zip(_RECORD_SPAN_MOE, moe)) if moe else {}),
+                     **(dict(zip(SSM_COUNTS, ssm)) if ssm else {}))
             self.traced_programs.update(pending.programs)
         if self.sched_led.enabled:
             info = pending.sched or {}
@@ -2252,7 +2404,7 @@ class EngineCore:
                 wall_s=wall,
                 budget_util=info.get("budget_util", 0.0),
                 queue_depths=self.sched.waiting.depths(),
-                hol=info.get("hol"), moe=moe and tuple(moe),
+                hol=info.get("hol"), moe=moe and tuple(moe), ssm=ssm,
                 **step_geometry(self.model_cfg, self.engine_cfg,
                                 pending.batches, counts=counts, moe=moe,
                                 shapes=self.metrics.step_shapes,
@@ -2665,6 +2817,7 @@ class EngineCore:
                 self._transfer = BlockTransferEngine()
         return self._transfer
 
+    @_moves_blocks_alone
     def export_blocks(self, seq_hashes: list[int]) -> list[tuple[int, int | None, np.ndarray]]:
         """Gather the device-resident prefix of a hash chain off the device.
         The prefill side of disaggregated serving (reference: the NIXL
@@ -2683,6 +2836,7 @@ class EngineCore:
         blocks = self.transfer.extract(self.runner.cache_k, self.runner.cache_v, ids)
         return [(h, par, data) for (h, par), data in zip(kept, blocks)]
 
+    @_moves_blocks_alone
     def import_blocks(self, plan: list[tuple[int, int | None, np.ndarray]],
                       span_attrs: dict | None = None) -> int:
         """Inject externally-received blocks as matchable cache entries —
@@ -2744,6 +2898,7 @@ class EngineCore:
 
         return vote_min(n)
 
+    @_moves_blocks_alone
     def stage_export(self, xfer_id: str, seq_hashes: list[int]) -> int:
         """Pin the device-resident prefix of a chain and stage this rank's
         cache shard of it to host memory; returns hashes covered. The pin
@@ -2813,6 +2968,7 @@ class EngineCore:
             self._stream_exports: dict[str, _StreamExport] = {}
             self._streams_by_xid: dict[str, _StreamExport] = {}
 
+    @_moves_blocks_alone
     def stream_begin(self, xfer_id: str, request_id: str,
                      seq_hashes: list[int]) -> int:
         """Open a streamed export for ``request_id``'s chain. No device
@@ -2979,6 +3135,7 @@ class EngineCore:
         return self._pulls.setdefault(
             xfer_id, {"clients": {}, "waves": {}, "last": None})
 
+    @_moves_blocks_alone
     def prefetch_remote(self, params: dict, start: int | None = None,
                         stop: int | None = None, tail: bool = False) -> None:
         """Start the pull's network half on a background thread so engine
@@ -3013,6 +3170,7 @@ class EngineCore:
         state["last"] = slot
         t.start()
 
+    @_moves_blocks_alone
     def import_remote(self, params: dict, start: int | None = None,
                       stop: int | None = None, final: bool = True) -> int:
         """Join the prefetch (or fetch inline), vote, and inject one

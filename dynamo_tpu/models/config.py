@@ -58,17 +58,46 @@ class ModelConfig:
     # ``sliding_window_pattern`` "LLLG" is 4); 0 = the shortest that fits.
     pattern_len: int = 0
     qk_norm: bool = False        # RMSNorm over each head of q and k
-    rope_scope: str = "all"      # "sliding": full-attention layers carry no position
+    # "sliding": full-attention layers carry no position; "none": no layer does
+    rope_scope: str = "all"
     # "pre": h + f(norm(h)) (Llama). "post": h + norm(f(h)), the sub-layer's
     # input not normalised (EXAONE 4.0's block); the same two leaves.
     norm_placement: str = "pre"
-    # A routed expert's gate activation: "silu" (SwiGLU) | "relu" (ReGLU).
+    # A routed expert's gate activation: "silu" (SwiGLU) | "relu" (ReGLU);
+    # "relu2" (squared ReLU) is the activation of an expert without a gate.
     expert_act: str = "silu"
+    # False: an expert (and the shared expert) is two matrices and no gate,
+    # ``down(act(up(x)))``: no ``w_gate`` / ``shared_gate`` leaf.
+    expert_gated: bool = True
+    # The shared expert's width where it is not ``num_shared_experts`` x
+    # ``moe_intermediate_size`` (0 = it is).
+    shared_expert_intermediate_size: int = 0
     # What the router reads: "mlp_norm", the expert layer's own input, or
     # "attn_norm", the state that enters attention (the attention norm's
     # output under "pre"): the choice is made before attention and carried
     # past it (SmallThinker's "router placed before attention").
     router_input: str = "mlp_norm"
+    # A model whose layers are one mixer each (``h + mixer(norm(h))``), one
+    # character a layer: "M" a Mamba-2 layer, "*" attention, "E" routed
+    # experts (NemotronH's ``hybrid_override_pattern``). "" = every layer is
+    # attention then an FFN. ``num_layers`` counts these layers; the KV cache
+    # has the "*" ones alone and the state pool the "M" ones
+    # (models/mamba.py).
+    hybrid_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1          # B and C are shared by the heads of a group
+    conv_kernel: int = 4
+    ssm_chunk: int = 128         # block of the blocked scan in a chunk step
+    # The seeded init's range of dt (log-uniform, floored), as the
+    # published initialisation draws it.
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # The stored recurrent state's type ("float32" alone is served); the
+    # convolution tail is ``dtype``.
+    ssm_state_dtype: str = "float32"
     # Multimodal (vision encoder attached)
     vision: "VisionConfig | None" = None
 
@@ -87,9 +116,25 @@ class ModelConfig:
         if self.first_k_dense and not self.is_moe:
             raise ValueError("first_k_dense leading dense layers in a model "
                              "with no routed layer: leave it 0")
-        if self.expert_act not in ("silu", "relu"):
+        if self.expert_act not in ("silu", "relu", "relu2"):
             raise ValueError(f"expert_act {self.expert_act!r}: the routed "
-                             "experts gate by 'silu' or 'relu'")
+                             "experts act by 'silu', 'relu' or 'relu2'")
+        if self.hybrid_pattern:
+            odd = set(self.hybrid_pattern) - set("M*E")
+            if odd or len(self.hybrid_pattern) != self.num_layers:
+                raise ValueError(
+                    f"hybrid_pattern {self.hybrid_pattern!r}: one of 'M', "
+                    f"'*', 'E' for each of {self.num_layers} layers")
+            if "M" in self.hybrid_pattern and (
+                    self.ssm_state_dtype != "float32"
+                    or (self.mamba_num_heads % max(self.ssm_groups, 1))):
+                raise ValueError(
+                    "the recurrent state is stored as float32 (a bfloat16 "
+                    "or quantized state is not implemented) and the Mamba "
+                    "heads divide into ssm_groups equal groups")
+            if self.first_k_dense or self.layer_types:
+                raise ValueError("a hybrid_pattern gives every layer's kind: "
+                                 "no first_k_dense, no layer_types")
         if self.router_input not in ("mlp_norm", "attn_norm"):
             raise ValueError(f"router_input {self.router_input!r}: the "
                              "router reads 'mlp_norm' or 'attn_norm'")
@@ -107,6 +152,82 @@ class ModelConfig:
     def holds_share(self) -> bool:
         """Whether the expert layer holds fewer experts than it routes over."""
         return 0 < self.num_experts < self.router_width
+
+    @property
+    def has_ssm(self) -> bool:
+        """Whether some layer carries recurrent state (models/mamba.py)."""
+        return "M" in self.hybrid_pattern
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers of a hybrid pattern are ``kind`` ("M", "*", "E")."""
+        return self.hybrid_pattern.count(kind)
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers with attention: the KV cache's layers."""
+        return (self.layers_of("*") if self.hybrid_pattern
+                else self.num_layers)
+
+    @property
+    def attn_windows(self) -> tuple[int, ...]:
+        """The window of each layer that has attention (0: full)."""
+        if self.hybrid_pattern:
+            return (0,) * self.layers_of("*")
+        return tuple(self.window_of(i) for i in range(self.num_layers))
+
+    @property
+    def routed_layers(self) -> int:
+        if self.hybrid_pattern:
+            return self.layers_of("E")
+        return self.num_layers - self.first_k_dense if self.is_moe else 0
+
+    @property
+    def shared_expert_width(self) -> int:
+        return (self.shared_expert_intermediate_size
+                or self.moe_intermediate_size * self.num_shared_experts)
+
+    @property
+    def expert_store_width(self) -> int:
+        """The width an expert's matrices are stored at: the model's
+        (``moe_intermediate_size``) or, where that is wider than a 128-lane
+        tile and no multiple of it (1,856), the next multiple, the extra
+        columns of ``w_up`` / ``w_gate`` and rows of ``w_down`` zeros, which
+        add nothing to any result. A TPU keeps a matrix whose last dimension
+        does not fill its tiles in another order than the grouped matmul
+        takes, and the program would then copy the whole stack in every
+        step (PERF.md section 6, PR 45)."""
+        m = self.moe_intermediate_size
+        return -(-m // 128) * 128 if m > 128 else m
+
+    @property
+    def ssm_inner(self) -> int:
+        """d: the Mamba mixer's inner width, heads x head size."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """c: the channels the convolution runs over, x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    @property
+    def hybrid_groups(self) -> tuple[int, int, int]:
+        """(leading layers, period, whole periods) of a hybrid pattern: the
+        split that traces the fewest layer bodies, the leading group and
+        what is left behind the last whole period one by one, one period as
+        the body of a scan (at least two trips, else nothing is scanned)."""
+        pat, n = self.hybrid_pattern, self.num_layers
+        best = (n, 0, n, 1, 0)          # (bodies, rest, lead, period, whole)
+        for lead in range(n):
+            for p in range(1, (n - lead) // 2 + 1):
+                whole = (n - lead) // p
+                while whole >= 2 and any(
+                        pat[lead + i] != pat[lead + i % p]
+                        for i in range(whole * p)):
+                    whole -= 1
+                if whole >= 2:
+                    rest = n - lead - whole * p
+                    best = min(best, (lead + p + rest, rest, lead, p, whole))
+        return best[2:]
 
     def window_of(self, layer: int) -> int:
         """Layer ``layer``'s attention window, 0 = full."""
@@ -141,7 +262,7 @@ class ModelConfig:
     def from_hf_config(cls, path: str) -> "ModelConfig":
         """Read a local HF config.json (llama-family keys)."""
         cfg = json.loads((Path(path) / "config.json").read_text())
-        cfg = _smallthinker_keys(cfg)
+        cfg = _nemotron_h_keys(_smallthinker_keys(cfg))
         n_heads = cfg["num_attention_heads"]
         # MoE keys across HF families: mixtral (num_local_experts),
         # deepseek/qwen-moe (n_routed_experts, num_experts).
@@ -193,7 +314,21 @@ class ModelConfig:
             rope_scope=cfg.get("rope_scope", "all"),
             norm_placement=cfg.get("norm_placement", "pre"),
             expert_act=cfg.get("expert_act", "silu"),
+            expert_gated=bool(cfg.get("expert_gated", True)),
+            shared_expert_intermediate_size=int(
+                cfg.get("moe_shared_expert_intermediate_size") or 0),
             router_input=cfg.get("router_input", "mlp_norm"),
+            hybrid_pattern=cfg.get("hybrid_override_pattern", ""),
+            mamba_num_heads=cfg.get("mamba_num_heads", 0),
+            mamba_head_dim=cfg.get("mamba_head_dim", 0),
+            ssm_state_size=cfg.get("ssm_state_size", 0),
+            ssm_groups=cfg.get("n_groups", 1),
+            conv_kernel=cfg.get("conv_kernel", 4),
+            ssm_chunk=cfg.get("chunk_size", 128),
+            time_step_min=cfg.get("time_step_min", 0.001),
+            time_step_max=cfg.get("time_step_max", 0.1),
+            time_step_floor=cfg.get("time_step_floor", 1e-4),
+            ssm_state_dtype=cfg.get("ssm_state_dtype", "float32"),
             name=cfg.get("_name_or_path", Path(path).name),
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
@@ -263,6 +398,47 @@ def _smallthinker_keys(cfg: dict) -> dict:
                         for s in sliding],
         "sliding_window": cfg.get("sliding_window_size", 0),
         "rope_scope": scope,
+    }
+
+
+def _nemotron_h_keys(cfg: dict) -> dict:
+    """``cfg`` with NemotronH's keys (``model_type: "nemotron_h"``: layers of
+    one mixer each by ``hybrid_override_pattern``, Mamba-2, attention without
+    positions, sigmoid-routed experts of two matrices and a squared ReLU)
+    under the names ``from_hf_config`` reads; any other config comes back as
+    it is. What cannot be served is refused by its key."""
+    if cfg.get("model_type") != "nemotron_h":
+        return cfg
+    for key in ("mamba_proj_bias", "use_bias", "attention_bias", "mlp_bias"):
+        if cfg.get(key):
+            raise ValueError(
+                f"{key}: true is not implemented: models/mamba.py and "
+                "models/llama.py project without bias")
+    pattern = cfg["hybrid_override_pattern"][:cfg["num_hidden_layers"]]
+    if "-" in pattern:
+        raise ValueError(
+            "hybrid_override_pattern has a '-' layer (a dense FFN as a "
+            "layer of its own): not implemented, models/llama.py runs "
+            "'M', '*' and 'E' layers")
+    act = cfg.get("mlp_hidden_act", "relu2")
+    if act != "relu2" or cfg.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"mlp_hidden_act {act!r} / mamba_hidden_act "
+            f"{cfg.get('mamba_hidden_act')!r}: this family is served with "
+            "relu2 experts without a gate and a silu Mamba gate")
+    if cfg.get("rope_scope", "none") != "none":
+        raise ValueError("rope_scope: this family's attention carries no "
+                         "position (the published block applies none)")
+    return {
+        **cfg,
+        "hybrid_override_pattern": pattern,
+        "scoring_func": "sigmoid",       # the family's router, no key for it
+        "router_bias": True,             # e_score_correction_bias
+        "expert_act": "relu2",
+        "expert_gated": False,
+        "rope_scope": "none",
+        "rms_norm_eps": cfg.get("layer_norm_epsilon",
+                                cfg.get("norm_eps", 1e-5)),
     }
 
 

@@ -111,9 +111,30 @@ def _layer_axes(cfg: ModelConfig, routed: bool) -> Params:
     return layer
 
 
+def _hybrid_axes(cfg: ModelConfig) -> Params:
+    """A hybrid pattern's leaves, a stack a kind of layer: attention's four
+    matrices and norm, the routed layer's (two matrices an expert where it
+    has no gate), the Mamba mixer's (models/mamba.py)."""
+    from dynamo_tpu.models import mamba
+
+    routed = _layer_axes(cfg, True)
+    layer = {k: routed.pop(k) for k in ("wq", "wk", "wv", "wo", "attn_norm")}
+    if not cfg.layers_of("*"):
+        layer = {}
+    if cfg.layers_of("E"):
+        if not cfg.expert_gated:
+            routed.pop("w_gate")
+            routed.pop("shared_gate", None)
+        layer.update(routed)
+    if cfg.has_ssm:
+        layer.update(mamba.logical_axes())
+    return layer
+
+
 def param_logical_axes(cfg: ModelConfig) -> Params:
     """Logical axis names per parameter leaf (for mesh sharding rules)."""
-    layer = _layer_axes(cfg, cfg.is_moe)
+    layer = (_hybrid_axes(cfg) if cfg.hybrid_pattern
+             else _layer_axes(cfg, cfg.is_moe))
     if cfg.first_k_dense:
         layer.update({LEAD + k: v
                       for k, v in _layer_axes(cfg, False).items()})
@@ -144,13 +165,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     h = cfg.hidden_size
     L = cfg.num_layers - cfg.first_k_dense     # the repeated group
 
-    def whole(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
+    def whole(key, shape, fan_in, pad=None):
+        w = (jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
+        if pad:     # (axis from the end, stored size): zeros behind the draw
+            axis, size = pad
+            widths = [(0, 0)] * len(shape)
+            widths[axis] = (0, size - shape[axis])
+            w = jnp.pad(w, widths)
+        return w
 
-    def dense(key, shape, fan_in):
+    def dense(key, shape, fan_in, pad=None):
         if math.prod(shape) <= INIT_WHOLE_MAX:
-            return whole(key, shape, fan_in)
-        return lax.map(lambda k1: whole(k1, shape[1:], fan_in),
+            return whole(key, shape, fan_in, pad)
+        return lax.map(lambda k1: whole(k1, shape[1:], fan_in, pad),
                        jax.random.split(key, shape[0]))
 
     def attention(L):
@@ -173,6 +200,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 "w_up": dense(next(k), (L, h, i), h),
                 "w_down": dense(next(k), (L, i, h), i)}
 
+    if cfg.hybrid_pattern:
+        return _init_hybrid(cfg, k, dense, attention)
     layer: Params = attention(L)
     if cfg.is_moe:
         E, m = cfg.num_experts, cfg.moe_intermediate_size
@@ -209,6 +238,53 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if cfg.first_k_dense:
         lead = {**attention(cfg.first_k_dense), **dense_ffn(cfg.first_k_dense)}
         layer.update({LEAD + name: v for name, v in lead.items()})
+    return params
+
+
+def _init_hybrid(cfg: ModelConfig, k, dense, attention) -> Params:
+    """:func:`init_params` for a hybrid pattern: a stack a kind of layer,
+    each as long as the pattern has layers of that kind (``k``: the keys'
+    iterator, ``dense`` and ``attention`` the draws of :func:`init_params`).
+    A layer is one mixer under one norm, so attention's stack has no
+    ``mlp_norm`` and the routed one no attention."""
+    from dynamo_tpu.models import mamba
+
+    dt = _dtype(cfg)
+    h = cfg.hidden_size
+    layer: Params = {}
+    if A := cfg.layers_of("*"):
+        layer.update(attention(A))
+        del layer["mlp_norm"]
+    if R := cfg.layers_of("E"):
+        E, m, sm = (cfg.num_experts, cfg.moe_intermediate_size,
+                    cfg.shared_expert_width)
+        # Stored at cfg.expert_store_width: zeros behind the model's width.
+        stored = cfg.expert_store_width
+        cols = (-1, stored) if stored != m else None
+        rows = (-2, stored) if stored != m else None
+        layer["mlp_norm"] = jnp.ones((R, h), dt)
+        layer["router"] = dense(next(k), (R, h, cfg.router_width), h)
+        if cfg.expert_gated:
+            layer["w_gate"] = dense(next(k), (R, E, h, m), h, cols)
+        layer["w_up"] = dense(next(k), (R, E, h, m), h, cols)
+        layer["w_down"] = dense(next(k), (R, E, m, h), m, rows)
+        if cfg.num_shared_experts:
+            if cfg.expert_gated:
+                layer["shared_gate"] = dense(next(k), (R, h, sm), h)
+            layer["shared_up"] = dense(next(k), (R, h, sm), h)
+            layer["shared_down"] = dense(next(k), (R, sm, h), sm)
+        if cfg.router_bias:
+            layer["router_bias"] = 0.02 * jax.random.normal(
+                next(k), (R, cfg.router_width), jnp.float32)
+    if M := cfg.layers_of("M"):
+        layer.update(mamba.init_layers(cfg, dense, next(k), M))
+    params: Params = {
+        "embed": dense(next(k), (cfg.vocab_size, h), h),
+        "final_norm": jnp.ones((h,), dt),
+        "layers": layer,
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = dense(next(k), (h, cfg.vocab_size), h)
     return params
 
 
@@ -429,23 +505,29 @@ def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
 
     x: [..., H] (token-major [N, H] in the step)
     """
-    from dynamo_tpu.models.moe import gate_act, route
+    from dynamo_tpu.models.moe import gate_act, route, ungated_ffn
 
     h = x.shape[-1]
     xt = x.reshape(-1, h)                                     # [N, H]
     topi, weights = routing or route(xt, lp, cfg)             # [N, k]
-    e = lp["w_gate"].shape[0]                                 # experts held
+    e = lp["w_up"].shape[0]                                   # experts held
     gate_mask = jnp.zeros((xt.shape[0], cfg.router_width), jnp.float32)
     gate_mask = gate_mask.at[jnp.arange(xt.shape[0])[:, None], topi].add(
         weights)[:, :e]                                       # [N, E]
     # all-experts compute: [N,E,m]
     up = jnp.einsum("nh,ehm->nem", xt, lp["w_up"])
-    gate = jnp.einsum("nh,ehm->nem", xt, lp["w_gate"])
-    act = gate_act(cfg)(gate) * up
+    if cfg.expert_gated:
+        gate = jnp.einsum("nh,ehm->nem", xt, lp["w_gate"])
+        act = gate_act(cfg)(gate) * up
+    else:
+        act = gate_act(cfg)(up)
     per_expert = jnp.einsum("nem,emh->neh", act, lp["w_down"])
     out = jnp.einsum("neh,ne->nh", per_expert.astype(jnp.float32), gate_mask).astype(x.dtype)
-    if cfg.num_shared_experts:
+    if cfg.num_shared_experts and cfg.expert_gated:
         out = out + swiglu(xt, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    elif cfg.num_shared_experts:
+        out = out + ungated_ffn(xt, lp["shared_up"], lp["shared_down"],
+                                gate_act(cfg))
     return out.reshape(x.shape)
 
 
@@ -543,8 +625,6 @@ def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     ``counts`` is the routed layer's int32 [3] under ``moe_impl="held"``
     (models/moe.py ``held_rows``), else None; ``live`` [N] names the
     bucket's live tokens for it."""
-    n = hid.shape[0]
-    tp = mesh.shape.get("model", 1) if mesh is not None else 1
     post = cfg.norm_placement == "post"
     x = hid if post else rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
     routing = None
@@ -555,6 +635,32 @@ def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
 
         with _perf_phase("moe_route"):
             routing = route(x, lp, cfg)
+    attn, cache_k, cache_v = _attention(
+        cfg, lp, layer, x, cache_k, cache_v, lay=lay, positions=positions,
+        slot=slot, block_tables=block_tables, q_start=q_start,
+        kv_lens=kv_lens, attn_impl=attn_impl, mesh=mesh, use_ring=use_ring,
+        window=window)
+    if post:
+        attn = rms_norm(attn, lp["attn_norm"], cfg.rms_norm_eps)
+    hid = hid + attn
+    x = hid if post else rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
+    mlp_out, counts = _ffn(cfg, lp, x, routing, moe_impl, mesh, live)
+    if post:
+        mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.rms_norm_eps)
+    return hid + mlp_out, cache_k, cache_v, counts
+
+
+def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
+               lay: TokenLayout, positions, slot, block_tables, q_start,
+               kv_lens, attn_impl: str = "dense", mesh=None,
+               use_ring: bool = False, window: int = 0):
+    """The attention mixer on the normed state ``x [N, H]``: Q/K/V, this
+    step's K/V written at ``(layer, slot)`` of the whole cache, attention
+    over layer ``layer`` of it, ``wo``. ``layer`` is the layer's place in
+    the cache, which has the attention layers alone. Returns
+    (out [N, H], cache_k, cache_v)."""
+    n = x.shape[0]
+    tp = mesh.shape.get("model", 1) if mesh is not None else 1
     # The three products stay [N, out] up to the barrier and get their head
     # axis after it. A reshape XLA can fold into the dot makes the weight
     # operand [heads, D, H], the stored matrix transposed, and the compiler
@@ -569,7 +675,7 @@ def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    if cfg.rope_scope == "all" or window:
+    if cfg.rope_scope == "all" or (window and cfg.rope_scope == "sliding"):
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     # Phase hooks (obs/profiler.py): jax.named_scope annotations for
@@ -625,10 +731,14 @@ def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     attn = lay.to_tokens(attn).reshape(n, cfg.q_size)
     with _perf_phase("proj"):
         attn = mm(attn, lp["wo"])
-    if post:
-        attn = rms_norm(attn, lp["attn_norm"], cfg.rms_norm_eps)
-    hid = hid + attn
-    x = hid if post else rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
+    return attn, cache_k, cache_v
+
+
+def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
+         live):
+    """The FFN on the normed state ``x [N, H]``: the routed experts in the
+    formulation ``moe_impl`` names where ``lp`` has a ``router``, else the
+    dense SwiGLU. Returns (out [N, H], the routed layer's counts or None)."""
     counts = None
     if "router" in lp:
         if moe_impl == "held":
@@ -652,9 +762,91 @@ def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     else:
         with _perf_phase("mlp"):
             mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-    if post:
-        mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.rms_norm_eps)
-    return hid + mlp_out, cache_k, cache_v, counts
+    return mlp_out, counts
+
+
+def _run_hybrid(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, ssm,
+                *, ssm_slots, ssm_live, q_len, live=None, **kw):
+    """:func:`_run_layers` for a hybrid pattern (``cfg.hybrid_pattern``):
+    each layer is one mixer, ``h + mixer(norm(h))``, of the kind its
+    character names. The leading group and what is left behind the last
+    whole period are traced one by one, one period is the body of a
+    ``lax.scan`` (``cfg.hybrid_groups``); a layer's kind is static structure
+    of the body. Each kind has its own stack under ``layers`` and its own
+    carried buffer: attention layer ``i`` of the model's attention layers
+    is layer ``i`` of the KV cache, Mamba layer ``i`` row ``i`` of the state
+    pool ``ssm`` (models/mamba.py), routed layer ``i`` place ``i`` of the
+    experts' stack, which is never cut by layer. The scan carries the
+    period's index alone and every layer reads its matrices from its kind's
+    stack at its own place. Returns (hidden, cache_k, cache_v, ssm,
+    counts)."""
+    from dynamo_tpu.models import mamba
+
+    pat = cfg.hybrid_pattern
+    lead, p, whole = cfg.hybrid_groups
+    moe_impl, mesh = kw.pop("moe_impl", "dense"), kw.get("mesh")
+    attn_leaves = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
+    stacks = {
+        "M": {k: v for k, v in layers.items() if k in mamba.LEAVES},
+        "*": {k: v for k, v in layers.items() if k in attn_leaves},
+        "E": {k: v for k, v in layers.items()
+              if k not in mamba.LEAVES and k not in attn_leaves},
+    }
+    counted = moe_impl == "held" and "E" in pat
+    experts = {}
+    carry = (h, cache_k, cache_v, ssm)
+    if counted:
+        carry += (jnp.zeros((3,), jnp.int32),)
+        experts = {k: stacks["E"].pop(k) for k in ("w_gate", "w_up", "w_down")
+                   if k in stacks["E"]}
+
+    def one(carry, kind, i):
+        """Layer ``i`` of the layers of ``kind``."""
+        hid, ck, cv, state, *counts = carry
+        lp = jax.tree.map(lambda a: a[i], stacks[kind])
+        with _perf_phase("layer"):
+            if kind == "M":
+                x = rms_norm(hid, lp["ssm_norm"], cfg.rms_norm_eps)
+                out, state = mamba.mixer(
+                    cfg, lp, i, x, state, lay=kw["lay"], slots=ssm_slots,
+                    q_start=kw["q_start"], q_len=q_len, live=ssm_live,
+                    impl={"dense": "jnp"}.get(kw["attn_impl"],
+                                              kw["attn_impl"]))
+            elif kind == "*":
+                x = rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
+                out, ck, cv = _attention(cfg, lp, i, x, ck, cv, **kw)
+            else:
+                if experts:
+                    lp = {**lp, **experts, "expert_layer": i}
+                x = rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
+                out, c = _ffn(cfg, lp, x, None, moe_impl, mesh, live)
+                if c is not None:
+                    counts = [counts[0] + c]
+            return (hid + out, ck, cv, state, *counts)
+
+    seen = {"M": 0, "*": 0, "E": 0}
+    for kind in pat[:lead]:
+        carry = one(carry, kind, seen[kind])
+        seen[kind] += 1
+    if whole:
+        period = pat[lead:lead + p]
+        base = dict(seen)
+        per = {k: period.count(k) for k in seen}
+
+        def period_fn(carry, i):
+            off = {k: 0 for k in per}
+            for kind in period:
+                carry = one(carry, kind, base[kind] + i * per[kind] + off[kind])
+                off[kind] += 1
+            return carry, None
+
+        carry, _ = lax.scan(period_fn, carry,
+                            jnp.arange(whole, dtype=jnp.int32))
+        seen = {k: base[k] + whole * per[k] for k in per}
+    for kind in pat[lead + whole * p:]:
+        carry = one(carry, kind, seen[kind])
+        seen[kind] += 1
+    return (*carry, None) if not counted else carry
 
 
 def _run_layers(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, **kw):
@@ -766,10 +958,15 @@ def forward(
     pp_microbatches: int = 0,                 # pp>1: schedule depth (0 = auto)
     num_tokens: int | None = None,            # token bucket N (None = B*T)
     moe_counts: bool = False,                 # also return the routed layers' counts
+    ssm=None,                                 # the state pool (models/mamba.py)
+    ssm_slots: jax.Array | None = None,       # [B] each row's row of it
 ) -> tuple[jax.Array, ...]:
     """One engine step. Returns (last_hidden [B,H], cache_k, cache_v) —
     or (hidden [B,T,H], ...) with ``return_all_hidden`` (the speculative
-    verify step needs logits at every chunk position). With ``moe_counts``
+    verify step needs logits at every chunk position). A model with
+    recurrent layers (``cfg.has_ssm``) takes the state pool ``ssm`` and
+    each row's place in it (a padded row: the trash row) and returns the
+    pool behind ``cache_v``. With ``moe_counts``
     (``moe_impl="held"`` only) a fourth: int32 [3], over the step's routed
     layers the (token, choice) rows computed here, the experts that had
     rows, and the rows of each layer's largest group, summed.
@@ -844,18 +1041,26 @@ def forward(
                           lay.to_tokens(embed_override).astype(h.dtype), h)
 
     held = {"live": valid} if moe_impl == "held" else {}
-    h, cache_k, cache_v, counts = _run_layers(
-        cfg, params["layers"], h, cache_k, cache_v, lay=lay,
-        positions=positions, slot=slot, block_tables=block_tables,
-        q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
-        moe_impl=moe_impl, mesh=mesh,
-        use_ring=use_ring, **held)
+    if cfg.hybrid_pattern:
+        h, cache_k, cache_v, ssm, counts = _run_hybrid(
+            cfg, params["layers"], h, cache_k, cache_v, ssm,
+            ssm_slots=ssm_slots, ssm_live=valid, q_len=q_len, lay=lay,
+            positions=positions, slot=slot, block_tables=block_tables,
+            q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
+            moe_impl=moe_impl, mesh=mesh, use_ring=use_ring, **held)
+    else:
+        h, cache_k, cache_v, counts = _run_layers(
+            cfg, params["layers"], h, cache_k, cache_v, lay=lay,
+            positions=positions, slot=slot, block_tables=block_tables,
+            q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
+            moe_impl=moe_impl, mesh=mesh,
+            use_ring=use_ring, **held)
     # The head's own preparation: the final norm and each row's last token.
     with _perf_phase("logits"):
         h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
         last = (lay.to_rows(h) if return_all_hidden                # [B, T, H]
                 else _last_hidden(h, lay, q_len))
-    out = last, cache_k, cache_v
+    out = (last, cache_k, cache_v) + ((ssm,) if cfg.has_ssm else ())
     return (*out, counts) if moe_counts else out
 
 

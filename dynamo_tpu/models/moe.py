@@ -42,9 +42,20 @@ Params = dict
 
 
 def gate_act(cfg: ModelConfig):
-    """The routed experts' gate activation, ``cfg.expert_act``: every
-    formulation computes ``act(x W_gate) * (x W_up)`` with this one."""
-    return {"silu": jax.nn.silu, "relu": jax.nn.relu}[cfg.expert_act]
+    """The routed experts' activation, ``cfg.expert_act``: every
+    formulation computes ``act(x W_gate) * (x W_up)`` with this one, or,
+    for an expert without a gate (``cfg.expert_gated`` false: two matrices),
+    ``act(x W_up)``."""
+    return {"silu": jax.nn.silu, "relu": jax.nn.relu,
+            "relu2": lambda x: jnp.square(jax.nn.relu(x))}[cfg.expert_act]
+
+
+def ungated_ffn(x, w_up, w_down, act):
+    """One dense expert without a gate (a shared one): ``down(act(up(x)))``;
+    the gated one is ``llama.swiglu``."""
+    from dynamo_tpu.models.llama import mm
+
+    return mm(act(mm(x, w_up)), w_down)
 
 
 def route(xt: jax.Array, lp: Params, cfg: ModelConfig):
@@ -93,12 +104,15 @@ STREAM_MAX_ROWS = 64
 
 
 def streams_experts(n: int, h: int, m: int, itemsize: int,
-                    mesh=None) -> bool:
+                    mesh=None, matrices: int = 3) -> bool:
     """Whether a program of ``n`` tokens computes its held experts
-    (``[h, m]``, ``[h, m]`` and ``[m, h]`` of ``itemsize`` bytes each) by
+    (``[h, m]``, ``[h, m]`` and ``[m, h]`` of ``itemsize`` bytes each, or,
+    with ``matrices`` 2, an expert without a gate's ``[h, m]`` and
+    ``[m, h]``) by
     the streaming kernel and not by groups: the program is decode-sized
-    (``STREAM_MAX_ROWS``), one expert's three matrices fit the kernel's
-    VMEM twice (the pipeline holds the next beside the current), and the
+    (``STREAM_MAX_ROWS``), one expert's matrices fit twice within the most
+    VMEM the kernel asks for (``moe_stream.VMEM_MAX_BYTES``; the pipeline
+    holds the next expert beside the current), and the
     backend is a TPU (the kernel is Mosaic's; on the CPU the tests run it
     interpreted and every engine keeps the grouped form) on which the
     program is one chip's: under a ``mesh`` of several the compiler
@@ -110,8 +124,8 @@ def streams_experts(n: int, h: int, m: int, itemsize: int,
     from dynamo_tpu.ops import moe_stream
 
     return (n <= STREAM_MAX_ROWS
-            and moe_stream.vmem_bytes(n, h, m, itemsize)
-            <= moe_stream.VMEM_LIMIT_BYTES
+            and moe_stream.vmem_bytes(n, h, m, itemsize, matrices)
+            <= moe_stream.VMEM_MAX_BYTES
             and jax.default_backend() == "tpu"
             and (mesh is None or mesh.size == 1))
 
@@ -146,20 +160,22 @@ def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
     int32 [3]: rows computed, experts touched, rows of the largest group)."""
     n, h = xt.shape
     k = topi.shape[1]
-    if streams_experts(n, h, w_gate.shape[-1], w_gate.dtype.itemsize, mesh):
+    if streams_experts(n, h, w_up.shape[-1], w_up.dtype.itemsize, mesh,
+                       2 if w_gate is None else 3):
         from dynamo_tpu.ops.moe_stream import stream_rows
 
         return stream_rows(xt, topi, weights, w_gate, w_up, w_down, live,
                            layer, act)
     first = 0
     if layer is not None:
-        n_layers, held = w_gate.shape[:2]
-        w_gate, w_up, w_down = (w.reshape(n_layers * held, *w.shape[2:])
-                                for w in (w_gate, w_up, w_down))
+        n_layers, held = w_up.shape[:2]
+        w_gate, w_up, w_down = (
+            w if w is None else w.reshape(n_layers * held, *w.shape[2:])
+            for w in (w_gate, w_up, w_down))
         first = layer * held
     else:
-        held = w_gate.shape[0]
-    groups = w_gate.shape[0]
+        held = w_up.shape[0]
+    groups = w_up.shape[0]
     flat_e = topi.reshape(-1)                         # [Nk] token-major
     flat_t = jnp.repeat(jnp.arange(n), k)             # [Nk]
     here = (flat_e >= 0) & (flat_e < held)
@@ -172,9 +188,13 @@ def held_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
     group_sizes = jnp.zeros((groups,), jnp.int32).at[
         jnp.where(here, first + flat_e, groups)].add(1, mode="drop")
     xs = xt[rows]                                     # [Nk, H]
-    gate = lax.ragged_dot(xs, w_gate, group_sizes)    # [Nk, M]
-    up = lax.ragged_dot(xs, w_up, group_sizes)
-    out = lax.ragged_dot(act(gate) * up, w_down, group_sizes)
+    if w_gate is None:
+        hidden = act(lax.ragged_dot(xs, w_up, group_sizes))   # [Nk, M]
+    else:
+        gate = lax.ragged_dot(xs, w_gate, group_sizes)    # [Nk, M]
+        up = lax.ragged_dot(xs, w_up, group_sizes)
+        hidden = act(gate) * up
+    out = lax.ragged_dot(hidden, w_down, group_sizes)
     # What a row behind the last group holds is not defined: select, do
     # not multiply.
     contrib = jnp.where(here[perm][:, None],
@@ -208,13 +228,18 @@ def moe_mlp_held(x: jax.Array, lp: Params, cfg: ModelConfig, live=None,
             routing = route(xt, lp, cfg)
     topi, weights = routing
     with phase("moe_experts"):
-        y, counts = held_rows(xt, topi, weights, lp["w_gate"], lp["w_up"],
+        y, counts = held_rows(xt, topi, weights, lp.get("w_gate"), lp["w_up"],
                               lp["w_down"], live, lp.get("expert_layer"),
                               gate_act(cfg), mesh)
     if cfg.num_shared_experts:
         with phase("moe_shared"):
-            y = y + swiglu(xt, lp["shared_gate"], lp["shared_up"],
-                           lp["shared_down"]).astype(jnp.float32)
+            if cfg.expert_gated:
+                shared = swiglu(xt, lp["shared_gate"], lp["shared_up"],
+                                lp["shared_down"])
+            else:
+                shared = ungated_ffn(xt, lp["shared_up"], lp["shared_down"],
+                                     gate_act(cfg))
+            y = y + shared.astype(jnp.float32)
     return y.astype(x.dtype).reshape(x.shape), counts
 
 

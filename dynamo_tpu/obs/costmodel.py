@@ -345,22 +345,43 @@ def step_shapes(cfg: ModelConfig, *, block_size: int,
     ``bytes_per_param`` as the program holds them, and the bytes of one KV
     block of one layer (K and V). ``devices`` is what the model is divided
     over. Norm weights and biases are left out: thousands, not millions."""
-    h, L = cfg.hidden_size, cfg.num_layers
-    routed = L - cfg.first_k_dense if cfg.is_moe else 0
+    h, L = cfg.hidden_size, cfg.attn_layers
+    routed = cfg.routed_layers
     m = cfg.moe_intermediate_size
     kvb = _kv_itemsize(kv_dtype)
     block = 2 * block_size * cfg.num_kv_heads * cfg.head_dim * kvb
     if kv_dtype in ("int8", "int4"):
         block += 2 * cfg.num_kv_heads * 4
+    # A layer's fixed matrices that are no attention's: a dense FFN's
+    # three or, in a hybrid pattern (a layer is one mixer), a Mamba
+    # mixer's W_in and W_out. The pricing has one term for them,
+    # ``dense_ffn_layers x dense_ffn_params``; the ``ssm_*`` keys below say
+    # what stands there for such a model. (The recurrent state's bytes have
+    # no term: ``step_work`` leaves them unpriced.)
+    from dynamo_tpu.models.mamba import slot_layer_bytes
+
+    mats = 3 if cfg.expert_gated else 2
+    ssm_layers = cfg.layers_of("M")
+    ssm_params = h * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.mamba_num_heads) \
+        + cfg.ssm_inner * h if ssm_layers else 0
+    if cfg.hybrid_pattern:
+        fixed_layers, fixed_params = ssm_layers, ssm_params
+    else:
+        fixed_layers = L - routed
+        fixed_params = 3 * h * cfg.intermediate_size if routed < L else 0
     return {
-        "layers": L, "routed_layers": routed, "dense_ffn_layers": L - routed,
+        "layers": L, "routed_layers": routed,
+        "dense_ffn_layers": fixed_layers,
         "hidden_size": h, "num_heads": cfg.num_heads,
         "head_dim": cfg.head_dim, "q_size": cfg.q_size,
         "attn_params": 2 * h * cfg.q_size + 2 * h * cfg.kv_size,
-        "dense_ffn_params": 3 * h * cfg.intermediate_size if routed < L else 0,
-        "shared_expert_params": 3 * h * m * cfg.num_shared_experts,
+        "dense_ffn_params": fixed_params,
+        "shared_expert_params": mats * h * cfg.shared_expert_width,
         "router_params": h * cfg.router_width if routed else 0,
-        "expert_params": 3 * h * m,
+        "expert_params": mats * h * m,
+        **({"ssm_layers": ssm_layers, "ssm_params": ssm_params,
+            "ssm_slot_layer_bytes": slot_layer_bytes(cfg)}
+           if ssm_layers else {}),
         "experts_held": cfg.num_experts, "router_width": cfg.router_width,
         "experts_per_token": cfg.num_experts_per_tok,
         "head_params": cfg.vocab_size * h,

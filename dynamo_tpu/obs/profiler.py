@@ -87,7 +87,8 @@ def phase(name: str):
 # first token).
 DEVICE_PHASES = (
     "layout", "embed", "layer", "proj", "scatter", "gather", "attention",
-    "mlp", "moe_route", "moe_experts", "moe_shared", "logits", "sampling",
+    "mlp", "moe_route", "moe_experts", "moe_shared", "ssm_proj", "ssm_conv",
+    "ssm_scan", "logits", "sampling",
 )
 
 _INSTRUCTION = re.compile(

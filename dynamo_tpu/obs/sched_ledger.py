@@ -289,6 +289,7 @@ class SchedLedger:
         self.kv_blocks_walked_total = 0
         # layer steps, rows, touched, largest, streamed layer steps
         self.moe_totals = [0, 0, 0, 0, 0]
+        self.ssm_totals = [0, 0, 0, 0]
         self.padding_flops_total = 0.0
         self.padding_bytes_total = 0.0
         self.hol_stall_seconds_total = 0.0
@@ -322,6 +323,7 @@ class SchedLedger:
             self.kv_blocks_live_total = 0
             self.kv_blocks_walked_total = 0
             self.moe_totals = [0, 0, 0, 0, 0]
+            self.ssm_totals = [0, 0, 0, 0]
             self.padding_flops_total = 0.0
             self.padding_bytes_total = 0.0
             self.hol_stall_seconds_total = 0.0
@@ -382,6 +384,7 @@ class SchedLedger:
         kv_blocks_live: int = 0,
         kv_blocks_walked: int = 0,
         moe: tuple[int, int, int, int, int] | None = None,
+        ssm: tuple[int, int, int, int] | None = None,
         live_flops: float = 0.0,
         sched_flops: float = 0.0,
         live_bytes: float = 0.0,
@@ -459,6 +462,8 @@ class SchedLedger:
             self.kv_blocks_walked_total += kv_blocks_walked
             if moe:
                 self.moe_totals = [a + b for a, b in zip(self.moe_totals, moe)]
+            if ssm:
+                self.ssm_totals = [a + b for a, b in zip(self.ssm_totals, ssm)]
             self.padding_flops_total += pad_f
             self.padding_bytes_total += pad_b
             if rec.hol_victims:
@@ -514,6 +519,8 @@ class SchedLedger:
                 "moe_experts_touched_total": self.moe_totals[2],
                 "moe_largest_group_total": self.moe_totals[3],
                 "moe_streamed_layer_steps_total": self.moe_totals[4],
+                **dict(zip((k + "_total" for k in SSM_COUNTS),
+                           self.ssm_totals)),
                 "padding_flops_total": self.padding_flops_total,
                 "padding_hbm_bytes_total": self.padding_bytes_total,
                 "admission_blocked": dict(self.blocked_totals),
@@ -586,7 +593,14 @@ def get_sched_ledger() -> SchedLedger:
 # A step's work, counted once — and its live-vs-scheduled geometry
 # ---------------------------------------------------------------------------
 
-def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0) -> dict:
+#: A step's counts of its recurrent layers' work (``step_counts`` with
+#: ``ssm_layers``), in the order the ledger totals them.
+SSM_COUNTS = ("ssm_layer_steps", "ssm_live_tokens", "ssm_scanned_positions",
+              "ssm_state_rows")
+
+
+def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
+                ssm_layers: int = 0) -> dict:
     """What one step did, in the program's own terms and nothing priced:
     THE walk over a step's rows, made once between plan and record
     (EngineCore._record_step). The profiler prices it, the ledger's goodput
@@ -621,7 +635,17 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0) -> dict
       sees ``p + 1`` keys, of a layer of window ``w`` ``min(p + 1, w)``;
     - ``table_q_ctx``, ``table_blocks``: what the programs' block tables
       span over all the layers (``b x t x nblk`` entries' keys, ``b x nblk``
-      blocks): the dense gather pays for that, the kernel for the two above.
+      blocks): the dense gather pays for that, the kernel for the two above;
+    - for a model with ``ssm_layers`` recurrent layers (models/mamba.py; 0
+      for every other model) ``SSM_COUNTS``: ``ssm_layer_steps`` (a program
+      times those layers), ``ssm_live_tokens`` (``live_tokens``),
+      ``ssm_scanned_positions`` (the positions the mixer computes: a
+      program's token bucket ``sig.n`` and, for each row of several tokens,
+      the ``t`` positions of its blocked scan) and ``ssm_state_rows`` (the
+      rows whose state is read and written, times the layers).
+
+    ``windows`` has the layers that have attention, and ``layers`` below
+    counts those: the KV cache's layers.
     """
     bs = block_size
     layer_kinds = tuple(Counter(windows).items())   # a few kinds, many rows
@@ -629,7 +653,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0) -> dict
     kinds: list[str] = []
     programs = pf_rows = n_dec = pf_tokens = dec_tokens = 0
     live = logit_rows = sched = rect = sched_rows = 0
-    blocks = walked = q_ctx = table_q = table_blocks = 0
+    blocks = walked = q_ctx = table_q = table_blocks = scanned = 0
     dec_left = dec_rows
     for sig, rows, *_ in batches:
         if not rows:
@@ -671,9 +695,11 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0) -> dict
                                   + (length - whole) * w)
             if chunks and length > 1:
                 pf_tokens += length
+                scanned += sig.t
             else:
                 dec_tokens += length
         sched += sig.n
+        scanned += sig.n
         rect += sig.b * sig.t
         sched_rows += sig.b
         table_q += layers * sig.b * sig.t * sig.nblk * bs
@@ -687,6 +713,10 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0) -> dict
         "kv_blocks_live": blocks, "kv_blocks_walked": walked,
         "attn_q_ctx": q_ctx,
         "table_q_ctx": table_q, "table_blocks": table_blocks,
+        "ssm_layer_steps": programs * ssm_layers,
+        "ssm_live_tokens": live if ssm_layers else 0,
+        "ssm_scanned_positions": scanned if ssm_layers else 0,
+        "ssm_state_rows": logit_rows * ssm_layers,
     }
 
 
@@ -714,9 +744,8 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
     ec = engine_cfg
     if counts is None:
         counts = step_counts(
-            batches, ec.block_size,
-            [model_cfg.window_of(i) for i in range(model_cfg.num_layers)],
-            dec_rows=dec_rows)
+            batches, ec.block_size, model_cfg.attn_windows,
+            dec_rows=dec_rows, ssm_layers=model_cfg.layers_of("M"))
     if shapes is None:
         shapes = cm.step_shapes(
             model_cfg, block_size=ec.block_size,

@@ -1,5 +1,6 @@
 """Pallas TPU kernel for the routed experts of a decode-sized step: the
-touched experts' three matrices streamed once each, where they lie.
+touched experts' matrices (three, or two where an expert has no gate)
+streamed once each, where they lie.
 
 The grouped form (models/moe.py ``held_rows``: sort the (token, choice)
 rows by expert, gather them, three ``lax.ragged_dot`` calls, scatter back)
@@ -35,10 +36,11 @@ Design notes (in the idiom of ops/paged_attention.py):
   (``lax.ragged_dot`` hands gate, up and out back in the operands' dtype;
   here they stay float32 up to the one cast before the down product).
 - VMEM: two of each of an expert's three matrices (the pipeline's double
-  buffer) and the rows' blocks, :func:`vmem_bytes`, within
-  ``VMEM_LIMIT_BYTES``, which the kernel asks for. An expert that does not
-  fit so (K-EXAONE's 75.5 MB) is not this kernel's: models/moe.py
-  ``streams_experts`` keeps it on the grouped form.
+  buffer) and the rows' blocks, :func:`vmem_bytes`; the kernel asks for
+  ``VMEM_LIMIT_BYTES`` or, where an expert needs more, for what it needs, up
+  to ``VMEM_MAX_BYTES``. An expert that does not fit so (K-EXAONE's 75.5 MB)
+  is not this kernel's: models/moe.py ``streams_experts`` keeps it on the
+  grouped form.
 """
 
 from __future__ import annotations
@@ -53,18 +55,25 @@ from jax.experimental.pallas import tpu as pltpu
 #: The VMEM the kernel asks for (``vmem_limit_bytes``): 32 MiB of the v5e's
 #: 128, twice the 16 MiB a kernel gets unasked.
 VMEM_LIMIT_BYTES = 32 << 20
+#: The most it asks for where an expert needs more than that: half of the
+#: v5e's VMEM. An expert of two matrices ``[2688, 1920]`` is 20.6 MB, 42 MiB
+#: with the pipeline's second buffer; the ask is then what it needs.
+VMEM_MAX_BYTES = 64 << 20
 
 
-def vmem_bytes(n: int, h: int, m: int, itemsize: int) -> int:
+def vmem_bytes(n: int, h: int, m: int, itemsize: int,
+               matrices: int = 3) -> int:
     """VMEM the kernel holds at ``n`` rows of width ``h`` against experts
-    of width ``m``: two of each block the pipeline moves (an expert's
-    three matrices, the rows, their float32 result, a column of the combine
-    matrix padded to a lane tile) and the float32 values of one step
-    (gate, up, their product; the down product, weighted, selected). An
+    of width ``m`` and ``matrices`` matrices (3: gate, up, down; 2: no
+    gate): two of each block the pipeline moves (an expert's matrices, the
+    rows, their float32 result, a column of the combine matrix padded to a
+    lane tile) and the float32 values of one step (gate, up, their product;
+    the down product, weighted, selected). An
     upper bound: the compiler took 23.41 MiB of these 23.6 at 16 rows of
     SmallThinker's (tests/test_ops.py compiles with no more than this)."""
     rows = -(-n // 16) * 16
-    blocks = 3 * h * m * itemsize + rows * (h * itemsize + h * 4 + 128 * 4)
+    blocks = (matrices * h * m * itemsize
+              + rows * (h * itemsize + h * 4 + 128 * 4))
     return 2 * blocks + rows * (3 * m + 3 * h) * 4
 
 
@@ -98,9 +107,12 @@ def stream_plan(topi, weights, live, held: int):
     return c, ids, n_touched.reshape(1), counts
 
 
-def _kernel(ly_ref, ids_ref, nt_ref, x_ref, c_ref, wg_ref, wu_ref, wd_ref,
-            o_ref, *, act):
+def _kernel(ly_ref, ids_ref, nt_ref, x_ref, c_ref, *refs, act):
+    """``refs``: an expert's matrices, ``w_gate``, ``w_up`` and ``w_down``
+    or, for an expert without a gate, ``w_up`` and ``w_down``; then the
+    output."""
     del ly_ref, ids_ref       # the index maps read them
+    *w_refs, o_ref = refs
     g = pl.program_id(0)
 
     @pl.when(g == 0)
@@ -110,9 +122,15 @@ def _kernel(ly_ref, ids_ref, nt_ref, x_ref, c_ref, wg_ref, wu_ref, wd_ref,
     @pl.when(g < nt_ref[0])
     def _expert():
         x = x_ref[...]
-        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        out = jnp.dot((act(gate) * up).astype(wd_ref.dtype), wd_ref[...],
+        *first, wd_ref = w_refs
+        if len(first) == 2:
+            gate = jnp.dot(x, first[0][...], preferred_element_type=jnp.float32)
+            up = jnp.dot(x, first[1][...], preferred_element_type=jnp.float32)
+            hidden = act(gate) * up
+        else:
+            hidden = act(jnp.dot(x, first[0][...],
+                                 preferred_element_type=jnp.float32))
+        out = jnp.dot(hidden.astype(wd_ref.dtype), wd_ref[...],
                       preferred_element_type=jnp.float32)         # [N, H]
         w = c_ref[...]                                            # [N, 1]
         o_ref[...] += jnp.where(w != 0.0, w * out, 0.0)
@@ -126,10 +144,12 @@ def stream_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
     be traced), the whole stack ``[L, E_held, ...]``, of which the kernel
     reads the touched experts of that layer and nothing else."""
     if layer is None:
-        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+        w_gate, w_up, w_down = (w if w is None else w[None]
+                                for w in (w_gate, w_up, w_down))
         layer = 0
     n, h = xt.shape
-    _, held, _, m = w_gate.shape
+    _, held, _, m = w_up.shape
+    mats = [w for w in (w_gate, w_up, w_down) if w is not None]
     c, ids, n_touched, counts = stream_plan(topi, weights, live, held)
 
     def rows(g, *_prefetch):
@@ -144,9 +164,7 @@ def stream_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
         in_specs=[
             pl.BlockSpec((n, h), rows),
             pl.BlockSpec((None, n, 1), lambda g, ly, ids, nt: (ids[g], 0, 0)),
-            pl.BlockSpec((None, None, h, m), expert),
-            pl.BlockSpec((None, None, h, m), expert),
-            pl.BlockSpec((None, None, m, h), expert),
+            *[pl.BlockSpec((None, None, *w.shape[2:]), expert) for w in mats],
         ],
         out_specs=pl.BlockSpec((n, h), rows),
     )
@@ -156,10 +174,11 @@ def stream_rows(xt, topi, weights, w_gate, w_up, w_down, live=None,
         out_shape=jax.ShapeDtypeStruct((n, h), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+            vmem_limit_bytes=max(VMEM_LIMIT_BYTES, vmem_bytes(
+                n, h, m, w_up.dtype.itemsize, len(mats))),
         ),
         interpret=interpret,
         name="moe_stream",
     )(jnp.asarray(layer, jnp.int32).reshape(1), ids, n_touched,
-      xt.astype(w_gate.dtype), c[:, :, None], w_gate, w_up, w_down)
+      xt.astype(w_up.dtype), c[:, :, None], *mats)
     return y, counts

@@ -115,3 +115,71 @@ def test_smallthinkers_reference_is_found_accepted_and_agrees_at_a_toy_size(
     # a later position can only have met more near-ties on its way
     own = reference.routing_margin_at(params, model, tokens[:6], [5])
     assert margins[0] == pytest.approx(float(own[0]), abs=1e-5)
+
+
+NEMOTRON = (_bench.manifest.ROOT / "chipbench" / "configs"
+            / "nemotron-3-nano-30b-a3b-ep8-l34")
+
+
+def test_nemotrons_reference_is_found_accepted_and_agrees_at_a_toy_size(
+        tmp_path):
+    """The same for the configuration with recurrent layers: the probe's
+    finder loads its ``reference.py`` (``logits_at``, ``routing_margin_at``,
+    no import of ``dynamo_tpu``), ``manifest.check`` has no fault with the
+    directory, and at a toy size of the same keys on the CPU, through the
+    KV cache of its attention layers and a pool of recurrent state, the
+    program's logits are its logits; its margins have the probe's shape."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.models import llama, mamba
+    from dynamo_tpu.models.config import ModelConfig
+
+    path = _bench.probe.reference_path(NEMOTRON)
+    assert path == NEMOTRON / "reference.py"
+    reference = _bench.probe.load_reference(path)
+    assert callable(reference.routing_margin_at)
+    about = json.loads((NEMOTRON / "about.json").read_text())
+    assert _bench.manifest.probe_faults(NEMOTRON, about) == []
+    assert sorted(about["reduced"]) == [
+        "hybrid_override_pattern", "n_routed_experts", "num_hidden_layers",
+        "vocab_size"]
+    published = json.loads((NEMOTRON / "config.json").read_text())
+    assert sorted(published["assumed"]) == sorted(about["assumed"])
+
+    pattern = "MEM*EM*E"
+    model = {**published, "hidden_size": 64, "head_dim": 16,
+             "num_attention_heads": 4, "num_key_value_heads": 2,
+             "mamba_num_heads": 8, "mamba_head_dim": 8, "n_groups": 2,
+             "ssm_state_size": 16, "chunk_size": 8,
+             "moe_intermediate_size": 32, "intermediate_size": 32,
+             "moe_shared_expert_intermediate_size": 48,
+             "n_routed_experts": 4, "n_routed_experts_published": 8,
+             "num_experts_per_tok": 2, "num_hidden_layers": len(pattern),
+             "hybrid_override_pattern": pattern, "vocab_size": 128}
+    (tmp_path / "config.json").write_text(json.dumps(model))
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(str(tmp_path)),
+                              dtype="float32")
+    params = llama.init_params(cfg, jax.random.key(2))
+    n, at = 40, [5, 20, 38, 39]
+    tokens = np.random.default_rng(3).integers(0, 128, n).tolist()
+    ids = jnp.asarray([tokens], jnp.int32)
+    shape = (cfg.attn_layers, 5, 16, cfg.num_kv_heads, cfg.head_dim)
+    hid, *_ = llama.forward(
+        params, cfg, ids, jnp.zeros((1,), jnp.int32),
+        jnp.asarray([n], jnp.int32), jnp.arange(1, 4, dtype=jnp.int32)[None],
+        jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32),
+        moe_impl="held", return_all_hidden=True,
+        ssm=mamba.zeros_state(cfg, 1), ssm_slots=jnp.zeros((1,), jnp.int32))
+    got = np.asarray(llama.logits_from_hidden(params, cfg, hid[0]))[at]
+    want = reference.logits_at(params, model, tokens, at, pad_to=64)
+    assert want.shape == (len(at), 128) and want.dtype == np.float32
+    assert np.max(np.abs(got - want)) < 2e-4
+    margins = reference.routing_margin_at(params, model, tokens, at, pad_to=64)
+    assert margins.shape == (len(at),) and margins.dtype == np.float32
+    assert (margins >= 0).all() and np.isfinite(margins).all()
+    own = reference.routing_margin_at(params, model, tokens[:6], [5])
+    assert margins[0] == pytest.approx(float(own[0]), abs=1e-5)

@@ -5,6 +5,8 @@ tested via block_manager tests); here the kernels are compared bit-for-tol
 against the portable XLA paths they replace.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -674,6 +676,86 @@ def test_step_keeps_no_copy_of_a_period_on_v5e(v5e_device):
     r = aot_check.compile_bucket(*parts, 16, 1, parts[0].max_nblk, True, 2048)
     assert r["kernel"]
     assert r["beyond_arguments_bytes"] < 32 * 2**20, r
+
+
+def _hybrid_step(config: str, b: int, t: int):
+    """A step program of a configuration with recurrent layers, compiled
+    for the described v5e: ``aot_check.compile_bucket`` with the state pool
+    handed in by its keyword, as ``ModelRunner._run_step`` does. Returns
+    (the compiled program, the configuration, the pool's abstract leaves)."""
+    from dynamo_tpu.engine.cache import KVCacheSpec, abstract_cache
+    from dynamo_tpu.models import mamba
+
+    _aot, (runner, cfg, ec, params, state, on_chip) = _aot_parts(config)
+    cache = on_chip(abstract_cache(
+        KVCacheSpec.for_model(cfg, 2048, ec.block_size), None))
+    sds = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    nblk = runner.max_nblk
+    inputs = on_chip((
+        sds((b, t), i32), sds((b,), i32), sds((b,), i32), sds((b, nblk), i32),
+        sds((b,), i32), sds((b,), f32), sds((b,), i32), sds((b,), f32),
+        sds((b,), f32), sds((b,), f32), sds((b,), f32), sds((b,), bool),
+        sds((b,), bool)))
+    pool = on_chip(mamba.state_shapes(cfg, ec.max_batch_size))
+    fn = runner._build_step_fn(b, t, nblk, fast_greedy=True)
+    return fn.lower(params, cache, cache, *state, *inputs,
+                    ssm=pool).compile(), cfg, pool
+
+
+@pytest.mark.parametrize("b, t", [(32, 1), (8, 64)], ids=["decode", "chunk"])
+def test_step_updates_the_state_pool_in_place_on_v5e(v5e_device, b, t):
+    """The benchmark's 34-layer Nemotron-3-Nano cut, a step compiled for
+    the described v5e: the recurrent state's pool (2.08 GB of float32 for
+    64 slots and 15 layers, beside a 36 MB pool of convolution tails) is
+    donated and aliased to the program's output, and nothing in the program
+    has its shape but the in-place updates (a scatter a Mamba layer body)
+    and the loops that carry it; the experts' stacks are read where they lie
+    (with an expert's 1,856 columns stored unpadded the compiler kept
+    ``w_up`` in another order and copied all 2.2 GB of it in every step:
+    PERF.md section 6, PR 45). Beyond its arguments the program keeps a few
+    rows' states, not a pool. Memory and text only: no time is read here."""
+    import re
+
+    compiled, cfg, pool = _hybrid_step("nemotron-3-nano-30b-a3b-ep8-l34", b, t)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in pool.values())
+    assert pool_bytes > 2e9
+    # donated: both leaves come back in the buffers they came in
+    assert mem.alias_size_in_bytes > pool_bytes
+    assert mem.temp_size_in_bytes < 0.05 * pool_bytes, mem
+    shapes = {",".join(map(str, x.shape)) for x in pool.values()}
+    e, h, m = cfg.num_experts, cfg.hidden_size, cfg.expert_store_width
+    stacks = {f"{cfg.layers_of('E')},{e},{h},{m}",
+              f"{cfg.layers_of('E')},{e},{m},{h}"}
+    # (custom-call: the one-token update's kernel, whose output is the pool
+    # it was given, ops/ssm_update.py; dynamic-update-slice: a chunk row's
+    # state put back; conditional: the loop over rows that skips the others)
+    allowed = ("parameter", "get-tuple-element", "tuple", "while", "scatter",
+               "fusion", "bitcast", "custom-call", "dynamic-update-slice",
+               "conditional")
+    odd = []
+    for name, dims, op in re.findall(
+            r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+            text, re.M):
+        if dims in shapes and op not in allowed:
+            odd.append(f"{name} [{dims}] {op}")
+        if dims in stacks and op not in ("parameter", "get-tuple-element",
+                                         "bitcast"):
+            odd.append(f"{name} [{dims}] {op}")
+    assert not odd, odd
+    # a fusion of the pool's shape is an update of it in place, nothing else
+    roots = re.findall(
+        r"ROOT %(\S+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(", text)
+    assert all(op in ("scatter", "dynamic-update-slice", "tuple", "bitcast",
+                      "get-tuple-element", "custom-call", "conditional",
+                      "parameter")
+               or dims not in shapes for _n, dims, op in roots), \
+        [r for r in roots if r[1] in shapes]
+    assert "ssm_update" in text
 
 
 # -- the streaming expert kernel (ops/moe_stream.py) on the described v5e ------

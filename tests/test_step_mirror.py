@@ -52,11 +52,13 @@ from dynamo_tpu.protocols.common import (  # noqa: E402
 # under the kernel: (rows 8, 16) x (decode + six chunk buckets), one width.
 WARMED = {"mistral-7b.chat": 64, "mistral-7b.longprompt": 48,
           "mistral-nemo-12b.chat": 64, "k-exaone-236b.reasoning": 99,
-          "smallthinker-21b.reasoning": 66}
+          "smallthinker-21b.reasoning": 66,
+          "nemotron-3-nano-30b.reasoning": 99}
 # ... rows (8, 16), or (8, 16, 32) where the cell's ``max_rows`` is 32.
 WARMED_KERNEL = {"mistral-7b.chat": 14, "mistral-7b.longprompt": 14,
                  "mistral-nemo-12b.chat": 14, "k-exaone-236b.reasoning": 21,
-                 "smallthinker-21b.reasoning": 14}
+                 "smallthinker-21b.reasoning": 14,
+                 "nemotron-3-nano-30b.reasoning": 21}
 # What EngineCore resolves EngineConfig.attn_impl to: on a TPU, elsewhere.
 PATHS = {"kernel": "pallas", "gather": "dense"}
 # Seconds a step takes in the replay: (a decode step, each chunk token on
@@ -73,7 +75,11 @@ CLOCKS_OF = {"k-exaone-236b.reasoning": {"fast": (0.011, 0.0001),
              # (PR 41: a decode step 14-16 ms at 7-10 rows, a 512-token
              # chunk step 72 ms)
              "smallthinker-21b.reasoning": {"fast": (0.014, 0.00011),
-                                            "slow": (0.018, 0.00015)}}
+                                            "slow": (0.018, 0.00015)},
+             # (PR 45: a decode step 11-15 ms at 10-20 rows, a 512-token
+             # chunk step ~90 ms)
+             "nemotron-3-nano-30b.reasoning": {"fast": (0.011, 0.00012),
+                                               "slow": (0.015, 0.00018)}}
 POOL_BLOCKS = 6000
 
 

@@ -438,18 +438,29 @@ def test_new_readers_find_nothing_on_the_rehearsals_traces(monkeypatch, trace):
 def test_the_new_entries_and_their_cells():
     entries = {m["name"]: m for m in BENCH["per_layer"]}
     assert set(NEW) <= set(entries)
-    routed = {"k-exaone-236b.reasoning", "smallthinker-21b.reasoning"}
+    gated = {"k-exaone-236b.reasoning", "smallthinker-21b.reasoning"}
+    # (PR 45) experts without a gate: the phase's share is read there, its
+    # roofline share by a count of its own (moe.ungated_experts_roofline_pct:
+    # moe_gemm_counts.py prices three matrices an expert)
+    routed = gated | {"nemotron-3-nano-30b.reasoning"}
     for name in NEW:
         e = entries[name]
         assert e["moves"] == "itl_p95_ms"
-        if name in ("device.moe_experts_pct", "moe.experts_roofline_pct"):
+        if name == "device.moe_experts_pct":
             assert set(e["workloads"]) == routed
+        elif name == "moe.experts_roofline_pct":
+            assert set(e["workloads"]) == gated
         else:
             assert "workloads" not in e
+    assert entries["moe.ungated_experts_roofline_pct"]["workloads"] == [
+        "nemotron-3-nano-30b.reasoning"]
     for w in BENCH["workloads"]:
         cell = manifest.load_cell(w["name"])
-        want = set(NEW) if w["name"] in routed else set(NEW) - {
-            "device.moe_experts_pct", "moe.experts_roofline_pct"}
+        want = set(NEW) - {"device.moe_experts_pct", "moe.experts_roofline_pct"}
+        if w["name"] in routed:
+            want |= {"device.moe_experts_pct"}
+        if w["name"] in gated:
+            want |= {"moe.experts_roofline_pct"}
         assert want <= set(cell.per_layer)
         assert not (set(NEW) - want) & set(cell.per_layer)
     assert manifest.check() == []
@@ -504,3 +515,93 @@ def test_the_readers_on_a_hand_made_trace(monkeypatch, tmp_path):
     assert reader("device.unscoped_pct") == pytest.approx(10.0)
     assert reader("device.moe_experts_pct") is None              # no such phase
     assert reader("moe.experts_roofline_pct") is None
+
+
+# ---------------------------------------------------------------------------
+# PR 45: the recurrent layers' readers and the count of an expert's matrices
+# ---------------------------------------------------------------------------
+
+SSM = {"layers": 15, "slots": 64, "slot_layer_bytes": 2_134_016,
+       "token_bytes": (2 * 4096 + 6144 + 64) * 2, "heads": 64, "head_dim": 64,
+       "state_size": 128, "groups": 8, "conv_kernel": 4, "conv_dim": 6144}
+UNGATED = {"hidden_size": 2688, "expert_width": 1856, "bytes_per_param": 2,
+           "expert_matrices": 2}
+PR45 = ("device.ssm_pct", "ssm.scan_roofline_pct", "ssm.padding_pct",
+        "moe.ungated_experts_roofline_pct")
+
+
+def test_the_recurrent_readers_on_a_hand_made_trace(monkeypatch, tmp_path):
+    """Two decode steps of 10 ms at 16 rows: 1.5 ms of Mamba projections,
+    0.5 ms of convolution, 2 ms of the state's update (16 rows x 15 layers
+    of 2.13 MB read and written: 1.25 ms at 819 GB/s), 3 ms of experts
+    without a gate (14 layers, 96 rows over 10 experts of two matrices)."""
+    ssm_counts = measure.load_module(
+        ROOT / "chipbench/layers/ssm_counts.py", "ssm_counts")
+    ungated = measure.load_module(
+        ROOT / "chipbench/layers/moe_ungated_counts.py", "moe_ungated_counts")
+    gated = measure.load_module(
+        ROOT / "chipbench/layers/moe_gemm_counts.py", "moe_gemm_counts")
+    ops, modules, host = [], [], []
+    counts = dict(live_tokens=16, logit_rows=16, kv_blocks_walked=16 * 64 * 5,
+                  attn_q_ctx=16 * 1000 * 5, ssm_layer_steps=15,
+                  ssm_live_tokens=16, ssm_scanned_positions=16,
+                  ssm_state_rows=16 * 15, moe_layer_steps=14,
+                  moe_rows=14 * 12, moe_experts_touched=14 * 10)
+    for step, t0 in ((1, 10 * MS), (2, 25 * MS)):
+        modules.append((f"{DEC}(1)", t0, t0 + 10 * MS))
+        ops += [("%fusion.1 = bf16[16,10304] fusion(%a)", t0, t0 + 1.5 * MS),
+                ("%fusion.2 = bf16[16,6144] fusion(%b)", t0 + 1.5 * MS,
+                 t0 + 2 * MS),
+                ("%ssm_update.3 = f32[15,65,64,64,128] custom-call(%s)",
+                 t0 + 2 * MS, t0 + 4 * MS),
+                ("%moe_stream.4 = f32[16,2688] custom-call(%x)", t0 + 4 * MS,
+                 t0 + 7 * MS),
+                ("%fusion.5 = bf16[16,16384] fusion(%h)", t0 + 7 * MS,
+                 t0 + 10 * MS)]
+        host += [_program(step, 0, DEC, t0 - MS), _wait(step, t0 + 10.1 * MS),
+                 _record(step, t0 + 10.5 * MS, **counts)]
+    ev = _events(modules, host, ops)
+    ev.path = tmp_path / "hand.xplane.pb"
+    tables = {DEC: {"fusion.1": "ssm_proj", "fusion.2": "ssm_conv",
+                    "ssm_update.3": "ssm_scan", "moe_stream.4": "moe_experts",
+                    "fusion.5": "logits"}}
+    monkeypatch.setattr(xevents, "current", lambda: ev)
+    sched0 = {"ssm_live_tokens_total": 100, "ssm_scanned_positions_total": 400}
+    sched1 = {"ssm_live_tokens_total": 700, "ssm_scanned_positions_total": 1600}
+    full = {"ssm": SSM, "moe": UNGATED, "sched": sched1,
+            "device": {"device_kind": "TPU v5 lite"}}
+
+    def reader(name, c1=full, c0=None):
+        mod = measure.load_reader(name)
+        if hasattr(mod, "join"):
+            monkeypatch.setattr(mod.join, "current",
+                                lambda: join.build(ev, tables))
+        return mod.read(_ctx({"sched": sched0} if c0 is None else c0, c1))
+
+    assert reader("device.ssm_pct") == pytest.approx(40.0)      # 4 ms of 10
+    nbytes, flop = ssm_counts.step(16 * 15, 16, SSM)
+    assert nbytes == 16 * 15 * 2 * 2_134_016 + 16 * 15 * SSM["token_bytes"]
+    assert flop == 16 * 15 * (5 * 64 * 64 * 128 + 2 * 4 * 6144)
+    assert reader("ssm.scan_roofline_pct") == pytest.approx(
+        100 * (nbytes / 819e9) / 0.0025)
+    assert 49.0 < reader("ssm.scan_roofline_pct") < 51.0
+    assert reader("ssm.padding_pct") == pytest.approx(50.0)     # 600 of 1200
+    b2, f2 = ungated.layer_step(12, 10, 2688, 1856, 2)
+    b3, f3 = gated.layer_step(12, 10, 2688, 1856)
+    assert ungated.layer_step(12, 10, 2688, 1856, 3) == (b3, f3)
+    assert f2 * 3 == f3 * 2 and b2 < b3
+    assert reader("moe.ungated_experts_roofline_pct") == pytest.approx(
+        100 * 14 * (b2 / 819e9) / 0.003)
+    # a program without the facts (the parent's, another model's): nothing
+    # (device.ssm_pct reads the phases alone: nothing where no table has one)
+    for name in PR45[1:]:
+        assert reader(name, {"device": full["device"], "sched": {},
+                             "moe": {k: v for k, v in UNGATED.items()
+                                     if k != "expert_matrices"}},
+                      {"sched": {}}) is None, name
+    tables = {DEC: {"fusion.1": "proj", "fusion.5": "logits"}}
+    assert reader("device.ssm_pct") is None
+    entries = {m["name"]: m for m in BENCH["per_layer"]}
+    for name in PR45:
+        assert entries[name]["workloads"] == ["nemotron-3-nano-30b.reasoning"]
+        assert entries[name]["moves"] == "itl_p95_ms"
