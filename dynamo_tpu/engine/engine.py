@@ -180,6 +180,62 @@ def _named(fn: Callable, name: str) -> Callable:
 
 _NO_PHASE = contextlib.nullcontext()
 
+# The packed inputs of a step program: what a step's rows send to the device,
+# as one array (two where a row samples) in place of one placement a field.
+#   ints   int32   [b, 5 + t + nblk]  q_start, q_len, slot, top_k, flags (bit 0
+#                                     do_sample, bit 1 from_slot), then the
+#                                     row's t tokens, then its nblk block ids
+#   floats float32 [b, 5]             temp, top_p, fp, pp, rp: only for a
+#                                     program that reads them (not fast_greedy)
+# ``pack_step_inputs`` and ``unpack_step_inputs`` are the one definition of it.
+_ROW_INTS = 5
+
+
+def pack_step_inputs(tokens, q_start, q_len, bt, slots, temp, top_k, top_p,
+                     fp, pp, rp, do_sample, from_slot, *, greedy: bool):
+    """A step's per-row numpy arrays as its packed inputs ``(ints,)``, or
+    ``(ints, floats)`` for a program that is not ``greedy`` (a
+    ``fast_greedy`` program reads no sampling option and has no such
+    parameter)."""
+    b, t = tokens.shape
+    ints = np.empty((b, _ROW_INTS + t + bt.shape[1]), np.int32)
+    flags = np.asarray(do_sample, np.int32) | (
+        np.asarray(from_slot, np.int32) << 1)
+    for col, x in enumerate((q_start, q_len, slots, top_k, flags)):
+        ints[:, col] = x
+    ints[:, _ROW_INTS:_ROW_INTS + t] = tokens
+    ints[:, _ROW_INTS + t:] = bt
+    if greedy:
+        return (ints,)
+    return ints, np.stack([temp, top_p, fp, pp, rp], axis=1, dtype=np.float32,
+                          casting="same_kind")
+
+
+def unpack_step_inputs(t: int, ints, floats=None) -> tuple:
+    """The thirteen per-row fields of a step's packed inputs (a step of
+    ``t`` tokens a row), in the order ``pack_step_inputs`` takes them and in
+    the dtypes it was given: static slices, of numpy arrays or inside a
+    traced program alike. The five sampling options are None where the
+    program has no ``floats``."""
+    q_start, q_len, slots, top_k, flags = (ints[:, c] for c in range(_ROW_INTS))
+    tokens = ints[:, _ROW_INTS:_ROW_INTS + t]
+    bt = ints[:, _ROW_INTS + t:]
+    temp, top_p, fp, pp, rp = (
+        (None,) * 5 if floats is None else (floats[:, c] for c in range(5)))
+    return (tokens, q_start, q_len, bt, slots, temp, top_k, top_p, fp, pp, rp,
+            (flags & 1) != 0, (flags & 2) != 0)
+
+
+def _padding_rows(b: int, t: int, nblk: int) -> tuple:
+    """The thirteen per-row numpy arrays of a (b, t, nblk) step with no live
+    row, in ``pack_step_inputs``' order: no tokens, temperature 0 and the
+    neutral penalties (greedy-compatible), nothing sampled. What a step's
+    fill starts from."""
+    z, i32, f32 = np.zeros, np.int32, np.float32
+    return (z((b, t), i32), z(b, i32), z(b, i32), z((b, nblk), i32),
+            z(b, i32), z(b, f32), z(b, i32), np.ones(b, f32), z(b, f32),
+            z(b, f32), np.ones(b, f32), z(b, bool), z(b, bool))
+
 # What a step's ``engine.record`` span carries of its one count
 # (obs/sched_ledger.py step_counts), and of the routed layers' device counts:
 # what chipbench/layers/step_work_counts.py prices, and no more.
@@ -218,6 +274,10 @@ class EngineMetrics:
     (reference: ForwardPassMetrics, lib/llm/src/kv_router/publisher.rs:686)."""
 
     num_steps: int = 0
+    # Host-to-device placements of step inputs (``ModelRunner.dispatch``):
+    # one a greedy step program, two where a row samples, and the multimodal
+    # pair and the logit mask where a step carries them.
+    placed_inputs: int = 0
     num_prefill_tokens: int = 0
     num_decode_tokens: int = 0
     num_requests_finished: int = 0
@@ -301,6 +361,7 @@ class EngineMetrics:
             "kv_usage": pool.usage,
             "kv_total_blocks": pool.num_blocks,
             "num_steps": self.num_steps,
+            "placed_inputs": self.placed_inputs,
             "prefill_tokens": self.num_prefill_tokens,
             "decode_tokens": self.num_decode_tokens,
             "requests_finished": self.num_requests_finished,
@@ -461,6 +522,8 @@ class ModelRunner:
         # engine.compile around a step program built inside serving;
         # EngineCore shares this clock for the rest of the loop.
         self.loop_clock = LoopClock()
+        # Arrays ``dispatch`` has placed (EngineMetrics.placed_inputs).
+        self.placed_inputs = 0
         # The pool comes last: everything else that lives on the device is
         # resident by now, so what memory_stats() calls free really is.
         self.spec = KVCacheSpec.for_model(
@@ -715,7 +778,7 @@ class ModelRunner:
                if self.cfg.has_ssm else {})
         mem = fn.lower(
             self.params, cache, cache, self.counts, self.keys, self.slot_toks,
-            *self._padding_inputs(sig.b, sig.t, sig.nblk), **ssm,
+            *self._padding_inputs(sig.b, sig.t, sig.nblk, True), **ssm,
         ).compile().memory_analysis()
         extra = (mem.temp_size_in_bytes + mem.output_size_in_bytes
                  - mem.alias_size_in_bytes)
@@ -742,25 +805,34 @@ class ModelRunner:
             self.ssm, *rest = rest
         return tuple(rest)
 
-    def _padding_inputs(self, b: int, t: int, nblk: int) -> tuple:
-        """The per-step inputs of a (b, t, nblk) step, all padding: q_len=0
-        rows compute nothing meaningful and do_sample=False routes
-        sampling-state writes to the trash row."""
-        place = self._place
-        zi = np.zeros((b,), np.int32)
-        zf = np.zeros((b,), np.float32)
-        ones = np.ones((b,), np.float32)
-        zb = np.zeros((b,), bool)
-        return (place(np.zeros((b, t), np.int32)), place(zi), place(zi),
-                place(np.zeros((b, nblk), np.int32)), place(zi),
-                place(zf), place(zi), place(ones),
-                place(zf), place(zf), place(ones),
-                place(zb), place(zb))
+    def _padding_inputs(self, b: int, t: int, nblk: int,
+                        greedy: bool) -> tuple:
+        """The packed inputs of a (b, t, nblk) step, all padding and placed:
+        q_len=0 rows compute nothing meaningful and do_sample=False routes
+        sampling-state writes to the trash row. One array for a ``greedy``
+        (``fast_greedy``) program, two for one that reads sampling options."""
+        return tuple(self._place(x) for x in pack_step_inputs(
+            *_padding_rows(b, t, nblk), greedy=greedy))
 
     # ------------------------------------------------------------------
     def _build_step_fn(self, b: int, t: int, nblk: int, sp_prefill: bool = False,
                        fast_greedy: bool = False, mm: bool = False,
                        masked: bool = False):
+        """The jitted step program of one bucket. After the device state it
+        carries (params, K, V, counts, keys, slot_toks; all but the first
+        donated) it takes the packed inputs (``pack_step_inputs``: one int32
+        array, and a float32 one unless ``fast_greedy``), which it cuts apart
+        itself, then the multimodal pair and the logit mask where the
+        program has them, and the recurrent state's pool by keyword.
+
+        The same function still lowers from the thirteen per-row arrays
+        passed one by one (``unpack_step_inputs``' order) in place of the
+        packed ones: a different argument count is a different trace of the
+        same body. That convention is kept for one caller,
+        ``chipbench/aot_check.py compile_bucket`` (the benchmark's file, and
+        the tests that go through it); the serving path only ever makes the
+        packed trace. It goes when that file asks the runner for its
+        abstract inputs (ROADMAP.md, benchmark debts)."""
         cfg = self.cfg
         trash_row = self.engine_cfg.max_batch_size
 
@@ -773,21 +845,31 @@ class ModelRunner:
         n_tok = _step_tokens(b, t, sp_prefill)
 
         has_ssm = cfg.has_ssm
+        name = self._step_program(b, t, nblk, sp_prefill, fast_greedy, mm,
+                                  masked)
 
-        def step(params, ck, cv, counts, keys, slot_toks, tokens, q_start, q_len,
-                 bt, slots, temp, top_k, top_p, fp, pp, rp, do_sample, from_slot,
-                 *mm_args, ssm=None):
+        def step(params, ck, cv, counts, keys, slot_toks, *inputs, ssm=None):
             # (ssm: the recurrent state's pool, by keyword and donated by
             # name, where the model has one; every other model's program
             # has the arguments it always had.)
-            # Device-fed decode input: rows whose previous token was sampled
-            # by an in-flight step read it from slot_toks instead of the host
-            # tokens array (which holds 0 for them) — XLA's execution order
-            # guarantees the producing step has run.
+            n_rest = 2 * mm + masked
+            per_row, rest = inputs[:len(inputs) - n_rest], \
+                list(inputs[len(inputs) - n_rest:])
             with _perf_phase("layout"):
+                if len(per_row) != 13:     # packed: what the serving path sends
+                    if len(per_row) != (1 if fast_greedy else 2):
+                        raise TypeError(
+                            f"{name} takes {1 if fast_greedy else 2} packed "
+                            f"per-row inputs, got {len(per_row)}")
+                    per_row = unpack_step_inputs(t, *per_row)
+                (tokens, q_start, q_len, bt, slots, temp, top_k, top_p, fp,
+                 pp, rp, do_sample, from_slot) = per_row
+                # Device-fed decode input: rows whose previous token was
+                # sampled by an in-flight step read it from slot_toks
+                # instead of the host tokens (which hold 0 for them) — XLA's
+                # execution order guarantees the producing step has run.
                 first = jnp.where(from_slot, slot_toks[slots], tokens[:, 0])
                 tokens = tokens.at[:, 0].set(first)
-            rest = list(mm_args)
             emb_override = rest.pop(0) if mm else None
             emb_mask = rest.pop(0) if mm else None
             logit_mask = rest.pop(0) if masked else None
@@ -842,8 +924,6 @@ class ModelRunner:
             return (ck, cv, counts, keys, slot_toks,
                     *((ssm,) if has_ssm else ()), toks, lps, *moe)
 
-        name = self._step_program(b, t, nblk, sp_prefill, fast_greedy, mm,
-                                  masked)
         by_name = {"donate_argnames": ("ssm",)} if has_ssm else {}
         return jax.jit(_named(step, name), donate_argnums=(1, 2, 3, 4, 5),
                        **by_name, **self._jit_shardings())
@@ -900,7 +980,7 @@ class ModelRunner:
             name = "jit_" + self._step_program(*key)
             if programs is not None and name not in programs:
                 continue
-            b, t, nblk, _sp, _greedy, mm, masked = key
+            b, t, nblk, _sp, greedy, mm, masked = key
             extra = ((place(np.zeros((b, t, self.cfg.hidden_size), np.float32)),
                       place(np.zeros((b, t), bool))) if mm else ())
             if masked:
@@ -915,7 +995,7 @@ class ModelRunner:
                 text = self._step_fns[key].lower(
                     self.params, self.cache_k, self.cache_v, self.counts,
                     self.keys, self.slot_toks,
-                    *self._padding_inputs(b, t, nblk), *extra,
+                    *self._padding_inputs(b, t, nblk, greedy), *extra,
                     **self._ssm_kw()
                 ).compile().as_text()
             except Exception:
@@ -982,8 +1062,9 @@ class ModelRunner:
         program's name, which holds its bucket: what a reader joins the
         device's ``XLA Modules`` event to.
         Inside it the host's work has three parts, ``engine.dispatch.fill``
-        (the numpy inputs), ``.place`` (host to device) and ``.launch`` (the
-        jitted call). The caller overlaps host
+        (the packed inputs, in numpy), ``.place`` (host to device: one
+        array a greedy step, two where a row samples, counted in
+        ``placed_inputs``) and ``.launch`` (the jitted call). The caller overlaps host
         work (scheduling, output assembly for earlier steps) with the
         device, then materializes via ``np.asarray``. A batch whose longest
         row is one token is the decode program; anything else is the ragged
@@ -1020,6 +1101,7 @@ class ModelRunner:
                 span.set_metadata(step=step, program=program)
             with loop_phase(clock, "engine.dispatch.place"):
                 inputs = [self._place(x) for x in arrays]
+            self.placed_inputs += len(inputs)
             if cold:
                 # jit compiles lazily: the cache miss pays its trace+compile
                 # wall INSIDE the fn(...) call below (only execution stays
@@ -1041,10 +1123,11 @@ class ModelRunner:
 
     def _fill_inputs(self, rows, sample_rows, masks):
         """The numpy inputs of the step program that serves ``rows``, filled
-        row by row (``engine.dispatch.fill``): the program's signature
-        (``greedy`` as the rows turned out), whether it is a ring prefill,
-        the arrays in the program's argument order, and whether the
-        multimodal pair and the logit mask are among them."""
+        row by row and packed (``engine.dispatch.fill``): the program's
+        signature (``greedy`` as the rows turned out), whether it is a ring
+        prefill, the packed inputs (``pack_step_inputs``: one array, two
+        where a row samples) with the multimodal pair and the logit mask
+        behind them where the step has them, and whether it has them."""
         t_max = max(length for _, _, length in rows)
         sig = self.bucket_of(rows)
         b, t, nblk = sig.b, sig.t, sig.nblk
@@ -1076,20 +1159,10 @@ class ModelRunner:
                 rpm.bypassed.inc()
 
         masked = masks is not None and any(m is not None for m in masks)
-        tokens = np.zeros((b, t), np.int32)
-        q_start = np.zeros((b,), np.int32)
-        q_len = np.zeros((b,), np.int32)
-        bt = np.zeros((b, nblk), np.int32)
-        slots = np.zeros((b,), np.int32)
+        per_row = _padding_rows(b, t, nblk)
+        (tokens, q_start, q_len, bt, slots, temp, top_k, top_p, fp, pp, rp,
+         do_sample, from_slot) = per_row
         fast_greedy = True  # padding rows (temp 0, rp 1) are greedy-compatible
-        temp = np.zeros((b,), np.float32)
-        top_k = np.zeros((b,), np.int32)
-        top_p = np.ones((b,), np.float32)
-        fp = np.zeros((b,), np.float32)
-        pp = np.zeros((b,), np.float32)
-        rp = np.ones((b,), np.float32)
-        do_sample = np.zeros((b,), bool)
-        from_slot = np.zeros((b,), bool)
 
         for i, (seq, start, length) in enumerate(rows):
             # Decode rows only (start at/after the prefill target): a
@@ -1152,8 +1225,7 @@ class ModelRunner:
                     logit_mask[i, ~m] = -1e30
         if not fast_greedy:
             sig = dataclasses.replace(sig, greedy=False)
-        arrays = [tokens, q_start, q_len, bt, slots, temp, top_k, top_p, fp,
-                  pp, rp, do_sample, from_slot]
+        arrays = list(pack_step_inputs(*per_row, greedy=fast_greedy))
         if mm:
             arrays += [emb_override, emb_mask]
         if masked:
@@ -1392,7 +1464,8 @@ class ModelRunner:
             if key in self._step_fns:
                 return True
             fn = self.step_fn(b, t, nblk, False, sig.greedy, False, False)
-            toks, *_rest = self._run_step(fn, self._padding_inputs(b, t, nblk))
+            toks, *_rest = self._run_step(
+                fn, self._padding_inputs(b, t, nblk, sig.greedy))
             np.asarray(toks)
         self._ledger.record(sig, time.perf_counter() - t0, source="warmup")
         return False
@@ -2096,6 +2169,7 @@ class EngineCore:
         # the step's ``engine.finalize.wait`` and its ``engine.record``.
         with loop_phase(self.loop_clock, "engine.dispatch"):
             pending = self._dispatch_plan(plan, self.metrics.num_steps)
+        self.metrics.placed_inputs = self.runner.placed_inputs
         if self.sched_led.enabled:
             with loop_phase(self.loop_clock, "engine.plan"):
                 pending.sched = self._sched_context(plan)

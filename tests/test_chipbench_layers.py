@@ -502,3 +502,27 @@ def test_routed_reader_finds_nothing_on_a_program_without_the_counters(
     c0, c1 = _routed_counters()     # the counters, and a trace without the ops
     if name == "moe.expert_gemm_roofline_pct":
         assert reader.read(_ctx(c0, c1, trace=trace)) is None
+
+
+def _placed(steps: int, placed: int | None):
+    c0, c1 = {"num_steps": 100}, {"num_steps": 100 + steps}
+    if placed is not None:
+        c0["placed_inputs"], c1["placed_inputs"] = 1_300, 1_300 + placed
+    return _ctx(c0, c1)
+
+
+@pytest.mark.parametrize("ctx, expect", [
+    # 1,000 steps: thirteen arrays a step (what the counter would have read
+    # before the inputs were packed), one packed array a step, a window in
+    # which one step in ten was cut into two programs and one in twenty had
+    # a row that samples
+    (_placed(1_000, 13_000), 13.0),
+    (_placed(1_000, 1_000), 1.0),
+    (_placed(1_000, 1_150), 1.15),
+    # no step in the window; a program without the counter (the parent's)
+    (_placed(0, 0), None),
+    (_placed(1_000, None), None),
+], ids=["thirteen", "one", "cut_and_sampled", "no_steps", "no_counter"])
+def test_placed_inputs_per_step_on_a_hand_made_context(ctx, expect):
+    value = measure.load_reader("engine.placed_inputs_per_step").read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))

@@ -243,7 +243,7 @@ def test_step_program_name_carries_the_bucket(runner, t, greedy, expect):
     text = fn.lower(
         runner.params, runner.cache_k, runner.cache_v, runner.counts,
         runner.keys, runner.slot_toks,
-        *runner._padding_inputs(4, t, 4)).as_text()
+        *runner._padding_inputs(4, t, 4, greedy)).as_text()
     assert f"module @{expect} " in text, text[:200]
 
 

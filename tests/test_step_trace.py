@@ -91,7 +91,7 @@ def test_another_grouped_matmul_under_the_scope_keeps_the_phase(monkeypatch,
     text = fn.lower(
         runner.params, runner.cache_k, runner.cache_v, runner.counts,
         runner.keys, runner.slot_toks,
-        *runner._padding_inputs(4, 1, 4)).compile().as_text()
+        *runner._padding_inputs(4, 1, 4, True)).compile().as_text()
     assert "ragged" not in text
     table = phase_table(text)
     experts = {n for n, p in table.items() if p == "moe_experts"}

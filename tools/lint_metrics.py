@@ -47,6 +47,9 @@ PROVIDER_METRICS = {
         "ttft_count", "ttft_inbox_s", "ttft_queue_s", "ttft_prefill_s",
         "num_waiting", "num_running", "kv_usage", "kv_total_blocks",
         "num_steps", "prefill_tokens", "decode_tokens",
+        # Host-to-device placements of step inputs (ModelRunner.dispatch):
+        # over num_steps, one a greedy step since the inputs are packed.
+        "placed_inputs",
         "requests_finished", "preemptions", "prefix_hit_rate",
         "spec_proposed", "spec_accepted", "deadline_cancelled",
         "session_remote_resumes", "stream_ckpt_resumes",
