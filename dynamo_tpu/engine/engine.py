@@ -242,6 +242,8 @@ def _padding_rows(b: int, t: int, nblk: int) -> tuple:
 _RECORD_SPAN_COUNTS = (
     "programs", "live_tokens", "logit_rows", "attn_q_ctx", "kv_blocks_walked")
 _RECORD_SPAN_MOE = ("moe_layer_steps", "moe_rows", "moe_experts_touched")
+_RECORD_SPAN_SSM = ("ssm_layer_steps", "ssm_live_tokens",
+                    "ssm_scanned_positions", "ssm_state_rows")
 
 
 @jax.jit
@@ -2470,7 +2472,8 @@ class EngineCore:
         if span is not None and jax.profiler.TraceAnnotation.is_enabled():
             span.set(**{k: counts[k] for k in _RECORD_SPAN_COUNTS},
                      **(dict(zip(_RECORD_SPAN_MOE, moe)) if moe else {}),
-                     **(dict(zip(SSM_COUNTS, ssm)) if ssm else {}))
+                     **({k: counts[k] for k in _RECORD_SPAN_SSM}
+                        if ssm else {}))
             self.traced_programs.update(pending.programs)
         if self.sched_led.enabled:
             info = pending.sched or {}

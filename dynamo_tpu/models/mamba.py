@@ -6,10 +6,11 @@ of blocks but one row of a pool, ``{"state": float32 [M, slots + 1, H, P,
 N], "conv": [M, slots + 1, (K - 1) x C]}`` for the model's M Mamba layers, named
 by the slot the sequence holds from admission to its end (the sampling
 state's slot, engine/scheduler.py ``Seq.slot``); the last row is the trash
-row that a padded row of a step reads and writes. The pool is carried through
-the step and donated like K and V: a layer gathers its rows' states
-``[B, ...]``, and writes them back with one scatter on the buffer itself.
-Nothing else in a step program has the pool's shape.
+row that a padded row of a step names (its convolution tail is written
+there; the one-token update's kernel moves no state for it). The pool is
+carried through the step and donated like K and V: a layer gathers its
+rows' states ``[B, ...]``, and writes them back with one scatter on the
+buffer itself. Nothing else in a step program has the pool's shape.
 
 A row whose ``q_start`` is 0 starts from zeros: the program decides that,
 no host call clears a slot, and a sequence that is preempted and recomputed
@@ -233,14 +234,19 @@ def _token_rows(lay, n: int):
     return lay.tok_row, lay.tok_off, lay.row_tok[:, 0]
 
 
-def _update_rows(state, layer, slots, a, dx, bm, cm, impl: str):
-    """The one-token recurrence of B rows on the pool, in place: the kernel
-    (ops/ssm_update.py) or, for ``impl`` "jnp", a gather, ``_scan_one``'s
-    arithmetic and a scatter."""
+def _update_rows(state, layer, slots, one, a, dx, bm, cm, impl: str):
+    """The one-token recurrence on the pool, in place, of those of B rows
+    that ``one`` marks: the kernel (ops/ssm_update.py), which moves those
+    rows' state and no other's, or, for ``impl`` "jnp", a gather of all B,
+    ``_scan_one``'s arithmetic (the identity for the others: ``a`` 1, ``dx``
+    0) and a scatter."""
     if impl != "jnp":
         from dynamo_tpu.ops.ssm_update import update_rows
 
-        return update_rows(state, layer, slots, a, dx, bm, cm,
+        # (an int32 whether the layer is a number, in the leading group, or
+        # the scan's: one trace and one lowering of the kernel a program)
+        return update_rows(state, jnp.asarray(layer, jnp.int32), slots, one,
+                           a, dx, bm, cm,
                            interpret=impl == "pallas_interpret")
     y, s1 = _scan_one(state[layer, slots], a, dx, bm, cm)
     return state.at[layer, slots].set(s1), y
@@ -284,12 +290,12 @@ def mixer(cfg: ModelConfig, lp, layer, u, ssm, *, lay, slots, q_start, q_len,
         cm = xbc[:, d + g * ns:].reshape(n, g, ns)
         neg_a = -jnp.exp(lp["ssm_A_log"])
         # Rows of one token, all at once. A row of none or of several is
-        # left as it is here (a = 1, nothing added).
+        # left as it is here (a = 1, nothing added; the kernel passes it by).
         one = q_len == 1
         dt1 = jnp.where(one[:, None], dt[starts], 0.0)             # [B, H]
         a1 = jnp.where((one & fresh)[:, None], 0.0, jnp.exp(dt1 * neg_a))
         state, y1 = _update_rows(
-            ssm["state"], layer, slots, a1,
+            ssm["state"], layer, slots, one, a1,
             dt1[:, :, None] * x[starts].astype(jnp.float32),
             bm[starts].astype(jnp.float32), cm[starts].astype(jnp.float32),
             impl)

@@ -289,7 +289,7 @@ class SchedLedger:
         self.kv_blocks_walked_total = 0
         # layer steps, rows, touched, largest, streamed layer steps
         self.moe_totals = [0, 0, 0, 0, 0]
-        self.ssm_totals = [0, 0, 0, 0]
+        self.ssm_totals = [0] * len(SSM_COUNTS)
         self.padding_flops_total = 0.0
         self.padding_bytes_total = 0.0
         self.hol_stall_seconds_total = 0.0
@@ -323,7 +323,7 @@ class SchedLedger:
             self.kv_blocks_live_total = 0
             self.kv_blocks_walked_total = 0
             self.moe_totals = [0, 0, 0, 0, 0]
-            self.ssm_totals = [0, 0, 0, 0]
+            self.ssm_totals = [0] * len(SSM_COUNTS)
             self.padding_flops_total = 0.0
             self.padding_bytes_total = 0.0
             self.hol_stall_seconds_total = 0.0
@@ -384,7 +384,7 @@ class SchedLedger:
         kv_blocks_live: int = 0,
         kv_blocks_walked: int = 0,
         moe: tuple[int, int, int, int, int] | None = None,
-        ssm: tuple[int, int, int, int] | None = None,
+        ssm: tuple[int, ...] | None = None,
         live_flops: float = 0.0,
         sched_flops: float = 0.0,
         live_bytes: float = 0.0,
@@ -596,7 +596,8 @@ def get_sched_ledger() -> SchedLedger:
 #: A step's counts of its recurrent layers' work (``step_counts`` with
 #: ``ssm_layers``), in the order the ledger totals them.
 SSM_COUNTS = ("ssm_layer_steps", "ssm_live_tokens", "ssm_scanned_positions",
-              "ssm_state_rows")
+              "ssm_state_rows", "ssm_update_rows_given",
+              "ssm_update_rows_moved")
 
 
 def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
@@ -641,8 +642,12 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
       times those layers), ``ssm_live_tokens`` (``live_tokens``),
       ``ssm_scanned_positions`` (the positions the mixer computes: a
       program's token bucket ``sig.n`` and, for each row of several tokens,
-      the ``t`` positions of its blocked scan) and ``ssm_state_rows`` (the
-      rows whose state is read and written, times the layers).
+      the ``t`` positions of its blocked scan), ``ssm_state_rows`` (the
+      rows whose state is read and written, times the layers), and of the
+      one-token update's kernel (ops/ssm_update.py) ``ssm_update_rows_given``
+      (the rows its grid has, a program's bucket ``sig.b``, times the layers)
+      and ``ssm_update_rows_moved`` (the rows of one token among them, whose
+      state it moves, times the layers; the others cost it no byte).
 
     ``windows`` has the layers that have attention, and ``layers`` below
     counts those: the KV cache's layers.
@@ -653,7 +658,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
     kinds: list[str] = []
     programs = pf_rows = n_dec = pf_tokens = dec_tokens = 0
     live = logit_rows = sched = rect = sched_rows = 0
-    blocks = walked = q_ctx = table_q = table_blocks = scanned = 0
+    blocks = walked = q_ctx = table_q = table_blocks = scanned = ones = 0
     dec_left = dec_rows
     for sig, rows, *_ in batches:
         if not rows:
@@ -679,6 +684,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
         for _seq, start, length in rows:
             end = start + length
             live += length
+            ones += length == 1
             used = -(-end // bs)
             blocks += used
             for w, count in layer_kinds:
@@ -717,6 +723,8 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
         "ssm_live_tokens": live if ssm_layers else 0,
         "ssm_scanned_positions": scanned if ssm_layers else 0,
         "ssm_state_rows": logit_rows * ssm_layers,
+        "ssm_update_rows_given": sched_rows * ssm_layers,
+        "ssm_update_rows_moved": ones * ssm_layers,
     }
 
 

@@ -526,3 +526,29 @@ def _placed(steps: int, placed: int | None):
 def test_placed_inputs_per_step_on_a_hand_made_context(ctx, expect):
     value = measure.load_reader("engine.placed_inputs_per_step").read(ctx)
     assert value == (None if expect is None else pytest.approx(expect))
+
+
+def _update_rows(given: int | None, moved: int = 0):
+    c0 = {"sched": {}} if given is None else {"sched": {
+        "ssm_update_rows_given_total": 1_200,
+        "ssm_update_rows_moved_total": 900}}
+    c1 = {"sched": {}} if given is None else {"sched": {
+        "ssm_update_rows_given_total": 1_200 + given,
+        "ssm_update_rows_moved_total": 900 + moved}}
+    return _ctx(c0, c1)
+
+
+@pytest.mark.parametrize("ctx, expect", [
+    # 1,000 programs of 15 layers: 16-row buckets with 9 rows of one token;
+    # full 8-row buckets; chunks alone, every row passed by
+    (_update_rows(16 * 15_000, 9 * 15_000), 100.0 * 7 / 16),
+    (_update_rows(8 * 15_000, 8 * 15_000), 0.0),
+    (_update_rows(8 * 15_000, 0), 100.0),
+    # no program with recurrent layers in the window (every other cell:
+    # the counts are there and stay 0); a program without the counts
+    (_update_rows(0, 0), None),
+    (_update_rows(None), None),
+], ids=["nine_of_sixteen", "full", "chunks_alone", "no_ssm", "no_counter"])
+def test_update_rows_skipped_pct_on_a_hand_made_context(ctx, expect):
+    value = measure.load_reader("ssm.update_rows_skipped_pct").read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))

@@ -317,15 +317,27 @@ def test_blocked_scan_equals_the_recurrence(t, block):
                                rtol=2e-4)
 
 
-def test_a_padded_row_touches_the_trash_row_alone(tiny, served):
+@pytest.mark.parametrize("attn_impl, cuts", [
+    ("dense", [16, 4]), ("pallas_interpret", [16, 4]),
+    ("pallas_interpret", [16, 1, 1])])
+def test_a_padded_row_touches_the_trash_row_alone(tiny, served, attn_impl,
+                                                  cuts):
+    """Of the pool a step changes its live rows' slots and, for its padded
+    rows, the trash row and nothing else; and of the trash row the
+    convolution tail alone: its state is what it was, whether the one-token
+    update is the kernel's (which moves the live rows' state and no other:
+    PR 49), in a chunk program or a decode program, or ``jax.numpy``'s (the
+    identity written back)."""
     cfg, _model, params = tiny
     tokens, _ref = served
     pool = jax.tree.map(lambda a: a + 7.0, mamba.zeros_state(cfg, 3))
-    _, after = _serve(cfg, params, tokens[:20], [16, 4], slot=1, ssm=pool)
+    _, after = _serve(cfg, params, tokens[:sum(cuts)], cuts, slot=1, ssm=pool,
+                      attn_impl=attn_impl)
     for leaf in ("state", "conv"):
         a = np.asarray(after[leaf])
         assert (a[:, [0, 2]] == 7.0).all()          # other sequences' rows
         assert not (a[:, 1] == 7.0).all()           # the live row's
+    assert (np.asarray(after["state"])[:, 3] == 7.0).all()     # the trash row
 
 
 # ---------------------------------------------------------------------------

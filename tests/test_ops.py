@@ -758,6 +758,94 @@ def test_step_updates_the_state_pool_in_place_on_v5e(v5e_device, b, t):
     assert "ssm_update" in text
 
 
+# -- the one-token update of the state pool (ops/ssm_update.py), interpreted --
+
+def _ssm_update_case(b: int, ones: int, chunks: bool, seed: int):
+    """A pool of 2 layers x (b + 3 slots and the trash row) and a step's B
+    rows: ``ones`` rows of one token at places spread over the bucket when
+    ``chunks`` (rows of several tokens lie between and behind them, each on
+    a slot of its own: a prompt's last chunk of one token does not lead),
+    leading it otherwise; the rest is padding and names the trash row."""
+    rng = np.random.default_rng(seed)
+    h, p, n, g = 4, 8, 128, 2
+    slots_n = b + 3
+    pool = jnp.asarray(rng.standard_normal((2, slots_n + 1, h, p, n)),
+                       jnp.float32)
+    perm = rng.permutation(slots_n)[:b].astype(np.int32)
+    one = np.zeros(b, bool)
+    if chunks and 0 < ones < b:
+        one[np.sort(rng.choice(np.arange(1, b), ones, replace=False))] = True
+    else:
+        one[:ones] = True
+    if chunks:
+        slots = perm                            # every row a sequence
+    else:
+        slots = np.where(one, perm, slots_n).astype(np.int32)
+    a = jnp.asarray(rng.uniform(0.2, 1.0, (b, h)), jnp.float32)
+    a = a.at[0].set(0.0)                        # a row that starts from zeros
+    dx = jnp.asarray(rng.standard_normal((b, h, p)), jnp.float32)
+    bm = jnp.asarray(rng.standard_normal((b, g, n)), jnp.float32)
+    cm = jnp.asarray(rng.standard_normal((b, g, n)), jnp.float32)
+    return pool, jnp.asarray(slots), jnp.asarray(one), a, dx, bm, cm
+
+
+@pytest.mark.parametrize("chunks", [False, True], ids=["padding", "chunks"])
+@pytest.mark.parametrize("ones", ["0", "1", "b-1", "b"])
+@pytest.mark.parametrize("b", [8, 16])
+def test_ssm_update_moves_the_one_token_rows_alone(b, ones, chunks):
+    """The kernel against ``_scan_one`` on the rows of one token: ``y`` and
+    their slots equal to the tolerance of the blocked scan's test; every
+    other slot of the pool, the other layer and **the trash row** bit for
+    bit what they were (rows of several tokens and padded rows cost the
+    kernel nothing and it writes nothing for them); with no row of one
+    token the whole pool is bit for bit what it was."""
+    from dynamo_tpu.models import mamba
+    from dynamo_tpu.ops.ssm_update import update_rows
+
+    k = {"0": 0, "1": 1, "b-1": b - 1, "b": b}[ones]
+    pool, slots, one, a, dx, bm, cm = _ssm_update_case(
+        b, k, chunks, seed=b * 10 + k + chunks)
+    layer = 1
+    before = np.asarray(pool)
+    got, y = update_rows(pool, layer, slots, one, a, dx, bm, cm,
+                         interpret=True)
+    got, y = np.asarray(got), np.asarray(y)
+    live = np.flatnonzero(np.asarray(one))
+    assert len(live) == k
+    want_y, want_s = mamba._scan_one(
+        jnp.asarray(before[layer, np.asarray(slots)]), a, dx, bm, cm)
+    moved = np.asarray(slots)[live]
+    np.testing.assert_allclose(got[layer, moved], np.asarray(want_s)[live],
+                               atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(y[live], np.asarray(want_y)[live], atol=2e-4,
+                               rtol=2e-4)
+    assert (y[~np.asarray(one)] == 0).all()
+    rest = np.ones(before.shape[:2], bool)
+    rest[layer, moved] = False
+    assert not rest[layer, moved].any() and rest[layer, -1]    # the trash row
+    assert np.array_equal(got[rest], before[rest])
+    if k:
+        assert not np.array_equal(got[layer, moved], before[layer, moved])
+    else:
+        assert np.array_equal(got, before)
+
+
+def test_live_steps_name_the_marked_rows_in_order_then_the_last_again():
+    from dynamo_tpu.ops.ssm_update import live_steps
+
+    one = jnp.asarray([0, 1, 0, 0, 1, 1, 0, 1], bool)
+    slots = jnp.arange(8, dtype=jnp.int32) * 3 + 2
+    rows, row_slots, n = live_steps(one, slots)
+    assert int(n) == 4
+    assert np.asarray(rows).tolist() == [1, 4, 5, 7, 7, 7, 7, 7]
+    assert np.asarray(row_slots).tolist() == [5, 14, 17, 23, 23, 23, 23, 23]
+    rows, row_slots, n = live_steps(jnp.zeros(8, bool), slots)
+    assert int(n) == 0 and np.asarray(rows).tolist() == [0] * 8
+    assert np.asarray(row_slots).tolist() == [2] * 8
+    rows, _, n = live_steps(jnp.ones(8, bool), slots)
+    assert int(n) == 8 and np.asarray(rows).tolist() == list(range(8))
+
+
 # -- the streaming expert kernel (ops/moe_stream.py) on the described v5e ------
 
 def _on_a_tpu(monkeypatch):

@@ -369,6 +369,52 @@ def test_the_one_count_on_windowed_rows():
     assert c["table_blocks"] == 8 * 512 and c["kinds"] == ("mixed",)
 
 
+@pytest.mark.parametrize("kind, b, t, rows, ssm_layers, given, moved", [
+    # a decode program of 16 rows with 9 live: the grid has 16 rows a
+    # layer, the kernel moves 9
+    ("decode", 16, 1, [(None, 40 + i, 1) for i in range(9)], 15,
+     16 * 15, 9 * 15),
+    # a mixed program of 8 rows: three decode rows lead, two chunks follow,
+    # then a prompt's last chunk of one token, which does not lead and is
+    # moved all the same; the chunks are the blocked scan's
+    ("mixed", 8, 64, [(None, 700, 1), (None, 90, 1), (None, 5, 1),
+                      (None, 0, 64), (None, 64, 37), (None, 128, 1)], 15,
+     8 * 15, 4 * 15),
+    # chunks alone: the grid is given its rows and moves none
+    ("mixed", 8, 64, [(None, 0, 64), (None, 64, 20)], 15, 8 * 15, 0),
+    # a model without recurrent layers: both are zero, as SSM_COUNTS are
+    ("decode", 16, 1, [(None, 40 + i, 1) for i in range(9)], 0, 0, 0),
+    ("mixed", 8, 64, [(None, 700, 1), (None, 0, 64)], 0, 0, 0),
+], ids=["decode", "mixed", "chunks_alone", "no_ssm_decode", "no_ssm_mixed"])
+def test_the_one_count_of_the_update_rows(kind, b, t, rows, ssm_layers,
+                                          given, moved):
+    """PR 49: what the one-token update's grid was given (a program's
+    bucket of rows x the recurrent layers) and what it moved (the rows of
+    one token x the layers), in the step's one count, and summed by the
+    ledger beside the four other counts of the recurrent layers."""
+    from dynamo_tpu.obs.compile_ledger import BucketSig
+    from dynamo_tpu.obs.sched_ledger import SSM_COUNTS, SchedLedger
+
+    sig = BucketSig(kind, b, t, 512, True, "bfloat16")
+    dec = sum(1 for r in rows if r[2] == 1)
+    c = step_counts([(sig, rows, None, None, None)], 16, (0,) * 5,
+                    dec_rows=dec, ssm_layers=ssm_layers)
+    assert c["ssm_update_rows_given"] == given
+    assert c["ssm_update_rows_moved"] == moved
+    assert c["ssm_state_rows"] == len(rows) * ssm_layers
+    assert SSM_COUNTS[-2:] == ("ssm_update_rows_given",
+                               "ssm_update_rows_moved")
+    led = SchedLedger()
+    for _ in range(2):
+        led.record_step(wall_s=0.01, kinds=c["kinds"],
+                        ssm=tuple(c[k] for k in SSM_COUNTS))
+    snap = led.snapshot()
+    assert snap["ssm_update_rows_given_total"] == 2 * given
+    assert snap["ssm_update_rows_moved_total"] == 2 * moved
+    led.reset()
+    assert led.snapshot()["ssm_update_rows_given_total"] == 0
+
+
 # ---------------------------------------------------------------------------
 # the readers where there is nothing to read, and on hand-made contexts
 # ---------------------------------------------------------------------------

@@ -167,6 +167,19 @@ COMPILE_METRICS = (
     "xla_compile_warmup_buckets",
 )
 
+# The recurrent layers' step counts (obs/sched_ledger.py SSM_COUNTS), each a
+# ``<name>_total`` leaf of the engine provider's ``sched`` section: what the
+# mixers computed, and of the one-token update's kernel the rows its grid
+# was given and the rows whose state it moved. Same bidirectional drift rule.
+SCHED_SSM_COUNTS = (
+    "ssm_layer_steps",
+    "ssm_live_tokens",
+    "ssm_scanned_positions",
+    "ssm_state_rows",
+    "ssm_update_rows_given",
+    "ssm_update_rows_moved",
+)
+
 # The scheduling-ledger family (obs/sched_ledger.py SchedMetrics):
 # per-step goodput/padding-waste gauges, admission/preemption cause
 # counters, and the HOL-stall histogram. Same bidirectional drift rule
@@ -549,6 +562,43 @@ def _lint_sched_metrics(root: Path, problems: list[str]) -> None:
             "does not register it")
 
 
+def _module_tuple(path: Path, name: str) -> set[str] | None:
+    """The string constants of the module-level tuple ``name`` (None if the
+    module or the assignment isn't found — partial trees in tests)."""
+    try:
+        tree = ast.parse(path.read_text(), filename=str(path))
+    except (OSError, SyntaxError):
+        return None
+    for node in tree.body:
+        if (isinstance(node, ast.Assign) and isinstance(node.value, ast.Tuple)
+                and any(isinstance(t, ast.Name) and t.id == name
+                        for t in node.targets)):
+            return {e.value for e in node.value.elts
+                    if isinstance(e, ast.Constant) and isinstance(e.value, str)}
+    return None
+
+
+def _lint_sched_ssm_counts(root: Path, problems: list[str]) -> None:
+    """SCHED_SSM_COUNTS must match obs/sched_ledger.py SSM_COUNTS, whose
+    names the ledger's snapshot exports with ``_total`` behind them."""
+    actual = _module_tuple(root / "obs" / "sched_ledger.py", "SSM_COUNTS")
+    if actual is None:
+        return
+    declared = set(SCHED_SSM_COUNTS)
+    for key in sorted(actual - declared):
+        problems.append(
+            f"obs/sched_ledger.py SSM_COUNTS has {key!r} but it is missing "
+            "from tools/lint_metrics.py SCHED_SSM_COUNTS")
+    for key in sorted(declared - actual):
+        problems.append(
+            f"SCHED_SSM_COUNTS declares {key!r} but obs/sched_ledger.py "
+            "SSM_COUNTS does not have it")
+    for key in sorted(declared):
+        if not NAME_RE.match(f"engine_sched_{key}_total"):
+            problems.append(
+                f"SCHED_SSM_COUNTS: {key} does not match [a-z][a-z0-9_]*")
+
+
 def _lint_fleet_metrics(root: Path, problems: list[str]) -> None:
     """FLEET_METRICS + SLO_METRICS together must match what obs/fleet.py
     actually registers — same no-silent-drift rule as KV_TRANSFER_METRICS.
@@ -707,6 +757,7 @@ def lint_tree(root: Path | None = None) -> list[str]:
     _lint_ring_prefill_metrics(root, problems)
     _lint_compile_metrics(root, problems)
     _lint_sched_metrics(root, problems)
+    _lint_sched_ssm_counts(root, problems)
     _lint_stream_ckpt_metrics(root, problems)
     _lint_mem_metrics(root, problems)
     _lint_fleet_metrics(root, problems)
