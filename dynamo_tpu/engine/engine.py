@@ -68,6 +68,7 @@ from dynamo_tpu.models.config import ModelConfig, resolve_model_config
 from dynamo_tpu.obs.compile_ledger import (
     WARMUP_MODES,
     BucketSig,
+    attends_tokens,
     enumerate_buckets,
     get_compile_ledger,
     pack_rows,
@@ -2452,7 +2453,8 @@ class EngineCore:
         of a trace to join to the step's programs by ``step``."""
         counts = step_counts(pending.batches, self.engine_cfg.block_size,
                              self._windows, dec_rows=pending.dec_rows,
-                             ssm_layers=self._ssm_layers)
+                             ssm_layers=self._ssm_layers,
+                             attn_tokens=attends_tokens(self.engine_cfg))
         ssm = (tuple(counts[k] for k in SSM_COUNTS) if self._ssm_layers
                else None)
         pc = self.sched.preemption_count

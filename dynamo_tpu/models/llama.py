@@ -543,10 +543,12 @@ class TokenLayout(NamedTuple):
     dense layers run over tokens; attention runs over rows. ``tok_row`` /
     ``tok_off`` name each token's rectangle position and ``row_tok`` each
     rectangle position's token; padding on either side points at some live
-    neighbour, whose value is computed twice and read by nobody.
+    neighbour, whose value is computed twice and read by nobody. The paged
+    kernel needs neither move: it finds a row's queries among the tokens by
+    the row's first token, ``starts`` (``_attention``).
 
     With ``N == B*T`` the packing is the rectangle itself, row-major: the
-    three index arrays are None and both moves are reshapes. That is a
+    four index arrays are None and both moves are reshapes. That is a
     decode step (T=1) and every caller that keeps a rectangle."""
 
     b: int
@@ -554,6 +556,7 @@ class TokenLayout(NamedTuple):
     tok_row: jax.Array | None = None   # [N] row of each token
     tok_off: jax.Array | None = None   # [N] offset of each token in its row
     row_tok: jax.Array | None = None   # [B, T] token at each row position
+    starts: jax.Array | None = None    # [B] first token of each row
 
     def to_rows(self, x: jax.Array) -> jax.Array:
         """[N, ...] -> [B, T, ...]."""
@@ -583,7 +586,7 @@ def token_layout(q_len: jax.Array, b: int, t: int, n: int) -> tuple[
     tok_off = jnp.clip(i - starts[tok_row], 0, t - 1)
     row_tok = jnp.minimum(
         starts[:, None] + jnp.arange(t, dtype=q_len.dtype)[None, :], n - 1)
-    return TokenLayout(b, t, tok_row, tok_off, row_tok), i < ends[-1]
+    return TokenLayout(b, t, tok_row, tok_off, row_tok, starts), i < ends[-1]
 
 
 def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, **kw):
@@ -611,7 +614,9 @@ def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
     Token-major: ``hid [N, H]``, ``positions`` and ``slot [N]``. Norms,
     the Q/K/V/O projections, rope, the scatter and the MLP run over the N
     tokens; ``q`` alone is laid out as rows ``[B, T, heads, D]`` (``lay``)
-    for attention, and the attention output packed back to ``[N, q_size]``.
+    for attention, and the attention output packed back to ``[N, q_size]``
+    (under the paged kernel a packed step's ``q`` stays ``[N, heads, D]``
+    too: ``_attention``).
     Ring attention takes K and V as rows too, and only a rectangle
     (``N == B*T``), where those moves are reshapes.
 
@@ -684,6 +689,32 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     with _perf_phase("scatter"):
         cache_k = _scatter_kv(cache_k, k, slot, layer)
         cache_v = _scatter_kv(cache_v, v, slot, layer)
+    kernel = attn_impl in ("pallas", "pallas_interpret")
+    if kernel:
+        from dynamo_tpu.ops.paged_attention import (
+            paged_attention_kernel,
+            paged_attention_sharded,
+        )
+
+        # TP: shard_map the kernel over the head axis; GSPMD's psum in the
+        # wo projection completes the TP contraction.
+        attend = partial(
+            paged_attention_kernel if tp == 1
+            else partial(paged_attention_sharded, mesh),
+            layer=layer, interpret=attn_impl == "pallas_interpret",
+            window=window)
+    if kernel and lay.starts is not None and (
+            mesh is None or mesh.shape.get("data", 1) == 1):
+        # A packed step (N < B*T) under the kernel: q goes in token-major
+        # as it is and the output comes back so. No array of B x T
+        # positions exists here, in or around the kernel. (Rows split over
+        # "data" keep the rectangle: the tokens have no batch axis.)
+        with _perf_phase("attention"):
+            attn = attend(q, cache_k, cache_v, block_tables, q_start,
+                          kv_lens, starts=lay.starts, t=lay.t)
+        with _perf_phase("proj"):
+            attn = mm(attn.reshape(n, cfg.q_size), lp["wo"])
+        return attn, cache_k, cache_v
     # The rows are gathered from q's grouped view [N, KH, REP, D], the split
     # the kernel's wrapper makes of them anyway: from [N, heads, D] the
     # compiler moves a chunk step's [B, T] rectangle twice on its way to
@@ -699,26 +730,9 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
         with _perf_phase("attention"):
             attn = ring_attention_prefill(
                 mesh, q, lay.to_rows(k), lay.to_rows(v), kv_lens)
-    elif attn_impl in ("pallas", "pallas_interpret"):
-        from dynamo_tpu.ops.paged_attention import (
-            paged_attention_kernel,
-            paged_attention_sharded,
-        )
-
-        interp = attn_impl == "pallas_interpret"
+    elif kernel:
         with _perf_phase("attention"):
-            if tp > 1:
-                # TP: shard_map the kernel over the head axis; GSPMD's
-                # psum in the wo projection completes the TP contraction.
-                attn = paged_attention_sharded(
-                    mesh, q, cache_k, cache_v, block_tables, q_start,
-                    kv_lens, layer=layer, interpret=interp, window=window,
-                )
-            else:
-                attn = paged_attention_kernel(
-                    q, cache_k, cache_v, block_tables, q_start, kv_lens,
-                    layer=layer, interpret=interp, window=window,
-                )
+            attn = attend(q, cache_k, cache_v, block_tables, q_start, kv_lens)
     else:
         with _perf_phase("gather"):
             ctx_k = _gather_kv(cache_k, block_tables, layer)

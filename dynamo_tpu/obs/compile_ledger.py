@@ -413,6 +413,14 @@ def walks_live_context(ec) -> bool:
     return getattr(ec, "attn_impl", "") in ("pallas", "pallas_interpret")
 
 
+def attends_tokens(ec) -> bool:
+    """Whether a packed step's attention is handed the step's tokens as they
+    lie, ``n`` query positions and no ``b x t`` rectangle: the kernel's
+    token-major entry (models/llama.py ``_attention``; rows split over
+    "data" keep the rectangle, the tokens having no batch axis to split)."""
+    return walks_live_context(ec) and getattr(ec, "dp", 1) == 1
+
+
 def _nblk_ladder(ec) -> list[int]:
     """Reachable block-table widths. Under the kernel one, ``max_nblk``
     (``walks_live_context``); under the dense gather ``sig_for_rows``

@@ -200,7 +200,7 @@ class SchedStepRecord:
     decode_rows: int = 0
     live_tokens: int = 0            # tokens the plan actually needed
     sched_tokens: int = 0           # tokens the dense layers computed (N)
-    rect_tokens: int = 0            # positions attention ran over (b x t)
+    rect_tokens: int = 0            # query positions attention was handed
     kv_blocks_live: int = 0         # KV blocks the rows hold (a full layer's walk)
     kv_blocks_walked: int = 0       # ... the kernel walks over all layers, windows counted
     # A routed model under moe_impl="held", summed over the step's routed
@@ -601,7 +601,7 @@ SSM_COUNTS = ("ssm_layer_steps", "ssm_live_tokens", "ssm_scanned_positions",
 
 
 def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
-                ssm_layers: int = 0) -> dict:
+                ssm_layers: int = 0, attn_tokens: bool = False) -> dict:
     """What one step did, in the program's own terms and nothing priced:
     THE walk over a step's rows, made once between plan and record
     (EngineCore._record_step). The profiler prices it, the ledger's goodput
@@ -622,8 +622,11 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
       prefill chunks, every other row decodes; a one-token prefill tail
       lands on the decode side);
     - ``live_tokens``; ``sched_tokens``, the dense layers' token bucket
-      (``sig.n`` a program); ``rect_tokens``, the ``b x t`` positions of the
-      attention rectangles; ``logit_rows`` (one a row) and
+      (``sig.n`` a program); ``rect_tokens``, the query positions attention
+      is handed: the ``b x t`` of a program's rectangle, or with
+      ``attn_tokens`` (the paged kernel takes a packed step's tokens as
+      they lie: models/llama.py ``_attention``) its token bucket too;
+      ``logit_rows`` (one a row) and
       ``sched_logit_rows`` (``sig.b`` a program);
     - ``kv_blocks_live``: ``ceil((start + length) / block_size)`` a row,
       the blocks the rows hold, what a full layer's kernel walks for them;
@@ -706,7 +709,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
                 dec_tokens += length
         sched += sig.n
         scanned += sig.n
-        rect += sig.b * sig.t
+        rect += sig.n if attn_tokens else sig.b * sig.t
         sched_rows += sig.b
         table_q += layers * sig.b * sig.t * sig.nblk * bs
         table_blocks += layers * sig.b * sig.nblk
@@ -747,13 +750,17 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
     live side where the profiler has priced it already.
     """
     from dynamo_tpu.obs import costmodel as cm
-    from dynamo_tpu.obs.compile_ledger import walks_live_context
+    from dynamo_tpu.obs.compile_ledger import (
+        attends_tokens,
+        walks_live_context,
+    )
 
     ec = engine_cfg
     if counts is None:
         counts = step_counts(
             batches, ec.block_size, model_cfg.attn_windows,
-            dec_rows=dec_rows, ssm_layers=model_cfg.layers_of("M"))
+            dec_rows=dec_rows, ssm_layers=model_cfg.layers_of("M"),
+            attn_tokens=attends_tokens(ec))
     if shapes is None:
         shapes = cm.step_shapes(
             model_cfg, block_size=ec.block_size,

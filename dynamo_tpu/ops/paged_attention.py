@@ -19,7 +19,8 @@ the chip through the server.
 Design notes (reference has no TPU analog; its one kernel is a CUDA block
 copy, lib/llm/src/kernels/block_copy.cu — paged attention itself lives
 inside vLLM/TRT-LLM, which we replace):
-- grid = (B, NQ), both parallel: a row, a chunk of its query rows. There is
+- grid = (B, NQ), both parallel (in order under the token-major entry,
+  below): a row, a chunk of its query rows. There is
   no block axis, so the block table's width specialises nothing but an
   operand's shape (a step program is compiled for ONE width, the longest
   context the engine admits: obs/compile_ledger.py ``sig_for_rows``): the
@@ -65,7 +66,20 @@ inside vLLM/TRT-LLM, which we replace):
   are float32, and the probabilities stay float32 into P.V.
 - q rows are pre-laid-out ``[B, KH, T*REP, D]`` (rep = query heads per kv
   head) outside the kernel so each head's queries are one contiguous 2D
-  slab — one MXU matmul covers all query heads of the kv head.
+  slab — one MXU matmul covers all query heads of the kv head. That is a
+  rectangle step's entry (every decode program, verify, the pipeline's
+  microbatches), where the layout is a reshape.
+- a packed step (N live tokens < B x T) has a second entry, token-major
+  (``starts``): ``q [N, H, D]`` stays in HBM like the cache, a (row, query
+  chunk) grid step copies its own tile of tokens from its row's first token
+  on, builds the slabs in VMEM (``_token_tile``), runs the same walk and
+  writes the tile's live tokens back over the queries they came from. The
+  ``[B, T]`` rectangle of ``q`` and of the output, six or seven passes of
+  B x T x H x D elements a layer around and inside the rectangle's kernel
+  (8 x 512 positions for 513 live tokens), does not exist on that path, and
+  a (row, chunk) without a live token moves nothing (PERF.md, PR 50). The
+  grid's steps run in order there ("arbitrary"): a row's last tile runs
+  into the next row's tokens.
 - quantized caches: int8 payloads copy at 1 byte/elem and go to the MXU as
   they are (exact in bf16); the per-(block, kv-head) scale multiplies that
   block's BS columns of the group's scores, and of its probabilities before
@@ -199,13 +213,14 @@ def query_chunks(t: int, *, rep: int, kh: int, d: int,
     # Halving stops while the chunk stays Mosaic-legal: a partial block's
     # second-to-minor dim must be a multiple of the dtype's min sublane
     # count (rchunk == r needs no divisibility — whole-axis blocks are
-    # always legal). Better to overshoot the soft scratch cap than emit a
-    # block shape the TPU refuses to lower.
+    # always legal), and a chunk holds whole tokens (every T the engine
+    # buckets to, a power of two, halves that way). Better to overshoot the
+    # soft scratch cap than emit a block shape the TPU refuses to lower.
     q_sub = _sublane(q_dtype)
     while ((kh * rchunk * (d + 256) * 4 > _SCRATCH_CAP_BYTES
             or rchunk > _CHUNK_ROWS)
            and rchunk % 2 == 0 and rchunk > rep
-           and (rchunk // 2) % q_sub == 0):
+           and (rchunk // 2) % q_sub == 0 and (rchunk // 2) % rep == 0):
         rchunk //= 2
     return rchunk, r // rchunk
 
@@ -250,12 +265,18 @@ def _group_blocks(rows: int, bs: int, nblk: int) -> int:
 
 
 def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
-            quant: bool, int4: bool, mm_dtype, window: int = 0):
+            quant: bool, int4: bool, mm_dtype, window: int = 0,
+            tile: int = 0):
     # A window (static, > 0) adds one scalar-prefetch operand, the block
     # each walk begins at, ahead of the others, and a lower bound in the
     # mask; with window == 0 nothing below is traced that was not before.
     if window:
         fb_ref, *refs = refs
+    # The token-major entry (static ``tile`` > 0: the tokens a query chunk
+    # holds) adds each row's first token, and takes ``q`` and the output as
+    # one array of tokens in HBM: see ``_token_tile``.
+    if tile:
+        ts_ref, *refs = refs
     if quant:
         # Scales ride the scalar-prefetch channel with the block table, so
         # dequant needs no extra DMA: the int8/int4 payload goes to the MXU
@@ -265,11 +286,17 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
     else:
         (bt_ref, qs_ref, kl_ref, ub_ref, ly_ref, *refs) = refs
         ks_ref = vs_ref = None
-    (q_ref, k_hbm, v_hbm, o_ref,
-     kbuf, vbuf, sems, acc_ref, m_ref, l_ref) = refs
+    if tile:
+        (q_hbm, k_hbm, v_hbm, o_hbm, kbuf, vbuf, sems, acc_ref, m_ref, l_ref,
+         tok_ref, q_ref, tok_sems) = refs
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, sems, acc_ref, m_ref, l_ref) = refs
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    r = q_ref.shape[2]          # rows in this q chunk (row = token*rep + q-head)
+    # Rows in this q chunk: row = token*rep + q-head in a rectangle's slab,
+    # q-head*tile + token in the slab built from a tile of tokens.
+    r = q_ref.shape[2]
     gk = gb * bs                # keys a group holds
 
     # Blocks this query chunk of the row can see: none past the row's
@@ -337,14 +364,16 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
         land(g, slot)
 
         # Causal/visibility mask is head-independent, [R, GK]: key c of the
-        # group is seen by chunk row w (query token w // rep) if
+        # group is seen by chunk row w (query token w // rep, or w % tile
+        # where the slab is a tile's) if
         # g*gk + c <= q_pos0 + (qi*r + w) // rep and g*gk + c < kv_len.
         base = lax.mul(g, _i32(gk))
         if window:
             base = lax.add(base, lax.mul(first, _i32(bs)))
         ctx = lax.broadcasted_iota(jnp.int32, (r, gk), 1)
-        tok = lax.div(lax.broadcasted_iota(jnp.int32, (r, gk), 0),
-                      lax.full((r, gk), rep, jnp.int32))
+        tok = (lax.rem if tile else lax.div)(
+            lax.broadcasted_iota(jnp.int32, (r, gk), 0),
+            lax.full((r, gk), tile or rep, jnp.int32))
         q_pos = lax.sub(lax.add(q_pos0, lax.mul(qi, _i32(r // rep))), base)
         visible = lax.bitwise_and(
             lax.le(lax.sub(ctx, tok), lax.broadcast(q_pos, (r, gk))),
@@ -453,33 +482,102 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
     # the kernel's top level (no trip for a dead step) and only the short
     # pieces around it are conditional.
     live = lax.gt(groups, _i32(0))
+    if tile:
+        load, spread, collect, store = _token_tile(
+            q_hbm, o_hbm, tok_ref, q_ref, tok_sems,
+            first=lax.add(ts_ref[b], lax.mul(qi, _i32(tile))),
+            n_live=lax.sub(lax.sub(kv_len, q_pos0), lax.mul(qi, _i32(tile))))
 
     @pl.when(live)
     def _init():
         m_ref[:] = lax.full(m_ref.shape, NEG_INF, m_ref.dtype)
         l_ref[:] = lax.full(l_ref.shape, 0.0, l_ref.dtype)
         acc_ref[:] = lax.full(acc_ref.shape, 0.0, acc_ref.dtype)
+        if tile:
+            load.start()
         fetch(_i32(0), _i32(0))
+        if tile:
+            load.wait()
+            spread()
 
     lax.fori_loop(_i32(0), groups, group, 0)
 
+    def result(ki):
+        """The chunk's output of kv head ``ki`` [R, D] (a slice: of them
+        all)."""
+        l = l_ref[ki, :, :1]
+        l = lax.select(lax.eq(l, lax.full(l.shape, 0.0, l.dtype)),
+                       lax.full(l.shape, 1.0, l.dtype), l)    # all-masked rows → 0
+        acc = acc_ref[ki]
+        return lax.convert_element_type(
+            lax.div(acc, lax.broadcast_in_dim(
+                l, acc.shape, tuple(range(acc.ndim)))), q_ref.dtype)
+
     @pl.when(live)
     def _finish():
+        if tile:
+            collect(result(slice(None)))
+            store.start()
+            store.wait()
+            return
+
         def one(ki, c):
-            l = l_ref[ki, :, :1]
-            l = lax.select(lax.eq(l, lax.full(l.shape, 0.0, l.dtype)),
-                           lax.full(l.shape, 1.0, l.dtype), l)    # all-masked rows → 0
-            o_ref[0, ki] = lax.convert_element_type(
-                lax.div(acc_ref[ki], lax.broadcast_in_dim(
-                    l, acc_ref.shape[1:], (0, 1))), o_ref.dtype)
+            o_ref[0, ki] = result(ki)
             return c
         lax.fori_loop(0, kh, one, 0)
 
-    @pl.when(lax.eq(groups, _i32(0)))
-    def _dead():
-        # Nothing to walk (a padding row, a chunk of padding): the step
-        # costs its grid slot and zeros.
-        o_ref[...] = lax.full(o_ref.shape, 0.0, o_ref.dtype)
+    if not tile:
+        @pl.when(lax.eq(groups, _i32(0)))
+        def _dead():
+            # Nothing to walk (a padding row, a chunk of padding): the step
+            # costs its grid slot and zeros. (Of a tile of tokens it costs
+            # the slot: nothing is copied in and nothing written.)
+            o_ref[...] = lax.full(o_ref.shape, 0.0, o_ref.dtype)
+
+
+def _token_tile(q_hbm, o_hbm, tok_ref, q_ref, sems, *, first, n_live):
+    """How a (row, query chunk) of the token-major entry gets its queries
+    and leaves its output: ``q_hbm [N + tile, HP, D]`` holds the step's
+    tokens packed row after row, the chunk's are the ``tile`` from ``first``
+    on, of which ``n_live`` (or more: then all) are the row's own; the rest
+    are a later row's or padding, computed against this row's context and
+    never written. ``o_hbm`` is the same buffer (the call aliases them).
+
+    Returns (load, spread, collect, store). ``load`` copies the tile into
+    ``tok_ref [tile, HP, D]``; ``spread()`` lays it out as the walk reads
+    it, one slab a kv head in ``q_ref [1, KH, rep * tile, D]``, row
+    ``j * tile + i`` query head ``j`` of the kv head's ``rep``, token ``i``;
+    ``collect(out)`` puts the walk's output ``[KH, rep * tile, D]`` back
+    into ``tok_ref`` token-major, and ``store`` copies the tile over the
+    tokens it came from: the live ones take their output, the others the
+    ``q`` they held, so a later row still finds its queries there. That
+    needs the grid's steps in order, which the call's
+    ``dimension_semantics`` say.
+
+    Both moves are one transpose of the tile's two leading axes, a handful
+    of equations whatever the number of heads (a kernel body's length is
+    set-up time: PERF.md, PR 49)."""
+    tile, hp, d = tok_ref.shape
+    _, kh, r, _ = q_ref.shape
+    h = kh * r // tile
+    here = pl.ds(first, tile)
+    load = pltpu.make_async_copy(q_hbm.at[here], tok_ref, sems.at[0])
+    store = pltpu.make_async_copy(tok_ref, o_hbm.at[here], sems.at[1])
+
+    def spread():
+        heads = lax.transpose(tok_ref[...], (1, 0, 2))       # [HP, tile, D]
+        q_ref[0] = lax.reshape(lax.slice_in_dim(heads, 0, h), (kh, r, d))
+
+    def collect(out):
+        heads = lax.pad(lax.reshape(out, (h, tile, d)),
+                        lax.full((), 0.0, out.dtype),
+                        ((0, hp - h, 0), (0, 0, 0), (0, 0, 0)))
+        mine = lax.lt(lax.broadcasted_iota(jnp.int32, tok_ref.shape, 0),
+                      lax.broadcast(n_live, tok_ref.shape))
+        tok_ref[...] = lax.select(mine, lax.transpose(heads, (1, 0, 2)),
+                                  tok_ref[...])
+
+    return load, spread, collect, store
 
 
 def _layer_stack(k_cache, v_cache, layer):
@@ -492,7 +590,7 @@ def _layer_stack(k_cache, v_cache, layer):
 
 
 def paged_attention_kernel(
-    q: jax.Array,             # [B, T, H, D]
+    q: jax.Array,             # [B, T, H, D]; with ``starts``: [N, H, D]
     k_cache,                  # [L, NB, BS, KH, D] — or {"q": int8
                               #   [L,NB,BS,KH,D] | uint8 packed int4
                               #   [L,NB,BS,KH,D/2], "s": f32 [L, NB, KH]}
@@ -507,9 +605,22 @@ def paged_attention_kernel(
     interpret: bool = False,
     window: int = 0,          # static; > 0: a sliding layer, query i sees
                               #   the keys j with i - j < window
+    starts: jax.Array | None = None,  # [B] int32: token-major, each row's
+                              #   first token among ``q``'s N
+    t: int | None = None,     # ... and the row bucket T (static)
 ) -> jax.Array:
     """Flash paged attention over layer ``layer`` of a block-table cache.
     Returns [B, T, H, D].
+
+    Token-major (``starts`` given): ``q [N, H, D]`` holds the rows' live
+    query tokens packed row after row, row ``i``'s ``kv_lens[i] -
+    q_start[i]`` of them from ``starts[i]`` on, as a step's dense layers
+    leave them, and the result is ``[N, H, D]`` in the same places (a token
+    of no row keeps its scaled ``q``: finite, and read by nobody). No array
+    of ``B x T`` positions exists on that path: a (row, query chunk) grid
+    step copies its own tile of tokens from HBM, as it copies its KV blocks,
+    and writes its tile back (``_token_tile``); one with no live token
+    copies nothing. The walk, the scores and the sums are the rectangle's.
 
     Under a ``window`` the walk of a (row, query chunk) starts at the first
     block any of its queries can see (``chunk_first_blocks``, one more
@@ -542,20 +653,33 @@ def paged_attention_kernel(
         v_scale = v_cache["s"][layer].astype(jnp.float32)
         k_cache, v_cache = k_cache["q"], v_cache["q"]
         int4 = k_cache.dtype == jnp.uint8            # packed marker dtype
-    b, t, h, d = q.shape
+    packed = starts is not None
+    if packed:
+        (n, h, d), b = q.shape, block_tables.shape[0]
+    else:
+        b, t, h, d = q.shape
     _, nb, bs, kh, dp = k_cache.shape
     if int4 and dp * 2 != d:
         raise ValueError(
             f"packed int4 cache trailing dim {dp} != head_dim/2 ({d}//2)")
     nblk = block_tables.shape[1]
     rep = h // kh
-    # [B, T, KH, REP, D] → [B, KH, T*REP, D]: one contiguous query slab per
-    # kv head (row r ↔ query token r // rep, query head r % rep).
-    qs = (q * (d ** -0.5)).reshape(b, t, kh, rep, d)
-    qs = qs.transpose(0, 2, 1, 3, 4).reshape(b, kh, t * rep, d)
-
     r = t * rep
     rchunk, nq = query_chunks(t, rep=rep, kh=kh, d=d, q_dtype=q.dtype)
+    tile = rchunk // rep if packed else 0
+    qs = q * (d ** -0.5)
+    if packed:
+        # A tile read from a row's first token runs into the next row's
+        # tokens and, on the last row, past N: a tile of padding behind
+        # them. The heads fill whole sublane tiles (a slice of the array in
+        # HBM is of whole tiles).
+        qs = lax.pad(qs, lax.full((), 0.0, qs.dtype), (
+            (0, tile, 0), (0, -h % _sublane(q.dtype), 0), (0, 0, 0)))
+    else:
+        # [B, T, KH, REP, D] → [B, KH, T*REP, D]: one contiguous query slab
+        # per kv head (row r ↔ query token r // rep, query head r % rep).
+        qs = qs.reshape(b, t, kh, rep, d)
+        qs = qs.transpose(0, 2, 1, 3, 4).reshape(b, kh, t * rep, d)
 
     gb = _group_blocks(rchunk, bs, nblk)   # the walk is over groups of G blocks
 
@@ -574,6 +698,8 @@ def paged_attention_kernel(
                layer.reshape(1))
     if quant:
         scalars = scalars + (k_scale, v_scale)
+    if packed:
+        scalars = (starts.astype(jnp.int32),) + scalars
     if window:
         scalars = (chunk_first_blocks(
             qs32, nq=nq, rchunk=rchunk, rep=rep, bs=bs,
@@ -585,40 +711,61 @@ def paged_attention_kernel(
     mm_dtype = jnp.bfloat16 if narrow and q.dtype == jnp.bfloat16 \
         else jnp.float32
 
+    scratch_shapes = [
+        pltpu.VMEM((2, gb * bs, kh, dp), k_cache.dtype),
+        pltpu.VMEM((2, gb * bs, kh, dp), v_cache.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((kh, rchunk, d), jnp.float32),
+        pltpu.VMEM((kh, rchunk, 128), jnp.float32),
+        pltpu.VMEM((kh, rchunk, 128), jnp.float32),
+    ]
+    # The queries and the output: a rectangle's are blocks of the pipeline,
+    # [1, KH, rchunk, D] a grid step; the tokens stay in HBM like the cache
+    # and a grid step copies its tile into scratch (the tile as it lies,
+    # the slabs the walk reads, two semaphores).
+    q_spec = pl.BlockSpec((1, kh, rchunk, d), qmap)
+    if packed:
+        q_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch_shapes += [
+            pltpu.VMEM((tile,) + qs.shape[1:], q.dtype),
+            pltpu.VMEM((1, kh, rchunk, d), q.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b, nq),
         in_specs=[
-            pl.BlockSpec((1, kh, rchunk, d), qmap),
+            q_spec,
             # The cache stays where it is, [L, NB, BS, KH, Dp] in HBM: the
             # kernel copies the blocks the table names, a group at a time.
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, kh, rchunk, d), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((2, gb * bs, kh, dp), k_cache.dtype),
-            pltpu.VMEM((2, gb * bs, kh, dp), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((kh, rchunk, d), jnp.float32),
-            pltpu.VMEM((kh, rchunk, 128), jnp.float32),
-            pltpu.VMEM((kh, rchunk, 128), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=scratch_shapes,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, kh=kh, rep=rep, gb=gb, nq=nq,
                           quant=quant, int4=int4, mm_dtype=mm_dtype,
-                          window=window),
+                          window=window, tile=tile),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, r, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            qs.shape if packed else (b, kh, r, d), q.dtype),
+        # The tokens' tiles overlap (a row's last one runs into the next
+        # row's tokens) and are written over the queries they were read
+        # from: the grid's steps run in order.
+        input_output_aliases={len(scalars): 0} if packed else {},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=(("arbitrary",) if packed
+                                 else ("parallel",)) * 2,
         ),
         interpret=interpret,
         # A name of its own in the device trace (the custom call would
         # otherwise take it from whatever scope encloses it).
         name="paged_attention",
     )(*scalars, qs, k_cache, v_cache)
+    if packed:
+        return lax.slice(out, (0, 0, 0), (n, h, d))
     # [B, KH, T*REP, D] → [B, T, H, D]
     return out.reshape(b, kh, t, rep, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
 
@@ -635,13 +782,17 @@ def paged_attention_sharded(
     layer=None,               # as paged_attention_kernel
     interpret: bool = False,
     window: int = 0,          # as paged_attention_kernel
+    starts: jax.Array | None = None,  # as paged_attention_kernel: q is
+    t: int | None = None,             #   [N, H, D], and so is the result
 ) -> jax.Array:
     """TP-sharded paged attention: shard_map the kernel over the "model"
     (head) axis so each device runs the kernel on its local heads. Heads are
     fully parallel in attention, so no collective is needed — the psum for
     TP happens in the subsequent wo projection, inserted by GSPMD.
 
-    Batch rides the "data" axis (size-1 no-op on pure-TP meshes).
+    Batch rides the "data" axis (size-1 no-op on pure-TP meshes). The
+    token-major form has no batch axis to ride it: the caller keeps the
+    rectangle where "data" splits the rows.
     """
     k_cache, v_cache, layer = _layer_stack(k_cache, v_cache, layer)
     cache_spec = P(None, None, None, "model", None)
@@ -651,28 +802,34 @@ def paged_attention_sharded(
         # Packed-int4 payloads shard identically (packing is along D).
         cache_spec = {"q": cache_spec, "s": P(None, None, "model")}
 
-    def local(q, k_cache, v_cache, block_tables, q_start, kv_lens, layer):
+    def local(q, k_cache, v_cache, block_tables, q_start, kv_lens, layer,
+              *starts):
         return paged_attention_kernel(
             q, k_cache, v_cache, block_tables, q_start, kv_lens, layer=layer,
-            interpret=interpret, window=window)
+            interpret=interpret, window=window, t=t, **(
+                {"starts": starts[0]} if starts else {}))
 
+    q_spec = (P("data", None, "model", None) if starts is None
+              else P(None, "model", None))
+    rows = () if starts is None else (starts.astype(jnp.int32),)
     fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
-            P("data", None, "model", None),
+            q_spec,
             cache_spec,
             cache_spec,
             P("data", None),
             P("data"),
             P("data"),
             P(),
-        ),
-        out_specs=P("data", None, "model", None),
+        ) + (P(),) * len(rows),
+        out_specs=q_spec,
         check_vma=False,
     )
     return fn(q, k_cache, v_cache, block_tables.astype(jnp.int32),
-              q_start.astype(jnp.int32), kv_lens.astype(jnp.int32), layer)
+              q_start.astype(jnp.int32), kv_lens.astype(jnp.int32), layer,
+              *rows)
 
 
 def select_attn_impl(requested: str = "auto") -> str:

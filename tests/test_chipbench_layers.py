@@ -552,3 +552,31 @@ def _update_rows(given: int | None, moved: int = 0):
 def test_update_rows_skipped_pct_on_a_hand_made_context(ctx, expect):
     value = measure.load_reader("ssm.update_rows_skipped_pct").read(ctx)
     assert value == (None if expect is None else pytest.approx(expect))
+
+
+def _q_positions(handed: int | None, live: int = 0):
+    c0 = {"sched": {}} if handed is None else {"sched": {
+        "rect_tokens_total": 50_000, "live_tokens_total": 7_000}}
+    c1 = {"sched": {}} if handed is None else {"sched": {
+        "rect_tokens_total": 50_000 + handed,
+        "live_tokens_total": 7_000 + live}}
+    return _ctx(c0, c1)
+
+
+@pytest.mark.parametrize("ctx, expect", [
+    # 100 b8 t512 steps of 513 live tokens beside 1,000 decode steps of one
+    # row in eight: the rectangle (4,096 positions a mixed step) and the
+    # token bucket (520)
+    (_q_positions(100 * 4_096 + 8_000, 100 * 513 + 1_000),
+     100.0 * (1 - 52_300 / 417_600)),
+    (_q_positions(100 * 520 + 8_000, 100 * 513 + 1_000),
+     100.0 * (1 - 52_300 / 60_000)),
+    # every position a live token
+    (_q_positions(8_000, 8_000), 0.0),
+    # no step in the window; a program without the count
+    (_q_positions(0, 0), None),
+    (_q_positions(None), None),
+], ids=["rectangle", "token_bucket", "full", "no_steps", "no_counter"])
+def test_q_padding_pct_on_a_hand_made_context(ctx, expect):
+    value = measure.load_reader("attn.q_padding_pct").read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))

@@ -1000,6 +1000,96 @@ def test_kexaone_decode_step_keeps_the_grouped_form_on_v5e(v5e_device,
         _step_text(monkeypatch, "k-exaone-236b-a23b-ep8-l5", 16, 1))
 
 
+@pytest.mark.parametrize("b,t,n,h,kh,window", [
+    # The cells' mixed programs: the Mistral cuts' (32 Q / 8 KV x 128),
+    # K-EXAONE's sliding and full layers (64 / 8), SmallThinker's (28 / 4:
+    # the heads padded to whole sublane tiles) and Nemotron's (32 / 2).
+    pytest.param(8, 512, 520, 32, 8, 0, id="7b-b8-t512"),
+    pytest.param(8, 16, 24, 32, 8, 0, id="7b-b8-t16"),
+    pytest.param(16, 512, 528, 64, 8, 128, id="kexaone-b16-t512-sliding"),
+    pytest.param(16, 512, 528, 64, 8, 0, id="kexaone-b16-t512-full"),
+    pytest.param(8, 512, 520, 28, 4, 4096, id="smallthinker-b8-t512"),
+    pytest.param(32, 512, 544, 32, 2, 0, id="nemotron-b32-t512"),
+])
+def test_token_major_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, n,
+                                             h, kh, window):
+    """Mosaic itself on the kernel's token-major entry at the cells' mixed
+    shapes: the tile's copies from a row's first token (no tile boundary),
+    the transposes that spread a tile over the slabs and bring the output
+    back, the output written over ``q``'s buffer. The
+    grid's steps are in order and the VMEM stays under the scoped limit."""
+    from jax.sharding import SingleDeviceSharding
+
+    import dynamo_tpu.ops.paged_attention as pa
+
+    d, bs, nblk, nb, nl = 128, 16, 512, 6817, 4
+    sh = SingleDeviceSharding(v5e_device)
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    calls = []
+    real_call = pa.pl.pallas_call
+    monkeypatch.setattr(
+        pa.pl, "pallas_call",
+        lambda kernel, *a, **kw: (calls.append(kw), real_call(kernel, *a, **kw))[1])
+    cache = abstract((nl, nb, bs, kh, d), jnp.bfloat16)
+    rows = abstract((b,), jnp.int32)
+    compiled = jax.jit(
+        lambda q, k, v, bt, qs, kl, layer, starts: paged_attention_kernel(
+            q, k, v, bt, qs, kl, layer=layer, window=window, starts=starts,
+            t=t)
+    ).lower(abstract((n, h, d), jnp.bfloat16), cache, cache,
+            abstract((b, nblk), jnp.int32), rows, rows,
+            abstract((), jnp.int32), rows).compile()
+    (kw,) = calls
+    assert kw["name"] == "paged_attention"
+    assert kw["compiler_params"].dimension_semantics == ("arbitrary",) * 2
+    assert kw["input_output_aliases"] == {
+        kw["grid_spec"].num_scalar_prefetch: 0}
+    assert _vmem_bytes(kw["grid_spec"], []) < V5E_SCOPED_VMEM_BYTES
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # nothing of the rectangle's size: the padded tokens at most
+    tile = kw["grid_spec"].scratch_shapes[6].shape[0]
+    assert tile * (h // kh) <= 512
+    shapes = _result_shapes(text)
+    assert max(int(np.prod(s)) for s in shapes) < b * t * h * d // 2, shapes
+
+
+def _result_shapes(text: str) -> set[tuple[int, ...]]:
+    """The shapes of the instructions' results in a compiled program's
+    text, the parameters' own left out."""
+    import re
+
+    return {tuple(int(x) for x in dims.split(","))
+            for dims, op in re.findall(
+                r"^\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]+)\]\S* (\S+?)\(",
+                text, re.M)
+            if op != "parameter"}
+
+
+def test_mixed_step_holds_no_rectangle_on_v5e(v5e_device, monkeypatch):
+    """The 7B cut's ``b8 t512`` mixed program compiled for the described
+    v5e: no instruction's result has the ``8 x 512 x 4096`` elements of
+    ``q``'s rectangle but what has a weight's shape (the parent's program
+    had ``bf16[8,512,32,128]``, ``[8,8,2048,128]`` and five more), and the
+    kernel's call keeps the name the trace's readers find it by. The
+    program's text only: no time is read here."""
+    import re
+
+    text = _step_text(monkeypatch, "mistral-7b-v0.3-l16", 8, 512)
+    assert re.search(r"%paged_attention\S* = bf16\[648,32,128\]", text)
+    hidden, mlp, vocab, kv = 4096, 14336, 32768, 1024
+    weights = {(hidden, hidden), (hidden, kv), (hidden, mlp), (mlp, hidden),
+               (hidden, vocab), (vocab, hidden)}
+    pool = (16, 2048, 16, 8, 128)                 # updated in place
+    big = {s for s in _result_shapes(text)
+           if int(np.prod(s)) >= 8 * 512 * hidden and s != pool
+           and tuple(x for x in s if x != 1)[-2:] not in weights}
+    assert not big, big
+
+
 def test_paged_attention_kernel_parity_at_bench_shapes():
     """Interpret-mode parity at the llama-3-8b-lite geometry the bench
     actually dispatches (kh=8, d=128, bs=16) — the configuration whose

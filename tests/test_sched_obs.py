@@ -206,6 +206,17 @@ def test_step_geometry_chunk_hand_computed():
     assert mixed["prefill_rows"] == 1 and mixed["decode_rows"] == 1
     assert mixed["live_tokens"] == 21 and mixed["sched_tokens"] == 34
     assert mixed["live_flops"] > g["live_flops"]
+    # Under the paged kernel attention is handed the packed step's 34
+    # tokens, not the 2 x 32 rectangle; with the rows split over "data" it
+    # keeps the rectangle (obs/compile_ledger.py attends_tokens).
+    for kw, handed in ((dict(attn_impl="pallas_interpret"), 34),
+                       (dict(attn_impl="pallas_interpret", tp=2), 34),
+                       (dict(attn_impl="pallas_interpret", dp=2), 64)):
+        kernel_ec = tiny_ec(**kw)
+        ksig = sig_for_rows("mixed", 1, 20, 2, kernel_ec)
+        assert (ksig.b, ksig.t, ksig.n) == (2, 32, 34)
+        assert step_geometry(mc, kernel_ec, [
+            (ksig, rows, [True], toks, None)])["rect_tokens"] == handed
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,7 @@ from dynamo_tpu.engine.prefix_pool import PrefixPool  # noqa: E402
 from dynamo_tpu.engine.scheduler import Scheduler, Seq  # noqa: E402
 from dynamo_tpu.models.config import resolve_model_config  # noqa: E402
 from dynamo_tpu.obs.compile_ledger import (  # noqa: E402
+    attends_tokens,
     pack_rows,
     sig_for_rows,
     token_bucket,
@@ -206,7 +207,10 @@ def test_replayed_trace_reaches_only_warmed_programs(cells, name, order,
         runs = [_bucket_of_batch(ec, batch) for batch in batches]
         assert runs == [sig for sig, *_ in batches]
         assert g["sched_tokens"] == sum(s.n for s in runs)
-        assert g["rect_tokens"] == sum(s.b * s.t for s in runs)
+        # what attention is handed: a packed step's tokens under the
+        # kernel (PR 50), the b x t rectangle under the dense gather
+        assert g["rect_tokens"] == sum(
+            s.n if attends_tokens(ec) else s.b * s.t for s in runs)
         assert g["live_tokens"] == sum(
             length for _, rows, *_ in batches for _, _, length in rows)
         assert g["decode_rows"] == dec_rows

@@ -359,6 +359,13 @@ def test_the_one_count_on_windowed_rows():
     assert (c["decode_rows"], c["prefill_rows"]) == (1, 1)
     assert (c["decode_tokens"], c["prefill_tokens"]) == (1, 512)
     assert (c["sched_tokens"], c["rect_tokens"]) == (520, 8 * 512)
+    # ... and where the kernel takes a packed step's tokens as they lie
+    # (PR 50), attention is handed the token bucket and no rectangle.
+    tokens = step_counts(batches, 16, (128, 0, 128), dec_rows=1,
+                         attn_tokens=True)
+    assert tokens["rect_tokens"] == 520
+    assert {k: v for k, v in tokens.items() if k != "rect_tokens"} == {
+        k: v for k, v in c.items() if k != "rect_tokens"}
     full = 701 + sum(p + 1 for p in range(300, 812))
     slid = 128 + 512 * 128             # every query sees its window, full
     assert c["attn_q_ctx"] == full + 2 * slid
