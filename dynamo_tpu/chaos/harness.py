@@ -628,7 +628,9 @@ def scenario_retire_under_load(seed: int = 1234,
     n_sessions = 2 if quick else 4
     n_bg = 3 if quick else 8
     cfg = FleetConfig(
-        workers=1, kv_store=True, speedup_ratio=50.0, lease_ttl_s=3.0,
+        # (10 s: a worker started mid-scenario registers under a lease granted
+        # before its start-up; 3 s ran out there under the full test run's load)
+        workers=1, kv_store=True, speedup_ratio=50.0, lease_ttl_s=10.0,
         # TTL far beyond the scenario: retention must survive until the
         # drain evacuates it (pop_oldest ignores TTL); both workers drain
         # at the end, so no sweep is needed for the leak check either.
@@ -748,7 +750,7 @@ def scenario_worker_kill_mid_decode(seed: int = 1234,
         {"point": "mocker.step", "kind": "kill", "rate": 1.0,
          "count": 1, "after": kill_after},
     ]})
-    cfg = FleetConfig(workers=1, kv_store=True, lease_ttl_s=3.0,
+    cfg = FleetConfig(workers=1, kv_store=True, lease_ttl_s=10.0,
                       speedup_ratio=50.0, chaos_plan=plan, chaos_seed=seed,
                       worker_args=["--stream-ckpt-blocks", str(ckpt_blocks),
                                    # keep token ids byte-decodable so the

@@ -127,7 +127,6 @@ def _recurrent_engine_config(ec: EngineConfig) -> EngineConfig:
         "spec_ngram": (ec.spec_ngram > 0,
                        "a rejected draft would need the state rolled back"),
         "tp": (ec.tp > 1, "the state and the mixer are not sharded"),
-        "pp": (ec.pp > 1, "the stages' layers are not of one kind"),
         "sp": (ec.sp > 1, "the scan does not run over a 'seq' axis"),
         "ep": (ec.ep > 1, "the experts' exchange is not wired to a pattern"),
         "kv_dtype": (ec.kv_dtype in ("int8", "int4"),
@@ -1554,18 +1553,18 @@ class EngineCore:
                 f"needs it even; model {engine_cfg.model!r} has head_dim="
                 f"{self.model_cfg.head_dim}")
         mc = self.model_cfg
-        patterned = bool(mc.first_k_dense or len(mc.layer_period) > 1)
         if mc.holds_share and engine_cfg.ep > 1:
             raise ValueError(
                 f"model {engine_cfg.model!r} holds {mc.num_experts} of "
                 f"{mc.router_width} routed experts: that is one chip's share "
                 f"of an expert-parallel deployment, and ep={engine_cfg.ep} "
                 "would divide it again; give the whole model to ep > 1")
-        if patterned and engine_cfg.pp > 1:
+        if engine_cfg.pp > 1 and mc.layer_plan[1:] != (0, 1, mc.num_layers, 0):
             raise ValueError(
                 "pipeline stages take equal stacks of identical layers: a "
-                "model with leading dense layers or a pattern of sliding and "
-                f"full layers cannot run at pp={engine_cfg.pp}")
+                "model with leading layers or a period of several (sliding "
+                "and full attention, one mixer a layer) cannot run at "
+                f"pp={engine_cfg.pp}")
         if mc.sliding_window and engine_cfg.sp > 1:
             raise ValueError("ring prefill has no window: a model with "
                              f"sliding layers cannot run at sp={engine_cfg.sp}")
@@ -1576,7 +1575,7 @@ class EngineCore:
         # layers' counts: what the one count of a step's work needs
         # (obs/sched_ledger.py step_counts).
         self._windows = mc.attn_windows
-        self._routed_layers = mc.routed_layers
+        self._routed_layers = mc.layers_of("E")
         self._ssm_layers = mc.layers_of("M")
         # Whether a program of n tokens streams its experts: the routed
         # layer's own predicate at this model's expert shape.

@@ -11,7 +11,76 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass, field
+from functools import cached_property
 from pathlib import Path
+from typing import NamedTuple
+
+
+class Mixer(NamedTuple):
+    """One sub-layer, ``h + mixer(norm(h))``, and where its operands lie.
+    The mixers of one layer that share a stack share the place."""
+    #: "*" attention, "-" a dense FFN, "E" routed experts, "M" Mamba-2: the
+    #: letters of NemotronH's ``hybrid_override_pattern``
+    kind: str
+    #: the group of ``params["layers"]`` that holds its leaves: "lead" (the
+    #: ``lead_`` leaves), "rep" (the repeated group) or, in a hybrid pattern,
+    #: its kind's own stack
+    stack: str
+    #: its index in that stack; a routed mixer's place in the experts'
+    #: stack and a recurrent one's layer of the state pool as well
+    place: int
+    #: its layer of the buffer its kind carries: the KV cache counts the
+    #: model's attention layers from the first; every other kind, ``place``
+    layer: int
+    window: int = 0     # attention's window, 0 = full or no attention
+
+
+class LayerPlan(NamedTuple):
+    """A model's layers in order, each a tuple of mixers, and how they are
+    run (models/llama.py ``_run_layers``): ``lead`` layers traced one by
+    one, ``period`` layers as the body of a scan of ``trips`` trips,
+    ``rest`` layers traced one by one."""
+
+    layers: tuple[tuple[Mixer, ...], ...]
+    lead: int
+    period: int
+    trips: int
+    rest: int
+
+    def stage(self, n: int) -> "LayerPlan":
+        """The plan of one pipeline stage's stack: ``n`` of a model's
+        identical layers (the engine gives no other model stages)."""
+        return self._replace(layers=self.layers[:n], trips=n)
+
+
+def _split(shapes: list, lead: int, period: int) -> tuple[int, int, int, int]:
+    """(lead, period, trips, rest) of layers whose static shapes are
+    ``shapes``: where a scan stands in the program. One problem, leading
+    layers + whole periods + what is left, with one difference. A model
+    that states its period (``pattern_len``, behind ``lead`` leading
+    layers) gets that period, scanned whenever one whole period fits: a
+    depth cut to one period also fits a shorter one (L L G L is L L G and
+    one more), and the stated length decides. A model that states none
+    gets the split that traces the fewest layer bodies with at least two
+    trips (else nothing is scanned: every layer leads)."""
+    n = len(shapes)
+
+    def fits(lead, p, count):
+        return all(shapes[lead + i] == shapes[lead + i % p]
+                   for i in range(count))
+
+    if 0 < period <= n - lead and fits(lead, period, n - lead):
+        return lead, period, (n - lead) // period, (n - lead) % period
+    best = (n, 0, n, 1, 0)              # (bodies, rest, lead, period, trips)
+    for lead in range(n):
+        for p in range(1, (n - lead) // 2 + 1):
+            trips = (n - lead) // p
+            while trips >= 2 and not fits(lead, p, trips * p):
+                trips -= 1
+            if trips >= 2:
+                rest = n - lead - trips * p
+                best = min(best, (lead + p + rest, rest, lead, p, trips))
+    return (*best[2:], best[1])
 
 
 @dataclass(frozen=True)
@@ -158,28 +227,47 @@ class ModelConfig:
         """Whether some layer carries recurrent state (models/mamba.py)."""
         return "M" in self.hybrid_pattern
 
+    @cached_property
+    def layer_plan(self) -> LayerPlan:
+        """The one description of the model's layers: every reader of their
+        kinds, places, windows and of where the scan stands reads this. A
+        hybrid pattern's layer is one mixer from its kind's stack; any
+        other model's is attention then an FFN from one place of one."""
+        layers = []
+        if self.hybrid_pattern:
+            seen = dict.fromkeys("M*E", 0)
+            for kind in self.hybrid_pattern:
+                layers.append((Mixer(kind, kind, seen[kind], seen[kind]),))
+                seen[kind] += 1
+        else:
+            for i in range(self.num_layers):
+                leads = i < self.first_k_dense
+                stack, place = ("lead", i) if leads else (
+                    "rep", i - self.first_k_dense)
+                layers.append((
+                    Mixer("*", stack, place, i, self.window_of(i)),
+                    Mixer("E" if self.is_moe and not leads else "-", stack,
+                          place, place)))
+        shapes = [tuple((m.kind, m.stack, m.window) for m in layer)
+                  for layer in layers]
+        return LayerPlan(tuple(layers), *_split(
+            shapes, self.first_k_dense, self.pattern_len))
+
     def layers_of(self, kind: str) -> int:
-        """How many layers of a hybrid pattern are ``kind`` ("M", "*", "E")."""
-        return self.hybrid_pattern.count(kind)
+        """How many of the model's mixers are ``kind`` (``Mixer.kind``)."""
+        return sum(m.kind == kind
+                   for layer in self.layer_plan.layers for m in layer)
 
     @property
     def attn_layers(self) -> int:
         """Layers with attention: the KV cache's layers."""
-        return (self.layers_of("*") if self.hybrid_pattern
-                else self.num_layers)
+        return self.layers_of("*")
 
     @property
     def attn_windows(self) -> tuple[int, ...]:
         """The window of each layer that has attention (0: full)."""
-        if self.hybrid_pattern:
-            return (0,) * self.layers_of("*")
-        return tuple(self.window_of(i) for i in range(self.num_layers))
-
-    @property
-    def routed_layers(self) -> int:
-        if self.hybrid_pattern:
-            return self.layers_of("E")
-        return self.num_layers - self.first_k_dense if self.is_moe else 0
+        return tuple(m.window for layer in self.layer_plan.layers
+                     for m in layer if m.kind == "*")
 
     @property
     def shared_expert_width(self) -> int:
@@ -209,46 +297,11 @@ class ModelConfig:
         """c: the channels the convolution runs over, x | B | C."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
-    @property
-    def hybrid_groups(self) -> tuple[int, int, int]:
-        """(leading layers, period, whole periods) of a hybrid pattern: the
-        split that traces the fewest layer bodies, the leading group and
-        what is left behind the last whole period one by one, one period as
-        the body of a scan (at least two trips, else nothing is scanned)."""
-        pat, n = self.hybrid_pattern, self.num_layers
-        best = (n, 0, n, 1, 0)          # (bodies, rest, lead, period, whole)
-        for lead in range(n):
-            for p in range(1, (n - lead) // 2 + 1):
-                whole = (n - lead) // p
-                while whole >= 2 and any(
-                        pat[lead + i] != pat[lead + i % p]
-                        for i in range(whole * p)):
-                    whole -= 1
-                if whole >= 2:
-                    rest = n - lead - whole * p
-                    best = min(best, (lead + p + rest, rest, lead, p, whole))
-        return best[2:]
-
     def window_of(self, layer: int) -> int:
         """Layer ``layer``'s attention window, 0 = full."""
         if self.layer_types and self.layer_types[layer] == "sliding_attention":
             return self.sliding_window
         return 0
-
-    @property
-    def layer_period(self) -> tuple[int, ...]:
-        """The windows of one period of the layers behind the leading dense
-        ones: the shortest pattern whose repetition (cut at the end) they
-        are. ``(0,)`` for a model of identical layers."""
-        kinds = [self.window_of(i)
-                 for i in range(self.first_k_dense, self.num_layers)]
-        # A depth cut to one period fits a shorter pattern too (L L G L is
-        # also L L G and one more): the stated length decides where it fits.
-        for p in (self.pattern_len, *range(1, len(kinds) + 1)):
-            if 0 < p <= len(kinds) and all(
-                    k == kinds[i % p] for i, k in enumerate(kinds)):
-                return tuple(kinds[:p])
-        return (0,)
 
     @property
     def q_size(self) -> int:
@@ -388,7 +441,7 @@ def _smallthinker_keys(cfg: dict) -> dict:
         raise ValueError(
             "rope_layout is neither every layer nor the sliding layers of "
             "sliding_window_layout: positions by a layout of their own are "
-            "not implemented (models/llama.py _layer ropes by rope_scope)")
+            "not implemented (models/llama.py _attention ropes by rope_scope)")
     return {
         **cfg,
         "num_experts": cfg["moe_num_primary_experts"],
@@ -459,7 +512,6 @@ class VisionConfig:
 MODEL_PRESETS: dict[str, ModelConfig] = {
     # CPU-testable tiny models (the llama.cpp-of-this-repo).
     "tiny-llama": ModelConfig(),
-    "tiny-llama-big-vocab": ModelConfig(name="tiny-llama-big-vocab", vocab_size=32000),
     "tiny-moe": ModelConfig(
         name="tiny-moe",
         num_experts=8,
@@ -508,35 +560,6 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         rope_theta=500000.0,
         max_position_embeddings=8192,
         tie_word_embeddings=False,
-    ),
-    # DeepSeek-R1-style wide-EP target (GQA stand-in for MLA in v1).
-    "deepseek-moe": ModelConfig(
-        name="deepseek-moe",
-        vocab_size=129280,
-        hidden_size=7168,
-        intermediate_size=18432,
-        num_layers=61,
-        num_heads=128,
-        num_kv_heads=8,
-        head_dim=128,
-        num_experts=256,
-        num_experts_per_tok=8,
-        moe_intermediate_size=2048,
-        num_shared_experts=1,
-    ),
-    # gpt-oss-120b-style MoE.
-    "gpt-oss-120b": ModelConfig(
-        name="gpt-oss-120b",
-        vocab_size=201088,
-        hidden_size=2880,
-        intermediate_size=2880,
-        num_layers=36,
-        num_heads=64,
-        num_kv_heads=8,
-        head_dim=64,
-        num_experts=128,
-        num_experts_per_tok=4,
-        moe_intermediate_size=2880,
     ),
 }
 

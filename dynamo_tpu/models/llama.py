@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.config import LayerPlan, ModelConfig
 from dynamo_tpu.obs.profiler import phase as _perf_phase
 from dynamo_tpu.utils.logging import get_logger
 
@@ -58,18 +58,31 @@ def _dtype(cfg: ModelConfig):
 LEAD = "lead_"   # prefix of the leading group's leaves under params["layers"]
 
 
-def layer_groups(layers: Params) -> tuple[Params, Params]:
-    """(leading group, repeated group) of ``params["layers"]``. The
-    repeated group is the stacked ``[L_rep, ...]`` leaves under their plain
-    names: the layers that ``_run_layers`` scans. A model whose first
+def layer_stacks(layers: Params) -> dict[str, Params]:
+    """``params["layers"]`` by the names a plan's mixers give their stacks
+    (``Mixer.stack``). The leaves lie in two layouts, and this is the one
+    reader of both (ROADMAP.md, Design: the debt). A model of attention
+    then FFN layers has the repeated group "rep", the stacked ``[L_rep,
+    ...]`` leaves under their plain names, and, where its first
     ``cfg.first_k_dense`` layers are of another shape (a dense FFN before
-    routed ones) keeps those as a second stack ``[L_lead, ...]`` under
-    ``lead_<name>``, in the same flat dict: every leaf of ``layers`` stays
-    an array, which is what the loaders, the sharding rules and the
-    benchmark's weight rounding walk."""
-    lead = {k[len(LEAD):]: v for k, v in layers.items() if k.startswith(LEAD)}
+    routed ones), those as a second stack "lead" ``[L_lead, ...]`` under
+    ``lead_<name>`` in the same flat dict: every leaf of ``layers`` stays an
+    array, which is what the loaders, the sharding rules and the
+    benchmark's weight rounding walk. A hybrid pattern has a stack a kind,
+    "M", "*" and "E", told apart by the leaves' names."""
+    from dynamo_tpu.models import mamba
+
     rep = {k: v for k, v in layers.items() if not k.startswith(LEAD)}
-    return lead, rep
+    attn = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
+    return {
+        "lead": {k[len(LEAD):]: v for k, v in layers.items()
+                 if k.startswith(LEAD)},
+        "rep": rep,
+        "M": {k: v for k, v in rep.items() if k in mamba.LEAVES},
+        "*": {k: v for k, v in rep.items() if k in attn},
+        "E": {k: v for k, v in rep.items()
+              if k not in mamba.LEAVES and k not in attn},
+    }
 
 
 def _layer_axes(cfg: ModelConfig, routed: bool) -> Params:
@@ -589,81 +602,28 @@ def token_layout(q_len: jax.Array, b: int, t: int, n: int) -> tuple[
     return TokenLayout(b, t, tok_row, tok_off, row_tok, starts), i < ends[-1]
 
 
-def _layer(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, **kw):
-    """:func:`_layer_body` under the ``layer`` scope: what no inner phase
-    names (norms, rope, residual adds, the moves of ``q`` and of the
-    attention output around the kernel) is the layer's rest
-    (obs/profiler.py ``DEVICE_PHASES``)."""
-    with _perf_phase("layer"):
-        return _layer_body(cfg, lp, layer, hid, cache_k, cache_v, **kw)
-
-
-def _layer_body(cfg: ModelConfig, lp: Params, layer, hid, cache_k, cache_v, *,
-                lay: TokenLayout, positions, slot, block_tables, q_start,
-                kv_lens, attn_impl: str = "dense",
-                moe_impl: str = "dense", mesh=None, use_ring: bool = False,
-                window: int = 0, live=None):
-    """One transformer layer over the WHOLE cache ([L,NB,BS,KH,D], or the
-    stage-local part of it under pp): writes this step's K/V at
-    ``(layer, slot)``, attends over layer ``layer``, returns
-    (hidden, cache_k, cache_v, counts). The one layer body of ``forward``
-    and of both pp schedules. Nothing here materialises a layer of the
-    cache: the scatter, the kernel's DMAs and the dense gather all address
-    the carried buffer by layer index.
-
-    Token-major: ``hid [N, H]``, ``positions`` and ``slot [N]``. Norms,
-    the Q/K/V/O projections, rope, the scatter and the MLP run over the N
-    tokens; ``q`` alone is laid out as rows ``[B, T, heads, D]`` (``lay``)
-    for attention, and the attention output packed back to ``[N, q_size]``
-    (under the paged kernel a packed step's ``q`` stays ``[N, heads, D]``
-    too: ``_attention``).
-    Ring attention takes K and V as rows too, and only a rectangle
-    (``N == B*T``), where those moves are reshapes.
-
-    What differs from layer to layer is static: ``window`` (> 0: a sliding
-    layer, query i sees the keys j with i - j < window), the FFN's kind
-    (``lp`` has a ``router`` or it is dense) and with them, by the
-    configuration, whether the layer carries positions at all
-    (``rope_scope``), which state the router reads (``router_input``: the
-    expert layer's input, or the attention's, routed before attention and
-    carried past it) and the experts' activation (``expert_act``).
-    ``counts`` is the routed layer's int32 [3] under ``moe_impl="held"``
-    (models/moe.py ``held_rows``), else None; ``live`` [N] names the
-    bucket's live tokens for it."""
-    post = cfg.norm_placement == "post"
-    x = hid if post else rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
-    routing = None
-    if "router" in lp and cfg.router_input == "attn_norm":
-        # The router reads the state that enters attention; its choice
-        # rides past attention to the expert layer.
-        from dynamo_tpu.models.moe import route
-
-        with _perf_phase("moe_route"):
-            routing = route(x, lp, cfg)
-    attn, cache_k, cache_v = _attention(
-        cfg, lp, layer, x, cache_k, cache_v, lay=lay, positions=positions,
-        slot=slot, block_tables=block_tables, q_start=q_start,
-        kv_lens=kv_lens, attn_impl=attn_impl, mesh=mesh, use_ring=use_ring,
-        window=window)
-    if post:
-        attn = rms_norm(attn, lp["attn_norm"], cfg.rms_norm_eps)
-    hid = hid + attn
-    x = hid if post else rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
-    mlp_out, counts = _ffn(cfg, lp, x, routing, moe_impl, mesh, live)
-    if post:
-        mlp_out = rms_norm(mlp_out, lp["mlp_norm"], cfg.rms_norm_eps)
-    return hid + mlp_out, cache_k, cache_v, counts
-
-
 def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
                lay: TokenLayout, positions, slot, block_tables, q_start,
                kv_lens, attn_impl: str = "dense", mesh=None,
                use_ring: bool = False, window: int = 0):
     """The attention mixer on the normed state ``x [N, H]``: Q/K/V, this
-    step's K/V written at ``(layer, slot)`` of the whole cache, attention
+    step's K/V written at ``(layer, slot)`` of the WHOLE cache
+    ([L,NB,BS,KH,D], or the stage-local part of it under pp), attention
     over layer ``layer`` of it, ``wo``. ``layer`` is the layer's place in
-    the cache, which has the attention layers alone. Returns
-    (out [N, H], cache_k, cache_v)."""
+    the cache, which has the attention layers alone. Nothing here
+    materialises a layer of the cache: the scatter, the kernel's DMAs and
+    the dense gather all address the carried buffer by layer index.
+    Returns (out [N, H], cache_k, cache_v).
+
+    Token-major: ``positions`` and ``slot [N]``. The Q/K/V/O projections,
+    rope and the scatter run over the N tokens; ``q`` alone is laid out as
+    rows ``[B, T, heads, D]`` (``lay``) for attention, and the attention
+    output packed back to ``[N, q_size]`` (under the paged kernel a packed
+    step's ``q`` stays ``[N, heads, D]`` too). Ring attention takes K and V
+    as rows too, and only a rectangle (``N == B*T``), where those moves are
+    reshapes. ``window`` is static (> 0: a sliding layer, query i sees the
+    keys j with i - j < window), and with it, by the configuration, whether
+    the layer carries positions at all (``rope_scope``)."""
     n = x.shape[0]
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
     # The three products stay [N, out] up to the barrier and get their head
@@ -750,9 +710,14 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
 
 def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
          live):
-    """The FFN on the normed state ``x [N, H]``: the routed experts in the
-    formulation ``moe_impl`` names where ``lp`` has a ``router``, else the
-    dense SwiGLU. Returns (out [N, H], the routed layer's counts or None)."""
+    """The FFN on the normed state ``x [N, H]``: where ``lp`` has a
+    ``router`` the routed experts (activation ``cfg.expert_act``) in the
+    form ``moe_impl`` names, "held", "ep" or the plain :func:`moe_mlp`, on
+    ``routing`` where the choice was made before attention
+    (``cfg.router_input``); else the dense SwiGLU. Returns (out [N, H],
+    counts): the routed layer's int32 [3] under "held" (models/moe.py
+    ``held_rows``; ``live`` [N] names the bucket's live tokens for it),
+    else None."""
     counts = None
     if "router" in lp:
         if moe_impl == "held":
@@ -767,10 +732,6 @@ def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
 
             mlp_out = moe_mlp_dropless(x, lp, cfg, mesh=mesh,
                                        routing=routing)
-        elif moe_impl == "ep_capacity":
-            from dynamo_tpu.models.moe import moe_mlp_ep
-
-            mlp_out = moe_mlp_ep(x, lp, cfg, routing=routing)
         else:
             mlp_out = moe_mlp(x, lp, cfg, routing)
     else:
@@ -779,166 +740,149 @@ def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
     return mlp_out, counts
 
 
-def _run_hybrid(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, ssm,
-                *, ssm_slots, ssm_live, q_len, live=None, **kw):
-    """:func:`_run_layers` for a hybrid pattern (``cfg.hybrid_pattern``):
-    each layer is one mixer, ``h + mixer(norm(h))``, of the kind its
-    character names. The leading group and what is left behind the last
-    whole period are traced one by one, one period is the body of a
-    ``lax.scan`` (``cfg.hybrid_groups``); a layer's kind is static structure
-    of the body. Each kind has its own stack under ``layers`` and its own
-    carried buffer: attention layer ``i`` of the model's attention layers
-    is layer ``i`` of the KV cache, Mamba layer ``i`` row ``i`` of the state
-    pool ``ssm`` (models/mamba.py), routed layer ``i`` place ``i`` of the
-    experts' stack, which is never cut by layer. The scan carries the
-    period's index alone and every layer reads its matrices from its kind's
-    stack at its own place. Returns (hidden, cache_k, cache_v, ssm,
-    counts)."""
+#: the norm a mixer reads, by its kind
+_NORM = {"*": "attn_norm", "-": "mlp_norm", "E": "mlp_norm", "M": "ssm_norm"}
+
+
+def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
+                cache_k, cache_v, ssm=None, *, lay: TokenLayout, q_start,
+                q_len=None, live=None, ssm_slots=None,
+                attn_impl: str = "dense", moe_impl: str = "dense", mesh=None,
+                **attn):
+    """Run the layers of ``plan`` (``cfg.layer_plan``, or a pipeline
+    stage's part of it) over ``layers``, their stacked params: the one
+    runner of ``forward`` and of both pp schedules. The plan's leading
+    layers are traced one by one, one period is the body of a ``lax.scan``
+    over its trips, what is left behind the last whole period is traced one
+    by one. Returns (hidden, cache_k, cache_v, ssm, counts).
+
+    A layer is its mixers in order, each ``h + mixer(norm(h))`` (or, under
+    ``cfg.norm_placement == "post"``, ``h + norm(mixer(h))``) under the
+    norm its kind names: attention (:func:`_attention`), an FFN dense or
+    routed (:func:`_ffn`), a Mamba-2 mixer (models/mamba.py). Token-major:
+    ``hid [N, H]`` throughout. What differs from layer to layer (a mixer's
+    kind, its window) is static structure of the body, never a traced
+    branch; what is the same is traced once a period. One thing couples a
+    layer's mixers: under ``cfg.router_input == "attn_norm"`` the routing
+    is made from the state that enters attention and rides past it to the
+    same layer's routed mixer.
+
+    The carry is the hidden state and the buffers the plan's kinds need,
+    each entering the loop once, whole, and addressed by absolute layer
+    index (``Mixer.layer``): K and V where a layer attends, the state pool
+    ``ssm`` where one is recurrent, and under ``moe_impl="held"`` the
+    routed layers' int32 [3] counts, summed over them (else None). (The
+    cache must not ride xs→ys: XLA then cuts each layer out, stacks it back
+    and keeps a second K and V as a temporary, which cost over half of a
+    decode step on the v5e — PERF.md section 6. A buffer no layer uses is
+    not carried: it would be an operand of every loop of a program that
+    never reads it.)
+
+    The scan has two forms. A period of one layer whose stack the scan
+    walks whole carries that layer's params and index on xs: the scan over
+    stacked params that a model of identical layers always was. Any other
+    period carries the trip's index alone: the body takes each layer's
+    params from its stack at the layer's own place, so every matrix has the
+    one dot that reads it for a consumer and is read where it lies. (A
+    period's ``[p, ...]`` slice on xs has ``p`` consumers: XLA then copies
+    the period's ``wq``, ``wo``, ``wk`` and ``wv`` out of the stack in
+    every trip and the dots read the copy, 8 % of a decode step at four
+    layers a period — PERF.md section 6, PR 42.) Under ``moe_impl="held"``
+    the experts' stacks do not ride xs either and are never cut by layer:
+    the grouped matmul takes the stack whole and the layer's place in it
+    (``expert_layer``; models/moe.py ``held_rows``)."""
     from dynamo_tpu.models import mamba
+    from dynamo_tpu.models.moe import route
 
-    pat = cfg.hybrid_pattern
-    lead, p, whole = cfg.hybrid_groups
-    moe_impl, mesh = kw.pop("moe_impl", "dense"), kw.get("mesh")
-    attn_leaves = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
-    stacks = {
-        "M": {k: v for k, v in layers.items() if k in mamba.LEAVES},
-        "*": {k: v for k, v in layers.items() if k in attn_leaves},
-        "E": {k: v for k, v in layers.items()
-              if k not in mamba.LEAVES and k not in attn_leaves},
-    }
-    counted = moe_impl == "held" and "E" in pat
+    stacks = layer_stacks(layers)
+    of_kind = {m.kind: m.stack for mixers in plan.layers for m in mixers}
+    # (a part of the carry that is None is no operand of the loop)
+    carry = (h, *((cache_k, cache_v) if "*" in of_kind else (None, None)),
+             ssm, None)
     experts = {}
-    carry = (h, cache_k, cache_v, ssm)
-    if counted:
-        carry += (jnp.zeros((3,), jnp.int32),)
-        experts = {k: stacks["E"].pop(k) for k in ("w_gate", "w_up", "w_down")
-                   if k in stacks["E"]}
+    if moe_impl == "held" and "E" in of_kind:
+        carry = (*carry[:4], jnp.zeros((3,), jnp.int32))
+        routed = stacks[of_kind["E"]]
+        experts = {k: routed.pop(k) for k in ("w_gate", "w_up", "w_down")
+                   if k in routed}
+    post = cfg.norm_placement == "post"
 
-    def one(carry, kind, i):
-        """Layer ``i`` of the layers of ``kind``."""
-        hid, ck, cv, state, *counts = carry
-        lp = jax.tree.map(lambda a: a[i], stacks[kind])
+    def one(carry, mixers, at=lambda m: m.place, lp=None):
+        """One layer on ``carry``. ``at(m)``: mixer ``m``'s place in its
+        stack here (its own outside the scan, the trip's inside it); ``lp``
+        the layer's params where the scan hands them in."""
+        hid, k, v, state, counts = carry
+        place = {}
+        for m in mixers:
+            if m.stack not in place:
+                place[m.stack] = at(m)
+        if lp is None:
+            lp = {}
+            for stack, i in place.items():
+                lp.update(jax.tree.map(lambda a: a[i], stacks[stack]))
+        routing = None
+        # What no inner phase names (norms, rope, residual adds, the moves
+        # of q and of the attention output around the kernel) is the
+        # layer's rest (obs/profiler.py DEVICE_PHASES).
         with _perf_phase("layer"):
-            if kind == "M":
-                x = rms_norm(hid, lp["ssm_norm"], cfg.rms_norm_eps)
-                out, state = mamba.mixer(
-                    cfg, lp, i, x, state, lay=kw["lay"], slots=ssm_slots,
-                    q_start=kw["q_start"], q_len=q_len, live=ssm_live,
-                    impl={"dense": "jnp"}.get(kw["attn_impl"],
-                                              kw["attn_impl"]))
-            elif kind == "*":
-                x = rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
-                out, ck, cv = _attention(cfg, lp, i, x, ck, cv, **kw)
-            else:
-                if experts:
-                    lp = {**lp, **experts, "expert_layer": i}
-                x = rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
-                out, c = _ffn(cfg, lp, x, None, moe_impl, mesh, live)
-                if c is not None:
-                    counts = [counts[0] + c]
-            return (hid + out, ck, cv, state, *counts)
+            for m in mixers:
+                i, norm = place[m.stack], lp[_NORM[m.kind]]
+                x = hid if post else rms_norm(hid, norm, cfg.rms_norm_eps)
+                if m.kind == "*":
+                    if cfg.router_input == "attn_norm" and any(
+                            o.kind == "E" for o in mixers):
+                        with _perf_phase("moe_route"):
+                            routing = route(x, lp, cfg)
+                    # (no "+ 0": it would be an equation of every program)
+                    out, k, v = _attention(
+                        cfg, lp, (m.layer - m.place) + i
+                        if m.layer != m.place else i, x, k, v, lay=lay,
+                        q_start=q_start, attn_impl=attn_impl, mesh=mesh,
+                        window=m.window, **attn)
+                elif m.kind == "M":
+                    out, state = mamba.mixer(
+                        cfg, lp, i, x, state, lay=lay, slots=ssm_slots,
+                        q_start=q_start, q_len=q_len, live=live,
+                        impl={"dense": "jnp"}.get(attn_impl, attn_impl))
+                else:
+                    if experts and m.kind == "E":
+                        lp = {**lp, **experts, "expert_layer": i}
+                    out, c = _ffn(cfg, lp, x, routing, moe_impl, mesh, live)
+                    if c is not None:
+                        counts = counts + c
+                if post:
+                    out = rms_norm(out, norm, cfg.rms_norm_eps)
+                hid = hid + out
+        return hid, k, v, state, counts
 
-    seen = {"M": 0, "*": 0, "E": 0}
-    for kind in pat[:lead]:
-        carry = one(carry, kind, seen[kind])
-        seen[kind] += 1
-    if whole:
-        period = pat[lead:lead + p]
-        base = dict(seen)
-        per = {k: period.count(k) for k in seen}
+    for mixers in plan.layers[:plan.lead]:
+        carry = one(carry, mixers)
+    if plan.trips:
+        period = plan.layers[plan.lead:plan.lead + plan.period]
+        index = jnp.arange(plan.trips, dtype=jnp.int32)
+        on_xs = stacks[period[0][0].stack]
+        if plan.period == 1 and plan.trips == len(jax.tree.leaves(on_xs)[0]):
+            def layer_fn(carry, xs):
+                lp, i = xs
+                return one(carry, period[0], lambda m: i, lp), None
 
-        def period_fn(carry, i):
-            off = {k: 0 for k in per}
-            for kind in period:
-                carry = one(carry, kind, base[kind] + i * per[kind] + off[kind])
-                off[kind] += 1
-            return carry, None
+            carry, _ = lax.scan(layer_fn, carry, (on_xs, index))
+        else:
+            def period_fn(carry, trip):
+                for mixers in period:
+                    # (a stack's places step from trip to trip by the
+                    # period's layers that read it)
+                    carry = one(carry, mixers, lambda m: trip * sum(
+                        any(o.stack == m.stack for o in layer)
+                        for layer in period) + m.place)
+                return carry, None
 
-        carry, _ = lax.scan(period_fn, carry,
-                            jnp.arange(whole, dtype=jnp.int32))
-        seen = {k: base[k] + whole * per[k] for k in per}
-    for kind in pat[lead + whole * p:]:
-        carry = one(carry, kind, seen[kind])
-        seen[kind] += 1
-    return (*carry, None) if not counted else carry
-
-
-def _run_layers(cfg: ModelConfig, layers: Params, h, cache_k, cache_v, **kw):
-    """Run the layers: the leading group one by one, then a ``lax.scan``
-    over whole periods of the repeated group's pattern with one period's
-    layers unrolled in the body, then what is left of a last period.
-    Returns (hidden, cache_k, cache_v, counts).
-
-    A model of identical layers is a period of one: the scan of
-    :func:`_layer` over the stacked params that this always was. Where the
-    layers of a period differ (sliding and full attention), the difference
-    is static structure of the body, never a traced branch. The cache
-    enters the loop once, whole, as carried state beside the hidden state,
-    addressed by absolute layer index. (The cache must not ride xs→ys: XLA
-    then cuts each layer out, stacks it back and keeps a second K and V as a
-    temporary, which cost over half of a decode step on the v5e — PERF.md
-    section 6.) A period of one carries its layer's params and index on xs.
-    A longer period carries the period's index alone: the body takes each
-    of its layers' params from the repeated group's stack at that layer's
-    own place, so every matrix has the one dot that reads it for a consumer
-    and is read where it lies. (A period's ``[p, ...]`` slice on xs has
-    ``p`` consumers: XLA then copies the period's ``wq``, ``wo``, ``wk`` and
-    ``wv`` out of the stack in every trip and the dots read the copy, 8 %
-    of a decode step at four layers a period — PERF.md section 6, PR 42.)
-
-    ``counts``: under ``moe_impl="held"`` the routed layers' int32 [3]
-    summed over them, carried beside the cache; else None. There the
-    experts' stacks do not ride xs either and are never cut by layer: the
-    grouped matmul takes the stack whole and the layer's place in it
-    (models/moe.py ``held_rows``)."""
-    lead, rep = layer_groups(layers)
-    n_lead = jax.tree.leaves(lead)[0].shape[0] if lead else 0
-    n = jax.tree.leaves(rep)[0].shape[0]
-    period = cfg.layer_period
-    p = len(period)
-    counted = kw.get("moe_impl") == "held" and "router" in rep
-    carry = (h, cache_k, cache_v)
-    experts = {}
-    if counted:
-        carry += (jnp.zeros((3,), jnp.int32),)
-        experts = {k: rep.pop(k) for k in ("w_gate", "w_up", "w_down")}
-
-    def one(carry, lp, layer, window):
-        if experts and "router" in lp:
-            lp = {**lp, **experts, "expert_layer": layer - n_lead}
-        *state, counts = _layer(cfg, lp, layer, *carry[:3], window=window, **kw)
-        return (*state, *((carry[3] + counts,) if counts is not None
-                          else carry[3:]))
-
-    def at(tree, i):
-        return jax.tree.map(lambda a: a[i], tree)
-
-    for i in range(n_lead):
-        carry = one(carry, at(lead, i), i, cfg.window_of(i))
-    if p == 1:
-        index = jnp.arange(n, dtype=jnp.int32)
-
-        def layer_fn(carry, xs):
-            lp, layer = xs
-            return one(carry, lp, layer, period[0]), None
-
-        carry, _ = lax.scan(layer_fn, carry,
-                            (rep, index + n_lead if n_lead else index))
-    else:
-        whole = n // p
-
-        def period_fn(carry, i):
-            for j, window in enumerate(period):
-                k = i * p + j
-                carry = one(carry, at(rep, k), n_lead + k, window)
-            return carry, None
-
-        if whole:
-            carry, _ = lax.scan(period_fn, carry,
-                                jnp.arange(whole, dtype=jnp.int32))
-        for j in range(whole * p, n):
-            carry = one(carry, at(rep, j), n_lead + j, period[j % p])
-    return (*carry, None) if not counted else carry
+            carry, _ = lax.scan(period_fn, carry, index)
+    for mixers in plan.layers[len(plan.layers) - plan.rest:]:
+        carry = one(carry, mixers)
+    h, k, v, ssm, counts = carry
+    return (h, *((k, v) if "*" in of_kind else (cache_k, cache_v)), ssm,
+            counts)
 
 
 def _positions_and_slots(lay: TokenLayout, valid, q_start, block_tables,
@@ -1054,21 +998,12 @@ def forward(
             h = jnp.where(lay.to_tokens(embed_mask)[:, None],
                           lay.to_tokens(embed_override).astype(h.dtype), h)
 
-    held = {"live": valid} if moe_impl == "held" else {}
-    if cfg.hybrid_pattern:
-        h, cache_k, cache_v, ssm, counts = _run_hybrid(
-            cfg, params["layers"], h, cache_k, cache_v, ssm,
-            ssm_slots=ssm_slots, ssm_live=valid, q_len=q_len, lay=lay,
-            positions=positions, slot=slot, block_tables=block_tables,
-            q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
-            moe_impl=moe_impl, mesh=mesh, use_ring=use_ring, **held)
-    else:
-        h, cache_k, cache_v, counts = _run_layers(
-            cfg, params["layers"], h, cache_k, cache_v, lay=lay,
-            positions=positions, slot=slot, block_tables=block_tables,
-            q_start=q_start, kv_lens=kv_lens, attn_impl=attn_impl,
-            moe_impl=moe_impl, mesh=mesh,
-            use_ring=use_ring, **held)
+    h, cache_k, cache_v, ssm, counts = _run_layers(
+        cfg, cfg.layer_plan, params["layers"], h, cache_k, cache_v, ssm,
+        lay=lay, positions=positions, slot=slot, block_tables=block_tables,
+        q_start=q_start, q_len=q_len, kv_lens=kv_lens, live=valid,
+        ssm_slots=ssm_slots, attn_impl=attn_impl, moe_impl=moe_impl,
+        mesh=mesh, use_ring=use_ring)
     # The head's own preparation: the final norm and each row's last token.
     with _perf_phase("logits"):
         h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
@@ -1130,6 +1065,7 @@ def forward_pp(
     pp = mesh.shape["pipe"]
     if cfg.num_layers % pp != 0:
         raise ValueError(f"num_layers={cfg.num_layers} not divisible by pp={pp}")
+    stage = cfg.layer_plan.stage(cfg.num_layers // pp)
     b, t = token_ids.shape
     bs = _cache_block_size(cache_k)
     nblk = block_tables.shape[1]
@@ -1200,8 +1136,9 @@ def forward_pp(
             # and the output contribution is masked.
             slot_t = jnp.where(live, slot_mb[mbc], 0)
             h_in = jnp.where(s == 0, h0_mb[mbc], h_cur)
-            h_out, ck, cv, _ = _run_layers(
-                cfg, lp_stack, h_in, ck, cv, lay=lay_mb, positions=pos_mb[mbc],
+            h_out, ck, cv, *_ = _run_layers(
+                cfg, stage, lp_stack, h_in, ck, cv, lay=lay_mb,
+                positions=pos_mb[mbc],
                 slot=slot_t, block_tables=bt_mb[mbc], q_start=qs_mb[mbc],
                 kv_lens=kl_mb[mbc], attn_impl=attn_impl, moe_impl=moe_impl)
             out = out.at[mbc].add(jnp.where((s == pp - 1) & live, h_out, 0))
@@ -1238,11 +1175,13 @@ def _forward_pp_sequential(params, cfg, lay, positions, q_start, kv_lens, slot,
     site covers the kernel case)."""
     from jax.sharding import PartitionSpec as P
 
+    stage = cfg.layer_plan.stage(cfg.num_layers // pp)
+
     def pp_fn(lp_stack, ck_local, cv_local, h):
         s = lax.axis_index("pipe")
         for i in range(pp):
-            h_out, ck_new, cv_new, _ = _run_layers(
-                cfg, lp_stack, h, ck_local, cv_local, lay=lay,
+            h_out, ck_new, cv_new, *_ = _run_layers(
+                cfg, stage, lp_stack, h, ck_local, cv_local, lay=lay,
                 positions=positions, slot=slot, block_tables=block_tables,
                 q_start=q_start, kv_lens=kv_lens, moe_impl=moe_impl)
             keep = s == i

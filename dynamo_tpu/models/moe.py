@@ -1,4 +1,4 @@
-"""Expert-parallel MoE dispatch: dropless ragged groups + capacity variant.
+"""Expert-parallel MoE dispatch: dropless ragged groups.
 
 The reference only passes wide-EP flags through to SGLang/vLLM
 (SURVEY.md §2.7: TEP16/DEP16 recipes, e.g. recipes/deepseek-r1/sglang-wideep);
@@ -10,7 +10,9 @@ expert's tokens form one contiguous ragged group of one ``lax.ragged_dot``;
 static shapes, no capacity, nothing dropped; rows of experts held elsewhere
 belong to no group; in a decode-sized program on a TPU whose experts fit
 VMEM, :func:`streams_experts`, the same result by one kernel that streams
-each touched expert's matrices once, ops/moe_stream.py) under three layers:
+each touched expert's matrices once, ops/moe_stream.py) under two layers
+(the plain form both are held to is ``llama.moe_mlp``: every expert for
+every token):
 
 - :func:`moe_mlp_held` (``moe_impl="held"``): one chip told which experts
   it holds of a wider router, as one chip's share of an expert-parallel
@@ -22,11 +24,6 @@ each touched expert's matrices once, ops/moe_stream.py) under three layers:
   each device computes the rows of ITS experts and partial outputs ``psum``
   over the axis. A serving engine cannot ship an output-changing dispatch —
   vLLM-class engines are dropless for the same reason.
-
-- :func:`moe_mlp_ep` (``moe_impl="ep_capacity"``) — the classic
-  Switch/GShard capacity-bounded dispatch/combine einsum formulation, kept
-  for experimentation: with enough capacity it equals the dense reference;
-  under pressure it drops over-capacity choices.
 """
 
 from __future__ import annotations
@@ -64,7 +61,7 @@ def route(xt: jax.Array, lp: Params, cfg: ModelConfig):
     ``xt`` [N, H] is the state the router reads, which need not be the
     state the experts read: under ``cfg.router_input == "attn_norm"`` the
     layer calls this on the attention's input and hands the result past
-    attention to the expert layer (models/llama.py ``_layer``).
+    attention to the expert layer (models/llama.py ``_run_layers``).
 
     "softmax" (Mixtral): the k largest logits, weighted by the softmax over
     those k alone. "sigmoid" (DeepSeek-V3's, which K-EXAONE's keys name):
@@ -330,63 +327,3 @@ def moe_mlp_dropless(x: jax.Array, lp: Params, cfg: ModelConfig,
         check_vma=False,
     )
     return fn(*args)
-
-
-def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
-                    capacity_factor: float) -> int:
-    """Per-expert token slots, padded to a lane-friendly multiple of 8."""
-    cap = int(num_tokens * top_k / num_experts * capacity_factor) + 1
-    return max(-(-cap // 8) * 8, 8)
-
-
-def moe_mlp_ep(x: jax.Array, lp: Params, cfg: ModelConfig,
-               capacity_factor: float = 2.0, routing=None) -> jax.Array:
-    """Capacity-based EP MoE FFN. x: [..., H] → [..., H].
-
-    The dispatch/combine tensors route each token's top-k expert choices to
-    per-expert buffers of C slots; choice order is priority order (a token's
-    1st choice wins slots over another token's 2nd choice at equal index by
-    flattened position).
-    """
-    h = x.shape[-1]
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
-    xt = x.reshape(-1, h)
-    n = xt.shape[0]
-    topi, weights = routing or route(xt, lp, cfg)                 # [N, k]
-
-    cap = expert_capacity(n, e, k, capacity_factor)
-    # Position of each (choice, token) within its expert's buffer. Flatten
-    # choice-major so every token's 1st choice outranks all 2nd choices.
-    oh = jax.nn.one_hot(topi.T.reshape(k * n), e, dtype=jnp.int32)       # [kN, E]
-    pos = jnp.cumsum(oh, axis=0) * oh - 1                                # [kN, E]
-    pos_in_e = jnp.max(pos, axis=1)                                      # [kN]
-    keep = (pos_in_e >= 0) & (pos_in_e < cap)
-    pos_in_e = jnp.where(keep, pos_in_e, 0)
-
-    # Back to [N, k] layout.
-    keep = keep.reshape(k, n).T
-    pos_nk = pos_in_e.reshape(k, n).T                                    # [N, k]
-
-    # dispatch[n, e, c] = 1 where token n's choice lands in slot c of expert e
-    slot_oh = jax.nn.one_hot(pos_nk, cap, dtype=jnp.float32)             # [N, k, C]
-    exp_oh = jax.nn.one_hot(topi, e, dtype=jnp.float32)                  # [N, k, E]
-    keep_f = keep.astype(jnp.float32)[..., None]
-    dispatch = jnp.einsum("nke,nkc->nec", exp_oh, slot_oh * keep_f)      # [N, E, C]
-    combine = jnp.einsum("nke,nkc->nec", exp_oh * (weights * keep)[..., None],
-                         slot_oh)                                        # [N, E, C]
-
-    # Expert buffers [E, C, H]: sharded on "expert" with the weights; GSPMD
-    # turns the N↔(E,C) einsums into token all-to-alls over ICI.
-    expert_in = jnp.einsum("nec,nh->ech", dispatch, xt.astype(jnp.float32))
-    expert_in = expert_in.astype(x.dtype)
-    gate = jnp.einsum("ech,ehm->ecm", expert_in, lp["w_gate"])
-    up = jnp.einsum("ech,ehm->ecm", expert_in, lp["w_up"])
-    act = gate_act(cfg)(gate) * up
-    out_e = jnp.einsum("ecm,emh->ech", act, lp["w_down"])                # [E, C, H]
-    y = jnp.einsum("nec,ech->nh", combine, out_e.astype(jnp.float32)).astype(x.dtype)
-
-    if cfg.num_shared_experts:
-        from dynamo_tpu.models.llama import swiglu
-
-        y = y + swiglu(xt, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
-    return y.reshape(x.shape)

@@ -346,7 +346,7 @@ def step_shapes(cfg: ModelConfig, *, block_size: int,
     block of one layer (K and V). ``devices`` is what the model is divided
     over. Norm weights and biases are left out: thousands, not millions."""
     h, L = cfg.hidden_size, cfg.attn_layers
-    routed = cfg.routed_layers
+    routed = cfg.layers_of("E")
     m = cfg.moe_intermediate_size
     kvb = _kv_itemsize(kv_dtype)
     block = 2 * block_size * cfg.num_kv_heads * cfg.head_dim * kvb
@@ -364,11 +364,11 @@ def step_shapes(cfg: ModelConfig, *, block_size: int,
     ssm_layers = cfg.layers_of("M")
     ssm_params = h * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.mamba_num_heads) \
         + cfg.ssm_inner * h if ssm_layers else 0
-    if cfg.hybrid_pattern:
+    if ssm_layers:
         fixed_layers, fixed_params = ssm_layers, ssm_params
     else:
-        fixed_layers = L - routed
-        fixed_params = 3 * h * cfg.intermediate_size if routed < L else 0
+        fixed_layers = cfg.layers_of("-")
+        fixed_params = 3 * h * cfg.intermediate_size if fixed_layers else 0
     return {
         "layers": L, "routed_layers": routed,
         "dense_ffn_layers": fixed_layers,

@@ -91,14 +91,13 @@ class FlightRecorder:
     eviction is LRU on trace insertion order (a trace that keeps
     receiving spans stays fresh)."""
 
-    def __init__(self, capacity: int = 256, spans_per_trace: int = 512,
-                 step_capacity: int = 2048):
+    def __init__(self, capacity: int = 256, spans_per_trace: int = 512):
         self.capacity = max(capacity, 1)
         self.spans_per_trace = spans_per_trace
         self._traces: "OrderedDict[str, list[Span]]" = OrderedDict()
         self._span_ids: dict[str, set[str]] = {}
         self._lock = threading.Lock()
-        self.steps = StepProfiler(capacity=step_capacity)
+        self.steps = StepProfiler()
 
     def record(self, span: "Span") -> bool:
         """File a closed span. Returns False on duplicate span_id (wire

@@ -48,7 +48,7 @@ inside vLLM/TRT-LLM, which we replace):
   HBM (memory space ANY) and a layer index (one more scalar-prefetch
   operand): a block is copied from ``cache[layer, table[b, j]]``, so the
   model's layer loop never cuts a layer out of the cache for it
-  (models/llama.py ``_layer``), and the cache is only read. A quantized
+  (models/llama.py ``_attention``), and the cache is only read. A quantized
   pool's scales stay a per-layer ``[NB, KH]`` operand: SMEM must not grow
   with L.
 - a block lands with ALL its kv heads, ``[BS, KH, Dp]`` as the pool holds

@@ -242,7 +242,7 @@ def _one_layer(kind, dtype):
 @pytest.mark.parametrize("kind", ["dense", "qk_norm-window", "int8"])
 def test_layer_equals_the_projections_reshaped_in_place(monkeypatch, kind,
                                                         dtype):
-    """``_layer`` holds its Q, K and V products two-dimensional up to an
+    """``_attention`` holds its Q, K and V products two-dimensional up to an
     optimization barrier and splits the heads after it, so that the chip's
     compiler reads each matrix where it lies (tests/test_ops.py holds the
     compiled text to that). It is the arithmetic it was: with the barrier
@@ -261,10 +261,14 @@ def test_layer_equals_the_projections_reshaped_in_place(monkeypatch, kind,
     hid = jnp.asarray(rng.standard_normal((b * t, cfg.hidden_size)), dt)
 
     def layer(lp, hid, ck, cv):
-        return llama._layer(
-            cfg, lp, 1, hid, ck, cv, lay=lay, positions=positions, slot=slot,
+        x = llama.rms_norm(hid, lp["attn_norm"], cfg.rms_norm_eps)
+        attn, ck, cv = llama._attention(
+            cfg, lp, 1, x, ck, cv, lay=lay, positions=positions, slot=slot,
             block_tables=bt, q_start=q_start, kv_lens=q_start + q_len,
-            window=window)[:3]
+            window=window)
+        hid = hid + attn
+        x = llama.rms_norm(hid, lp["mlp_norm"], cfg.rms_norm_eps)
+        return hid + llama._ffn(cfg, lp, x, None, "dense", None, None)[0], ck, cv
 
     barriers = []
     real = jax.lax.optimization_barrier

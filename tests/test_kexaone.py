@@ -59,6 +59,13 @@ TINY = {
 LOGIT_TOL = 2e-4
 
 
+def _period_windows(cfg) -> tuple[int, ...]:
+    """The attention windows of the layers of one period of the plan."""
+    p = cfg.layer_plan
+    return tuple(layer[0].window
+                 for layer in p.layers[p.lead:p.lead + p.period])
+
+
 def _reference():
     spec = importlib.util.spec_from_file_location(
         "kexaone_reference", CONFIG_DIR / "reference.py")
@@ -229,8 +236,8 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
     chip computes alike) counted once, are the whole layer's result."""
     cfg = tiny[0]
     whole = dataclasses.replace(cfg, num_experts=16, num_experts_published=0)
-    lp = jax.tree.map(lambda a: a[0], llama.layer_groups(
-        llama.init_params(whole, jax.random.key(9))["layers"])[1])
+    lp = jax.tree.map(lambda a: a[0], llama.layer_stacks(
+        llama.init_params(whole, jax.random.key(9))["layers"])["rep"])
     x = jnp.asarray(np.random.default_rng(2).standard_normal(
         (37, cfg.hidden_size)), jnp.float32)
     uncut = llama.moe_mlp(x, lp, whole)           # every expert, all-experts form
@@ -267,8 +274,8 @@ def test_the_whole_model_sharded_over_ep_routes_by_the_bias(tiny, ep):
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
     cfg = tiny[0]
     whole = dataclasses.replace(cfg, num_experts=16, num_experts_published=0)
-    lp = jax.tree.map(lambda a: a[0], llama.layer_groups(
-        llama.init_params(whole, jax.random.key(9))["layers"])[1])
+    lp = jax.tree.map(lambda a: a[0], llama.layer_stacks(
+        llama.init_params(whole, jax.random.key(9))["layers"])["rep"])
     lp = {**lp, "router_bias": 25.0 * lp["router_bias"]}
     x = jnp.asarray(np.random.default_rng(2).standard_normal(
         (40, cfg.hidden_size)), jnp.float32)
@@ -286,7 +293,7 @@ def test_the_whole_model_sharded_over_ep_routes_by_the_bias(tiny, ep):
 
 def test_a_biased_router_requires_its_bias(tiny):
     cfg = tiny[0]
-    lp = jax.tree.map(lambda a: a[0], llama.layer_groups(tiny[2]["layers"])[1])
+    lp = jax.tree.map(lambda a: a[0], llama.layer_stacks(tiny[2]["layers"])["rep"])
     x = jnp.ones((3, cfg.hidden_size), jnp.float32)
     with pytest.raises(KeyError, match="router_bias"):
         moe.route(x, {"router": lp["router"]}, cfg)
@@ -294,7 +301,7 @@ def test_a_biased_router_requires_its_bias(tiny):
 
 def test_held_rows_counts_and_padding(tiny):
     cfg = tiny[0]
-    lp = jax.tree.map(lambda a: a[0], llama.layer_groups(tiny[2]["layers"])[1])
+    lp = jax.tree.map(lambda a: a[0], llama.layer_stacks(tiny[2]["layers"])["rep"])
     x = jnp.asarray(np.random.default_rng(3).standard_normal(
         (10, cfg.hidden_size)), jnp.float32)
     live = jnp.arange(10) < 6
@@ -318,57 +325,6 @@ def test_a_share_has_to_divide_the_published_count(tmp_path):
         _config(tmp_path, num_experts=5)
     with pytest.raises(ValueError, match="group-limited"):
         _config(tmp_path, n_group=4, topk_group=2)
-
-
-# ---------------------------------------------------------------------------
-# the pattern scan against a plain loop
-# ---------------------------------------------------------------------------
-
-def _plain_loop(cfg, params, h, ck, cv, **kw):
-    lead, rep = llama.layer_groups(params["layers"])
-    for i in range(cfg.num_layers):
-        group, j = (lead, i) if i < cfg.first_k_dense else (
-            rep, i - cfg.first_k_dense)
-        lp = jax.tree.map(lambda a: a[j], group)
-        h, ck, cv, _ = llama._layer(cfg, lp, i, h, ck, cv,
-                                    window=cfg.window_of(i), **kw)
-    return h, ck, cv
-
-
-@pytest.mark.parametrize("layers", [5, 8, 48])
-def test_pattern_scan_matches_a_plain_loop(tmp_path, layers):
-    """Leading layer, whole periods scanned, a remainder: 5 = 1 + one
-    period; 8 = 1 + one period + 3; the published 48 = 1 + 11 periods + 3."""
-    cfg, _ = _config(
-        tmp_path, num_hidden_layers=layers,
-        layer_types=(TINY["layer_types"] * 12)[:layers],
-        mlp_layer_types=["dense"] + ["sparse"] * (layers - 1))
-    assert cfg.layer_period == (40, 40, 0, 40)
-    params = llama.init_params(cfg, jax.random.key(layers))
-    b, t = 2, 24
-    rng = np.random.default_rng(layers)
-    h = jnp.asarray(rng.standard_normal((b * t, cfg.hidden_size)), jnp.float32)
-    shape = (layers, 8, BS, cfg.num_kv_heads, cfg.head_dim)
-    ck = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    cv = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    q_start = jnp.asarray([50, 3], jnp.int32)
-    q_len = jnp.asarray([t, t], jnp.int32)
-    bt = jnp.asarray([[1, 2, 3, 4, 5], [6, 7, 0, 0, 0]], jnp.int32)
-    lay, valid = llama.token_layout(q_len, b, t, b * t)
-    positions, slot = llama._positions_and_slots(lay, valid, q_start, bt, BS)
-    kw = dict(lay=lay, positions=positions, slot=slot, block_tables=bt,
-              q_start=q_start, kv_lens=q_start + q_len, moe_impl="held")
-    got = jax.jit(lambda: llama._run_layers(cfg, params["layers"], h, ck, cv,
-                                            **kw))()
-    want = jax.jit(lambda: _plain_loop(cfg, params, h, ck, cv, **kw))()
-    assert int(got[3][0]) > 0
-    # The same float32 operations in another program: rounding alone, which
-    # grows with the depth as the hidden state does (a post-norm residual
-    # adds a unit-norm vector a sub-layer: |h| ~ 10 after 48 layers, where
-    # 4e-5 was read).
-    for a, w in zip(got[:3], want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
-                                   atol=1e-5 + 2e-6 * layers)
 
 
 # ---------------------------------------------------------------------------
@@ -561,7 +517,7 @@ def test_a_dense_configuration_resolves_to_what_it_did(name):
         max_position_embeddings=cfg.max_position_embeddings,
         tie_word_embeddings=cfg.tie_word_embeddings)
     assert cfg == plain
-    assert cfg.layer_period == (0,) and not cfg.holds_share
+    assert _period_windows(cfg) == (0,) and not cfg.holds_share
     assert cfg.rope_theta == 1000000.0 and isinstance(cfg.rope_theta, float)
 
 
@@ -577,7 +533,7 @@ def test_the_published_configuration_resolves():
     assert cfg.holds_share and cfg.first_k_dense == 1 and cfg.num_layers == 5
     assert (cfg.router_scoring, cfg.router_bias, cfg.norm_topk_prob,
             cfg.routed_scaling_factor) == ("sigmoid", True, True, 2.5)
-    assert cfg.layer_period == (128, 128, 0, 128)
+    assert _period_windows(cfg) == (128, 128, 0, 128)
     assert [cfg.window_of(i) for i in range(5)] == [128, 128, 128, 0, 128]
     assert (cfg.qk_norm, cfg.rope_scope, cfg.norm_placement) == (
         True, "sliding", "post")
@@ -586,11 +542,12 @@ def test_the_published_configuration_resolves():
     assert len(model["layer_types"]) == 48
     deep = dataclasses.replace(
         cfg, num_layers=48, layer_types=tuple(model["layer_types"]))
-    assert deep.layer_period == (128, 128, 0, 128)
+    assert _period_windows(deep) == (128, 128, 0, 128)
     assert (48 - 1) // 4 == 11 and (48 - 1) % 4 == 3
     # the parameter tree: a leading group beside the repeated one
     shapes = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
-    lead, rep = llama.layer_groups(shapes["layers"])
+    stacks = llama.layer_stacks(shapes["layers"])
+    lead, rep = stacks["lead"], stacks["rep"]
     assert lead["w_gate"].shape == (1, 6144, 18432)
     assert rep["w_gate"].shape == (4, 16, 6144, 2048)
     assert rep["router"].shape == (4, 6144, 128)

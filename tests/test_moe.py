@@ -1,4 +1,4 @@
-"""EP capacity-dispatch MoE vs dense-dispatch equivalence + sharded compile."""
+"""Dropless expert-parallel MoE against the all-experts form, off and on a mesh."""
 
 import jax
 import jax.numpy as jnp
@@ -7,8 +7,7 @@ import pytest
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import resolve_model_config
-from dynamo_tpu.models.moe import expert_capacity, moe_mlp_ep
-from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh, param_sharding_rules
+from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -22,49 +21,9 @@ def moe_case():
     return cfg, lp, x
 
 
-def test_ep_matches_dense_with_capacity(moe_case):
-    cfg, lp, x = moe_case
-    ref = llama.moe_mlp(x, lp, cfg)
-    out = moe_mlp_ep(x, lp, cfg, capacity_factor=8.0)  # no drops
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
-
-
-def test_ep_drops_under_pressure(moe_case):
-    """Tiny capacity drops tokens: output differs but stays finite."""
-    cfg, lp, x = moe_case
-    out = np.asarray(moe_mlp_ep(x, lp, cfg, capacity_factor=0.1))
-    assert np.isfinite(out).all()
-
-
-def test_capacity_rounding():
-    assert expert_capacity(64, 8, 2, 1.0) % 8 == 0
-    assert expert_capacity(1, 8, 1, 1.0) >= 8
-
-
-def test_ep_compiles_on_expert_mesh(moe_case):
-    """Jit with expert-sharded weights on an 8-device mesh: GSPMD must place
-    the all-to-alls and produce the same numbers."""
-    cfg, lp, x = moe_case
-    mesh = make_mesh(MeshConfig(ep=8))
-    axes = {
-        "router": (None, "expert"),
-        "w_gate": ("expert", None, "moe_mlp"),
-        "w_up": ("expert", None, "moe_mlp"),
-        "w_down": ("expert", "moe_mlp", None),
-    }
-    sharded = {
-        k: jax.device_put(v, param_sharding_rules(mesh, axes.get(k, (None,) * v.ndim)))
-        for k, v in lp.items()
-    }
-    ref = llama.moe_mlp(x, lp, cfg)
-    fn = jax.jit(lambda x, w: moe_mlp_ep(x, w, cfg, capacity_factor=8.0))
-    out = fn(x, sharded)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
-
-
 # ---------------------------------------------------------------------------
-# Dropless dispatch (moe_mlp_dropless): exact under ANY routing skew —
-# the property the capacity formulation cannot give a serving engine.
+# Dropless dispatch (moe_mlp_dropless): exact under ANY routing skew,
+# the property a capacity-bounded dispatch cannot give a serving engine.
 # ---------------------------------------------------------------------------
 
 def test_dropless_matches_dense(moe_case):
@@ -78,9 +37,8 @@ def test_dropless_matches_dense(moe_case):
 
 def test_dropless_exact_under_total_skew(moe_case):
     """Router biased so EVERY token picks the same expert — the worst
-    over-capacity regime. Dropless must still equal the dense reference
-    (the capacity version drops all but C choices here)."""
-    from dynamo_tpu.models.moe import moe_mlp_dropless, moe_mlp_ep
+    over-capacity regime. Dropless must still equal the dense reference."""
+    from dynamo_tpu.models.moe import moe_mlp_dropless
 
     cfg, lp, x = moe_case
     lp_skew = dict(lp)
@@ -90,11 +48,6 @@ def test_dropless_exact_under_total_skew(moe_case):
     ref = llama.moe_mlp(x, lp_skew, cfg)
     out = moe_mlp_dropless(x, lp_skew, cfg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
-    # And the capacity version demonstrably DOES diverge here (factor 1.0
-    # cannot hold 32 tokens x k choices on one expert) — the gap this
-    # formulation closes.
-    capped = moe_mlp_ep(x, lp_skew, cfg, capacity_factor=1.0)
-    assert not np.allclose(np.asarray(capped), np.asarray(ref), atol=1e-4)
 
 
 def test_dropless_ep_sharded_matches_dense(moe_case):

@@ -549,7 +549,7 @@ def test_step_reads_the_qkv_matrices_in_place_on_v5e(v5e_device, monkeypatch,
     ``wo`` out of its stack, alone or a period of them at a time, or copies
     one into another layout. The three products reach the compiler as
     ``[N, H] x [H, out]`` and read the stack where it lies, as ``wo`` and
-    the MLP's three do (models/llama.py ``_layer``); with the head split
+    the MLP's three do (models/llama.py ``_attention``); with the head split
     folded into the dot the weight operand was the matrix transposed, and a
     slice and a copy of it, 15 % of a decode step's device time, ran in
     every layer (PERF.md section 6, PR 40). A scan over periods of several
@@ -870,12 +870,14 @@ def _step_text(monkeypatch, config: str, b: int, t: int) -> str:
 
 def _without_metadata(text: str) -> str:
     """A program's text less what names its source: the header's tables of
-    files, functions, locations and frames, and each instruction's
-    ``metadata``."""
+    files, functions, locations and frames, each instruction's ``metadata``,
+    and a kernel's payload, whose debug locations hold the call stack up to
+    the caller's own line."""
     import re
 
     if "StackFrames" in text:
         text = text[text.index("\n\n", text.index("StackFrames")):]
+    text = re.sub(r'"body":"[A-Za-z0-9+/=]+"', '"body":"<kernel>"', text)
     return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
 
 

@@ -55,6 +55,13 @@ LOGIT_TOL = 2e-4
 N_TOKENS = 100
 
 
+def _period_windows(cfg) -> tuple[int, ...]:
+    """The attention windows of the layers of one period of the plan."""
+    p = cfg.layer_plan
+    return tuple(layer[0].window
+                 for layer in p.layers[p.lead:p.lead + p.period])
+
+
 def _reference():
     spec = importlib.util.spec_from_file_location(
         "smallthinker_reference", CONFIG_DIR / "reference.py")
@@ -214,11 +221,11 @@ def test_whole_held_rows_equal_the_all_experts_form(tiny, act, routing):
                            np.asarray(y), atol=1e-3)
 
 
-@pytest.mark.parametrize("impl", ["dropless_ep2", "dropless_ep4", "capacity"])
+@pytest.mark.parametrize("impl", ["dropless_ep2", "dropless_ep4"])
 def test_the_sharded_forms_take_the_activation_and_the_routing(tiny, impl):
-    """``moe_mlp_dropless`` and ``moe_mlp_ep`` compute ReLU-gated experts
-    from a routing made elsewhere, as ``held_rows`` does: none applies SiLU
-    or routes again from its own input."""
+    """``moe_mlp_dropless`` computes ReLU-gated experts from a routing made
+    elsewhere, as ``held_rows`` does: it neither applies SiLU nor routes
+    again from its own input."""
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
     cfg = tiny[0]
@@ -228,12 +235,9 @@ def test_the_sharded_forms_take_the_activation_and_the_routing(tiny, impl):
     routing = moe.route(jnp.asarray(rng.standard_normal(
         (40, cfg.hidden_size)), jnp.float32), lp, cfg)
     want = llama.moe_mlp(x, lp, cfg, routing)
-    if impl == "capacity":
-        got = moe.moe_mlp_ep(x, lp, cfg, capacity_factor=8.0, routing=routing)
-    else:
-        mesh = make_mesh(MeshConfig(ep=int(impl[-1])))
-        got = jax.jit(lambda x, w, r: moe.moe_mlp_dropless(
-            x, w, cfg, mesh=mesh, routing=r))(x, lp, routing)
+    mesh = make_mesh(MeshConfig(ep=int(impl[-1])))
+    got = jax.jit(lambda x, w, r: moe.moe_mlp_dropless(
+        x, w, cfg, mesh=mesh, routing=r))(x, lp, routing)
     # float32 partial sums in another order
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     silu = llama.moe_mlp(x, lp, dataclasses.replace(cfg, expert_act="silu"),
@@ -304,7 +308,7 @@ def test_the_catalog_rows_config_resolves_verbatim(tmp_path):
         64, 6, 768, 0, 0, 64)
     assert cfg.intermediate_size == 0 and not cfg.holds_share
     assert (cfg.router_scoring, cfg.router_bias) == ("softmax", False)
-    assert cfg.layer_period == (0, 4096, 4096, 4096)
+    assert _period_windows(cfg) == (0, 4096, 4096, 4096)
     assert [cfg.window_of(i) for i in (0, 1, 4, 51)] == [0, 4096, 0, 4096]
     assert cfg.rope_scope == "sliding" and cfg.rope_theta == 1.5e6
     assert cfg.rms_norm_eps == 1e-6 and not cfg.tie_word_embeddings
@@ -322,7 +326,7 @@ def test_the_cells_configuration_is_the_catalog_row_cut_in_depth():
     assert set(model) - set(row) - {"_name_or_path", "assumed"} \
         == set(model["assumed"])
     cfg = ModelConfig.from_hf_config(str(CONFIG_DIR))
-    assert cfg.num_layers == 12 and cfg.layer_period == (0, 4096, 4096, 4096)
+    assert cfg.num_layers == 12 and _period_windows(cfg) == (0, 4096, 4096, 4096)
     assert (cfg.expert_act, cfg.router_input) == ("relu", "attn_norm")
     shapes = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
     assert shapes["layers"]["w_gate"].shape == (12, 64, 2560, 768)
