@@ -5,10 +5,13 @@ The device tier (G1) of the KV block story: cache tensors are
 over the mesh "model" axis on kv_heads. Block 0 is reserved as the trash
 block for padding writes (models/llama.py). Host/disk tiers and offload live
 in dynamo_tpu.kvbm (reference: lib/llm/src/block_manager/). ``layers`` counts
-the layers that have attention: a model whose layers are one mixer each
+the layers that have attention (``ModelConfig.attn_layers``, read off the
+layer plan): a model whose layers are one mixer each
 (``ModelConfig.hybrid_pattern``) keeps K and V for its attention layers
-alone, and for its recurrent layers a second kind of cache beside this one,
-a pool of fixed-size state a sequence (models/mamba.py; ``ModelRunner.ssm``).
+alone, and a model with recurrent mixers keeps a second kind of cache beside
+this one, a pool of fixed-size state a sequence (models/mamba.py;
+``ModelRunner.ssm``), for some layers or, where every layer has both mixers
+(``ssm_beside_attention``), for every layer that is here too.
 
 With ``kv_dtype="int8"`` each cache becomes a two-leaf pytree
 ``{"q": int8 payload [L, NB, BS, KH, D], "s": float32 scales [L, NB, KH]}``

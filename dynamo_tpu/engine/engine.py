@@ -127,6 +127,7 @@ def _recurrent_engine_config(ec: EngineConfig) -> EngineConfig:
         "spec_ngram": (ec.spec_ngram > 0,
                        "a rejected draft would need the state rolled back"),
         "tp": (ec.tp > 1, "the state and the mixer are not sharded"),
+        "pp": (ec.pp > 1, "the state pool is not divided over stages"),
         "sp": (ec.sp > 1, "the scan does not run over a 'seq' axis"),
         "ep": (ec.ep > 1, "the experts' exchange is not wired to a pattern"),
         "kv_dtype": (ec.kv_dtype in ("int8", "int4"),
@@ -349,7 +350,9 @@ class EngineMetrics:
             "kv_quant_enabled": self.kv_quant_enabled,
             "kv_cache_shape": list(self.kv_cache_shape),
             **({"moe": self.moe} if self.moe else {}),
-            **({"ssm": self.ssm} if self.ssm else {}),
+            # Beside the pool's shapes, its rows that a sequence holds now.
+            **({"ssm": {**self.ssm, "slots_in_use": sched.slots_in_use}}
+               if self.ssm else {}),
             **({"step_shapes": self.step_shapes} if self.step_shapes else {}),
             "kv_pool_blocks": self.kv_pool_blocks,
             "kv_block_bytes": self.kv_block_bytes,

@@ -253,6 +253,12 @@ class Scheduler:
     def num_running(self) -> int:
         return len(self.running)
 
+    @property
+    def slots_in_use(self) -> int:
+        """Rows of the sampling state, and of the recurrent state's pool,
+        that a sequence holds (``stats()["ssm"]``)."""
+        return self.max_batch_size - len(self._slot_free)
+
     # ------------------------------------------------------------------
     def _try_admit(self, seq: Seq) -> bool:
         """Admit a waiting seq: match cached prefix, allocate prompt blocks,

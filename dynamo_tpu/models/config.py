@@ -15,6 +15,8 @@ from functools import cached_property
 from pathlib import Path
 from typing import NamedTuple
 
+import numpy as np
+
 
 class Mixer(NamedTuple):
     """One sub-layer, ``h + mixer(norm(h))``, and where its operands lie.
@@ -33,6 +35,10 @@ class Mixer(NamedTuple):
     #: model's attention layers from the first; every other kind, ``place``
     layer: int
     window: int = 0     # attention's window, 0 = full or no attention
+    #: it stands beside the mixer before it: it reads the output of that
+    #: mixer's norm and the two are added to the residual in one add
+    #: (Falcon-H1's attention and Mamba-2 mixer); it has no norm of its own
+    joined: bool = False
 
 
 class LayerPlan(NamedTuple):
@@ -167,6 +173,26 @@ class ModelConfig:
     # The stored recurrent state's type ("float32" alone is served); the
     # convolution tail is ``dtype``.
     ssm_state_dtype: str = "float32"
+    # Every layer's attention has a Mamba-2 mixer beside it (``Mixer.joined``:
+    # one norm feeds both, one add takes both), and the FFN follows: three
+    # mixers a layer from one place of the repeated stack, recurrent state
+    # and keys and values in every layer (Falcon-H1's block).
+    ssm_beside_attention: bool = False
+    # Scalars on activations, as a muP-parametrised model publishes them
+    # (Falcon-H1's keys; 1 / () = none, and then no operation of a program):
+    # on the embedding's rows and on the logits; on attention's input, on
+    # ``k`` and on attention's output; on the Mamba mixer's input, on the
+    # five slices z, x, B, C, dt of its in-projection's output and on its
+    # output; on the FFN's gate (before the activation) and on its output.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = ()
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple[float, ...] = ()
     # Multimodal (vision encoder attached)
     vision: "VisionConfig | None" = None
 
@@ -194,16 +220,30 @@ class ModelConfig:
                 raise ValueError(
                     f"hybrid_pattern {self.hybrid_pattern!r}: one of 'M', "
                     f"'*', 'E' for each of {self.num_layers} layers")
-            if "M" in self.hybrid_pattern and (
-                    self.ssm_state_dtype != "float32"
-                    or (self.mamba_num_heads % max(self.ssm_groups, 1))):
-                raise ValueError(
-                    "the recurrent state is stored as float32 (a bfloat16 "
-                    "or quantized state is not implemented) and the Mamba "
-                    "heads divide into ssm_groups equal groups")
-            if self.first_k_dense or self.layer_types:
+            if (self.first_k_dense or self.layer_types
+                    or self.ssm_beside_attention):
                 raise ValueError("a hybrid_pattern gives every layer's kind: "
-                                 "no first_k_dense, no layer_types")
+                                 "no first_k_dense, no layer_types, no "
+                                 "ssm_beside_attention")
+        if self.ssm_beside_attention and (
+                self.is_moe or self.norm_placement != "pre"):
+            raise ValueError(
+                "ssm_beside_attention is implemented for a model of dense "
+                "FFNs under norm_placement 'pre' (models/llama.py "
+                "_run_layers): no routed layer, no 'post'")
+        if self.has_ssm and (
+                self.ssm_state_dtype != "float32"
+                or (self.mamba_num_heads % max(self.ssm_groups, 1))):
+            raise ValueError(
+                "the recurrent state is stored as float32 (a bfloat16 "
+                "or quantized state is not implemented) and the Mamba "
+                "heads divide into ssm_groups equal groups")
+        if len(self.ssm_multipliers) not in (0, 5) or len(
+                self.mlp_multipliers) not in (0, 2):
+            raise ValueError(
+                "ssm_multipliers has one scalar for each of z, x, B, C, dt "
+                "and mlp_multipliers one for the gate and one for the "
+                f"output: got {self.ssm_multipliers}, {self.mlp_multipliers}")
         if self.router_input not in ("mlp_norm", "attn_norm"):
             raise ValueError(f"router_input {self.router_input!r}: the "
                              "router reads 'mlp_norm' or 'attn_norm'")
@@ -225,14 +265,16 @@ class ModelConfig:
     @property
     def has_ssm(self) -> bool:
         """Whether some layer carries recurrent state (models/mamba.py)."""
-        return "M" in self.hybrid_pattern
+        return self.layers_of("M") > 0
 
     @cached_property
     def layer_plan(self) -> LayerPlan:
         """The one description of the model's layers: every reader of their
         kinds, places, windows and of where the scan stands reads this. A
         hybrid pattern's layer is one mixer from its kind's stack; any
-        other model's is attention then an FFN from one place of one."""
+        other model's is attention then an FFN from one place of one, with
+        a Mamba-2 mixer joined to attention under
+        ``ssm_beside_attention``."""
         layers = []
         if self.hybrid_pattern:
             seen = dict.fromkeys("M*E", 0)
@@ -244,11 +286,13 @@ class ModelConfig:
                 leads = i < self.first_k_dense
                 stack, place = ("lead", i) if leads else (
                     "rep", i - self.first_k_dense)
+                beside = ((Mixer("M", stack, place, place, joined=True),)
+                          if self.ssm_beside_attention else ())
                 layers.append((
-                    Mixer("*", stack, place, i, self.window_of(i)),
+                    Mixer("*", stack, place, i, self.window_of(i)), *beside,
                     Mixer("E" if self.is_moe and not leads else "-", stack,
                           place, place)))
-        shapes = [tuple((m.kind, m.stack, m.window) for m in layer)
+        shapes = [tuple((m.kind, m.stack, m.window, m.joined) for m in layer)
                   for layer in layers]
         return LayerPlan(tuple(layers), *_split(
             shapes, self.first_k_dense, self.pattern_len))
@@ -297,6 +341,42 @@ class ModelConfig:
         """c: the channels the convolution runs over, x | B | C."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
+    @cached_property
+    def ssm_column_multipliers(self) -> "np.ndarray | None":
+        """The multiplier of each column of the Mamba in-projection's output,
+        float32 ``[d + c + heads]``: ``ssm_multipliers``' five scalars over
+        the slices z, x, B, C, dt in the projection's own order; None where
+        the model publishes none."""
+        if not self.ssm_multipliers:
+            return None
+        gn = self.ssm_groups * self.ssm_state_size
+        widths = (self.ssm_inner, self.ssm_inner, gn, gn, self.mamba_num_heads)
+        return np.repeat(np.float32(self.ssm_multipliers), widths)
+
+    @cached_property
+    def init_gain(self) -> dict:
+        """What the seeded init multiplies a matrix's ``fan_in^-0.5`` by, by
+        leaf (a scalar, for ``ssm_in`` one a column; no entry where it is 1):
+        1 over the multipliers that stand between the matrix's input and its
+        product's use, so that a branch gives what it gives in a model
+        without multipliers. At plain fan-in scale the published multipliers
+        leave every logit within 0.01 of 0 and a broken mixer inside bf16's
+        rounding: the comparison with a reference would see nothing."""
+        a_in, columns = self.attention_in_multiplier, self.ssm_column_multipliers
+        gate, down = self.mlp_multipliers or (1.0, 1.0)
+        gain = {
+            "embed": 1 / self.embedding_multiplier,
+            "lm_head": 1 / self.lm_head_multiplier,
+            "wq": 1 / a_in, "wv": 1 / a_in,
+            "wk": 1 / (a_in * self.key_multiplier),
+            "wo": 1 / self.attention_out_multiplier,
+            "ssm_in": 1 / (self.ssm_in_multiplier
+                           * (1.0 if columns is None else columns)),
+            "ssm_out": 1 / self.ssm_out_multiplier,
+            "w_gate": 1 / gate, "w_down": 1 / down,
+        }
+        return {k: g for k, g in gain.items() if np.any(g != 1.0)}
+
     def window_of(self, layer: int) -> int:
         """Layer ``layer``'s attention window, 0 = full."""
         if self.layer_types and self.layer_types[layer] == "sliding_attention":
@@ -315,7 +395,7 @@ class ModelConfig:
     def from_hf_config(cls, path: str) -> "ModelConfig":
         """Read a local HF config.json (llama-family keys)."""
         cfg = json.loads((Path(path) / "config.json").read_text())
-        cfg = _nemotron_h_keys(_smallthinker_keys(cfg))
+        cfg = _falcon_h1_keys(_nemotron_h_keys(_smallthinker_keys(cfg)))
         n_heads = cfg["num_attention_heads"]
         # MoE keys across HF families: mixtral (num_local_experts),
         # deepseek/qwen-moe (n_routed_experts, num_experts).
@@ -382,6 +462,8 @@ class ModelConfig:
             time_step_max=cfg.get("time_step_max", 0.1),
             time_step_floor=cfg.get("time_step_floor", 1e-4),
             ssm_state_dtype=cfg.get("ssm_state_dtype", "float32"),
+            # (the fields that one family's reader alone sets)
+            **cfg.get(_OWN_FIELDS, {}),
             name=cfg.get("_name_or_path", Path(path).name),
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
@@ -492,6 +574,73 @@ def _nemotron_h_keys(cfg: dict) -> dict:
         "rope_scope": "none",
         "rms_norm_eps": cfg.get("layer_norm_epsilon",
                                 cfg.get("norm_eps", 1e-5)),
+    }
+
+
+#: where a family's reader puts the ``ModelConfig`` fields that no key of
+#: the common reader stands for
+_OWN_FIELDS = "model_config_fields"
+
+
+def _falcon_h1_keys(cfg: dict) -> dict:
+    """``cfg`` with Falcon-H1's keys (``model_type: "falcon_h1"``: in every
+    block attention and a Mamba-2 mixer side by side under one norm, then a
+    gated MLP; muP multipliers on the activations) under the names
+    ``from_hf_config`` reads, the multipliers as the fields they are; any
+    other config comes back as it is. What cannot be served is refused by
+    its key."""
+    if cfg.get("model_type") != "falcon_h1":
+        return cfg
+    for key in ("attention_bias", "mamba_proj_bias", "mlp_bias",
+                "projectors_bias"):
+        if cfg.get(key):
+            raise ValueError(
+                f"{key}: true is not implemented: models/mamba.py and "
+                "models/llama.py project without bias")
+    refused = {
+        "attn_layer_indices": (
+            cfg.get("attn_layer_indices") is not None,
+            "attention in some blocks alone is not implemented: every block "
+            "has both mixers"),
+        "rope_scaling": (cfg.get("rope_scaling") is not None,
+                         "models/llama.py rope() scales no frequency"),
+        "mamba_norm_before_gate": (
+            bool(cfg.get("mamba_norm_before_gate", False)),
+            "models/mamba.py gates first and norms then"),
+        "mamba_rms_norm": (not cfg.get("mamba_rms_norm", True),
+                           "models/mamba.py norms the gated output"),
+        "mamba_use_mlp": (not cfg.get("mamba_use_mlp", True),
+                          "a block without its MLP is not implemented"),
+        "hidden_act": (cfg.get("hidden_act", "silu") != "silu",
+                       "the MLP and the Mamba gate act by silu"),
+    }
+    for key, (hit, why) in refused.items():
+        if hit:
+            raise ValueError(f"{key}: {cfg.get(key)!r} is refused: {why}")
+    heads, p = cfg["mamba_n_heads"], cfg["mamba_d_head"]
+    inner = cfg.get("mamba_d_ssm") or cfg["mamba_expand"] * cfg["hidden_size"]
+    if inner != heads * p:
+        raise ValueError(
+            f"mamba_d_ssm {inner} is not mamba_n_heads x mamba_d_head "
+            f"({heads} x {p}): the mixer's inner width is its heads'")
+    return {
+        **cfg,
+        "mamba_num_heads": heads,
+        "mamba_head_dim": p,
+        "ssm_state_size": cfg["mamba_d_state"],
+        "n_groups": cfg["mamba_n_groups"],
+        "conv_kernel": cfg["mamba_d_conv"],
+        "chunk_size": cfg["mamba_chunk_size"],
+        _OWN_FIELDS: {
+            "ssm_beside_attention": True,
+            **{k: float(cfg.get(k, 1.0)) for k in (
+                "embedding_multiplier", "lm_head_multiplier",
+                "attention_in_multiplier", "key_multiplier",
+                "attention_out_multiplier", "ssm_in_multiplier",
+                "ssm_out_multiplier")},
+            **{k: tuple(float(x) for x in cfg.get(k) or ())
+               for k in ("ssm_multipliers", "mlp_multipliers")},
+        },
     }
 
 

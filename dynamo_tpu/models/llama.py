@@ -148,6 +148,10 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     """Logical axis names per parameter leaf (for mesh sharding rules)."""
     layer = (_hybrid_axes(cfg) if cfg.hybrid_pattern
              else _layer_axes(cfg, cfg.is_moe))
+    if cfg.ssm_beside_attention:
+        from dynamo_tpu.models import mamba
+
+        layer.update(mamba.logical_axes(own_norm=False))
     if cfg.first_k_dense:
         layer.update({LEAD + k: v
                       for k, v in _layer_axes(cfg, False).items()})
@@ -178,8 +182,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     h = cfg.hidden_size
     L = cfg.num_layers - cfg.first_k_dense     # the repeated group
 
-    def whole(key, shape, fan_in, pad=None):
-        w = (jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
+    gain = cfg.init_gain        # {} for a model without multipliers
+
+    def whole(key, shape, fan_in, pad=None, leaf=None):
+        w = jax.random.normal(key, shape, jnp.float32) * (fan_in**-0.5)
+        if leaf in gain:        # a scalar, or one a column
+            w = w * gain[leaf]
+        w = w.astype(dt)
         if pad:     # (axis from the end, stored size): zeros behind the draw
             axis, size = pad
             widths = [(0, 0)] * len(shape)
@@ -187,18 +196,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             w = jnp.pad(w, widths)
         return w
 
-    def dense(key, shape, fan_in, pad=None):
+    def dense(key, shape, fan_in, pad=None, leaf=None):
         if math.prod(shape) <= INIT_WHOLE_MAX:
-            return whole(key, shape, fan_in, pad)
-        return lax.map(lambda k1: whole(k1, shape[1:], fan_in, pad),
+            return whole(key, shape, fan_in, pad, leaf)
+        return lax.map(lambda k1: whole(k1, shape[1:], fan_in, pad, leaf),
                        jax.random.split(key, shape[0]))
 
     def attention(L):
         out = {
-            "wq": dense(next(k), (L, h, cfg.q_size), h),
-            "wk": dense(next(k), (L, h, cfg.kv_size), h),
-            "wv": dense(next(k), (L, h, cfg.kv_size), h),
-            "wo": dense(next(k), (L, cfg.q_size, h), cfg.q_size),
+            "wq": dense(next(k), (L, h, cfg.q_size), h, leaf="wq"),
+            "wk": dense(next(k), (L, h, cfg.kv_size), h, leaf="wk"),
+            "wv": dense(next(k), (L, h, cfg.kv_size), h, leaf="wv"),
+            "wo": dense(next(k), (L, cfg.q_size, h), cfg.q_size, leaf="wo"),
             "attn_norm": jnp.ones((L, h), dt),
             "mlp_norm": jnp.ones((L, h), dt),
         }
@@ -209,9 +218,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     def dense_ffn(L):
         i = cfg.intermediate_size
-        return {"w_gate": dense(next(k), (L, h, i), h),
+        return {"w_gate": dense(next(k), (L, h, i), h, leaf="w_gate"),
                 "w_up": dense(next(k), (L, h, i), h),
-                "w_down": dense(next(k), (L, i, h), i)}
+                "w_down": dense(next(k), (L, i, h), i, leaf="w_down")}
 
     if cfg.hybrid_pattern:
         return _init_hybrid(cfg, k, dense, attention)
@@ -234,14 +243,20 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     else:
         layer.update(dense_ffn(L))
     params: Params = {
-        "embed": dense(next(k), (cfg.vocab_size, h), h),
+        "embed": dense(next(k), (cfg.vocab_size, h), h, leaf="embed"),
         "final_norm": jnp.ones((h,), dt),
         "layers": layer,
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = dense(next(k), (h, cfg.vocab_size), h)
+        params["lm_head"] = dense(next(k), (h, cfg.vocab_size), h,
+                                  leaf="lm_head")
     # Drawn behind everything above, so that a model without them keeps
     # the weights its seed always gave.
+    if cfg.ssm_beside_attention:
+        # The mixer beside attention reads attention's norm: none of its own.
+        from dynamo_tpu.models import mamba
+
+        layer.update(mamba.init_layers(cfg, dense, next(k), L, own_norm=False))
     if cfg.is_moe and cfg.router_bias:
         # A selection bias is learned to even the experts' load: small
         # beside the scores it is added to, and not zero, so that a choice
@@ -502,8 +517,15 @@ def embed_lookup(embed, token_ids: jax.Array, dt) -> jax.Array:
     return embed[token_ids].astype(dt)
 
 
-def swiglu(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
-    return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+def swiglu(x: jax.Array, w_gate, w_up, w_down,
+           multipliers: tuple[float, ...] = ()) -> jax.Array:
+    """``multipliers``: a scalar on the gate before its activation and one
+    on the output (``ModelConfig.mlp_multipliers``); () is none."""
+    gate = mm(x, w_gate)
+    if multipliers:
+        gate = gate * multipliers[0]
+    out = mm(jax.nn.silu(gate) * mm(x, w_up), w_down)
+    return out * multipliers[1] if multipliers else out
 
 
 def moe_mlp(x: jax.Array, lp: Params, cfg: ModelConfig,
@@ -631,9 +653,14 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     # operand [heads, D, H], the stored matrix transposed, and the compiler
     # then cuts the layer's matrix out of its stack and copies it in that
     # layout in every layer of every step (PERF.md section 6, PR 40).
+    # (a multiplier of 1 is no operation of the program, here and below)
+    if cfg.attention_in_multiplier != 1.0:
+        x = x * cfg.attention_in_multiplier
     with _perf_phase("proj"):
         q, k, v = jax.lax.optimization_barrier(
             (mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])))
+    if cfg.key_multiplier != 1.0:
+        k = k * cfg.key_multiplier
     q = q.reshape(n, cfg.num_heads, cfg.head_dim)
     k = k.reshape(n, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(n, cfg.num_kv_heads, cfg.head_dim)
@@ -649,6 +676,14 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     with _perf_phase("scatter"):
         cache_k = _scatter_kv(cache_k, k, slot, layer)
         cache_v = _scatter_kv(cache_v, v, slot, layer)
+
+    def out_proj(attn):
+        with _perf_phase("proj"):
+            out = mm(attn, lp["wo"])
+        if cfg.attention_out_multiplier != 1.0:
+            out = out * cfg.attention_out_multiplier
+        return out
+
     kernel = attn_impl in ("pallas", "pallas_interpret")
     if kernel:
         from dynamo_tpu.ops.paged_attention import (
@@ -672,9 +707,7 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
         with _perf_phase("attention"):
             attn = attend(q, cache_k, cache_v, block_tables, q_start,
                           kv_lens, starts=lay.starts, t=lay.t)
-        with _perf_phase("proj"):
-            attn = mm(attn.reshape(n, cfg.q_size), lp["wo"])
-        return attn, cache_k, cache_v
+        return out_proj(attn.reshape(n, cfg.q_size)), cache_k, cache_v
     # The rows are gathered from q's grouped view [N, KH, REP, D], the split
     # the kernel's wrapper makes of them anyway: from [N, heads, D] the
     # compiler moves a chunk step's [B, T] rectangle twice on its way to
@@ -703,9 +736,7 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
                 q_start[:, None] + jnp.arange(lay.t)[None, :], kv_lens,
                 window=window)
     attn = lay.to_tokens(attn).reshape(n, cfg.q_size)
-    with _perf_phase("proj"):
-        attn = mm(attn, lp["wo"])
-    return attn, cache_k, cache_v
+    return out_proj(attn), cache_k, cache_v
 
 
 def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
@@ -736,11 +767,13 @@ def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
             mlp_out = moe_mlp(x, lp, cfg, routing)
     else:
         with _perf_phase("mlp"):
-            mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+            mlp_out = swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                             cfg.mlp_multipliers)
     return mlp_out, counts
 
 
-#: the norm a mixer reads, by its kind
+#: the norm a mixer reads, by its kind (a joined mixer has none of its own:
+#: it reads what the mixer before it read)
 _NORM = {"*": "attn_norm", "-": "mlp_norm", "E": "mlp_norm", "M": "ssm_norm"}
 
 
@@ -759,7 +792,10 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
     A layer is its mixers in order, each ``h + mixer(norm(h))`` (or, under
     ``cfg.norm_placement == "post"``, ``h + norm(mixer(h))``) under the
     norm its kind names: attention (:func:`_attention`), an FFN dense or
-    routed (:func:`_ffn`), a Mamba-2 mixer (models/mamba.py). Token-major:
+    routed (:func:`_ffn`), a Mamba-2 mixer (models/mamba.py). A mixer that
+    is ``joined`` stands beside the one before it: the two read one norm's
+    output and their outputs are summed into one add to the residual,
+    ``h + (a(u) + s(u))`` with ``u = norm(h)``. Token-major:
     ``hid [N, H]`` throughout. What differs from layer to layer (a mixer's
     kind, its window) is static structure of the body, never a traced
     branch; what is the same is traced once a period. One thing couples a
@@ -825,9 +861,12 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
         # of q and of the attention output around the kernel) is the
         # layer's rest (obs/profiler.py DEVICE_PHASES).
         with _perf_phase("layer"):
-            for m in mixers:
-                i, norm = place[m.stack], lp[_NORM[m.kind]]
-                x = hid if post else rms_norm(hid, norm, cfg.rms_norm_eps)
+            beside = None       # the output a joined mixer is added to
+            for m, after in zip(mixers, (*mixers[1:], None)):
+                i = place[m.stack]
+                if not m.joined:
+                    norm = lp[_NORM[m.kind]]
+                    x = hid if post else rms_norm(hid, norm, cfg.rms_norm_eps)
                 if m.kind == "*":
                     if cfg.router_input == "attn_norm" and any(
                             o.kind == "E" for o in mixers):
@@ -850,6 +889,11 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                     out, c = _ffn(cfg, lp, x, routing, moe_impl, mesh, live)
                     if c is not None:
                         counts = counts + c
+                if m.joined:
+                    out = beside + out
+                if after is not None and after.joined:
+                    beside = out
+                    continue
                 if post:
                     out = rms_norm(out, norm, cfg.rms_norm_eps)
                 hid = hid + out
@@ -997,6 +1041,8 @@ def forward(
             # bookkeeping — see preprocessor digest-salted placeholders).
             h = jnp.where(lay.to_tokens(embed_mask)[:, None],
                           lay.to_tokens(embed_override).astype(h.dtype), h)
+        if cfg.embedding_multiplier != 1.0:
+            h = h * cfg.embedding_multiplier
 
     h, cache_k, cache_v, ssm, counts = _run_layers(
         cfg, cfg.layer_plan, params["layers"], h, cache_k, cache_v, ssm,
@@ -1212,6 +1258,11 @@ def logits_from_hidden(params: Params, cfg: ModelConfig, hidden: jax.Array) -> j
         if cfg.tie_word_embeddings:
             e = params["embed"]
             if isinstance(e, dict):
-                return (hidden @ e["q"].astype(hidden.dtype).T) * e["sr"].astype(hidden.dtype)
-            return hidden @ e.T
-        return mm(hidden, params["lm_head"])
+                out = (hidden @ e["q"].astype(hidden.dtype).T) * e["sr"].astype(hidden.dtype)
+            else:
+                out = hidden @ e.T
+        else:
+            out = mm(hidden, params["lm_head"])
+        if cfg.lm_head_multiplier != 1.0:
+            out = out * cfg.lm_head_multiplier
+        return out

@@ -87,9 +87,12 @@ def slot_layer_bytes(cfg: ModelConfig) -> int:
                for s in state_shapes(cfg, 0).values())
 
 
-def init_layers(cfg: ModelConfig, dense, key: jax.Array, m: int) -> dict:
-    """The ``[M, ...]`` stacks of ``m`` Mamba layers. The matrices are drawn
-    as every other matrix is (``dense(key, shape, fan_in)``); ``dt_bias``,
+def init_layers(cfg: ModelConfig, dense, key: jax.Array, m: int,
+                own_norm: bool = True) -> dict:
+    """The ``[M, ...]`` stacks of ``m`` Mamba layers (without ``ssm_norm``
+    where the mixer reads another's norm: ``own_norm`` false). The matrices
+    are drawn as every other matrix is (``dense(key, shape, fan_in)``, the
+    two projections by their leaf's name); ``dt_bias``,
     ``A_log`` and ``D`` as the published initialisation draws them (dt
     log-uniform in [time_step_min, time_step_max], floored, and ``dt_bias``
     its inverse softplus; ``A`` uniform in [1, 16]; ``D`` ones): a normal
@@ -104,8 +107,8 @@ def init_layers(cfg: ModelConfig, dense, key: jax.Array, m: int) -> dict:
         jax.random.uniform(next(k), (m, heads), jnp.float32) * (hi - lo) + lo),
         cfg.time_step_floor)
     return {
-        "ssm_norm": jnp.ones((m, h), dt),
-        "ssm_in": dense(next(k), (m, h, d + c + heads), h),
+        **({"ssm_norm": jnp.ones((m, h), dt)} if own_norm else {}),
+        "ssm_in": dense(next(k), (m, h, d + c + heads), h, leaf="ssm_in"),
         "ssm_conv_w": dense(next(k), (m, kk, c), kk),
         "ssm_conv_b": jnp.zeros((m, c), dt),
         # softplus^-1(dt) = dt + log(-expm1(-dt))
@@ -114,15 +117,16 @@ def init_layers(cfg: ModelConfig, dense, key: jax.Array, m: int) -> dict:
             next(k), (m, heads), jnp.float32, 1.0, 16.0)),
         "ssm_D": jnp.ones((m, heads), jnp.float32),
         "ssm_gate_norm": jnp.ones((m, d), dt),
-        "ssm_out": dense(next(k), (m, d, h), d),
+        "ssm_out": dense(next(k), (m, d, h), d, leaf="ssm_out"),
     }
 
 
-def logical_axes() -> dict:
+def logical_axes(own_norm: bool = True) -> dict:
     """No leaf of a Mamba layer is divided over a mesh (tp, pp, sp and ep
     above 1 are refused for a model with recurrent layers)."""
     wide = {"ssm_in": 3, "ssm_conv_w": 3, "ssm_out": 3}
-    return {k: ("layers",) + (None,) * (wide.get(k, 2) - 1) for k in LEAVES}
+    return {k: ("layers",) + (None,) * (wide.get(k, 2) - 1) for k in LEAVES
+            if own_norm or k != "ssm_norm"}
 
 
 def _conv(cfg: ModelConfig, lp, xbc, tail, tok_row, tok_off, starts, q_len):
@@ -272,8 +276,13 @@ def mixer(cfg: ModelConfig, lp, layer, u, ssm, *, lay, slots, q_start, q_len,
     d, c, heads = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.mamba_num_heads
     g, ns, p = cfg.ssm_groups, cfg.ssm_state_size, cfg.mamba_head_dim
     n, b, t = u.shape[0], lay.b, lay.t
+    # (a multiplier of 1 is no operation of the program)
+    if cfg.ssm_in_multiplier != 1.0:
+        u = u * cfg.ssm_in_multiplier
     with phase("ssm_proj"):
         zxd = lax.optimization_barrier(u @ lp["ssm_in"])          # [N, d+c+H]
+        if cfg.ssm_multipliers:     # one a slice: z, x, B, C, dt
+            zxd = zxd * cfg.ssm_column_multipliers.astype(zxd.dtype)
         z, xbc, dt = zxd[:, :d], zxd[:, d:d + c], zxd[:, d + c:]
     tok_row, tok_off, starts = _token_rows(lay, n)
     fresh = q_start == 0
@@ -342,4 +351,6 @@ def mixer(cfg: ModelConfig, lp, layer, u, ssm, *, lay, slots, q_start, q_len,
         y = (yg.reshape(n, d).astype(u.dtype) * lp["ssm_gate_norm"])
     with phase("ssm_proj"):
         out = y @ lp["ssm_out"]
+    if cfg.ssm_out_multiplier != 1.0:
+        out = out * cfg.ssm_out_multiplier
     return out, {"state": state, "conv": conv}

@@ -353,22 +353,23 @@ def step_shapes(cfg: ModelConfig, *, block_size: int,
     if kv_dtype in ("int8", "int4"):
         block += 2 * cfg.num_kv_heads * 4
     # A layer's fixed matrices that are no attention's: a dense FFN's
-    # three or, in a hybrid pattern (a layer is one mixer), a Mamba
-    # mixer's W_in and W_out. The pricing has one term for them,
-    # ``dense_ffn_layers x dense_ffn_params``; the ``ssm_*`` keys below say
-    # what stands there for such a model. (The recurrent state's bytes have
-    # no term: ``step_work`` leaves them unpriced.)
+    # three, a Mamba mixer's W_in and W_out, or both where a layer has both
+    # (the mixer beside attention, then the FFN). The pricing has one term
+    # for them, ``dense_ffn_layers x dense_ffn_params``: the layers that
+    # have such matrices and their parameters a layer (the mean, where the
+    # two kinds are not in the same layers); the ``ssm_*`` keys below say
+    # what of it is the mixers'. (The recurrent state's bytes have no term:
+    # ``step_work`` leaves them unpriced.)
     from dynamo_tpu.models.mamba import slot_layer_bytes
 
     mats = 3 if cfg.expert_gated else 2
-    ssm_layers = cfg.layers_of("M")
+    ssm_layers, ffn_layers = cfg.layers_of("M"), cfg.layers_of("-")
     ssm_params = h * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.mamba_num_heads) \
         + cfg.ssm_inner * h if ssm_layers else 0
-    if ssm_layers:
-        fixed_layers, fixed_params = ssm_layers, ssm_params
-    else:
-        fixed_layers = cfg.layers_of("-")
-        fixed_params = 3 * h * cfg.intermediate_size if fixed_layers else 0
+    ffn_params = 3 * h * cfg.intermediate_size if ffn_layers else 0
+    fixed_layers = max(ssm_layers, ffn_layers)
+    fixed_params = (ssm_layers * ssm_params + ffn_layers * ffn_params
+                    ) // max(fixed_layers, 1)
     return {
         "layers": L, "routed_layers": routed,
         "dense_ffn_layers": fixed_layers,

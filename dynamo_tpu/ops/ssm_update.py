@@ -65,11 +65,20 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: scoped VMEM the kernel asks for: a row's slot-layer in and out, each
-#: twice (the pipeline holds the next beside the current), are 8 MB at the
-#: published shape; a group's spread ``dx`` and ``a`` 256 KB each, the rest
-#: a few tiles.
+#: scoped VMEM the kernel asks for a 2 MiB slot-layer (``[64, 64, 128]``, the
+#: shape it was written at): the slot-layer in and out, each twice (the
+#: pipeline holds the next beside the current), are 8 MB; a group's spread
+#: ``dx`` and ``a`` 256 KB each, the rest a few tiles. A larger slot-layer
+#: asks for as many times more (:func:`vmem_limit_bytes`).
 VMEM_LIMIT_BYTES = 16 * 2**20
+
+
+def vmem_limit_bytes(heads: int, p: int, n: int) -> int:
+    """The scoped VMEM for a slot-layer ``[heads, p, n]`` of float32: what
+    the 2 MiB one is given, times the slot-layer's size over 2 MiB. At
+    ``[32, 128, 256]`` (4 MiB: in and out twice are 16 MiB, a group's 16
+    heads of temporaries 2 MiB each) that is 32 MiB of the v5e's 128."""
+    return VMEM_LIMIT_BYTES * max(1, heads * p * n * 4 // (2 * 2**20))
 
 
 def _kernel(layer_ref, slot_ref, row_ref, n_ref, s_ref, a_ref, dx_ref, b_ref,
@@ -170,7 +179,7 @@ def update_rows(state, layer, slots, one, a, dx, bm, cm, *,
         input_output_aliases={4: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            vmem_limit_bytes=vmem_limit_bytes(h, p, n)),
         interpret=interpret,
         name="ssm_update",
     )(jnp.asarray(layer, jnp.int32).reshape(1), row_slots, rows,

@@ -554,6 +554,33 @@ def test_update_rows_skipped_pct_on_a_hand_made_context(ctx, expect):
     assert value == (None if expect is None else pytest.approx(expect))
 
 
+def _slots(edges: tuple | None, in_flight: tuple = (), slots: int = 64):
+    """A window with ``edges`` rows of the state pool held at its start and
+    end and ``in_flight`` requests running or waiting at its samples."""
+    c0, c1 = ({"ssm": {"slots": slots} if edges is None else
+               {"slots": slots, "slots_in_use": n}} for n in edges or (0, 0))
+    ctx = _ctx(c0, c1)
+    ctx.in_flight = list(in_flight)
+    return ctx
+
+
+@pytest.mark.parametrize("ctx, expect", [
+    # a sample above both edges; an edge above every sample; no sample at
+    # all; more requests in flight than the pool has rows: some waited
+    (_slots((14, 19), (15, 21, 18)), 100.0 * 21 / 64),
+    (_slots((14, 9), (12, 11)), 100.0 * 14 / 64),
+    (_slots((7, 5)), 100.0 * 7 / 64),
+    (_slots((60, 64), (63, 70)), 100.0),
+    # a program without the count (the parent's), or without a state pool
+    (_slots(None, (3, 4)), None),
+    (_ctx({}, {}), None),
+], ids=["sample_above", "edge_above", "no_sample", "some_waited",
+        "no_counter", "no_pool"])
+def test_slots_peak_usage_pct_on_a_hand_made_context(ctx, expect):
+    value = measure.load_reader("ssm.slots_peak_usage_pct").read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))
+
+
 def _q_positions(handed: int | None, live: int = 0):
     c0 = {"sched": {}} if handed is None else {"sched": {
         "rect_tokens_total": 50_000, "live_tokens_total": 7_000}}

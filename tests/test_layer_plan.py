@@ -45,6 +45,8 @@ PLANS = {
     # MEMEM* then EMEMEM* x 4
     "nemotron-3-nano-30b-a3b-ep8-l34": ((6, 7, 4, 0), 5, 14, (0,) * 5),
     "llama-3-8b-lite": ((0, 1, 8, 0), 8, 0, (0,) * 8),
+    # attention and a Mamba-2 mixer joined, then the FFN, in every layer
+    "falcon-h1-34b-l6": ((0, 1, 6, 0), 6, 0, (0,) * 6),
 }
 
 
@@ -64,11 +66,24 @@ def test_the_mixers_cover_the_model_once(name):
     assert (cfg.attn_layers, cfg.layers_of("E"), cfg.attn_windows) == (
         attn, routed, windows)
     mixers = [m for layer in layers for m in layer]
-    # a layer is attention then an FFN, or one letter of a hybrid pattern
+    # a layer is attention then an FFN (a Mamba-2 mixer joined to attention
+    # between them where the model has one beside it), or one letter of a
+    # hybrid pattern
     assert {len(layer) for layer in layers} == (
-        {1} if cfg.hybrid_pattern else {2})
+        {1} if cfg.hybrid_pattern else
+        {3} if cfg.ssm_beside_attention else {2})
     if cfg.hybrid_pattern:
         assert "".join(m.kind for m in mixers) == cfg.hybrid_pattern
+    # recurrent state: read off the plan, pattern string or none
+    assert cfg.has_ssm == any(m.kind == "M" for m in mixers)
+    # a joined mixer stands behind the one it joins, in its stack and place
+    for layer in layers:
+        for before, m in zip(layer, layer[1:]):
+            if m.joined:
+                assert (before.kind, m.kind) == ("*", "M")
+                assert (before.stack, before.place) == (m.stack, m.place)
+        assert not layer[0].joined
+    assert any(m.joined for m in mixers) == cfg.ssm_beside_attention
     for key in {(m.kind, m.stack) for m in mixers}:
         places = [m.place for m in mixers if (m.kind, m.stack) == key]
         assert places == list(range(len(places))), key
@@ -132,6 +147,17 @@ FAMILIES = {
         shared_expert_intermediate_size=48, router_scoring="sigmoid",
         router_bias=True, routed_scaling_factor=2.5, expert_act="relu2",
         expert_gated=False, rope_scope="none"),
+    # Falcon-H1's: attention and a Mamba-2 mixer under one norm and one add,
+    # then a gated FFN, multipliers on the activations
+    "side_by_side": ModelConfig(
+        **_TINY, num_layers=4, intermediate_size=96,
+        ssm_beside_attention=True, mamba_num_heads=8, mamba_head_dim=8,
+        ssm_groups=2, ssm_state_size=16, ssm_chunk=8,
+        embedding_multiplier=5.5, lm_head_multiplier=0.25,
+        attention_in_multiplier=0.5, key_multiplier=0.3,
+        attention_out_multiplier=0.7, ssm_in_multiplier=0.25,
+        ssm_multipliers=(0.35, 0.25, 0.18, 0.5, 0.35),
+        ssm_out_multiplier=0.6, mlp_multipliers=(0.18, 0.4)),
 }
 
 # (split, bodies one trace of forward makes: attention, FFN, Mamba)
@@ -140,6 +166,7 @@ TRACED = {
     "lead_window": ((1, 4, 2, 1), (6, 6, 0)),
     "routed_before_attention": ((0, 4, 2, 0), (4, 4, 0)),
     "hybrid": ((1, 3, 3, 2), (1, 2, 3)),
+    "side_by_side": ((0, 1, 4, 0), (1, 1, 1)),
 }
 
 
@@ -179,11 +206,12 @@ def _one_by_one(cfg, layers, h, ck, cv, ssm, *, lay, q_start, q_len, live,
     post = cfg.norm_placement == "post"
     for mixers in cfg.layer_plan.layers:
         routing = None
-        for m in mixers:
+        for m, after in zip(mixers, (*mixers[1:], None)):
             lp = jax.tree.map(lambda a: a[m.place], stacks[m.stack])
-            norm = lp[{"*": "attn_norm", "M": "ssm_norm"}.get(
-                m.kind, "mlp_norm")]
-            x = h if post else llama.rms_norm(h, norm, cfg.rms_norm_eps)
+            if not m.joined:    # (a joined mixer reads what the one before read)
+                norm = lp[{"*": "attn_norm", "M": "ssm_norm"}.get(
+                    m.kind, "mlp_norm")]
+                x = h if post else llama.rms_norm(h, norm, cfg.rms_norm_eps)
             if m.kind == "*":
                 if cfg.router_input == "attn_norm" and "router" in lp:
                     from dynamo_tpu.models.moe import route
@@ -199,6 +227,11 @@ def _one_by_one(cfg, layers, h, ck, cv, ssm, *, lay, q_start, q_len, live,
             else:
                 out, c = llama._ffn(cfg, lp, x, routing, moe_impl, None, live)
                 counts = counts + (0 if c is None else c)
+            if m.joined:
+                out = beside + out
+            if after is not None and after.joined:
+                beside = out        # added with the mixer beside it
+                continue
             h = h + (llama.rms_norm(out, norm, cfg.rms_norm_eps) if post
                      else out)
     return h, ck, cv, ssm, counts

@@ -347,6 +347,24 @@ def test_scheduler_batch_full_cause(clean_ledger):
     assert "no_free_blocks" not in led.blocked_totals
 
 
+def test_scheduler_counts_the_slots_in_use():
+    """A running sequence holds a slot, a waiting one does not, and a
+    finished one gives its slot back (``stats()["ssm"]["slots_in_use"]``)."""
+    from dynamo_tpu.engine.prefix_pool import PrefixPool
+    from dynamo_tpu.protocols.common import FinishReason
+
+    sched = _sched(PrefixPool(16, 16), max_batch_size=2)
+    assert sched.slots_in_use == 0
+    for rid in ("first", "second", "third"):
+        sched.add(_seq(17, rid=rid))
+    sched.plan()
+    assert sched.slots_in_use == len(sched.running) == 2
+    sched.finish(sched.running[0], FinishReason.LENGTH)
+    assert sched.slots_in_use == 1
+    sched.plan()
+    assert sched.slots_in_use == 2 and not sched.waiting
+
+
 def test_scheduler_no_free_blocks_and_wdrr_causes(clean_ledger):
     from dynamo_tpu.engine.prefix_pool import PrefixPool
     from dynamo_tpu.qos.deadline import PRIORITY_KEY

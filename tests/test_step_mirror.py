@@ -54,12 +54,14 @@ from dynamo_tpu.protocols.common import (  # noqa: E402
 WARMED = {"mistral-7b.chat": 64, "mistral-7b.longprompt": 48,
           "mistral-nemo-12b.chat": 64, "k-exaone-236b.reasoning": 99,
           "smallthinker-21b.reasoning": 66,
-          "nemotron-3-nano-30b.reasoning": 99}
+          "nemotron-3-nano-30b.reasoning": 99,
+          "falcon-h1-34b.reasoning": 99}
 # ... rows (8, 16), or (8, 16, 32) where the cell's ``max_rows`` is 32.
 WARMED_KERNEL = {"mistral-7b.chat": 14, "mistral-7b.longprompt": 14,
                  "mistral-nemo-12b.chat": 14, "k-exaone-236b.reasoning": 21,
                  "smallthinker-21b.reasoning": 14,
-                 "nemotron-3-nano-30b.reasoning": 21}
+                 "nemotron-3-nano-30b.reasoning": 21,
+                 "falcon-h1-34b.reasoning": 21}
 # What EngineCore resolves EngineConfig.attn_impl to: on a TPU, elsewhere.
 PATHS = {"kernel": "pallas", "gather": "dense"}
 # Seconds a step takes in the replay: (a decode step, each chunk token on
@@ -80,7 +82,15 @@ CLOCKS_OF = {"k-exaone-236b.reasoning": {"fast": (0.011, 0.0001),
              # (PR 45: a decode step 11-15 ms at 10-20 rows, a 512-token
              # chunk step ~90 ms)
              "nemotron-3-nano-30b.reasoning": {"fast": (0.011, 0.00012),
-                                               "slow": (0.015, 0.00018)}}
+                                               "slow": (0.015, 0.00018)},
+             # (PR 52: a step period of 14.8 ms at 15-24 rows, a mixed step
+             # 25 ms in the mean over chunk buckets; and 7 % slower, all the
+             # room this cell has: at 0.8 x its knee 26-27 of the 32 warmed
+             # rows are in flight, and the replay passes 32 at steps 10 %
+             # slower in order 4 and 15 % slower in the cell's own.
+             # ``engine.compiles_in_window.chat`` reads it in a traced run)
+             "falcon-h1-34b.reasoning": {"fast": (0.0148, 0.00004),
+                                         "slow": (0.0158, 0.000043)}}
 POOL_BLOCKS = 6000
 
 
