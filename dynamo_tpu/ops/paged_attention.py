@@ -6,8 +6,8 @@ materializes S=NBLK*BS rows per sequence and streams them twice. This kernel
 instead walks the block table directly: for each (sequence, query chunk)
 grid step it loops over groups of the KV blocks that chunk can see, copies a
 group's blocks ``[BS, KH, D]`` from HBM into VMEM itself (one async copy a
-block, by table lookup, the next group landing while this one computes) and
-folds the group into a running online softmax. No gathered context tensor
+block, by table lookup, one wait a group, the next group landing while this
+one computes) and folds the group into a running online softmax. No gathered context tensor
 ever exists.
 
 Works for both prefill chunks (T>1 query tokens) and decode (T=1) with the
@@ -63,7 +63,53 @@ inside vLLM/TRT-LLM, which we replace):
 - precision: bf16 queries and keys go to the MXU as bf16 with a float32
   result (the products are exact, so the scores are those of a float32
   widening up to summation order); max, exp, sum, alpha and the accumulator
-  are float32, and the probabilities stay float32 into P.V.
+  are float32. The probabilities are float32 values when they enter P.V, and
+  the MXU takes them in one bf16 pass: at default precision Mosaic rounds a
+  float32 operand to bf16, so the product is bit for bit that of
+  ``p.astype(bf16)`` and the values' own bf16 with a float32 result
+  (tools/attn_bench.py's ``dot`` line, on the chip: PERF.md, PR 53). P.V is
+  no multi-pass float32 product, and the vector units' work in a group is
+  the de-interleave of the heads and the softmax.
+- the walk's copies, and what the scalar core runs for them: a group's
+  blocks are fetched by a loop of one K and one V copy a block (16 KB each
+  at 4 KV heads), every copy signalling the semaphore of its buffer and
+  slot by the bytes it moved, and the group is awaited by ONE wait a buffer
+  for the slot's whole byte count (a DMA semaphore counts bytes; the wait's
+  descriptor is the slot itself). What does not change from block to block
+  (the row's place in the table, which is flat in SMEM so that it is one
+  product; the run's first block; how far the used blocks reach into it) is
+  worked out before the loop. The kernel is compiled with
+  ``disable_bounds_checks``: the hardware checks of each copy's two ends
+  were half of the loop (28 of 57 bundles a block; one loop trip is 18-22
+  now: tools/kernel_bundles.py), and every address is in range by
+  construction instead. **Every dynamic address the kernel forms, and why
+  it is in range:**
+  (1) the table read ``bt[row + start + min(i, reach)]``: ``row = b * NBLK``
+  with ``b`` a grid index; a group is fetched only if it begins at a used
+  block (``start <= last``, so ``reach >= 0``) and ``last < NBLK``
+  (``chunk_used_blocks`` clips to the table's width), so the index lies in
+  the row's own ``[0, NBLK)``;
+  (2) a block copy's source ``cache[layer, id]``: the id is clamped into
+  ``[0, NB - 1]`` after it is read, so a table that names no block of the
+  pool reads the nearest block that exists, never past the pool (the
+  quantized pool's ``scale[id, head]`` takes the same clamped id);
+  ``layer`` is clamped into ``[0, L - 1]`` by the wrapper before it rides
+  the scalar prefetch;
+  (3) a block copy's target ``buf[slot, i * BS : (i + 1) * BS]``: ``slot``
+  is ``g % 2`` or ``1 - g % 2`` and ``i < G`` is the loop's static bound;
+  (4) the wait's ``buf[slot]`` and the semaphores ``[n, slot]``: the same
+  ``slot``;
+  (5) the reads of a landed group: ``buf[slot]`` whole, by head
+  ``buf[slot, :, ki, :]`` with ``ki`` static, or as uint32 rows
+  ``pl.ds(j, G * BS, stride=KH / 2)`` with ``j < KH / 2`` static: the last
+  row is ``j + (G * BS - 1) * KH / 2 < G * BS * KH / 2``;
+  (6) the token-major tile ``q[first : first + tile]`` (``_token_tile``'s
+  load and store, the one write the kernel addresses itself): ``first`` is
+  clamped into ``[0, N]`` and the array holds ``N + tile`` tokens, so the
+  store stays inside the output whatever ``starts`` says;
+  (7) the per-row and per-chunk scalars ``ub[chunk]``, ``fb[chunk]``,
+  ``kl[b]``, ``qs[b]``, ``ts[b]``: grid indices. The output of a rectangle
+  step is a block of the pipeline, addressed by Pallas.
 - q rows are pre-laid-out ``[B, KH, T*REP, D]`` (rep = query heads per kv
   head) outside the kernel so each head's queries are one contiguous 2D
   slab — one MXU matmul covers all query heads of the kv head. That is a
@@ -264,7 +310,7 @@ def _group_blocks(rows: int, bs: int, nblk: int) -> int:
     return max(1, min(keys // bs, nblk))
 
 
-def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
+def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int, nblk: int,
             quant: bool, int4: bool, mm_dtype, window: int = 0,
             tile: int = 0):
     # A window (static, > 0) adds one scalar-prefetch operand, the block
@@ -298,6 +344,7 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
     # q-head*tile + token in the slab built from a tile of tokens.
     r = q_ref.shape[2]
     gk = gb * bs                # keys a group holds
+    nb = k_hbm.shape[1]         # blocks the pool holds
 
     # Blocks this query chunk of the row can see: none past the row's
     # context, none past the chunk's own last position, none at all for a
@@ -317,59 +364,74 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
     kv_len = kl_ref[b]
     q_pos0 = qs_ref[b]
 
-    def block_id(g, i):
-        # The group's i-th block. Past the last used block the last one is
-        # named again: its keys are masked by position, and what the buffer
-        # holds there is KV that was written, never stale VMEM.
-        j = lax.add(lax.mul(g, _i32(gb)), i)
-        if window:
-            j = lax.add(j, first)
-        return bt_ref[b, lax.min(j, last)]
+    row = lax.mul(b, _i32(nblk))    # the row's place in the flat table
 
-    def copies(g, slot, i, landing=False):
-        # A wait needs the copy's shape and semaphore, not its source.
-        blk = 0 if landing else block_id(g, i)
-        dst = pl.ds(pl.multiple_of(lax.mul(i, _i32(bs)), bs), bs)
-        return (
+    def block_ids(start):
+        # The ids of the blocks from the row's block ``start`` on (None: its
+        # first, which costs no equation), as a function of a block's place
+        # i in that run. Past the
+        # last used block the last one is named again: its keys are masked
+        # by position, and what the buffer holds there is KV that was
+        # written, never stale VMEM. What does not change with i is worked
+        # out here, before the loop that asks: where the run begins in the
+        # table, and how far the used blocks reach into it (>= 0: a group
+        # that is fetched begins at a used block).
+        at, reach = row, last
+        if start is not None:
+            at, reach = lax.add(row, start), lax.sub(last, start)
+
+        def block_id(i):
+            # ... and an id is held inside the pool, whatever the table
+            # says: no hardware check stands behind the copies' addresses.
+            return lax.clamp(_i32(0), bt_ref[lax.add(at, lax.min(i, reach))],
+                             _i32(nb - 1))
+        return block_id
+
+    def fetch(start, slot):
+        # The group of blocks from ``start`` on into buffer ``slot``: one
+        # copy a block and buffer, each signalling its buffer's semaphore of
+        # the slot by the bytes it moved.
+        block_id = block_ids(start)
+
+        def copy(i, c):
+            blk = block_id(i)
+            dst = pl.ds(lax.mul(i, _i32(bs)), bs)
             pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[slot, dst],
-                                  sems.at[0, slot]),
+                                  sems.at[0, slot]).start()
             pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[slot, dst],
-                                  sems.at[1, slot]),
-        )
-
-    def fetch(g, slot):
-        def start(i, c):
-            for cp in copies(g, slot, i):
-                cp.start()
+                                  sems.at[1, slot]).start()
             return c
-        lax.fori_loop(0, gb, start, 0)
+        lax.fori_loop(0, gb, copy, 0)
 
-    def land(g, slot):
-        def wait(i, c):
-            for cp in copies(g, slot, i, landing=True):
-                cp.wait()
-            return c
-        lax.fori_loop(0, gb, wait, 0)
+    def land(slot):
+        # One wait a buffer for the group's whole byte count: a DMA
+        # semaphore counts bytes, and a wait takes its count from the
+        # descriptor's shape, not from a source.
+        for n, buf in enumerate((kbuf, vbuf)):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sems.at[n, slot]).wait()
 
     def group(g, c):
         # (lax primitives all through the walk: a jnp call is a nested jit
         # to trace, and a step program is traced at every start.)
         slot = lax.rem(g, _i32(2))
         nxt = lax.add(g, _i32(1))
+        # The group's first block among the row's, and its first key.
+        start = lax.mul(g, _i32(gb))
+        if window:
+            start = lax.add(start, first)
+        base = lax.mul(start, _i32(bs))
 
         @pl.when(lax.lt(nxt, groups))
         def _next():
-            fetch(nxt, lax.sub(_i32(1), slot))
+            fetch(lax.add(start, _i32(gb)), lax.sub(_i32(1), slot))
 
-        land(g, slot)
+        land(slot)
 
         # Causal/visibility mask is head-independent, [R, GK]: key c of the
         # group is seen by chunk row w (query token w // rep, or w % tile
         # where the slab is a tile's) if
-        # g*gk + c <= q_pos0 + (qi*r + w) // rep and g*gk + c < kv_len.
-        base = lax.mul(g, _i32(gk))
-        if window:
-            base = lax.add(base, lax.mul(first, _i32(bs)))
+        # base + c <= q_pos0 + (qi*r + w) // rep and base + c < kv_len.
         ctx = lax.broadcasted_iota(jnp.int32, (r, gk), 1)
         tok = (lax.rem if tile else lax.div)(
             lax.broadcasted_iota(jnp.int32, (r, gk), 0),
@@ -393,12 +455,14 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
             return lax.broadcast_in_dim(x, (r, n), (0, 1))
 
         if quant:
-            col_blk = lax.broadcasted_iota(jnp.int32, (1, gk), 1) // bs
+            col_blk = lax.div(lax.broadcasted_iota(jnp.int32, (1, gk), 1),
+                              lax.full((1, gk), bs, jnp.int32))
+            block_id = block_ids(start)
 
             def scale_row(s_ref, ki):
                 # [1, GK]: block i's scale over its bs columns.
                 def put(i, acc):
-                    return jnp.where(col_blk == i, s_ref[block_id(g, i), ki],
+                    return jnp.where(col_blk == i, s_ref[block_id(i), ki],
                                      acc)
                 return lax.fori_loop(0, gb, put,
                                      jnp.zeros((1, gk), jnp.float32))
@@ -431,7 +495,8 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
                             lax.expand_dims(lax.reduce_sum(p, (1,)), (1,)))
             if quant:
                 p = p * scale_row(vs_ref, ki)
-            # The probabilities stay float32 into P.V.
+            # Float32 operands at default precision: the MXU takes both in
+            # one bf16 pass (the module's note on precision).
             pv = lax.dot_general(
                 p, _cast(v, jnp.float32), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)                   # [R, D]
@@ -495,7 +560,7 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int,
         acc_ref[:] = lax.full(acc_ref.shape, 0.0, acc_ref.dtype)
         if tile:
             load.start()
-        fetch(_i32(0), _i32(0))
+        fetch(first if window else None, _i32(0))
         if tile:
             load.wait()
             spread()
@@ -560,7 +625,10 @@ def _token_tile(q_hbm, o_hbm, tok_ref, q_ref, sems, *, first, n_live):
     tile, hp, d = tok_ref.shape
     _, kh, r, _ = q_ref.shape
     h = kh * r // tile
-    here = pl.ds(first, tile)
+    # ``first`` comes from the caller's ``starts``: held to the tokens, so
+    # that the tile lies inside the array (the tile of padding behind them
+    # is there for the last one), whatever a row's start says.
+    here = pl.ds(lax.clamp(_i32(0), first, _i32(q_hbm.shape[0] - tile)), tile)
     load = pltpu.make_async_copy(q_hbm.at[here], tok_ref, sems.at[0])
     store = pltpu.make_async_copy(tok_ref, o_hbm.at[here], sems.at[1])
 
@@ -646,6 +714,9 @@ def paged_attention_kernel(
     k_cache, v_cache, layer = _layer_stack(k_cache, v_cache, layer)
     quant = isinstance(k_cache, dict)
     int4 = False
+    # Held inside the cache: the kernel's copies are not checked.
+    layers = (k_cache["q"] if quant else k_cache).shape[0]
+    layer = lax.clamp(jnp.int32(0), layer, jnp.int32(layers - 1))
     if quant:
         # The layer's scales, [NB, KH]: small, and what SMEM holds must not
         # grow with L.
@@ -694,8 +765,10 @@ def paged_attention_kernel(
     def qmap(bi, qi, *_prefetch):
         return (bi, 0, qi, 0)
 
-    scalars = (block_tables.astype(jnp.int32), qs32, kl32, used_blocks,
-               layer.reshape(1))
+    # (the table flat: a row's place in it is one product, worked out once
+    # a group and not a tiled 2-D SMEM address a block)
+    scalars = (block_tables.astype(jnp.int32).reshape(-1), qs32, kl32,
+               used_blocks, layer.reshape(1))
     if quant:
         scalars = scalars + (k_scale, v_scale)
     if packed:
@@ -746,7 +819,7 @@ def paged_attention_kernel(
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, kh=kh, rep=rep, gb=gb, nq=nq,
-                          quant=quant, int4=int4, mm_dtype=mm_dtype,
+                          nblk=nblk, quant=quant, int4=int4, mm_dtype=mm_dtype,
                           window=window, tile=tile),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
@@ -758,6 +831,10 @@ def paged_attention_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(("arbitrary",) if packed
                                  else ("parallel",)) * 2,
+            # Every address the kernel forms is in range by construction
+            # (the module's docstring lists them), so the scalar core does
+            # not check each copy's two ends before it issues it.
+            disable_bounds_checks=True,
         ),
         interpret=interpret,
         # A name of its own in the device trace (the custom call would

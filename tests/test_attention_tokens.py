@@ -7,8 +7,11 @@ for bit (the rows gathered into ``[B, T]``, the kernel, the output gathered
 back: the path a packed step took until PR 50), over ``test_token_major``'s
 ragged batches and two made for the tiles' edges, for 4, 7, 8 and 16 query
 heads a kv head, a plain, an int8 and a packed-int4 pool, a full and a
-sliding layer; a token of no row stays finite. And a packed ``forward``
-under the kernel holds no array of ``B x T`` positions outside it.
+sliding layer, one group of blocks a walk and several with a partial last
+one (each landed by one wait a buffer); a token of no row stays finite. And a
+packed ``forward`` under the kernel holds no array of ``B x T`` positions
+outside it, and the kernel's body no more equations than it had before the
+walk's copies were rewritten (PR 53: a body's length is set-up time).
 """
 
 import dataclasses
@@ -59,11 +62,11 @@ def _step(case: str, kh: int, rep: int, kv: str, dtype):
 
 
 @functools.lru_cache(maxsize=None)
-def _entries(window: int, chunk_rows: int):
-    """The two entries under ``jit``, one pair a (window, chunk size): the
-    row cases are data, so each shape is compiled once and not once a
-    case."""
-    del chunk_rows                 # read by the kernel when it is traced
+def _entries(window: int, chunk_rows: int, group_keys: int):
+    """The two entries under ``jit``, one pair a (window, chunk size, group
+    size): the row cases are data, so each shape is compiled once and not
+    once a case."""
+    del chunk_rows, group_keys     # read by the kernel when it is traced
     kw = dict(interpret=True, window=window, t=T)
 
     def tokens(q, k, v, tables, q_start, kv_lens, starts):
@@ -81,7 +84,7 @@ def _entries(window: int, chunk_rows: int):
 def _both(case, kh, rep, kv, dtype, window):
     """(token-major, rectangle) outputs [N, H, D] and the live tokens [N]."""
     (q, *rest), lay, valid = _step(case, kh, rep, kv, dtype)
-    tokens, rectangle = _entries(window, pa._CHUNK_ROWS)
+    tokens, rectangle = _entries(window, pa._CHUNK_ROWS, pa._GROUP_KEYS)
     got = tokens(q, *rest, lay.starts)
     rect = rectangle(lay.to_rows(q).reshape(B, T, q.shape[1], D), *rest)
     return np.asarray(got), np.asarray(lay.to_tokens(rect)), valid
@@ -109,15 +112,27 @@ def test_token_major_entry_equals_the_rectangle_entry(
     assert np.isfinite(got).all()
 
 
-@pytest.mark.parametrize("chunks", ["one_chunk", "small_chunks"])
+@pytest.fixture
+def small_groups(monkeypatch):
+    """Groups of two blocks of 4 keys, so that a context of up to 32 tokens
+    is one to four groups, the last one half a group where its blocks are
+    odd (the served 16-32 blocks would make every walk here one group)."""
+    monkeypatch.setattr(pa, "_GROUP_KEYS", 2 * BS)
+    monkeypatch.setattr(pa, "_GROUP_KEYS_WIDE", 2 * BS)
+
+
+@pytest.mark.parametrize("chunks", ["one_chunk", "small_chunks",
+                                    "small_groups"])
 @pytest.mark.parametrize("kh,rep", [(2, 4), (4, 7), (1, 7)],
                          ids=["rep4", "rep7", "odd_heads"])
 @pytest.mark.parametrize("case", sorted(ROWS))
 def test_token_major_entry_in_bf16(request, case, kh, rep, chunks):
     """As served: bf16 queries and pool; 28 heads and 7 are padded to whole
     sublane tiles of 16."""
-    if chunks == "small_chunks":
+    if chunks != "one_chunk":
         request.getfixturevalue("small_chunks")
+    if chunks == "small_groups":
+        request.getfixturevalue("small_groups")
     got, want, valid = _both(case, kh, rep, "bfloat16", jnp.bfloat16, 0)
     np.testing.assert_array_equal(got[valid].view(np.uint16),
                                   want[valid].view(np.uint16))
@@ -172,6 +187,65 @@ def test_packed_forward_holds_no_rectangle(case):
     big = {shape for shape, inner in shapes
            if not inner and limit <= int(np.prod(shape)) < pool}
     assert not big, big
+
+
+# -- The kernel body's length -------------------------------------------------
+
+def _equations(jaxpr) -> int:
+    """The equations of ``jaxpr`` and of every jaxpr nested in them."""
+    return sum(1 + sum(_equations(sub)
+                       for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+def _kernel_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_calls(sub)
+
+
+# What the body counted before PR 53 (the parent's file traced on the same
+# shapes): the walk's copies may not lengthen it.
+@pytest.mark.parametrize("entry,window,kv,limit", [
+    ("decode", 0, "bfloat16", 165), ("decode", 6, "bfloat16", 177),
+    ("chunk", 0, "bfloat16", 165), ("tokens", 0, "bfloat16", 181),
+    ("tokens", 6, "bfloat16", 193), ("decode", 0, "int8", 225),
+], ids=["decode", "decode_sliding", "chunk", "tokens", "tokens_sliding",
+        "decode_int8"])
+def test_kernel_body_is_no_longer_than_it_was(entry, window, kv, limit):
+    """Every warmed program traces and lowers the kernel's body again, and
+    an equation under ``pl.when`` costs ~3 ms there (PERF.md, PR 49): the
+    body's equations, nested ones counted, stay at or under the count they
+    had with one wait a block and a table read in two dimensions."""
+    kh, rep = 2, 4
+    shape = jax.ShapeDtypeStruct
+    cache = shape((LAYERS, NB, BS, kh, D), jnp.bfloat16)
+    if kv == "int8":
+        cache = {"q": shape(cache.shape, jnp.int8),
+                 "s": shape((LAYERS, NB, kh), jnp.float32)}
+    rows = shape((B,), jnp.int32)
+    t = 1 if entry == "decode" else T
+    kw = dict(layer=jnp.int32(1), window=window, interpret=True)
+    if entry == "tokens":
+        q = shape((token_bucket("mixed", B, T), kh * rep, D), jnp.bfloat16)
+        kw.update(t=T)
+    else:
+        q = shape((B, t, kh * rep, D), jnp.bfloat16)
+
+    def call(q, k, v, tables, q_start, kv_lens, *starts):
+        return pa.paged_attention_kernel(
+            q, k, v, tables, q_start, kv_lens,
+            **({"starts": starts[0]} if starts else {}), **kw)
+
+    closed = jax.make_jaxpr(call)(
+        q, cache, cache, shape((B, NBLK), jnp.int32), rows, rows,
+        *([rows] if entry == "tokens" else []))
+    (kernel,) = _kernel_calls(closed.jaxpr)
+    body = sum(_equations(sub)
+               for sub in jax.core.jaxprs_in_params(kernel.params))
+    assert body <= limit, body
 
 
 # -- The heads split two ways -------------------------------------------------

@@ -325,18 +325,39 @@ def _spoil_other_layers(cache, layer):
     return jax.tree.map(spoil, cache)
 
 
-@pytest.mark.parametrize("t", [1, 8], ids=["decode", "chunk"])
-@pytest.mark.parametrize("width", [5, 512], ids=["tight", "max_nblk"])
-@pytest.mark.parametrize("kv", ["bfloat16", "int8", "int4"])
-@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+# (layer, kv, table, t, window): every layer, pool format and table width on
+# a full layer; then, at the middle layer, a table whose live entries name a
+# block past the pool and one before it, and a sliding layer whose walk
+# begins at block 3 of 5 (one group of two, from an odd block on).
+_WHOLE_CACHE_CASES = [
+    pytest.param(layer, kv, table, t, 0,
+                 id=f"{name}-{kv}-{table}-{'decode' if t == 1 else 'chunk'}")
+    for layer, name in enumerate(["first", "middle", "last"])
+    for kv in ["bfloat16", "int8", "int4"]
+    for table in ["tight", "max_nblk"] for t in [1, 8]
+] + [
+    pytest.param(1, kv, table, t, window,
+                 id=f"middle-{kv}-{table}-{'decode' if t == 1 else 'chunk'}")
+    for kv in ["bfloat16", "int8", "int4"]
+    for table, window in [("ids_out_of_range", 0), ("sliding", 24)]
+    for t in [1, 8]
+]
+
+
+@pytest.mark.parametrize("layer,kv,table,t,window", _WHOLE_CACHE_CASES)
 def test_kernel_addresses_one_layer_of_the_whole_cache(small_groups, layer,
-                                                       kv, width, t):
+                                                       kv, table, t, window):
     """The kernel on the whole [L, NB, ...] cache with layer index ``l`` is
     the kernel on that layer's slice, bit for bit, and the dense reference
     within tolerance; what the other layers hold does not matter, and
     neither does the table's width: handed ``max_nblk`` entries a row (what
     every step program is compiled for) of which 1, 2 and 5 are live, it
-    gives what the tight table gives."""
+    gives what the tight table gives. An id that names no block of the pool
+    is read as the nearest one that does (the copies' addresses are not
+    checked by the hardware: ``disable_bounds_checks``), so such a table
+    gives what the clamped table gives, scales of a quantized pool
+    included. Every walk here lands its groups of two blocks by one wait a
+    buffer, the half group at a row's end too."""
     from dynamo_tpu.models.llama import _gather_kv
 
     # Five blocks a row in groups of two: 1, 2 and 5 used blocks (a group
@@ -351,17 +372,21 @@ def test_kernel_addresses_one_layer_of_the_whole_cache(small_groups, layer,
     q_start = jnp.asarray([0, 21, nblk * bs - t], jnp.int32)    # ragged
     kv_lens = q_start + t
     # As dispatch() fills a wide table: the row's blocks, then zeros.
+    width = nblk if table == "tight" else 512
     wide = jnp.zeros((b, width), jnp.int32).at[:, :nblk].set(bt)
+    if table == "ids_out_of_range":
+        wide = wide.at[0, 0].set(nb + 7).at[1, 1].set(-3).at[2, 4].set(nb)
+        bt = jnp.clip(wide[:, :nblk], 0, nb - 1)
 
     def kernel(k, v, **kw):
         return np.asarray(paged_attention_kernel(
-            q, k, v, bt, q_start, kv_lens, interpret=True,
+            q, k, v, bt, q_start, kv_lens, interpret=True, window=window,
             **kw).astype(jnp.float32))
 
     # The layer index traced, as inside the model's scan.
     whole = np.asarray(jax.jit(
         lambda k, v, l: paged_attention_kernel(
-            q, k, v, wide, q_start, kv_lens, layer=l,
+            q, k, v, wide, q_start, kv_lens, layer=l, window=window,
             interpret=True))(kc, vc, jnp.int32(layer)).astype(jnp.float32))
     one = jax.tree.map(lambda a: a[layer], (kc, vc))
     np.testing.assert_array_equal(whole, kernel(*one))
@@ -371,7 +396,7 @@ def test_kernel_addresses_one_layer_of_the_whole_cache(small_groups, layer,
     ref = paged_attention(
         q.astype(jnp.float32), _gather_kv(kc, bt, layer).astype(jnp.float32),
         _gather_kv(vc, bt, layer).astype(jnp.float32),
-        q_start[:, None] + jnp.arange(t)[None, :], kv_lens)
+        q_start[:, None] + jnp.arange(t)[None, :], kv_lens, window=window)
     np.testing.assert_allclose(whole, np.asarray(ref), atol=2e-2, rtol=2e-2)
 
 
@@ -406,37 +431,54 @@ def _vmem_bytes(grid_spec, blocks):
     return scratch + 2 * sum(padded(shape, dtype) for shape, dtype in blocks)
 
 
-@pytest.mark.parametrize("b,t,nblk,nb,kv,kh,compiles", [
-    pytest.param(32, 1, 512, 18000, "bfloat16", 8, True, id="bf16-decode"),
+@pytest.mark.parametrize("b,t,nblk,nb,kv,h,kh,window,compiles", [
+    pytest.param(32, 1, 512, 18000, "bfloat16", 32, 8, 0, True, id="bf16-decode"),
     # The mixed step's shape: T = prefill_chunk, decode rows one token of it.
-    pytest.param(8, 512, 512, 18000, "bfloat16", 8, True, id="bf16-chunk512"),
+    pytest.param(8, 512, 512, 18000, "bfloat16", 32, 8, 0, True, id="bf16-chunk512"),
     # The cells' own step programs, every one at the table's one width
     # (max_model_len 8192 / 16): the first and the widest of the decode
     # ladder and the full chunk, over the 7B cut's pool and the Nemo cut's
     # (both 32 Q / 8 KV x 128), and at the two KV heads a chip holds under
     # tp=4.
-    pytest.param(8, 1, 512, 6817, "bfloat16", 8, True, id="7b-b8-decode"),
-    pytest.param(8, 512, 512, 6817, "bfloat16", 8, True, id="7b-b8-t512"),
-    pytest.param(64, 1, 512, 6817, "bfloat16", 8, True, id="7b-b64-decode"),
-    pytest.param(8, 1, 512, 9915, "bfloat16", 8, True, id="nemo-b8-decode"),
-    pytest.param(8, 512, 512, 9915, "bfloat16", 8, True, id="nemo-b8-t512"),
-    pytest.param(64, 1, 512, 9915, "bfloat16", 8, True, id="nemo-b64-decode"),
-    pytest.param(8, 1, 512, 12279, "bfloat16", 2, True, id="tp4-b8-decode"),
-    pytest.param(8, 512, 512, 12279, "bfloat16", 2, True, id="tp4-b8-t512"),
-    pytest.param(32, 1, 16, 449, "int8", 8, True, id="int8-small-pool"),
+    pytest.param(8, 1, 512, 6817, "bfloat16", 32, 8, 0, True, id="7b-b8-decode"),
+    pytest.param(8, 512, 512, 6817, "bfloat16", 32, 8, 0, True, id="7b-b8-t512"),
+    pytest.param(64, 1, 512, 6817, "bfloat16", 32, 8, 0, True, id="7b-b64-decode"),
+    pytest.param(8, 1, 512, 9915, "bfloat16", 32, 8, 0, True, id="nemo-b8-decode"),
+    pytest.param(8, 512, 512, 9915, "bfloat16", 32, 8, 0, True, id="nemo-b8-t512"),
+    pytest.param(64, 1, 512, 9915, "bfloat16", 32, 8, 0, True, id="nemo-b64-decode"),
+    pytest.param(8, 1, 512, 12279, "bfloat16", 8, 2, 0, True, id="tp4-b8-decode"),
+    pytest.param(8, 512, 512, 12279, "bfloat16", 8, 2, 0, True, id="tp4-b8-t512"),
+    # The other cells' decode programs at their own heads and windows:
+    # K-EXAONE's full and sliding layers, SmallThinker's, Nemotron's two KV
+    # heads, Falcon-H1's five query heads a KV head.
+    pytest.param(16, 1, 512, 20434, "bfloat16", 64, 8, 0, True,
+                 id="kexaone-b16-decode"),
+    pytest.param(16, 1, 512, 20434, "bfloat16", 64, 8, 128, True,
+                 id="kexaone-b16-decode-sliding"),
+    pytest.param(8, 1, 512, 7804, "bfloat16", 28, 4, 0, True,
+                 id="smallthinker-b8-decode"),
+    pytest.param(8, 1, 512, 7804, "bfloat16", 28, 4, 4096, True,
+                 id="smallthinker-b8-decode-sliding"),
+    pytest.param(32, 1, 512, 32769, "bfloat16", 32, 2, 0, True,
+                 id="nemotron-b32-decode"),
+    pytest.param(16, 1, 512, 14820, "bfloat16", 20, 4, 0, True,
+                 id="falcon-h1-b16-decode"),
+    pytest.param(32, 1, 16, 449, "int8", 32, 8, 0, True, id="int8-small-pool"),
     # The scale sidecars ride scalar prefetch into SMEM (1 MiB), 512 B a
     # block for K and for V: the compiler refuses the pool, and so must
     # the engine's own arithmetic, at construction.
-    pytest.param(32, 1, 16, 36000, "int8", 8, False, id="int8-pool-refused"),
+    pytest.param(32, 1, 16, 36000, "int8", 32, 8, 0, False, id="int8-pool-refused"),
 ])
 def test_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, nblk, nb, kv,
-                                 kh, compiles):
+                                 h, kh, window, compiles):
     """Mosaic itself, at the llama-3-8b geometry (32 Q / 8 KV heads x 128,
-    block 16: the benchmark's cuts have it too), judges the kernel's copies, loads and memory — and the
+    block 16: the benchmark's cuts have it too) and at the other cells'
+    heads and windows, judges the kernel's copies, loads and memory — and the
     engine's SMEM arithmetic (ModelRunner._check_kernel_fits) has to agree
     with it on which pools fit. The kernel's VMEM (two groups of K and of
     V, the softmax state, the query and output blocks) stays under the
-    scoped limit it runs with."""
+    scoped limit it runs with, and it is compiled with no hardware check
+    behind a copy's addresses: the kernel holds them in range itself."""
     from jax.sharding import SingleDeviceSharding
 
     import dynamo_tpu.ops.paged_attention as pa
@@ -445,7 +487,7 @@ def test_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, nblk, nb, kv,
         scalar_prefetch_bytes,
     )
 
-    h, d, bs = 4 * kh, 128, 16
+    d, bs = 128, 16
     sh = SingleDeviceSharding(v5e_device)
 
     def abstract(shape, dtype):
@@ -470,7 +512,7 @@ def test_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, nblk, nb, kv,
                  "s": abstract((nl, nb, kh), jnp.float32)}
     lowered = jax.jit(
         lambda q, k, v, bt, qs, kl, layer: paged_attention_kernel(
-            q, k, v, bt, qs, kl, layer=layer)
+            q, k, v, bt, qs, kl, layer=layer, window=window)
     ).lower(abstract((b, t, h, d), jnp.bfloat16), cache, cache,
             abstract((b, nblk), jnp.int32), abstract((b,), jnp.int32),
             abstract((b,), jnp.int32), abstract((), jnp.int32))
@@ -481,6 +523,7 @@ def test_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, nblk, nb, kv,
     (grid_spec, params), = calls
     assert len(grid_spec.grid) == 2          # (row, query chunk): no walk axis
     assert params.vmem_limit_bytes is None
+    assert params.disable_bounds_checks
     rchunk = grid_spec.in_specs[0].block_shape[2]
     blocks = 2 * [((kh, rchunk, d), jnp.bfloat16)]   # the query slab, the output
     assert _vmem_bytes(grid_spec, blocks) < V5E_SCOPED_VMEM_BYTES
@@ -1047,6 +1090,7 @@ def test_token_major_kernel_compiles_for_v5e(v5e_device, monkeypatch, b, t, n,
     (kw,) = calls
     assert kw["name"] == "paged_attention"
     assert kw["compiler_params"].dimension_semantics == ("arbitrary",) * 2
+    assert kw["compiler_params"].disable_bounds_checks
     assert kw["input_output_aliases"] == {
         kw["grid_spec"].num_scalar_prefetch: 0}
     assert _vmem_bytes(kw["grid_spec"], []) < V5E_SCOPED_VMEM_BYTES
