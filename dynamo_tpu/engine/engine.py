@@ -523,6 +523,12 @@ class ModelRunner:
         # times it, attributes the victim request, and feeds warmup
         # coverage. Disabled (warmup_mode=off) the gate is one bool read.
         self._ledger = get_compile_ledger()
+        # What it records of each program's build beside its seconds: the
+        # layer bodies a program of this model holds and the distinct among
+        # them, which are what its build traces and lowers
+        # (``LayerPlan.bodies``; read off the plan, no count inside a trace).
+        bodies = cfg.layer_plan.bodies
+        self._bodies = (len(bodies), len(set(bodies)))
         # Loop-phase seconds (obs/profiler.py): the runner adds
         # engine.compile around a step program built inside serving;
         # EngineCore shares this clock for the rest of the loop.
@@ -1121,7 +1127,7 @@ class ModelRunner:
                 dt = time.perf_counter() - t_compile
                 led.mark_inflight(False)
                 led.record(
-                    sig, dt,
+                    sig, dt, bodies=self._bodies,
                     trace_ctx=next((s.trace_ctx for s, _, _ in rows
                                     if s.trace_ctx is not None), None))
         return sig, program, toks, lps, (moe[0] if moe else None)
@@ -1325,7 +1331,7 @@ class ModelRunner:
             dt = time.perf_counter() - t_compile
             led.mark_inflight(False)
             led.record(
-                sig, dt,
+                sig, dt, bodies=self._bodies,
                 trace_ctx=next((s.trace_ctx for s, _, _ in rows
                                 if s.trace_ctx is not None), None))
         return sig, toks, lps
@@ -1399,7 +1405,8 @@ class ModelRunner:
                                    self._place(q_len)))
         if cold:
             led.mark_inflight(False)
-            led.record(sig, time.perf_counter() - t_compile)
+            led.record(sig, time.perf_counter() - t_compile,
+                       bodies=self._bodies)
         out[:] = hidden[: len(token_lists)]
         return out
 
@@ -1472,7 +1479,8 @@ class ModelRunner:
             toks, *_rest = self._run_step(
                 fn, self._padding_inputs(b, t, nblk, sig.greedy))
             np.asarray(toks)
-        self._ledger.record(sig, time.perf_counter() - t0, source="warmup")
+        self._ledger.record(sig, time.perf_counter() - t0, source="warmup",
+                            bodies=self._bodies)
         return False
 
 

@@ -41,6 +41,14 @@ class Mixer(NamedTuple):
     joined: bool = False
 
 
+def body_of(mixers: tuple[Mixer, ...]) -> tuple[Mixer, ...]:
+    """A layer's static description, what its traced body is a function of:
+    its mixers with their places taken out (``place`` 0, and ``layer`` how
+    far the buffer's layer stands from the place). Two layers of one
+    description are the same equations at other places."""
+    return tuple(m._replace(place=0, layer=m.layer - m.place) for m in mixers)
+
+
 class LayerPlan(NamedTuple):
     """A model's layers in order, each a tuple of mixers, and how they are
     run (models/llama.py ``_run_layers``): ``lead`` layers traced one by
@@ -52,6 +60,17 @@ class LayerPlan(NamedTuple):
     period: int
     trips: int
     rest: int
+
+    @property
+    def bodies(self) -> tuple[tuple[Mixer, ...], ...]:
+        """The layer bodies a program of this plan holds, by description
+        (:func:`body_of`): the leading layers, one period where one is
+        scanned, the rest. A description that stands there more than once
+        is traced and lowered once (``_run_layers``), so a program traces
+        ``len(set(bodies))`` of its ``len(bodies)`` bodies."""
+        held = (self.layers[:self.lead + (self.period if self.trips else 0)]
+                + self.layers[len(self.layers) - self.rest:])
+        return tuple(body_of(mixers) for mixers in held)
 
     def stage(self, n: int) -> "LayerPlan":
         """The plan of one pipeline stage's stack: ``n`` of a model's
@@ -292,10 +311,9 @@ class ModelConfig:
                     Mixer("*", stack, place, i, self.window_of(i)), *beside,
                     Mixer("E" if self.is_moe and not leads else "-", stack,
                           place, place)))
-        shapes = [tuple((m.kind, m.stack, m.window, m.joined) for m in layer)
-                  for layer in layers]
         return LayerPlan(tuple(layers), *_split(
-            shapes, self.first_k_dense, self.pattern_len))
+            [body_of(layer) for layer in layers], self.first_k_dense,
+            self.pattern_len))
 
     def layers_of(self, kind: str) -> int:
         """How many of the model's mixers are ``kind`` (``Mixer.kind``)."""

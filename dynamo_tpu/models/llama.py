@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dynamo_tpu.models.config import LayerPlan, ModelConfig
+from dynamo_tpu.models.config import LayerPlan, ModelConfig, body_of
 from dynamo_tpu.obs.profiler import phase as _perf_phase
 from dynamo_tpu.utils.logging import get_logger
 
@@ -781,7 +781,7 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                 cache_k, cache_v, ssm=None, *, lay: TokenLayout, q_start,
                 q_len=None, live=None, ssm_slots=None,
                 attn_impl: str = "dense", moe_impl: str = "dense", mesh=None,
-                **attn):
+                use_ring: bool = False, **attn):
     """Run the layers of ``plan`` (``cfg.layer_plan``, or a pipeline
     stage's part of it) over ``layers``, their stacked params: the one
     runner of ``forward`` and of both pp schedules. The plan's leading
@@ -826,7 +826,32 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
     layers a period — PERF.md section 6, PR 42.) Under ``moe_impl="held"``
     the experts' stacks do not ride xs either and are never cut by layer:
     the grouped matmul takes the stack whole and the layer's place in it
-    (``expert_layer``; models/moe.py ``held_rows``)."""
+    (``expert_layer``; models/moe.py ``held_rows``).
+
+    A body the program holds more than once is traced once (PR 54). The
+    bodies are the plan's (``plan.bodies``: lead, one period, rest), each
+    by its description (``body_of``: every mixer's kind, stack, window,
+    joined; not its place). Layers of a description that stands there more
+    than once run through one ``jax.jit``-wrapped ``one``, made once a call
+    of this function, which jax traces once a signature, the kernels'
+    bodies inside it with it: 13 bodies -> 3 in Nemotron's cut, 5 -> 3 in
+    K-EXAONE's, 4 -> 2 in SmallThinker's, in every program a start warms.
+    Two layers of one description differ in where they stand alone, so each
+    stack's place is an int32 operand (a constant outside the scan, which
+    XLA folds into the slice; the trip's inside it), and the stacks, the
+    experts' stacks, the carry and the rows' arrays are operands, whole: a
+    layer's params are read ``a[i]`` inside the body, every matrix by the
+    one dot that reads it where it lies. A description that stands once is
+    traced as it always was, with no wrapper: the choice reads the plan, no
+    model's name and no option. The wrapper is ``inline=True``: jax writes
+    the one traced body's equations into the program at each place, the
+    lowering's caches hit on the kernels they share, and the compiled
+    program is the parent's instruction for instruction (checked for the
+    described v5e, PERF.md section 6, PR 54). As a call (no ``inline``) XLA
+    inlines every one before it fuses, but then fuses a few elementwise
+    producers otherwise (Nemotron's decode program 451 -> 443 fusions): a
+    module a third the size, not the parent's program, for seconds the
+    chip's host did not tell apart."""
     from dynamo_tpu.models import mamba
     from dynamo_tpu.models.moe import route
 
@@ -842,16 +867,20 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
         experts = {k: routed.pop(k) for k in ("w_gate", "w_up", "w_down")
                    if k in routed}
     post = cfg.norm_placement == "post"
+    # Every traced value a layer reads beside the carry, which a shared
+    # body takes as operands (the layout's two integers are static).
+    rows, env = lay[:2], (stacks, experts, lay[2:], q_start, q_len, live,
+                          ssm_slots, attn)
 
-    def one(carry, mixers, at=lambda m: m.place, lp=None):
-        """One layer on ``carry``. ``at(m)``: mixer ``m``'s place in its
-        stack here (its own outside the scan, the trip's inside it); ``lp``
-        the layer's params where the scan hands them in."""
+    def one(carry, mixers, place, env, lp=None):
+        """One layer on ``carry``. ``place``: each stack's place here (its
+        mixers' own outside the scan, the trip's inside it; an int32 operand
+        of a shared body); ``env`` as above; ``lp`` the layer's params where
+        the scan hands them in. Of ``mixers`` it reads the description
+        alone (``body_of``)."""
         hid, k, v, state, counts = carry
-        place = {}
-        for m in mixers:
-            if m.stack not in place:
-                place[m.stack] = at(m)
+        stacks, experts, lay, q_start, q_len, live, ssm_slots, attn = env
+        lay = TokenLayout(*rows, *lay)
         if lp is None:
             lp = {}
             for stack, i in place.items():
@@ -877,7 +906,7 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                         cfg, lp, (m.layer - m.place) + i
                         if m.layer != m.place else i, x, k, v, lay=lay,
                         q_start=q_start, attn_impl=attn_impl, mesh=mesh,
-                        window=m.window, **attn)
+                        use_ring=use_ring, window=m.window, **attn)
                 elif m.kind == "M":
                     out, state = mamba.mixer(
                         cfg, lp, i, x, state, lay=lay, slots=ssm_slots,
@@ -899,8 +928,31 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                 hid = hid + out
         return hid, k, v, state, counts
 
+    bodies = plan.bodies
+    shared = {}         # a repeated description -> its one jitted body
+
+    def run(carry, mixers, at=lambda m: m.place):
+        """One layer, mixer ``m``'s stack at place ``at(m)``: through its
+        description's shared body where the program holds that description
+        more than once, else as it is."""
+        place = {}
+        for m in mixers:
+            if m.stack not in place:
+                place[m.stack] = at(m)
+        body = body_of(mixers)
+        if bodies.count(body) < 2:
+            return one(carry, mixers, place, env)
+        if body not in shared:
+            def layer(carry, place, env):
+                return one(carry, body, place, env)
+
+            shared[body] = jax.jit(layer, inline=True)
+        return shared[body](
+            carry, {s: jnp.asarray(i, jnp.int32) for s, i in place.items()},
+            env)
+
     for mixers in plan.layers[:plan.lead]:
-        carry = one(carry, mixers)
+        carry = run(carry, mixers)
     if plan.trips:
         period = plan.layers[plan.lead:plan.lead + plan.period]
         index = jnp.arange(plan.trips, dtype=jnp.int32)
@@ -908,7 +960,8 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
         if plan.period == 1 and plan.trips == len(jax.tree.leaves(on_xs)[0]):
             def layer_fn(carry, xs):
                 lp, i = xs
-                return one(carry, period[0], lambda m: i, lp), None
+                return one(carry, period[0],
+                           {m.stack: i for m in period[0]}, env, lp), None
 
             carry, _ = lax.scan(layer_fn, carry, (on_xs, index))
         else:
@@ -916,14 +969,14 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                 for mixers in period:
                     # (a stack's places step from trip to trip by the
                     # period's layers that read it)
-                    carry = one(carry, mixers, lambda m: trip * sum(
+                    carry = run(carry, mixers, lambda m: trip * sum(
                         any(o.stack == m.stack for o in layer)
                         for layer in period) + m.place)
                 return carry, None
 
             carry, _ = lax.scan(period_fn, carry, index)
     for mixers in plan.layers[len(plan.layers) - plan.rest:]:
-        carry = one(carry, mixers)
+        carry = run(carry, mixers)
     h, k, v, ssm, counts = carry
     return (h, *((k, v) if "*" in of_kind else (cache_k, cache_v)), ssm,
             counts)

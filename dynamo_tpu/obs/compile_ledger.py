@@ -268,6 +268,8 @@ class CompileLedger:
         self.events: list[CompileEvent] = []
         self.inventory: set[BucketSig] = set()
         self._dropped = 0
+        # (layer bodies held, traced) summed over the recorded programs
+        self._bodies = (0, 0)
         # Warmup plan: the enumerated lattice; None until an engine
         # configures warmup (coverage reads 0 with an empty plan).
         self.plan: set[BucketSig] | None = None
@@ -296,12 +298,17 @@ class CompileLedger:
             self.inventory.clear()
             self.plan = None
             self._dropped = 0
+            self._bodies = (0, 0)
 
     # -- recording ------------------------------------------------------
     def record(self, sig: BucketSig, seconds: float, *,
                trace_ctx=None, source: str = "serve",
-               ts: float | None = None) -> CompileEvent | None:
+               ts: float | None = None,
+               bodies: tuple[int, int] = (0, 0)) -> CompileEvent | None:
         """File one compile event; returns it (None when disabled).
+        ``bodies``: the layer bodies the program holds and how many of them
+        its build traced and lowered (``LayerPlan.bodies`` and the distinct
+        among them), read off the model's plan by the caller and summed.
 
         Serve-path events with a traced victim emit an ``engine.compile``
         span under the victim's trace; untraced serve events still land on
@@ -320,6 +327,8 @@ class CompileLedger:
                 self._dropped += 1
             self.inventory.add(sig)
             n_inv = len(self.inventory)
+            self._bodies = (self._bodies[0] + bodies[0],
+                            self._bodies[1] + bodies[1])
         m = get_compile_metrics()
         m.events.inc(kind=sig.kind, source=source)
         m.seconds.observe(seconds, kind=sig.kind)
@@ -379,6 +388,11 @@ class CompileLedger:
                 "serve_stall_seconds": sum(
                     e.seconds for e in self.events if e.source == "serve"),
                 "warmup_buckets": len(self.plan) if self.plan else 0,
+                # over the recorded programs: the layer bodies they hold
+                # and those their builds traced and lowered (a description
+                # a program repeats is traced once: llama._run_layers)
+                "layer_bodies": self._bodies[0],
+                "layer_bodies_traced": self._bodies[1],
             }
             if events:
                 out["events"] = [e.to_dict() for e in self.events]

@@ -607,3 +607,66 @@ def _q_positions(handed: int | None, live: int = 0):
 def test_q_padding_pct_on_a_hand_made_context(ctx, expect):
     value = measure.load_reader("attn.q_padding_pct").read(ctx)
     assert value == (None if expect is None else pytest.approx(expect))
+
+
+# ---------------------------------------------------------------------------
+# the step programs' build (PR 54): seconds a warmed program, and the share
+# of the programs' layer bodies that their builds traced
+# ---------------------------------------------------------------------------
+
+def _build(programs: int | None, seconds: float = 0.0, serve: float = 0.0,
+           bodies: tuple | None = None):
+    """Warm-up is over before the window's first edge: both snapshots hold
+    the same ledger, and the readers take the last."""
+    led = {} if programs is None else {
+        "cache_entries": programs, "compile_seconds_total": seconds,
+        "serve_stall_seconds": serve, "events_total": programs}
+    if bodies is not None:
+        led["layer_bodies"], led["layer_bodies_traced"] = bodies
+    return _ctx({"compile": dict(led)}, {"compile": led})
+
+
+@pytest.mark.parametrize("name, ctx, expect", [
+    # the hybrid cell's 21 programs: 3.5 s each with 13 of 13 bodies traced
+    # (what the parent's counts would read, had it them), 2.2 s with 3 of 13
+    ("engine.warmup_s_per_program", _build(21, 73.5), 3.5),
+    ("engine.warmup_s_per_program", _build(21, 46.2, bodies=(273, 63)), 2.2),
+    # a program the serving path had to build is no warmed program's second
+    ("engine.warmup_s_per_program", _build(14, 16.0, serve=2.0), 1.0),
+    ("engine.layer_bodies_traced_pct", _build(21, 46.2, bodies=(273, 63)),
+     100.0 * 3 / 13),
+    ("engine.layer_bodies_traced_pct", _build(21, 40.0, bodies=(105, 63)),
+     60.0),
+    ("engine.layer_bodies_traced_pct", _build(21, 40.0, bodies=(84, 42)),
+     50.0),
+    # a model of identical layers: one body a program, traced
+    ("engine.layer_bodies_traced_pct", _build(14, 11.0, bodies=(14, 14)),
+     100.0),
+    # the parent's ledger: its seconds are there, its bodies are not
+    ("engine.layer_bodies_traced_pct", _build(21, 73.5), None),
+    # no program recorded; a ledger switched off (no "compile" in stats)
+    ("engine.warmup_s_per_program", _build(0, 0.0, bodies=(0, 0)), None),
+    ("engine.layer_bodies_traced_pct", _build(0, 0.0, bodies=(0, 0)), None),
+    ("engine.warmup_s_per_program", _build(None), None),
+    ("engine.layer_bodies_traced_pct", _build(None), None),
+], ids=["parent_hybrid", "hybrid", "serve_compile_left_out", "3_of_13",
+        "3_of_5", "2_of_4", "one_body", "parent_no_counts", "no_programs_s",
+        "no_programs_pct", "no_ledger_s", "no_ledger_pct"])
+def test_build_reader_on_a_hand_made_context(name, ctx, expect):
+    value = measure.load_reader(name).read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))
+
+
+def test_build_readers_with_no_compile_key_at_all():
+    ctx = _ctx({"num_steps": 1}, {"num_steps": 9})
+    for name in ("engine.warmup_s_per_program",
+                 "engine.layer_bodies_traced_pct"):
+        assert measure.load_reader(name).read(ctx) is None
+
+
+def test_every_cell_reports_the_build_metrics():
+    """They move ``setup_s``, which every cell reports: no ``workloads``."""
+    for w in BENCH["workloads"]:
+        _judged, layer = manifest.cell_metrics(BENCH, w["name"])
+        assert {"engine.warmup_s_per_program",
+                "engine.layer_bodies_traced_pct"} <= set(layer)

@@ -8,8 +8,9 @@ compared by, for a described v5e and with no chip.
 For each (rows, chunk) it traces the program the serving path runs (packed
 inputs, greedy, ``attn_impl="pallas"``, the routed layer as a TPU backend
 chooses it) through the abstract runner of ``<checkout>/chipbench/aot_check.py``
-and writes ``<dir>/b<rows>_t<chunk>.txt``: the lowered text passed through
-``tools/kernel_text.py``. With ``--compile`` also ``.hlo.txt``, the compiled
+(put on a mesh of the described chips where the configuration's engine has
+``tp`` > 1) and writes ``<dir>/b<rows>_t<chunk>.txt``: the lowered text passed
+through ``tools/kernel_text.py``. With ``--compile`` also ``.hlo.txt``, the compiled
 program less what names its source and with the compiler's names replaced by
 their order, and one line of ``memory.jsonl`` with XLA's buffer assignment.
 ``diff -r`` of two checkouts' directories then says whether a change to the
@@ -72,9 +73,39 @@ def main() -> int:
     config_dir = root / "chipbench" / "configs" / args.config
     about = json.loads((config_dir / "about.json").read_text())
     runner, cfg, ec, params, state, on_chip = aot_check.build_abstract_runner(
-        config_dir, about.get("engine", {}))
-    cache = on_chip(abstract_cache(KVCacheSpec.for_model(
-        cfg, args.blocks, ec.block_size, kv_dtype=ec.kv_dtype), None))
+        config_dir, {k: v for k, v in about.get("engine", {}).items()
+                     if k != "why"})
+    spec = KVCacheSpec.for_model(cfg, args.blocks, ec.block_size,
+                                 kv_dtype=ec.kv_dtype)
+    cache = on_chip(abstract_cache(spec, None))
+    if ec.tp > 1:
+        # The runner on a mesh of the described chips ("model" alone): the
+        # parameters by their logical axes, the cache by its own rule,
+        # everything else whole on every chip.
+        from jax.experimental import topologies
+
+        from dynamo_tpu.models import llama
+        from dynamo_tpu.parallel.mesh import (
+            MeshConfig,
+            make_mesh,
+            param_shardings,
+            replicated,
+        )
+
+        runner.mesh = mesh = make_mesh(
+            MeshConfig(tp=ec.tp), topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2").devices)
+        runner._repl, runner.spec = replicated(mesh), spec
+
+        def on_chip(tree, sharding=None):
+            return jax.tree.map(
+                lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                   sharding=sh),
+                tree, sharding or jax.tree.map(lambda s: runner._repl, tree))
+
+        params = on_chip(params, param_shardings(
+            llama.param_logical_axes(cfg), mesh))
+        state, cache = on_chip(state), abstract_cache(spec, mesh)
     pool = {"ssm": on_chip(mamba.state_shapes(cfg, ec.max_batch_size))} \
         if cfg.has_ssm else {}
     args.out.mkdir(parents=True, exist_ok=True)
