@@ -641,7 +641,6 @@ async def amain(ns: argparse.Namespace) -> None:
         rt.on_reconnect(put_card)
     log.info("worker ready: engine=%s model=%s disagg=%s instance=%x",
              ns.engine, name, ns.disagg, rt.instance_id)
-    print(f"WORKER_READY instance={rt.instance_id:016x}", flush=True)
 
     # -- retirement (runtime/drain.py) ---------------------------------
     # First SIGTERM/SIGINT starts a graceful drain: membership out, bounded
@@ -672,6 +671,11 @@ async def amain(ns: argparse.Namespace) -> None:
 
     for sig in (signal.SIGINT, signal.SIGTERM):
         loop.add_signal_handler(sig, on_signal)
+    # Said only now: whoever reads this line may signal at once (the
+    # planner's connector retires a replica by SIGTERM), and a signal that
+    # lands before the handlers are in kills the process where it should
+    # drain it (seen under load: exit code -15 for 0, PR 56).
+    print(f"WORKER_READY instance={rt.instance_id:016x}", flush=True)
 
     async def watch_drain_key() -> None:
         key = drain_key(ns.namespace, rt.instance_id)

@@ -64,8 +64,9 @@ class KVCacheSpec:
             block_size=block_size,
             # (a layer of another mixer has no K and V: models/mamba.py)
             num_layers=cfg.attn_layers,
-            num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.head_dim,
+            # (differential attention's pairs of heads are one head each)
+            num_kv_heads=cfg.cache_kv_heads,
+            head_dim=cfg.cache_head_dim,
             dtype=cfg.dtype,
             kv_dtype=kv_dtype,
         )

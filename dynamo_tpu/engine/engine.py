@@ -90,6 +90,7 @@ from dynamo_tpu.obs.sched_ledger import (
     SSM_COUNTS,
     HolStall,
     get_sched_ledger,
+    recurrent_and_cross,
     step_counts,
     step_geometry,
 )
@@ -244,7 +245,9 @@ _RECORD_SPAN_COUNTS = (
     "programs", "live_tokens", "logit_rows", "attn_q_ctx", "kv_blocks_walked")
 _RECORD_SPAN_MOE = ("moe_layer_steps", "moe_rows", "moe_experts_touched")
 _RECORD_SPAN_SSM = ("ssm_layer_steps", "ssm_live_tokens",
-                    "ssm_scanned_positions", "ssm_state_rows")
+                    "ssm_scanned_positions", "ssm_state_rows",
+                    "ssm_scan_rows", "ssm_scan_positions")
+_RECORD_SPAN_CROSS = ("cross_tokens", "kv_blocks_walked_shared")
 
 
 @jax.jit
@@ -1349,7 +1352,7 @@ class ModelRunner:
 
         def embed(params, tokens, q_len):
             shape = (cfg.attn_layers, nblk + 1, ec.block_size,
-                     cfg.num_kv_heads, cfg.head_dim)
+                     cfg.cache_kv_heads, cfg.cache_head_dim)
             ck = jnp.zeros(shape, jnp.dtype(cfg.dtype))
             cv = jnp.zeros(shape, jnp.dtype(cfg.dtype))
             bt = jnp.tile(jnp.arange(1, nblk + 1, dtype=jnp.int32)[None, :],
@@ -1570,7 +1573,7 @@ class EngineCore:
                 f"{mc.router_width} routed experts: that is one chip's share "
                 f"of an expert-parallel deployment, and ep={engine_cfg.ep} "
                 "would divide it again; give the whole model to ep > 1")
-        if engine_cfg.pp > 1 and mc.layer_plan[1:] != (0, 1, mc.num_layers, 0):
+        if engine_cfg.pp > 1 and mc.layer_plan.split != (0, 1, mc.num_layers, 0):
             raise ValueError(
                 "pipeline stages take equal stacks of identical layers: a "
                 "model with leading layers or a period of several (sliding "
@@ -1587,7 +1590,8 @@ class EngineCore:
         # (obs/sched_ledger.py step_counts).
         self._windows = mc.attn_windows
         self._routed_layers = mc.layers_of("E")
-        self._ssm_layers = mc.layers_of("M")
+        self._recurrent_and_cross = recurrent_and_cross(mc)
+        self._ssm_layers = self._recurrent_and_cross["ssm_layers"]
         # Whether a program of n tokens streams its experts: the routed
         # layer's own predicate at this model's expert shape.
         from dynamo_tpu.models.moe import streams_experts
@@ -2142,16 +2146,21 @@ class EngineCore:
             return None
         slots = self.engine_cfg.max_batch_size
         pool = mamba.state_shapes(mc, slots)
-        return {"layers": mc.layers_of("M"), "slots": slots,
+        # A Mamba-1 mixer's state is [N, d], a channel where a Mamba-2 head
+        # has a [P, N] block: d heads of size 1, and its ``dt`` is d wide.
+        one = bool(mc.mamba_inner)
+        heads, head_dim = ((mc.mamba_inner, 1) if one
+                           else (mc.mamba_num_heads, mc.mamba_head_dim))
+        return {"layers": self._ssm_layers, "slots": slots,
+                "recurrence": "mamba1" if one else "mamba2",
                 "shapes": {k: list(v.shape) for k, v in pool.items()},
                 "dtypes": {k: str(v.dtype) for k, v in pool.items()},
                 "slot_layer_bytes": mamba.slot_layer_bytes(mc),
                 "pool_bytes": mamba.state_bytes(mc, slots),
                 # per live token of a layer: z, xBC and dt in, y out
-                "token_bytes": (2 * mc.ssm_inner + mc.ssm_conv_dim
-                                + mc.mamba_num_heads)
+                "token_bytes": (2 * mc.ssm_inner + mc.ssm_conv_dim + heads)
                 * jnp.dtype(mc.dtype).itemsize,
-                "heads": mc.mamba_num_heads, "head_dim": mc.mamba_head_dim,
+                "heads": heads, "head_dim": head_dim,
                 "state_size": mc.ssm_state_size, "groups": mc.ssm_groups,
                 "conv_kernel": mc.conv_kernel, "conv_dim": mc.ssm_conv_dim,
                 "chunk": mc.ssm_chunk,
@@ -2463,8 +2472,8 @@ class EngineCore:
         of a trace to join to the step's programs by ``step``."""
         counts = step_counts(pending.batches, self.engine_cfg.block_size,
                              self._windows, dec_rows=pending.dec_rows,
-                             ssm_layers=self._ssm_layers,
-                             attn_tokens=attends_tokens(self.engine_cfg))
+                             attn_tokens=attends_tokens(self.engine_cfg),
+                             **self._recurrent_and_cross)
         ssm = (tuple(counts[k] for k in SSM_COUNTS) if self._ssm_layers
                else None)
         pc = self.sched.preemption_count
@@ -2485,7 +2494,9 @@ class EngineCore:
             span.set(**{k: counts[k] for k in _RECORD_SPAN_COUNTS},
                      **(dict(zip(_RECORD_SPAN_MOE, moe)) if moe else {}),
                      **({k: counts[k] for k in _RECORD_SPAN_SSM}
-                        if ssm else {}))
+                        if ssm else {}),
+                     **({k: counts[k] for k in _RECORD_SPAN_CROSS}
+                        if counts["cross_tokens"] else {}))
             self.traced_programs.update(pending.programs)
         if self.sched_led.enabled:
             info = pending.sched or {}

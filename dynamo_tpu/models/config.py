@@ -22,7 +22,10 @@ class Mixer(NamedTuple):
     """One sub-layer, ``h + mixer(norm(h))``, and where its operands lie.
     The mixers of one layer that share a stack share the place."""
     #: "*" attention, "-" a dense FFN, "E" routed experts, "M" Mamba-2: the
-    #: letters of NemotronH's ``hybrid_override_pattern``
+    #: letters of NemotronH's ``hybrid_override_pattern``. SambaY's three
+    #: (``decoder_layout``): "S" a Mamba-1 mixer, "X" attention over another
+    #: layer's keys and values (a query projection alone, nothing written),
+    #: "G" a gated memory unit, which gates the step's memory
     kind: str
     #: the group of ``params["layers"]`` that holds its leaves: "lead" (the
     #: ``lead_`` leaves), "rep" (the repeated group) or, in a hybrid pattern,
@@ -32,62 +35,114 @@ class Mixer(NamedTuple):
     #: stack and a recurrent one's layer of the state pool as well
     place: int
     #: its layer of the buffer its kind carries: the KV cache counts the
-    #: model's attention layers from the first; every other kind, ``place``
+    #: model's attention layers from the first (an "X" mixer names the layer
+    #: it rereads); every other kind, ``place``
     layer: int
     window: int = 0     # attention's window, 0 = full or no attention
     #: it stands beside the mixer before it: it reads the output of that
     #: mixer's norm and the two are added to the residual in one add
     #: (Falcon-H1's attention and Mamba-2 mixer); it has no norm of its own
     joined: bool = False
+    #: an "S" mixer whose scan output, before its gate, is the step's memory:
+    #: carried beside the hidden state to the "G" mixers behind it
+    keeps: bool = False
 
 
 def body_of(mixers: tuple[Mixer, ...]) -> tuple[Mixer, ...]:
     """A layer's static description, what its traced body is a function of:
     its mixers with their places taken out (``place`` 0, and ``layer`` how
-    far the buffer's layer stands from the place). Two layers of one
-    description are the same equations at other places."""
-    return tuple(m._replace(place=0, layer=m.layer - m.place) for m in mixers)
+    far the buffer's layer stands from the place; an "X" mixer's layer is
+    another mixer's and stays what it is). Two layers of one description
+    are the same equations at other places."""
+    return tuple(m._replace(place=0, layer=m.layer if m.kind == "X"
+                            else m.layer - m.place) for m in mixers)
+
+
+class Run(NamedTuple):
+    """One scanned run of a plan: ``lead`` layers traced one by one (from
+    the run before it, or the model's first layer), then ``period`` layers
+    as the body of a scan of ``trips`` trips (``trips`` 0: nothing is
+    scanned)."""
+    lead: int
+    period: int
+    trips: int
 
 
 class LayerPlan(NamedTuple):
     """A model's layers in order, each a tuple of mixers, and how they are
-    run (models/llama.py ``_run_layers``): ``lead`` layers traced one by
-    one, ``period`` layers as the body of a scan of ``trips`` trips,
-    ``rest`` layers traced one by one."""
+    run (models/llama.py ``_run_layers``): run after run (:class:`Run`),
+    and what is left behind the last of them traced one by one. A model of
+    one repeated structure has one run; SambaY's two decoders have one
+    each."""
 
     layers: tuple[tuple[Mixer, ...], ...]
-    lead: int
-    period: int
-    trips: int
-    rest: int
+    runs: tuple[Run, ...]
+    #: the layers from this one on are needed for the tokens whose logits
+    #: are taken alone (SambaY's cross-decoder): a step runs them over each
+    #: row's last live token. None: every layer runs over every token
+    last_from: int | None = None
+
+    @property
+    def split(self) -> tuple[int, ...]:
+        """(lead, period, trips) of each run in turn, then ``rest``."""
+        return (*(x for run in self.runs for x in run), self.rest)
+
+    @property
+    def rest(self) -> int:
+        """Layers behind the last run, traced one by one."""
+        return len(self.layers) - sum(
+            r.lead + r.period * r.trips for r in self.runs)
+
+    # The first run's, which is all of a one-run plan.
+    lead = property(lambda self: self.runs[0].lead)
+    period = property(lambda self: self.runs[0].period)
+    trips = property(lambda self: self.runs[0].trips)
+
+    @property
+    def spans(self) -> tuple[tuple[int, int, int], ...]:
+        """The plan in the order it is run: ``(first layer, layers, trips)``
+        of each piece, ``trips`` 0 for layers traced one by one and else a
+        period's layers scanned that often. ``last_from`` begins a piece (a
+        period is one description, so it never stands inside one)."""
+        out, at, cut = [], 0, self.last_from
+        for lead, period, trips in self.runs:
+            out += [(at, lead, 0), (at + lead, period, trips)]
+            at += lead + period * trips
+        out.append((at, len(self.layers) - at, 0))
+        # (a run of no trips scans nothing: its period is no piece)
+        out = [(a, n, t) for i, (a, n, t) in enumerate(out)
+               if n and (t or not i % 2)]
+        return tuple(piece for a, n, t in out for piece in (
+            [(a, cut - a, 0), (cut, a + n - cut, 0)]
+            if cut is not None and not t and a < cut < a + n else [(a, n, t)]))
 
     @property
     def bodies(self) -> tuple[tuple[Mixer, ...], ...]:
         """The layer bodies a program of this plan holds, by description
-        (:func:`body_of`): the leading layers, one period where one is
-        scanned, the rest. A description that stands there more than once
-        is traced and lowered once (``_run_layers``), so a program traces
-        ``len(set(bodies))`` of its ``len(bodies)`` bodies."""
-        held = (self.layers[:self.lead + (self.period if self.trips else 0)]
-                + self.layers[len(self.layers) - self.rest:])
-        return tuple(body_of(mixers) for mixers in held)
+        (:func:`body_of`): each run's leading layers and one period where
+        one is scanned, then the rest. A description that stands there more
+        than once is traced and lowered once (``_run_layers``), so a program
+        traces ``len(set(bodies))`` of its ``len(bodies)`` bodies."""
+        return tuple(body_of(mixers) for at, n, _trips in self.spans
+                     for mixers in self.layers[at:at + n])
 
     def stage(self, n: int) -> "LayerPlan":
         """The plan of one pipeline stage's stack: ``n`` of a model's
         identical layers (the engine gives no other model stages)."""
-        return self._replace(layers=self.layers[:n], trips=n)
+        return self._replace(layers=self.layers[:n], runs=(Run(0, 1, n),))
 
 
-def _split(shapes: list, lead: int, period: int) -> tuple[int, int, int, int]:
-    """(lead, period, trips, rest) of layers whose static shapes are
-    ``shapes``: where a scan stands in the program. One problem, leading
-    layers + whole periods + what is left, with one difference. A model
-    that states its period (``pattern_len``, behind ``lead`` leading
-    layers) gets that period, scanned whenever one whole period fits: a
-    depth cut to one period also fits a shorter one (L L G L is L L G and
-    one more), and the stated length decides. A model that states none
-    gets the split that traces the fewest layer bodies with at least two
-    trips (else nothing is scanned: every layer leads)."""
+def _split(shapes: list, lead: int, period: int) -> tuple[Run, ...]:
+    """The runs of layers whose static shapes are ``shapes``: where the
+    scans stand in the program. One problem, leading layers + whole periods
+    + what is left, asked again of what is left for as long as that holds
+    another scan of two trips or more, with one difference in the first
+    asking. A model that states its period (``pattern_len``, behind ``lead``
+    leading layers) gets that period, scanned whenever one whole period
+    fits: a depth cut to one period also fits a shorter one (L L G L is
+    L L G and one more), and the stated length decides. A model that states
+    none gets the split that traces the fewest layer bodies with at least
+    two trips (else nothing is scanned: every layer leads)."""
     n = len(shapes)
 
     def fits(lead, p, count):
@@ -95,7 +150,7 @@ def _split(shapes: list, lead: int, period: int) -> tuple[int, int, int, int]:
                    for i in range(count))
 
     if 0 < period <= n - lead and fits(lead, period, n - lead):
-        return lead, period, (n - lead) // period, (n - lead) % period
+        return (Run(lead, period, (n - lead) // period),)
     best = (n, 0, n, 1, 0)              # (bodies, rest, lead, period, trips)
     for lead in range(n):
         for p in range(1, (n - lead) // 2 + 1):
@@ -105,7 +160,11 @@ def _split(shapes: list, lead: int, period: int) -> tuple[int, int, int, int]:
             if trips >= 2:
                 rest = n - lead - trips * p
                 best = min(best, (lead + p + rest, rest, lead, p, trips))
-    return (*best[2:], best[1])
+    run, rest = Run(*best[2:]), best[1]
+    if not run.trips:
+        return (Run(n, 1, 0),)
+    behind = _split(shapes[n - rest:], 0, 0) if rest else ()
+    return (run, *(r for r in behind if r.trips))
 
 
 @dataclass(frozen=True)
@@ -212,6 +271,29 @@ class ModelConfig:
     ssm_multipliers: tuple[float, ...] = ()
     ssm_out_multiplier: float = 1.0
     mlp_multipliers: tuple[float, ...] = ()
+    # "sambay": SambaY's decoder-hybrid-decoder (arXiv:2507.06607). The
+    # first half of the layers is a self-decoder, a Mamba-1 mixer ("S") and
+    # windowed attention by turns; layer n/2 is one more Mamba-1 mixer whose
+    # scan output is the step's memory and layer n/2 + 1 full attention;
+    # behind them a cross-decoder, by turns a gated memory unit ("G") on
+    # that memory and attention over layer n/2 + 1's keys and values ("X").
+    # Every layer is its mixer, then a dense FFN. "" = none of it.
+    decoder_layout: str = ""
+    # d and the rank of dt's projection in a Mamba-1 mixer (0: no such mixer)
+    mamba_inner: int = 0
+    mamba_dt_rank: int = 0
+    # "rms": RMSNorm, a weight. "layer": LayerNorm, a weight and a bias
+    # (``<norm>_b`` beside every norm's leaf), eps ``rms_norm_eps``.
+    norm_kind: str = "rms"
+    # q, k, v and o are projected with a bias (``bq``, ``bk``, ``bv``, ``bo``)
+    attention_bias: bool = False
+    # Differential attention (arXiv:2410.05258) in every attention mixer:
+    # heads in adjacent pairs, two softmaxes over one pair's keys each, read
+    # against the pair's two value heads side by side, the second taken from
+    # the first times a learned lambda, an RMSNorm over the pair's width,
+    # ``1 - lambda_init``. The KV cache holds whole pairs side by side in
+    # fewer, wider heads (``cache_kv_heads`` x ``cache_head_dim``).
+    diff_attention: bool = False
     # Multimodal (vision encoder attached)
     vision: "VisionConfig | None" = None
 
@@ -233,6 +315,29 @@ class ModelConfig:
         if self.expert_act not in ("silu", "relu", "relu2"):
             raise ValueError(f"expert_act {self.expert_act!r}: the routed "
                              "experts act by 'silu', 'relu' or 'relu2'")
+        if self.decoder_layout not in ("", "sambay"):
+            raise ValueError(f"decoder_layout {self.decoder_layout!r}: "
+                             "'sambay' or none")
+        if self.decoder_layout and (
+                self.hybrid_pattern or self.ssm_beside_attention
+                or self.is_moe or self.layer_types or self.num_layers < 4
+                or self.num_layers % 2 or not self.mamba_inner
+                or self.norm_placement != "pre"):
+            raise ValueError(
+                "decoder_layout 'sambay' gives every layer's kind (no "
+                "hybrid_pattern, layer_types, routed layer, "
+                "ssm_beside_attention or 'post' norm), over an even number "
+                "of layers, four or more, with mamba_inner stated")
+        if self.diff_attention and (
+                self.num_heads % 2 or self.num_kv_heads % 2 or self.qk_norm
+                or self.rope_scope != "none"):
+            raise ValueError(
+                "diff_attention pairs adjacent heads (even num_heads and "
+                "num_kv_heads) and is implemented without positions or QK "
+                "norm (rope_scope 'none': the pair view of models/llama.py "
+                "_attention has no rope of a half)")
+        if self.norm_kind not in ("rms", "layer"):
+            raise ValueError(f"norm_kind {self.norm_kind!r}: 'rms' or 'layer'")
         if self.hybrid_pattern:
             odd = set(self.hybrid_pattern) - set("M*E")
             if odd or len(self.hybrid_pattern) != self.num_layers:
@@ -284,7 +389,7 @@ class ModelConfig:
     @property
     def has_ssm(self) -> bool:
         """Whether some layer carries recurrent state (models/mamba.py)."""
-        return self.layers_of("M") > 0
+        return self.layers_of("M") + self.layers_of("S") > 0
 
     @cached_property
     def layer_plan(self) -> LayerPlan:
@@ -295,6 +400,8 @@ class ModelConfig:
         a Mamba-2 mixer joined to attention under
         ``ssm_beside_attention``."""
         layers = []
+        if self.decoder_layout:
+            return self._sambay_plan()
         if self.hybrid_pattern:
             seen = dict.fromkeys("M*E", 0)
             for kind in self.hybrid_pattern:
@@ -311,9 +418,34 @@ class ModelConfig:
                     Mixer("*", stack, place, i, self.window_of(i)), *beside,
                     Mixer("E" if self.is_moe and not leads else "-", stack,
                           place, place)))
-        return LayerPlan(tuple(layers), *_split(
+        return LayerPlan(tuple(layers), _split(
             [body_of(layer) for layer in layers], self.first_k_dense,
             self.pattern_len))
+
+    def _sambay_plan(self) -> LayerPlan:
+        """``layer_plan`` under ``decoder_layout`` "sambay": a stack a kind
+        (both Mamba kinds' is "M"), every layer its mixer and then its FFN
+        from place ``i`` of the FFNs' stack."""
+        half = self.num_layers // 2
+        layers, seen = [], dict.fromkeys("M*XG", 0)
+        for i in range(self.num_layers):
+            if i > half + 1:
+                kind = "X" if i % 2 else "G"
+            else:
+                kind = "*" if i % 2 else "S"
+            stack = "M" if kind == "S" else kind
+            at = seen[stack]
+            seen[stack] += 1
+            mixer = Mixer(
+                kind, stack, at,
+                # (the cross layers reread layer half + 1's keys and values:
+                # the last attention layer that writes any)
+                half // 2 if kind == "X" else at,
+                self.sliding_window if kind == "*" and i < half else 0,
+                keeps=i == half)
+            layers.append((mixer, Mixer("-", "-", i, i)))
+        return LayerPlan(tuple(layers), _split(
+            [body_of(layer) for layer in layers], 0, 0), last_from=half + 2)
 
     def layers_of(self, kind: str) -> int:
         """How many of the model's mixers are ``kind`` (``Mixer.kind``)."""
@@ -330,6 +462,34 @@ class ModelConfig:
         """The window of each layer that has attention (0: full)."""
         return tuple(m.window for layer in self.layer_plan.layers
                      for m in layer if m.kind == "*")
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """The KV cache's heads. Under ``diff_attention`` a cache head is
+        several whole pairs of the model's KV heads side by side, the same
+        bytes in another view (models/llama.py ``_attention``): as many
+        heads as the chip's tiles take whole, the most of 1, 2, 4 or a
+        multiple of 8 that divides the pairs (a TPU array's last dimension
+        but one is stored in tiles of 8: ten heads of 128 would lie as
+        sixteen, and the paged kernel cannot cut them: Phi-4-mini-flash's
+        20 heads of 64 are 2 of 640)."""
+        if not self.diff_attention:
+            return self.num_kv_heads
+        pairs = self.num_kv_heads // 2
+        return max(n for n in range(1, pairs + 1)
+                   if pairs % n == 0 and (n in (1, 2, 4) or n % 8 == 0))
+
+    @property
+    def cache_head_dim(self) -> int:
+        return self.num_kv_heads * self.head_dim // self.cache_kv_heads
+
+    def lambda_init(self, kind: str) -> "np.ndarray":
+        """Differential attention's ``lambda_init`` at each place of the
+        stack of ``kind`` ("*" or "X"), float32: ``0.8 - 0.6 exp(-0.3 i)``
+        at the model's layer ``i``."""
+        at = [i for i, layer in enumerate(self.layer_plan.layers)
+              if any(m.kind == kind for m in layer)]
+        return (0.8 - 0.6 * np.exp(-0.3 * np.asarray(at))).astype(np.float32)
 
     @property
     def shared_expert_width(self) -> int:
@@ -351,12 +511,16 @@ class ModelConfig:
 
     @property
     def ssm_inner(self) -> int:
-        """d: the Mamba mixer's inner width, heads x head size."""
-        return self.mamba_num_heads * self.mamba_head_dim
+        """d: the Mamba mixer's inner width, heads x head size (Mamba-2) or
+        as stated (Mamba-1)."""
+        return self.mamba_inner or self.mamba_num_heads * self.mamba_head_dim
 
     @property
     def ssm_conv_dim(self) -> int:
-        """c: the channels the convolution runs over, x | B | C."""
+        """c: the channels the convolution runs over, x | B | C (Mamba-2)
+        or x alone (Mamba-1)."""
+        if self.mamba_inner:
+            return self.mamba_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     @cached_property
@@ -413,7 +577,8 @@ class ModelConfig:
     def from_hf_config(cls, path: str) -> "ModelConfig":
         """Read a local HF config.json (llama-family keys)."""
         cfg = json.loads((Path(path) / "config.json").read_text())
-        cfg = _falcon_h1_keys(_nemotron_h_keys(_smallthinker_keys(cfg)))
+        cfg = _phi4flash_keys(
+            _falcon_h1_keys(_nemotron_h_keys(_smallthinker_keys(cfg))))
         n_heads = cfg["num_attention_heads"]
         # MoE keys across HF families: mixtral (num_local_experts),
         # deepseek/qwen-moe (n_routed_experts, num_experts).
@@ -442,7 +607,7 @@ class ModelConfig:
         sliding = "sliding_attention" in kinds
         rope = cfg.get("rope_parameters") or {}
         sigmoid = cfg.get("scoring_func") == "sigmoid"
-        return cls(
+        return cls(**{**dict(
             num_experts=n_experts,
             num_experts_published=published or 0,
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2 if n_experts else 0),
@@ -480,8 +645,6 @@ class ModelConfig:
             time_step_max=cfg.get("time_step_max", 0.1),
             time_step_floor=cfg.get("time_step_floor", 1e-4),
             ssm_state_dtype=cfg.get("ssm_state_dtype", "float32"),
-            # (the fields that one family's reader alone sets)
-            **cfg.get(_OWN_FIELDS, {}),
             name=cfg.get("_name_or_path", Path(path).name),
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
@@ -495,9 +658,11 @@ class ModelConfig:
             rope_theta=float(cfg.get("rope_theta")
                              or rope.get("rope_theta") or 10000.0),
             rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            attention_bias=bool(cfg.get("attention_bias", False)),
             max_position_embeddings=cfg.get("max_position_embeddings", 8192),
             tie_word_embeddings=cfg.get("tie_word_embeddings", False),
-        )
+            # (the fields that one family's reader alone sets)
+        ), **cfg.get(_OWN_FIELDS, {})})
 
 
 def _smallthinker_keys(cfg: dict) -> dict:
@@ -658,6 +823,68 @@ def _falcon_h1_keys(cfg: dict) -> dict:
                 "ssm_out_multiplier")},
             **{k: tuple(float(x) for x in cfg.get(k) or ())
                for k in ("ssm_multipliers", "mlp_multipliers")},
+        },
+    }
+
+
+def _phi4flash_keys(cfg: dict) -> dict:
+    """``cfg`` with Phi-4-mini-flash's keys (``model_type: "phi4flash"``:
+    SambaY with differential attention, arXiv:2507.06607; Mamba-1 and
+    windowed attention by turns, then a cross-decoder) under the names
+    ``from_hf_config`` reads; any other config comes back as it is. The
+    Mamba-1 sizes a config leaves out are the configuration class's
+    defaults. What cannot be served is refused by its key."""
+    if cfg.get("model_type") != "phi4flash":
+        return cfg
+    n, h = cfg["num_hidden_layers"], cfg["hidden_size"]
+    refused = {
+        "mb_per_layer": (
+            cfg.get("mb_per_layer", 2) != 2,
+            "a Mamba mixer in every second layer is what is implemented"),
+        "num_hidden_layers": (
+            n < 4 or n % 2, "the two decoders are half the layers each: an "
+            "even number, four or more"),
+        "sliding_window": (
+            not isinstance(cfg.get("sliding_window"), int)
+            or cfg["sliding_window"] <= 0,
+            "one window for the self-decoder's attention layers"),
+        "mamba_proj_bias": (bool(cfg.get("mamba_proj_bias", False)),
+                            "models/mamba.py projects without bias"),
+        "mamba_conv_bias": (not cfg.get("mamba_conv_bias", True),
+                            "the convolution is served with its bias"),
+        "mlp_bias": (bool(cfg.get("mlp_bias", False)),
+                     "the MLP projects without bias"),
+        "lm_head_bias": (bool(cfg.get("lm_head_bias", False)),
+                         "the head has no bias"),
+        "attention_bias": (not cfg.get("attention_bias", True),
+                           "the family's attention projects with bias"),
+        "hidden_act": (cfg.get("hidden_act", "silu") != "silu",
+                       "the MLP, the Mamba gate and the memory unit act by "
+                       "silu"),
+        "rope_scope": (cfg.get("rope_scope", "none") != "none",
+                       "no layer of this family carries a position"),
+        "ssm_state_dtype": (cfg.get("ssm_state_dtype", "float32") != "float32",
+                            "the recurrent state is stored as float32"),
+        "tie_word_embeddings": (not cfg.get("tie_word_embeddings", True),
+                                "the head is the embedding's table"),
+    }
+    for key, (hit, why) in refused.items():
+        if hit:
+            raise ValueError(f"{key}: {cfg.get(key)!r} is refused: {why}")
+    return {
+        **cfg,
+        "rope_scope": "none",
+        "attention_bias": True,
+        "rms_norm_eps": cfg.get("layer_norm_eps", 1e-5),
+        "ssm_state_size": cfg.get("mamba_d_state", 16),
+        "conv_kernel": cfg.get("mamba_d_conv", 4),
+        _OWN_FIELDS: {
+            "decoder_layout": "sambay",
+            "norm_kind": "layer",
+            "diff_attention": True,
+            "sliding_window": cfg["sliding_window"],
+            "mamba_inner": cfg.get("mamba_expand", 2) * h,
+            "mamba_dt_rank": cfg.get("mamba_dt_rank") or -(-h // 16),
         },
     }
 

@@ -69,11 +69,22 @@ def layer_stacks(layers: Params) -> dict[str, Params]:
     ``lead_<name>`` in the same flat dict: every leaf of ``layers`` stays an
     array, which is what the loaders, the sharding rules and the
     benchmark's weight rounding walk. A hybrid pattern has a stack a kind,
-    "M", "*" and "E", told apart by the leaves' names."""
+    "M", "*" and "E", told apart by the leaves' names; SambaY's layout
+    (``cfg.decoder_layout``) has "M", "*", the cross layers' "X" (attention's
+    names behind ``x_``), the memory units' "G" and the FFNs' "-"."""
     from dynamo_tpu.models import mamba
 
     rep = {k: v for k, v in layers.items() if not k.startswith(LEAD)}
-    attn = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
+    attn = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm",
+            *_SAMBAY_ATTN)
+    if "gmu_in" in rep:
+        return {
+            "M": {k: v for k, v in rep.items() if k in mamba.LEAVES1},
+            "*": {k: v for k, v in rep.items() if k in attn},
+            "X": {k[2:]: v for k, v in rep.items() if k.startswith("x_")},
+            "G": {k: v for k, v in rep.items() if k.startswith("gmu_")},
+            "-": {k: v for k, v in rep.items() if k in _SAMBAY_FFN},
+        }
     return {
         "lead": {k[len(LEAD):]: v for k, v in layers.items()
                  if k.startswith(LEAD)},
@@ -83,6 +94,14 @@ def layer_stacks(layers: Params) -> dict[str, Params]:
         "E": {k: v for k, v in rep.items()
               if k not in mamba.LEAVES and k not in attn},
     }
+
+
+#: what SambaY's layout adds to an attention layer's leaves (a norm's bias,
+#: the projections' biases, differential attention's four vectors and its
+#: norm over a pair's width), and its FFN stack's leaves
+_SAMBAY_ATTN = ("attn_norm_b", "bq", "bk", "bv", "bo", "diff_lq1", "diff_lk1",
+                "diff_lq2", "diff_lk2", "diff_norm")
+_SAMBAY_FFN = ("mlp_norm", "mlp_norm_b", "w_gate", "w_up", "w_down")
 
 
 def _layer_axes(cfg: ModelConfig, routed: bool) -> Params:
@@ -146,6 +165,13 @@ def _hybrid_axes(cfg: ModelConfig) -> Params:
 
 def param_logical_axes(cfg: ModelConfig) -> Params:
     """Logical axis names per parameter leaf (for mesh sharding rules)."""
+    if cfg.decoder_layout:
+        # No leaf is divided: the engine refuses a mesh for recurrent state.
+        shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+        return jax.tree.map(
+            lambda a: (("layers",) if a.ndim else ()) + (None,) * (a.ndim - 1),
+            shapes) | {"embed": ("vocab", None), "final_norm": (None,),
+                       "final_norm_b": (None,)}
     layer = (_hybrid_axes(cfg) if cfg.hybrid_pattern
              else _layer_axes(cfg, cfg.is_moe))
     if cfg.ssm_beside_attention:
@@ -224,6 +250,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     if cfg.hybrid_pattern:
         return _init_hybrid(cfg, k, dense, attention)
+    if cfg.decoder_layout:
+        return _init_sambay(cfg, k, dense, attention, dense_ffn)
     layer: Params = attention(L)
     if cfg.is_moe:
         E, m = cfg.num_experts, cfg.moe_intermediate_size
@@ -316,6 +344,63 @@ def _init_hybrid(cfg: ModelConfig, k, dense, attention) -> Params:
     return params
 
 
+def _init_sambay(cfg: ModelConfig, k, dense, attention, dense_ffn) -> Params:
+    """:func:`init_params` for SambaY's layout (``cfg.decoder_layout``): a
+    stack a kind of mixer and one of all the layers' FFNs (``k``, ``dense``,
+    ``attention``, ``dense_ffn``: the keys' iterator and the draws of
+    :func:`init_params`). Norm weights are drawn around 1 and every bias,
+    lambda vector and ``D`` around its published start with a spread, not at
+    it: a leaf at exactly 0 or 1 is one a comparison with the reference
+    cannot see (tests/test_phi4_flash.py leaves each out in turn)."""
+    from dynamo_tpu.models import mamba
+
+    dt = _dtype(cfg)
+    h, d, hd = cfg.hidden_size, cfg.ssm_inner, cfg.head_dim
+    keys = iter(jax.random.split(next(k), 64))
+
+    def around(mean, shape, spread, dtype=dt):
+        return (mean + spread * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    def norm(name, n):
+        return {name: around(1.0, (n, h), 0.1),
+                name + "_b": around(0.0, (n, h), 0.1)}
+
+    def diff(n):
+        # (the published start of the four vectors is N(0, 0.1))
+        return {**{f"diff_{v}": around(0.0, (n, hd), 0.1, jnp.float32)
+                   for v in ("lq1", "lk1", "lq2", "lk2")},
+                "diff_norm": around(1.0, (n, 2 * hd), 0.1)}
+
+    A, X, G = cfg.attn_layers, cfg.layers_of("X"), cfg.layers_of("G")
+    layer: Params = attention(A)
+    del layer["mlp_norm"]
+    layer.update(norm("attn_norm", A), **diff(A), **{
+        b: around(0.0, (A, w), 0.1) for b, w in (
+            ("bq", cfg.q_size), ("bk", cfg.kv_size), ("bv", cfg.kv_size),
+            ("bo", h))})
+    layer.update(
+        x_wq=dense(next(k), (X, h, cfg.q_size), h),
+        x_wo=dense(next(k), (X, cfg.q_size, h), cfg.q_size),
+        x_bq=around(0.0, (X, cfg.q_size), 0.1),
+        x_bo=around(0.0, (X, h), 0.1),
+        **{"x_" + name: v for name, v in {**norm("attn_norm", X),
+                                          **diff(X)}.items()})
+    layer.update(norm("gmu_norm", G),
+                 gmu_in=dense(next(k), (G, h, d), h),
+                 gmu_out=dense(next(k), (G, d, h), d))
+    layer.update(mamba.init_layers1(cfg, dense, next(k), cfg.layers_of("S")),
+                 **norm("ssm_norm", cfg.layers_of("S")))
+    layer.update(dense_ffn(cfg.num_layers), **norm("mlp_norm", cfg.num_layers))
+    final = norm("final_norm", 1)
+    return {
+        "embed": dense(next(k), (cfg.vocab_size, h), h),
+        "final_norm": final["final_norm"][0],
+        "final_norm_b": final["final_norm_b"][0],
+        "layers": layer,
+    }
+
+
 # ---------------------------------------------------------------------------
 # Building blocks
 # ---------------------------------------------------------------------------
@@ -324,6 +409,21 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     x32 = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * scale).astype(x.dtype) * w
+
+
+def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale).astype(x.dtype) * w + b
+
+
+def norm_of(cfg: ModelConfig, x: jax.Array, lp: Params, name: str) -> jax.Array:
+    """The model's norm of ``x`` under the leaf ``name`` of ``lp``: RMSNorm,
+    or LayerNorm with the bias ``<name>_b`` (``cfg.norm_kind``)."""
+    if cfg.norm_kind == "layer":
+        return layer_norm(x, lp[name], lp[name + "_b"], cfg.rms_norm_eps)
+    return rms_norm(x, lp[name], cfg.rms_norm_eps)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -476,6 +576,7 @@ def paged_attention(
     q_positions: jax.Array,  # [B, T]
     kv_lens: jax.Array,      # [B] total valid context length
     window: int = 0,         # static; > 0: query i sees keys j, i - j < window
+    scale: float | None = None,  # on q; None: head size ** -0.5
 ) -> jax.Array:
     """Dense attention over gathered paged context with causal position mask.
 
@@ -486,7 +587,7 @@ def paged_attention(
     s = ctx_k.shape[1]
     kh = ctx_k.shape[2]
     rep = h // kh
-    qf = q.astype(jnp.float32) * (d**-0.5)
+    qf = q.astype(jnp.float32) * (d**-0.5 if scale is None else scale)
     qf = qf.reshape(b, t, kh, rep, d)
     scores = jnp.einsum("btkrd,bskd->btkrs", qf, ctx_k.astype(jnp.float32))
     ctx_idx = jnp.arange(s)[None, None, :]                      # [1,1,S]
@@ -627,7 +728,8 @@ def token_layout(q_len: jax.Array, b: int, t: int, n: int) -> tuple[
 def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
                lay: TokenLayout, positions, slot, block_tables, q_start,
                kv_lens, attn_impl: str = "dense", mesh=None,
-               use_ring: bool = False, window: int = 0):
+               use_ring: bool = False, window: int = 0, cross: bool = False,
+               lambda_init=None):
     """The attention mixer on the normed state ``x [N, H]``: Q/K/V, this
     step's K/V written at ``(layer, slot)`` of the WHOLE cache
     ([L,NB,BS,KH,D], or the stage-local part of it under pp), attention
@@ -645,9 +747,24 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     as rows too, and only a rectangle (``N == B*T``), where those moves are
     reshapes. ``window`` is static (> 0: a sliding layer, query i sees the
     keys j with i - j < window), and with it, by the configuration, whether
-    the layer carries positions at all (``rope_scope``)."""
+    the layer carries positions at all (``rope_scope``).
+
+    ``cross``: the mixer has a query projection alone and writes nothing:
+    it attends over layer ``layer`` as another mixer of the same step left
+    it (SambaY's cross-decoder). Under ``cfg.diff_attention`` the kernel and
+    the cache see the KV heads in whole pairs side by side, ``[k1 | k2 |
+    k3 | k4 ...]`` one cache head (``cfg.cache_kv_heads`` of them: the same
+    bytes), V likewise, and a Q head as its own 64 in its key head's place
+    among zeros, so that a head's scores are its own key head's and it
+    reads its cache head's value heads (:func:`_pair_queries`); its pair's
+    two value heads cut from that, the two softmaxes' difference, the norm
+    over a pair's width and ``1 - lambda_init`` (a traced scalar, the
+    layer's) are :func:`_diff_combine` on what comes back."""
     n = x.shape[0]
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
+    # (the cache's view of the heads: the model's, or pairs of them)
+    kvh, hd = cfg.cache_kv_heads, cfg.cache_head_dim
+    scale = {"scale": cfg.head_dim ** -0.5} if cfg.diff_attention else {}
     # The three products stay [N, out] up to the barrier and get their head
     # axis after it. A reshape XLA can fold into the dot makes the weight
     # operand [heads, D, H], the stored matrix transposed, and the compiler
@@ -657,13 +774,24 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     if cfg.attention_in_multiplier != 1.0:
         x = x * cfg.attention_in_multiplier
     with _perf_phase("proj"):
-        q, k, v = jax.lax.optimization_barrier(
-            (mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])))
+        if cross:
+            q = jax.lax.optimization_barrier(mm(x, lp["wq"]))
+            k = v = None
+        else:
+            q, k, v = jax.lax.optimization_barrier(
+                (mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])))
+        if cfg.attention_bias:
+            q = q + lp["bq"]
+            if not cross:
+                k, v = k + lp["bk"], v + lp["bv"]
     if cfg.key_multiplier != 1.0:
         k = k * cfg.key_multiplier
     q = q.reshape(n, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(n, cfg.num_kv_heads, cfg.head_dim)
-    v = v.reshape(n, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.diff_attention:
+        q = _pair_queries(q, cfg.num_kv_heads, kvh)
+    if not cross:
+        k = k.reshape(n, kvh, hd)
+        v = v.reshape(n, kvh, hd)
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
@@ -673,13 +801,22 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     # Phase hooks (obs/profiler.py): jax.named_scope annotations for
     # XLA profiles, plus wall capture in eager profiling runs. Under
     # jit they execute at trace time only — zero ops in the program.
-    with _perf_phase("scatter"):
-        cache_k = _scatter_kv(cache_k, k, slot, layer)
-        cache_v = _scatter_kv(cache_v, v, slot, layer)
+    if not cross:
+        with _perf_phase("scatter"):
+            cache_k = _scatter_kv(cache_k, k, slot, layer)
+            cache_v = _scatter_kv(cache_v, v, slot, layer)
 
     def out_proj(attn):
+        # attn [N, heads, D] as attention leaves it
+        if cfg.diff_attention:
+            with _perf_phase("attn_diff"):
+                attn = _diff_combine(cfg, lp, attn, lambda_init)
+        else:
+            attn = attn.reshape(n, cfg.q_size)
         with _perf_phase("proj"):
             out = mm(attn, lp["wo"])
+            if cfg.attention_bias:
+                out = out + lp["bo"]
         if cfg.attention_out_multiplier != 1.0:
             out = out * cfg.attention_out_multiplier
         return out
@@ -697,7 +834,7 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
             paged_attention_kernel if tp == 1
             else partial(paged_attention_sharded, mesh),
             layer=layer, interpret=attn_impl == "pallas_interpret",
-            window=window)
+            window=window, **scale)
     if kernel and lay.starts is not None and (
             mesh is None or mesh.shape.get("data", 1) == 1):
         # A packed step (N < B*T) under the kernel: q goes in token-major
@@ -707,13 +844,13 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
         with _perf_phase("attention"):
             attn = attend(q, cache_k, cache_v, block_tables, q_start,
                           kv_lens, starts=lay.starts, t=lay.t)
-        return out_proj(attn.reshape(n, cfg.q_size)), cache_k, cache_v
+        return out_proj(attn), cache_k, cache_v
     # The rows are gathered from q's grouped view [N, KH, REP, D], the split
     # the kernel's wrapper makes of them anyway: from [N, heads, D] the
     # compiler moves a chunk step's [B, T] rectangle twice on its way to
     # the kernel's [B, KH, T*REP, D] (PERF.md section 6, PR 40).
-    q = lay.to_rows(q.reshape(n, cfg.num_kv_heads, -1, cfg.head_dim))
-    q = q.reshape(lay.b, lay.t, cfg.num_heads, cfg.head_dim)  # [B,T,heads,D]
+    q = lay.to_rows(q.reshape(n, kvh, -1, hd))
+    q = q.reshape(lay.b, lay.t, cfg.num_heads, hd)            # [B,T,heads,D]
     if use_ring:
         from dynamo_tpu.ops.ring_attention import ring_attention_prefill
 
@@ -734,9 +871,53 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
             attn = paged_attention(
                 q, ctx_k, ctx_v,
                 q_start[:, None] + jnp.arange(lay.t)[None, :], kv_lens,
-                window=window)
-    attn = lay.to_tokens(attn).reshape(n, cfg.q_size)
-    return out_proj(attn), cache_k, cache_v
+                window=window, **scale)
+    return out_proj(lay.to_tokens(attn)), cache_k, cache_v
+
+
+def _pair_queries(q: jax.Array, kv_heads: int, cache_heads: int) -> jax.Array:
+    """Differential attention's queries ``[N, heads, D]`` as the cache's
+    view takes them, ``[N, heads, W]``, ``W`` the ``kv_heads / cache_heads``
+    key heads of ``D`` that lie side by side in one cache head: a query in
+    the place of its key head among them and zeros elsewhere. Pairs are
+    adjacent heads (Q heads ``2p`` and ``2p + 1``, KV heads ``2j`` and ``2j
+    + 1``, Q pair ``p`` reading KV pair ``p // (Q heads a KV head)``), so Q
+    head ``i`` scores against KV head ``2 (i // 2 // per) + i % 2``. A
+    head's scores are then its own key head's, to the bit (the zeros add
+    nothing), and what it reads is its cache head's value heads."""
+    n, h, d = q.shape
+    per = h // kv_heads                       # Q heads a KV head
+    side = kv_heads // cache_heads            # key heads in a cache head
+    i = jnp.arange(h)
+    key = 2 * (i // 2 // per) + i % 2         # each Q head's key head
+    at = jax.nn.one_hot(key % side, side, dtype=q.dtype)           # [h, side]
+    return (at[None, :, :, None] * q[:, :, None, :]).reshape(n, h, side * d)
+
+
+def _diff_combine(cfg: ModelConfig, lp: Params, attn: jax.Array,
+                  lambda_init) -> jax.Array:
+    """``attn [N, heads, W]``, each head's softmax read against its cache
+    head's value heads side by side, to differential attention's output
+    ``[N, heads x D]``: of each a pair's ``[v1 | v2]`` (``2 D`` of the
+    ``W``), then ``RMSNorm(A1 - lambda A2; w) (1 - lambda_init)`` a pair,
+    ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, float32."""
+    n, h, w = attn.shape
+    d2 = 2 * cfg.head_dim
+    lam = (jnp.exp(jnp.sum(lp["diff_lq1"] * lp["diff_lk1"]))
+           - jnp.exp(jnp.sum(lp["diff_lq2"] * lp["diff_lk2"])) + lambda_init)
+    a = attn.astype(jnp.float32).reshape(n, h // 2, 2, w)
+    if w != d2:
+        # Q pair p's value pair, p // (Q heads a KV head), among the w / d2
+        # that its cache head holds
+        pair = (jnp.arange(h // 2) // (h // cfg.num_kv_heads)) % (w // d2)
+        a = jnp.take_along_axis(
+            a.reshape(n, h // 2, 2, w // d2, d2),
+            pair[None, :, None, None, None], axis=3)[:, :, :, 0]
+    x = a[:, :, 0] - lam * a[:, :, 1]
+    x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                      + cfg.rms_norm_eps)
+    x = x * lp["diff_norm"].astype(jnp.float32) * (1.0 - lambda_init)
+    return x.astype(attn.dtype).reshape(n, h // 2 * d2)
 
 
 def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
@@ -774,20 +955,34 @@ def _ffn(cfg: ModelConfig, lp: Params, x, routing, moe_impl: str, mesh,
 
 #: the norm a mixer reads, by its kind (a joined mixer has none of its own:
 #: it reads what the mixer before it read)
-_NORM = {"*": "attn_norm", "-": "mlp_norm", "E": "mlp_norm", "M": "ssm_norm"}
+_NORM = {"*": "attn_norm", "-": "mlp_norm", "E": "mlp_norm", "M": "ssm_norm",
+         "S": "ssm_norm", "X": "attn_norm", "G": "gmu_norm"}
 
 
 def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                 cache_k, cache_v, ssm=None, *, lay: TokenLayout, q_start,
                 q_len=None, live=None, ssm_slots=None,
                 attn_impl: str = "dense", moe_impl: str = "dense", mesh=None,
-                use_ring: bool = False, **attn):
+                use_ring: bool = False, narrow: bool = False, **attn):
     """Run the layers of ``plan`` (``cfg.layer_plan``, or a pipeline
     stage's part of it) over ``layers``, their stacked params: the one
-    runner of ``forward`` and of both pp schedules. The plan's leading
-    layers are traced one by one, one period is the body of a ``lax.scan``
-    over its trips, what is left behind the last whole period is traced one
-    by one. Returns (hidden, cache_k, cache_v, ssm, counts).
+    runner of ``forward`` and of both pp schedules. The plan's pieces in
+    order (``plan.spans``): layers traced one by one, and a run's period
+    as the body of a ``lax.scan`` over its trips. Returns (hidden, cache_k,
+    cache_v, ssm, counts).
+
+    SambaY's layout adds three kinds and two things to carry. A Mamba-1
+    mixer ("S", models/mamba.py ``mixer1``) that ``keeps`` leaves its scan
+    output, before the gate, as the step's memory ``mem [N, d]``, carried
+    beside the hidden state and never stored; a memory unit ("G") gates it,
+    ``(mem * silu(x W_1)) W_2``; a cross mixer ("X") attends with a query
+    projection alone over the layer of the cache that an attention mixer
+    earlier in the same step wrote (:func:`_attention`, ``cross``). With
+    ``narrow`` the layers from ``plan.last_from`` on, which only the tokens
+    whose logits are taken need, run over each row's last live token: the
+    hidden state and the memory are gathered there ``[B, ...]``, the rows'
+    arrays become those of a step of one query a row at that token's
+    position, and the hidden state comes back ``[B, H]``.
 
     A layer is its mixers in order, each ``h + mixer(norm(h))`` (or, under
     ``cfg.norm_placement == "post"``, ``h + norm(mixer(h))``) under the
@@ -857,12 +1052,13 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
 
     stacks = layer_stacks(layers)
     of_kind = {m.kind: m.stack for mixers in plan.layers for m in mixers}
-    # (a part of the carry that is None is no operand of the loop)
+    # (a part of the carry that is None is no operand of the loop; the last
+    # is the step's memory, which the mixer that keeps it sets)
     carry = (h, *((cache_k, cache_v) if "*" in of_kind else (None, None)),
-             ssm, None)
+             ssm, None, None)
     experts = {}
     if moe_impl == "held" and "E" in of_kind:
-        carry = (*carry[:4], jnp.zeros((3,), jnp.int32))
+        carry = (*carry[:4], jnp.zeros((3,), jnp.int32), None)
         routed = stacks[of_kind["E"]]
         experts = {k: routed.pop(k) for k in ("w_gate", "w_up", "w_down")
                    if k in routed}
@@ -871,14 +1067,16 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
     # body takes as operands (the layout's two integers are static).
     rows, env = lay[:2], (stacks, experts, lay[2:], q_start, q_len, live,
                           ssm_slots, attn)
+    lambdas = {kind: jnp.asarray(cfg.lambda_init(kind)) for kind in "*X"
+               if cfg.diff_attention and kind in of_kind}
 
-    def one(carry, mixers, place, env, lp=None):
+    def one(rows, carry, mixers, place, env, lp=None):
         """One layer on ``carry``. ``place``: each stack's place here (its
         mixers' own outside the scan, the trip's inside it; an int32 operand
-        of a shared body); ``env`` as above; ``lp`` the layer's params where
-        the scan hands them in. Of ``mixers`` it reads the description
-        alone (``body_of``)."""
-        hid, k, v, state, counts = carry
+        of a shared body); ``rows`` and ``env`` as above; ``lp`` the layer's
+        params where the scan hands them in. Of ``mixers`` it reads the
+        description alone (``body_of``)."""
+        hid, k, v, state, counts, mem = carry
         stacks, experts, lay, q_start, q_len, live, ssm_slots, attn = env
         lay = TokenLayout(*rows, *lay)
         if lp is None:
@@ -895,8 +1093,26 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                 i = place[m.stack]
                 if not m.joined:
                     norm = lp[_NORM[m.kind]]
-                    x = hid if post else rms_norm(hid, norm, cfg.rms_norm_eps)
-                if m.kind == "*":
+                    x = hid if post else norm_of(cfg, hid, lp, _NORM[m.kind])
+                diff = ({"lambda_init": lambdas[m.kind][i]}
+                        if m.kind in lambdas else {})
+                if m.kind == "X":
+                    out, _, _ = _attention(
+                        cfg, lp, m.layer, x, k, v, lay=lay, q_start=q_start,
+                        attn_impl=attn_impl, mesh=mesh, cross=True, **diff,
+                        **attn)
+                elif m.kind == "S":
+                    out, state, y = mamba.mixer1(
+                        cfg, lp, i, x, state, lay=lay, slots=ssm_slots,
+                        q_start=q_start, q_len=q_len, live=live,
+                        impl={"dense": "jnp"}.get(attn_impl, attn_impl))
+                    if m.keeps:
+                        mem = y
+                elif m.kind == "G":
+                    with _perf_phase("gmu"):
+                        out = mm(mem * jax.nn.silu(mm(x, lp["gmu_in"])),
+                                 lp["gmu_out"])
+                elif m.kind == "*":
                     if cfg.router_input == "attn_norm" and any(
                             o.kind == "E" for o in mixers):
                         with _perf_phase("moe_route"):
@@ -906,7 +1122,7 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                         cfg, lp, (m.layer - m.place) + i
                         if m.layer != m.place else i, x, k, v, lay=lay,
                         q_start=q_start, attn_impl=attn_impl, mesh=mesh,
-                        use_ring=use_ring, window=m.window, **attn)
+                        use_ring=use_ring, window=m.window, **diff, **attn)
                 elif m.kind == "M":
                     out, state = mamba.mixer(
                         cfg, lp, i, x, state, lay=lay, slots=ssm_slots,
@@ -926,7 +1142,7 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                 if post:
                     out = rms_norm(out, norm, cfg.rms_norm_eps)
                 hid = hid + out
-        return hid, k, v, state, counts
+        return hid, k, v, state, counts, mem
 
     bodies = plan.bodies
     shared = {}         # a repeated description -> its one jitted body
@@ -941,43 +1157,67 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                 place[m.stack] = at(m)
         body = body_of(mixers)
         if bodies.count(body) < 2:
-            return one(carry, mixers, place, env)
-        if body not in shared:
-            def layer(carry, place, env):
-                return one(carry, body, place, env)
+            return one(rows, carry, mixers, place, env)
+        if (body, rows) not in shared:
+            def layer(carry, place, env, rows=rows):
+                return one(rows, carry, body, place, env)
 
-            shared[body] = jax.jit(layer, inline=True)
-        return shared[body](
+            shared[body, rows] = jax.jit(layer, inline=True)
+        return shared[body, rows](
             carry, {s: jnp.asarray(i, jnp.int32) for s, i in place.items()},
             env)
 
-    for mixers in plan.layers[:plan.lead]:
-        carry = run(carry, mixers)
-    if plan.trips:
-        period = plan.layers[plan.lead:plan.lead + plan.period]
-        index = jnp.arange(plan.trips, dtype=jnp.int32)
+    def scanned(carry, period, trips):
+        index = jnp.arange(trips, dtype=jnp.int32)
         on_xs = stacks[period[0][0].stack]
-        if plan.period == 1 and plan.trips == len(jax.tree.leaves(on_xs)[0]):
+        if len(period) == 1 and trips == len(jax.tree.leaves(on_xs)[0]):
             def layer_fn(carry, xs):
                 lp, i = xs
-                return one(carry, period[0],
+                return one(rows, carry, period[0],
                            {m.stack: i for m in period[0]}, env, lp), None
 
-            carry, _ = lax.scan(layer_fn, carry, (on_xs, index))
-        else:
-            def period_fn(carry, trip):
-                for mixers in period:
-                    # (a stack's places step from trip to trip by the
-                    # period's layers that read it)
-                    carry = run(carry, mixers, lambda m: trip * sum(
-                        any(o.stack == m.stack for o in layer)
-                        for layer in period) + m.place)
-                return carry, None
+            return lax.scan(layer_fn, carry, (on_xs, index))[0]
 
-            carry, _ = lax.scan(period_fn, carry, index)
-    for mixers in plan.layers[len(plan.layers) - plan.rest:]:
-        carry = run(carry, mixers)
-    h, k, v, ssm, counts = carry
+        def period_fn(carry, trip):
+            for mixers in period:
+                # (a stack's places step from trip to trip by the
+                # period's layers that read it)
+                carry = run(carry, mixers, lambda m: trip * sum(
+                    any(o.stack == m.stack for o in layer)
+                    for layer in period) + m.place)
+            return carry, None
+
+        return lax.scan(period_fn, carry, index)[0]
+
+    def to_last_tokens(carry):
+        """The rows' arrays and the carry for the layers that run over each
+        row's last live token alone: one query a row at that token's
+        position (a decode program's rows are that already)."""
+        nonlocal lay, q_start, q_len, live, attn, rows, env
+        if lay.t == 1:
+            return carry
+        hid, *held, mem = carry
+        with _perf_phase("layout"):
+            hid, mem = [_last_hidden(a, lay, q_len) for a in (hid, mem)]
+            q_start = jnp.maximum(q_start + q_len - 1, 0)
+            live, q_len = q_len > 0, jnp.minimum(q_len, 1)
+            lay = TokenLayout(lay.b, 1)
+            attn = {**attn, "positions": q_start,
+                    "slot": jnp.zeros_like(q_start)}
+        rows, env = lay[:2], (stacks, experts, lay[2:], q_start, q_len, live,
+                              ssm_slots, attn)
+        return hid, *held, mem
+
+    for first, count, trips in plan.spans:
+        if narrow and first == plan.last_from:
+            carry = to_last_tokens(carry)
+        pieces = plan.layers[first:first + count]
+        if trips:
+            carry = scanned(carry, pieces, trips)
+        else:
+            for mixers in pieces:
+                carry = run(carry, mixers)
+    h, k, v, ssm, counts, _mem = carry
     return (h, *((k, v) if "*" in of_kind else (cache_k, cache_v)), ssm,
             counts)
 
@@ -1097,17 +1337,25 @@ def forward(
         if cfg.embedding_multiplier != 1.0:
             h = h * cfg.embedding_multiplier
 
+    # A plan whose last layers only the tokens whose logits are taken need
+    # (SambaY's cross-decoder) runs them over each row's last token, and the
+    # hidden state comes back [B, H]; a caller that wants every position
+    # gets every layer over every position.
+    plan = cfg.layer_plan
+    narrow = plan.last_from is not None and not return_all_hidden
     h, cache_k, cache_v, ssm, counts = _run_layers(
-        cfg, cfg.layer_plan, params["layers"], h, cache_k, cache_v, ssm,
+        cfg, plan, params["layers"], h, cache_k, cache_v, ssm,
         lay=lay, positions=positions, slot=slot, block_tables=block_tables,
         q_start=q_start, q_len=q_len, kv_lens=kv_lens, live=valid,
         ssm_slots=ssm_slots, attn_impl=attn_impl, moe_impl=moe_impl,
-        mesh=mesh, use_ring=use_ring)
+        mesh=mesh, use_ring=use_ring, narrow=narrow)
     # The head's own preparation: the final norm and each row's last token.
     with _perf_phase("logits"):
-        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        last = (lay.to_rows(h) if return_all_hidden                # [B, T, H]
-                else _last_hidden(h, lay, q_len))
+        h = norm_of(cfg, h, params, "final_norm")
+        if return_all_hidden:
+            last = lay.to_rows(h)                                  # [B, T, H]
+        else:       # (narrowed rows of several tokens are [B, H] already)
+            last = h if narrow and t > 1 else _last_hidden(h, lay, q_len)
     out = (last, cache_k, cache_v) + ((ssm,) if cfg.has_ssm else ())
     return (*out, counts) if moe_counts else out
 

@@ -51,16 +51,25 @@ from dynamo_tpu.obs.profiler import phase
 #: the leaves of a Mamba layer under ``params["layers"]``, stacked ``[M, ...]``
 LEAVES = ("ssm_norm", "ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias",
           "ssm_A_log", "ssm_D", "ssm_gate_norm", "ssm_out")
+#: a Mamba-1 layer's (:func:`mixer1`): no norm on the gated output; a
+#: LayerNorm's bias, the projection to delta | B | C and delta's to dt
+LEAVES1 = ("ssm_norm", "ssm_norm_b", "ssm_in", "ssm_conv_w", "ssm_conv_b",
+           "ssm_x", "ssm_dt", "ssm_dt_bias", "ssm_A_log", "ssm_D", "ssm_out")
 
 
 def state_shapes(cfg: ModelConfig, slots: int) -> dict[str, jax.ShapeDtypeStruct]:
     """Shape and type of the state pool's two leaves for ``slots``
     sequences (and a trash row), with nothing allocated."""
-    m = cfg.layers_of("M")
+    m = cfg.layers_of("M") + cfg.layers_of("S")
+    if cfg.mamba_inner:     # Mamba-1: [N, d] a slot-layer, d on the lanes
+        from dynamo_tpu.ops.selective_scan import state_shape
+
+        own = state_shape(cfg.ssm_state_size, cfg.mamba_inner)
+    else:
+        own = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
     return {
         "state": jax.ShapeDtypeStruct(
-            (m, slots + 1, cfg.mamba_num_heads, cfg.mamba_head_dim,
-             cfg.ssm_state_size), jnp.dtype(cfg.ssm_state_dtype)),
+            (m, slots + 1, *own), jnp.dtype(cfg.ssm_state_dtype)),
         # a row's K - 1 last inputs, [K - 1, C] flattened: with K - 1 = 3
         # as a dimension of its own the chip keeps the pool in another
         # order than the program and converts all of it, both ways, a step
@@ -118,6 +127,37 @@ def init_layers(cfg: ModelConfig, dense, key: jax.Array, m: int,
         "ssm_D": jnp.ones((m, heads), jnp.float32),
         "ssm_gate_norm": jnp.ones((m, d), dt),
         "ssm_out": dense(next(k), (m, d, h), d, leaf="ssm_out"),
+    }
+
+
+def init_layers1(cfg: ModelConfig, dense, key: jax.Array, m: int) -> dict:
+    """The ``[M, ...]`` stacks of ``m`` Mamba-1 layers, without their norm
+    (llama.py ``_init_sambay`` draws the norms). The matrices as every other
+    matrix; ``dt_bias`` the inverse softplus of a dt drawn log-uniform in
+    [time_step_min, time_step_max], floored; ``A[j, c] = j + 1`` for every
+    channel (S4D-real, the published start; ``A_log`` is ``[N, d]``, the
+    channels last); ``D`` and the convolution's bias drawn around 1 and 0
+    with a spread (a leaf at exactly its start is one a comparison cannot
+    see)."""
+    h, d, ns, r = (cfg.hidden_size, cfg.ssm_inner, cfg.ssm_state_size,
+                   cfg.mamba_dt_rank)
+    kk, dt = cfg.conv_kernel, jnp.dtype(cfg.dtype)
+    k = iter(jax.random.split(key, 8))
+    lo, hi = jnp.log(cfg.time_step_min), jnp.log(cfg.time_step_max)
+    step = jnp.maximum(jnp.exp(
+        jax.random.uniform(next(k), (m, d), jnp.float32) * (hi - lo) + lo),
+        cfg.time_step_floor)
+    return {
+        "ssm_in": dense(next(k), (m, h, 2 * d), h),
+        "ssm_conv_w": dense(next(k), (m, kk, d), kk),
+        "ssm_conv_b": (0.1 * jax.random.normal(next(k), (m, d))).astype(dt),
+        "ssm_x": dense(next(k), (m, d, r + 2 * ns), d),
+        "ssm_dt": dense(next(k), (m, r, d), r),
+        "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "ssm_A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+            1, ns + 1, dtype=jnp.float32))[None, :, None], (m, ns, d)) + 0.0,
+        "ssm_D": 1.0 + 0.1 * jax.random.normal(next(k), (m, d), jnp.float32),
+        "ssm_out": dense(next(k), (m, d, h), d),
     }
 
 
@@ -354,3 +394,51 @@ def mixer(cfg: ModelConfig, lp, layer, u, ssm, *, lay, slots, q_start, q_len,
     if cfg.ssm_out_multiplier != 1.0:
         out = out * cfg.ssm_out_multiplier
     return out, {"state": state, "conv": conv}
+
+
+def mixer1(cfg: ModelConfig, lp, layer, u, ssm, *, lay, slots, q_start, q_len,
+           live, impl: str = "jnp"):
+    """One Mamba-1 mixer over a step's tokens (``transformers``'
+    ``modeling_mamba.py``; d the inner width, N the state size, R dt's rank):
+
+        [x | z] = u W_in;  x <- silu(conv_causal_K(x) + b)
+        [delta | B | C] = x W_x;  dt = softplus(delta W_dt + b_dt)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D x_t
+        out = (y * silu(z)) W_out
+
+    with ``A = -exp(A_log)`` of ``[N, d]``: a decay a channel and a state
+    index, so none of :func:`mixer`'s forms computes it. The pool, the slots,
+    the convolution and its tail, the zero start at ``q_start`` 0 and the
+    trash row are :func:`mixer`'s; the recurrence is one kernel over every
+    row of the step, of one position or of a chunk's
+    (ops/selective_scan.py; ``impl`` "jnp": the same recurrence without it).
+    Arguments as :func:`mixer`'s. Returns (out [N, H], the pool, ``y [N, d]``
+    before the gate, which SambaY's memory units read)."""
+    from dynamo_tpu.ops.selective_scan import selective_scan
+
+    d, ns, r = cfg.ssm_inner, cfg.ssm_state_size, cfg.mamba_dt_rank
+    n, b = u.shape[0], lay.b
+    with phase("ssm_proj"):
+        xz = lax.optimization_barrier(u @ lp["ssm_in"])           # [N, 2 d]
+        x, z = xz[:, :d], xz[:, d:]
+    tok_row, tok_off, starts = _token_rows(lay, n)
+    fresh = q_start == 0
+    with phase("ssm_conv"):
+        tail = jnp.where(fresh[:, None], 0, ssm["conv"][layer, slots])
+        x, tail = _conv(cfg, lp, x, tail.reshape(b, -1, d), tok_row, tok_off,
+                        starts, q_len)
+        conv = ssm["conv"].at[layer, slots].set(tail.reshape(b, -1))
+    with phase("ssm_proj"):
+        dbc = x @ lp["ssm_x"]                                     # [N, R + 2 N]
+        dt = jax.nn.softplus((dbc[:, :r] @ lp["ssm_dt"]).astype(jnp.float32)
+                             + lp["ssm_dt_bias"])                 # [N, d]
+    with phase("ssm_scan"):
+        state, y = selective_scan(
+            ssm["state"], jnp.asarray(layer, jnp.int32), slots, starts, q_len,
+            fresh, x, dt, dbc[:, r:r + ns], dbc[:, r + ns:], lp["ssm_A_log"],
+            lp["ssm_D"], t=lay.t, impl=impl, out_dtype=u.dtype)
+        gated = (y.astype(jnp.float32)
+                 * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype)
+    with phase("ssm_proj"):
+        out = gated @ lp["ssm_out"]
+    return out, {"state": state, "conv": conv}, y

@@ -362,14 +362,34 @@ def step_shapes(cfg: ModelConfig, *, block_size: int,
     # ``step_work`` leaves them unpriced.)
     from dynamo_tpu.models.mamba import slot_layer_bytes
 
+    #
+    # A plan whose last layers run for the rows' last tokens alone
+    # (``LayerPlan.last_from``: SambaY's cross-decoder) has those layers'
+    # matrices where the pricing has that form already, beside the head's
+    # in ``head_params``: read once a program, computed for ``logit_rows``.
+    # The fixed terms are then the layers before them, which every live
+    # token runs, and no share of a peak prices a prompt's tokens for
+    # layers they skip (chipbench/layers/step_work_counts.py).
     mats = 3 if cfg.expert_gated else 2
-    ssm_layers, ffn_layers = cfg.layers_of("M"), cfg.layers_of("-")
-    ssm_params = h * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.mamba_num_heads) \
-        + cfg.ssm_inner * h if ssm_layers else 0
+    d, every = cfg.ssm_inner, cfg.layer_plan.last_from
+    of = lambda kind, layers: sum(
+        m.kind == kind for layer in layers for m in layer)
+    body = cfg.layer_plan.layers[:every]
+    tail = cfg.layer_plan.layers[len(body):]
+    ssm_layers, ffn_layers = of("M", body) + of("S", body), of("-", body)
+    if cfg.mamba_inner:     # Mamba-1: W_in, W_x, W_dt, W_out
+        ssm_params = (h * 2 * d + d * (cfg.mamba_dt_rank
+                                       + 2 * cfg.ssm_state_size)
+                      + cfg.mamba_dt_rank * d + d * h)
+    else:
+        ssm_params = h * (d + cfg.ssm_conv_dim + cfg.mamba_num_heads) \
+            + d * h if ssm_layers else 0
     ffn_params = 3 * h * cfg.intermediate_size if ffn_layers else 0
     fixed_layers = max(ssm_layers, ffn_layers)
     fixed_params = (ssm_layers * ssm_params + ffn_layers * ffn_params
                     ) // max(fixed_layers, 1)
+    last_params = (of("G", tail) * 2 * h * d + of("X", tail) * 2 * h * cfg.q_size
+                   + of("-", tail) * ffn_params)
     return {
         "layers": L, "routed_layers": routed,
         "dense_ffn_layers": fixed_layers,
@@ -385,7 +405,9 @@ def step_shapes(cfg: ModelConfig, *, block_size: int,
            if ssm_layers else {}),
         "experts_held": cfg.num_experts, "router_width": cfg.router_width,
         "experts_per_token": cfg.num_experts_per_tok,
-        "head_params": cfg.vocab_size * h,
+        "head_params": cfg.vocab_size * h + last_params,
+        **({"last_token_layers": len(tail), "last_token_params": last_params}
+           if tail else {}),
         "bytes_per_param": _weight_itemsize(quantization),
         "kv_block_bytes_per_layer": int(block),
         "block_size": block_size, "devices": devices,

@@ -89,6 +89,9 @@ DEVICE_PHASES = (
     "layout", "embed", "layer", "proj", "scatter", "gather", "attention",
     "mlp", "moe_route", "moe_experts", "moe_shared", "ssm_proj", "ssm_conv",
     "ssm_scan", "logits", "sampling",
+    # differential attention's combine on the kernel's output, and SambaY's
+    # gated memory unit (models/llama.py)
+    "attn_diff", "gmu",
 )
 
 _INSTRUCTION = re.compile(

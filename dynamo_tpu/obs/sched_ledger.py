@@ -287,6 +287,8 @@ class SchedLedger:
         self.rect_tokens_total = 0
         self.kv_blocks_live_total = 0
         self.kv_blocks_walked_total = 0
+        self.kv_blocks_walked_shared_total = 0
+        self.cross_tokens_total = 0
         # layer steps, rows, touched, largest, streamed layer steps
         self.moe_totals = [0, 0, 0, 0, 0]
         self.ssm_totals = [0] * len(SSM_COUNTS)
@@ -322,6 +324,8 @@ class SchedLedger:
             self.rect_tokens_total = 0
             self.kv_blocks_live_total = 0
             self.kv_blocks_walked_total = 0
+            self.kv_blocks_walked_shared_total = 0
+            self.cross_tokens_total = 0
             self.moe_totals = [0, 0, 0, 0, 0]
             self.ssm_totals = [0] * len(SSM_COUNTS)
             self.padding_flops_total = 0.0
@@ -383,6 +387,8 @@ class SchedLedger:
         rect_tokens: int = 0,
         kv_blocks_live: int = 0,
         kv_blocks_walked: int = 0,
+        kv_blocks_walked_shared: int = 0,
+        cross_tokens: int = 0,
         moe: tuple[int, int, int, int, int] | None = None,
         ssm: tuple[int, ...] | None = None,
         live_flops: float = 0.0,
@@ -460,6 +466,8 @@ class SchedLedger:
             self.rect_tokens_total += rect_tokens
             self.kv_blocks_live_total += kv_blocks_live
             self.kv_blocks_walked_total += kv_blocks_walked
+            self.kv_blocks_walked_shared_total += kv_blocks_walked_shared
+            self.cross_tokens_total += cross_tokens
             if moe:
                 self.moe_totals = [a + b for a, b in zip(self.moe_totals, moe)]
             if ssm:
@@ -514,6 +522,9 @@ class SchedLedger:
                 "rect_tokens_total": self.rect_tokens_total,
                 "kv_blocks_live_total": self.kv_blocks_live_total,
                 "kv_blocks_walked_total": self.kv_blocks_walked_total,
+                "kv_blocks_walked_shared_total":
+                    self.kv_blocks_walked_shared_total,
+                "cross_tokens_total": self.cross_tokens_total,
                 "moe_layer_steps_total": self.moe_totals[0],
                 "moe_rows_total": self.moe_totals[1],
                 "moe_experts_touched_total": self.moe_totals[2],
@@ -597,11 +608,12 @@ def get_sched_ledger() -> SchedLedger:
 #: ``ssm_layers``), in the order the ledger totals them.
 SSM_COUNTS = ("ssm_layer_steps", "ssm_live_tokens", "ssm_scanned_positions",
               "ssm_state_rows", "ssm_update_rows_given",
-              "ssm_update_rows_moved")
+              "ssm_update_rows_moved", "ssm_scan_rows", "ssm_scan_positions")
 
 
 def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
-                ssm_layers: int = 0, attn_tokens: bool = False) -> dict:
+                ssm_layers: int = 0, attn_tokens: bool = False,
+                cross_layers: int = 0, scan_layers: int = 0) -> dict:
     """What one step did, in the program's own terms and nothing priced:
     THE walk over a step's rows, made once between plan and record
     (EngineCore._record_step). The profiler prices it, the ledger's goodput
@@ -651,9 +663,21 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
       (the rows its grid has, a program's bucket ``sig.b``, times the layers)
       and ``ssm_update_rows_moved`` (the rows of one token among them, whose
       state it moves, times the layers; the others cost it no byte).
+      ``scan_layers`` of the ``ssm_layers`` are Mamba-1's, whose recurrence
+      is one kernel over every row (ops/selective_scan.py): they count no
+      ``ssm_update_rows_*`` and no blocked scan's ``t`` positions, and
+      instead ``ssm_scan_rows`` (the rows with live tokens, times those
+      layers) and ``ssm_scan_positions`` (the live tokens, times them): what
+      that kernel ran;
+    - for a model with ``cross_layers`` attention mixers that reread another
+      layer's keys and values for each row's last token alone (SambaY's
+      cross-decoder; 0 for every other model): ``cross_tokens``, the tokens
+      that entered those layers (one a row), and ``kv_blocks_walked_shared``,
+      their walks' blocks, a row's whole context a layer. Both are in
+      ``kv_blocks_walked``, and ``attn_q_ctx`` has their one query a row.
 
-    ``windows`` has the layers that have attention, and ``layers`` below
-    counts those: the KV cache's layers.
+    ``windows`` has the layers that have attention and write it, and
+    ``layers`` below counts those: the KV cache's layers.
     """
     bs = block_size
     layer_kinds = tuple(Counter(windows).items())   # a few kinds, many rows
@@ -662,6 +686,8 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
     programs = pf_rows = n_dec = pf_tokens = dec_tokens = 0
     live = logit_rows = sched = rect = sched_rows = 0
     blocks = walked = q_ctx = table_q = table_blocks = scanned = ones = 0
+    shared = 0
+    blocked = ssm_layers > scan_layers      # some layer scans in blocks
     dec_left = dec_rows
     for sig, rows, *_ in batches:
         if not rows:
@@ -702,9 +728,12 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
                 whole = max(0, min(end, w) - start)
                 q_ctx += count * (whole * start + whole * (whole + 1) // 2
                                   + (length - whole) * w)
+            if cross_layers:
+                shared += cross_layers * used
+                q_ctx += cross_layers * end
             if chunks and length > 1:
                 pf_tokens += length
-                scanned += sig.t
+                scanned += sig.t * blocked
             else:
                 dec_tokens += length
         sched += sig.n
@@ -719,16 +748,29 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
         "prefill_tokens": pf_tokens, "decode_tokens": dec_tokens,
         "live_tokens": live, "sched_tokens": sched, "rect_tokens": rect,
         "logit_rows": logit_rows, "sched_logit_rows": sched_rows,
-        "kv_blocks_live": blocks, "kv_blocks_walked": walked,
+        "kv_blocks_live": blocks, "kv_blocks_walked": walked + shared,
+        "kv_blocks_walked_shared": shared,
+        "cross_tokens": logit_rows if cross_layers else 0,
         "attn_q_ctx": q_ctx,
         "table_q_ctx": table_q, "table_blocks": table_blocks,
         "ssm_layer_steps": programs * ssm_layers,
         "ssm_live_tokens": live if ssm_layers else 0,
         "ssm_scanned_positions": scanned if ssm_layers else 0,
         "ssm_state_rows": logit_rows * ssm_layers,
-        "ssm_update_rows_given": sched_rows * ssm_layers,
-        "ssm_update_rows_moved": ones * ssm_layers,
+        "ssm_update_rows_given": sched_rows * (ssm_layers - scan_layers),
+        "ssm_update_rows_moved": ones * (ssm_layers - scan_layers),
+        "ssm_scan_rows": logit_rows * scan_layers,
+        "ssm_scan_positions": live * scan_layers,
     }
+
+
+def recurrent_and_cross(model_cfg) -> dict:
+    """What :func:`step_counts` takes of a model beside its windows: its
+    recurrent layers, those of them that are Mamba-1's, and its cross
+    layers."""
+    scan = model_cfg.layers_of("S")
+    return {"ssm_layers": model_cfg.layers_of("M") + scan,
+            "scan_layers": scan, "cross_layers": model_cfg.layers_of("X")}
 
 
 def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
@@ -759,8 +801,8 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
     if counts is None:
         counts = step_counts(
             batches, ec.block_size, model_cfg.attn_windows,
-            dec_rows=dec_rows, ssm_layers=model_cfg.layers_of("M"),
-            attn_tokens=attends_tokens(ec))
+            dec_rows=dec_rows, attn_tokens=attends_tokens(ec),
+            **recurrent_and_cross(model_cfg))
     if shapes is None:
         shapes = cm.step_shapes(
             model_cfg, block_size=ec.block_size,
@@ -781,7 +823,7 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
         **{k: counts[k] for k in (
             "kinds", "prefill_rows", "decode_rows", "live_tokens",
             "sched_tokens", "rect_tokens", "kv_blocks_live",
-            "kv_blocks_walked")},
+            "kv_blocks_walked", "kv_blocks_walked_shared", "cross_tokens")},
         "live_flops": lc.flops if lc else 0.0,
         "sched_flops": sc.flops if sc else 0.0,
         "live_bytes": lc.hbm_bytes if lc else 0.0,

@@ -676,6 +676,9 @@ def paged_attention_kernel(
     starts: jax.Array | None = None,  # [B] int32: token-major, each row's
                               #   first token among ``q``'s N
     t: int | None = None,     # ... and the row bucket T (static)
+    scale: float | None = None,  # on q; None: the head's ``D ** -0.5``
+                              #   (a caller whose D is not the model's
+                              #   head: differential attention's pair view)
 ) -> jax.Array:
     """Flash paged attention over layer ``layer`` of a block-table cache.
     Returns [B, T, H, D].
@@ -738,7 +741,7 @@ def paged_attention_kernel(
     r = t * rep
     rchunk, nq = query_chunks(t, rep=rep, kh=kh, d=d, q_dtype=q.dtype)
     tile = rchunk // rep if packed else 0
-    qs = q * (d ** -0.5)
+    qs = q * (d ** -0.5 if scale is None else scale)
     if packed:
         # A tile read from a row's first token runs into the next row's
         # tokens and, on the last row, past N: a tile of padding behind
@@ -861,6 +864,7 @@ def paged_attention_sharded(
     window: int = 0,          # as paged_attention_kernel
     starts: jax.Array | None = None,  # as paged_attention_kernel: q is
     t: int | None = None,             #   [N, H, D], and so is the result
+    scale: float | None = None,       # as paged_attention_kernel
 ) -> jax.Array:
     """TP-sharded paged attention: shard_map the kernel over the "model"
     (head) axis so each device runs the kernel on its local heads. Heads are
@@ -883,7 +887,7 @@ def paged_attention_sharded(
               *starts):
         return paged_attention_kernel(
             q, k_cache, v_cache, block_tables, q_start, kv_lens, layer=layer,
-            interpret=interpret, window=window, t=t, **(
+            interpret=interpret, window=window, t=t, scale=scale, **(
                 {"starts": starts[0]} if starts else {}))
 
     q_spec = (P("data", None, "model", None) if starts is None

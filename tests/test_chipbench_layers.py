@@ -670,3 +670,47 @@ def test_every_cell_reports_the_build_metrics():
         _judged, layer = manifest.cell_metrics(BENCH, w["name"])
         assert {"engine.warmup_s_per_program",
                 "engine.layer_bodies_traced_pct"} <= set(layer)
+
+
+# ---------------------------------------------------------------------------
+# PR 56: the cross-decoder's two counters
+# ---------------------------------------------------------------------------
+
+def _cross(shared: int | None, walked: int = 0, cross: int = 0, live: int = 0):
+    c0 = {"sched": {}} if shared is None else {"sched": {
+        "kv_blocks_walked_total": 9_000, "kv_blocks_walked_shared_total": 4_000,
+        "cross_tokens_total": 300, "live_tokens_total": 5_000}}
+    c1 = {"sched": {}} if shared is None else {"sched": {
+        "kv_blocks_walked_total": 9_000 + walked,
+        "kv_blocks_walked_shared_total": 4_000 + shared,
+        "cross_tokens_total": 300 + cross,
+        "live_tokens_total": 5_000 + live}}
+    return _ctx(c0, c1)
+
+
+@pytest.mark.parametrize("name, ctx, expect", [
+    # 1,000 decode steps of 20 rows at 3,000 tokens: 8 walks of 33 blocks
+    # and 8 of 188 a row, 7 of the latter the cross layers'
+    ("attn.shared_kv_walk_pct",
+     _cross(20_000 * 7 * 188, 20_000 * (8 * 33 + 8 * 188)),
+     100.0 * 7 * 188 / (8 * 33 + 8 * 188)),
+    # a model without cross layers walks none of them; nothing walked at all
+    ("attn.shared_kv_walk_pct", _cross(0, 50_000), 0.0),
+    ("attn.shared_kv_walk_pct", _cross(0, 0), None),
+    ("attn.shared_kv_walk_pct", _cross(None), None),
+    # 1,000 decode steps of 20 rows and 30 chunks of 512 beside them: one
+    # token a row a step entered the cross-decoder
+    ("xdec.tokens_pct", _cross(0, 0, 20_000 + 30, 20_000 + 30 * 512),
+     100.0 * 20_030 / (20_000 + 30 * 512)),
+    ("xdec.tokens_pct", _cross(0, 0, 20_000, 20_000), 100.0),
+    # a model without a cross-decoder; a program without the counter
+    ("xdec.tokens_pct", _cross(0, 0, 0, 20_000), None),
+    ("xdec.tokens_pct", _cross(None), None),
+], ids=["decode_at_3000", "no_cross_layers", "nothing_walked", "no_counter",
+        "chunks_beside", "decode_alone", "no_cross_decoder", "no_counter2"])
+def test_cross_decoder_counter_on_a_hand_made_context(name, ctx, expect):
+    value = measure.load_reader(name).read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))
+    entry = {m["name"]: m for m in BENCH["per_layer"]}[name]
+    assert entry["workloads"] == ["phi-4-mini-flash.reasoning"]
+    assert entry["source"] == "program_counter"

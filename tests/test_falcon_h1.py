@@ -146,7 +146,7 @@ def _serve(cfg, params, tokens, cuts, *, slot=1, ssm=None, slots=3,
 def test_the_published_config_resolves_to_the_plan(tmp_path):
     (tmp_path / "config.json").write_text(json.dumps(PUBLISHED))
     cfg = resolve_model_config(str(tmp_path))
-    assert cfg.layer_plan[1:] == (0, 1, 72, 0)
+    assert cfg.layer_plan.split == (0, 1, 72, 0)
     kinds = [(m.kind, m.stack, m.place, m.layer, m.joined)
              for m in cfg.layer_plan.layers[7]]
     assert kinds == [("*", "rep", 7, 7, False), ("M", "rep", 7, 7, True),
@@ -180,7 +180,7 @@ def test_the_benchmarks_cut_differs_in_depth_alone():
         "num_hidden_layers"}
     assert set(cut) == set(PUBLISHED)
     cfg = resolve_model_config(str(CONFIG_DIR))
-    assert cfg.layer_plan[1:] == (0, 1, 6, 0)
+    assert cfg.layer_plan.split == (0, 1, 6, 0)
     # a slot-layer of the pool: the float32 state and the bf16 tail
     assert mamba.slot_layer_bytes(cfg) == 32 * 128 * 256 * 4 + 3 * 5120 * 2 \
         == 4225024

@@ -32,7 +32,8 @@ def _resolve(name: str) -> ModelConfig:
         str(CONFIGS / name) if (CONFIGS / name).is_dir() else name)
 
 
-# (lead, period, trips, rest), then what the readers of the plan read:
+# (lead, period, trips) of each scanned run and then rest
+# (``LayerPlan.split``), then what the readers of the plan read:
 # the KV cache's layers, the routed layers, each attention layer's window
 PLANS = {
     "mistral-7b-v0.3-l16": ((0, 1, 16, 0), 16, 0, (0,) * 16),
@@ -47,14 +48,33 @@ PLANS = {
     "llama-3-8b-lite": ((0, 1, 8, 0), 8, 0, (0,) * 8),
     # attention and a Mamba-2 mixer joined, then the FFN, in every layer
     "falcon-h1-34b-l6": ((0, 1, 6, 0), 6, 0, (0,) * 6),
+    # SambaY whole: (Mamba-1, window) x 8 scanned, the Mamba-1 layer that
+    # keeps the memory and the full layer traced between, (memory unit,
+    # cross) x 7 scanned: two runs. The cache has the nine layers that write
+    "phi-4-mini-flash-reasoning": ((0, 2, 8, 2, 2, 7, 0), 9, 0,
+                                   (512,) * 8 + (0,)),
 }
 
 
 @pytest.mark.parametrize("name", sorted(PLANS))
 def test_where_the_scan_stands(name):
     plan = _resolve(name).layer_plan
-    assert plan[1:] == PLANS[name][0]
-    assert plan.lead + plan.period * plan.trips + plan.rest == len(plan.layers)
+    assert plan.split == PLANS[name][0]
+    assert sum(r.lead + r.period * r.trips for r in plan.runs) + plan.rest \
+        == len(plan.layers)
+    # the first run's are the plan's own: all of a one-run plan
+    assert (plan.lead, plan.period, plan.trips) == plan.split[:3]
+    assert sum(n * max(trips, 1) for _at, n, trips in plan.spans) \
+        == len(plan.layers)
+    assert [a for a, _n, _t in plan.spans] == sorted(
+        a for a, _n, _t in plan.spans)
+    if name == "phi-4-mini-flash-reasoning":
+        # layers 16 and 17 stand between the two runs, traced one by one,
+        # and the layers that run over the last tokens alone begin a run
+        assert plan.spans == ((0, 2, 8), (16, 2, 0), (18, 2, 7))
+        assert plan.last_from == 18 and len(plan.bodies) == 6
+    else:
+        assert len(plan.runs) == 1 and plan.last_from is None
 
 
 @pytest.mark.parametrize("name", sorted(PLANS))
@@ -72,10 +92,13 @@ def test_the_mixers_cover_the_model_once(name):
     assert {len(layer) for layer in layers} == (
         {1} if cfg.hybrid_pattern else
         {3} if cfg.ssm_beside_attention else {2})
+    if cfg.decoder_layout:      # SambaY: a mixer of four kinds, then an FFN
+        assert {layer[1].kind for layer in layers} == {"-"}
+        assert {layer[0].kind for layer in layers} == set("S*GX")
     if cfg.hybrid_pattern:
         assert "".join(m.kind for m in mixers) == cfg.hybrid_pattern
     # recurrent state: read off the plan, pattern string or none
-    assert cfg.has_ssm == any(m.kind == "M" for m in mixers)
+    assert cfg.has_ssm == any(m.kind in "MS" for m in mixers)
     # a joined mixer stands behind the one it joins, in its stack and place
     for layer in layers:
         for before, m in zip(layer, layer[1:]):
@@ -89,9 +112,12 @@ def test_the_mixers_cover_the_model_once(name):
         assert places == list(range(len(places))), key
     # the buffers' layers: the KV cache counts every attention layer, the
     # state pool and the experts' stack their own
-    for kind in "*ME":
+    for kind in "*MES":
         at = [m.layer for m in mixers if m.kind == kind]
         assert at == list(range(cfg.layers_of(kind))), kind
+    # ... and a cross mixer rereads the last layer that writes, and no other
+    assert {m.layer for m in mixers if m.kind == "X"} <= {cfg.attn_layers - 1}
+    assert [m.keeps for m in mixers if m.kind == "S"][:-1].count(True) == 0
     assert all(m.window == 0 for m in mixers if m.kind != "*")
     # what shares a stack within a layer shares the place
     assert all(len({m.place for m in layer if m.stack == s}) == 1
@@ -247,7 +273,7 @@ def test_forward_traces_a_period_once(monkeypatch, family):
     mixers' own functions, wrapped here: the program has no hook."""
     cfg = FAMILIES[family]
     split, bodies = TRACED[family]
-    assert cfg.layer_plan[1:] == split
+    assert cfg.layer_plan.split == split
     calls = {"attention": 0, "ffn": 0, "mamba": 0}
 
     def counted(name, fn):

@@ -131,7 +131,7 @@ def _serve(cfg, params, tokens, cuts, *, slot=1, ssm=None, slots=3,
 def test_the_published_configuration_resolves():
     cfg = resolve_model_config(str(CONFIG_DIR))
     assert cfg.hybrid_pattern == "MEMEM*" + "EMEMEM*" * 4
-    assert cfg.layer_plan[1:] == (6, 7, 4, 0)
+    assert cfg.layer_plan.split == (6, 7, 4, 0)
     assert (cfg.layers_of("M"), cfg.layers_of("E"), cfg.attn_layers) == (15, 14, 5)
     assert (cfg.num_experts, cfg.router_width, cfg.num_experts_per_tok) == (16, 128, 6)
     assert (cfg.expert_act, cfg.expert_gated, cfg.rope_scope) == ("relu2", False, "none")
@@ -161,7 +161,7 @@ def test_the_published_configuration_resolves():
 def test_a_patterns_leading_group_and_period(pattern, groups):
     cfg = ModelConfig(num_layers=len(pattern), hybrid_pattern=pattern,
                       mamba_num_heads=2, mamba_head_dim=4, ssm_state_size=4)
-    assert cfg.layer_plan[1:4] == groups
+    assert cfg.layer_plan.split[:3] == groups
 
 
 @pytest.mark.parametrize("key, value, says", [

@@ -55,13 +55,15 @@ WARMED = {"mistral-7b.chat": 64, "mistral-7b.longprompt": 48,
           "mistral-nemo-12b.chat": 64, "k-exaone-236b.reasoning": 99,
           "smallthinker-21b.reasoning": 66,
           "nemotron-3-nano-30b.reasoning": 99,
-          "falcon-h1-34b.reasoning": 99}
+          "falcon-h1-34b.reasoning": 99,
+          "phi-4-mini-flash.reasoning": 99}
 # ... rows (8, 16), or (8, 16, 32) where the cell's ``max_rows`` is 32.
 WARMED_KERNEL = {"mistral-7b.chat": 14, "mistral-7b.longprompt": 14,
                  "mistral-nemo-12b.chat": 14, "k-exaone-236b.reasoning": 21,
                  "smallthinker-21b.reasoning": 14,
                  "nemotron-3-nano-30b.reasoning": 21,
-                 "falcon-h1-34b.reasoning": 21}
+                 "falcon-h1-34b.reasoning": 21,
+                 "phi-4-mini-flash.reasoning": 21}
 # What EngineCore resolves EngineConfig.attn_impl to: on a TPU, elsewhere.
 PATHS = {"kernel": "pallas", "gather": "dense"}
 # Seconds a step takes in the replay: (a decode step, each chunk token on
@@ -90,7 +92,15 @@ CLOCKS_OF = {"k-exaone-236b.reasoning": {"fast": (0.011, 0.0001),
              # slower in order 4 and 15 % slower in the cell's own.
              # ``engine.compiles_in_window.chat`` reads it in a traced run)
              "falcon-h1-34b.reasoning": {"fast": (0.0148, 0.00004),
-                                         "slow": (0.0158, 0.000043)}}
+                                         "slow": (0.0158, 0.000043)},
+             # (PR 56: a step period of 17.6 ms at 13-20 rows at 0.8 req/s,
+             # 18.4 at 20 rows at 1.0; a mixed step 33 ms in the mean over
+             # chunk buckets, ~0.05 ms a chunk token: a prompt's tokens run
+             # half the depth; and a seventh slower: 31 rows were in flight
+             # at most at 1.0 req/s over 150 s, so the 32-row programs have
+             # that room at the cell's 0.8)
+             "phi-4-mini-flash.reasoning": {"fast": (0.0176, 0.00005),
+                                            "slow": (0.0200, 0.000057)}}
 POOL_BLOCKS = 6000
 
 

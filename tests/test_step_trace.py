@@ -376,6 +376,40 @@ def test_the_one_count_on_windowed_rows():
     assert c["table_blocks"] == 8 * 512 and c["kinds"] == ("mixed",)
 
 
+def test_the_one_count_of_a_cross_decoders_step():
+    """PR 56: a mixed program of SambaY's plan (8 windows of 512, a full
+    layer, 7 cross layers, 9 Mamba-1 layers). The windows' walks begin at
+    the oldest key a row's first query sees; the full layer and the cross
+    layers walk the row's whole context, the cross layers for the row's last
+    token alone; one token a row enters the cross-decoder; the scan's kernel
+    runs the live rows and positions and the one-token kernel none."""
+    from dynamo_tpu.obs.compile_ledger import BucketSig
+
+    sig = BucketSig("mixed", 8, 512, 512, True, "bfloat16")
+    rows = [(None, 2999, 1), (None, 1024, 300)]
+    c = step_counts([(sig, rows, None, None, None)], 16, (512,) * 8 + (0,),
+                    dec_rows=1, ssm_layers=9, scan_layers=9, cross_layers=7)
+    used = (188, 83)                     # ceil(3000 / 16), ceil(1324 / 16)
+    first = (2488 // 16, 513 // 16)      # the oldest key seen: start - 511
+    windows = sum(8 * (u - f) for u, f in zip(used, first))
+    assert c["kv_blocks_walked_shared"] == 7 * sum(used)
+    assert c["kv_blocks_walked"] == windows + sum(used) + 7 * sum(used)
+    assert c["cross_tokens"] == c["logit_rows"] == 2
+    assert c["live_tokens"] == 301
+    full = 3000 + sum(1025 + i for i in range(300))
+    assert c["attn_q_ctx"] == 8 * (512 + 300 * 512) + full + 7 * (3000 + 1324)
+    assert (c["ssm_scan_rows"], c["ssm_scan_positions"]) == (2 * 9, 301 * 9)
+    assert c["ssm_update_rows_given"] == c["ssm_update_rows_moved"] == 0
+    assert c["ssm_state_rows"] == 2 * 9 and c["ssm_layer_steps"] == 9
+    # the mixer computes the bucket's tokens: no blocked scan's t beside them
+    assert c["ssm_scanned_positions"] == sig.n
+    # ... and a model without any of it counts none of it
+    plain = step_counts([(sig, rows, None, None, None)], 16, (0,) * 9,
+                        dec_rows=1)
+    assert plain["cross_tokens"] == plain["kv_blocks_walked_shared"] == 0
+    assert plain["kv_blocks_walked"] == 9 * sum(used)
+
+
 @pytest.mark.parametrize("kind, b, t, rows, ssm_layers, given, moved", [
     # a decode program of 16 rows with 9 live: the grid has 16 rows a
     # layer, the kernel moves 9
@@ -409,8 +443,11 @@ def test_the_one_count_of_the_update_rows(kind, b, t, rows, ssm_layers,
     assert c["ssm_update_rows_given"] == given
     assert c["ssm_update_rows_moved"] == moved
     assert c["ssm_state_rows"] == len(rows) * ssm_layers
-    assert SSM_COUNTS[-2:] == ("ssm_update_rows_given",
+    assert SSM_COUNTS[4:6] == ("ssm_update_rows_given",
                                "ssm_update_rows_moved")
+    # (PR 56: behind them what the Mamba-1 kernel ran, 0 for these models)
+    assert SSM_COUNTS[6:] == ("ssm_scan_rows", "ssm_scan_positions")
+    assert c["ssm_scan_rows"] == c["ssm_scan_positions"] == 0
     led = SchedLedger()
     for _ in range(2):
         led.record_step(wall_s=0.01, kinds=c["kinds"],
@@ -656,7 +693,78 @@ def test_the_recurrent_readers_on_a_hand_made_trace(monkeypatch, tmp_path):
     assert reader("device.ssm_pct") is None
     entries = {m["name"]: m for m in BENCH["per_layer"]}
     for name in PR45:
-        # (the recurrent layer's three also in the cell PR 52 added)
+        # (the recurrent layer's three also in the cells PR 52 and PR 56 added)
         assert entries[name]["workloads"] == ["nemotron-3-nano-30b.reasoning"] \
-            + ["falcon-h1-34b.reasoning"] * name.startswith(("device.ssm", "ssm."))
+            + ["falcon-h1-34b.reasoning", "phi-4-mini-flash.reasoning"] \
+            * name.startswith(("device.ssm", "ssm."))
         assert entries[name]["moves"] == "itl_p95_ms"
+
+
+# ---------------------------------------------------------------------------
+# PR 56: the Mamba-1 recurrence's kernel by its own name
+# ---------------------------------------------------------------------------
+
+SSM1 = {"layers": 9, "slots": 64, "slot_layer_bytes": 358_400,
+        "token_bytes": (2 * 5120 + 5120 + 5120) * 2, "heads": 5120,
+        "head_dim": 1, "state_size": 16, "groups": 1, "conv_kernel": 4,
+        "conv_dim": 5120, "recurrence": "mamba1"}
+
+
+def test_the_selective_scan_reader_on_a_hand_made_trace(monkeypatch, tmp_path):
+    """Two decode steps at 20 rows: the kernel's nine calls take 0.3 ms of
+    a step, beside 0.2 ms of casts and the gate under the same phase. The
+    ideal is the rows' states read and written (20 x 9 x 2 x 327,680 B:
+    0.144 ms at 819 GB/s) and a position's operands; the share is of the
+    kernel's calls by name, the phase's rest is ``ssm.scan_roofline_pct``'s."""
+    counts_mod = measure.load_module(
+        ROOT / "chipbench/layers/selective_scan_counts.py",
+        "selective_scan_counts")
+    ops, modules, host = [], [], []
+    counts = dict(live_tokens=20, logit_rows=20, kv_blocks_walked=20 * 16 * 40,
+                  attn_q_ctx=20 * 16 * 600, ssm_layer_steps=9,
+                  ssm_live_tokens=20, ssm_scanned_positions=20,
+                  ssm_state_rows=20 * 9, ssm_scan_rows=20 * 9,
+                  ssm_scan_positions=20 * 9, cross_tokens=20,
+                  kv_blocks_walked_shared=20 * 7 * 40)
+    for step, t0 in ((1, 10 * MS), (2, 25 * MS)):
+        modules.append((f"{DEC}(1)", t0, t0 + 10 * MS))
+        ops += [("%fusion.1 = f32[20,40,128] fusion(%a)", t0, t0 + 0.2 * MS),
+                ("%selective_scan.2 = f32[9,65,16,40,128] custom-call(%s)",
+                 t0 + 0.2 * MS, t0 + 0.5 * MS),
+                ("%fusion.5 = bf16[20,16384] fusion(%h)", t0 + 0.5 * MS,
+                 t0 + 10 * MS)]
+        host += [_program(step, 0, DEC, t0 - MS), _wait(step, t0 + 10.1 * MS),
+                 _record(step, t0 + 10.5 * MS, **counts)]
+    ev = _events(modules, host, ops)
+    ev.path = tmp_path / "hand.xplane.pb"
+    tables = {DEC: {"fusion.1": "ssm_scan", "selective_scan.2": "ssm_scan",
+                    "fusion.5": "logits"}}
+    monkeypatch.setattr(xevents, "current", lambda: ev)
+    mod = measure.load_reader("ssm.selective_scan_roofline_pct")
+    monkeypatch.setattr(mod.join, "current", lambda: join.build(ev, tables))
+    full = {"ssm": SSM1, "sched": {}, "device": {"device_kind": "TPU v5 lite"}}
+    nbytes, ops_ = counts_mod.step(180, 180, SSM1)
+    assert nbytes == 180 * 2 * 16 * 5120 * 4 + 180 * (5120 * 8 + 64)
+    assert ops_ == 180 * 6 * 16 * 5120
+    got = mod.read(_ctx({"sched": {}}, full))
+    assert got == pytest.approx(100 * (nbytes / 819e9) / 0.0003)
+    assert 40.0 < got < 60.0
+    # the phase's share, beside it, is of all 0.5 ms
+    ssm_counts = measure.load_module(
+        ROOT / "chipbench/layers/ssm_counts.py", "ssm_counts")
+    phase = measure.load_reader("ssm.scan_roofline_pct")
+    monkeypatch.setattr(phase.join, "current", lambda: join.build(ev, tables))
+    assert phase.read(_ctx({"sched": {}}, full)) == pytest.approx(
+        100 * ssm_counts.ideal_seconds(180, 20, SSM1, peaks.peaks_for(
+            "TPU v5 lite")) / 0.0005)
+    # nothing on a program without the kernel (the parent's: no such call,
+    # no such count) or without a state pool
+    tables = {DEC: {"fusion.1": "ssm_scan", "fusion.5": "logits"}}
+    ev2 = _events(modules, host, [o for o in ops if "selective" not in o[0]])
+    ev2.path = tmp_path / "hand2.xplane.pb"
+    monkeypatch.setattr(mod.join, "current", lambda: join.build(ev2, tables))
+    assert mod.read(_ctx({"sched": {}}, full)) is None
+    assert mod.read(_ctx({"sched": {}}, {"sched": {}})) is None
+    entry = {m["name"]: m for m in BENCH["per_layer"]}[mod.name]
+    assert entry["workloads"] == ["phi-4-mini-flash.reasoning"]
+    assert (entry["moves"], entry["source"]) == ("itl_p95_ms", "device_trace")

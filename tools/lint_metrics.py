@@ -178,6 +178,8 @@ SCHED_SSM_COUNTS = (
     "ssm_state_rows",
     "ssm_update_rows_given",
     "ssm_update_rows_moved",
+    "ssm_scan_rows",
+    "ssm_scan_positions",
 )
 
 # The scheduling-ledger family (obs/sched_ledger.py SchedMetrics):
