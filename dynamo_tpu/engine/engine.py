@@ -49,6 +49,11 @@ from dynamo_tpu.engine.cache import (
     register_device_tier,
 )
 from dynamo_tpu.engine.prefix_pool import PrefixPool
+from dynamo_tpu.engine.program_store import (
+    ProgramStore,
+    program_key,
+    runtime_facts,
+)
 from dynamo_tpu.engine.sampling import (
     SamplingState,
     greedy_sample as _greedy_sample,
@@ -423,6 +428,7 @@ class ModelRunner:
         mesh=None,
         params=None,
         rng_seed: int = 0,
+        program_dir: "str | Path | None" = None,
     ):
         self.cfg = cfg
         self.engine_cfg = engine_cfg
@@ -521,6 +527,13 @@ class ModelRunner:
         # the pipelined (host/device-overlapped) step loop. Row maxb = trash.
         self.slot_toks = self._place(jnp.zeros((maxb + 1,), jnp.int32))
         self._step_fns: dict[tuple[int, int, int], Callable] = {}
+        # The compiled step programs an earlier start left under
+        # ``program_dir`` (engine/program_store.py): ``step_fn`` looks there
+        # before it builds one, and a program built here is written there.
+        # None where there is no persistent compile cache to keep them
+        # beside (the CPU backend), unless a test names a directory.
+        self._store = ProgramStore(program_dir) if program_dir else None
+        self._facts: tuple | None = None     # ``_program_facts``, read once
         # Compile ledger (obs/compile_ledger.py): every cache miss below is
         # a trace+compile that blocks the engine-core thread; the ledger
         # times it, attributes the victim request, and feeds warmup
@@ -786,24 +799,44 @@ class ModelRunner:
         variant's temporaries are no larger, and it compiles 5x slower)."""
         cache = abstract_cache(
             dataclasses.replace(self.spec, num_blocks=num_blocks), self.mesh)
-        fn = self._build_step_fn(sig.b, sig.t, sig.nblk, fast_greedy=True)
         maxb = self.engine_cfg.max_batch_size
         ssm = ({"ssm": mamba.state_shapes(self.cfg, maxb)}
                if self.cfg.has_ssm else {})
-        mem = fn.lower(
-            self.params, cache, cache, self.counts, self.keys, self.slot_toks,
-            *self._padding_inputs(sig.b, sig.t, sig.nblk, True), **ssm,
-        ).compile().memory_analysis()
+        args = (self.params, cache, cache, self.counts, self.keys,
+                self.slot_toks,
+                *self._padding_inputs(sig.b, sig.t, sig.nblk, True))
+        # The answer is kept in the program store under the probe's own
+        # arguments: a warm start then lowers nothing here either (two
+        # lowerings, 5 s of a hybrid cell's warm start).
+        name, key = sig.program() + "_memory", None
+        if self._store is not None:
+            key = program_key(name, (args, ssm), self._program_facts())
+            try:
+                peak, extra = map(int, json.loads(self._store.read(name, key)))
+                return peak, extra
+            except (ValueError, TypeError):
+                pass    # no entry, or a damaged one: measured and written
+        mem = self._build_step_fn(sig.b, sig.t, sig.nblk, fast_greedy=True) \
+            .lower(*args, **ssm).compile().memory_analysis()
         extra = (mem.temp_size_in_bytes + mem.output_size_in_bytes
                  - mem.alias_size_in_bytes)
         # (the state pool is _fit_pool's to count, not the step's own)
         pool = mamba.state_bytes(self.cfg, maxb) if ssm else 0
-        return mem.argument_size_in_bytes - pool + extra, extra
+        out = mem.argument_size_in_bytes - pool + extra, extra
+        if key is not None:
+            self._store.write(name, key, json.dumps(out).encode())
+        return out
 
     def _ssm_kw(self) -> dict:
         """The state pool as a step program takes it: by keyword, and only
         where the model has one."""
         return {"ssm": self.ssm} if self.ssm is not None else {}
+
+    def _carried(self) -> tuple:
+        """The device state a step program takes before its inputs, as
+        ``_run_step`` hands it in."""
+        return (self.params, self.cache_k, self.cache_v, self.counts,
+                self.keys, self.slot_toks)
 
     def _run_step(self, fn, inputs) -> tuple:
         """Call a step program on the device state it carries (K, V, the
@@ -967,26 +1000,132 @@ class ModelRunner:
     def step_fn(self, b: int, t: int, nblk: int, sp_prefill: bool = False,
                 fast_greedy: bool = False, mm: bool = False,
                 masked: bool = False):
+        """The step program of one bucket, built once a runner: loaded from
+        the program store where an earlier start left it there (a
+        ``jax.stages.Compiled``, called as the jitted function is), else
+        the jitted function, which traces, lowers and compiles (or fetches
+        from the persistent cache) inside its first call; the caller hands
+        that call's inputs to ``_keep_program`` afterwards."""
         key = (b, t, nblk, sp_prefill, fast_greedy, mm, masked)
         if key not in self._step_fns:
-            log.info("compiling step fn B=%d T=%d NBLK=%d sp_prefill=%s "
-                     "greedy=%s mm=%s masked=%s", b, t, nblk, sp_prefill,
-                     fast_greedy, mm, masked)
-            self._step_fns[key] = self._build_step_fn(
-                b, t, nblk, sp_prefill, fast_greedy, mm, masked)
+            fn = self._load_program(key)
+            if fn is None:
+                log.info("compiling step fn B=%d T=%d NBLK=%d sp_prefill=%s "
+                         "greedy=%s mm=%s masked=%s", b, t, nblk, sp_prefill,
+                         fast_greedy, mm, masked)
+                fn = self._build_step_fn(
+                    b, t, nblk, sp_prefill, fast_greedy, mm, masked)
+            self._step_fns[key] = fn
         return self._step_fns[key]
+
+    # -- the program store (engine/program_store.py) --------------------
+    def _program_facts(self) -> tuple:
+        """What a step program of this runner is built from beside its
+        bucket and its arguments: the source (the store's code digest), the
+        resolved model, every field of the resolved ``EngineConfig`` but
+        ``seed`` (which decides values of arguments and nothing of a
+        program; a field no program closes over costs a miss when it
+        changes, one left out would cost a wrong program), the
+        implementations the runner resolved, the mesh with its devices, how
+        host-built inputs are placed, and the process (``runtime_facts``).
+        Read once."""
+        if self._facts is None:
+            ec = dataclasses.asdict(self.engine_cfg)
+            del ec["seed"]
+            mesh = self.mesh
+            self._facts = (
+                self._store.digest, repr(self.cfg), sorted(ec.items()),
+                self.attn_impl, self.moe_impl,
+                None if mesh is None else (
+                    mesh.axis_names, mesh.devices.shape,
+                    [(d.id, d.device_kind) for d in mesh.devices.flat]),
+                repr(self._repl), runtime_facts())
+        return self._facts
+
+    def _padding_extras(self, b: int, t: int, mm: bool, masked: bool) -> tuple:
+        """What a step program takes behind its packed inputs, as numpy
+        zeros: the multimodal pair, the logit mask."""
+        extra = ((np.zeros((b, t, self.cfg.hidden_size), np.float32),
+                  np.zeros((b, t), bool)) if mm else ())
+        if masked:
+            extra += (np.zeros((b, self.cfg.vocab_size), np.float32),)
+        return extra
+
+    def _program_key(self, key: tuple) -> tuple[str, str]:
+        """(name, store key) of the step program of a ``_step_fns`` key: the
+        shape, dtype and sharding of every argument of the serving call,
+        read off the live arrays (the parameters, both caches with the pool
+        ``_fit_pool`` chose, the sampling state, the recurrent pool) and off
+        the bucket's padding inputs, with ``_program_facts``. No trace."""
+        b, t, nblk, _sp, greedy, mm, masked = key
+        name = self._step_program(*key)
+        args = (*self._carried(),
+                *pack_step_inputs(*_padding_rows(b, t, nblk), greedy=greedy),
+                *self._padding_extras(b, t, mm, masked))
+        return name, program_key(name, (args, self._ssm_kw()),
+                                 self._program_facts())
+
+    def _built(self, key: tuple) -> dict:
+        """What the compile ledger records of how the program of a
+        ``_step_fns`` key came to be: the layer bodies it holds and those
+        its build traced (none where it was loaded), and whether it was
+        loaded from the store."""
+        loaded = self._was_loaded(key)
+        return {"bodies": (self._bodies[0], 0) if loaded else self._bodies,
+                "loaded": loaded}
+
+    def _was_loaded(self, key: tuple) -> bool:
+        """Whether the program of a ``_step_fns`` key came from the store:
+        it is then the loaded executable itself, not a jitted function."""
+        return isinstance(self._step_fns.get(key), jax.stages.Compiled)
+
+    def _load_program(self, key: tuple):
+        """The step program of ``key`` from the store, or None."""
+        if self._store is None:
+            return None
+        devices = (list(self.mesh.devices.flat) if self.mesh is not None
+                   else list(self.counts.devices()))
+        t0 = time.perf_counter()
+        name, store_key = self._program_key(key)
+        fn = self._store.load(name, store_key, devices)
+        if fn is not None:
+            log.info("step fn %s loaded from the program store in %.3fs",
+                     name, time.perf_counter() - t0)
+        return fn
+
+    def _keep_program(self, key: tuple, inputs) -> None:
+        """Write the step program of ``key`` to the store, after its first
+        call (on ``inputs``) built it the ordinary way: lowered again from
+        the live arrays, which jit answers from what that call left it
+        (the executable is the one it runs), then serialised. Nothing for a
+        program that came from the store, or where there is none."""
+        if self._store is None or self._was_loaded(key):
+            return
+        t0 = time.perf_counter()
+        name, store_key = self._program_key(key)
+        try:
+            compiled = self._step_fns[key].lower(
+                *self._carried(), *inputs, **self._ssm_kw()).compile()
+        except Exception:
+            log.warning("step fn %s not kept", name, exc_info=True)
+            return
+        t1 = time.perf_counter()
+        self._store.save(name, store_key, compiled)
+        log.info("step fn %s kept in the program store in %.3fs (%.3fs of "
+                 "it the lowering jit holds)", name,
+                 time.perf_counter() - t0, t1 - t0)
 
     def phase_tables(self, programs=None) -> dict[str, dict[str, str]]:
         """``{program: {instruction: innermost phase}}`` of the step
-        programs built so far (of those named in ``programs``, where given):
-        each lowered again with padding inputs beside the live state, which
-        jit answers from what it holds for the serving call, and its text
-        read by obs/profiler.py ``phase_table``. Tens of milliseconds a
+        programs built so far (of those named in ``programs``, where given),
+        by obs/profiler.py ``phase_table`` from each one's compiled text: a
+        program loaded from the store has its own; a jitted one is lowered
+        again with padding inputs beside the live state, which jit answers
+        from what it holds for the serving call. Tens of milliseconds a
         program: for after a traced run (``AsyncJaxEngine.shutdown``) or an
         operator's question (``/debug/phases``), not for the serving path. A
         program that does not lower again (a live engine donated the cache
         under it) is left out, with a warning: ask again."""
-        place = self._place
         out: dict[str, dict[str, str]] = {}
         for key in list(self._step_fns):
             if isinstance(key[0], str):
@@ -995,23 +1134,18 @@ class ModelRunner:
             if programs is not None and name not in programs:
                 continue
             b, t, nblk, _sp, greedy, mm, masked = key
-            extra = ((place(np.zeros((b, t, self.cfg.hidden_size), np.float32)),
-                      place(np.zeros((b, t), bool))) if mm else ())
-            if masked:
-                extra += (place(np.zeros((b, self.cfg.vocab_size),
-                                         np.float32)),)
+            fn = self._step_fns[key]
             try:
                 # The arrays themselves, as the serving call hands them
                 # in: the lowering and the executable are then the ones jit
                 # holds already (0.05 s a program on the chip; from shapes
                 # with the same shardings it is another module, a compile
                 # of its own: 14 s for a routed chunk step, PERF.md).
-                text = self._step_fns[key].lower(
-                    self.params, self.cache_k, self.cache_v, self.counts,
-                    self.keys, self.slot_toks,
-                    *self._padding_inputs(b, t, nblk, greedy), *extra,
-                    **self._ssm_kw()
-                ).compile().as_text()
+                text = (fn if self._was_loaded(key) else fn.lower(
+                    *self._carried(),
+                    *self._padding_inputs(b, t, nblk, greedy),
+                    *map(self._place, self._padding_extras(b, t, mm, masked)),
+                    **self._ssm_kw()).compile()).as_text()
             except Exception:
                 log.warning("no phase table for %s", name, exc_info=True)
                 continue
@@ -1105,10 +1239,9 @@ class ModelRunner:
                     f"{live} live tokens in a {kind} batch whose "
                     f"bucket (b={b}, t={t}) holds {n_tok}: cut it with "
                     "pack_rows")
-            miss = ((b, t, nblk, sp_prefill, fast_greedy, mm, masked)
-                    not in self._step_fns)
+            key = (b, t, nblk, sp_prefill, fast_greedy, mm, masked)
+            miss = key not in self._step_fns
             cold = led.enabled and miss
-            fn = self.step_fn(b, t, nblk, sp_prefill, fast_greedy, mm, masked)
             program = "jit_" + sig.program(sp_prefill=sp_prefill, mm=mm,
                                            masked=masked)
             if span.is_enabled():    # a profiler session is recording
@@ -1117,20 +1250,23 @@ class ModelRunner:
                 inputs = [self._place(x) for x in arrays]
             self.placed_inputs += len(inputs)
             if cold:
-                # jit compiles lazily: the cache miss pays its trace+compile
-                # wall INSIDE the fn(...) call below (only execution stays
-                # async), so timing the call measures the engine-thread
-                # stall.
+                # A miss pays for its program inside the phase below: the
+                # store's load in step_fn, or jit's trace+compile, which
+                # is lazy, INSIDE the first call (only execution stays
+                # async); then the write to the store. Timing the three
+                # measures the engine-thread stall.
                 led.mark_inflight(True)
                 t_compile = time.perf_counter()
             with loop_phase(clock, "engine.dispatch.launch"), \
                     self._compile_phase(miss, kind, b, t, nblk):
-                toks, lps, *moe = self._run_step(fn, inputs)
+                toks, lps, *moe = self._run_step(self.step_fn(*key), inputs)
+                if miss:
+                    self._keep_program(key, inputs)
             if cold:
                 dt = time.perf_counter() - t_compile
                 led.mark_inflight(False)
                 led.record(
-                    sig, dt, bodies=self._bodies,
+                    sig, dt, **self._built(key),
                     trace_ctx=next((s.trace_ctx for s, _, _ in rows
                                     if s.trace_ctx is not None), None))
         return sig, program, toks, lps, (moe[0] if moe else None)
@@ -1478,12 +1614,12 @@ class ModelRunner:
             key = (b, t, nblk, False, sig.greedy, False, False)
             if key in self._step_fns:
                 return True
-            fn = self.step_fn(b, t, nblk, False, sig.greedy, False, False)
-            toks, *_rest = self._run_step(
-                fn, self._padding_inputs(b, t, nblk, sig.greedy))
+            inputs = self._padding_inputs(b, t, nblk, sig.greedy)
+            toks, *_rest = self._run_step(self.step_fn(*key), inputs)
             np.asarray(toks)
+            self._keep_program(key, inputs)
         self._ledger.record(sig, time.perf_counter() - t0, source="warmup",
-                            bodies=self._bodies)
+                            **self._built(key))
         return False
 
 
@@ -1496,11 +1632,19 @@ class EngineCore:
         mesh=None,
         params=None,
         event_sink: Callable[[KvCacheEvent], None] | None = None,
+        program_dir: "str | Path | None" = None,
     ):
         # Before the first jit on every path that builds an engine.
         device.require_backend(jax.default_backend(),
                                jax.config.jax_platforms)
         compile_cache_dir = device.configure_compile_cache()
+        # The compiled step programs are kept beside the compile cache
+        # (engine/program_store.py), so exactly where there is one; a test
+        # names a directory of its own. (One process: a program of a mesh
+        # that spans processes has not been shown to load in each of them.)
+        if (program_dir is None and compile_cache_dir
+                and jax.process_count() == 1):
+            program_dir = Path(compile_cache_dir) / "programs"
         if engine_cfg.sp > 1 and engine_cfg.ring_prefill_threshold >= 0 and (
             engine_cfg.prefill_chunk < engine_cfg.max_model_len
             or engine_cfg.max_tokens_per_step < engine_cfg.max_model_len
@@ -1647,7 +1791,8 @@ class EngineCore:
                                         sp=engine_cfg.sp, tp=engine_cfg.tp,
                                         ep=engine_cfg.ep))
         self.runner = ModelRunner(self.model_cfg, engine_cfg, mesh=mesh, params=params,
-                                  rng_seed=engine_cfg.seed)
+                                  rng_seed=engine_cfg.seed,
+                                  program_dir=program_dir)
         # What is running, said once here and again in stats(): the smoke
         # and the benchmark read it instead of touching JAX themselves.
         spec = self.runner.spec

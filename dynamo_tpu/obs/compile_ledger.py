@@ -168,10 +168,11 @@ class CompileEvent:
     ts: float                     # trigger timestamp (epoch)
     trace_id: str | None = None   # victim request's trace, if any
     source: str = "serve"         # "serve" | "warmup"
+    loaded: bool = False          # from the program store, not built
 
     def to_dict(self) -> dict:
         d = {**self.sig.to_dict(), "seconds": self.seconds, "ts": self.ts,
-             "source": self.source}
+             "source": self.source, "loaded": self.loaded}
         if self.trace_id:
             d["trace_id"] = self.trace_id
         return d
@@ -267,6 +268,10 @@ class CompileLedger:
         self.mode = "lazy"
         self.events: list[CompileEvent] = []
         self.inventory: set[BucketSig] = set()
+        # of the inventory, the programs that were loaded from the program
+        # store (engine/program_store.py) and not traced, lowered and
+        # compiled or fetched
+        self.loaded: set[BucketSig] = set()
         self._dropped = 0
         # (layer bodies held, traced) summed over the recorded programs
         self._bodies = (0, 0)
@@ -296,6 +301,7 @@ class CompileLedger:
         with self._lock:
             self.events.clear()
             self.inventory.clear()
+            self.loaded.clear()
             self.plan = None
             self._dropped = 0
             self._bodies = (0, 0)
@@ -304,11 +310,15 @@ class CompileLedger:
     def record(self, sig: BucketSig, seconds: float, *,
                trace_ctx=None, source: str = "serve",
                ts: float | None = None,
-               bodies: tuple[int, int] = (0, 0)) -> CompileEvent | None:
+               bodies: tuple[int, int] = (0, 0),
+               loaded: bool = False) -> CompileEvent | None:
         """File one compile event; returns it (None when disabled).
         ``bodies``: the layer bodies the program holds and how many of them
         its build traced and lowered (``LayerPlan.bodies`` and the distinct
         among them), read off the model's plan by the caller and summed.
+        ``loaded``: the program came whole from the program store
+        (engine/program_store.py) and nothing of it was traced; its seconds
+        are the load and the first run, stalled on like any other's.
 
         Serve-path events with a traced victim emit an ``engine.compile``
         span under the victim's trace; untraced serve events still land on
@@ -319,13 +329,15 @@ class CompileLedger:
         end = ts if ts is not None else time.time()
         trace_id = getattr(trace_ctx, "trace_id", None)
         ev = CompileEvent(sig=sig, seconds=seconds, ts=end - seconds,
-                          trace_id=trace_id, source=source)
+                          trace_id=trace_id, source=source, loaded=loaded)
         with self._lock:
             if len(self.events) < self.cap:
                 self.events.append(ev)
             else:
                 self._dropped += 1
             self.inventory.add(sig)
+            if loaded:
+                self.loaded.add(sig)
             n_inv = len(self.inventory)
             self._bodies = (self._bodies[0] + bodies[0],
                             self._bodies[1] + bodies[1])
@@ -383,6 +395,7 @@ class CompileLedger:
                 "mode": self.mode,
                 "enabled": self.enabled,
                 "cache_entries": len(self.inventory),
+                "programs_loaded": len(self.loaded),
                 "events_total": len(self.events) + self._dropped,
                 "compile_seconds_total": sum(e.seconds for e in self.events),
                 "serve_stall_seconds": sum(
