@@ -93,9 +93,11 @@ from dynamo_tpu.obs.profiler import (
 from dynamo_tpu.obs.mem_ledger import get_mem_ledger, live_ids_of
 from dynamo_tpu.obs.sched_ledger import (
     SSM_COUNTS,
+    GapStamps,
     HolStall,
     get_sched_ledger,
     recurrent_and_cross,
+    step_class,
     step_counts,
     step_geometry,
 )
@@ -407,7 +409,7 @@ class PendingStep:
     moe: list = field(default_factory=list)
     # Scheduling-ledger context captured at plan time (token-budget
     # utilization, HOL victim list) — consumed by _record_step at
-    # finalize. None when DYN_SCHED_LEDGER=0.
+    # finalize and by outputs_posted. None when DYN_SCHED_LEDGER=0.
     sched: Any = None
     # How many of the step batches' rows, counted from the first, are
     # decode/guided rows; the rest are prefill chunks. Captured at plan
@@ -1864,8 +1866,12 @@ class EngineCore:
         self.loop_clock = self.runner.loop_clock
         # (t_arrival, t_added, t_first_plan) of the sequences whose first
         # token the last finalize emitted; whoever hands the outputs on
-        # closes them (first_tokens_posted).
+        # closes them (outputs_posted).
         self._first_tokens: list[tuple[float, float, float]] = []
+        # The gap ledger's side of a finalize (obs/sched_ledger.py
+        # GapStamps), and the step's ledger record, which gains the gap.
+        self._gap = GapStamps()
+        self._sched_rec = None
         # Hardware counters: analytic FLOPs/bytes + MFU/BW-util per step
         # (obs/profiler.py). DYN_PERF_PROFILE=0 turns the whole thing into
         # a no-op dict lookup per step.
@@ -2503,40 +2509,21 @@ class EngineCore:
 
     def _sched_context(self, plan: StepPlan) -> dict:
         """Scheduling-ledger context of a dispatched plan (token-budget
-        utilization, HOL victims), consumed by ``_record_step``."""
+        utilization, HOL victims): ``_record_step`` reads the first,
+        ``outputs_posted`` files the second with the gap it measures.
+        Nothing is priced here."""
         used = len(plan.decode) + sum(w.length for w in plan.prefill)
         hol = None
         if plan.prefill and plan.decode:
             # Every decode-ready stream in this step waits out the
             # prefill work before its token materializes; the culprit
-            # is the request contributing the largest chunk. The stall is
-            # not a whole launch: only the chunk's marginal share of the
-            # mixed step's wall (priced by the cost model) is charged to
-            # the victims.
-            from dynamo_tpu.obs import costmodel as cm
-
+            # is the request contributing the largest chunk.
             culprit = max(plan.prefill, key=lambda w: w.length)
-            stall_share = None
-            kw = dict(
-                decode_rows=len(plan.decode),
-                decode_kv_len=max(s.num_computed for s in plan.decode),
-                chunk_kv_len=max(w.start + w.length for w in plan.prefill),
-                block_size=self.engine_cfg.block_size,
-                kv_dtype=self.engine_cfg.kv_dtype or "bfloat16",
-                quantization=self.engine_cfg.quantization or "none")
-            mixed_s = cm.mixed_step_seconds(
-                self.model_cfg, self._hw,
-                chunk=sum(w.length for w in plan.prefill), **kw)
-            pure_s = cm.mixed_step_seconds(
-                self.model_cfg, self._hw, chunk=0, **kw)
-            if mixed_s > 0:
-                stall_share = max(mixed_s - pure_s, 0.0) / mixed_s
             hol = HolStall(
                 culprit=culprit.seq.request_id,
                 culprit_tokens=sum(w.length for w in plan.prefill),
                 victims=[(s.trace_ctx, s.request_id, s.qos_priority)
-                         for s in plan.decode],
-                stall_share=stall_share)
+                         for s in plan.decode])
         return {
             "budget_util": used / max(self.sched.max_tokens_per_step, 1),
             "hol": hol,
@@ -2645,11 +2632,12 @@ class EngineCore:
             self.traced_programs.update(pending.programs)
         if self.sched_led.enabled:
             info = pending.sched or {}
-            self.sched_led.record_step(
+            # (the step's HOL victims are filed with its gap: outputs_posted)
+            self._sched_rec = self.sched_led.record_step(
                 wall_s=wall,
                 budget_util=info.get("budget_util", 0.0),
                 queue_depths=self.sched.waiting.depths(),
-                hol=info.get("hol"), moe=moe and tuple(moe), ssm=ssm,
+                moe=moe and tuple(moe), ssm=ssm,
                 **step_geometry(self.model_cfg, self.engine_cfg,
                                 pending.batches, counts=counts, moe=moe,
                                 shapes=self.metrics.step_shapes,
@@ -2724,6 +2712,8 @@ class EngineCore:
             self._first_tokens.append(
                 (seq.t_arrival, seq.t_added, seq.t_first_plan))
             seq.t_first_plan = 0.0
+        if self._gap.step and candidates:
+            self._gap.stamp(seq)
         for token in candidates:
             seq.tokens.append(token)
             seq.block_seq.append(token)
@@ -2776,6 +2766,8 @@ class EngineCore:
         outputs: dict[str, LLMEngineOutput] = {}
         dec_left = pending.dec_rows
         moe = None
+        self._gap.step = pending.step if self.sched_led.enabled else 0
+        waited = clock.seconds["engine.finalize.wait"]
         for (sig, rows, sample_rows, toks_dev, lps_dev), moe_dev in zip(
                 pending.batches, pending.moe, strict=True):
             with loop_phase(clock, "engine.finalize.wait", step=pending.step):
@@ -2798,6 +2790,7 @@ class EngineCore:
                 self._finalize_batch(rows, sample_rows, toks, lps,
                                      dec_left, outputs)
             dec_left = max(dec_left - len(rows), 0)
+        self._gap.wait_s = clock.seconds["engine.finalize.wait"] - waited
         with loop_phase(clock, "engine.record", step=pending.step) as span:
             self._record_step(t0, pending, moe, span)
         if self.kvbm is not None and not self.sched.has_work():
@@ -2808,21 +2801,51 @@ class EngineCore:
                 self.kvbm.drain_publish()
         return outputs
 
-    def first_tokens_posted(self) -> None:
-        """The outputs of the last finalize have been handed on: close the
-        time to first token of the sequences whose first token was among
-        them (``EngineMetrics.ttft_*``). A sequence counts once, at its
-        first first-token, however often it is preempted and re-prefilled."""
-        if not self._first_tokens:
-            return
-        now = time.perf_counter()
-        m = self.metrics
-        for t_arrival, t_added, t_first_plan in self._first_tokens:
-            m.ttft_count += 1
-            m.ttft_inbox_s += t_added - t_arrival
-            m.ttft_queue_s += t_first_plan - t_arrival
-            m.ttft_prefill_s += now - t_first_plan
-        self._first_tokens.clear()
+    def outputs_posted(self, pending: "PendingStep | None" = None,
+                       span=None, now: float | None = None) -> None:
+        """The outputs of the last finalize (``pending``'s; None: there was
+        none) have been handed on. One ``perf_counter()`` (``now``, where
+        the caller took it as it handed them on) closes
+
+        - the time to first token of the sequences whose first token was
+          among them (``EngineMetrics.ttft_*``): a sequence counts once, at
+          its first first-token, however often it is preempted and
+          re-prefilled;
+        - the token gap of every other sequence the step posted to: the
+          seconds since the step that posted to it before (``_emit_and_finish``
+          stamped which), filed in the scheduling ledger under the class of
+          the programs that ran and their widest row bucket, beside the
+          seconds this thread was blocked on the device for the step
+          (``SchedLedger.record_post``), with the step's HOL victims, whose
+          stall is that gap less the decode mean of their row bucket.
+
+        ``span`` (the ``engine.post`` phase) carries the gap while a
+        profiler session records."""
+        if now is None:
+            now = time.perf_counter()
+        if self._first_tokens:
+            m = self.metrics
+            for t_arrival, t_added, t_first_plan in self._first_tokens:
+                m.ttft_count += 1
+                m.ttft_inbox_s += t_added - t_arrival
+                m.ttft_queue_s += t_first_plan - t_arrival
+                m.ttft_prefill_s += now - t_first_plan
+            self._first_tokens.clear()
+        gap = self._gap
+        if pending is None or gap.step != pending.step:
+            return          # no finalize before this post, or the ledger off
+        period, rows, odd = gap.close(now)
+        cls, b = step_class(pending.batches)
+        hol = (pending.sched or {}).get("hol")
+        self.sched_led.record_post(
+            self._sched_rec, cls=cls, b=b, period_s=period, rows=rows,
+            odd_gaps=odd, wait_s=gap.wait_s, hol=hol,
+            hol_b=sig_for_rows("decode", len(hol.victims), 1, 0,
+                               self.engine_cfg).b if hol else 0)
+        self._sched_rec = None
+        if span is not None and jax.profiler.TraceAnnotation.is_enabled():
+            span.set(gap_ms=round(period * 1e3, 3), gap_rows=rows + len(odd),
+                     cls=cls)
 
     def _finalize_batch(self, rows, sample_rows, toks, lps, dec_rows: int,
                         outputs: dict[str, LLMEngineOutput]) -> None:
@@ -3040,7 +3063,7 @@ class EngineCore:
         pending = self.step_begin()
         if pending is not None:
             outs.update(self.step_finalize(pending))
-            self.first_tokens_posted()
+            self.outputs_posted(pending)
         return outs
 
     # -- disagg / KV-transfer primitives (engine-core thread only) ---------
@@ -3699,13 +3722,15 @@ class AsyncJaxEngine:
                     self._emit_op({"op": "step", "now": t_step})
                     self.core.set_step_time(t_step)
                     nxt = self.core.step_begin() if self.core.has_work() else None
-                    outputs = (self.core.step_finalize(pending)
-                               if pending is not None else {})
+                    done = pending
+                    outputs = (self.core.step_finalize(done)
+                               if done is not None else {})
                     pending = nxt
-                    with loop_phase(clock, "engine.post"):
-                        for rid, out in outputs.items():
-                            self._post(rid, out)
-                        self.core.first_tokens_posted()
+                    # ``step`` joins the span to the step's wait and record.
+                    with loop_phase(clock, "engine.post",
+                                    step=done.step if done else 0) as span:
+                        self.core.outputs_posted(
+                            done, span, now=self._post_step(outputs))
                         self._stage_stream_waves()
                 except Exception as exc:
                     # Engine-fatal: fail + drain all in-flight state so the loop
@@ -3852,6 +3877,36 @@ class AsyncJaxEngine:
             return
         loop.call_soon_threadsafe(q.put_nowait, out)
 
+    def _post_step(self, outputs: dict[str, LLMEngineOutput]) -> float:
+        """Hand a step's outputs to their streams' queues on the event
+        loop, and return the one ``perf_counter()`` of it, taken as the
+        last of them goes. That last hand-off carries the stamp: its
+        callback runs behind the step's other ``put_nowait``s and files
+        the seconds since (``stats()["gaps"]["handover"]``), what the loop
+        adds before a consumer can take the step's tokens. No call of its
+        own: one more wake-up of the loop a step moved ``itl_p95_ms`` by
+        more than a per cent on the chip (PERF.md, PR 59)."""
+        loop, streams, last = self._loop, self._streams, None
+        for rid, out in outputs.items():
+            q = streams.get(rid)
+            if q is None or loop is None:
+                continue
+            if last is not None:
+                loop.call_soon_threadsafe(last[0].put_nowait, last[1])
+            last = (q, out)
+        now = time.perf_counter()
+        if last is not None:
+            if self.core.sched_led.enabled:
+                loop.call_soon_threadsafe(self._put_last, *last, now)
+            else:
+                loop.call_soon_threadsafe(last[0].put_nowait, last[1])
+        return now
+
+    def _put_last(self, q: asyncio.Queue, out: LLMEngineOutput,
+                  posted: float) -> None:
+        q.put_nowait(out)
+        self.core.sched_led.record_handover(time.perf_counter() - posted)
+
     # ------------------------------------------------------------------
     async def generate(self, req: PreprocessedRequest) -> AsyncIterator[LLMEngineOutput]:
         self.start()
@@ -3927,6 +3982,9 @@ class AsyncJaxEngine:
             # Goodput, padding waste, and stall attribution ride the same
             # stats channel (bench stamps, planner feed, /debug/fleet).
             out["sched"] = sled.snapshot()
+            # Token gaps by the step that made them, and the hand-over to
+            # the streams' loop: histograms, cumulative.
+            out["gaps"] = sled.gaps_snapshot()
         mled = get_mem_ledger()
         if mled.enabled:
             # Tier occupancy, pin-owner totals, TTX posture, and the last

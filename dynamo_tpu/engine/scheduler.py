@@ -122,6 +122,9 @@ class Seq:
     t_arrival: float = 0.0
     t_added: float = 0.0
     t_first_plan: float = 0.0
+    # The ordinal of the step that last posted tokens to this sequence (0:
+    # none has): the gap ledger's stamp (EngineCore.outputs_posted).
+    post_step: int = 0
 
     def __post_init__(self) -> None:
         self.tokens = list(self.req.token_ids)

@@ -29,6 +29,9 @@ class StepRecord:
     disabled so the ring schema is stable either way."""
 
     ts: float
+    # Seconds from step_finalize's start to _record_step (the rest of the
+    # device's step after the next was dispatched, plus the finalize's host
+    # work): not the step's period, nor the gap a stream saw.
     wall_s: float
     num_prefill: int
     num_decode: int

@@ -10,9 +10,21 @@ for the *scheduler's* decisions. Every dispatched engine step files one
   pure waste, priced through the same analytic cost model the perf
   profiler uses (obs/costmodel.py) and exported as
   ``dynamo_sched_goodput_fraction`` plus cumulative padding FLOPs/bytes.
+* **Token gaps** — when a step's tokens have been handed on
+  (``EngineCore.outputs_posted``), every row that had been posted tokens
+  before files the seconds since that earlier post under the class of the
+  step that ran (decode | mixed | verify) and its widest row bucket, with
+  the seconds of it the engine thread was blocked on the device for that
+  step: fixed-size histograms over ``GAP_EDGES``, measured, nothing priced
+  (``record_post``, ``stats()["gaps"]``). The hand-over from the engine
+  thread to the stream's event loop has a histogram of its own
+  (``record_handover``).
 * **HOL interference** — when a prefill chunk shares a step with decode
-  streams, every decode row's token delivery is delayed by the chunk's
-  marginal share of the step's wall. Each victim stream
+  streams, every decode row's token delivery is delayed by the chunk.
+  The engine's stall is measured: the mixed step's post-to-post gap less
+  the mean gap the ledger holds for decode steps of the victims' row
+  bucket (``record_post``); the mocker passes a share of its simulated
+  wall (``record_step(hol=...)``). Each victim stream
   accrues an ``engine.hol_stall`` span in its OWN trace carrying the
   culprit request id, aggregated into
   ``dynamo_sched_hol_stall_seconds{qos_class}`` and a per-step
@@ -40,6 +52,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+from bisect import bisect_right
 from collections import Counter, deque
 from dataclasses import dataclass, field
 
@@ -128,14 +141,15 @@ class SchedMetrics:
             "than live tokens (scheduled minus live)")
         self.hol_stall = registry.histogram(
             "sched_hol_stall_seconds",
-            "Per-victim head-of-line stall: wall seconds one decode-ready "
+            "Per-victim head-of-line stall: seconds one decode-ready "
             "stream's token delivery waited on a step that carried a "
-            "prefill chunk, by qos_class",
+            "prefill chunk (the step's measured token gap less the mean "
+            "gap of decode steps of the same row bucket), by qos_class",
             buckets=_STALL_SECONDS_BUCKETS)
         self.interference = registry.counter(
             "sched_interference_row_seconds_total",
             "Interference index: cumulative stalled-decode-row-seconds "
-            "(per step, victims x stall wall)")
+            "(per step, victims x measured stall)")
 
 
 _metrics: SchedMetrics | None = None
@@ -178,15 +192,17 @@ class HolStall:
     """One step's head-of-line interference: the culprit prefill and the
     decode-ready streams whose token delivery its chunk delayed.
 
-    ``stall_share`` scales the per-victim stall below the full step wall:
-    the chunk is not a separate launch, so the engine passes the chunk's
-    cost-model marginal share of the step (mixed minus pure-decode over
-    mixed). None (the cost model priced nothing) charges the whole wall."""
+    The engine hands it to ``record_post`` when the step's tokens have
+    been handed on, and the stall is measured there: the step's gap less
+    the decode mean of the victims' row bucket. ``stall_share`` is for a
+    caller with no gap to measure (the mocker, through ``record_step``): it
+    scales the per-victim stall below the step's wall; None charges the
+    whole wall."""
 
     culprit: str                    # culprit request id (largest chunk)
     culprit_tokens: int             # prefill tokens the step carried
     victims: list = field(default_factory=list)  # (trace_ctx, rid, qos_class)
-    stall_share: float | None = None  # chunk's marginal fraction of the wall
+    stall_share: float | None = None  # record_step only: fraction of wall_s
 
 
 @dataclass
@@ -194,7 +210,10 @@ class SchedStepRecord:
     """One dispatched engine step as the scheduler saw it."""
 
     ts: float                       # record timestamp (epoch, at finalize)
-    wall_s: float                   # dispatch-to-materialize wall
+    # Seconds from step_finalize's start to _record_step: in the pipelined
+    # loop the rest of the device's step after the next one was dispatched,
+    # plus the finalize's host work. Not the gap a stream saw: ``gap_s``.
+    wall_s: float
     kinds: tuple                    # batch kinds dispatched, in order
     prefill_rows: int = 0
     decode_rows: int = 0
@@ -223,8 +242,14 @@ class SchedStepRecord:
     preempt: dict = field(default_factory=dict)        # cause -> tokens
     hol_culprit: str = ""
     hol_victims: int = 0
-    hol_stall_s: float = 0.0        # per-victim stall (wall x stall_share)
+    hol_stall_s: float = 0.0        # per-victim stall (gap less decode mean)
     interference_row_s: float = 0.0  # victims x stall
+    # Filed when the step's tokens were handed on (record_post): the
+    # post-to-post seconds of the rows that were in the previous step too
+    # (0.0: none were), the rows that filed a gap, the step's class.
+    gap_s: float = 0.0
+    gap_rows: int = 0
+    gap_class: str = ""
 
     def to_dict(self) -> dict:
         d = {
@@ -253,6 +278,9 @@ class SchedStepRecord:
             d["blocked"] = dict(self.blocked)
         if self.preempt:
             d["preempt_recompute_tokens"] = dict(self.preempt)
+        if self.gap_rows:
+            d["gap"] = {"class": self.gap_class, "rows": self.gap_rows,
+                        "seconds": round(self.gap_s, 6)}
         if self.hol_victims:
             d["hol"] = {
                 "culprit": self.hol_culprit,
@@ -261,6 +289,128 @@ class SchedStepRecord:
                 "row_seconds": round(self.interference_row_s, 6),
             }
         return d
+
+
+# ---------------------------------------------------------------------------
+# Token gaps: the histograms' edges and one (class, row bucket)'s arrays
+# ---------------------------------------------------------------------------
+
+#: The classes a step's gaps are filed under: every program a decode
+#: program, one that carried a prompt chunk, a speculative verify beside
+#: decode programs.
+GAP_CLASSES = ("decode", "mixed", "verify")
+
+_FINE = 308    # 2000 ** (1 / 308) = 1.02499: edges at most 2.5 % apart
+
+
+def _gap_edges() -> tuple[float, ...]:
+    """Shared by every gap histogram, in seconds, six significant digits:
+    geometric between 0.5 ms and 1 s, coarser outside. Bucket ``i`` holds
+    ``edges[i - 1] <= gap < edges[i]``; the first starts at 0 and the last
+    (index ``len(edges)``) has no upper edge."""
+    fine = (5e-4 * 2000.0 ** (i / _FINE) for i in range(_FINE + 1))
+    return ((1e-5, 2e-5, 5e-5, 1e-4, 2e-4, 3.5e-4)
+            + tuple(float(f"{x:.6g}") for x in fine)
+            + (1.25, 1.6, 2.0, 2.5, 3.2, 4.0, 5.0, 6.4, 8.0, 10.0, 15.0,
+               20.0, 30.0, 60.0))
+
+
+GAP_EDGES = _gap_edges()
+
+
+class _GapHist:
+    """Per bucket of ``GAP_EDGES``: how many gaps were filed, the sum of
+    their seconds and the seconds of them the engine thread was blocked on
+    the device; beside them the steps counted and the sum of their periods
+    (a step counts where some row of it was in the previous step too).
+    ``lo`` / ``hi`` bound the buckets ever filed, so a snapshot copies
+    those and not the zeros around them."""
+
+    __slots__ = ("rows", "gap_s", "wait_s", "lo", "hi", "steps", "period_s")
+
+    def __init__(self) -> None:
+        n = len(GAP_EDGES) + 1
+        self.rows = [0] * n
+        self.gap_s = [0.0] * n
+        self.wait_s = [0.0] * n
+        self.lo, self.hi = n, 0
+        self.steps = 0
+        self.period_s = 0.0
+
+    def file(self, seconds: float, rows: int = 1, wait_s: float = 0.0) -> None:
+        i = bisect_right(GAP_EDGES, seconds)
+        self.rows[i] += rows
+        self.gap_s[i] += rows * seconds
+        self.wait_s[i] += rows * min(wait_s, seconds)
+        if i < self.lo:
+            self.lo = i
+        if i >= self.hi:
+            self.hi = i + 1
+
+    def snapshot(self) -> dict:
+        lo, hi = min(self.lo, self.hi), self.hi
+        return {"lo": lo, "rows": self.rows[lo:hi],
+                "gap_s": self.gap_s[lo:hi], "wait_s": self.wait_s[lo:hi],
+                "steps": self.steps, "period_s": self.period_s}
+
+
+class GapStamps:
+    """One engine's side of the gap ledger: which step posted tokens to a
+    row last (``Seq.post_step``, set by ``stamp``), and when each of the
+    last ``RING`` steps' outputs were handed on, so that ``close`` turns
+    the ordinals into seconds with one clock read a step and no work a row
+    beyond the stamp. The engine thread alone touches it."""
+
+    RING = 1024     # a row's gap reaches that many steps back, no further
+
+    __slots__ = ("step", "posted", "times", "same", "odd", "wait_s")
+
+    def __init__(self) -> None:
+        self.step = 0           # the step being finalized (0: none, or off)
+        self.posted = 0         # the last step whose outputs were handed on
+        self.times = [0.0] * self.RING      # when, by step % RING
+        self.same = 0           # rows stamped that ``posted`` posted to too
+        self.odd: list[int] = []            # the others' last steps
+        self.wait_s = 0.0       # the finalize's wait on the device
+
+    def stamp(self, seq) -> None:
+        """``seq`` is posted tokens by the step being finalized. A first
+        post is no gap."""
+        prev, seq.post_step = seq.post_step, self.step
+        if prev == self.posted:
+            self.same += prev > 0
+        elif prev:
+            self.odd.append(prev)
+
+    def close(self, now: float) -> tuple[float, int, list[float]]:
+        """The step's outputs were handed on at ``now``: the post-to-post
+        period of the rows that were in the previous step too (0.0 of
+        none), how many they are, and the others' own gaps (a row away for
+        more steps than the ring holds: since the oldest it holds)."""
+        step, times, ring = self.step, self.times, self.RING
+        rows, self.same = self.same, 0
+        period = now - times[self.posted % ring] if rows else 0.0
+        odd = [now - times[max(p, step - ring + 1) % ring] for p in self.odd]
+        self.odd.clear()
+        times[step % ring] = now
+        self.posted, self.step = step, 0
+        return period, rows, odd
+
+
+def step_class(batches) -> tuple[str, int]:
+    """The class a step's gaps are filed under and its widest row bucket,
+    off ``PendingStep.batches``' signatures: ``mixed`` where a program
+    carried a prompt chunk, else ``verify`` where one verified a proposal,
+    else ``decode``."""
+    decode, mixed, verify = GAP_CLASSES
+    cls, b = decode, 0
+    for sig, *_ in batches:
+        b = max(b, sig.b)
+        if sig.kind == "verify":
+            cls = verify if cls == decode else cls
+        elif sig.t > 1:
+            cls = mixed
+    return cls, b
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +456,12 @@ class SchedLedger:
         # accumulated between steps, flushed into the next record
         self._blocked_step: dict[str, int] = {}
         self._preempt_step: dict[str, int] = {}
+        # Token gaps by (class, row bucket), written by the engine thread
+        # (record_post); the hand-over to the stream's loop, written by
+        # that loop (record_handover). Both under the lock, a step at once.
+        self._gaps: dict[tuple[str, int], _GapHist] = {}
+        self._handover = _GapHist()
+        self._handover_max = 0.0
 
     # -- configuration --------------------------------------------------
     def configure(self, enabled: bool | None = None) -> None:
@@ -339,6 +495,9 @@ class SchedLedger:
             self._blocked_step.clear()
             self._preempt_step.clear()
             self.prefill_chunks = {}
+            self._gaps.clear()
+            self._handover = _GapHist()
+            self._handover_max = 0.0
 
     def set_prefill_chunks(self, chunk_by_qos: dict) -> None:
         """Publish the effective per-QoS prefill chunk sizes (resolved at
@@ -402,10 +561,9 @@ class SchedLedger:
     ) -> SchedStepRecord | None:
         """File one step record; returns it (None when disabled).
 
-        HOL victims with a traced request additionally accrue a
-        retroactive ``engine.hol_stall`` span in their own trace (start =
-        end - wall, like the compile ledger's retro spans) carrying the
-        culprit request id; untraced victims still count in the metrics."""
+        ``hol`` is for a caller with no token gap to measure (the mocker;
+        the engine hands its own to ``record_post``): its victims are
+        charged ``wall_s`` times ``stall_share`` (``_file_hol``)."""
         if not self.enabled:
             return None
         end = ts if ts is not None else time.time()
@@ -432,30 +590,10 @@ class SchedLedger:
         pad_f = max(sched_flops - live_flops, 0.0)
         pad_b = max(sched_bytes - live_bytes, 0.0)
         if hol is not None and hol.victims:
-            # Every decode-ready stream in the step waited for its token
-            # (outputs materialize at finalize); the chunk is charged its
-            # marginal share of the single launch.
-            stall = (wall_s * hol.stall_share
-                     if hol.stall_share is not None else wall_s)
-            rec.hol_culprit = hol.culprit
-            rec.hol_victims = len(hol.victims)
-            rec.hol_stall_s = stall
-            rec.interference_row_s = stall * len(hol.victims)
-            tr = None
-            for v_ctx, v_rid, v_cls in hol.victims:
-                m.hol_stall.observe(stall, qos_class=v_cls)
-                if v_ctx is None:
-                    continue  # untraced stream: metrics only, no span
-                if tr is None:
-                    from dynamo_tpu.obs.tracer import get_tracer
-
-                    tr = get_tracer()
-                span = tr.start_span(
-                    "engine.hol_stall", ctx=v_ctx, start=end - stall,
-                    request_id=v_rid, culprit=hol.culprit,
-                    culprit_tokens=hol.culprit_tokens, qos_class=v_cls)
-                tr.end_span(span, end=end, seconds=round(stall, 6))
-            m.interference.inc(rec.interference_row_s)
+            # A caller with no gap to measure (the mocker): the chunk is
+            # charged its share of the single launch's wall.
+            self._file_hol(rec, hol, wall_s * hol.stall_share
+                           if hol.stall_share is not None else wall_s, end)
         with self._lock:
             rec.blocked, self._blocked_step = self._blocked_step, {}
             rec.preempt, self._preempt_step = self._preempt_step, {}
@@ -475,17 +613,7 @@ class SchedLedger:
             self.padding_flops_total += pad_f
             self.padding_bytes_total += pad_b
             if rec.hol_victims:
-                self.hol_stall_seconds_total += rec.interference_row_s
-                self.hol_victims_total += rec.hol_victims
-                self.interference_row_seconds_total += rec.interference_row_s
-                s, n = self._culprits.get(rec.hol_culprit, (0.0, 0))
-                self._culprits[rec.hol_culprit] = (
-                    s + rec.interference_row_s, n + rec.hol_victims)
-                if len(self._culprits) > self._CULPRIT_CAP:
-                    keep = sorted(self._culprits.items(),
-                                  key=lambda kv: kv[1][0],
-                                  reverse=True)[: self._CULPRIT_CAP // 2]
-                    self._culprits = dict(keep)
+                self._count_hol(rec)
         for k in rec.kinds:
             m.steps.inc(kind=k)
         m.goodput.set(goodput)
@@ -498,7 +626,138 @@ class SchedLedger:
             m.queue_depth.set(float(d), qos_class=cls)
         return rec
 
+    def _file_hol(self, rec: SchedStepRecord, hol: HolStall, stall: float,
+                  end: float) -> None:
+        """One step's victims, each stalled ``stall`` seconds up to ``end``:
+        the record's fields, the histogram, the interference index and, for
+        a victim with a traced request, a retroactive ``engine.hol_stall``
+        span in its own trace. The totals are ``_count_hol``'s, under the
+        lock."""
+        m = get_sched_metrics()
+        rec.hol_culprit = hol.culprit
+        rec.hol_victims = len(hol.victims)
+        rec.hol_stall_s = stall
+        rec.interference_row_s = stall * len(hol.victims)
+        tr = None
+        for v_ctx, v_rid, v_cls in hol.victims:
+            m.hol_stall.observe(stall, qos_class=v_cls)
+            if v_ctx is None:
+                continue  # untraced stream: metrics only, no span
+            if tr is None:
+                from dynamo_tpu.obs.tracer import get_tracer
+
+                tr = get_tracer()
+            span = tr.start_span(
+                "engine.hol_stall", ctx=v_ctx, start=end - stall,
+                request_id=v_rid, culprit=hol.culprit,
+                culprit_tokens=hol.culprit_tokens, qos_class=v_cls)
+            tr.end_span(span, end=end, seconds=round(stall, 6))
+        m.interference.inc(rec.interference_row_s)
+
+    def _count_hol(self, rec: SchedStepRecord) -> None:
+        """``rec``'s stall into the totals and the culprit table (the
+        caller holds the lock)."""
+        self.hol_stall_seconds_total += rec.interference_row_s
+        self.hol_victims_total += rec.hol_victims
+        self.interference_row_seconds_total += rec.interference_row_s
+        s, n = self._culprits.get(rec.hol_culprit, (0.0, 0))
+        self._culprits[rec.hol_culprit] = (
+            s + rec.interference_row_s, n + rec.hol_victims)
+        if len(self._culprits) > self._CULPRIT_CAP:
+            keep = sorted(self._culprits.items(), key=lambda kv: kv[1][0],
+                          reverse=True)[: self._CULPRIT_CAP // 2]
+            self._culprits = dict(keep)
+
+    def record_post(
+        self, rec: SchedStepRecord | None, *,
+        cls: str,
+        b: int,
+        period_s: float = 0.0,
+        rows: int = 0,
+        odd_gaps: tuple | list = (),
+        wait_s: float = 0.0,
+        hol: HolStall | None = None,
+        hol_b: int = 0,
+        ts: float | None = None,
+    ) -> None:
+        """A step's tokens have been handed on: file its rows' gaps under
+        the step's class ``cls`` and widest row bucket ``b``.
+
+        ``rows`` of them were posted tokens by the previous step too and
+        waited ``period_s``, the post-to-post period; each of ``odd_gaps``
+        is a row that sat steps out (a verify pause, a preemption) and has
+        its own. A first post files nothing (the caller leaves it out: it
+        is time to first token). ``wait_s`` is what the engine thread was
+        blocked on the device for this step, filed against each gap as far
+        as the gap is long. ``rec`` is the step's record, which gains the
+        gap. One lock hold a step: a snapshot sees a step whole or not at
+        all.
+
+        ``hol``'s victims are charged the step's gap (``period_s``; the
+        shortest of ``odd_gaps`` where no row was in the previous step)
+        less the mean period the ledger holds for decode steps of row
+        bucket ``hol_b``, the program the victims would have run alone;
+        the whole gap while it holds no such step."""
+        if not self.enabled:
+            return
+        if not rows and not odd_gaps:
+            return      # first posts, or none: no gap, nobody stalled
+        stall = None
+        with self._lock:
+            cell = self._gaps.get((cls, b))
+            if cell is None:
+                cell = self._gaps[cls, b] = _GapHist()
+            if rows:
+                cell.file(period_s, rows, wait_s)
+                cell.steps += 1
+                cell.period_s += period_s
+            for g in odd_gaps:
+                cell.file(g, wait_s=wait_s)
+            if hol is not None and hol.victims:
+                alone = self._gaps.get(("decode", hol_b))
+                mean = (alone.period_s / alone.steps
+                        if alone is not None and alone.steps else 0.0)
+                stall = max((period_s if rows else min(odd_gaps)) - mean, 0.0)
+        if rec is not None:
+            rec.gap_class = cls
+            rec.gap_rows = rows + len(odd_gaps)
+            rec.gap_s = period_s if rows else 0.0
+        if stall is None:
+            return
+        rec = rec or SchedStepRecord(ts=0.0, wall_s=0.0, kinds=())
+        self._file_hol(rec, hol, stall, ts if ts is not None else time.time())
+        with self._lock:
+            self._count_hol(rec)
+
+    def record_handover(self, seconds: float) -> None:
+        """From the engine thread's hand-off of a step's outputs to the
+        moment the stream's event loop could take them (one a step)."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._handover.file(seconds)
+            if seconds > self._handover_max:
+                self._handover_max = seconds
+
     # -- accounting -----------------------------------------------------
+    def gaps_snapshot(self) -> dict:
+        """``stats()["gaps"]``: cumulative since the ledger's start or
+        ``reset``; a reader takes the difference of two. ``by_class[cls]
+        [str(b)]`` holds, for buckets ``lo`` onward of ``edges``, the rows
+        filed, the sum of their gaps and the seconds of those the engine
+        thread was blocked on the device, with the steps counted and the
+        sum of their periods; ``handover`` the same of the hand-over."""
+        with self._lock:
+            by_class: dict[str, dict[str, dict]] = {}
+            for (cls, b), cell in self._gaps.items():
+                by_class.setdefault(cls, {})[str(b)] = cell.snapshot()
+            hand = self._handover.snapshot()
+            hand_max = self._handover_max
+        return {"edges": GAP_EDGES, "by_class": by_class,
+                "handover": {"count": sum(hand["rows"]),
+                             "sum_s": sum(hand["gap_s"]), "max_s": hand_max,
+                             "lo": hand["lo"], "buckets": hand["rows"]}}
+
     def top_culprits(self, top: int = 5) -> list[dict]:
         """Worst HOL offenders: [{request_id, stall_seconds, victims}]."""
         with self._lock:

@@ -351,8 +351,8 @@ def follower_loop(core_factory: Callable[[dict], Any], sock: socket.socket) -> N
                 if pending is not None:
                     core.step_finalize(pending)
                     # A follower posts to nobody; close the first-token
-                    # stamps so they do not pile up.
-                    core.first_tokens_posted()
+                    # stamps and the step's gaps so they do not pile up.
+                    core.outputs_posted(pending)
                 pending = nxt
             except Exception as exc:
                 log.exception("follower step failed; wiping in-flight state")

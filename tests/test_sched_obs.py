@@ -442,11 +442,15 @@ def test_real_engine_hol_attribution(clean_ledger):
     # Unified step (default): the prefill chunks rode mixed launches.
     kinds = {k for r in led.steps for k in r.kinds}
     assert {"mixed", "decode"} <= kinds
-    # Marginal HOL attribution: each mixed record's stall is the chunk's
-    # cost-model share of the step wall, never more than the full wall.
+    # Measured HOL attribution: each mixed record's stall is the step's
+    # own token gap less the decode mean of the victims' row bucket, never
+    # more than the gap; nothing is priced.
     mixed_hol = [r for r in led.steps if "mixed" in r.kinds and r.hol_victims]
     assert mixed_hol
-    assert all(0.0 <= r.hol_stall_s <= r.wall_s for r in mixed_hol)
+    assert all(r.gap_class == "mixed" and r.gap_rows >= r.hol_victims
+               and 0.0 <= r.hol_stall_s <= r.gap_s for r in mixed_hol)
+    alone = led.gaps_snapshot()["by_class"]["decode"]["1"]
+    assert alone["steps"] and sum(alone["rows"]) >= alone["steps"]
 
 
 def test_real_engine_disabled_is_inert(clean_ledger, monkeypatch):
