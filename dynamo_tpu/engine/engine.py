@@ -79,6 +79,7 @@ from dynamo_tpu.obs.compile_ledger import (
     pack_rows,
     sig_for_rows,
     token_bucket,
+    writes_blocks,
 )
 from dynamo_tpu.obs.profiler import (
     LoopClock,
@@ -2605,6 +2606,7 @@ class EngineCore:
         counts = step_counts(pending.batches, self.engine_cfg.block_size,
                              self._windows, dec_rows=pending.dec_rows,
                              attn_tokens=attends_tokens(self.engine_cfg),
+                             block_writes=writes_blocks(self.engine_cfg),
                              **self._recurrent_and_cross)
         ssm = (tuple(counts[k] for k in SSM_COUNTS) if self._ssm_layers
                else None)

@@ -798,13 +798,31 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     if cfg.rope_scope == "all" or (window and cfg.rope_scope == "sliding"):
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
+    kernel = attn_impl in ("pallas", "pallas_interpret")
+    # A packed step (N < B*T) under the kernel: its tokens lie row after row
+    # from ``lay.starts`` on. (Rows split over "data" keep the rectangle:
+    # the tokens have no batch axis.)
+    packed = kernel and lay.starts is not None and (
+        mesh is None or mesh.shape.get("data", 1) == 1)
     # Phase hooks (obs/profiler.py): jax.named_scope annotations for
     # XLA profiles, plus wall capture in eager profiling runs. Under
     # jit they execute at trace time only — zero ops in the program.
     if not cross:
         with _perf_phase("scatter"):
-            cache_k = _scatter_kv(cache_k, k, slot, layer)
-            cache_v = _scatter_kv(cache_v, v, slot, layer)
+            if packed and not isinstance(cache_k, dict):
+                # ... so a row's K and V go into the pools by runs of
+                # consecutive slots, a copy a block and not an update a
+                # token (ops/kv_write.py); padded tokens write nothing.
+                from dynamo_tpu.ops.kv_write import kv_write, kv_write_sharded
+
+                cache_k, cache_v = (
+                    kv_write if tp == 1 else partial(kv_write_sharded, mesh))(
+                    k, v, cache_k, cache_v, block_tables, q_start, kv_lens,
+                    lay.starts, layer=layer,
+                    interpret=attn_impl == "pallas_interpret")
+            else:
+                cache_k = _scatter_kv(cache_k, k, slot, layer)
+                cache_v = _scatter_kv(cache_v, v, slot, layer)
 
     def out_proj(attn):
         # attn [N, heads, D] as attention leaves it
@@ -821,7 +839,6 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
             out = out * cfg.attention_out_multiplier
         return out
 
-    kernel = attn_impl in ("pallas", "pallas_interpret")
     if kernel:
         from dynamo_tpu.ops.paged_attention import (
             paged_attention_kernel,
@@ -835,12 +852,9 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
             else partial(paged_attention_sharded, mesh),
             layer=layer, interpret=attn_impl == "pallas_interpret",
             window=window, **scale)
-    if kernel and lay.starts is not None and (
-            mesh is None or mesh.shape.get("data", 1) == 1):
-        # A packed step (N < B*T) under the kernel: q goes in token-major
-        # as it is and the output comes back so. No array of B x T
-        # positions exists here, in or around the kernel. (Rows split over
-        # "data" keep the rectangle: the tokens have no batch axis.)
+    if packed:
+        # q goes in token-major as it is and the output comes back so. No
+        # array of B x T positions exists here, in or around the kernel.
         with _perf_phase("attention"):
             attn = attend(q, cache_k, cache_v, block_tables, q_start,
                           kv_lens, starts=lay.starts, t=lay.t)

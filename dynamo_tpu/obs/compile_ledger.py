@@ -448,6 +448,16 @@ def attends_tokens(ec) -> bool:
     return walks_live_context(ec) and getattr(ec, "dp", 1) == 1
 
 
+def writes_blocks(ec) -> bool:
+    """Whether a packed step's K and V go into the pools by runs of
+    consecutive slots, one small kernel a layer (ops/kv_write.py), and not
+    by one scatter update a token: where its attention takes the tokens as
+    they lie and the pool is a plain array (a quantized pool's scatter
+    rescales the blocks it touches: models/llama.py ``_scatter_kv_quant``)."""
+    return attends_tokens(ec) and getattr(ec, "kv_dtype", "") not in (
+        "int8", "int4")
+
+
 def _nblk_ladder(ec) -> list[int]:
     """Reachable block-table widths. Under the kernel one, ``max_nblk``
     (``walks_live_context``); under the dense gather ``sig_for_rows``

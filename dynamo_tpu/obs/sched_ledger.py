@@ -439,6 +439,7 @@ class SchedLedger:
         self.kv_blocks_walked_total = 0
         self.kv_blocks_walked_shared_total = 0
         self.cross_tokens_total = 0
+        self.kv_block_written_tokens_total = 0
         # layer steps, rows, touched, largest, streamed layer steps
         self.moe_totals = [0, 0, 0, 0, 0]
         self.ssm_totals = [0] * len(SSM_COUNTS)
@@ -482,6 +483,7 @@ class SchedLedger:
             self.kv_blocks_walked_total = 0
             self.kv_blocks_walked_shared_total = 0
             self.cross_tokens_total = 0
+            self.kv_block_written_tokens_total = 0
             self.moe_totals = [0, 0, 0, 0, 0]
             self.ssm_totals = [0] * len(SSM_COUNTS)
             self.padding_flops_total = 0.0
@@ -548,6 +550,7 @@ class SchedLedger:
         kv_blocks_walked: int = 0,
         kv_blocks_walked_shared: int = 0,
         cross_tokens: int = 0,
+        kv_block_written_tokens: int = 0,
         moe: tuple[int, int, int, int, int] | None = None,
         ssm: tuple[int, ...] | None = None,
         live_flops: float = 0.0,
@@ -606,6 +609,7 @@ class SchedLedger:
             self.kv_blocks_walked_total += kv_blocks_walked
             self.kv_blocks_walked_shared_total += kv_blocks_walked_shared
             self.cross_tokens_total += cross_tokens
+            self.kv_block_written_tokens_total += kv_block_written_tokens
             if moe:
                 self.moe_totals = [a + b for a, b in zip(self.moe_totals, moe)]
             if ssm:
@@ -784,6 +788,8 @@ class SchedLedger:
                 "kv_blocks_walked_shared_total":
                     self.kv_blocks_walked_shared_total,
                 "cross_tokens_total": self.cross_tokens_total,
+                "kv_block_written_tokens_total":
+                    self.kv_block_written_tokens_total,
                 "moe_layer_steps_total": self.moe_totals[0],
                 "moe_rows_total": self.moe_totals[1],
                 "moe_experts_touched_total": self.moe_totals[2],
@@ -872,7 +878,8 @@ SSM_COUNTS = ("ssm_layer_steps", "ssm_live_tokens", "ssm_scanned_positions",
 
 def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
                 ssm_layers: int = 0, attn_tokens: bool = False,
-                cross_layers: int = 0, scan_layers: int = 0) -> dict:
+                cross_layers: int = 0, scan_layers: int = 0,
+                block_writes: bool = False) -> dict:
     """What one step did, in the program's own terms and nothing priced:
     THE walk over a step's rows, made once between plan and record
     (EngineCore._record_step). The profiler prices it, the ledger's goodput
@@ -899,6 +906,11 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
       they lie: models/llama.py ``_attention``) its token bucket too;
       ``logit_rows`` (one a row) and
       ``sched_logit_rows`` (``sig.b`` a program);
+    - ``kv_block_written_tokens``: with ``block_writes`` (the engine's
+      ``writes_blocks``), the live tokens of the packed programs (``sig.n <
+      sig.b x sig.t``), whose K and V go into the pools by runs of
+      consecutive slots (ops/kv_write.py); a rectangle program's keep the
+      scatter, an update a token;
     - ``kv_blocks_live``: ``ceil((start + length) / block_size)`` a row,
       the blocks the rows hold, what a full layer's kernel walks for them;
     - ``kv_blocks_walked``: the same over all the layers, a sliding layer's
@@ -945,7 +957,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
     programs = pf_rows = n_dec = pf_tokens = dec_tokens = 0
     live = logit_rows = sched = rect = sched_rows = 0
     blocks = walked = q_ctx = table_q = table_blocks = scanned = ones = 0
-    shared = 0
+    shared = by_blocks = 0
     blocked = ssm_layers > scan_layers      # some layer scans in blocks
     dec_left = dec_rows
     for sig, rows, *_ in batches:
@@ -969,9 +981,11 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
             pf_rows += n - d
         chunks = sig.kind == "mixed"
         logit_rows += n
+        packed = block_writes and sig.n != sig.b * sig.t
         for _seq, start, length in rows:
             end = start + length
             live += length
+            by_blocks += length * packed
             ones += length == 1
             used = -(-end // bs)
             blocks += used
@@ -1010,6 +1024,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
         "kv_blocks_live": blocks, "kv_blocks_walked": walked + shared,
         "kv_blocks_walked_shared": shared,
         "cross_tokens": logit_rows if cross_layers else 0,
+        "kv_block_written_tokens": by_blocks,
         "attn_q_ctx": q_ctx,
         "table_q_ctx": table_q, "table_blocks": table_blocks,
         "ssm_layer_steps": programs * ssm_layers,
@@ -1054,6 +1069,7 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
     from dynamo_tpu.obs.compile_ledger import (
         attends_tokens,
         walks_live_context,
+        writes_blocks,
     )
 
     ec = engine_cfg
@@ -1061,6 +1077,7 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
         counts = step_counts(
             batches, ec.block_size, model_cfg.attn_windows,
             dec_rows=dec_rows, attn_tokens=attends_tokens(ec),
+            block_writes=writes_blocks(ec),
             **recurrent_and_cross(model_cfg))
     if shapes is None:
         shapes = cm.step_shapes(
@@ -1082,7 +1099,8 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
         **{k: counts[k] for k in (
             "kinds", "prefill_rows", "decode_rows", "live_tokens",
             "sched_tokens", "rect_tokens", "kv_blocks_live",
-            "kv_blocks_walked", "kv_blocks_walked_shared", "cross_tokens")},
+            "kv_blocks_walked", "kv_blocks_walked_shared", "cross_tokens",
+            "kv_block_written_tokens")},
         "live_flops": lc.flops if lc else 0.0,
         "sched_flops": sc.flops if sc else 0.0,
         "live_bytes": lc.hbm_bytes if lc else 0.0,

@@ -8,6 +8,9 @@ kernels with numerically-equivalent XLA fallbacks for CPU tests:
 - ring_attention: blockwise attention sharded over the "seq" mesh axis.
 - moe_stream: a decode step's routed experts, each touched expert's three
   matrices streamed once (the fallback is models/moe.py's grouped form).
+- kv_write: a packed step's K and V into the paged cache by runs of
+  consecutive slots (the fallback is models/llama.py's scatter, an update a
+  token).
 """
 
 from dynamo_tpu.ops.paged_attention import (

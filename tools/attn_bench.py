@@ -1,6 +1,6 @@
 """Single-program bench of the paged kernel, on the chip.
 
-    chiprun -- python3 tools/attn_bench.py [--only mixed,decode,dot]
+    chiprun -- python3 tools/attn_bench.py [--only mixed,decode,dot,write]
         [--against <other checkout>/dynamo_tpu/ops/paged_attention.py]
 
 **mixed** (~2.5 min): one layer's attention of a packed step under one
@@ -27,6 +27,16 @@ outputs are equal bit for bit.
 kernel, which is how the walk multiplies its probabilities by the values,
 equals ``dot_general(p.astype(bf16), v.astype(bf16))`` bit for bit (one bf16
 pass of the MXU), with ``Precision.HIGHEST`` as the control that must differ.
+
+**write** (~1 min; PR 60): a packed step's K and V into both pools alone, at
+the benchmark's four cache views and two token buckets (a ``b8 t512`` program's
+520 tokens: seven decode rows and a chunk of 505 from mid-block; a ``b8 t16``
+program's 24): the scatter a step ran until PR 60 (``llama._scatter_kv`` at
+``_positions_and_slots``' slots, K then V) against ``ops/kv_write.py``,
+microseconds a call from ``CALLS`` calls chained in one program over the
+donated pools, the scatter's microseconds an update, the bytes over 819 GB/s
+(read and written), and whether every block but the trash block is equal bit
+for bit.
 
 Lines go to stdout and to ``chiprun_out/attn_bench.json``. It fails without a
 TPU: the kernel is not interpreted here (tests/test_attention_tokens.py does
@@ -182,6 +192,70 @@ def decode_lines(emit, other):
                 emit(line)
 
 
+# (name, layers, KV heads, head width): the cells' cache views.
+WRITE_VIEWS = [("7b [16,NB,16,8,128]", 16, 8, 128), ("fh1 [6,NB,16,4,128]", 6, 4, 128),
+               ("nemo3 [6,NB,16,2,128]", 6, 2, 128), ("phi4 [9,NB,16,2,640]", 9, 2, 640)]
+
+
+def write_lines(emit):
+    from dynamo_tpu.ops.kv_write import kv_write
+
+    b, nb = 8, 8 * 128 + 1
+    for name, layers, kh, d in WRITE_VIEWS:
+        for t, rows in ((512, [(900 + 37 * i, 1) for i in range(7)] + [(1029, 505)]),
+                        (16, [(900 + 37 * i, 1) for i in range(7)] + [(1029, 16)])):
+            n = token_bucket("mixed", b, t)
+            rng = np.random.default_rng(kh + d + t)
+            qs = jnp.asarray([r[0] for r in rows], jnp.int32)
+            ql = jnp.asarray([r[1] for r in rows], jnp.int32)
+            bt = jnp.asarray(1 + rng.permutation(nb - 1)[:b * 128].reshape(b, 128), jnp.int32)
+            k, v = (jnp.asarray(rng.standard_normal((n, kh, d)), jnp.bfloat16) for _ in range(2))
+
+            def pools():
+                return tuple(jax.random.normal(jax.random.key(kh + d + i), (layers, nb, BS, kh, d),
+                                               jnp.bfloat16) for i in range(2))
+
+            def scatter(k, v, ck, cv, layer):
+                lay, valid = llama.token_layout(ql, b, t, n)
+                _, slot = llama._positions_and_slots(lay, valid, qs, bt, BS)
+                return llama._scatter_kv(ck, k, slot, layer), llama._scatter_kv(cv, v, slot, layer)
+
+            def blocks(k, v, ck, cv, layer):
+                lay, _ = llama.token_layout(ql, b, t, n)
+                return kv_write(k, v, ck, cv, bt, qs, qs + ql, lay.starts, layer=layer)
+
+            def chain(one):
+                def chained(k, v, ck, cv):
+                    def step(i, c):
+                        return one(k, v, *c, lax.rem(i, jnp.int32(layers))) if one else c
+                    return lax.fori_loop(jnp.int32(0), jnp.int32(CALLS), step, (ck, cv))
+                return jax.jit(chained, donate_argnums=(2, 3))
+
+            def timed(one):
+                f, c, ts = chain(one), pools(), []
+                for _ in range(8):
+                    t0 = time.perf_counter()
+                    c = f(k, v, *c); jax.block_until_ready(c)
+                    ts.append(time.perf_counter() - t0)
+                return sorted(ts[1:])[3] * 1e6, c
+
+            idle_us, _ = timed(None)
+            old_us, old = timed(scatter)
+            new_us, new = timed(blocks)
+            live = int(ql.sum())
+            moved = 2 * live * kh * d * 2
+            line = {"case": name, "n": n, "live": live,
+                    "scatter_us": round((old_us - idle_us) / CALLS, 2),
+                    "scatter_us_an_update": round((old_us - idle_us) / CALLS / (2 * n), 4),
+                    "kv_write_us": round((new_us - idle_us) / CALLS, 2),
+                    "bytes_us_at_819": round(2 * moved / HBM_BYTES_PER_S * 1e6, 2),
+                    "equal_bits_but_trash": all(
+                        bool((np.asarray(o[:, 1:]).view(np.uint16) == np.asarray(w[:, 1:]).view(np.uint16)).all())
+                        for o, w in zip(old, new))}
+            del old, new
+            emit(line)
+
+
 def dot_line(emit):
     """P.V as the walk's ``head`` writes it (float32 probabilities, the
     values a bf16 pool's widened to float32, default precision) against the
@@ -236,6 +310,8 @@ def main():
         decode_lines(emit, other)
     if "mixed" in parts:
         mixed_lines(emit)
+    if "write" in parts:
+        write_lines(emit)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/attn_bench.json", "w") as f:
         json.dump(out, f, indent=1)

@@ -4,6 +4,7 @@ final VLIW bundles of each loop.
 
     JAX_PLATFORMS=cpu python tools/kernel_bundles.py --out /tmp/bundles          # SmallThinker's decode shape
     JAX_PLATFORMS=cpu python tools/kernel_bundles.py --out /tmp/b --b 16 --h 20 --kh 4
+    JAX_PLATFORMS=cpu python tools/kernel_bundles.py --out /tmp/w --kernel kv_write --kh 8 --tokens 520
     python tools/kernel_bundles.py --read <dir>/<...>-final_bundles.txt      # a dump that is there
 
 The compile runs in a child process: libtpu's dumper aborts the process once
@@ -94,6 +95,17 @@ def _compile(args) -> None:
     b, t, d = args.b, args.t, 128
     cache = a((12, 4096, 16, args.kh, d), jnp.bfloat16)
     rows = a((b,), jnp.int32)
+    if args.kernel == "kv_write":
+        # A packed step's K and V into the pools (ops/kv_write.py): the loop
+        # over a row's blocks, and inside it a conditional copy a size.
+        from dynamo_tpu.ops.kv_write import kv_write
+
+        new = a((args.tokens or 520, args.kh, d), jnp.bfloat16)
+        jax.jit(lambda k, v, ck, cv, bt, qs, kl, ts, layer: kv_write(
+            k, v, ck, cv, bt, qs, kl, ts, layer=layer)).lower(
+                new, new, cache, cache, a((b, 512), jnp.int32), rows, rows,
+                rows, a((), jnp.int32)).compile()
+        return
     if args.tokens:
         q, extra, kw = a((args.tokens, args.h, d), jnp.bfloat16), (rows,), {"t": t}
     else:
@@ -116,6 +128,8 @@ def main() -> None:
     p.add_argument("--window", type=int, default=0)
     p.add_argument("--tokens", type=int, default=0,
                    help="> 0: the token-major entry over this many tokens")
+    p.add_argument("--kernel", default="paged_attention",
+                   choices=["paged_attention", "kv_write"])
     p.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     args = p.parse_args()
     if args.child:
@@ -129,7 +143,7 @@ def main() -> None:
                        + sys.argv[1:], env={**os.environ, "JAX_PLATFORMS": "cpu"},
                        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
         found = sorted(glob.glob(os.path.join(
-            args.out, "*paged_attention*-final_bundles.txt")))
+            args.out, f"*{args.kernel}*-final_bundles.txt")))
         if not found:
             raise SystemExit(f"no final bundles under {args.out}")
         path = found[-1]
