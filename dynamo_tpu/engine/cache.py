@@ -25,6 +25,18 @@ byte along head_dim: ``{"q": uint8 [L, NB, BS, KH, D//2], "s": f32}`` —
 the uint8 payload dtype IS the packed-int4 marker everywhere downstream
 (kernel, kvbm, scatter), so no third leaf or flag is needed. A block costs
 ~0.25x its bf16 bytes, so auto-sizing fits ~4x the blocks.
+
+A model of latent attention (``ModelConfig.latent``: multi-head latent
+attention) has ONE pool, not a K pool and a V pool: ``[layers, num_blocks,
+block_size, 1, W]``, a token's row ``[c_kv | k_r | zeros]`` of
+``latent_row`` values stored ``W`` wide (the next multiple of 128 lanes),
+which every head reads; a token's value is its row's first ``kv_lora_rank``
+values (models/llama.py ``_latent_attention``). Wherever the engine carries
+"K and V" such a model carries ``(pool, None)``: None is an empty pytree, so
+donation, shardings and the layer loop's carry take it as it is. A block is
+sized, fitted and counted by that one array's bytes. A quantized latent pool
+is refused here; the tiers and the wire that copy K and V by name
+(dynamo_tpu.kvbm, disagg) are refused at the engine's construction.
 """
 
 from __future__ import annotations
@@ -55,6 +67,18 @@ class KVCacheSpec:
     #: "int8" / "int4" enable quantized storage; any other value means the
     #: cache is stored at ``dtype`` (model precision) exactly as before.
     kv_dtype: str = "bfloat16"
+    #: one pool of latent rows (``ModelConfig.latent``), ``head_dim`` the
+    #: stored row's width of which the first ``row_width`` values are the
+    #: row; False: a K pool and a V pool
+    latent: bool = False
+    row_width: int = 0
+
+    def __post_init__(self):
+        if self.latent and self.quantized:
+            raise ValueError(
+                f"kv_dtype={self.kv_dtype} with a latent (MLA) cache: a "
+                "quantized latent pool is not implemented (the row's rotary "
+                "part and its latent would want scales of their own)")
 
     @classmethod
     def for_model(cls, cfg: ModelConfig, num_blocks: int, block_size: int,
@@ -69,7 +93,23 @@ class KVCacheSpec:
             head_dim=cfg.cache_head_dim,
             dtype=cfg.dtype,
             kv_dtype=kv_dtype,
+            latent=cfg.latent,
+            row_width=cfg.latent_row if cfg.latent else 0,
         )
+
+    @property
+    def pools(self) -> int:
+        """Arrays of ``shape`` the cache is: K and V, or the one latent
+        pool."""
+        return 1 if self.latent else 2
+
+    @property
+    def kind(self) -> str:
+        return "latent" if self.latent else "kv"
+
+    def bytes_per_token(self) -> int:
+        """A token's stored bytes over all layers (scales apart)."""
+        return self.bytes_per_block() // self.block_size
 
     @property
     def quantized(self) -> bool:
@@ -117,8 +157,9 @@ class KVCacheSpec:
             scales = 2 * self.num_layers * self.num_kv_heads * _SCALE_ITEMSIZE
             return payload + scales
         itemsize = jnp.dtype(self.dtype).itemsize
-        # k + v, all layers
-        return 2 * self.num_layers * self.block_size * self.num_kv_heads * self.head_dim * itemsize
+        # k + v (or the one latent pool), all layers
+        return (self.pools * self.num_layers * self.block_size
+                * self.num_kv_heads * self.head_dim * itemsize)
 
 
 def register_device_tier(pool, spec: KVCacheSpec, *, name: str = "device") -> None:
@@ -161,12 +202,13 @@ def cache_sharding(spec: KVCacheSpec, mesh: Mesh | None):
 
 def allocate_cache(spec: KVCacheSpec, mesh: Mesh | None = None):
     """Allocate zeroed K and V caches (sharded if a mesh is given: each
-    device then zeroes its own shard and never sees the whole)."""
+    device then zeroes its own shard and never sees the whole). A latent
+    spec's are ``(pool, None)``."""
     if mesh is None:
-        return _zeros(spec), _zeros(spec)
+        return _zeros(spec), None if spec.latent else _zeros(spec)
     zeros = jax.jit(partial(_zeros, spec),
                     out_shardings=cache_sharding(spec, mesh))
-    return zeros(), zeros()
+    return zeros(), None if spec.latent else zeros()
 
 
 def abstract_cache(spec: KVCacheSpec, mesh: Mesh | None = None):
@@ -180,6 +222,13 @@ def abstract_cache(spec: KVCacheSpec, mesh: Mesh | None = None):
     return jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         shapes, sharding)
+
+
+def abstract_caches(spec: KVCacheSpec, mesh: Mesh | None = None) -> tuple:
+    """:func:`abstract_cache` as a step program takes the cache: K and V,
+    or ``(pool, None)`` of a latent spec."""
+    one = abstract_cache(spec, mesh)
+    return one, None if spec.latent else one
 
 
 def cache_payload(cache) -> jax.Array:

@@ -43,6 +43,7 @@ from dynamo_tpu.engine import device
 from dynamo_tpu.engine.cache import (
     KVCacheSpec,
     abstract_cache,
+    abstract_caches,
     allocate_cache,
     cache_payload,
     cache_sharding,
@@ -162,6 +163,33 @@ def _recurrent_engine_config(ec: EngineConfig) -> EngineConfig:
     return dataclasses.replace(ec, enable_prefix_caching=False)
 
 
+def _latent_engine_config(ec: EngineConfig) -> None:
+    """Refuse, each by its option, what is not implemented over a latent
+    (MLA) cache (engine/cache.py: one pool of rows, no K and V by head):
+    the meshes that divide heads, layers or the sequence, a quantized pool,
+    and the tiers and checkpoints that copy a K block and a V block by
+    name (dynamo_tpu.kvbm)."""
+    refused = {
+        "tp": (ec.tp > 1, "the one row a token has no head axis to divide"),
+        "pp": (ec.pp > 1, "the latent pool is not divided over stages"),
+        "sp": (ec.sp > 1, "ring prefill exchanges keys and values by head"),
+        "kv_dtype": (ec.kv_dtype in ("int8", "int4"),
+                     "a quantized latent pool is not implemented"),
+        "host_kv_blocks / disk_kv_path / remote_kv_addr": (
+            ec.host_kv_blocks > 0 or bool(ec.disk_kv_path)
+            or bool(ec.remote_kv_addr),
+            "the offload tiers hold a K block and a V block"),
+        "stream_ckpt_blocks": (
+            ec.stream_ckpt_blocks > 0,
+            "a checkpoint holds a K block and a V block"),
+    }
+    for option, (hit, why) in refused.items():
+        if hit:
+            raise ValueError(
+                f"{option} is refused for a model with a latent (MLA) cache "
+                f"({ec.model!r}): {why}")
+
+
 def _moves_blocks_alone(op: Callable) -> Callable:
     """An ``EngineCore`` operation that hands a sequence's cache on as
     blocks (disaggregated transfer in either direction): refused for a
@@ -169,6 +197,12 @@ def _moves_blocks_alone(op: Callable) -> Callable:
     blocks without its state."""
     @functools.wraps(op)
     def guarded(self, *args, **kwargs):
+        if self.model_cfg.latent:
+            raise ValueError(
+                f"{op.__name__} is refused for a model with a latent (MLA) "
+                f"cache ({self.engine_cfg.model!r}): the transfer's wire "
+                "format is a K block and a V block; the prompt has to be "
+                "recomputed where the sequence runs")
         if self.model_cfg.has_ssm:
             raise ValueError(
                 f"{op.__name__} is refused for a model with recurrent "
@@ -331,6 +365,14 @@ class EngineMetrics:
     # pool's layers, slots, the two leaves' shapes and dtypes, the bytes of
     # one sequence's state in one layer, and that prefix matching is off.
     ssm: dict | None = None
+    # A model of latent attention (set once): the cache's kind, a row's
+    # stored and useful width, its bytes a token over the layers; and what
+    # its chunk rows carried (cumulative): the prefill chunks of several
+    # tokens that ran, and the context under them, each chunk's last
+    # position + 1 (``stats()["attn"]``).
+    attn: dict | None = None
+    attn_chunk_rows: int = 0
+    attn_chunk_ctx_tokens: int = 0
     # The shapes that price a step's counts (set once;
     # obs/costmodel.py step_shapes): per kind of layer the parameters a
     # program reads whatever its rows, one expert's, the head's,
@@ -364,6 +406,9 @@ class EngineMetrics:
             # Beside the pool's shapes, its rows that a sequence holds now.
             **({"ssm": {**self.ssm, "slots_in_use": sched.slots_in_use}}
                if self.ssm else {}),
+            **({"attn": {**self.attn, "chunk_rows": self.attn_chunk_rows,
+                         "chunk_ctx_tokens": self.attn_chunk_ctx_tokens}}
+               if self.attn else {}),
             **({"step_shapes": self.step_shapes} if self.step_shapes else {}),
             "kv_pool_blocks": self.kv_pool_blocks,
             "kv_block_bytes": self.kv_block_bytes,
@@ -784,11 +829,11 @@ class ModelRunner:
                    key=lambda s: (s.n, s.b * s.t, s.nblk))
 
     def _block_bytes_per_device(self) -> int:
-        """One block's bytes on each device (K and V), by the cache's own
-        sharding."""
+        """One block's bytes on each device (K and V, or the one latent
+        pool), by the cache's own sharding."""
         one = abstract_cache(
             dataclasses.replace(self.spec, num_blocks=1), self.mesh)
-        return 2 * sum(
+        return self.spec.pools * sum(
             math.prod(leaf.shape if self.mesh is None
                       else leaf.sharding.shard_shape(leaf.shape))
             * leaf.dtype.itemsize for leaf in jax.tree.leaves(one))
@@ -800,12 +845,12 @@ class ModelRunner:
         assignment. Nothing is allocated: the cache is abstract. The greedy
         variant stands for both (measured ahead-of-time: the sampling
         variant's temporaries are no larger, and it compiles 5x slower)."""
-        cache = abstract_cache(
+        caches = abstract_caches(
             dataclasses.replace(self.spec, num_blocks=num_blocks), self.mesh)
         maxb = self.engine_cfg.max_batch_size
         ssm = ({"ssm": mamba.state_shapes(self.cfg, maxb)}
                if self.cfg.has_ssm else {})
-        args = (self.params, cache, cache, self.counts, self.keys,
+        args = (self.params, *caches, self.counts, self.keys,
                 self.slot_toks,
                 *self._padding_inputs(sig.b, sig.t, sig.nblk, True))
         # The answer is kept in the program store under the probe's own
@@ -995,7 +1040,8 @@ class ModelRunner:
         if self.mesh is None:
             return {}
         repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
-        return {"out_shardings": (cache, cache, repl, repl, repl)
+        cache_v = None if self.spec.latent else cache
+        return {"out_shardings": (cache, cache_v, repl, repl, repl)
                 # (the recurrent state's pool: every device holds it whole)
                 + ((repl,) if self.cfg.has_ssm else ()) + (repl, repl)
                 + ((repl,) if self.moe_impl == "held" else ())}
@@ -1422,7 +1468,8 @@ class ModelRunner:
         kw = {}
         if self.mesh is not None:
             repl, cache = self._repl, cache_sharding(self.spec, self.mesh)
-            kw["out_shardings"] = (cache, cache, repl, repl)
+            kw["out_shardings"] = (
+                cache, None if self.spec.latent else cache, repl, repl)
         name = BucketSig("verify", b, t, nblk, True, "").program()
         return jax.jit(_named(verify, name), donate_argnums=(1, 2), **kw)
 
@@ -1493,7 +1540,7 @@ class ModelRunner:
             shape = (cfg.attn_layers, nblk + 1, ec.block_size,
                      cfg.cache_kv_heads, cfg.cache_head_dim)
             ck = jnp.zeros(shape, jnp.dtype(cfg.dtype))
-            cv = jnp.zeros(shape, jnp.dtype(cfg.dtype))
+            cv = None if cfg.latent else jnp.zeros(shape, jnp.dtype(cfg.dtype))
             bt = jnp.tile(jnp.arange(1, nblk + 1, dtype=jnp.int32)[None, :],
                           (tokens.shape[0], 1))
             q_start = jnp.zeros((tokens.shape[0],), jnp.int32)
@@ -1714,6 +1761,8 @@ class EngineCore:
                 f"needs it even; model {engine_cfg.model!r} has head_dim="
                 f"{self.model_cfg.head_dim}")
         mc = self.model_cfg
+        if mc.latent:
+            _latent_engine_config(engine_cfg)
         if mc.holds_share and engine_cfg.ep > 1:
             raise ValueError(
                 f"model {engine_cfg.model!r} holds {mc.num_experts} of "
@@ -1850,6 +1899,7 @@ class EngineCore:
             kv_step_copy_bytes_per_block=self.runner.step_copy_bytes_per_block,
             moe=self._moe_facts(),
             ssm=self._ssm_facts(),
+            attn=self._attn_facts(),
             step_shapes=cm.step_shapes(
                 mc, block_size=engine_cfg.block_size,
                 kv_dtype=engine_cfg.kv_dtype or "bfloat16",
@@ -2291,6 +2341,18 @@ class EngineCore:
                            [h, mc.router_width]]
                 + ([[h, sm], [sm, h]] if sm else [])}
 
+    def _attn_facts(self) -> dict | None:
+        """``stats()["attn"]`` of a model of latent attention."""
+        mc, spec = self.model_cfg, self.runner.spec
+        if not mc.latent:
+            return None
+        return {"cache_kind": spec.kind, "pools": spec.pools,
+                "row_stored": spec.head_dim, "row_useful": spec.row_width,
+                "value_width": mc.kv_lora_rank,
+                "bytes_per_token": spec.bytes_per_token(),
+                "layers": spec.num_layers, "heads": mc.num_heads,
+                "absorbed": "decode and chunk rows"}
+
     def _ssm_facts(self) -> dict | None:
         """``stats()["ssm"]`` of a model with recurrent layers."""
         mc = self.model_cfg
@@ -2606,8 +2668,12 @@ class EngineCore:
         counts = step_counts(pending.batches, self.engine_cfg.block_size,
                              self._windows, dec_rows=pending.dec_rows,
                              attn_tokens=attends_tokens(self.engine_cfg),
-                             block_writes=writes_blocks(self.engine_cfg),
+                             # (a latent row is written by the scatter)
+                             block_writes=writes_blocks(self.engine_cfg)
+                             and not self.model_cfg.latent,
                              **self._recurrent_and_cross)
+        self.metrics.attn_chunk_rows += counts["chunk_rows"]
+        self.metrics.attn_chunk_ctx_tokens += counts["chunk_ctx_tokens"]
         ssm = (tuple(counts[k] for k in SSM_COUNTS) if self._ssm_layers
                else None)
         pc = self.sched.preemption_count

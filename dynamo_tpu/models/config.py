@@ -294,6 +294,20 @@ class ModelConfig:
     # ``1 - lambda_init``. The KV cache holds whole pairs side by side in
     # fewer, wider heads (``cache_kv_heads`` x ``cache_head_dim``).
     diff_attention: bool = False
+    # Multi-head latent attention (arXiv:2405.04434) in every attention
+    # mixer, ``kv_lora_rank`` > 0: the cache holds one row a token, the
+    # normed latent ``c_kv`` and the one rotary key ``k_r`` all heads share
+    # (``kv_lora_rank + qk_rope_head_dim`` values, no head axis), and the
+    # up-projections are absorbed into the query and the output
+    # (models/llama.py ``_latent_attention``). A head scores over
+    # ``qk_nope_head_dim + qk_rope_head_dim`` values and reads ``v_head_dim``;
+    # ``head_dim`` is the first of the two sums. ``q_lora_rank`` 0: the
+    # query has no down-projection.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # Multimodal (vision encoder attached)
     vision: "VisionConfig | None" = None
 
@@ -338,6 +352,24 @@ class ModelConfig:
                 "_attention has no rope of a half)")
         if self.norm_kind not in ("rms", "layer"):
             raise ValueError(f"norm_kind {self.norm_kind!r}: 'rms' or 'layer'")
+        if self.latent and (
+                self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim
+                or self.qk_rope_head_dim % 2 or not self.v_head_dim
+                or self.num_kv_heads != self.num_heads
+                or self.kv_lora_rank % 128
+                or self.qk_norm or self.diff_attention or self.attention_bias
+                or self.layer_types or self.hybrid_pattern
+                or self.decoder_layout or self.ssm_beside_attention
+                or self.rope_scope != "all" or self.norm_placement != "pre"
+                or self.norm_kind != "rms"):
+            raise ValueError(
+                "latent attention (kv_lora_rank) is implemented for full "
+                "attention layers under pre-norm RMSNorm with rotary on "
+                "qk_rope_head_dim (even) values of a head of "
+                "qk_nope_head_dim + qk_rope_head_dim = head_dim, a "
+                "v_head_dim, as many KV heads as heads and a latent of whole "
+                "128-lane tiles: no window, QK norm, bias, differential "
+                "attention, recurrent mixer or 'post' norm beside it")
         if self.hybrid_pattern:
             odd = set(self.hybrid_pattern) - set("M*E")
             if odd or len(self.hybrid_pattern) != self.num_layers:
@@ -385,6 +417,17 @@ class ModelConfig:
     def holds_share(self) -> bool:
         """Whether the expert layer holds fewer experts than it routes over."""
         return 0 < self.num_experts < self.router_width
+
+    @property
+    def latent(self) -> bool:
+        """Whether attention keeps a latent row a token (``kv_lora_rank``)
+        in place of keys and values by head."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """What a token's row of the latent cache holds: ``c_kv | k_r``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def has_ssm(self) -> bool:
@@ -473,6 +516,8 @@ class ModelConfig:
         but one is stored in tiles of 8: ten heads of 128 would lie as
         sixteen, and the paged kernel cannot cut them: Phi-4-mini-flash's
         20 heads of 64 are 2 of 640)."""
+        if self.latent:
+            return 1        # one row a token, which every head reads
         if not self.diff_attention:
             return self.num_kv_heads
         pairs = self.num_kv_heads // 2
@@ -481,6 +526,12 @@ class ModelConfig:
 
     @property
     def cache_head_dim(self) -> int:
+        """The width of a cache head. A latent row is stored at the next
+        multiple of the chip's 128 lanes, zeros behind ``latent_row`` (576
+        values lie as 640: a block is then whole tiles, which the paged
+        kernel's copies and products take as they are)."""
+        if self.latent:
+            return -(-self.latent_row // 128) * 128
         return self.num_kv_heads * self.head_dim // self.cache_kv_heads
 
     def lambda_init(self, kind: str) -> "np.ndarray":
@@ -571,14 +622,31 @@ class ModelConfig:
 
     @property
     def kv_size(self) -> int:
+        """Values a token's K (and V) has over all heads; under latent
+        attention what its down-projection gives, the cached row."""
+        if self.latent:
+            return self.latent_row
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def o_size(self) -> int:
+        """The width of ``wo``'s input: the heads' values side by side."""
+        return self.num_heads * (self.v_head_dim if self.latent
+                                 else self.head_dim)
 
     @classmethod
     def from_hf_config(cls, path: str) -> "ModelConfig":
         """Read a local HF config.json (llama-family keys)."""
         cfg = json.loads((Path(path) / "config.json").read_text())
-        cfg = _phi4flash_keys(
-            _falcon_h1_keys(_nemotron_h_keys(_smallthinker_keys(cfg))))
+        cfg = _glm4_moe_lite_keys(_phi4flash_keys(
+            _falcon_h1_keys(_nemotron_h_keys(_smallthinker_keys(cfg)))))
+        if "kv_lora_rank" in cfg and not cfg.get(_OWN_FIELDS, {}).get(
+                "kv_lora_rank"):
+            raise ValueError(
+                f"kv_lora_rank under model_type {cfg.get('model_type')!r}: a "
+                "latent (MLA) cache is read for model_type 'glm4_moe_lite' "
+                "alone; read as keys and values by head it would be served "
+                "wrongly")
         n_heads = cfg["num_attention_heads"]
         # MoE keys across HF families: mixtral (num_local_experts),
         # deepseek/qwen-moe (n_routed_experts, num_experts).
@@ -885,6 +953,57 @@ def _phi4flash_keys(cfg: dict) -> dict:
             "sliding_window": cfg["sliding_window"],
             "mamba_inner": cfg.get("mamba_expand", 2) * h,
             "mamba_dt_rank": cfg.get("mamba_dt_rank") or -(-h // 16),
+        },
+    }
+
+
+def _glm4_moe_lite_keys(cfg: dict) -> dict:
+    """``cfg`` with GLM-4.7-Flash's keys (``model_type: "glm4_moe_lite"``:
+    DeepSeek-V2's multi-head latent attention, arXiv:2405.04434, over
+    DeepSeek-V3's bias-corrected sigmoid routing, arXiv:2412.19437, a
+    leading dense layer and a shared expert) under the names
+    ``from_hf_config`` reads, the latent ranks and the three head sizes as
+    the fields they are; any other config comes back as it is. What cannot
+    be served is refused by its key."""
+    if cfg.get("model_type") != "glm4_moe_lite":
+        return cfg
+    refused = {
+        "rope_scaling": (cfg.get("rope_scaling") is not None,
+                         "models/llama.py rope() scales no frequency"),
+        "attention_bias": (bool(cfg.get("attention_bias", False)),
+                           "the latent projections have no bias"),
+        "topk_method": (cfg.get("topk_method", "noaux_tc") != "noaux_tc",
+                        "the family's router is the bias-corrected sigmoid "
+                        "choice"),
+        "scoring_func": (cfg.get("scoring_func", "sigmoid") != "sigmoid",
+                         "the family's router scores by sigmoid"),
+        "num_nextn_predict_layers": (
+            bool(cfg.get("num_nextn_predict_layers", 0)),
+            "multi-token-prediction (drafting) blocks are not implemented: "
+            "state 0"),
+        "hidden_act": (cfg.get("hidden_act", "silu") != "silu",
+                       "the MLP and the experts act by silu"),
+        "num_key_value_heads": (
+            cfg.get("num_key_value_heads", cfg["num_attention_heads"])
+            != cfg["num_attention_heads"],
+            "every head has its own up-projection of the one latent"),
+    }
+    for key, (hit, why) in refused.items():
+        if hit:
+            raise ValueError(f"{key}: {cfg.get(key)!r} is refused: {why}")
+    nope, rot = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+    return {
+        **cfg,
+        "head_dim": nope + rot,
+        "num_key_value_heads": cfg["num_attention_heads"],
+        "scoring_func": "sigmoid",      # topk_method noaux_tc states both
+        "router_bias": True,            # e_score_correction_bias
+        _OWN_FIELDS: {
+            "kv_lora_rank": cfg["kv_lora_rank"],
+            "q_lora_rank": cfg.get("q_lora_rank") or 0,
+            "qk_nope_head_dim": nope,
+            "qk_rope_head_dim": rot,
+            "v_head_dim": cfg["v_head_dim"],
         },
     }
 

@@ -115,6 +115,17 @@ def _layer_axes(cfg: ModelConfig, routed: bool) -> Params:
     }
     if cfg.qk_norm:
         layer.update(q_norm=("layers", None), k_norm=("layers", None))
+    if cfg.latent:
+        # No leaf of it is divided: the engine refuses a mesh for this cache.
+        for name in ("wq", "wk", "wv"):
+            del layer[name]
+        layer.update(
+            wo=("layers", None, None), wkv_a=("layers", None, None),
+            kv_a_norm=("layers", None), w_uk=("layers", None, None, None),
+            w_uv=("layers", None, None, None),
+            **({"wq_a": ("layers", None, None), "q_a_norm": ("layers", None),
+                "wq_b": ("layers", None, None)} if cfg.q_lora_rank
+               else {"wq": ("layers", None, None)}))
     if routed:
         layer.update(
             router=("layers", None, "expert"),
@@ -228,7 +239,42 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         return lax.map(lambda k1: whole(k1, shape[1:], fan_in, pad, leaf),
                        jax.random.split(key, shape[0]))
 
+    def latent_attention(L):
+        # What latent attention (``cfg.latent``) has in place of ``wq``
+        # (where the query has a down-projection), ``wk`` and ``wv``: the
+        # two down-projections with their norms, the query's up-projection,
+        # and the latent's two up-projections by head, ``w_uk [heads, nope,
+        # rank]`` (keys; stored transposed, as the absorb reads it) and
+        # ``w_uv [heads, rank, v]``. (The norms' weights around 1, not at
+        # it: a norm left out, or its weight, is then something a
+        # comparison can see.)
+        r, qr, heads = cfg.kv_lora_rank, cfg.q_lora_rank, cfg.num_heads
+        ks = iter(jax.random.split(next(k), 12))
+
+        def around_one(shape):
+            return (1.0 + 0.1 * jax.random.normal(
+                next(ks), shape, jnp.float32)).astype(dt)
+
+        out = {
+            "wkv_a": dense(next(ks), (L, h, cfg.latent_row), h),
+            "kv_a_norm": around_one((L, r)),
+            "w_uk": dense(next(ks), (L, heads, cfg.qk_nope_head_dim, r), r),
+            "w_uv": dense(next(ks), (L, heads, r, cfg.v_head_dim), r),
+            "wo": dense(next(ks), (L, cfg.o_size, h), cfg.o_size),
+            "attn_norm": jnp.ones((L, h), dt),
+            "mlp_norm": jnp.ones((L, h), dt),
+        }
+        if qr:
+            out.update(wq_a=dense(next(ks), (L, h, qr), h),
+                       q_a_norm=around_one((L, qr)),
+                       wq_b=dense(next(ks), (L, qr, cfg.q_size), qr))
+        else:
+            out["wq"] = dense(next(ks), (L, h, cfg.q_size), h)
+        return out
+
     def attention(L):
+        if cfg.latent:
+            return latent_attention(L)
         out = {
             "wq": dense(next(k), (L, h, cfg.q_size), h, leaf="wq"),
             "wk": dense(next(k), (L, h, cfg.kv_size), h, leaf="wk"),
@@ -597,7 +643,7 @@ def paged_attention(
     scores = jnp.where(visible[:, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("btkrs,bskd->btkrd", probs, ctx_v.astype(jnp.float32))
-    return out.reshape(b, t, h, d).astype(q.dtype)
+    return out.reshape(b, t, h, ctx_v.shape[-1]).astype(q.dtype)
 
 
 def mm(x: jax.Array, w) -> jax.Array:
@@ -889,6 +935,97 @@ def _attention(cfg: ModelConfig, lp: Params, layer, x, cache_k, cache_v, *,
     return out_proj(lay.to_tokens(attn)), cache_k, cache_v
 
 
+def _latent_attention(cfg: ModelConfig, lp: Params, layer, x, cache, *,
+                      lay: TokenLayout, positions, slot, block_tables,
+                      q_start, kv_lens, attn_impl: str = "dense", mesh=None,
+                      use_ring: bool = False, window: int = 0):
+    """Multi-head latent attention (``cfg.latent``; arXiv:2405.04434) on the
+    normed state ``x [N, H]``, in its absorbed form for every row, a decode
+    row's one token and a chunk's alike. Returns (out [N, H], cache).
+
+    What is cached is one row a token and layer, ``[c_kv | k_r | zeros]``
+    (``cfg.latent_row`` values stored ``cfg.cache_head_dim`` wide): the
+    normed latent ``c_kv = RMSNorm(x W_dkv[:, :rank])`` and the one rotary
+    key ``k_r = rope(x W_dkv[:, rank:])`` every head shares. ``cache`` is
+    the one pool ``[L, NB, BS, 1, W]`` (engine/cache.py): there is no V
+    pool, a token's value is its row's first ``rank`` values. No key or
+    value by head is ever built over a context: a head's keys are ``c_kv
+    W_uk`` and its values ``c_kv W_uv``, so
+
+        scores = q_nope . (c_kv W_uk) + q_rope . k_r
+               = (q_nope W_uk^T) . c_kv + q_rope . k_r
+        out    = (softmax(scores) c_kv) W_uv
+
+    which is attention with ``heads`` query heads ``[q_nope W_uk^T | q_rope
+    | 0]`` over ONE KV head whose key is the cached row and whose value is
+    that row's first ``rank`` lanes: the paged walk with ``rep = heads``
+    (ops/paged_attention.py, ``v_cache`` None), or the dense gather on the
+    same two operands. The scale is the full head's, ``(nope + rope) **
+    -0.5``. ``w_uk`` is stored ``[heads, nope, rank]`` and ``w_uv [heads,
+    rank, v]``: each product is one ``dot_general`` batched over heads that
+    reads the leaf as it lies."""
+    if use_ring or window or mesh is not None and mesh.shape.get("model", 1) > 1:
+        raise ValueError("latent attention is served on one device, full "
+                         "layers: no ring prefill, no window, no 'model' axis")
+    n, heads = x.shape[0], cfg.num_heads
+    rank, nope, rot = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    width = cfg.cache_head_dim
+    with _perf_phase("mla_down"):
+        # The down-projections, their norms, the query's up-projection and
+        # the rotary positions of the rope parts.
+        if cfg.q_lora_rank:
+            c_q, ckv = jax.lax.optimization_barrier(
+                (mm(x, lp["wq_a"]), mm(x, lp["wkv_a"])))
+            q = mm(rms_norm(c_q, lp["q_a_norm"], cfg.rms_norm_eps),
+                   lp["wq_b"])
+        else:
+            q, ckv = jax.lax.optimization_barrier(
+                (mm(x, lp["wq"]), mm(x, lp["wkv_a"])))
+        q = q.reshape(n, heads, nope + rot)
+        q_nope, q_rope = q[..., :nope], rope(q[..., nope:], positions,
+                                             cfg.rope_theta)
+        c_kv = rms_norm(ckv[:, :rank], lp["kv_a_norm"], cfg.rms_norm_eps)
+        k_r = rope(ckv[:, None, rank:], positions, cfg.rope_theta)[:, 0]
+        row = jnp.concatenate(
+            [c_kv, k_r, jnp.zeros((n, width - rank - rot), c_kv.dtype)], -1)
+    with _perf_phase("mla_absorb"):
+        # q' = q_nope W_uk^T, [N, heads, rank], and the query as the walk
+        # takes it: zeros where the stored row is padding.
+        q_lat = jnp.einsum("nhd,hdc->nhc", q_nope, lp["w_uk"])
+        q = jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros((n, heads, width - rank - rot),
+                                      q_lat.dtype)], -1)
+    with _perf_phase("mla_write"):
+        cache = _scatter_kv(cache, row.astype(cache.dtype), slot, layer)
+    scale = (nope + rot) ** -0.5
+    kernel = attn_impl in ("pallas", "pallas_interpret")
+    if kernel:
+        from dynamo_tpu.ops.paged_attention import paged_attention_kernel
+
+        attend = partial(
+            paged_attention_kernel, layer=layer, scale=scale, v_width=rank,
+            interpret=attn_impl == "pallas_interpret")
+    with _perf_phase("mla_walk"):
+        if kernel and lay.starts is not None:
+            o_lat = attend(q, cache, None, block_tables, q_start, kv_lens,
+                           starts=lay.starts, t=lay.t)
+        elif kernel:
+            o_lat = lay.to_tokens(attend(
+                lay.to_rows(q), cache, None, block_tables, q_start, kv_lens))
+        else:
+            ctx = _gather_kv(cache, block_tables, layer)     # [B, S, 1, W]
+            o_lat = lay.to_tokens(paged_attention(
+                lay.to_rows(q), ctx, ctx[..., :rank],
+                q_start[:, None] + jnp.arange(lay.t)[None, :], kv_lens,
+                scale=scale))
+    with _perf_phase("mla_unabsorb"):
+        # o = o_lat W_uv, [N, heads, v]
+        o = jnp.einsum("nhc,hcv->nhv", o_lat, lp["w_uv"])
+    with _perf_phase("proj"):
+        out = mm(o.reshape(n, cfg.o_size), lp["wo"])
+    return out, cache
+
+
 def _pair_queries(q: jax.Array, kv_heads: int, cache_heads: int) -> jax.Array:
     """Differential attention's queries ``[N, heads, D]`` as the cache's
     view takes them, ``[N, heads, W]``, ``W`` the ``kv_heads / cache_heads``
@@ -1132,11 +1269,18 @@ def _run_layers(cfg: ModelConfig, plan: LayerPlan, layers: Params, h,
                         with _perf_phase("moe_route"):
                             routing = route(x, lp, cfg)
                     # (no "+ 0": it would be an equation of every program)
-                    out, k, v = _attention(
-                        cfg, lp, (m.layer - m.place) + i
-                        if m.layer != m.place else i, x, k, v, lay=lay,
-                        q_start=q_start, attn_impl=attn_impl, mesh=mesh,
-                        use_ring=use_ring, window=m.window, **diff, **attn)
+                    at = (m.layer - m.place) + i if m.layer != m.place else i
+                    if cfg.latent:      # one pool: ``v`` stays None
+                        out, k = _latent_attention(
+                            cfg, lp, at, x, k, lay=lay, q_start=q_start,
+                            attn_impl=attn_impl, mesh=mesh,
+                            use_ring=use_ring, window=m.window, **attn)
+                    else:
+                        out, k, v = _attention(
+                            cfg, lp, at, x, k, v, lay=lay,
+                            q_start=q_start, attn_impl=attn_impl, mesh=mesh,
+                            use_ring=use_ring, window=m.window, **diff,
+                            **attn)
                 elif m.kind == "M":
                     out, state = mamba.mixer(
                         cfg, lp, i, x, state, lay=lay, slots=ssm_slots,

@@ -121,7 +121,7 @@ def streams_experts(n: int, h: int, m: int, itemsize: int,
     from dynamo_tpu.ops import moe_stream
 
     return (n <= STREAM_MAX_ROWS
-            and moe_stream.vmem_bytes(n, h, m, itemsize, matrices)
+            and moe_stream.vmem_ask(n, h, m, itemsize, matrices)
             <= moe_stream.VMEM_MAX_BYTES
             and jax.default_backend() == "tpu"
             and (mesh is None or mesh.size == 1))

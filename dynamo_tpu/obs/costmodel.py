@@ -293,6 +293,8 @@ def model_step_cost(
     kv_block_bytes = block_size * cfg.num_kv_heads * cfg.head_dim * kvb
     if kv_dtype in ("int8", "int4"):
         kv_block_bytes += cfg.num_kv_heads * 4
+    if cfg.latent:      # one row a token, read once (x 2 below: K and V)
+        kv_block_bytes = block_size * cfg.latent_row * kvb / 2
     attn_flops = 4.0 * cfg.num_heads * cfg.head_dim * attn_q_ctx * L
     attn_bytes = (2.0 * n * cfg.q_size * ab
                   + 2.0 * kv_blocks * kv_block_bytes) * L
@@ -390,12 +392,34 @@ def step_shapes(cfg: ModelConfig, *, block_size: int,
                     ) // max(fixed_layers, 1)
     last_params = (of("G", tail) * 2 * h * d + of("X", tail) * 2 * h * cfg.q_size
                    + of("-", tail) * ffn_params)
+    attn = {"head_dim": cfg.head_dim, "q_size": cfg.q_size,
+            "attn_params": 2 * h * cfg.q_size + 2 * h * cfg.kv_size}
+    if cfg.latent:
+        # Latent attention in the terms the pricing has (``step_work``: ``4
+        # x heads x head_dim`` FLOP a (query, key) pair, a block's bytes a
+        # block walked, ``q_size`` values a token in and out of the walk),
+        # by the USEFUL widths, never the stored row's padding: a pair is
+        # ``2 x latent_row`` (scores over the row) + ``2 x rank`` (values:
+        # the row's first lanes), so ``head_dim`` is their mean; a token's
+        # one row is read once for both, so a block is ``block_size x
+        # latent_row`` values, no second pool; the matrices are the two
+        # down-projections, the query's up-projection, the two absorbed
+        # up-projections (2 FLOP a parameter a token, as any matrix) and wo.
+        rank, row, heads = cfg.kv_lora_rank, cfg.latent_row, cfg.num_heads
+        mean = (row + rank) // 2
+        q_in = (h * cfg.q_lora_rank + cfg.q_lora_rank * cfg.q_size
+                if cfg.q_lora_rank else h * cfg.q_size)
+        attn = {"head_dim": mean, "q_size": heads * mean,
+                "attn_params": q_in + h * row + heads * rank * (
+                    cfg.qk_nope_head_dim + cfg.v_head_dim) + cfg.o_size * h,
+                "cache_kind": "latent", "latent_row": row,
+                "latent_rank": rank, "latent_row_stored": cfg.cache_head_dim}
+        block = block_size * row * kvb
     return {
         "layers": L, "routed_layers": routed,
         "dense_ffn_layers": fixed_layers,
         "hidden_size": h, "num_heads": cfg.num_heads,
-        "head_dim": cfg.head_dim, "q_size": cfg.q_size,
-        "attn_params": 2 * h * cfg.q_size + 2 * h * cfg.kv_size,
+        **attn,
         "dense_ffn_params": fixed_params,
         "shared_expert_params": mats * h * cfg.shared_expert_width,
         "router_params": h * cfg.router_width if routed else 0,

@@ -92,6 +92,11 @@ DEVICE_PHASES = (
     # differential attention's combine on the kernel's output, and SambaY's
     # gated memory unit (models/llama.py)
     "attn_diff", "gmu",
+    # latent attention (models/llama.py ``_latent_attention``): the two
+    # down-projections with their norms, the query's up-projection and the
+    # rope; ``q_nope W_uk^T``; the row's write into the latent pool; the
+    # paged walk in its latent form; ``o_lat W_uv``
+    "mla_down", "mla_absorb", "mla_write", "mla_walk", "mla_unabsorb",
 )
 
 _INSTRUCTION = re.compile(

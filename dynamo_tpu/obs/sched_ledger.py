@@ -911,6 +911,10 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
       sig.b x sig.t``), whose K and V go into the pools by runs of
       consecutive slots (ops/kv_write.py); a rectangle program's keep the
       scatter, an update a token;
+    - ``chunk_rows``, ``chunk_ctx_tokens``: the prefill chunks of several
+      tokens among the rows, and the context under them, ``start + length``
+      a chunk (what its last query sees): how long the contexts are that
+      reach attention under chunk rows;
     - ``kv_blocks_live``: ``ceil((start + length) / block_size)`` a row,
       the blocks the rows hold, what a full layer's kernel walks for them;
     - ``kv_blocks_walked``: the same over all the layers, a sliding layer's
@@ -957,7 +961,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
     programs = pf_rows = n_dec = pf_tokens = dec_tokens = 0
     live = logit_rows = sched = rect = sched_rows = 0
     blocks = walked = q_ctx = table_q = table_blocks = scanned = ones = 0
-    shared = by_blocks = 0
+    shared = by_blocks = chunk_rows = chunk_ctx = 0
     blocked = ssm_layers > scan_layers      # some layer scans in blocks
     dec_left = dec_rows
     for sig, rows, *_ in batches:
@@ -1006,6 +1010,8 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
                 q_ctx += cross_layers * end
             if chunks and length > 1:
                 pf_tokens += length
+                chunk_rows += 1
+                chunk_ctx += end
                 scanned += sig.t * blocked
             else:
                 dec_tokens += length
@@ -1025,6 +1031,7 @@ def step_counts(batches, block_size: int, windows, *, dec_rows: int = 0,
         "kv_blocks_walked_shared": shared,
         "cross_tokens": logit_rows if cross_layers else 0,
         "kv_block_written_tokens": by_blocks,
+        "chunk_rows": chunk_rows, "chunk_ctx_tokens": chunk_ctx,
         "attn_q_ctx": q_ctx,
         "table_q_ctx": table_q, "table_blocks": table_blocks,
         "ssm_layer_steps": programs * ssm_layers,
@@ -1077,7 +1084,9 @@ def step_geometry(model_cfg, engine_cfg, batches, *, dec_rows: int = 0,
         counts = step_counts(
             batches, ec.block_size, model_cfg.attn_windows,
             dec_rows=dec_rows, attn_tokens=attends_tokens(ec),
-            block_writes=writes_blocks(ec),
+            # (a latent row is written by the scatter)
+            block_writes=writes_blocks(ec)
+            and not model_cfg.latent,
             **recurrent_and_cross(model_cfg))
     if shapes is None:
         shapes = cm.step_shapes(

@@ -126,6 +126,18 @@ inside vLLM/TRT-LLM, which we replace):
   a (row, chunk) without a live token moves nothing (PERF.md, PR 50). The
   grid's steps run in order there ("arbitrary"): a row's last tile runs
   into the next row's tokens.
+- a latent pool (``v_cache`` None, ``v_width``: multi-head latent
+  attention's one row a token, ``[L, NB, BS, 1, W]``, which every query head
+  reads; models/llama.py ``_latent_attention``) is one more mode of the same
+  walk, not a second kernel: one copy a block (there is no V pool), the
+  group's buffer ``[G*BS, W]`` (the pool seen ``[L, NB, BS, W]``: its one
+  head is no axis of the tiles, a block is ``BS x W`` bf16 in whole
+  ``(16, 128)`` tiles), the scores over the whole stored row (the query
+  carries zeros where the row is padding) and **V taken from K's tile**,
+  its first ``v_width`` lanes (a multiple of 128: a lane-aligned slice of
+  the value already loaded, no copy), so P.V and the accumulator are
+  ``v_width`` wide. The table walk, the mask, the softmax state and every
+  address are the ones above: no new dynamic address.
 - quantized caches: int8 payloads copy at 1 byte/elem and go to the MXU as
   they are (exact in bf16); the per-(block, kv-head) scale multiplies that
   block's BS columns of the group's scores, and of its probabilities before
@@ -312,7 +324,7 @@ def _group_blocks(rows: int, bs: int, nblk: int) -> int:
 
 def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int, nblk: int,
             quant: bool, int4: bool, mm_dtype, window: int = 0,
-            tile: int = 0):
+            tile: int = 0, latent: bool = False):
     # A window (static, > 0) adds one scalar-prefetch operand, the block
     # each walk begins at, ahead of the others, and a lower bound in the
     # mask; with window == 0 nothing below is traced that was not before.
@@ -332,6 +344,9 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int, nblk: int,
     else:
         (bt_ref, qs_ref, kl_ref, ub_ref, ly_ref, *refs) = refs
         ks_ref = vs_ref = None
+    if latent:
+        # One pool: no V operand and no V buffer (V is K's first lanes).
+        refs = [*refs[:2], None, refs[2], refs[3], None, *refs[4:]]
     if tile:
         (q_hbm, k_hbm, v_hbm, o_hbm, kbuf, vbuf, sems, acc_ref, m_ref, l_ref,
          tok_ref, q_ref, tok_sems) = refs
@@ -398,8 +413,10 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int, nblk: int,
             dst = pl.ds(lax.mul(i, _i32(bs)), bs)
             pltpu.make_async_copy(k_hbm.at[layer, blk], kbuf.at[slot, dst],
                                   sems.at[0, slot]).start()
-            pltpu.make_async_copy(v_hbm.at[layer, blk], vbuf.at[slot, dst],
-                                  sems.at[1, slot]).start()
+            if not latent:
+                pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                      vbuf.at[slot, dst],
+                                      sems.at[1, slot]).start()
             return c
         lax.fori_loop(0, gb, copy, 0)
 
@@ -407,7 +424,7 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int, nblk: int,
         # One wait a buffer for the group's whole byte count: a DMA
         # semaphore counts bytes, and a wait takes its count from the
         # descriptor's shape, not from a source.
-        for n, buf in enumerate((kbuf, vbuf)):
+        for n, buf in enumerate((kbuf,) if latent else (kbuf, vbuf)):
             pltpu.make_async_copy(buf.at[slot], buf.at[slot],
                                   sems.at[n, slot]).wait()
 
@@ -496,9 +513,15 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int, nblk: int,
             if quant:
                 p = p * scale_row(vs_ref, ki)
             # Float32 operands at default precision: the MXU takes both in
-            # one bf16 pass (the module's note on precision).
+            # one bf16 pass (the module's note on precision). A latent
+            # tile's values are the keys' own lanes, bf16 as they landed:
+            # the probabilities take that pass's rounding here, by name.
+            if latent and v.dtype == jnp.bfloat16:
+                p = lax.convert_element_type(p, jnp.bfloat16)
+            else:
+                v = _cast(v, jnp.float32)
             pv = lax.dot_general(
-                p, _cast(v, jnp.float32), (((1,), (0,)), ((), ())),
+                p, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)                   # [R, D]
             acc_ref[ki] = lax.add(
                 lax.mul(acc_ref[ki], cols(alpha, acc_ref.shape[-1])), pv)
@@ -507,7 +530,12 @@ def _kernel(*refs, bs: int, kh: int, rep: int, gb: int, nq: int, nblk: int,
 
         # A block lands as it lies in the pool, [BS, KH, Dp], so one head's
         # rows are KH apart in the group's buffer.
-        if int4:
+        if latent:
+            # The group's rows [GK, W] as they landed, and their first
+            # lanes as the values.
+            rows = kbuf[slot]
+            head(0, rows, lax.slice_in_dim(rows, 0, acc_ref.shape[-1], axis=1))
+        elif int4:
             # Unpack once per group for all kv heads: uint8 [GK, KH, D/2]
             # → signed nibbles [GK, KH, D].
             k_wide = unpack_int4(kbuf[slot]).astype(jnp.float32)
@@ -637,9 +665,11 @@ def _token_tile(q_hbm, o_hbm, tok_ref, q_ref, sems, *, first, n_live):
         q_ref[0] = lax.reshape(lax.slice_in_dim(heads, 0, h), (kh, r, d))
 
     def collect(out):
-        heads = lax.pad(lax.reshape(out, (h, tile, d)),
+        # (a latent walk's output is the values' width: zeros behind it)
+        dv = out.shape[-1]
+        heads = lax.pad(lax.reshape(out, (h, tile, dv)),
                         lax.full((), 0.0, out.dtype),
-                        ((0, hp - h, 0), (0, 0, 0), (0, 0, 0)))
+                        ((0, hp - h, 0), (0, 0, 0), (0, d - dv, 0)))
         mine = lax.lt(lax.broadcasted_iota(jnp.int32, tok_ref.shape, 0),
                       lax.broadcast(n_live, tok_ref.shape))
         tok_ref[...] = lax.select(mine, lax.transpose(heads, (1, 0, 2)),
@@ -679,9 +709,15 @@ def paged_attention_kernel(
     scale: float | None = None,  # on q; None: the head's ``D ** -0.5``
                               #   (a caller whose D is not the model's
                               #   head: differential attention's pair view)
+    v_width: int = 0,         # with ``v_cache`` None (a latent pool): the
+                              #   lanes of a row that are its value
 ) -> jax.Array:
     """Flash paged attention over layer ``layer`` of a block-table cache.
     Returns [B, T, H, D].
+
+    A latent pool (``v_cache`` None, ``k_cache [L, NB, BS, 1, W]``): every
+    query head ``[.., W]`` scores against the one row a token and reads its
+    first ``v_width`` lanes; the result is ``[.., H, v_width]``.
 
     Token-major (``starts`` given): ``q [N, H, D]`` holds the rows' live
     query tokens packed row after row, row ``i``'s ``kv_lens[i] -
@@ -716,6 +752,14 @@ def paged_attention_kernel(
     """
     k_cache, v_cache, layer = _layer_stack(k_cache, v_cache, layer)
     quant = isinstance(k_cache, dict)
+    latent = v_cache is None
+    if latent and (quant or window or not v_width or v_width % 128
+                   or k_cache.shape[3] != 1 or v_width > k_cache.shape[4]):
+        raise ValueError(
+            "a latent pool is [L, NB, BS, 1, W] at the model's precision, "
+            "walked whole (no quantized payload, no window) with v_width a "
+            f"multiple of 128 within W: got {jax.tree.map(jnp.shape, k_cache)}"
+            f", window {window}, v_width {v_width}")
     int4 = False
     # Held inside the cache: the kernel's copies are not checked.
     layers = (k_cache["q"] if quant else k_cache).shape[0]
@@ -733,6 +777,10 @@ def paged_attention_kernel(
     else:
         b, t, h, d = q.shape
     _, nb, bs, kh, dp = k_cache.shape
+    dv = v_width if latent else d       # the output's width
+    if latent:
+        # The one head is no axis of a block's tiles: the same bytes.
+        k_cache = k_cache.reshape(layers, nb, bs, dp)
     if int4 and dp * 2 != d:
         raise ValueError(
             f"packed int4 cache trailing dim {dp} != head_dim/2 ({d}//2)")
@@ -788,10 +836,11 @@ def paged_attention_kernel(
         else jnp.float32
 
     scratch_shapes = [
-        pltpu.VMEM((2, gb * bs, kh, dp), k_cache.dtype),
-        pltpu.VMEM((2, gb * bs, kh, dp), v_cache.dtype),
+        *([pltpu.VMEM((2, gb * bs, dp), k_cache.dtype)] if latent else
+          [pltpu.VMEM((2, gb * bs, kh, dp), k_cache.dtype),
+           pltpu.VMEM((2, gb * bs, kh, dp), v_cache.dtype)]),
         pltpu.SemaphoreType.DMA((2, 2)),
-        pltpu.VMEM((kh, rchunk, d), jnp.float32),
+        pltpu.VMEM((kh, rchunk, dv), jnp.float32),
         pltpu.VMEM((kh, rchunk, 128), jnp.float32),
         pltpu.VMEM((kh, rchunk, 128), jnp.float32),
     ]
@@ -814,19 +863,19 @@ def paged_attention_kernel(
             q_spec,
             # The cache stays where it is, [L, NB, BS, KH, Dp] in HBM: the
             # kernel copies the blocks the table names, a group at a time.
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            *[pl.BlockSpec(memory_space=pl.ANY)] * (1 if latent else 2),
         ],
-        out_specs=q_spec,
+        out_specs=q_spec if packed or not latent else pl.BlockSpec(
+            (1, kh, rchunk, dv), qmap),
         scratch_shapes=scratch_shapes,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, kh=kh, rep=rep, gb=gb, nq=nq,
                           nblk=nblk, quant=quant, int4=int4, mm_dtype=mm_dtype,
-                          window=window, tile=tile),
+                          window=window, tile=tile, latent=latent),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            qs.shape if packed else (b, kh, r, d), q.dtype),
+            qs.shape if packed else (b, kh, r, dv), q.dtype),
         # The tokens' tiles overlap (a row's last one runs into the next
         # row's tokens) and are written over the queries they were read
         # from: the grid's steps run in order.
@@ -843,11 +892,12 @@ def paged_attention_kernel(
         # A name of its own in the device trace (the custom call would
         # otherwise take it from whatever scope encloses it).
         name="paged_attention",
-    )(*scalars, qs, k_cache, v_cache)
+    )(*scalars, qs, k_cache, *(() if latent else (v_cache,)))
     if packed:
-        return lax.slice(out, (0, 0, 0), (n, h, d))
+        return lax.slice(out, (0, 0, 0), (n, h, dv))
     # [B, KH, T*REP, D] → [B, T, H, D]
-    return out.reshape(b, kh, t, rep, d).transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
+    return out.reshape(b, kh, t, rep, dv).transpose(0, 2, 1, 3, 4).reshape(
+        b, t, h, dv)
 
 
 def paged_attention_sharded(

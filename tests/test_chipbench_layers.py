@@ -714,3 +714,137 @@ def test_cross_decoder_counter_on_a_hand_made_context(name, ctx, expect):
     entry = {m["name"]: m for m in BENCH["per_layer"]}[name]
     assert entry["workloads"] == ["phi-4-mini-flash.reasoning"]
     assert entry["source"] == "program_counter"
+
+
+# ---------------------------------------------------------------------------
+# PR 61: the latent (MLA) cache's three readers and their counting function
+# ---------------------------------------------------------------------------
+
+LATENT_CELL = "glm-4.7-flash.longdoc"
+LATENT = {"cache_kind": "latent", "pools": 1, "row_stored": 640,
+          "row_useful": 576, "value_width": 512, "bytes_per_token": 10240,
+          "layers": 8, "heads": 20}
+
+
+def _latent(rows: int | None, ctx_tokens: int = 0):
+    c0 = {} if rows is None else {"attn": {
+        **LATENT, "chunk_rows": 40, "chunk_ctx_tokens": 300_000}}
+    c1 = {} if rows is None else {"attn": {
+        **LATENT, "chunk_rows": 40 + rows,
+        "chunk_ctx_tokens": 300_000 + ctx_tokens}}
+    return _ctx(c0, c1)
+
+
+@pytest.mark.parametrize("ctx, expect", [
+    # a 16,384-token prompt in 32 chunks of 512: the chunks end at 512,
+    # 1,024, ... 16,384, a mean of 8,448
+    (_latent(32, 512 * 32 * 33 // 2), 8448.0),
+    (_latent(0, 0), None),          # a window in which no chunk ran
+    (_latent(None), None),          # a program without the counters
+], ids=["one_prompt", "no_chunk", "no_counter"])
+def test_chunk_context_on_a_hand_made_context(ctx, expect):
+    value = measure.load_reader("mla.chunk_ctx_tokens").read(ctx)
+    assert value == (None if expect is None else pytest.approx(expect))
+
+
+def test_latent_counts_are_the_useful_widths():
+    counts = measure.load_module(
+        ROOT / "chipbench" / "layers" / "mla_counts.py", "mla_counts")
+    # one chunk of 512 tokens at a context of 16,384 over 8 layers: 1,024
+    # blocks a layer; a query at position p sees p + 1 keys
+    pairs = 8 * (512 * (16_384 - 512) + 512 * 513 // 2)
+    nbytes, flop = counts.step(8 * 1024, pairs, 512, LATENT, 16)
+    assert nbytes == 8 * 1024 * 16 * 576 * 2 + 512 * 8 * 20 * (576 + 512) * 2
+    assert flop == pairs * 20 * 2 * (576 + 512)
+    # compute-bound by far: 2.9 TFLOP against 0.24 GB
+    from harness import peaks
+    pk = peaks.peaks_for("TPU v5 lite")
+    assert counts.ideal_seconds(8 * 1024, pairs, 512, LATENT, 16, pk) == \
+        pytest.approx(flop / pk.flops_bf16)
+    assert flop / pk.flops_bf16 > 10 * nbytes / pk.hbm_bytes_per_s
+
+
+def test_latent_trace_readers_on_a_hand_made_join(monkeypatch):
+    """``device.mla_pct`` by phase and ``attn.latent_roofline_pct`` by the
+    kernel's name over one matched chunk step."""
+    join = measure.load_module(
+        ROOT / "chipbench" / "layers" / "step_join.py", "step_join")
+    pairs = 8 * (512 * (16_384 - 512) + 512 * 513 // 2)
+    step = join.Step(7, {"kv_blocks_walked": 8 * 1024, "attn_q_ctx": pairs,
+                         "live_tokens": 512}, programs=[0])
+    ops = [(0, "fusion.1", "mla_down", 2e6), (0, "fusion.2", "mla_absorb", 1e6),
+           (0, "scatter.3", "mla_write", 1e6),
+           (0, "paged_attention.4", "mla_walk", 40e6),
+           (0, "fusion.5", "mla_unabsorb", 1e6), (0, "fusion.6", "proj", 5e6),
+           (0, "ragged-dot.7", "moe_experts", 50e6)]
+    j = join.Joined([("step_mixed_b8_t512", 0.0, 100e6)], [step], 1.0, ops,
+                    {"step_mixed_b8_t512": {}})
+
+    class Events:
+        path = None
+
+        def busy_ns(self):
+            return 100e6
+    readers = {name: measure.load_reader(name)
+               for name in ("device.mla_pct", "attn.latent_roofline_pct")}
+    for reader in readers.values():
+        monkeypatch.setattr(reader.join, "current", lambda: j)
+    monkeypatch.setattr(xevents, "current", Events)
+    c1 = {"attn": LATENT, "step_shapes": {"block_size": 16},
+          "device": {"device_kind": "TPU v5 lite"}}
+    ctx = _ctx({}, c1, trace={"busy_s": 0.1, "window_s": 5.0})
+    assert readers["device.mla_pct"].read(ctx) == pytest.approx(45.0)
+    ideal = pairs * 20 * 2 * (576 + 512) / 197e12
+    assert readers["attn.latent_roofline_pct"].read(ctx) == \
+        pytest.approx(100.0 * ideal / 40e-3, rel=1e-3)
+    # a program that states no latent cache: nothing to read
+    plain = _ctx({}, {"step_shapes": {"block_size": 16}}, trace=ctx.trace)
+    assert readers["attn.latent_roofline_pct"].read(plain) is None
+
+
+@pytest.mark.parametrize("name", ["device.mla_pct", "attn.latent_roofline_pct",
+                                  "mla.chunk_ctx_tokens"])
+def test_latent_reader_is_the_new_cells_alone(name, monkeypatch):
+    """Each is read in the new cell alone and moves what it is judged on; on
+    the parent's program (no counters, no phases) it returns None."""
+    entry = {m["name"]: m for m in BENCH["per_layer"]}[name]
+    assert entry["workloads"] == [LATENT_CELL]
+    assert entry["moves"] == "itl_p95_ms"
+    monkeypatch.setattr(xevents, "newest_xplane", lambda *a, **k: CHIP_TRACE)
+    assert measure.load_reader(name).read(_ctx(
+        {"num_steps": 1}, {"num_steps": 9},
+        trace={"busy_s": 0.2, "window_s": 0.25})) is None
+
+
+def test_the_long_document_slice_may_hold_no_decode_step():
+    """The new cell's traced slice is chunk steps end to end (busy 97 %), so
+    the reader of the ``jit_step_decode_*`` executions finds none there: its
+    entry lists the accepted cells, which all report it, and the cell is held
+    to every other metric that has no list and moves what it reports."""
+    entry = {m["name"]: m for m in BENCH["per_layer"]}["engine.decode_step_dev_ms"]
+    accepted = [w["name"] for w in BENCH["workloads"] if w["name"] != LATENT_CELL]
+    assert entry["workloads"] == accepted
+    cell = manifest.load_cell(LATENT_CELL)
+    assert "engine.decode_step_dev_ms" not in cell.per_layer
+    unlisted = {m["name"] for m in BENCH["per_layer"]
+                if "workloads" not in m and m["moves"] in cell.end_to_end}
+    assert unlisted <= set(cell.per_layer)
+
+
+def test_the_latent_configuration_brings_its_reference():
+    cfg = {c["name"]: c for c in BENCH["configs"]}["glm-4.7-flash-l5"]
+    assert cfg["reduced"] == ["num_hidden_layers", "num_nextn_predict_layers"]
+    ref = (ROOT / cfg["file"]).parent / "reference.py"
+    _tree, defined = manifest._functions(ref)
+    assert {"logits_at", "routing_margin_at"} <= defined
+    assert "dynamo_tpu" not in ref.read_text().replace(
+        "``dynamo_tpu/models/llama.py", "")
+    about = json.loads(((ROOT / cfg["file"]).parent / "about.json").read_text())
+    assert manifest.probe_faults(ref.parent, about) == []
+    cell = manifest.load_cell(LATENT_CELL)
+    assert cell.end_to_end == ["itl_p95_ms", "tokens_per_s", "setup_s"]
+    assert cell.traffic["prompt_tokens"] == {
+        "median": 16384, "sigma": 0.4, "min": 8192, "max": 30720}
+    assert cell.traffic["output_tokens"] == {
+        "median": 128, "sigma": 0.4, "min": 64, "max": 256}
+    assert cell.about["engine"]["max_model_len"] == 32768

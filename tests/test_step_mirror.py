@@ -56,14 +56,17 @@ WARMED = {"mistral-7b.chat": 64, "mistral-7b.longprompt": 48,
           "smallthinker-21b.reasoning": 66,
           "nemotron-3-nano-30b.reasoning": 99,
           "falcon-h1-34b.reasoning": 99,
-          "phi-4-mini-flash.reasoning": 99}
+          "phi-4-mini-flash.reasoning": 99,
+          # (PR 61: tables of 512-2,048 blocks, prompts of 8,192 and up)
+          "glm-4.7-flash.longdoc": 40}
 # ... rows (8, 16), or (8, 16, 32) where the cell's ``max_rows`` is 32.
 WARMED_KERNEL = {"mistral-7b.chat": 14, "mistral-7b.longprompt": 14,
                  "mistral-nemo-12b.chat": 14, "k-exaone-236b.reasoning": 21,
                  "smallthinker-21b.reasoning": 14,
                  "nemotron-3-nano-30b.reasoning": 21,
                  "falcon-h1-34b.reasoning": 21,
-                 "phi-4-mini-flash.reasoning": 21}
+                 "phi-4-mini-flash.reasoning": 21,
+                 "glm-4.7-flash.longdoc": 14}
 # What EngineCore resolves EngineConfig.attn_impl to: on a TPU, elsewhere.
 PATHS = {"kernel": "pallas", "gather": "dense"}
 # Seconds a step takes in the replay: (a decode step, each chunk token on
@@ -100,8 +103,16 @@ CLOCKS_OF = {"k-exaone-236b.reasoning": {"fast": (0.011, 0.0001),
              # at most at 1.0 req/s over 150 s, so the 32-row programs have
              # that room at the cell's 0.8)
              "phi-4-mini-flash.reasoning": {"fast": (0.0176, 0.00005),
-                                            "slow": (0.0200, 0.000057)}}
+                                            "slow": (0.0200, 0.000057)},
+             # (PR 61, chip call 177: a decode step 5-8 ms at 8 rows and
+             # 6.5-12 at 16 over 1k-30k of latent context, a 512-token chunk
+             # step 20-55 ms by the context under it, ~0.06 ms a chunk
+             # token in the mean; and a third slower)
+             "glm-4.7-flash.longdoc": {"fast": (0.008, 0.000055),
+                                       "slow": (0.009, 0.00006)}}
 POOL_BLOCKS = 6000
+# ... and where a cell's prompts would not fit that: about the chip's pool
+POOL_BLOCKS_OF = {"glm-4.7-flash.longdoc": 20000}
 
 
 def _replay(cell, ec, order: int, clock: tuple[float, float]):
@@ -114,7 +125,8 @@ def _replay(cell, ec, order: int, clock: tuple[float, float]):
     reqs = [(r.due_s, r) for r in reqs] + [
         (ramp_s + r.due_s, r)
         for r in traffic.schedule(tr, vocab, 51.0, 1, rate, 0, order)]
-    sched = Scheduler(PrefixPool(POOL_BLOCKS, ec.block_size),
+    sched = Scheduler(PrefixPool(POOL_BLOCKS_OF.get(cell.name, POOL_BLOCKS),
+                                 ec.block_size),
                       ec.max_batch_size, ec.prefill_chunk, ec.max_model_len,
                       ec.max_tokens_per_step)
     runner = types.SimpleNamespace(

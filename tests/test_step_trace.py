@@ -528,7 +528,8 @@ def test_new_readers_find_nothing_on_the_rehearsals_traces(monkeypatch, trace):
 def test_the_new_entries_and_their_cells():
     entries = {m["name"]: m for m in BENCH["per_layer"]}
     assert set(NEW) <= set(entries)
-    gated = {"k-exaone-236b.reasoning", "smallthinker-21b.reasoning"}
+    gated = {"k-exaone-236b.reasoning", "smallthinker-21b.reasoning",
+             "glm-4.7-flash.longdoc"}       # (PR 61: 64 gated experts, whole)
     # (PR 45) experts without a gate: the phase's share is read there, its
     # roofline share by a count of its own (moe.ungated_experts_roofline_pct:
     # moe_gemm_counts.py prices three matrices an expert)

@@ -120,7 +120,8 @@ def main() -> int:
             with mock.patch.object(jax, "default_backend", lambda: "tpu"):
                 lowered = runner._build_step_fn(
                     b, t, runner.max_nblk, fast_greedy=True).lower(
-                        params, cache, cache, *state, *inputs, **pool)
+                        params, cache, None if spec.latent else cache,
+                        *state, *inputs, **pool)
             name = f"b{b}_t{t}"
             (args.out / f"{name}.txt").write_text(
                 kernel_text(lowered.as_text(debug_info=False)))
